@@ -29,6 +29,9 @@ __version__ = "0.1.0"
 
 from . import base
 from .base import MXNetError
+
+base.configure_compile_cache()  # before the first compile, see base.py
+
 from .context import Context, cpu, tpu, gpu, current_context, num_gpus, \
     num_tpus, num_devices
 from . import ndarray

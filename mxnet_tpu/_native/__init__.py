@@ -8,9 +8,11 @@ pooled_storage_manager.h for host staging buffers; the indexed RecordIO
 reader + batch prefetcher are iter_image_recordio_2.cc/iter_prefetcher.h.
 Device-side scheduling belongs to XLA's async dispatch and needs no C++.
 
-The library is built on demand with g++ (make -C src/cpp) and cached;
-every consumer falls back to pure python when unavailable
-(``native.available()`` gates the fast paths).
+The library is a git-ignored build product: it is built on demand with
+g++ (make -C src/cpp) whenever it is missing or older than the committed
+sources.  Every consumer falls back to pure python when unavailable
+(``native.available()`` gates the fast paths; ``native.build_error()``
+says why).
 """
 from __future__ import annotations
 
@@ -21,27 +23,47 @@ import threading
 
 import numpy as np
 
-__all__ = ["available", "lib", "Engine", "RecordReader", "Prefetcher",
-           "pool_stats"]
+__all__ = ["available", "build_error", "lib", "Engine", "RecordReader",
+           "Prefetcher", "pool_stats"]
 
 _LOCK = threading.Lock()
 _LIB = None
 _TRIED = False
+_ERROR = None  # why the library is unavailable, for whoever reports it
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libmxtpu.so")
 _SRC = os.path.normpath(os.path.join(_HERE, "..", "..", "src", "cpp"))
 
 
-def _build():
+def _stale():
+    """True when the library is missing or older than any committed
+    source: the ``.so`` is a git-ignored build product, so one left on
+    disk by another checkout state must not be loaded as if it matched
+    ``src/cpp``."""
+    if not os.path.isfile(_SO):
+        return True
     if not os.path.isdir(_SRC):
-        return False
+        return False  # installed without sources: nothing to compare
+    built = os.path.getmtime(_SO)
+    return any(os.path.getmtime(os.path.join(_SRC, f)) > built
+               for f in os.listdir(_SRC) if f.endswith((".cc", ".h")))
+
+
+def _build():
+    """``make -C src/cpp``; returns the failure reason, None on success."""
+    if not os.path.isdir(_SRC):
+        return f"no sources at {_SRC}"
     try:
         subprocess.run(["make", "-C", _SRC], check=True,
-                       capture_output=True, timeout=300)
-        return os.path.isfile(_SO)
-    except Exception:
-        return False
+                       capture_output=True, text=True, timeout=300)
+    except FileNotFoundError as e:
+        return f"make not found: {e}"
+    except subprocess.TimeoutExpired:
+        return "make timed out (300s)"
+    except subprocess.CalledProcessError as e:
+        return f"make failed (rc={e.returncode}): {e.stderr[-500:]}"
+    return None if os.path.isfile(_SO) else f"make produced no {_SO}"
 
 
 def _bind(so):
@@ -87,25 +109,36 @@ def _bind(so):
 
 
 def lib():
-    """Load (building if needed) the native library; None if unavailable."""
-    global _LIB, _TRIED
+    """Load the native library, (re)building it from ``src/cpp`` when it
+    is missing or older than the sources; None if unavailable (see
+    :func:`build_error`)."""
+    global _LIB, _TRIED, _ERROR
     with _LOCK:
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
         if os.environ.get("MXNET_TPU_NO_NATIVE"):
+            _ERROR = "disabled by MXNET_TPU_NO_NATIVE"
             return None
-        if not os.path.isfile(_SO) and not _build():
-            return None
+        if _stale():
+            _ERROR = _build()
+            if _ERROR is not None:
+                return None
         try:
             _LIB = _bind(ctypes.CDLL(_SO))
-        except OSError:
-            _LIB = None
+        except OSError as e:
+            _ERROR = f"dlopen failed: {e}"
         return _LIB
 
 
 def available():
     return lib() is not None
+
+
+def build_error():
+    """Why :func:`available` is False (None when the library loaded)."""
+    lib()
+    return _ERROR
 
 
 def _i64arr(values):

@@ -20,6 +20,30 @@ class MXNetError(RuntimeError):
     ``mxnet.base.MXNetError`` via the C ABI, python/mxnet/base.py:?)."""
 
 
+#: Persistent XLA compile cache used when ``JAX_COMPILATION_CACHE_DIR`` does
+#: not place it elsewhere: one fixed, git-ignored directory beside the
+#: package.  The path is part of jax's cache key, so it must never move
+#: between runs (no tempfile, pid or clock in it).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def configure_compile_cache() -> None:
+    """Point jax's persistent compilation cache at a directory that
+    survives the process, before anything compiles.  A chip session
+    starts with no compiled code and a full-width step costs minutes to
+    compile, so every executable is admitted (jax's default skips
+    compiles under one second — the hundreds of small eager-op programs
+    a cold start pays for one by one — and has no size floor).  ``JAX_COMPILATION_CACHE_DIR``
+    wins when set: jax reads it itself and no directory is set here."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
 def check(cond: bool, msg: str = "") -> None:
     """CHECK-style assertion (reference ``dmlc/logging.h`` ``CHECK(x)``)."""
     if not cond:

@@ -6,12 +6,12 @@ every NDArray creation.
 
 TPU-native redesign: a Context resolves to a concrete ``jax.Device``.  The
 north star extends the reference's {cpu, gpu} pair with ``mx.tpu()``;
-``mx.gpu()`` is kept as a compatibility alias that maps to the accelerator
-backend when one exists (so reference scripts that say ``ctx=mx.gpu(0)`` run
-unchanged on a TPU host).  Multi-device placement for data-parallel training
-is a *list* of contexts, exactly like the reference's ``ctx=[mx.gpu(i) ...]``;
-the parallel layer (mxnet_tpu/parallel) turns such lists into a
-``jax.sharding.Mesh``.
+``mx.gpu()`` is kept as a compatibility alias for ``mx.tpu()`` (so reference
+scripts that say ``ctx=mx.gpu(0)`` run unchanged on a TPU host); both raise
+``MXNetError`` in a process that has no TPU.  Multi-device placement for
+data-parallel training is a *list* of contexts, exactly like the reference's
+``ctx=[mx.gpu(i) ...]``; the parallel layer (mxnet_tpu/parallel) turns such
+lists into a ``jax.sharding.Mesh``.
 """
 from __future__ import annotations
 
@@ -27,9 +27,9 @@ class Context:
     Parameters
     ----------
     device_type : str
-        'cpu', 'tpu' or 'gpu' ('gpu' aliases the default jax accelerator).
+        'cpu', 'tpu' or 'gpu' ('gpu' aliases 'tpu').
     device_id : int
-        Index into this process's ``jax.local_devices(backend)``.
+        Index into this process's devices of that platform.
     """
 
     _local = threading.local()
@@ -54,11 +54,15 @@ class Context:
         if self.device_type == "cpu":
             devs = jax.local_devices(backend="cpu")
         else:
-            # 'tpu' and the 'gpu' compat alias both mean "the accelerator
-            # backend jax booted with" — under JAX_PLATFORMS=cpu that is the
-            # (virtual) CPU device list, which is exactly what the unit-test
-            # mesh wants.
-            devs = jax.local_devices()
+            # 'tpu' and the 'gpu' compat alias name a real accelerator:
+            # never hand back a CPU device under that name — a process
+            # with no TPU says so here instead of running on the host
+            devs = [d for d in jax.local_devices() if d.platform == "tpu"]
+            if not devs:
+                raise MXNetError(
+                    f"context {self}: this process has no TPU device "
+                    f"(jax backend is {jax.default_backend()!r}); use "
+                    "mx.cpu() or the default context")
         if self.device_id >= len(devs):
             raise MXNetError(
                 f"context {self} out of range: only {len(devs)} "
@@ -149,13 +153,19 @@ def current_context() -> Context:
 def num_devices(device_type: Optional[str] = None) -> int:
     """Reference analog: ``mx.context.num_gpus()`` — counts THIS
     process's devices (like CUDA device enumeration), so the canonical
-    ``[mx.tpu(i) for i in range(num_devices())]`` idiom stays valid in
-    multi-process groups.  Use ``global_num_devices`` for mesh math."""
+    ``[mx.tpu(i) for i in range(num_tpus())]`` idiom stays valid in
+    multi-process groups.  ``None`` counts the default backend's devices
+    whatever their platform; 'tpu'/'gpu' count TPU devices only, matching
+    what ``mx.tpu(i)`` resolves.  Use ``global_num_devices`` for mesh
+    math."""
     import jax
 
     if device_type == "cpu":
         return len(jax.local_devices(backend="cpu"))
-    return len(jax.local_devices())
+    devs = jax.local_devices()
+    if device_type is None:
+        return len(devs)
+    return sum(d.platform == "tpu" for d in devs)
 
 
 def global_num_devices() -> int:
@@ -165,9 +175,9 @@ def global_num_devices() -> int:
     return jax.device_count()
 
 
-def num_gpus() -> int:  # compat shim; counts accelerator devices
-    return num_devices()
+def num_gpus() -> int:  # compat shim for the 'gpu' alias
+    return num_devices("tpu")
 
 
 def num_tpus() -> int:
-    return num_devices()
+    return num_devices("tpu")

@@ -126,23 +126,26 @@ class Parameter:
         if initializer is None:
             initializer = init_mod.Uniform()
         initializer(desc, arr)
-        # initializers assign fresh arrays born on jax's DEFAULT device;
-        # honor the requested context (e.g. cpu ctx on a TPU host — the
-        # parity lane's cross-backend runs) by re-placing when they
-        # differ.  Only without an active mesh: under a mesh `.device`
-        # is a Sharding and replicate() below owns placement (a
-        # device_put here would collapse the mesh layout, and would
-        # crash on non-addressable multi-process arrays).
+        # initializers assign fresh arrays born UNCOMMITTED on jax's
+        # DEFAULT device; commit the parameter to the requested context
+        # (a re-placement when they differ, e.g. a cpu ctx on a TPU host;
+        # an alias of the same buffer when they agree).  A parameter must
+        # be committed from birth: jit keys its executables on which
+        # operands are committed, every jit output is committed as soon
+        # as one input is (batches from ``nd.array`` are), so a step
+        # whose parameters start uncommitted compiles once for the first
+        # call and once more for every later one.  Only without an
+        # active mesh: under a mesh `.device` is a Sharding and
+        # replicate() below owns placement (a device_put here would
+        # collapse the mesh layout, and would crash on non-addressable
+        # multi-process arrays).
         from .. import parallel
 
         mesh = parallel.current_mesh()
         if mesh is None:
             import jax
 
-            want = ctx_list[0].device
-            dev = getattr(arr._data, "device", None)
-            if isinstance(dev, jax.Device) and dev != want:
-                arr._data = jax.device_put(arr._data, want)
+            arr._data = jax.device_put(arr._data, ctx_list[0].device)
         else:
             # under an active device mesh, parameters are born
             # replicated so GSPMD derives the gradient all-reduce

@@ -50,7 +50,7 @@ def export_block(block, path, epoch=0):
             "(the reference has the same requirement)")
     sig, graph = next(iter(cached._graphs.items()))
     import jax
-    import jax.export  # jax >= 0.4.30 no longer auto-imports the submodule
+    import jax.export  # a submodule: `import jax` alone does not load it
 
     params = graph.params
     p_raws = tuple(p.data()._data for p in params)
@@ -114,7 +114,7 @@ def import_block(symbol_file, input_names, param_file=None, ctx=None):
 
 def _import_stablehlo(symbol_file, meta, param_file):
     import jax
-    import jax.export  # jax >= 0.4.30 no longer auto-imports the submodule
+    import jax.export  # a submodule: `import jax` alone does not load it
 
     from .block import HybridBlock, SymbolBlock
     from .. import serialization

@@ -268,24 +268,22 @@ def _install():
         flipud rot90 broadcast_to broadcast_arrays append where clip
         round around argsort take take_along_axis partition argpartition
         trace tensordot einsum pad bincount digitize interp histogram
-        allclose isclose array_equal array_equiv triu tril trilu
+        allclose isclose array_equal array_equiv triu tril
         meshgrid unravel_index ravel_multi_index diff ediff1d gradient
-        trapz dot insert delete resize flatten invert""".split()
+        dot insert delete resize invert""".split()
     creation = """zeros ones full arange linspace logspace geomspace eye
         identity tri zeros_like ones_like full_like empty_like
         frombuffer""".split()
 
     for nm in unary + binary + other:
-        jfn = getattr(jnp, nm, None)
-        if jfn is None or nm in g:
+        if nm in g:
             continue
-        g[nm] = _wrap(jfn, nm)
+        g[nm] = _wrap(getattr(jnp, nm), nm)
         __all__.append(nm)
     for nm in creation:
-        jfn = getattr(jnp, nm, None)
-        if jfn is None or nm in g:
+        if nm in g:
             continue
-        g[nm] = _creation(jfn, nm)
+        g[nm] = _creation(getattr(jnp, nm), nm)
         __all__.append(nm)
     __all__.extend(["array", "empty"])
 

@@ -4,10 +4,10 @@ Reference: ``src/operator/contrib/transformer.cc:?`` — the
 ``interleaved_matmul_selfatt_qk/valatt`` + ``div_sqrt_dim`` ops GluonNLP's
 BERT uses for fused self-attention.
 
-TPU-native: one fused ``dot_product_attention`` op (jax.nn's flash-style
-kernel path on TPU; falls back to the XLA softmax(QKᵀ)V fusion elsewhere),
-plus reference-compatible wrappers for the interleaved contrib ops.  bf16
-inputs accumulate in fp32 on the MXU.
+TPU-native: one fused ``dot_product_attention`` op (the Pallas flash
+kernel on TPU where its shape rules hold, jax.nn's XLA softmax(QKᵀ)V
+fusion otherwise), plus reference-compatible wrappers for the interleaved
+contrib ops.  bf16 inputs accumulate in fp32 on the MXU.
 """
 from __future__ import annotations
 
@@ -26,9 +26,9 @@ _export = make_exporter(_this)
 
 def sdpa_raw(q, k, v, m=None, scale=None, causal=False):
     """Raw-array fused attention: the Pallas flash kernel when it applies
-    (TPU, unmasked/causal, 128-aligned lengths), else jax.nn's kernel
-    path, else an explicit einsum/softmax fallback.  Shared by the
-    NDArray op below and the sequence-parallel bodies (parallel/ring.py).
+    (TPU, unmasked/causal, 128-aligned lengths), else jax.nn's
+    ``dot_product_attention``.  Shared by the NDArray op below and the
+    sequence-parallel bodies (parallel/ring.py).
 
     Layout here is (B, T, N, H); the flash kernel takes (B, N, T, H)."""
     if m is None and q.shape[1] == k.shape[1] and \
@@ -46,22 +46,8 @@ def sdpa_raw(q, k, v, m=None, scale=None, causal=False):
             return out.transpose(0, 2, 1, 3)
     if m is not None and m.dtype != jnp.bool_:
         m = m.astype(jnp.bool_)
-    try:
-        return jax.nn.dot_product_attention(
-            q, k, v, mask=m, scale=scale, is_causal=causal)
-    except Exception:
-        d = q.shape[-1]
-        s = float(scale) if scale is not None else float(1.0 / np.sqrt(d))
-        logits = jnp.einsum("btnh,bsnh->bnts", q, k,
-                            preferred_element_type=np.float32) * s
-        if causal:
-            tq, tk = logits.shape[-2:]
-            cm = jnp.tril(jnp.ones((tq, tk), bool))
-            logits = jnp.where(cm, logits, -1e30)
-        if m is not None:
-            logits = jnp.where(m, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bnts,bsnh->btnh", probs, v)
+    return jax.nn.dot_product_attention(
+        q, k, v, mask=m, scale=scale, is_causal=causal)
 
 
 def dot_product_attention(query, key, value, mask=None, scale=None,

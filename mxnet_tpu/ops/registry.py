@@ -124,16 +124,12 @@ def platform_of_raw(raw):
     """Platform of a CONCRETE jax array (None for tracers/unknown)."""
     import jax
 
-    if isinstance(raw, jax.core.Tracer):
-        return None  # keep the hot traced-dispatch path exception-free
-    try:
-        dev = raw.device  # Device for single-device arrays, else Sharding
-        plat = getattr(dev, "platform", None)
-        if plat is None:
-            plat = next(iter(dev.device_set)).platform
-        return plat
-    except Exception:
-        return None
+    if isinstance(raw, jax.core.Tracer) or not isinstance(raw, jax.Array):
+        return None  # tracers and host (numpy/scalar) operands
+    dev = raw.device  # Device for single-device arrays, else Sharding
+    if isinstance(dev, jax.Device):
+        return dev.platform
+    return next(iter(dev.device_set)).platform
 
 
 def platform_of_raws(raws):

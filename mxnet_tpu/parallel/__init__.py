@@ -426,10 +426,7 @@ def _process_psum(n):
     from jax.sharding import PartitionSpec
 
     pmesh = jax.sharding.Mesh(onp.asarray(devs), ("dp",))
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.6 jax keeps it under experimental
-        from jax.experimental.shard_map import shard_map
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(x, "dp"), mesh=pmesh,
         in_specs=PartitionSpec("dp", None),
         out_specs=PartitionSpec("dp", None)))

@@ -180,8 +180,6 @@ def _cost(jfn, abstract_params, abstract_states, in_structs, label_struct):
     lowered = jfn.lower(*args)
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns per-device list
-        ca = ca[0]
     out = {
         "flops": float(ca.get("flops", float("nan"))),
         "bytes_accessed": float(ca.get("bytes accessed",
