@@ -40,7 +40,10 @@ times) or stacked ``(K, ...)`` leaves scanned one slice per inner step.
 """
 from __future__ import annotations
 
+import time
+
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .. import autograd as ag
 from .. import optimizer as opt
@@ -50,6 +53,7 @@ from ..telemetry import costs as _costs
 from ..telemetry import memwatch as _mw
 from ..telemetry import numerics as _numerics
 from ..telemetry import retrace as _retrace
+from ..telemetry import tracing
 from ..base import MXNetError
 from ..ndarray import NDArray
 from .block import _trace_guard
@@ -143,6 +147,7 @@ class FusedTrainStep:
         # failure there — a died backend — poisons params like any
         # donated jit program would.
         self._validated_sigs = set()
+        self._dispatches = 0   # __call__ count: the lane log's ``seq``
 
         optzr = trainer._optimizer
         if type(optzr)._step is opt.Optimizer._step:
@@ -395,6 +400,17 @@ class FusedTrainStep:
         if _engine._bulk_on:
             _engine.flush("dispatch")
 
+        t0 = time.perf_counter()
+        self._dispatches += 1
+        with TraceAnnotation("mxt.train.dispatch", seq=self._dispatches,
+                             k=self.k):
+            return self._dispatch(batch, t0)
+
+    def _dispatch(self, batch, t0):
+        """The body of :meth:`__call__`, stamped for the lane log's
+        ``train.dispatch`` record: ``t0`` entry, ``t_args`` operands
+        ready (cache lookup, flatten, rates, key, first-call snapshot),
+        ``t_disp1`` jitted call returned, ``t_end`` results committed."""
         trainer = self.trainer
         optzr = trainer._optimizer
         sig, fn, mp_flags, masters, head, tail = self._prepare(batch)
@@ -406,6 +422,7 @@ class FusedTrainStep:
 
         snapshot = None if sig in self._validated_sigs else \
             self._snapshot()
+        t_args = time.perf_counter()
         telemetry.gauge("step_fusion.steps_per_execution", self.k)
         telemetry.count("step_fusion.steps", self.k)
         if _costs._enabled:
@@ -430,6 +447,7 @@ class FusedTrainStep:
                     dispatch_platform(platform_of_raws(w_raws)):
                 (new_w, new_m, new_s, new_aux, _new_t), losses, nstats = \
                     fn(*head, key, *tail)
+            t_disp1 = time.perf_counter()
 
             if _san._enabled:
                 # weights/masters/states/aux were donated at dispatch;
@@ -472,6 +490,11 @@ class FusedTrainStep:
                 losses.block_until_ready()  # mxlint: allow=T1
                 self._validated_sigs.add(sig)
                 telemetry.count("step_fusion.compile")
+            tracing.lane_record(
+                "train.dispatch", path="fused", seq=self._dispatches,
+                k=self.k, compiled=snapshot is not None, t0=t0,
+                t_args=t_args, t_disp1=t_disp1,
+                t_end=time.perf_counter())
             return NDArray(losses)
         except Exception as exc:
             if snapshot is not None:

@@ -18,6 +18,8 @@ import signal
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ..base import MXNetError
 from .parameter import Parameter, ParameterDict
 from .. import optimizer as opt
@@ -27,6 +29,7 @@ from ..telemetry import costs as _costs
 from ..telemetry import memwatch as _mw
 from ..telemetry import numerics as _numerics
 from ..telemetry import retrace as _retrace
+from ..telemetry import tracing
 
 __all__ = ["Trainer", "PREEMPTED_EXIT_CODE", "install_preemption_handler",
            "drain_requested", "drain_consensus", "request_drain",
@@ -191,6 +194,8 @@ class Trainer:
         self._kvstore = None
         self._update_on_kvstore = None
         self._fused_cache = {}  # sig -> jitted multi-tensor update
+        self._fused_compiled = False   # did the last update build one
+        self._steps = 0   # step() calls: the lane log's ``seq``
         # offload="host": optimizer state + f32 masters live in host
         # memory between steps (mxnet_tpu.memory.offload); the update
         # donates transient device copies, so the donation contract and
@@ -396,7 +401,11 @@ class Trainer:
     def step(self, batch_size, ignore_stale_grad=False):
         """Allreduce gradients and apply one optimizer update, scaling
         gradients by 1/batch_size (reference: ``Trainer.step``)."""
-        with telemetry.span("trainer.step"):
+        t0 = time.perf_counter()
+        self._steps += 1
+        seq = self._steps
+        with TraceAnnotation("mxt.trainer.step", seq=seq), \
+                telemetry.span("trainer.step"):
             # rescale is set BEFORE kvstore init: update_on_kvstore ships a
             # pickled optimizer copy to the (possibly remote) server, so it
             # must already carry the right rescale_grad at that point
@@ -406,10 +415,25 @@ class Trainer:
             if self._update_on_kvstore:
                 self._sync_kvstore_hparams()
             self._prefetch_offloaded()
-            self._allreduce_grads()
-            self._update(ignore_stale_grad)
+            t_allreduce0 = time.perf_counter()
+            with TraceAnnotation("mxt.trainer.allreduce", seq=seq):
+                self._allreduce_grads()
+            t_update0 = time.perf_counter()
+            self._fused_compiled = False
+            with TraceAnnotation("mxt.trainer.update", seq=seq):
+                self._update(ignore_stale_grad)
+            t_update1 = time.perf_counter()
             self._offload_prefetched = {}
             self._poke_data_prefetcher()
+        # the per-step path's record in the always-on lane log: the
+        # all-reduce ends where the update starts, the update's
+        # dispatch is the step's (``k`` = 1 optimizer step)
+        tracing.lane_record(
+            "train.dispatch", path="trainer.step", seq=seq, k=1,
+            compiled=self._fused_compiled, t0=t0, t_args=t_allreduce0,
+            t_allreduce0=t_allreduce0, t_allreduce1=t_update0,
+            t_update0=t_update0, t_update1=t_update1, t_disp1=t_update1,
+            t_end=time.perf_counter())
 
     def allreduce_grads(self):
         if not self._kv_initialized:
@@ -579,7 +603,7 @@ class Trainer:
                tuple(len(s) for s in states), mesh_sig,
                _numerics.signature())
         fn = self._fused_cache.get(sig)
-        compiling = fn is None
+        compiling = self._fused_compiled = fn is None
         if compiling:
             telemetry.count("trainer.fused_cache_miss")
             if _retrace._enabled:

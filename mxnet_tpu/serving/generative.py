@@ -54,6 +54,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .. import telemetry
 from ..telemetry import numerics as _numerics
@@ -205,6 +206,11 @@ class LlamaServingEngine:
         self._last = np.zeros(self.num_slots, np.int32)
         self._pos = np.zeros(self.num_slots, np.int32)
         self.steps = 0
+        #: (t_lock, t_disp0, t_disp1, t_tok) of the last step()/verify():
+        #: before dev_lock, lock held, jitted call returned and lock
+        #: released, tokens on host.  Written and read by the decode
+        #: thread alone (the lane log's ``decode.tick`` record).
+        self.tick_stamps = None
         self._signatures = set()
 
         # decode-step logit stats behind the same gate as the training
@@ -486,7 +492,11 @@ class LlamaServingEngine:
         SHARED prefix blocks already holding K/V — ``rows`` then only
         carry the novel suffix, the scatter targets the block list past
         the shared prefix, and ``t0s`` stays the FULL prompt length
-        (the decode cursor).  Shared blocks are never written."""
+        (the decode cursor).  Shared blocks are never written.
+
+        Returns ``(t_lock, t_commit1)``: ``perf_counter`` before asking
+        for the device lock and after releasing it (the lane log's
+        ``prefill.batch`` record)."""
         import jax.numpy as jnp
 
         kb = len(slots)
@@ -500,6 +510,7 @@ class LlamaServingEngine:
             tail = blocks[skip:]
             take = min(nbp, len(tail))
             flat[r * nbp: r * nbp + take] = tail[:take]
+        t_lock = time.perf_counter()
         with self.dev_lock:
             self._pool = self._scatter(self._pool, rows, self._dev(flat))
             for i, s in enumerate(slots):
@@ -511,6 +522,7 @@ class LlamaServingEngine:
                     self._tables[s] = row
                     self._last[s] = first[i]
                     self._pos[s] = t0s[i]
+        return t_lock, time.perf_counter()
 
     def gather_prefix(self, rows_idx):
         """Radix-hit prefill, phase 0: dense per-request copies of the
@@ -556,33 +568,45 @@ class LlamaServingEngine:
         wait."""
         self._note(("step",))
         lstats = None
+        t_lock = time.perf_counter()
         with self.dev_lock:
-            if self.kv_mode == "paged":
-                if self._numerics:
-                    toks, pool, lstats = self._step(
-                        self._w, self._pool, self._dev(self._tables),
-                        self._dev(self._last), self._dev(self._pos))
+            t_disp0 = time.perf_counter()
+            with TraceAnnotation("mxt.decode.dispatch",
+                                 seq=self.steps + 1,
+                                 replica=self.replica_id):
+                if self.kv_mode == "paged":
+                    if self._numerics:
+                        toks, pool, lstats = self._step(
+                            self._w, self._pool,
+                            self._dev(self._tables),
+                            self._dev(self._last), self._dev(self._pos))
+                    else:
+                        toks, pool = self._step(
+                            self._w, self._pool,
+                            self._dev(self._tables),
+                            self._dev(self._last), self._dev(self._pos))
+                    self._pool = pool
                 else:
-                    toks, pool = self._step(
-                        self._w, self._pool, self._dev(self._tables),
-                        self._dev(self._last), self._dev(self._pos))
-                self._pool = pool
-            else:
-                if self._numerics:
-                    toks, caches, lstats = self._step(
-                        self._w, self._caches, self._dev(self._last),
-                        self._dev(self._pos))
-                else:
-                    toks, caches = self._step(
-                        self._w, self._caches, self._dev(self._last),
-                        self._dev(self._pos))
-                self._caches = caches
+                    if self._numerics:
+                        toks, caches, lstats = self._step(
+                            self._w, self._caches, self._dev(self._last),
+                            self._dev(self._pos))
+                    else:
+                        toks, caches = self._step(
+                            self._w, self._caches, self._dev(self._last),
+                            self._dev(self._pos))
+                    self._caches = caches
             self.steps += 1
+            seq = self.steps
+        t_disp1 = time.perf_counter()
         if lstats is not None:
             # queue the decode-step logit stats (device scalars) for the
             # stride harvest, outside the device lock
             _numerics.record_compiled(("serving.logits",), (lstats,))
-        out = _materialize([toks])[0]
+        with TraceAnnotation("mxt.decode.fetch", seq=seq,
+                             replica=self.replica_id):
+            out = _materialize([toks])[0]
+        self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
         with self.dev_lock:
             for s in active:
                 self._last[s] = out[s]
@@ -607,23 +631,34 @@ class LlamaServingEngine:
             raise MXNetError("verify() requires kv_mode='paged'")
         self._note(("verify",))
         lstats = None
+        t_lock = time.perf_counter()
         with self.dev_lock:
-            toks_mat = np.concatenate(
-                [self._last[:, None], np.asarray(drafts, np.int32)],
-                axis=1)
-            if self._numerics:
-                out, pool, lstats = self._verify(
-                    self._w, self._pool, self._dev(self._tables),
-                    self._dev(toks_mat), self._dev(self._pos))
-            else:
-                out, pool = self._verify(
-                    self._w, self._pool, self._dev(self._tables),
-                    self._dev(toks_mat), self._dev(self._pos))
-            self._pool = pool
+            t_disp0 = time.perf_counter()
+            with TraceAnnotation("mxt.decode.dispatch",
+                                 seq=self.steps + 1,
+                                 replica=self.replica_id):
+                toks_mat = np.concatenate(
+                    [self._last[:, None], np.asarray(drafts, np.int32)],
+                    axis=1)
+                if self._numerics:
+                    out, pool, lstats = self._verify(
+                        self._w, self._pool, self._dev(self._tables),
+                        self._dev(toks_mat), self._dev(self._pos))
+                else:
+                    out, pool = self._verify(
+                        self._w, self._pool, self._dev(self._tables),
+                        self._dev(toks_mat), self._dev(self._pos))
+                self._pool = pool
             self.steps += 1
+            seq = self.steps
+        t_disp1 = time.perf_counter()
         if lstats is not None:
             _numerics.record_compiled(("serving.logits",), (lstats,))
-        return _materialize([out])[0]
+        with TraceAnnotation("mxt.decode.fetch", seq=seq,
+                             replica=self.replica_id):
+            out = _materialize([out])[0]
+        self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
+        return out
 
     def last_tokens(self):
         """Snapshot of the per-slot last-committed-token mirror."""

@@ -53,6 +53,7 @@ import time
 from collections import deque
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .. import sanitizer as _san
 from .. import telemetry
@@ -104,6 +105,10 @@ class PrefillLane:
         self._drain = True
         self._thread = None
         self.error = None
+        self._gate = None   # why the last _admit_batch ran nothing
+        # busy / gated / idle seconds and the batch count (the ``seq``
+        # of ``prefill.batch`` records), always on (tracing.lane_state)
+        self.clock = tracing.LaneClock(replica.index)
 
     def start(self):
         if self._thread is None:
@@ -148,14 +153,18 @@ class PrefillLane:
                 if not self._drain or not len(q):
                     break
             if not self._admit_batch() and not self._stop.is_set():
-                if len(q):
+                if self._gate is not None:
                     # queue non-empty but gated on capacity: wait for an
                     # eviction to free slots/blocks (wait_for_item would
                     # return immediately and busy-spin against decode)
+                    self.clock.enter("gated", time.perf_counter(),
+                                     self._gate)
                     self.r.capacity_evt.wait(self.poll_s)
                     self.r.capacity_evt.clear()
                 else:
+                    self.clock.enter("idle", time.perf_counter())
                     q.wait_for_item(self.poll_s)
+        self.clock.enter("idle", time.perf_counter())
 
     def _bucket(self, req):
         """Prompt-length bucket — of the NOVEL SUFFIX when the radix
@@ -170,11 +179,17 @@ class PrefillLane:
 
     def _admit_batch(self):
         """One prefill batch: gate → admit → forward (unlocked) →
-        commit (locked) → handoff.  Returns True if anything ran."""
+        commit (locked) → handoff.  Returns True if anything ran;
+        otherwise ``self._gate`` says what the FIFO head waits for
+        (``"slot"``, ``"block"``, ``"tokens"``; None: nothing queued)."""
         r = self.r
         mgr = r.mgr
+        self._gate = None
+        if not len(r.queue):
+            return False
         free_slots = mgr.free_slots()
-        if not free_slots or not len(r.queue):
+        if not free_slots:
+            self._gate = "slot"
             return False
         free_blocks = mgr.allocator.free_blocks
         budget = {"n": 0, "blocks": 0, "tokens": 0}
@@ -188,13 +203,17 @@ class PrefillLane:
             if r.radix is not None:
                 need -= r.radix.match_len(req.prompt_ids) \
                     // mgr.block_size
+            # a refusal of the FIFO head (nothing taken) gates the lane
             if budget["n"] >= free_slots:
+                self._gate = "slot"
                 return False
             if budget["blocks"] + need > free_blocks:
+                self._gate = "block"
                 return False
             if budget["tokens"] and (budget["tokens"]
                                      + len(req.prompt_ids)
                                      > r.max_prefill_tokens):
+                self._gate = "tokens"
                 return False
             budget["n"] += 1
             budget["blocks"] += need
@@ -205,7 +224,41 @@ class PrefillLane:
             self._bucket, min(free_slots, r.policy.max_batch), accept)
         if not group:
             return False
+        self._gate = None   # a refusal after the head only ends the batch
+        with TraceAnnotation("mxt.prefill.batch",
+                             seq=self.clock.batches + 1, replica=r.index):
+            self._prefill_group(group)
+        return True
+
+    def _forward(self, group, prompts, matched, skip, block_lists,
+                 t0s_suf, s0s, kb):
+        """Dispatch the prompt forward (unlocked); returns the device
+        first-token vector and the raw K/V rows for the commit."""
+        r = self.r
+        eng = r.engine
+        if r.radix is None or not any(matched):
+            return eng.prefill_rows(prompts, t0s_suf)
+        # radix-hit path: dense prefix copies (locked gather) feed the
+        # suffix-only forward (unlocked); the commit scatters ONLY the
+        # suffix rows into the request's private blocks past the shared
+        # prefix
+        pre_lb = r.policy.length_bucket(max(matched))
+        nbp_pre = -(-pre_lb // r.mgr.block_size)
+        rows_idx = np.full((kb, nbp_pre), eng.num_blocks, np.int32)
+        for i in range(len(group)):
+            rows_idx[i, :skip[i]] = block_lists[i][:skip[i]]
+        pre_kv = eng.gather_prefix(rows_idx)
+        return eng.prefill_suffix(pre_kv, prompts, t0s_suf, s0s)
+
+    def _prefill_group(self, group):
+        """The admitted ``group`` through forward, commit and handoff,
+        stamped once at each boundary for the lane log, the capacity
+        duty cycle and the requests' span trees alike."""
+        r = self.r
+        mgr = r.mgr
         t_start = time.perf_counter()
+        self.clock.enter("busy", t_start)
+        seq = self.clock.batches
         lb = self._bucket(group[0])
         kb = r.policy.batch_bucket(len(group))
         eng = r.engine
@@ -265,26 +318,21 @@ class PrefillLane:
             with telemetry.span("serving.prefill",
                                 {"lane": "prefill", "replica": r.index,
                                  "batch": kb, "length": lb}):
-                if rx is not None and any(matched):
-                    # radix-hit path: dense prefix copies (locked
-                    # gather) feed the suffix-only forward (unlocked);
-                    # the commit scatters ONLY the suffix rows into the
-                    # request's private blocks past the shared prefix
-                    pre_lb = r.policy.length_bucket(max(matched))
-                    nbp_pre = -(-pre_lb // mgr.block_size)
-                    rows_idx = np.full((kb, nbp_pre), eng.num_blocks,
-                                       np.int32)
-                    for i in range(len(group)):
-                        rows_idx[i, :skip[i]] = \
-                            block_lists[i][:skip[i]]
-                    pre_kv = eng.gather_prefix(rows_idx)
-                    toks, rows = eng.prefill_suffix(pre_kv, prompts,
-                                                    t0s_suf, s0s)
-                else:
-                    toks, rows = eng.prefill_rows(prompts, t0s_suf)
-                first = _lane_materialize([toks])[0]
-                eng.commit_rows(rows, slots, block_lists, t0s, first,
-                                skip_blocks=skip)
+                with TraceAnnotation("mxt.prefill.dispatch", seq=seq,
+                                     replica=r.index):
+                    toks, rows = self._forward(
+                        group, prompts, matched, skip, block_lists,
+                        t0s_suf, s0s, kb)
+                t_disp1 = time.perf_counter()
+                with TraceAnnotation("mxt.prefill.fetch", seq=seq,
+                                     replica=r.index):
+                    first = _lane_materialize([toks])[0]
+                t_ready = time.perf_counter()
+                with TraceAnnotation("mxt.prefill.commit", seq=seq,
+                                     replica=r.index):
+                    t_lock, t_commit1 = eng.commit_rows(
+                        rows, slots, block_lists, t0s, first,
+                        skip_blocks=skip)
             if rx is not None:
                 # register the full prompt blocks (device-ordered after
                 # the commit scatter) so later requests share them
@@ -305,6 +353,7 @@ class PrefillLane:
                         r.draft.set_mirror(s, int(first[i]),
                                            int(t0s[i]))
         except Exception as exc:
+            self.clock.enter("idle", time.perf_counter())
             for req in group:
                 if req.slot is not None and req.slot in mgr._active:
                     mgr.evict(req.slot)
@@ -317,12 +366,20 @@ class PrefillLane:
                              context={"replica": r.index,
                                       "lane": "prefill",
                                       "error": repr(exc)})
-            return True
+            return
         t_first = time.perf_counter()
-        # retroactive prefill duty-cycle interval from the stamps the
-        # lane already took (same contract as the trace spans below)
-        capacity.lane_busy(r.index, "prefill", t_start, t_first)
+        self.clock.enter("idle", t_first)
         mates = [req.id for req in group]
+        # one stamp set for every consumer: the lane log, the capacity
+        # duty cycle and (below) the request's span tree
+        tracing.lane_record(
+            "prefill.batch", replica=r.index, seq=seq,
+            request_ids=tuple(mates),
+            n_tokens=int(t0s_suf[:len(group)].sum()), bucket=(kb, lb),
+            radix_hit_tokens=int(sum(matched)), t_start=t_start,
+            t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
+            t_commit1=t_commit1, t_first=t_first)
+        capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
             req.t_first = t_first
             if rx is not None and matched[i] and t0s_suf[i] > 0:
@@ -353,7 +410,6 @@ class PrefillLane:
                 r.decode.hand_off(_Handoff(req, req.slot,
                                            int(first[i])))
         telemetry.count("serving.admitted", len(group))
-        return True
 
 
 class DecodeLane:
@@ -372,6 +428,10 @@ class DecodeLane:
         self._stop = threading.Event()
         self._thread = None
         self.error = None
+        # the turn's first stamp and adoptions, set by _adopt for the
+        # tick's lane-log record
+        self._t_loop = None
+        self._n_adopted = 0
 
     def hand_off(self, h):
         with self._hand_lock:
@@ -436,15 +496,17 @@ class DecodeLane:
 
     def _run(self):
         spec = self.r.spec_k > 0 and self.r.draft is not None
+        tick = self._tick_spec if spec else self._tick
         while True:
-            self._adopt()
-            with self._hand_lock:
-                busy = bool(self._seqs)
-            if busy:
-                self._tick_spec() if spec else self._tick()
+            if self.pending():
+                # one turn: adopt, then advance every slot one tick
+                with TraceAnnotation("mxt.decode.tick",
+                                     seq=self.r.engine.steps + 1,
+                                     replica=self.r.index):
+                    self._adopt()
+                    tick()
             elif self._stop.is_set():
-                if not self.pending():
-                    break
+                break
             else:
                 self._wake.wait(self.poll_s)
                 self._wake.clear()
@@ -454,12 +516,16 @@ class DecodeLane:
         KV rows are already in the request's blocks (the prefill lane
         committed them before handing off), so adoption is pure
         bookkeeping — decode only ever advances slots it has adopted,
-        never a slot whose commit is still in flight."""
+        never a slot whose commit is still in flight.  Stamps the top
+        of the lane's turn (``t_loop``) for the tick's record."""
+        self._t_loop = time.perf_counter()
+        self._n_adopted = 0
         while True:
             with self._hand_lock:
                 if not self._handoffs:
                     return
                 h = self._handoffs.popleft()
+            self._n_adopted += 1
             h.req.t_handoff = time.perf_counter()
             hand_ms = (h.req.t_handoff - h.req.t_first) * 1e3
             telemetry.hist("serving.handoff_ms", hand_ms)
@@ -476,7 +542,7 @@ class DecodeLane:
         r = self.r
         with self._hand_lock:
             active = sorted(self._seqs)
-        t0 = time.perf_counter()
+            ids = tuple(self._seqs[s][0].id for s in active)
         try:
             toks = r.engine.step(active)
         except Exception as exc:
@@ -493,12 +559,13 @@ class DecodeLane:
                                       "lane": "decode",
                                       "error": repr(exc)})
             return
-        t1 = time.perf_counter()
+        stamps = self._engine_stamps()
+        _t_lock, t_disp0, _t_disp1, t_tok = stamps
         r.batches += 1
         telemetry.hist("serving.batch_size", len(active))
         telemetry.gauge("serving.kv_blocks_in_use",
                         r.mgr.allocator.blocks_in_use)
-        # retroactive capacity accounting from the stamps above: the
+        # retroactive capacity accounting from the engine's stamps: the
         # busy interval, batch occupancy, and pool pressure per tick.
         # Gated on is_enabled() so the argument expressions impose no
         # attribute contract (or cost) on duck-typed engines/managers
@@ -506,26 +573,53 @@ class DecodeLane:
         if capacity.is_enabled():
             capacity.note_tick(r.index, len(active),
                                getattr(r.engine, "num_slots", len(active)),
-                               t0, t1)
+                               t_disp0, t_tok)
             capacity.note_kv(r.index, r.mgr.allocator.free_blocks,
                              r.mgr.num_blocks)
         step_idx = r.engine.steps
-        for slot in active:
-            r.mgr.advance(slot)   # the step wrote K/V at slot's pos
-            with self._hand_lock:
-                req, tokens = self._seqs[slot]
-            tokens.append(int(toks[slot]))
-            if req.trace is not None:
-                # one span per traced slot per tick: the per-request
-                # decode slice (cost: one dict append — the tracing
-                # A/B lane in benchmark/serving_latency.py bounds it)
-                req.trace.add("decode.step", t0, t1, step=step_idx,
-                              batch=len(active), replica=r.index,
-                              slot=slot)
-            if r.mgr.consume(slot):
+        n_finished = 0
+        with TraceAnnotation("mxt.decode.book", seq=step_idx,
+                             replica=r.index):
+            for slot in active:
+                r.mgr.advance(slot)   # the step wrote K/V at slot's pos
                 with self._hand_lock:
-                    del self._seqs[slot]
-                r.finish(req, tokens)
+                    req, tokens = self._seqs[slot]
+                tokens.append(int(toks[slot]))
+                if req.first_tick is None:
+                    req.first_tick = step_idx
+                if req.trace is not None:
+                    # one span per traced slot per tick: the per-request
+                    # decode slice (cost: one dict append — the tracing
+                    # A/B lane in benchmark/serving_latency.py bounds it)
+                    req.trace.add("decode.step", t_disp0, t_tok,
+                                  step=step_idx, batch=len(active),
+                                  replica=r.index, slot=slot)
+                if r.mgr.consume(slot):
+                    with self._hand_lock:
+                        del self._seqs[slot]
+                    r.finish(req, tokens)
+                    n_finished += 1
+        self._record_tick(step_idx, ids, n_finished, stamps)
+
+    def _record_tick(self, seq, ids, n_finished, stamps, **extra):
+        """The turn's ``decode.tick`` record, its bookkeeping done."""
+        t_lock, t_disp0, t_disp1, t_tok = stamps
+        tracing.lane_record(
+            "decode.tick", replica=self.r.index, seq=seq,
+            n_active=len(ids), n_adopted=self._n_adopted,
+            n_finished=n_finished, request_ids=ids, t_loop=self._t_loop,
+            t_lock=t_lock, t_disp0=t_disp0, t_disp1=t_disp1, t_tok=t_tok,
+            t_book=time.perf_counter(), **extra)
+
+    def _engine_stamps(self):
+        """``(t_lock, t_disp0, t_disp1, t_tok)`` of the engine call
+        that just returned (``LlamaServingEngine.tick_stamps``); an
+        engine that keeps none gets the turn's own edges."""
+        stamps = getattr(self.r.engine, "tick_stamps", None)
+        if stamps is None:
+            now = time.perf_counter()
+            stamps = (self._t_loop, self._t_loop, now, now)
+        return stamps
 
     def _tick_spec(self):
         """Speculative tick: k sequential DRAFT steps propose a window,
@@ -545,6 +639,7 @@ class DecodeLane:
         k = r.spec_k
         with self._hand_lock:
             active = sorted(self._seqs)
+            ids = tuple(self._seqs[s][0].id for s in active)
         t0 = time.perf_counter()
         proposals = np.zeros((r.engine.num_slots, k), np.int32)
         try:
@@ -552,7 +647,6 @@ class DecodeLane:
                 # draft mirrors auto-advance, so step j+1 is
                 # conditioned on the draft's own proposal j
                 proposals[:, j] = r.draft.step(active)
-            t_draft = time.perf_counter()
             pos0 = r.engine.positions()
             out = r.engine.verify(proposals)
         except Exception as exc:
@@ -570,7 +664,9 @@ class DecodeLane:
                                       "lane": "decode",
                                       "error": repr(exc)})
             return
-        t1 = time.perf_counter()
+        # the verify's stamps; the k draft steps lie in [t0, t_lock]
+        stamps = self._engine_stamps()
+        t_lock, t_tok = stamps[0], stamps[3]
         r.batches += 1
         telemetry.hist("serving.batch_size", len(active))
         telemetry.gauge("serving.kv_blocks_in_use",
@@ -578,10 +674,12 @@ class DecodeLane:
         if capacity.is_enabled():
             capacity.note_tick(r.index, len(active),
                                getattr(r.engine, "num_slots", len(active)),
-                               t0, t1)
+                               t0, t_tok)
             capacity.note_kv(r.index, r.mgr.allocator.free_blocks,
                              r.mgr.num_blocks)
         accepted_this_tick = 0
+        accepted = {}       # request id -> tokens this tick committed
+        n_finished = 0
         step_idx = r.engine.steps
         for slot in active:
             d, g = proposals[slot], out[slot]
@@ -605,6 +703,9 @@ class DecodeLane:
             with self._hand_lock:
                 req, tokens = self._seqs[slot]
             tokens.extend(int(t) for t in g[:acc])
+            accepted[req.id] = acc
+            if req.first_tick is None:
+                req.first_tick = step_idx
             got = min(m, acc)
             req.draft_tokens += k
             req.accepted_tokens += got
@@ -613,9 +714,9 @@ class DecodeLane:
             accepted_this_tick += got
             telemetry.count("serving.accepted_tokens", got)
             if req.trace is not None:
-                req.trace.add("draft", t0, t_draft, step=step_idx,
+                req.trace.add("draft", t0, t_lock, step=step_idx,
                               k=k, replica=r.index, slot=slot)
-                req.trace.add("verify", t_draft, t1, step=step_idx,
+                req.trace.add("verify", t_lock, t_tok, step=step_idx,
                               accepted=acc, replica=r.index, slot=slot)
             done = False
             for _ in range(acc):
@@ -625,6 +726,9 @@ class DecodeLane:
                 with self._hand_lock:
                     del self._seqs[slot]
                 r.finish(req, tokens)
+                n_finished += 1
+        self._record_tick(step_idx, ids, n_finished, stamps,
+                          accepted=accepted)
         telemetry.count("serving.draft_tokens", k * len(active))
         capacity.note_spec(r.index, k * len(active), accepted_this_tick)
         if r.draft_tokens:

@@ -59,7 +59,8 @@ class Request:
     __slots__ = ("id", "inputs", "length", "prompt_ids", "max_new_tokens",
                  "future", "t_submit", "t_start", "t_first", "t_done",
                  "batch_size", "bucket", "slot", "joined_step",
-                 "done_step", "replica", "t_handoff", "kv_blocks",
+                 "first_tick", "done_step", "replica", "t_handoff",
+                 "kv_blocks",
                  "trace", "tenant", "draft_tokens", "accepted_tokens",
                  "prefix_hit_tokens", "prefill_saved_ms")
 
@@ -79,6 +80,11 @@ class Request:
         self.bucket = None
         self.slot = None
         self.joined_step = None
+        # decode ticks (``engine.steps`` values, the ``seq`` of the lane
+        # log's ``decode.tick`` records) that advanced the request: its
+        # token i >= 1 reached the host at ``t_tok`` of tick
+        # ``first_tick + i - 1`` — per-token stamps at no cost per token
+        self.first_tick = None
         self.done_step = None
         # disaggregated-lane fields (paged path; see docs/observability.md)
         self.replica = None     # which dp replica served the request
@@ -138,6 +144,7 @@ class Request:
         if self.slot is not None:
             rec["slot"] = self.slot
             rec["joined_step"] = self.joined_step
+            rec["first_tick"] = self.first_tick
             rec["done_step"] = self.done_step
         if self.replica is not None:
             rec["replica"] = self.replica

@@ -189,6 +189,9 @@ class _ServerBase:
                 "queue_capacity": self.queue.capacity,
                 "rejected": self.queue.rejected})
             raise
+        # the stamps (t_start, t_first, first_tick, ...) stay readable
+        # from the handle the caller holds
+        req.future.request = req
         return req.future
 
 
@@ -422,7 +425,8 @@ class GenerativeServer(_ServerBase):
     def submit(self, prompt_ids, max_new_tokens=None, tenant=None):
         """Async: 1-D prompt token ids → Future resolving to the full
         sequence (prompt + generated), greedy decode.  ``tenant`` keys
-        the request's SLO targets (config.slo)."""
+        the request's SLO targets (config.slo).  The future carries its
+        :class:`~.protocol.Request` as ``.request`` (id and stamps)."""
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         n = int(max_new_tokens or self.config.max_new_tokens)
         if n < 1:
@@ -655,6 +659,8 @@ class GenerativeServer(_ServerBase):
                         sum(r.mgr.allocator.blocks_in_use for r in reps))
         if capacity.is_enabled():
             out["capacity"] = [capacity.snapshot(r.index) for r in reps]
+        # always on: where each prefill lane's wall time went
+        out["lanes"] = [r.prefill.clock.snapshot() for r in reps]
         if self.slo is not None:
             out["slo"] = self.slo.snapshot()
         return out

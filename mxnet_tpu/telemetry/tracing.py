@@ -27,14 +27,25 @@ nothing else.  Completed traces go three places:
 
 * a ``{"record": "trace", ...}`` JSONL record via ``telemetry.emit``
   (so ``tools/trace_report.py`` can rebuild the tree from the stream);
-* the profiler's chrome-trace buffer via ``record_span_event`` when
-  profiling — request spans and per-op dispatch events land on ONE
-  Perfetto timeline;
+* the chrome-trace buffer of ``mxnet_tpu.profiler`` via
+  ``record_span_event`` when that profiler runs — request spans beside
+  its per-op dispatch events, on the host's ``perf_counter`` clock (NOT
+  the device trace: what shares a file with the device planes is the
+  ``mxt.*`` spans of the lane log below);
 * the **flight recorder**: a bounded ring of recent completed traces,
   dumped to JSON by :func:`incident` on overload rejection, replica
   exception, or OOM (memwatch embeds :func:`recent` into its
   post-mortem), so a tail-latency incident is explainable after the
   fact.
+
+The **lane log** (further down) is the always-on half: one bounded
+ring of coarse records — a decode tick, a prefill batch, a stretch the
+prefill lane spent gated, a train dispatch — appended by the thread that
+did the work, from ``perf_counter`` stamps taken once at each boundary.
+It needs no ``enable()``; the per-request span trees above stay opt-in.
+The same boundaries open ``jax.profiler.TraceAnnotation`` spans named
+``mxt.*`` (one atomic load while no profile runs), which land on
+``/host:CPU`` of the xplane beside the device planes.
 
 Cost contract (same as the rest of telemetry): disabled →
 ``start_trace`` is one module-boolean check returning None, and every
@@ -55,7 +66,8 @@ from collections import deque
 
 __all__ = ["enable", "disable", "is_enabled", "start_trace", "finish",
            "recent", "clear", "dump", "incident", "Trace",
-           "RECORDER_CAPACITY"]
+           "RECORDER_CAPACITY", "lane_record", "lane_log", "lane_state",
+           "LaneClock", "LANE_LOG_CAPACITY", "LANE_TAIL"]
 
 # -- state -------------------------------------------------------------------
 
@@ -241,9 +253,10 @@ def clear():
 
 
 def dump(path=None, reason="", context=None):
-    """Write the flight record — reason, context, and every ring trace
-    — to ``path`` (default ``MXNET_TRACE_DUMP`` or
-    ``flight_record_<pid>.json`` in the cwd).  Returns the path."""
+    """Write the flight record — reason, context, every ring trace and
+    the last :data:`LANE_TAIL` lane records — to ``path`` (default
+    ``MXNET_TRACE_DUMP`` or ``flight_record_<pid>.json`` in the cwd).
+    Returns the path."""
     if path is None:
         path = os.environ.get("MXNET_TRACE_DUMP") \
             or f"flight_record_{os.getpid()}.json"
@@ -253,6 +266,10 @@ def dump(path=None, reason="", context=None):
         "wall_time": time.time(),
         "context": context or {},
         "traces": recent(),
+        # which phase of which tick stood still: perf_counter stamps,
+        # comparable with ``now`` below and with each other
+        "now": time.perf_counter(),
+        "lanes": lane_log()[-LANE_TAIL:],
     }
     with open(path, "w", encoding="utf-8") as f:
         json.dump(report, f, indent=2, default=str)
@@ -279,6 +296,112 @@ def incident(reason, context=None, path=None):
         return dump(path=path, reason=reason, context=context)
     except Exception:
         return None  # reporting never masks the original failure
+
+
+# -- lane log ----------------------------------------------------------------
+
+#: records the ring keeps (a 100 ms decode tick fills it in 27 minutes)
+LANE_LOG_CAPACITY = 16384
+#: lane records a flight-record dump carries
+LANE_TAIL = 256
+
+_lane_log = deque(maxlen=LANE_LOG_CAPACITY)
+_lane_clocks = {}   # replica -> the prefill lane's LaneClock
+#: first and last stamp of each record kind: what ``lane_log`` filters on
+_LANE_SPAN = {"decode.tick": ("t_loop", "t_book"),
+              "prefill.batch": ("t_start", "t_first"),
+              "prefill.gated": ("t0", "t1"),
+              "train.dispatch": ("t0", "t_end")}
+
+
+def lane_record(kind, **fields):
+    """Append one record to the lane log.  Always on: a dict and a
+    deque append (atomic under CPython, so the writers share no lock).
+    Every stamp is a ``time.perf_counter()`` the caller took where the
+    work happened; the schema of each ``kind`` is in
+    docs/observability.md."""
+    fields["kind"] = kind
+    _lane_log.append(fields)
+
+
+def lane_log(kind=None, since=None, until=None):
+    """A copy of the lane log, oldest first.  ``kind`` keeps one record
+    kind; ``since`` / ``until`` (``perf_counter`` seconds) keep the
+    records that overlap ``[since, until)``: last stamp at or after
+    ``since``, first stamp before ``until``."""
+    while True:
+        try:
+            records = list(_lane_log)
+            break
+        except RuntimeError:   # a lane appended while we copied
+            continue
+    out = []
+    for rec in records:
+        if kind is not None and rec["kind"] != kind:
+            continue
+        first, last = _LANE_SPAN[rec["kind"]]
+        if since is not None and rec[last] < since:
+            continue
+        if until is not None and rec[first] >= until:
+            continue
+        out.append(rec)
+    return out
+
+
+class LaneClock:
+    """Where one prefill lane's wall time went since it was built:
+    seconds ``busy`` (a batch in hand), ``gated`` (work queued but no
+    slot, block or token budget for it) and ``idle`` (nothing queued).
+    The lane thread calls :meth:`enter` at each change with a stamp it
+    already holds, so the three always sum to the time since
+    ``t_origin``.  Each stretch of ``gated`` is also written to the lane
+    log when it ends, for readers that clip to a window."""
+
+    MODES = ("busy", "gated", "idle")
+
+    def __init__(self, replica):
+        self.replica = replica
+        self.t_origin = self._mark = time.perf_counter()
+        self._mode = "idle"
+        self._why = None
+        self.seconds = dict.fromkeys(self.MODES, 0.0)
+        self.gates = {}      # reason -> stretches of gated begun for it
+        self.batches = 0
+        _lane_clocks[replica] = self
+
+    def enter(self, mode, t, why=None):
+        """The lane is in ``mode`` from ``t`` on (``why``: what gates it)."""
+        prev = self._mode
+        self.seconds[prev] += t - self._mark
+        self._mark = t
+        if mode == prev:
+            return
+        if prev == "gated":
+            lane_record("prefill.gated", replica=self.replica,
+                        t0=self._gate0, t1=t, reason=self._why)
+        elif mode == "gated":
+            self._gate0, self._why = t, why
+            self.gates[why] = self.gates.get(why, 0) + 1
+        if mode == "busy":
+            self.batches += 1
+        self._mode = mode
+
+    def snapshot(self):
+        now = time.perf_counter()
+        out = {f"{m}_s": s for m, s in self.seconds.items()}
+        out[f"{self._mode}_s"] += now - self._mark   # the open stretch
+        out.update(replica=self.replica, mode=self._mode,
+                   wall_s=now - self.t_origin, gates=dict(self.gates),
+                   batches=self.batches)
+        return out
+
+
+def lane_state(replica=0):
+    """``busy_s`` / ``gated_s`` / ``idle_s`` of replica ``replica``'s
+    prefill lane since its server was built, the count of gated
+    stretches per reason and of batches; None before any server."""
+    clock = _lane_clocks.get(replica)
+    return None if clock is None else clock.snapshot()
 
 
 if os.environ.get("MXNET_TRACING", "0") == "1":

@@ -322,11 +322,17 @@ def test_dp2_burst_saturation_precedes_queue_wait_breach(
         with srv:
             url = srv.metrics_url
             # warm trickle: enough completions per replica to trust mu,
-            # spaced so the duty cycle stays well under the threshold
+            # spaced so the duty cycle stays well under the threshold.
+            # The gap follows what the request took: a fixed 10 ms held
+            # the duty cycle down only while a request cost a few ms,
+            # and on a machine that six test workers share it costs
+            # tens, rho passes 0.85 here and the watch latches early
             for _ in range(14):
+                t_gen = time.perf_counter()
                 srv.generate(rs.randint(1, 250, size=6),
                              max_new_tokens=3)
-                time.sleep(0.01)
+                took = time.perf_counter() - t_gen
+                time.sleep(min(1.0, max(0.01, 4 * took)))
             assert capacity.saturated() is False
             # burst: far more than 2 replicas x 2 slots can drain
             futs = [srv.submit(rs.randint(1, 250, size=6),

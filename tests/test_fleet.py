@@ -293,7 +293,10 @@ def test_watchdog_halt_raises_at_step_boundary_and_dumps(tmp_path,
 
 def test_ring_bounded_and_dump_roundtrip(tmp_path):
     telemetry.enable()
-    fleet.enable(stride=10_000, ring=8)
+    # the watchdog never arms: an empty step lasts microseconds, so on a
+    # busy machine one of the 12 armed ones runs past twice the median
+    # and its ``step_regression`` anomaly record joins the ring
+    fleet.enable(stride=10_000, ring=8, min_history=1_000)
     for _ in range(20):
         telemetry.step_begin()
         telemetry.step_end(examples=4)

@@ -137,6 +137,7 @@ class _StubReq:
         self.id = rid
         self.t_first = 0.0
         self.t_handoff = None
+        self.first_tick = None
         self.trace = None
         self.max_new_tokens = 3
 

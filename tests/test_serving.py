@@ -64,10 +64,23 @@ def test_pad_batch_shapes_and_errors():
 
 # --- a shape-polymorphic position-wise model for bit-identity tests ----------
 
+def _eighths(rs, *shape):
+    """Seeded float32 values on a grid of 1/8: every product and every
+    six-term sum of the model below is exact in float32, so its result
+    does not depend on the order or the fusing (FMA or not) that the
+    gemm kernel picked for a batch shape — which differs between CPUs,
+    and made the bit-identity tests pass on one machine and fail on the
+    next.  What they hold still fails loudly: a row demuxed from the
+    wrong slot or padding that leaks into a real row is off by far more
+    than a rounding."""
+    return (np.round(rs.randn(*shape) * 8) / 8).astype(np.float32)
+
+
 def _positionwise_predictor(tmp_path, in_dim=6, hidden=5):
     """nnvm FullyConnected(flatten=False) chain: every (batch, length)
     row is an independent gemm row, so padded forwards are bit-identical
-    to unpadded ones on the real rows."""
+    to unpadded ones on the real rows (weights and inputs from
+    :func:`_eighths`, so that rounding cannot differ either)."""
     import mxnet_tpu.symbol as sym
 
     data = sym.Variable("data")
@@ -77,8 +90,8 @@ def _positionwise_predictor(tmp_path, in_dim=6, hidden=5):
                              name="fc")
     out = sym.Activation(out, act_type="relu")
     rs = np.random.RandomState(7)
-    wv = rs.randn(hidden, in_dim).astype(np.float32)
-    bv = rs.randn(hidden).astype(np.float32)
+    wv = _eighths(rs, hidden, in_dim)
+    bv = _eighths(rs, hidden)
     prefix = str(tmp_path / "posw")
     out.save(f"{prefix}-symbol.json")
     serialization.save_ndarrays(f"{prefix}-0000.params", {
@@ -93,7 +106,7 @@ def test_padding_bit_identity_vs_unpadded_oracle(tmp_path):
     BIT-identical to each request's own unbatched forward."""
     pred, _ = _positionwise_predictor(tmp_path)
     rs = np.random.RandomState(3)
-    exs = [rs.randn(l, 6).astype(np.float32) for l in (3, 7, 5)]
+    exs = [_eighths(rs, l, 6) for l in (3, 7, 5)]
     batch = pad_batch(exs, 4, 8)
     padded = pred.predict(batch).asnumpy()
     for i, x in enumerate(exs):
@@ -213,7 +226,7 @@ def test_multi_client_continuous_batching_end_to_end(tmp_path):
     srv = serving.InferenceServer(pred, cfg)
     rs = np.random.RandomState(11)
     lengths = [3, 5, 9, 7, 12, 4, 8, 15, 2, 6, 11, 16]
-    inputs = [rs.randn(l, 6).astype(np.float32) for l in lengths]
+    inputs = [_eighths(rs, l, 6) for l in lengths]
     results = [None] * len(inputs)
 
     def client(i):

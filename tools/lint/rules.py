@@ -146,6 +146,12 @@ RECORDING_HEADS = {"telemetry", "profiler", "prof",
                    # renderer reads telemetry snapshots — host-side by
                    # contract, never a device sync
                    "tracing", "_tracing", "metrics",
+                   # lane log (telemetry.tracing.lane_record, a dict and
+                   # a deque append) and the ``mxt.*`` profiler spans at
+                   # the same boundaries: ``jax.profiler.TraceAnnotation``
+                   # is a TraceMe — one atomic load while no profile
+                   # runs, a host-side event record while one does
+                   "TraceAnnotation",
                    # r13 fleet observability (telemetry.fleet, aliased
                    # _fleet_mod in telemetry/__init__; promtext is the
                    # shared scrape renderer): ring appends, watchdog
