@@ -732,7 +732,8 @@ class LlamaDecoder:
             for k, v in rows]
         return caches, logits
 
-    def _step_blocks_impl(self, w, pools, tables, ids_t, pos):
+    def _step_blocks_impl(self, w, pools, tables, ids_t, pos,
+                          paged_kernel=False):
         """Per-slot decode step against a PAGED KV pool: same vector-
         position continuous-batching contract as
         :meth:`_step_slots_impl`, but K/V storage is block-granular.
@@ -747,8 +748,17 @@ class LlamaDecoder:
         positions the causal mask (``t <= pos``) never exposes.  MB is
         static, so the compute cost matches the slot-ledger step while
         HBM capacity is the POOL size — bounded by tokens in flight,
-        not max_len × slots."""
+        not max_len × slots.
+
+        ``paged_kernel`` (static; the engine decides it from
+        ``ops.paged_attention.applicable``) replaces gather + ``_attend``
+        by the Pallas kernel that reads the pool in place through
+        ``tables``, bounded by each slot's ``pos + 1``: no view, no GQA
+        repeat.  A vacant slot's context is then zeros instead of
+        attention over clamped garbage; neither is ever read."""
         import jax.numpy as jnp
+
+        from ..ops.paged_attention import paged_decode_attention
 
         cfg = self.cfg
         hd = cfg.head_dim
@@ -763,8 +773,12 @@ class LlamaDecoder:
         mask = (jnp.arange(t)[None, :]
                 <= pos[:, None])[:, None, None, :]  # (S,1,1,T)
         blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
-                                  axis=1)[:, 0]     # (S,) physical block
-        off = pos % bs
+                                  axis=1)           # (S,1) physical block
+        off = (pos % bs)[:, None]
+        # the new row is written head by head, (block, head, offset) ->
+        # hd contiguous values: a scatter with the heads as a window
+        # makes XLA:TPU re-lay the whole pool, in and out, every layer
+        heads = jnp.arange(hkv)[None, :]            # (1,Hkv)
         gat = jnp.minimum(tables, nb - 1)           # clamp the sentinel
         new_pools = []
         for L, (kp, vp) in zip(w["layers"], pools):
@@ -775,21 +789,26 @@ class LlamaDecoder:
                 v = (h @ L["v"].T).reshape(s, cfg.num_kv_heads, 1, hd)
                 q = _apply_rope(q, cos, sin)
                 k = _apply_rope(k, cos, sin)
-                kp2 = kp.at[blk, :, off].set(k[:, :, 0, :], mode="drop")
-                vp2 = vp.at[blk, :, off].set(v[:, :, 0, :], mode="drop")
+                kp2 = kp.at[blk, heads, off].set(k[:, :, 0, :], mode="drop")
+                vp2 = vp.at[blk, heads, off].set(v[:, :, 0, :], mode="drop")
                 new_pools.append((kp2, vp2))
-                kc = kp2[gat].transpose(0, 2, 1, 3, 4) \
-                    .reshape(s, hkv, t, hd)
-                vc = vp2[gat].transpose(0, 2, 1, 3, 4) \
-                    .reshape(s, hkv, t, hd)
-                ctx = self._attend(q, kc, vc, mask)
+                if paged_kernel:
+                    ctx = paged_decode_attention(q[:, :, 0, :], kp2, vp2,
+                                                 tables, pos + 1)
+                else:
+                    kc = kp2[gat].transpose(0, 2, 1, 3, 4) \
+                        .reshape(s, hkv, t, hd)
+                    vc = vp2[gat].transpose(0, 2, 1, 3, 4) \
+                        .reshape(s, hkv, t, hd)
+                    ctx = self._attend(q, kc, vc, mask)
                 return ctx.reshape(s, cfg.num_heads * hd) @ L["o"].T
 
             x = self._layer(L, x, ctx_fn)
         x = self._rms(x, w["norm"], cfg.rms_eps)
         return x @ w["head"].T, new_pools
 
-    def _verify_blocks_impl(self, w, pools, tables, toks, pos0):
+    def _verify_blocks_impl(self, w, pools, tables, toks, pos0,
+                            paged_kernel=False):
         """Speculative VERIFY forward against the paged pool: a widened
         :meth:`_step_blocks_impl` that advances every slot K = k+1
         candidate positions in ONE dispatch.  ``toks`` (S, K) int32 is
@@ -809,8 +828,13 @@ class LlamaDecoder:
         and the next verify window overwrites them in place — the
         stale-row invariant, now doing rollback duty.  The causal mask
         here is per-COLUMN (``t <= pos0[s] + j``), so draft_j attends
-        the in-window K/V of draft_1..j-1 it was conditioned on."""
+        the in-window K/V of draft_1..j-1 it was conditioned on.
+        ``paged_kernel`` as in :meth:`_step_blocks_impl`: the same
+        kernel with K query columns, column j bounded by
+        ``pos0 + j + 1`` rows."""
         import jax.numpy as jnp
+
+        from ..ops.paged_attention import paged_decode_attention
 
         cfg = self.cfg
         hd = cfg.head_dim
@@ -829,8 +853,10 @@ class LlamaDecoder:
                                   jnp.minimum(pw // bs, mb - 1), axis=1)
         # columns past max_len have no legal row: force the sentinel so
         # the scatter drops instead of wrapping into a clamped block
-        blk = jnp.where(pw < jnp.int32(self.max_len), blk, nb)  # (S,K)
-        off = pw % bs
+        blk = jnp.where(pw < jnp.int32(self.max_len), blk,
+                        nb)[:, :, None]                     # (S,K,1)
+        off = (pw % bs)[:, :, None]
+        heads = jnp.arange(hkv)[None, None, :]              # (1,1,Hkv)
         gat = jnp.minimum(tables, nb - 1)
         new_pools = []
         for L, (kp, vp) in zip(w["layers"], pools):
@@ -844,19 +870,25 @@ class LlamaDecoder:
                     .transpose(0, 2, 1, 3)
                 q = _apply_rope(q, cos, sin)
                 k = _apply_rope(k, cos, sin)
-                # scatter indices (S,K) pair with update (S,K,Hkv,hd)
-                kp2 = kp.at[blk, :, off].set(
+                # scatter indices (S,K,Hkv) pair with update
+                # (S,K,Hkv,hd): rows of hd, as in the step
+                kp2 = kp.at[blk, heads, off].set(
                     k.transpose(0, 2, 1, 3), mode="drop")
-                vp2 = vp.at[blk, :, off].set(
+                vp2 = vp.at[blk, heads, off].set(
                     v.transpose(0, 2, 1, 3), mode="drop")
                 new_pools.append((kp2, vp2))
-                kc = kp2[gat].transpose(0, 2, 1, 3, 4) \
-                    .reshape(s, hkv, t, hd)
-                vc = vp2[gat].transpose(0, 2, 1, 3, 4) \
-                    .reshape(s, hkv, t, hd)
-                ctx = self._attend(q, kc, vc, mask)     # (S,H,K,hd)
-                return ctx.transpose(0, 2, 1, 3) \
-                    .reshape(s, kk, cfg.num_heads * hd) @ L["o"].T
+                if paged_kernel:
+                    ctx = paged_decode_attention(
+                        q.transpose(0, 2, 1, 3), kp2, vp2, tables,
+                        pos0 + 1)                       # (S,K,H,hd)
+                else:
+                    kc = kp2[gat].transpose(0, 2, 1, 3, 4) \
+                        .reshape(s, hkv, t, hd)
+                    vc = vp2[gat].transpose(0, 2, 1, 3, 4) \
+                        .reshape(s, hkv, t, hd)
+                    ctx = self._attend(q, kc, vc, mask) \
+                        .transpose(0, 2, 1, 3)
+                return ctx.reshape(s, kk, cfg.num_heads * hd) @ L["o"].T
 
             x = self._layer(L, x, ctx_fn)
         x = self._rms(x, w["norm"], cfg.rms_eps)

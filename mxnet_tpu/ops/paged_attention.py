@@ -1,0 +1,285 @@
+"""Paged decode attention: one Pallas TPU kernel that reads the KV pool
+in place, through the block table.
+
+Reference: NONE (the reference predates LLM serving).  The serving step
+(``LlamaDecoder._step_blocks_impl``) used to gather every slot's whole
+``(Hkv, MB*bs, hd)`` logical view out of the pool, repeat it
+``H / Hkv`` times for GQA and attend over all of it, whatever the slots
+held.  This kernel never builds a view:
+
+- ``tables`` and the per-slot schedule ride as scalar-prefetch operands
+  (SMEM), the pools stay in HBM (``memory_space=pl.ANY``);
+- a grid step is one slot; it walks the slot's blocks a chunk
+  (``blocks_per_chunk`` blocks) at a time, each block one async copy of
+  all KV heads (``(Hkv, bs, hd)``, contiguous in the pool's layout)
+  into a double-buffered VMEM scratch.  The next chunk — of this slot,
+  or the first of the next slot that has any — is in flight while the
+  current one is computed;
+- work is bounded by ``lengths[s]``: blocks past ``ceil(lengths[s] /
+  bs)`` are neither fetched nor computed, the last block is masked by
+  position, and a row that starts with the sentinel (a vacant slot)
+  costs no KV read and yields zeros.  Sentinel ids never index the
+  pool;
+- the ``H / Hkv`` query heads of a KV head meet that head's keys once:
+  no repeat, in HBM or in VMEM;
+- arithmetic as ``LlamaDecoder._attend``: operands in the pool's dtype,
+  float32 scores and online-softmax state (running max, sum,
+  accumulator), the probabilities cast to the pool's dtype for the
+  second product, float32 accumulation, output in ``q``'s dtype.
+
+The speculative verify is the same kernel with ``K`` query columns a
+slot: column ``j`` sees ``lengths[s] + j`` rows, and the ``K * H / Hkv``
+rows of a KV head share its keys.
+
+Rows of an owned block past the slot's length are fetched with their
+block and meet probability 0, as in the gather path: both count on the
+pool holding finite numbers (it is born zero and only ever written with
+K/V rows).
+"""
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+#: reviewed signature budget (mxlint T15): the jit at the foot of this
+#: module is inlined into the step or verify program that calls it and
+#: compiles nothing of its own there; called alone (tests_tpu/, tools/)
+#: it is one program per operand shapes
+__compile_signatures__ = {
+    "paged_decode_attention":
+        "0 inside a serving program; 1 per (operand shapes, "
+        "blocks_per_chunk) when called alone",
+}
+
+#: blocks fetched and computed per inner step.  On the v5e, 8 KV heads of
+#: 128 in bf16, blocks of 16 (PERF.md, PR 25): 16 blocks a chunk read
+#: 260-350 GB/s, 32 read 360 (58 slots of 100-500 tokens) to 550 (full
+#: slots), 64 read 310 to 680: a chunk computes its unused tail, so the
+#: widest loses on short slots.  At 32, K and V double-buffered take
+#: 4 MiB of VMEM.
+BLOCKS_PER_CHUNK = 32
+
+
+def _sublane_tile(dtype):
+    """Rows of one (sublane, 128-lane) tile of ``dtype``: 8 for 4-byte
+    types, 16 for bf16, 32 for 1-byte types."""
+    return 32 // np.dtype(dtype).itemsize
+
+
+def applicable(platform, mesh, head_dim, block_size, dtype):
+    """Whether the kernel can stand in for the gather path, from what
+    the caller observes: the platform its pool lives on, the engine's
+    mesh (a tp-sharded pool would need a ``shard_map`` wrapper: it keeps
+    the gather path), and the shapes Mosaic tiles without padding."""
+    return (platform == "tpu" and mesh is None
+            and head_dim % 128 == 0
+            and block_size % _sublane_tile(dtype) == 0)
+
+
+def _schedule(tables, lengths, num_blocks, block_size, chunk):
+    """Per-slot walk of the kernel, computed once in XLA on (S, MB)
+    integers: ``nblk`` blocks to read (bounded by the longest column's
+    ``lengths`` AND by the row's leading non-sentinel entries), ``par``
+    the scratch buffer of the slot's first chunk (the chunks of all
+    slots alternate between the two buffers) and ``nxt``, where
+    ``nxt[0]`` is the first slot with any chunk and ``nxt[s + 1]`` the
+    next one after ``s`` (``S`` for none)."""
+    s, mb = tables.shape
+    owned = jnp.cumprod((tables < num_blocks).astype(jnp.int32),
+                        axis=1).sum(axis=1)
+    lengths = jnp.clip(lengths, 0, mb * block_size)
+    nblk = jnp.minimum(-(-lengths // block_size), owned).astype(jnp.int32)
+    nch = -(-nblk // chunk)
+    par = ((jnp.cumsum(nch) - nch) % 2).astype(jnp.int32)
+    idx = jnp.where(nch > 0, jnp.arange(s, dtype=jnp.int32), s)
+    after = lax.cummin(idx, axis=0, reverse=True)
+    nxt = jnp.concatenate([after, jnp.full((1,), s, jnp.int32)])
+    return nblk, par, nxt
+
+
+def _kernel(len_ref, nblk_ref, par_ref, nxt_ref, tab_ref,
+            q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, k_sem, v_sem, m_ref, l_ref, acc_ref,
+            *, chunk, max_blocks, group, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    num_slots = pl.num_programs(0)
+    _, _, hkv, bs, hd = k_buf.shape
+    nblk = nblk_ref[s]
+    nch = (nblk + chunk - 1) // chunk
+
+    def transfer(slot, c, buf, wait):
+        """Start (or wait for) the K and V copies of every block of
+        chunk ``c`` of ``slot`` that the slot reads, into scratch
+        buffer ``buf``.  A loop, not ``chunk`` unrolled copies: the
+        kernel is traced and lowered at every start of a server."""
+        first = c * chunk
+
+        def block(j, carry):
+            bid = tab_ref[slot * max_blocks + first + j]
+            for hbm, vmem, sem in ((k_hbm, k_buf, k_sem),
+                                   (v_hbm, v_buf, v_sem)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[bid], vmem.at[buf, j], sem.at[buf])
+                copy.wait() if wait else copy.start()
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(chunk, nblk_ref[slot] - first),
+                      block, 0)
+
+    start = functools.partial(transfer, wait=False)
+    wait = functools.partial(transfer, wait=True)
+
+    @pl.when(s == 0)
+    def _first_step():
+        # a chunk's unfetched blocks meet probability 0; what the
+        # scratch was born with must not be NaN under that 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(s == nxt_ref[0])
+    def _first_chunk():
+        start(s, 0, par_ref[s])
+
+    @pl.when(nch == 0)
+    def _vacant():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(nch > 0)
+    def _attend():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # a row of a KV head's tile is (column j, query head g): it sees
+        # lengths + j rows of what was fetched (pad rows ride along)
+        col = lax.broadcasted_iota(jnp.int32, (m_ref.shape[1], 1),
+                                   0) // group
+        bound = jnp.minimum(len_ref[s] + col, nblk * bs)
+        par = par_ref[s]
+        follower = nxt_ref[s + 1]
+
+        def body(c, carry):
+            buf = (par + c) % 2
+
+            @pl.when(c + 1 < nch)
+            def _():
+                start(s, c + 1, 1 - buf)
+
+            @pl.when(jnp.logical_and(c + 1 == nch, follower < num_slots))
+            def _():
+                start(follower, 0, 1 - buf)
+
+            wait(s, c, buf)
+            tpos = c * (chunk * bs) + lax.broadcasted_iota(
+                jnp.int32, (1, chunk * bs), 1)
+            live = tpos < bound
+
+            # the heads are unrolled (as a loop the kernel ran a fifth
+            # slower: their matrix products no longer overlap); the
+            # copies above are loops, which costs nothing
+            for h in range(hkv):
+                k = k_buf[buf, :, h].reshape(chunk * bs, hd)
+                v = v_buf[buf, :, h].reshape(chunk * bs, hd)
+                sc = lax.dot_general(
+                    q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(live, sc, -jnp.inf)
+                m_prev = m_ref[h]
+                # every row sees position 0, so the running max is
+                # finite from the first chunk on
+                m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+                p = jnp.exp(sc - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+                acc_ref[h] = alpha * acc_ref[h] + lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+            return carry
+
+        lax.fori_loop(0, nch, body, 0)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                            blocks_per_chunk=BLOCKS_PER_CHUNK,
+                            interpret=False):
+    """Decode attention of one new token per slot over a paged KV pool.
+
+    ``q`` (S, H, hd) after RoPE; ``k_pool`` / ``v_pool`` ``(num_blocks,
+    Hkv, block_size, hd)`` holding the new token's row already;
+    ``tables`` (S, MB) int32 block ids in logical order, vacant entries
+    = ``num_blocks``; ``lengths`` (S,) int32 rows to attend
+    (``pos + 1``).  Returns (S, H, hd) in ``q``'s dtype; a slot with no
+    owned block yields zeros.
+
+    With ``q`` (S, K, H, hd), the speculative verify's window, column
+    ``j`` attends ``lengths + j`` rows; returns (S, K, H, hd)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    cols = q.shape[1] if q.ndim == 4 else 1
+    s, h, hd = q.shape[0], q.shape[-2], q.shape[-1]
+    nb, hkv, bs, _ = k_pool.shape
+    mb = tables.shape[1]
+    g = h // hkv
+    chunk = int(min(blocks_per_chunk, mb))
+    # the rows of a KV head's score tile are its query heads, column by
+    # column; padded up to whole sublane tiles of the operand dtype
+    rows = cols * g
+    tile = _sublane_tile(q.dtype)
+    gp = -(-rows // tile) * tile
+    qg = q.reshape(s, cols, hkv, g, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(s, hkv, rows, hd)
+    if gp != rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rows), (0, 0)))
+    tables = jnp.asarray(tables, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    # nothing to attend means nothing to read, whatever the row owns
+    last = jnp.where(lengths > 0, lengths + (cols - 1), 0)
+    nblk, par, nxt = _schedule(tables, last, nb, bs, chunk)
+    qspec = pl.BlockSpec((1, hkv, gp, hd), lambda i, *_: (i, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, chunk=chunk, max_blocks=mb, group=g,
+                          scale=1.0 / float(np.sqrt(hd))),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(s,),
+            in_specs=[qspec,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=qspec,
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk, hkv, bs, hd), k_pool.dtype),
+                pltpu.VMEM((2, chunk, hkv, bs, hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((hkv, gp, 1), jnp.float32),    # running max
+                pltpu.VMEM((hkv, gp, 1), jnp.float32),    # running sum
+                pltpu.VMEM((hkv, gp, hd), jnp.float32),   # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((s, hkv, gp, hd), q.dtype),
+        # slots run in order: the copies of a slot's first chunk are
+        # started by the slot before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_decode_attention",
+        interpret=interpret,
+    )(lengths, nblk, par, nxt, tables.reshape(-1), qg, k_pool, v_pool)
+    out = out[:, :, :rows].reshape(s, hkv, cols, g, hd) \
+        .transpose(0, 2, 1, 3, 4)
+    return out.reshape(q.shape)
+
+
+#: jitted, so that the layers of a step program share one trace and one
+#: Mosaic lowering of the kernel (a third of a second each, every start).
+#: The tests run ``_paged_decode_attention`` under the TPU interpreter
+#: eagerly: its simulated copies deadlock now and then inside a jit.
+paged_decode_attention = jax.jit(
+    _paged_decode_attention, static_argnames=("blocks_per_chunk",
+                                              "interpret"))

@@ -211,6 +211,9 @@ class LlamaServingEngine:
         #: released, tokens on host.  Written and read by the decode
         #: thread alone (the lane log's ``decode.tick`` record).
         self.tick_stamps = None
+        #: K/V rows the last step() attended: ``pos + 1`` summed over
+        #: its active slots (the record's ``kv_tokens``)
+        self.tick_kv_tokens = 0
         self._signatures = set()
 
         # decode-step logit stats behind the same gate as the training
@@ -218,11 +221,26 @@ class LlamaServingEngine:
         # one signature per numerics mode (rebuild the engine to toggle)
         self._numerics = _numerics.trace_enabled()
         numerics_on = self._numerics
+        paged_kernel = False
+        if kv_mode == "paged":
+            from ..ops import paged_attention
+
+            kp0 = self._pool[0][0]
+            paged_kernel = paged_attention.applicable(
+                next(iter(kp0.devices())).platform, mesh, cfg.head_dim,
+                self.block_size, kp0.dtype)
+        #: which attention the step and verify programs were built
+        #: with: "paged_kernel" (ops/paged_attention.py reads the pool
+        #: in place) or "gather" (a dense per-slot view through the
+        #: table).  Decided here, once, from where the pool lives, the
+        #: mesh and the shapes.
+        self.decode_attention = "paged_kernel" if paged_kernel else "gather"
         if kv_mode == "paged":
 
             def _step_fn(wq, pools, tables, ids, pos):
-                logits, pools = dec._step_blocks_impl(deq(wq), pools,
-                                                      tables, ids, pos)
+                logits, pools = dec._step_blocks_impl(
+                    deq(wq), pools, tables, ids, pos,
+                    paged_kernel=paged_kernel)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if numerics_on:
                     return tok, pools, _numerics.stats_of(logits)
@@ -234,7 +252,8 @@ class LlamaServingEngine:
 
             def _verify_fn(wq, pools, tables, toks, pos0):
                 logits, pools = dec._verify_blocks_impl(
-                    deq(wq), pools, tables, toks, pos0)
+                    deq(wq), pools, tables, toks, pos0,
+                    paged_kernel=paged_kernel)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if numerics_on:
                     return tok, pools, _numerics.stats_of(logits)
@@ -611,6 +630,8 @@ class LlamaServingEngine:
             for s in active:
                 self._last[s] = out[s]
                 self._pos[s] += 1
+            # the step attended pos + 1 rows: the cursors as they are now
+            self.tick_kv_tokens = int(self._pos[list(active)].sum())
         return out
 
     def verify(self, drafts):

@@ -432,6 +432,8 @@ class DecodeLane:
         # tick's lane-log record
         self._t_loop = None
         self._n_adopted = 0
+        # the engine's attention path goes into its first tick record
+        self._said_attention = False
 
     def hand_off(self, h):
         with self._hand_lock:
@@ -599,15 +601,25 @@ class DecodeLane:
                         del self._seqs[slot]
                     r.finish(req, tokens)
                     n_finished += 1
-        self._record_tick(step_idx, ids, n_finished, stamps)
+        self._record_tick(step_idx, ids, n_finished, stamps,
+                          getattr(r.engine, "tick_kv_tokens", 0))
 
-    def _record_tick(self, seq, ids, n_finished, stamps, **extra):
-        """The turn's ``decode.tick`` record, its bookkeeping done."""
+    def _record_tick(self, seq, ids, n_finished, stamps, kv_tokens,
+                     **extra):
+        """The turn's ``decode.tick`` record, its bookkeeping done.
+        ``kv_tokens``: K/V rows the step attended, summed over the
+        active slots.  The lane's first record also says which
+        attention the engine's step program was built with."""
         t_lock, t_disp0, t_disp1, t_tok = stamps
+        if not self._said_attention:
+            self._said_attention = True
+            extra["decode_attention"] = getattr(
+                self.r.engine, "decode_attention", None)
         tracing.lane_record(
             "decode.tick", replica=self.r.index, seq=seq,
             n_active=len(ids), n_adopted=self._n_adopted,
-            n_finished=n_finished, request_ids=ids, t_loop=self._t_loop,
+            n_finished=n_finished, kv_tokens=int(kv_tokens),
+            request_ids=ids, t_loop=self._t_loop,
             t_lock=t_lock, t_disp0=t_disp0, t_disp1=t_disp1, t_tok=t_tok,
             t_book=time.perf_counter(), **extra)
 
@@ -727,7 +739,9 @@ class DecodeLane:
                     del self._seqs[slot]
                 r.finish(req, tokens)
                 n_finished += 1
-        self._record_tick(step_idx, ids, n_finished, stamps,
+        # the verify's last column attended pos0 + k + 1 rows
+        kv_tokens = sum(int(pos0[slot]) + k + 1 for slot in active)
+        self._record_tick(step_idx, ids, n_finished, stamps, kv_tokens,
                           accepted=accepted)
         telemetry.count("serving.draft_tokens", k * len(active))
         capacity.note_spec(r.index, k * len(active), accepted_this_tick)

@@ -608,6 +608,7 @@ class GenerativeServer(_ServerBase):
                 "pending": len(self.queue),
                 "kv_cache": self._sched.mgr.stats(),
                 "compiled_signatures": self.engine.compiled_signatures(),
+                "decode_attention": self.engine.decode_attention,
             }
             telemetry.gauge("serving.kv_occupancy",
                             out["kv_cache"]["occupancy"])
@@ -624,6 +625,7 @@ class GenerativeServer(_ServerBase):
             "kv_cache": reps[0].mgr.stats(),
             "compiled_signatures":
                 reps[0].engine.compiled_signatures(),
+            "decode_attention": reps[0].engine.decode_attention,
             "num_replicas": len(reps),
         }
         if len(reps) > 1:
@@ -633,6 +635,7 @@ class GenerativeServer(_ServerBase):
                 "decode_steps": r.engine.steps,
                 "kv_cache": r.mgr.stats(),
                 "compiled_signatures": r.engine.compiled_signatures(),
+                "decode_attention": r.engine.decode_attention,
             } for r in reps]
         if any(r.spec_k for r in reps):
             drafted = sum(r.draft_tokens for r in reps)

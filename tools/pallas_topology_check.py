@@ -14,6 +14,8 @@ Kernels covered:
 - flash-attention forward (ops/flash_attention._fa_forward_pallas)
 - fused matmul+affine+ReLU conv probe
   (tools/pallas_conv_probe.fused_matmul_affine_relu)
+- paged decode attention (ops/paged_attention.paged_decode_attention),
+  also compiled by tests/test_paged_attention.py
 
 Writes one JSON blob to stdout (and argv[1] if given).  Single-process
 (libtpu lockfile).
@@ -88,6 +90,18 @@ def main():
                q, k, v, o, g, lse, True, scale),
            qkv + [jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)] * 2
            + [lse_aval])
+
+    # paged decode attention at the serving cell's shapes: 64 slots of
+    # 1024 tokens over a pool of 4096 blocks of 16, 32/8 heads of 128
+    from mxnet_tpu.ops.paged_attention import paged_decode_attention
+
+    pool_aval = jax.ShapeDtypeStruct((4096, 8, 16, 128), jnp.bfloat16)
+    record("paged_decode_attention_bf16_64x1024",
+           paged_decode_attention,
+           [jax.ShapeDtypeStruct((64, 32, 128), jnp.bfloat16),
+            pool_aval, pool_aval,
+            jax.ShapeDtypeStruct((64, 64), jnp.int32),
+            jax.ShapeDtypeStruct((64,), jnp.int32)])
 
     # fused 1x1conv(matmul)+BN-affine+ReLU probe kernel
     from pallas_conv_probe import fused_matmul_affine_relu
