@@ -221,13 +221,18 @@ def _registry_workspace(axes, remat):
 
 
 def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
-                 block_size, dtype=np.float32, mesh=None, rules=None):
-    """Per-device bytes of the serving engine's paged KV block pool:
-    2 (K and V) × layers × ``num_blocks × num_kv_heads × block_size ×
-    head_dim`` × itemsize, sharded the way the serving rule table
-    places the pool (``layers.{i}.kv_pool`` — KV-head axis over ``tp``
-    by default).  This is the serving analog of the allreduce-bytes
-    planning the trainer gets: size the pool BEFORE building the
+                 block_size, dtype=np.float32, mesh=None, rules=None,
+                 state_layers=0, state_shape=None, num_slots=0):
+    """Per-device bytes of the serving engine's paged cache.  The block
+    pool: 2 (K and V) × ``num_layers`` × ``num_blocks × num_kv_heads ×
+    block_size × head_dim`` × itemsize, sharded the way the serving rule
+    table places the pool (``layers.{i}.kv_pool`` — KV-head axis over
+    ``tp`` by default); ``num_layers`` counts the layers that OWN a K/V
+    pool (``CacheSpec.kv_layers``), not the model's depth.  Plus, for a
+    model whose other layers keep a fixed per-slot state:
+    ``state_layers × num_slots × prod(state_shape)`` × itemsize
+    (unsharded).  This is the serving analog of the allreduce-bytes
+    planning the trainer gets: size the cache BEFORE building the
     engine, and feed the figure to :func:`plan_model` via
     ``kv_pool_bytes=`` to get a fit verdict that includes serving
     state.  Matches ``LlamaServingEngine.kv_pool_bytes()`` exactly."""
@@ -244,7 +249,12 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
             {"layers.0.kv_pool": shape}, mesh)
         div = _shard_div(specs.get("layers.0.kv_pool"), axes)
     n_elem = int(np.prod(shape))
-    return 2 * int(num_layers) * _ceil_div(n_elem * dtype.itemsize, div)
+    state = 0
+    if state_layers:
+        state = int(state_layers) * int(num_slots) \
+            * int(np.prod(state_shape)) * dtype.itemsize
+    return 2 * int(num_layers) * _ceil_div(n_elem * dtype.itemsize, div) \
+        + state
 
 
 def plan_model(params, mesh=None, rules=None, optimizer=None,

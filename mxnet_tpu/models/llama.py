@@ -395,6 +395,11 @@ class LlamaForCausalLM(HybridBlock):
         _numerics.tap("logits", out)
         return out
 
+    def serving_decoder(self, max_len):
+        """What ``GenerativeServer``'s engine asks a model for: the
+        decoder with the paged programs and the cache spec."""
+        return LlamaDecoder(self, max_len)
+
     def set_remat(self, tier):
         """Set the decoder-stack remat tier ("none" / "dots" / "layer"
         / "auto"; see ``mxnet_tpu.memory.policy``).  "auto" asks the
@@ -484,8 +489,10 @@ class LlamaDecoder:
     The math mirrors ``LlamaAttention``/``LlamaMLP``; attention scores
     accumulate in float32 (``preferred_element_type``) exactly like the
     training ``_sdpa_ref`` path, and tests/test_llama.py pins cached ==
-    uncached logits so the paths cannot drift.  Dense MLP only (MoE
-    decode falls back to the oracle path).
+    uncached logits so the paths cannot drift.  Dense MLP only: a
+    routed expert layer is served by the dropless
+    ``models.moe.routed_ffn`` (as ``models.lfm2`` does), not by the
+    fixed-capacity ``MoEMLP`` this family trains with.
     """
 
     def __init__(self, net: "LlamaForCausalLM", max_len: int):
@@ -494,7 +501,11 @@ class LlamaDecoder:
 
         cfg = net.config
         if cfg.num_experts:
-            raise MXNetError("LlamaDecoder supports dense MLP configs")
+            raise MXNetError(
+                "LlamaDecoder serves dense MLP configs: num_experts > 0 "
+                "builds the fixed-capacity MoEMLP, which drops tokens; "
+                "a served expert layer is models.moe.routed_ffn "
+                "(dropless), as models.lfm2 wires it")
         self.cfg = cfg
         self.max_len = int(max_len)
         self._net = net
@@ -524,6 +535,14 @@ class LlamaDecoder:
         head = emb if self.cfg.tie_embeddings else raw(net.lm_head.weight)
         return dict(layers=layers, emb=emb,
                     norm=raw(net.model.norm.weight), head=head)
+
+    def cache_spec(self):
+        """The serving engine's question: every layer owns a K/V pool."""
+        from ..serving.kv_cache import CacheSpec
+
+        cfg = self.cfg
+        return CacheSpec(("kv",) * cfg.num_layers, cfg.num_kv_heads,
+                         cfg.head_dim)
 
     def init_cache(self, batch):
         import jax.numpy as jnp

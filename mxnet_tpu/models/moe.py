@@ -36,7 +36,7 @@ import math
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
 
-__all__ = ["MoEMLP", "collect_aux", "shard_moe"]
+__all__ = ["MoEMLP", "collect_aux", "shard_moe", "route", "routed_ffn"]
 
 
 # --- aux-loss collection ----------------------------------------------------
@@ -194,6 +194,78 @@ def _expert_ffn(ein, gw, uw, dw):
     u = jnp.einsum("ech,eih->eci", ein, uw.astype(ein.dtype))
     act = g * jax.nn.sigmoid(g) * u
     return jnp.einsum("eci,ehi->ech", act, dw.astype(ein.dtype))
+
+
+def route(x, router_w, k, score="softmax", choice_bias=None,
+          renormalize=True, scale=1.0):
+    """Top-``k`` routing over ALL experts, in float32: ``x`` (N, H),
+    ``router_w`` (E, H) -> ``(idx (N, k) int32, weights (N, k) f32)``.
+    ``score`` is ``softmax`` or ``sigmoid`` of the router logits;
+    ``choice_bias`` (E,) is added for the CHOICE only (the weights are
+    the scores without it); ``renormalize`` divides the chosen scores
+    by their sum (+1e-6); ``scale`` multiplies them."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    if score not in ("softmax", "sigmoid"):
+        raise MXNetError(f"unknown router score {score!r}")
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32).T
+    s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    pick = s if choice_bias is None \
+        else s + choice_bias.astype(jnp.float32)
+    _, idx = lax.top_k(pick, k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / (w.sum(-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), w * jnp.float32(scale)
+
+
+def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
+               choice_bias=None, renormalize=True, scale=1.0,
+               experts_held=None, live=None):
+    """Dropless routed SwiGLU: every token reaches its ``k`` experts, no
+    capacity, nothing dropped.  ``x`` (N, H); ``router_w`` (E, H) over
+    ALL experts; the expert bank holds the contiguous range
+    ``experts_held = (first, count)`` (default: all), stacked
+    ``w_gate`` / ``w_up`` (count, H, I) and ``w_down`` (count, I, H),
+    (in, out).
+
+    Routes over all experts and returns the part of the result that the
+    held experts give (what the absent ones would add is left out: the
+    shares of a layer divided over chips add up to the uncut layer),
+    and the rows each of the E experts received, ``(E,)`` int32.
+    ``live`` (N,) bool names the rows that belong to a request: every
+    row is computed, only those are counted (a served program also runs
+    vacant slots and the padded end of a prompt).
+
+    Every held expert computes every row, weighted by a (N, count)
+    combine matrix that is zero where the expert was not chosen: where
+    rows are few the expert weights have to be streamed whole anyway
+    (on a v5e this beat rows sorted by expert and ``jax.lax.ragged_dot``
+    at every size measured, up to 512 tokens x 4: PERF.md, PR 26)."""
+    import jax
+    import jax.numpy as jnp
+
+    n, _h = x.shape
+    e = router_w.shape[0]
+    first, held = experts_held if experts_held is not None else (0, e)
+    if w_gate.shape[0] != held:
+        raise MXNetError(f"expert bank holds {w_gate.shape[0]} experts, "
+                         f"experts_held says {held}")
+    idx, w = route(x, router_w, k, score, choice_bias, renormalize, scale)
+    x = x.astype(w_gate.dtype)
+    ones = jnp.ones((n,), jnp.int32) if live is None \
+        else live.astype(jnp.int32)
+    counts = jnp.zeros((e,), jnp.int32).at[idx].add(ones[:, None])
+    comb = jnp.zeros((n, e), jnp.float32) \
+        .at[jnp.arange(n)[:, None], idx].add(w)
+    comb = comb[:, first:first + held]
+    g = jnp.einsum("nh,ehi->nei", x, w_gate)
+    u = jnp.einsum("nh,ehi->nei", x, w_up)
+    act = g * jax.nn.sigmoid(g) * u * comb.astype(x.dtype)[:, :, None]
+    return jnp.einsum("nei,eih->nh", act, w_down), counts
 
 
 def moe_param_specs(block, ep_axis="ep", tp_axis=None):

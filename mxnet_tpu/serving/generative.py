@@ -1,9 +1,16 @@
-"""Continuous-batching decode engine for the llama generative path.
+"""Continuous-batching decode engine for the generative path.
 
 The engine owns the device half of serving: weights (optionally int8),
-the KV storage, and a fixed family of compiled programs that stay
-shape-stable under arbitrary request traffic.  Two storage modes share
-one surface (``kv_mode=``):
+the cache storage, and a fixed family of compiled programs that stay
+shape-stable under arbitrary request traffic.  It asks the model for
+two things (``net.serving_decoder(max_len)``): the decoder's paged
+programs (``_step_blocks_impl``, ``_prefill_rows_impl``; the commit is
+the engine's scatter over what they return) and its **cache spec**
+(:class:`~.kv_cache.CacheSpec`): which layers own a K/V block pool and
+which a fixed per-slot state, of what shape.  A layer's state lives in
+one array ``(num_slots,) + state_shape`` beside the pools, is donated
+through the step like them, and is written whole at admission.  Two
+storage modes share one surface (``kv_mode=``):
 
 * **paged** (default since r11) — K/V lives in a shared block pool per
   layer, ``(num_blocks, Hkv, block_size, head_dim)``; each slot carries
@@ -142,8 +149,29 @@ def _named_weight_items(w):
     return items
 
 
+#: why an engine refuses an option for a model whose cache spec has
+#: per-slot state (or routed experts): named, so nothing falls back
+_STATE_REFUSALS = {
+    "slots": "kv_mode='slots' keeps keys and values only: a model with "
+             "per-slot state needs kv_mode='paged'",
+    "spec": "speculative decoding (draft_net / spec_k) over per-slot "
+            "state needs the state rolled back when a draft is "
+            "rejected, which this engine does not do",
+    "mesh": "a mesh-placed engine (mesh=) has no partition rule for a "
+            "per-slot state or an expert bank",
+    "int8": "int8=True quantizes the dense decoder's matrices only; a "
+            "model with per-slot state or routed experts is served in "
+            "its load dtype",
+}
+
+
 class LlamaServingEngine:
-    """Device-side half of continuous batching for a LlamaForCausalLM."""
+    """Device-side half of continuous batching for any model that
+    answers ``serving_decoder(max_len)`` with a decoder holding the
+    paged programs (step, prefill rows) and a ``cache_spec()`` —
+    ``LlamaForCausalLM`` (every layer a K/V pool) and
+    ``Lfm2MoeForCausalLM`` (K/V pools beside per-slot states, routed
+    experts) today."""
 
     def __init__(self, net, max_len=None, num_slots=4, int8=False,
                  kv_mode="slots", block_size=16, num_blocks=None,
@@ -151,7 +179,6 @@ class LlamaServingEngine:
                  spec_k=0):
         import jax
         import jax.numpy as jnp
-        from ..models.llama import LlamaDecoder
 
         if kv_mode not in ("paged", "slots"):
             raise MXNetError(f"unknown kv_mode {kv_mode!r}; "
@@ -168,13 +195,25 @@ class LlamaServingEngine:
         self.partition_rules = partition_rules
         self.replica_id = int(replica_id)
         self.dev_lock = threading.RLock()
-        dec = LlamaDecoder(net, self.max_len)
+        dec = net.serving_decoder(self.max_len)
         self._dec = dec
+        #: the model's answer: which layers keep K/V, which a state
+        spec = self.cache_spec = dec.cache_spec()
+        if spec.state_layers or spec.expert_layers:
+            for key, bad in (("slots", kv_mode != "paged"),
+                             ("spec", self.spec_k),
+                             ("mesh", mesh is not None),
+                             ("int8", self.int8)):
+                if bad:
+                    raise MXNetError(_STATE_REFUSALS[key])
         w = dec._weights()
         self._w = _quantize_tree(w) if self.int8 else w
         deq = _dequantize_tree if self.int8 else (lambda t: t)
         cfg = net.config
         dt = w["emb"].dtype
+        #: bytes of one cached value (K, V and state share the weights'
+        #: load dtype): what the manager prices blocks and states with
+        self.cache_itemsize = int(np.dtype(dt).itemsize)
         if kv_mode == "paged":
             self.block_size = int(block_size)
             if self.block_size < 1:
@@ -184,10 +223,15 @@ class LlamaServingEngine:
             self.max_blocks = -(-self.max_len // self.block_size)
             self.num_blocks = int(num_blocks or
                                   self.num_slots * self.max_blocks)
-            pshape = (self.num_blocks, cfg.num_kv_heads, self.block_size,
-                      cfg.head_dim)
-            self._pool = [(jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
-                          for _ in range(cfg.num_layers)]
+            pshape = (self.num_blocks, spec.num_kv_heads, self.block_size,
+                      spec.head_dim)
+            # one entry a layer, by the spec: a (K, V) pool pair, or the
+            # layer's per-slot state
+            self._pool = [
+                (jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
+                if kind == "kv" else
+                jnp.zeros((self.num_slots,) + spec.state_shape, dt)
+                for kind in spec.layers]
             self._tables = np.full((self.num_slots, self.max_blocks),
                                    self.num_blocks, np.int32)
             self._caches = None
@@ -225,9 +269,9 @@ class LlamaServingEngine:
         if kv_mode == "paged":
             from ..ops import paged_attention
 
-            kp0 = self._pool[0][0]
+            kp0 = next(e for e in self._pool if isinstance(e, tuple))[0]
             paged_kernel = paged_attention.applicable(
-                next(iter(kp0.devices())).platform, mesh, cfg.head_dim,
+                next(iter(kp0.devices())).platform, mesh, spec.head_dim,
                 self.block_size, kp0.dtype)
         #: which attention the step and verify programs were built
         #: with: "paged_kernel" (ops/paged_attention.py reads the pool
@@ -235,20 +279,40 @@ class LlamaServingEngine:
         #: table).  Decided here, once, from where the pool lives, the
         #: mesh and the shapes.
         self.decode_attention = "paged_kernel" if paged_kernel else "gather"
+        #: per-expert row counts that ride behind the tokens of every
+        #: step and prefill fetch (0: the model routes nothing)
+        self._n_counts = spec.expert_layers * spec.num_experts
+        #: the last step's ``experts_touched`` / ``expert_rows_max`` /
+        #: ``expert_rows_mean`` (the ``decode.tick`` record), and the
+        #: totals over every step and prefill (``server.stats()``)
+        self.tick_experts = {}
+        self.expert_totals = {"programs": 0, "rows": 0,
+                              "experts_touched": 0, "expert_rows_max": 0}
         if kv_mode == "paged":
 
+            def _tokens(logits, out):
+                # a model with routed experts returns their row counts
+                # third: they go out in the same array as the tokens
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                if self._n_counts:
+                    tok = jnp.concatenate(
+                        [tok, out[2].reshape(-1).astype(jnp.int32)])
+                return tok
+
             def _step_fn(wq, pools, tables, ids, pos):
-                logits, pools = dec._step_blocks_impl(
+                out = dec._step_blocks_impl(
                     deq(wq), pools, tables, ids, pos,
                     paged_kernel=paged_kernel)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                logits, pools = out[:2]
+                tok = _tokens(logits, out)
                 if numerics_on:
                     return tok, pools, _numerics.stats_of(logits)
                 return tok, pools
 
             def _prefill_fn(wq, ids, t0):
-                rows, logits = dec._prefill_rows_impl(deq(wq), ids, t0)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), rows
+                out = dec._prefill_rows_impl(deq(wq), ids, t0)
+                rows, logits = out[:2]
+                return _tokens(logits, out), rows
 
             def _verify_fn(wq, pools, tables, toks, pos0):
                 logits, pools = dec._verify_blocks_impl(
@@ -288,14 +352,21 @@ class LlamaServingEngine:
 
             bs = self.block_size
 
-            def _scatter_fn(pools, rows, flat_idx):
+            def _scatter_fn(pools, rows, flat_idx, slots=None):
                 # rows[l]: (KB, Hkv, Lp, hd) raw prefill K/V; chunk each
                 # row into ceil(Lp/bs) block-sized pieces and write them
                 # at flat_idx (KB*nbp,) physical block ids — sentinel
                 # ids (== num_blocks) drop, covering vacant batch rows
-                # AND chunks past a short prompt's allocation
+                # AND chunks past a short prompt's allocation.  A state
+                # layer's rows (KB,) + state_shape replace the WHOLE
+                # state of ``slots`` (vacant rows: slot id num_slots,
+                # dropped), so a reused slot never sees its predecessor's
                 out = []
-                for (kp, vp), (k, v) in zip(pools, rows):
+                for entry, row in zip(pools, rows):
+                    if not isinstance(entry, tuple):
+                        out.append(entry.at[slots].set(row, mode="drop"))
+                        continue
+                    (kp, vp), (k, v) = entry, row
                     kb, hkv, lp, hd = k.shape
                     nbp = flat_idx.shape[0] // kb
                     pad = ((0, 0), (0, 0), (0, nbp * bs - lp), (0, 0))
@@ -442,12 +513,14 @@ class LlamaServingEngine:
         """Every (program, *bucket) shape this engine has compiled."""
         return sorted(self._signatures)
 
-    def kv_pool_bytes(self):
-        """PER-DEVICE bytes of the KV storage (pool or slot caches),
-        summed over layers and both of K/V — the figure the memory
-        planner's ``plan_kv_pool`` predicts pre-build.  On a tp mesh
-        each device holds one shard of the pool's head axis, so this is
-        the single-shard footprint, not the global array size."""
+    def kv_pool_bytes(self, by_kind=False):
+        """PER-DEVICE bytes of the cache storage (pool or slot caches):
+        K and V over the layers that own them, plus the per-slot states
+        of the layers that keep one — the figure the memory planner's
+        ``plan_kv_pool`` predicts pre-build.  ``by_kind`` splits it:
+        ``{"kv_blocks": ..., "slot_state": ...}``.  On a tp mesh each
+        device holds one shard of the pool's head axis, so this is the
+        single-shard footprint, not the global array size."""
         def shard_bytes(a):
             shards = getattr(a, "addressable_shards", None)
             if shards:
@@ -456,8 +529,36 @@ class LlamaServingEngine:
 
         with self.dev_lock:
             kv = self._pool if self.kv_mode == "paged" else self._caches
-            return int(sum(shard_bytes(k) + shard_bytes(v)
-                           for k, v in kv))
+            blocks = sum(shard_bytes(e[0]) + shard_bytes(e[1])
+                         for e in kv if isinstance(e, tuple))
+            state = sum(shard_bytes(e) for e in kv
+                        if not isinstance(e, tuple))
+        if by_kind:
+            return {"kv_blocks": int(blocks), "slot_state": int(state)}
+        return int(blocks + state)
+
+    def split_fetch(self, fetched, n):
+        """A step's or prefill's fetched vector -> (its ``n`` tokens,
+        the lane-log fields of the expert row counts behind them; {}
+        for a model that routes nothing).  Counts enter the totals."""
+        if not self._n_counts:
+            return fetched, {}
+        spec = self.cache_spec
+        counts = np.asarray(fetched[n:]).reshape(spec.expert_layers,
+                                                 spec.num_experts)
+        touched = counts > 0
+        fields = {
+            "experts_touched": int(touched.sum()),
+            "expert_rows_max": int(counts.max()),
+            "expert_rows_mean": float(counts.sum() / max(1, touched.sum())),
+        }
+        tot = self.expert_totals
+        tot["programs"] += 1
+        tot["rows"] += int(counts.sum())
+        tot["experts_touched"] += fields["experts_touched"]
+        tot["expert_rows_max"] = max(tot["expert_rows_max"],
+                                     fields["expert_rows_max"])
+        return fetched[:n], fields
 
     # -- transitions (slots mode: legacy single-loop scheduler) ---------------
     def admit(self, prompts_pad, t0s, slots):
@@ -519,7 +620,7 @@ class LlamaServingEngine:
         import jax.numpy as jnp
 
         kb = len(slots)
-        lp = rows[0][0].shape[2]
+        lp = next(r for r in rows if isinstance(r, tuple))[0].shape[2]
         nbp = -(-lp // self.block_size)
         flat = np.full(kb * nbp, self.num_blocks, np.int32)
         for r, blocks in enumerate(block_lists):
@@ -531,7 +632,11 @@ class LlamaServingEngine:
             flat[r * nbp: r * nbp + take] = tail[:take]
         t_lock = time.perf_counter()
         with self.dev_lock:
-            self._pool = self._scatter(self._pool, rows, self._dev(flat))
+            # state layers are written by slot, K/V layers by block
+            by_slot = (self._dev(slots),) \
+                if self.cache_spec.state_layers else ()
+            self._pool = self._scatter(self._pool, rows, self._dev(flat),
+                                       *by_slot)
             for i, s in enumerate(slots):
                 if s < self.num_slots:
                     row = np.full(self.max_blocks, self.num_blocks,
@@ -626,6 +731,7 @@ class LlamaServingEngine:
                              replica=self.replica_id):
             out = _materialize([toks])[0]
         self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
+        out, self.tick_experts = self.split_fetch(out, self.num_slots)
         with self.dev_lock:
             for s in active:
                 self._last[s] = out[s]

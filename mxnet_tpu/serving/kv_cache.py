@@ -33,12 +33,64 @@ that lets the slot ledger skip zeroing slot rows.
 """
 from __future__ import annotations
 
+import math
 import threading
 
 from ..base import MXNetError
 
 __all__ = ["KVCacheManager", "PagedKVCacheManager", "BlockAllocator",
-           "SlotState"]
+           "SlotState", "CacheSpec"]
+
+
+class CacheSpec:
+    """What a served model's decoder keeps between steps, layer by
+    layer — the answer to the engine's question (``decoder.cache_spec()``).
+
+    ``layers[l]`` is ``"kv"`` (the layer owns a K and a V block pool
+    ``(num_blocks, num_kv_heads, block_size, head_dim)``, addressed
+    through the slots' block tables) or ``"state"`` (it owns one array
+    ``(num_slots,) + state_shape``: a fixed-size state a slot, written
+    whole at admission and in place by every step).  ``expert_layers``
+    x ``num_experts`` is the shape of the per-expert row counts that
+    the step and prefill programs of a model with routed experts
+    return beside their tokens (0: none)."""
+
+    __slots__ = ("layers", "num_kv_heads", "head_dim", "state_shape",
+                 "expert_layers", "num_experts")
+
+    def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
+                 expert_layers=0, num_experts=0):
+        self.layers = tuple(layers)
+        if any(kind not in ("kv", "state") for kind in self.layers):
+            raise MXNetError(f"unknown cache kind in {self.layers}")
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.state_shape = None if state_shape is None \
+            else tuple(int(d) for d in state_shape)
+        self.expert_layers = int(expert_layers)
+        self.num_experts = int(num_experts)
+        if self.state_layers and self.state_shape is None:
+            raise MXNetError("state layers need a state_shape")
+
+    @property
+    def kv_layers(self):
+        return self.layers.count("kv")
+
+    @property
+    def state_layers(self):
+        return self.layers.count("state")
+
+    def kv_bytes_per_block(self, block_size, itemsize):
+        """Bytes one block holds over every K/V layer (K and V)."""
+        return 2 * self.kv_layers * self.num_kv_heads * int(block_size) \
+            * self.head_dim * int(itemsize)
+
+    def state_bytes_per_slot(self, itemsize):
+        """Bytes of one slot's state over every state layer."""
+        if not self.state_layers:
+            return 0
+        return self.state_layers * math.prod(self.state_shape) \
+            * int(itemsize)
 
 
 class SlotState:
@@ -294,12 +346,18 @@ class PagedKVCacheManager:
     lock-serialized: the prefill lane admits while the decode lane
     advances and evicts."""
 
-    def __init__(self, num_slots, max_len, num_blocks, block_size):
+    def __init__(self, num_slots, max_len, num_blocks, block_size,
+                 kv_bytes_per_block=0, state_bytes_per_slot=0):
         if num_slots < 1:
             raise MXNetError("num_slots must be >= 1")
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
         self.block_size = int(block_size)
+        #: bytes behind the two kinds of state this ledger counts
+        #: (``CacheSpec``): a block over the K/V layers only, and the
+        #: fixed per-slot state of the layers that keep one (0: none)
+        self.kv_bytes_per_block = int(kv_bytes_per_block)
+        self.state_bytes_per_slot = int(state_bytes_per_slot)
         self.allocator = BlockAllocator(num_blocks, block_size)
         self.num_blocks = self.allocator.num_blocks
         #: static per-slot block-table width: the step program gathers
@@ -393,6 +451,10 @@ class PagedKVCacheManager:
                 "shared_blocks": self.allocator.shared_blocks,
                 "peak_shared_blocks": self.allocator.peak_shared_blocks,
                 "capacity_tokens": cap,
+                "kv_block_bytes_in_use": used * self.kv_bytes_per_block,
+                "state_bytes_per_slot": self.state_bytes_per_slot,
+                "state_bytes_in_use":
+                    len(self._active) * self.state_bytes_per_slot,
                 "tokens_in_flight": int(live_unique),
                 "reserved_tokens": int(self.reserved_tokens()),
                 "peak_tokens": int(self._peak_tokens),

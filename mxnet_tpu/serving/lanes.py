@@ -57,6 +57,7 @@ from jax.profiler import TraceAnnotation
 
 from .. import sanitizer as _san
 from .. import telemetry
+from ..base import MXNetError
 from ..telemetry import capacity
 from ..telemetry import tracing
 from .bucketing import pad_batch
@@ -80,6 +81,15 @@ def _lane_materialize(arrays):
         else:
             out.append(np.asarray(a))
     return out
+
+
+def _cache_layers(engine):
+    """``kv_layers`` / ``state_layers`` of the engine's cache spec, for
+    a lane's first record ({} for an engine that keeps none)."""
+    spec = getattr(engine, "cache_spec", None)
+    if spec is None:
+        return {}
+    return {"kv_layers": spec.kv_layers, "state_layers": spec.state_layers}
 
 
 class _Handoff:
@@ -328,6 +338,10 @@ class PrefillLane:
                                      replica=r.index):
                     first = _lane_materialize([toks])[0]
                 t_ready = time.perf_counter()
+                # a model with routed experts sends their row counts
+                # behind the first tokens, in the same fetch
+                first, extra = eng.split_fetch(first, kb) \
+                    if hasattr(eng, "split_fetch") else (first, {})
                 with TraceAnnotation("mxt.prefill.commit", seq=seq,
                                      replica=r.index):
                     t_lock, t_commit1 = eng.commit_rows(
@@ -370,6 +384,8 @@ class PrefillLane:
         t_first = time.perf_counter()
         self.clock.enter("idle", t_first)
         mates = [req.id for req in group]
+        if seq == 1:
+            extra.update(_cache_layers(eng))
         # one stamp set for every consumer: the lane log, the capacity
         # duty cycle and (below) the request's span tree
         tracing.lane_record(
@@ -378,7 +394,7 @@ class PrefillLane:
             n_tokens=int(t0s_suf[:len(group)].sum()), bucket=(kb, lb),
             radix_hit_tokens=int(sum(matched)), t_start=t_start,
             t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
-            t_commit1=t_commit1, t_first=t_first)
+            t_commit1=t_commit1, t_first=t_first, **extra)
         capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
             req.t_first = t_first
@@ -602,19 +618,22 @@ class DecodeLane:
                     r.finish(req, tokens)
                     n_finished += 1
         self._record_tick(step_idx, ids, n_finished, stamps,
-                          getattr(r.engine, "tick_kv_tokens", 0))
+                          getattr(r.engine, "tick_kv_tokens", 0),
+                          **getattr(r.engine, "tick_experts", {}))
 
     def _record_tick(self, seq, ids, n_finished, stamps, kv_tokens,
                      **extra):
         """The turn's ``decode.tick`` record, its bookkeeping done.
         ``kv_tokens``: K/V rows the step attended, summed over the
         active slots.  The lane's first record also says which
-        attention the engine's step program was built with."""
+        attention the engine's step program was built with and how many
+        layers keep K/V and how many a per-slot state."""
         t_lock, t_disp0, t_disp1, t_tok = stamps
         if not self._said_attention:
             self._said_attention = True
             extra["decode_attention"] = getattr(
                 self.r.engine, "decode_attention", None)
+            extra.update(_cache_layers(self.r.engine))
         tracing.lane_record(
             "decode.tick", replica=self.r.index, seq=seq,
             n_active=len(ids), n_adopted=self._n_adopted,
@@ -783,11 +802,21 @@ class Replica:
                 num_slots=num_slots, int8=int8, kv_mode="slots",
                 mesh=mesh, partition_rules=partition_rules,
                 replica_id=self.index)
+        spec = self.engine.cache_spec
+        itemsize = self.engine.cache_itemsize
         self.mgr = PagedKVCacheManager(
             num_slots, policy.max_length,
             num_blocks=self.engine.num_blocks,
-            block_size=self.engine.block_size)
+            block_size=self.engine.block_size,
+            kv_bytes_per_block=spec.kv_bytes_per_block(
+                self.engine.block_size, itemsize),
+            state_bytes_per_slot=spec.state_bytes_per_slot(itemsize))
         self.radix = None
+        if radix_cache and spec.state_layers:
+            raise MXNetError(
+                "radix_cache=True shares a prompt prefix's K/V blocks; a "
+                "model with per-slot state also needs a snapshot of the "
+                "state at the prefix boundary, which nothing keeps")
         if radix_cache:
             from .radix import RadixPrefixCache
             cap = int(prefix_cache_tokens

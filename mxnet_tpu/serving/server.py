@@ -7,9 +7,11 @@ Two server classes over one contract (bounded queue → scheduler thread
   request): a ``Predictor`` (the MXPredCreate surface), a hybridized
   gluon block (e.g. BERT), or any callable.  Dynamic batching with
   power-of-two batch/length buckets.
-* :class:`GenerativeServer` — ``LlamaForCausalLM`` decode with the
-  sliced KV cache: requests join and leave the in-flight decode batch
-  between steps (continuous batching).
+* :class:`GenerativeServer` — decode with the paged cache for any
+  model that gives the engine a decoder (``serving_decoder(max_len)``:
+  the paged step and prefill programs) and a cache spec (which layers
+  keep K/V blocks, which a per-slot state): requests join and leave
+  the in-flight decode batch between steps (continuous batching).
 
 ``ServerConfig(int8=True)`` applies weight quantization at load time:
 gluon blocks go through ``contrib.quantization.quantize_net`` (needs
@@ -334,7 +336,10 @@ def _split_mesh(mesh, dp_axis="dp"):
 
 
 class GenerativeServer(_ServerBase):
-    """Continuous-batching decode server for ``LlamaForCausalLM``.
+    """Continuous-batching decode server for a causal LM that answers
+    ``serving_decoder(max_len)`` (``LlamaForCausalLM``,
+    ``Lfm2MoeForCausalLM``); the engine asks it for the decoder's paged
+    programs and its cache spec, the lanes for nothing.
 
     Mesh-native: ``mesh=`` places the weights (and the KV pool)
     tensor-parallel per ``partition_rules=`` (default: the
@@ -627,7 +632,16 @@ class GenerativeServer(_ServerBase):
                 reps[0].engine.compiled_signatures(),
             "decode_attention": reps[0].engine.decode_attention,
             "num_replicas": len(reps),
+            "kv_layers": reps[0].engine.cache_spec.kv_layers,
+            "state_layers": reps[0].engine.cache_spec.state_layers,
         }
+        if reps[0].engine.cache_spec.expert_layers:
+            # what the lane log's experts_touched / expert_rows_max add
+            # up to, over every step and prefill program
+            tots = [r.engine.expert_totals for r in reps]
+            out["experts"] = {
+                k: (max if k == "expert_rows_max" else sum)(
+                    t[k] for t in tots) for k in tots[0]}
         if len(reps) > 1:
             out["replicas"] = [{
                 "completed": r.completed,

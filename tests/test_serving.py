@@ -607,3 +607,48 @@ def test_generative_server_mesh_dp2_tp2_token_exact():
         net.config.num_layers, net.config.num_kv_heads,
         net.config.head_dim, num_blocks=eng.num_blocks,
         block_size=eng.block_size, mesh=tp_mesh)
+
+
+# --- two kinds of per-request state in one ledger ----------------------------
+
+@pytest.mark.parametrize("layers,state_shape", [
+    (("kv", "kv"), None),                               # a Llama: K/V only
+    (("state", "kv", "state", "state"), (3, 64)),       # conv states beside
+    (("state", "state", "kv", "kv", "state"), (2, 8, 4)),
+])
+def test_paged_manager_prices_blocks_and_slot_state(layers, state_shape):
+    """``CacheSpec`` says which layers own K/V blocks and which a fixed
+    per-slot state; the manager reports bytes of both kinds, blocks over
+    the K/V layers only."""
+    from mxnet_tpu.serving import PagedKVCacheManager
+    from mxnet_tpu.serving.kv_cache import CacheSpec
+
+    spec = CacheSpec(layers, num_kv_heads=2, head_dim=16,
+                     state_shape=state_shape)
+    n_kv, n_state = layers.count("kv"), layers.count("state")
+    assert (spec.kv_layers, spec.state_layers) == (n_kv, n_state)
+    per_block = 2 * n_kv * 2 * 4 * 16 * 2
+    per_slot = n_state * int(np.prod(state_shape or (0,))) * 2
+    assert spec.kv_bytes_per_block(block_size=4, itemsize=2) == per_block
+    assert spec.state_bytes_per_slot(itemsize=2) == per_slot
+    mgr = PagedKVCacheManager(3, 32, num_blocks=16, block_size=4,
+                              kv_bytes_per_block=per_block,
+                              state_bytes_per_slot=per_slot)
+    mgr.admit("a", 5, 3)          # 2 blocks
+    mgr.admit("b", 9, 4)          # 4 blocks
+    st = mgr.stats()
+    assert st["kv_block_bytes_in_use"] == 6 * per_block
+    assert st["state_bytes_per_slot"] == per_slot
+    assert st["state_bytes_in_use"] == 2 * per_slot
+    mgr.evict(mgr.active_slots()[0])
+    assert mgr.stats()["state_bytes_in_use"] == per_slot
+    mgr.check()
+
+
+def test_cache_spec_refuses_what_it_cannot_price():
+    from mxnet_tpu.serving.kv_cache import CacheSpec
+
+    with pytest.raises(mx.MXNetError, match="unknown cache kind"):
+        CacheSpec(("kv", "latent"), 2, 16)
+    with pytest.raises(mx.MXNetError, match="state_shape"):
+        CacheSpec(("kv", "state"), 2, 16)
