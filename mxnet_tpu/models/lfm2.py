@@ -208,9 +208,13 @@ class Lfm2Math:
             ctx = self._attend(q, k, v, causal).transpose(0, 2, 1, 3)
             return ctx.reshape(b, t, nq * hd) @ p["o"].T, (k, v)
         (kp, vp), s = view.entry, lead[0]
-        heads = jnp.arange(nkv)[None, :]
-        kp = kp.at[view.blk, heads, view.off].set(k, mode="drop")
-        vp = vp.at[view.blk, heads, view.off].set(v, mode="drop")
+        # whole stored rows: ``nkv`` of hd, or packed for the kernel
+        rows, lanes = kp.shape[1], kp.shape[3]
+        heads = jnp.arange(rows)[None, :]
+        kp = kp.at[view.blk, heads, view.off].set(
+            k.reshape(s, rows, lanes), mode="drop")
+        vp = vp.at[view.blk, heads, view.off].set(
+            v.reshape(s, rows, lanes), mode="drop")
         ctx = view.attend(q, kp, vp)
         return ctx.reshape(s, nq * hd) @ p["o"].T, (kp, vp)
 
@@ -386,11 +390,13 @@ class Lfm2Decoder(Lfm2Math):
         starts with the sentinel)."""
         import jax.numpy as jnp
 
-        from ..ops.paged_attention import paged_decode_attention
+        from ..ops.paged_attention import (gathered_view,
+                                           paged_decode_attention)
 
         kv = next(e for e in cache if isinstance(e, tuple))
-        nb, hkv, bs, hd = kv[0].shape
-        s, t = tables.shape[0], tables.shape[1] * bs
+        nb, _, bs, lanes = kv[0].shape              # as stored
+        pack = lanes // self.cfg.head_dim
+        t = tables.shape[1] * bs
         pos = jnp.asarray(pos, jnp.int32)
         gat = jnp.minimum(tables, nb - 1)           # clamp the sentinel
         mask = (jnp.arange(t)[None, :]
@@ -399,8 +405,7 @@ class Lfm2Decoder(Lfm2Math):
         def gathered(q, kp, vp):
             # the Llama step's gather path: a dense view of every slot's
             # blocks through the clamped table, masked at its length
-            kc = kp[gat].transpose(0, 2, 1, 3, 4).reshape(s, hkv, t, hd)
-            vc = vp[gat].transpose(0, 2, 1, 3, 4).reshape(s, hkv, t, hd)
+            kc, vc = (gathered_view(p, gat, pack) for p in (kp, vp))
             return self._attend(q[:, :, None, :], kc, vc, mask)
 
         def in_place(q, kp, vp):

@@ -757,7 +757,9 @@ class LlamaDecoder:
         position continuous-batching contract as
         :meth:`_step_slots_impl`, but K/V storage is block-granular.
         ``pools[l]`` is ``(kp, vp)`` each ``(num_blocks, Hkv,
-        block_size, hd)`` shared by every slot; ``tables`` (S, MB)
+        block_size, hd)`` shared by every slot (or packed for the
+        kernel, ``ops.paged_attention.pack_rows``: ``(num_blocks,
+        Hkv // pack, block_size, pack * hd)``); ``tables`` (S, MB)
         int32 holds each slot's block ids in logical order, vacant
         entries = ``num_blocks``.  The step scatters each slot's new
         K/V at ``(tables[s, pos//bs], pos%bs)`` — the sentinel id is
@@ -777,12 +779,16 @@ class LlamaDecoder:
         attention over clamped garbage; neither is ever read."""
         import jax.numpy as jnp
 
-        from ..ops.paged_attention import paged_decode_attention
+        from ..ops.paged_attention import (gathered_view,
+                                           paged_decode_attention)
 
         cfg = self.cfg
         hd = cfg.head_dim
         s = ids_t.shape[0]
-        nb, hkv, bs, _ = pools[0][0].shape
+        # as stored: ``pack`` KV heads to a row of ``lanes`` (1, hd
+        # unless the engine packed the pool for the kernel)
+        nb, hkv, bs, lanes = pools[0][0].shape
+        pack = lanes // hd
         mb = tables.shape[1]
         t = mb * bs
         pos = jnp.asarray(pos, jnp.int32)
@@ -794,10 +800,11 @@ class LlamaDecoder:
         blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
                                   axis=1)           # (S,1) physical block
         off = (pos % bs)[:, None]
-        # the new row is written head by head, (block, head, offset) ->
-        # hd contiguous values: a scatter with the heads as a window
-        # makes XLA:TPU re-lay the whole pool, in and out, every layer
-        heads = jnp.arange(hkv)[None, :]            # (1,Hkv)
+        # the new row is written head row by head row, (block, row,
+        # offset) -> ``lanes`` contiguous values: a scatter with the
+        # heads as a window makes XLA:TPU re-lay the whole pool, in and
+        # out, every layer
+        heads = jnp.arange(hkv)[None, :]            # (1,rows)
         gat = jnp.minimum(tables, nb - 1)           # clamp the sentinel
         new_pools = []
         for L, (kp, vp) in zip(w["layers"], pools):
@@ -808,17 +815,17 @@ class LlamaDecoder:
                 v = (h @ L["v"].T).reshape(s, cfg.num_kv_heads, 1, hd)
                 q = _apply_rope(q, cos, sin)
                 k = _apply_rope(k, cos, sin)
-                kp2 = kp.at[blk, heads, off].set(k[:, :, 0, :], mode="drop")
-                vp2 = vp.at[blk, heads, off].set(v[:, :, 0, :], mode="drop")
+                kp2 = kp.at[blk, heads, off].set(
+                    k[:, :, 0, :].reshape(s, hkv, lanes), mode="drop")
+                vp2 = vp.at[blk, heads, off].set(
+                    v[:, :, 0, :].reshape(s, hkv, lanes), mode="drop")
                 new_pools.append((kp2, vp2))
                 if paged_kernel:
                     ctx = paged_decode_attention(q[:, :, 0, :], kp2, vp2,
                                                  tables, pos + 1)
                 else:
-                    kc = kp2[gat].transpose(0, 2, 1, 3, 4) \
-                        .reshape(s, hkv, t, hd)
-                    vc = vp2[gat].transpose(0, 2, 1, 3, 4) \
-                        .reshape(s, hkv, t, hd)
+                    kc, vc = (gathered_view(p, gat, pack)
+                              for p in (kp2, vp2))
                     ctx = self._attend(q, kc, vc, mask)
                 return ctx.reshape(s, cfg.num_heads * hd) @ L["o"].T
 
@@ -853,12 +860,14 @@ class LlamaDecoder:
         ``pos0 + j + 1`` rows."""
         import jax.numpy as jnp
 
-        from ..ops.paged_attention import paged_decode_attention
+        from ..ops.paged_attention import (gathered_view,
+                                           paged_decode_attention)
 
         cfg = self.cfg
         hd = cfg.head_dim
         s, kk = toks.shape
-        nb, hkv, bs, _ = pools[0][0].shape
+        nb, hkv, bs, lanes = pools[0][0].shape      # as stored
+        pack = lanes // hd
         mb = tables.shape[1]
         t = mb * bs
         pos0 = jnp.asarray(pos0, jnp.int32)
@@ -875,7 +884,7 @@ class LlamaDecoder:
         blk = jnp.where(pw < jnp.int32(self.max_len), blk,
                         nb)[:, :, None]                     # (S,K,1)
         off = (pw % bs)[:, :, None]
-        heads = jnp.arange(hkv)[None, None, :]              # (1,1,Hkv)
+        heads = jnp.arange(hkv)[None, None, :]              # (1,1,rows)
         gat = jnp.minimum(tables, nb - 1)
         new_pools = []
         for L, (kp, vp) in zip(w["layers"], pools):
@@ -889,22 +898,22 @@ class LlamaDecoder:
                     .transpose(0, 2, 1, 3)
                 q = _apply_rope(q, cos, sin)
                 k = _apply_rope(k, cos, sin)
-                # scatter indices (S,K,Hkv) pair with update
-                # (S,K,Hkv,hd): rows of hd, as in the step
+                # scatter indices (S,K,rows) pair with update
+                # (S,K,rows,lanes): whole stored rows, as in the step
                 kp2 = kp.at[blk, heads, off].set(
-                    k.transpose(0, 2, 1, 3), mode="drop")
+                    k.transpose(0, 2, 1, 3).reshape(s, kk, hkv, lanes),
+                    mode="drop")
                 vp2 = vp.at[blk, heads, off].set(
-                    v.transpose(0, 2, 1, 3), mode="drop")
+                    v.transpose(0, 2, 1, 3).reshape(s, kk, hkv, lanes),
+                    mode="drop")
                 new_pools.append((kp2, vp2))
                 if paged_kernel:
                     ctx = paged_decode_attention(
                         q.transpose(0, 2, 1, 3), kp2, vp2, tables,
                         pos0 + 1)                       # (S,K,H,hd)
                 else:
-                    kc = kp2[gat].transpose(0, 2, 1, 3, 4) \
-                        .reshape(s, hkv, t, hd)
-                    vc = vp2[gat].transpose(0, 2, 1, 3, 4) \
-                        .reshape(s, hkv, t, hd)
+                    kc, vc = (gathered_view(p, gat, pack)
+                              for p in (kp2, vp2))
                     ctx = self._attend(q, kc, vc, mask) \
                         .transpose(0, 2, 1, 3)
                 return ctx.reshape(s, kk, cfg.num_heads * hd) @ L["o"].T

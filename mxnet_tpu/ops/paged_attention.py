@@ -31,6 +31,20 @@ The speculative verify is the same kernel with ``K`` query columns a
 slot: column ``j`` sees ``lengths[s] + j`` rows, and the ``K * H / Hkv``
 rows of a KV head share its keys.
 
+Heads narrower than a 128-lane row (``head_dim`` 64) are stored packed:
+``pack = 128 // head_dim`` consecutive KV heads share one lane row, the
+pool is ``(num_blocks, Hkv // pack, block_size, pack * head_dim)``
+(:func:`pack_rows` / :func:`unpack_rows`: the same bytes, and the
+row-major layout a minor dimension of 128 gets; at 64 the device would
+put the blocks minor-most and re-lay the whole pool around every use).
+The kernel is the same: to it a lane row is one KV head of 128.  The
+wrapper lays the queries of a lane row's heads block-diagonally (head
+``p``'s query in lanes ``[p * head_dim, (p + 1) * head_dim)``, zeros
+elsewhere), so one product against the key tile gives every head's
+scores and each output row's own lanes are its context.  Half of the
+matrix unit's work multiplies zeros; decode attention is bound by
+bytes.
+
 Rows of an owned block past the slot's length are fetched with their
 block and meet probability 0, as in the gather path: both count on the
 pool holding finite numbers (it is born zero and only ever written with
@@ -60,7 +74,9 @@ __compile_signatures__ = {
 #: 260-350 GB/s, 32 read 360 (58 slots of 100-500 tokens) to 550 (full
 #: slots), 64 read 310 to 680: a chunk computes its unused tail, so the
 #: widest loses on short slots.  At 32, K and V double-buffered take
-#: 4 MiB of VMEM.
+#: 4 MiB of VMEM.  At 8 KV heads of 64, two to a lane row (PR 27), the
+#: order is the same at half the bytes a block: 196 / 252 / 226 GB/s on
+#: 115 slots of 100-500 tokens, 266 / 376 / 453 on 128 full slots.
 BLOCKS_PER_CHUNK = 32
 
 
@@ -70,14 +86,51 @@ def _sublane_tile(dtype):
     return 32 // np.dtype(dtype).itemsize
 
 
-def applicable(platform, mesh, head_dim, block_size, dtype):
+def applicable(platform, mesh, head_dim, num_kv_heads, block_size, dtype):
     """Whether the kernel can stand in for the gather path, from what
     the caller observes: the platform its pool lives on, the engine's
     mesh (a tp-sharded pool would need a ``shard_map`` wrapper: it keeps
-    the gather path), and the shapes Mosaic tiles without padding."""
-    return (platform == "tpu" and mesh is None
-            and head_dim % 128 == 0
-            and block_size % _sublane_tile(dtype) == 0)
+    the gather path), and the shapes Mosaic tiles without padding.
+    Returns ``pack``, the KV heads the pool then stores to a 128-lane
+    row (1 at heads of 128 and wider), or 0: the gather path and the
+    unpacked pool."""
+    pack = max(1, 128 // head_dim)
+    ok = (platform == "tpu" and mesh is None
+          and head_dim >= 64 and (pack * head_dim) % 128 == 0
+          and num_kv_heads % pack == 0
+          and block_size % _sublane_tile(dtype) == 0)
+    return pack if ok else 0
+
+
+def pack_rows(a, pack):
+    """Logical K/V rows ``(.., Hkv, n, hd)`` as a packed pool stores
+    them, ``(.., Hkv // pack, n, pack * hd)``: head ``r * pack + p`` in
+    lanes ``[p * hd, (p + 1) * hd)`` of head row ``r``."""
+    if pack == 1:
+        return a
+    *lead, hkv, n, hd = a.shape
+    return jnp.moveaxis(a.reshape(*lead, hkv // pack, pack, n, hd), -3, -2) \
+        .reshape(*lead, hkv // pack, n, pack * hd)
+
+
+def unpack_rows(a, pack):
+    """:func:`pack_rows` undone: ``(.., R, n, pack * hd)`` ->
+    ``(.., R * pack, n, hd)``."""
+    if pack == 1:
+        return a
+    *lead, r, n, lanes = a.shape
+    return jnp.moveaxis(a.reshape(*lead, r, n, pack, lanes // pack), -2, -3) \
+        .reshape(*lead, r * pack, n, lanes // pack)
+
+
+def gathered_view(pool, gat, pack):
+    """The gather path's dense view of a stored pool through block ids
+    ``gat`` (S, MB), the sentinel already clamped: (S, Hkv, MB * bs,
+    hd), one head a row whatever ``pack`` the pool was stored with."""
+    s, mb = gat.shape
+    _, rows, bs, lanes = pool.shape
+    return unpack_rows(pool[gat].transpose(0, 2, 1, 3, 4)
+                       .reshape(s, rows, mb * bs, lanes), pack)
 
 
 def _schedule(tables, lengths, num_blocks, block_size, chunk):
@@ -212,7 +265,8 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
     """Decode attention of one new token per slot over a paged KV pool.
 
     ``q`` (S, H, hd) after RoPE; ``k_pool`` / ``v_pool`` ``(num_blocks,
-    Hkv, block_size, hd)`` holding the new token's row already;
+    Hkv // pack, block_size, pack * hd)`` holding the new token's row
+    already (``pack`` is read off the shapes: 1 at heads of 128);
     ``tables`` (S, MB) int32 block ids in logical order, vacant entries
     = ``num_blocks``; ``lengths`` (S,) int32 rows to attend
     (``pos + 1``).  Returns (S, H, hd) in ``q``'s dtype; a slot with no
@@ -225,17 +279,25 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
 
     cols = q.shape[1] if q.ndim == 4 else 1
     s, h, hd = q.shape[0], q.shape[-2], q.shape[-1]
-    nb, hkv, bs, _ = k_pool.shape
+    nb, hkv, bs, lanes = k_pool.shape
+    pack = lanes // hd
     mb = tables.shape[1]
+    # query heads of a lane row, column by column: the kernel's "group"
     g = h // hkv
     chunk = int(min(blocks_per_chunk, mb))
-    # the rows of a KV head's score tile are its query heads, column by
-    # column; padded up to whole sublane tiles of the operand dtype
+    # the rows of a lane row's score tile are (column, query head);
+    # padded up to whole sublane tiles of the operand dtype
     rows = cols * g
     tile = _sublane_tile(q.dtype)
     gp = -(-rows // tile) * tile
-    qg = q.reshape(s, cols, hkv, g, hd).transpose(0, 2, 1, 3, 4) \
-        .reshape(s, hkv, rows, hd)
+    qg = q.reshape(s, cols, hkv, g, hd).transpose(0, 2, 1, 3, 4)
+    if pack > 1:
+        # block-diagonal: the g / pack query heads of KV head p keep
+        # lanes [p * hd, (p + 1) * hd) and are zero in the others'
+        own = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+        qg = (qg.reshape(s, hkv, cols, pack, g // pack, 1, hd) * own) \
+            .reshape(s, hkv, cols, g, lanes)
+    qg = qg.reshape(s, hkv, rows, lanes)
     if gp != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rows), (0, 0)))
     tables = jnp.asarray(tables, jnp.int32)
@@ -243,7 +305,7 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
     # nothing to attend means nothing to read, whatever the row owns
     last = jnp.where(lengths > 0, lengths + (cols - 1), 0)
     nblk, par, nxt = _schedule(tables, last, nb, bs, chunk)
-    qspec = pl.BlockSpec((1, hkv, gp, hd), lambda i, *_: (i, 0, 0, 0))
+    qspec = pl.BlockSpec((1, hkv, gp, lanes), lambda i, *_: (i, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, max_blocks=mb, group=g,
                           scale=1.0 / float(np.sqrt(hd))),
@@ -255,15 +317,15 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=qspec,
             scratch_shapes=[
-                pltpu.VMEM((2, chunk, hkv, bs, hd), k_pool.dtype),
-                pltpu.VMEM((2, chunk, hkv, bs, hd), v_pool.dtype),
+                pltpu.VMEM((2, chunk, hkv, bs, lanes), k_pool.dtype),
+                pltpu.VMEM((2, chunk, hkv, bs, lanes), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((hkv, gp, 1), jnp.float32),    # running max
                 pltpu.VMEM((hkv, gp, 1), jnp.float32),    # running sum
-                pltpu.VMEM((hkv, gp, hd), jnp.float32),   # accumulator
+                pltpu.VMEM((hkv, gp, lanes), jnp.float32),  # accumulator
             ]),
-        out_shape=jax.ShapeDtypeStruct((s, hkv, gp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, hkv, gp, lanes), q.dtype),
         # slots run in order: the copies of a slot's first chunk are
         # started by the slot before it
         compiler_params=pltpu.CompilerParams(
@@ -271,9 +333,14 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
         name="paged_decode_attention",
         interpret=interpret,
     )(lengths, nblk, par, nxt, tables.reshape(-1), qg, k_pool, v_pool)
-    out = out[:, :, :rows].reshape(s, hkv, cols, g, hd) \
-        .transpose(0, 2, 1, 3, 4)
-    return out.reshape(q.shape)
+    out = out[:, :, :rows].reshape(s, hkv, cols, g, lanes)
+    if pack > 1:
+        # a row's context is in its own KV head's lanes
+        out = out.reshape(s, hkv, cols, pack, g // pack, pack, hd)
+        out = jnp.stack([out[:, :, :, p, :, p] for p in range(pack)],
+                        axis=3)
+    return out.reshape(s, hkv, cols, g, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(q.shape)
 
 
 #: jitted, so that the layers of a step program share one trace and one

@@ -13,7 +13,9 @@ through the step like them, and is written whole at admission.  Two
 storage modes share one surface (``kv_mode=``):
 
 * **paged** (default since r11) — K/V lives in a shared block pool per
-  layer, ``(num_blocks, Hkv, block_size, head_dim)``; each slot carries
+  layer, ``(num_blocks, Hkv, block_size, head_dim)`` (under the paged
+  kernel at heads of 64, two KV heads to a 128-lane row: ``(num_blocks,
+  Hkv // 2, block_size, 128)``, ``kv_pack``); each slot carries
   a block-table row (vacant entries = ``num_blocks``, the out-of-bounds
   sentinel XLA's scatter rule DROPS).  Capacity is bounded by tokens in
   flight, not ``max_len × num_slots``.  Programs: **step**
@@ -223,8 +225,20 @@ class LlamaServingEngine:
             self.max_blocks = -(-self.max_len // self.block_size)
             self.num_blocks = int(num_blocks or
                                   self.num_slots * self.max_blocks)
-            pshape = (self.num_blocks, spec.num_kv_heads, self.block_size,
-                      spec.head_dim)
+            from ..ops import paged_attention
+
+            # decided once, before the pool is made, from where the
+            # weights (and so the pool) live, the mesh and the shapes:
+            # the kernel reads whole 128-lane rows, so under it heads of
+            # 64 are stored ``kv_pack`` = 2 to a row, (blocks, Hkv // 2,
+            # bs, 128); the gather path keeps one head a row
+            pack = paged_attention.applicable(
+                next(iter(w["emb"].devices())).platform, mesh,
+                spec.head_dim, spec.num_kv_heads, self.block_size, dt)
+            paged_kernel = pack > 0
+            self.kv_pack = pack = max(1, pack)
+            pshape = (self.num_blocks, spec.num_kv_heads // pack,
+                      self.block_size, pack * spec.head_dim)
             # one entry a layer, by the spec: a (K, V) pool pair, or the
             # layer's per-slot state
             self._pool = [
@@ -237,6 +251,7 @@ class LlamaServingEngine:
             self._caches = None
         else:
             self.block_size = self.num_blocks = self.max_blocks = None
+            paged_kernel, self.kv_pack = False, 1
             shape = (self.num_slots, cfg.num_kv_heads, self.max_len,
                      cfg.head_dim)
             self._caches = [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
@@ -265,19 +280,11 @@ class LlamaServingEngine:
         # one signature per numerics mode (rebuild the engine to toggle)
         self._numerics = _numerics.trace_enabled()
         numerics_on = self._numerics
-        paged_kernel = False
-        if kv_mode == "paged":
-            from ..ops import paged_attention
-
-            kp0 = next(e for e in self._pool if isinstance(e, tuple))[0]
-            paged_kernel = paged_attention.applicable(
-                next(iter(kp0.devices())).platform, mesh, spec.head_dim,
-                self.block_size, kp0.dtype)
         #: which attention the step and verify programs were built
         #: with: "paged_kernel" (ops/paged_attention.py reads the pool
         #: in place) or "gather" (a dense per-slot view through the
-        #: table).  Decided here, once, from where the pool lives, the
-        #: mesh and the shapes.
+        #: table); ``kv_pack`` beside it says how many KV heads a stored
+        #: row holds (above 1 only under the kernel)
         self.decode_attention = "paged_kernel" if paged_kernel else "gather"
         #: per-expert row counts that ride behind the tokens of every
         #: step and prefill fetch (0: the model routes nothing)
@@ -328,21 +335,12 @@ class LlamaServingEngine:
             def _gather_fn(pools, rows_idx):
                 # rows_idx (KB, NBP) int32 physical block ids in logical
                 # order, sentinel-padded — dense per-row prefix K/V
-                # copies (KB, Hkv, NBP*bs, hd) for the suffix prefill;
-                # sentinel entries clamp to garbage rows the suffix
-                # mask (t < s0) never exposes
-                kb_, nbp_ = rows_idx.shape
+                # copies (KB, Hkv, NBP*bs, hd), unpacked, for the suffix
+                # prefill; sentinel entries clamp to garbage rows the
+                # suffix mask (t < s0) never exposes
                 g = jnp.minimum(rows_idx, nb_total - 1)
-                out = []
-                for kp, vp in pools:
-                    out.append((
-                        kp[g].transpose(0, 2, 1, 3, 4)
-                        .reshape(kb_, kp.shape[1], nbp_ * self.block_size,
-                                 kp.shape[3]),
-                        vp[g].transpose(0, 2, 1, 3, 4)
-                        .reshape(kb_, vp.shape[1], nbp_ * self.block_size,
-                                 vp.shape[3])))
-                return out
+                return [tuple(paged_attention.gathered_view(p, g, pack)
+                              for p in pair) for pair in pools]
 
             def _prefill_sfx_fn(wq, pre_kv, ids, t0, s0):
                 rows, logits = dec._prefill_suffix_impl(
@@ -367,12 +365,14 @@ class LlamaServingEngine:
                         out.append(entry.at[slots].set(row, mode="drop"))
                         continue
                     (kp, vp), (k, v) = entry, row
-                    kb, hkv, lp, hd = k.shape
+                    kb, lp = k.shape[0], k.shape[2]
+                    _, hkv, _, hd = kp.shape        # as stored
                     nbp = flat_idx.shape[0] // kb
                     pad = ((0, 0), (0, 0), (0, nbp * bs - lp), (0, 0))
 
                     def chunk(a):
-                        return jnp.pad(a, pad) \
+                        return jnp.pad(paged_attention.pack_rows(a, pack),
+                                       pad) \
                             .reshape(kb, hkv, nbp, bs, hd) \
                             .transpose(0, 2, 1, 3, 4) \
                             .reshape(kb * nbp, hkv, bs, hd)

@@ -626,13 +626,15 @@ class DecodeLane:
         """The turn's ``decode.tick`` record, its bookkeeping done.
         ``kv_tokens``: K/V rows the step attended, summed over the
         active slots.  The lane's first record also says which
-        attention the engine's step program was built with and how many
-        layers keep K/V and how many a per-slot state."""
+        attention the engine's step program was built with, how many KV
+        heads a stored pool row holds (``kv_pack``) and how many layers
+        keep K/V and how many a per-slot state."""
         t_lock, t_disp0, t_disp1, t_tok = stamps
         if not self._said_attention:
             self._said_attention = True
             extra["decode_attention"] = getattr(
                 self.r.engine, "decode_attention", None)
+            extra["kv_pack"] = getattr(self.r.engine, "kv_pack", None)
             extra.update(_cache_layers(self.r.engine))
         tracing.lane_record(
             "decode.tick", replica=self.r.index, seq=seq,

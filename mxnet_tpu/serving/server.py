@@ -614,6 +614,7 @@ class GenerativeServer(_ServerBase):
                 "kv_cache": self._sched.mgr.stats(),
                 "compiled_signatures": self.engine.compiled_signatures(),
                 "decode_attention": self.engine.decode_attention,
+                "kv_pack": self.engine.kv_pack,
             }
             telemetry.gauge("serving.kv_occupancy",
                             out["kv_cache"]["occupancy"])
@@ -631,6 +632,7 @@ class GenerativeServer(_ServerBase):
             "compiled_signatures":
                 reps[0].engine.compiled_signatures(),
             "decode_attention": reps[0].engine.decode_attention,
+            "kv_pack": reps[0].engine.kv_pack,
             "num_replicas": len(reps),
             "kv_layers": reps[0].engine.cache_spec.kv_layers,
             "state_layers": reps[0].engine.cache_spec.state_layers,

@@ -66,11 +66,11 @@ def _ref_cfg(cfg):
             "initializer_range": 0.3, "torch_dtype": "float32"}
 
 
-def _net_and_weights(ref, seed=3):
+def _net_and_weights(ref, seed=3, **overrides):
     """A tiny net filled with the reference's seeded weights (Normal(0,
     0.3), so that routing and attention are far from uniform) -> (net,
     the reference's weight tree)."""
-    net = lfm2.lfm2_moe_tiny()
+    net = lfm2.lfm2_moe_tiny(**overrides)
     net.initialize()
     cfg = _ref_cfg(net.config)
     key = jax.random.PRNGKey(seed)
@@ -167,7 +167,8 @@ def _teacher_forced_logits(eng, seq, t0, slot=0):
     through the engine's own programs, reading the LOGITS of every
     position from the prefill's last row on (the served programs return
     tokens; the decoder underneath gives the logits they are the argmax
-    of).  -> (len(seq) - t0 + 1, vocab)."""
+    of), with the decode attention the engine chose.
+    -> (len(seq) - t0 + 1, vocab)."""
     dec, w = eng._dec, eng._w
     lb = max(8, 1 << (t0 - 1).bit_length())
     ids = np.zeros((1, lb), np.int32)
@@ -184,7 +185,8 @@ def _teacher_forced_logits(eng, seq, t0, slot=0):
         ids_t[slot], pos[slot] = seq[t], t
         lg, eng._pool, _c = dec._step_blocks_impl(
             w, eng._pool, jnp.asarray(eng._tables), jnp.asarray(ids_t),
-            jnp.asarray(pos))
+            jnp.asarray(pos),
+            paged_kernel=eng.decode_attention == "paged_kernel")
         out.append(np.asarray(lg)[slot])
     return np.stack(out)
 
@@ -259,6 +261,71 @@ def test_step_rerun_at_one_position_leaves_the_state_alone(tiny):
     for a, b in zip(jax.tree_util.tree_leaves(pool1),
                     jax.tree_util.tree_leaves(pool2)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# --- heads of 64: two KV heads to a lane row, read by the paged kernel -----------
+
+def _as_on_a_chip(patch):
+    """Steer an engine built here as a TPU would: ``applicable`` sees
+    the platform ``tpu``, and the kernel runs through the interpreter."""
+    import functools
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from mxnet_tpu.ops import paged_attention as pa
+
+    real = pa.applicable
+    patch.setattr(pa, "applicable",
+                  lambda platform, *rest: real("tpu", *rest))
+    patch.setattr(pa, "paged_decode_attention", functools.partial(
+        pa._paged_decode_attention, interpret=pltpu.InterpretParams()))
+
+
+@pytest.fixture(scope="module")
+def tiny_hd64(ref):
+    """The tiny net with heads of 64: hidden 256, 4 query / 2 KV heads."""
+    return _net_and_weights(ref, seed=4, hidden_size=256)[0]
+
+
+def test_engine_at_heads_of_64_here_stores_one_head_a_row(tiny_hd64):
+    eng = _server(tiny_hd64, block_size=8).engine
+    assert tiny_hd64.config.head_dim == 64
+    assert (eng.decode_attention, eng.kv_pack) == ("gather", 1)
+    shapes = {e[0].shape for e in eng._pool if isinstance(e, tuple)}
+    assert shapes == {(eng.num_blocks, 2, 8, 64)}
+
+
+@pytest.mark.parametrize("t0", [1, 7, 20])
+def test_kernel_on_a_packed_pool_equals_gather_on_the_unpacked(
+        tiny_hd64, monkeypatch, t0):
+    """Prefill, commit and every decode step on an engine built as a chip
+    builds it (``paged_kernel``, the pool ``(blocks, 1, bs, 128)``, the
+    kernel in the interpreter) against the engine this machine builds
+    (``gather``, ``(blocks, 2, bs, 64)``): the logits of every position,
+    and the pools, read back through ``unpack_rows``."""
+    from mxnet_tpu.ops.paged_attention import unpack_rows
+
+    seq = np.random.RandomState(40 + t0).randint(1, 256, size=t0 + 6)
+    plain = _server(tiny_hd64, block_size=8).engine
+    assert plain.decode_attention == "gather"
+    want = _teacher_forced_logits(plain, seq, t0)
+    with monkeypatch.context() as patch:
+        _as_on_a_chip(patch)
+        packed = _server(tiny_hd64, block_size=8).engine
+        got = _teacher_forced_logits(packed, seq, t0)
+    assert (packed.decode_attention, packed.kv_pack) == ("paged_kernel", 2)
+    shapes = {e[0].shape for e in packed._pool if isinstance(e, tuple)}
+    assert shapes == {(packed.num_blocks, 1, 8, 128)}
+    assert packed.kv_pool_bytes(by_kind=True) == \
+        plain.kv_pool_bytes(by_kind=True)
+    assert np.abs(got - want).max() < 5e-4 * np.abs(want).max()
+    for a, b in zip(packed._pool, plain._pool):
+        if not isinstance(a, tuple):
+            assert np.allclose(a, b, atol=1e-4)
+            continue
+        for stored, rows in zip(a, b):
+            assert np.allclose(unpack_rows(stored, 2), rows, atol=1e-4)
+            assert np.asarray(rows).any()
 
 
 # --- what is refused, loudly ---------------------------------------------------

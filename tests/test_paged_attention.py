@@ -1,8 +1,10 @@
 """Paged decode attention (``mxnet_tpu/ops/paged_attention.py``): the
 Pallas kernel under the TPU interpreter against ``LlamaDecoder._attend``
 on the gathered view, the step and verify programs through it, which
-path an engine picks, and a compile of the kernel for the v5e at the two
-benchmark cells' shapes (no chip: the described topology)."""
+path an engine picks, and a compile of the kernel for the v5e at the
+benchmark cells' shapes (no chip: the described topology).  Heads of 64
+run the same tests on a PACKED pool (two KV heads to a 128-lane row)
+against ``_attend`` on the gathered unpacked one."""
 import functools
 import time
 
@@ -15,7 +17,11 @@ import jax.numpy as jnp
 from mxnet_tpu.models.llama import LlamaDecoder, llama_tiny
 from mxnet_tpu.ops import paged_attention as pa
 
-BS, MB, NB, HD = 16, 12, 96, 128
+BS, MB, NB = 16, 12, 96
+
+#: head widths the bare kernel is run at: 128 (a head a lane row) and 64
+#: (two KV heads a row)
+head_dims = pytest.mark.parametrize("hd", [128, 64], ids=["hd128", "hd64"])
 
 
 def _interpret():
@@ -27,34 +33,50 @@ def _interpret():
 class _Cfg:
     """What ``_attend`` reads of a decoder's config."""
 
-    def __init__(self, heads, kv_heads):
+    def __init__(self, heads, kv_heads, hd):
         self.num_heads, self.num_kv_heads, self.head_dim = \
-            heads, kv_heads, HD
+            heads, kv_heads, hd
 
 
 def _gathered(q, kp, vp, tables, lengths, heads, kv_heads):
     """The present step's attention: the clamped gather of every slot's
     whole view, then ``_attend`` under the per-slot (and, for a verify
-    window, per-column) length mask.  q (S, H, hd) or (S, K, H, hd)."""
-    s = q.shape[0]
+    window, per-column) length mask.  q (S, H, hd) or (S, K, H, hd);
+    the pools UNPACKED, (NB, Hkv, BS, hd)."""
+    s, hd = q.shape[0], q.shape[-1]
     q4 = q[:, None] if q.ndim == 3 else q           # (S, K, H, hd)
     cols = q4.shape[1]
     gat = jnp.minimum(tables, kp.shape[0] - 1)
-    kc = kp[gat].transpose(0, 2, 1, 3, 4).reshape(s, kv_heads, -1, HD)
-    vc = vp[gat].transpose(0, 2, 1, 3, 4).reshape(s, kv_heads, -1, HD)
+    kc = kp[gat].transpose(0, 2, 1, 3, 4).reshape(s, kv_heads, -1, hd)
+    vc = vp[gat].transpose(0, 2, 1, 3, 4).reshape(s, kv_heads, -1, hd)
     bound = lengths[:, None] + jnp.arange(cols)[None, :]        # (S, K)
     mask = (jnp.arange(kc.shape[2])[None, None, :]
             < bound[:, :, None])[:, None]                       # (S,1,K,T)
     dec = LlamaDecoder.__new__(LlamaDecoder)
-    dec.cfg = _Cfg(heads, kv_heads)
+    dec.cfg = _Cfg(heads, kv_heads, hd)
     out = dec._attend(q4.transpose(0, 2, 1, 3), kc, vc, mask)   # (S,H,K,hd)
     return out.transpose(0, 2, 1, 3).reshape(q.shape)
 
 
-def _pool(rng, kv_heads):
-    shape = (NB, kv_heads, BS, HD)
+def _pool(rng, kv_heads, hd=128):
+    """An unpacked (K, V) pool pair, one head a row."""
+    shape = (NB, kv_heads, BS, hd)
     return (jnp.asarray(rng.normal(size=shape), jnp.bfloat16),
             jnp.asarray(rng.normal(size=shape), jnp.bfloat16))
+
+
+def _stored(pools, hd):
+    """The pools as an engine under the kernel stores them."""
+    return tuple(pa.pack_rows(p, max(1, 128 // hd)) for p in pools)
+
+
+def _kernel(q, kp, vp, tables, lengths, **kw):
+    """The bare kernel under the interpreter, on the stored form of the
+    unpacked pools ``kp`` / ``vp``."""
+    kp, vp = _stored((kp, vp), q.shape[-1])
+    return pa._paged_decode_attention(q, kp, vp, jnp.asarray(tables),
+                                      jnp.asarray(lengths),
+                                      interpret=_interpret(), **kw)
 
 
 def _tables(rng, lengths, shuffled=True):
@@ -80,19 +102,18 @@ CASES = {
 }
 
 
+@head_dims
 @pytest.mark.parametrize("heads,kv_heads", [(32, 8), (4, 4)],
                          ids=["gqa_32_8", "mha"])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_matches_gathered_attention(case, heads, kv_heads):
+def test_kernel_matches_gathered_attention(case, heads, kv_heads, hd):
     rng = np.random.default_rng(3)
     lengths = np.asarray(CASES[case], np.int32)
     tables = _tables(rng, lengths)
-    kp, vp = _pool(rng, kv_heads)
-    q = jnp.asarray(rng.normal(size=(len(lengths), heads, HD)),
+    kp, vp = _pool(rng, kv_heads, hd)
+    q = jnp.asarray(rng.normal(size=(len(lengths), heads, hd)),
                     jnp.bfloat16)
-    got = pa._paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                    jnp.asarray(lengths),
-                                    interpret=_interpret())
+    got = _kernel(q, kp, vp, tables, lengths)
     want = _gathered(q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
                      heads, kv_heads)
     assert got.shape == q.shape and got.dtype == q.dtype
@@ -101,19 +122,17 @@ def test_kernel_matches_gathered_attention(case, heads, kv_heads):
                                atol=2e-2, rtol=2e-2)
 
 
+@head_dims
 @pytest.mark.parametrize("chunk", [1, 3, 8, 64])
-def test_kernel_any_chunk_width(chunk):
+def test_kernel_any_chunk_width(chunk, hd):
     """The chunk is a tuning width, not part of the result: partial last
     chunks, a chunk per block and one chunk for the whole row agree."""
     rng = np.random.default_rng(4)
     lengths = np.asarray([70, 1, MB * BS, 33], np.int32)
     tables = _tables(rng, lengths)
-    kp, vp = _pool(rng, 2)
-    q = jnp.asarray(rng.normal(size=(4, 8, HD)), jnp.bfloat16)
-    got = pa._paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                    jnp.asarray(lengths),
-                                    blocks_per_chunk=chunk,
-                                    interpret=_interpret())
+    kp, vp = _pool(rng, 2, hd)
+    q = jnp.asarray(rng.normal(size=(4, 8, hd)), jnp.bfloat16)
+    got = _kernel(q, kp, vp, tables, lengths, blocks_per_chunk=chunk)
     want = _gathered(q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
                      8, 2)
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -121,7 +140,8 @@ def test_kernel_any_chunk_width(chunk):
                                atol=2e-2, rtol=2e-2)
 
 
-def test_shared_prefix_blocks():
+@head_dims
+def test_shared_prefix_blocks(hd):
     """Two slots whose rows start with the same physical blocks (a radix
     hit) and go on in blocks of their own."""
     rng = np.random.default_rng(5)
@@ -129,11 +149,9 @@ def test_shared_prefix_blocks():
     tables = np.full((2, MB), NB, np.int32)
     tables[0, :4] = [40, 7, 19, 3]
     tables[1, :4] = [40, 7, 19, 88]
-    kp, vp = _pool(rng, 2)
-    q = jnp.asarray(rng.normal(size=(2, 8, HD)), jnp.bfloat16)
-    got = pa._paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                    jnp.asarray(lengths),
-                                    interpret=_interpret())
+    kp, vp = _pool(rng, 2, hd)
+    q = jnp.asarray(rng.normal(size=(2, 8, hd)), jnp.bfloat16)
+    got = _kernel(q, kp, vp, tables, lengths)
     want = _gathered(q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
                      8, 2)
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -141,7 +159,8 @@ def test_shared_prefix_blocks():
                                atol=2e-2, rtol=2e-2)
 
 
-def test_nothing_outside_a_slots_length_is_read():
+@head_dims
+def test_nothing_outside_a_slots_length_is_read(hd):
     """Vacant slots (all-sentinel rows at pos 0, as the engine leaves
     them) beside live ones, NaN in every block no live slot reads and in
     the tail blocks a live slot owns but has not reached: the result is
@@ -156,14 +175,12 @@ def test_nothing_outside_a_slots_length_is_read():
     tables[3, 1:3] = spare[2:4]
     read = {int(b) for s in (1, 3)
             for b in tables[s, :-(-int(lengths[s]) // BS)]}
-    kp, vp = _pool(rng, 2)
+    kp, vp = _pool(rng, 2, hd)
     unread = np.asarray([b not in read for b in range(NB)])
     bad_k = jnp.where(unread[:, None, None, None], jnp.nan, kp)
     bad_v = jnp.where(unread[:, None, None, None], jnp.nan, vp)
-    q = jnp.asarray(rng.normal(size=(5, 8, HD)), jnp.bfloat16)
-    got = np.asarray(pa._paged_decode_attention(
-        q, bad_k, bad_v, jnp.asarray(tables), jnp.asarray(lengths),
-        interpret=_interpret()), np.float32)
+    q = jnp.asarray(rng.normal(size=(5, 8, hd)), jnp.bfloat16)
+    got = np.asarray(_kernel(q, bad_k, bad_v, tables, lengths), np.float32)
     want = np.asarray(_gathered(q, kp, vp, jnp.asarray(tables),
                                 jnp.asarray(lengths), 8, 2), np.float32)
     assert np.isfinite(got).all()
@@ -175,19 +192,18 @@ def test_nothing_outside_a_slots_length_is_read():
             assert not got[s].any()
 
 
+@head_dims
 @pytest.mark.parametrize("cols", [2, 4, 5])
-def test_verify_window_columns(cols):
+def test_verify_window_columns(cols, hd):
     """The speculative verify's K columns through the same kernel:
     column j attends ``lengths + j`` rows (K = 4 fills a bf16 tile at
     four query heads a KV head, 2 and 5 pad it)."""
     rng = np.random.default_rng(7)
     lengths = np.asarray([1, BS - 1, BS, 61, MB * BS - cols + 1], np.int32)
     tables = _tables(rng, lengths + cols - 1)
-    kp, vp = _pool(rng, 2)
-    q = jnp.asarray(rng.normal(size=(5, cols, 8, HD)), jnp.bfloat16)
-    got = pa._paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                    jnp.asarray(lengths),
-                                    interpret=_interpret())
+    kp, vp = _pool(rng, 2, hd)
+    q = jnp.asarray(rng.normal(size=(5, cols, 8, hd)), jnp.bfloat16)
+    got = _kernel(q, kp, vp, tables, lengths)
     want = _gathered(q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
                      8, 2)
     assert got.shape == q.shape
@@ -198,15 +214,17 @@ def test_verify_window_columns(cols):
 
 # --- the step and verify programs through the kernel -------------------------
 
-@pytest.fixture
-def wide_decoder(monkeypatch):
-    """A two-layer decoder whose heads are 128 wide (the kernel's least),
-    with the kernel routed through the interpreter."""
+@pytest.fixture(params=[(128, 2, 1), (64, 4, 2)], ids=["hd128", "hd64"])
+def wide_decoder(request, monkeypatch):
+    """A two-layer decoder whose heads are 128 wide (a head a lane row)
+    or 64 (its two KV heads packed to one), with the kernel routed
+    through the interpreter."""
+    hd, heads, kv_heads = request.param
     monkeypatch.setattr(pa, "paged_decode_attention", functools.partial(
         pa._paged_decode_attention, interpret=_interpret()))
-    net = llama_tiny(hidden_size=256, intermediate_size=256, num_heads=2,
-                     num_kv_heads=1, num_layers=2)
-    assert net.config.head_dim == HD
+    net = llama_tiny(hidden_size=256, intermediate_size=256,
+                     num_heads=heads, num_kv_heads=kv_heads, num_layers=2)
+    assert net.config.head_dim == hd
     net.initialize()
     net.cast("bfloat16")
     return LlamaDecoder(net, max_len=MB * BS)
@@ -217,22 +235,30 @@ def _step_operands(dec, rng, lengths):
     live = pos >= 0
     tables = _tables(rng, [int(n) if a else 0
                            for n, a in zip(lengths, live)])
-    kv = dec.cfg.num_kv_heads
-    pools = [_pool(rng, kv) for _ in range(dec.cfg.num_layers)]
+    cfg = dec.cfg
+    pools = [_pool(rng, cfg.num_kv_heads, cfg.head_dim)
+             for _ in range(cfg.num_layers)]
     ids = jnp.asarray(rng.integers(1, 250, size=len(lengths)), jnp.int32)
     return pools, jnp.asarray(tables), ids, \
         jnp.asarray(np.maximum(pos, 0)), live
 
 
 def test_step_program_through_kernel_matches_gather(wide_decoder):
+    """The kernel path on the pool as its engine stores it (packed at
+    heads of 64) against the gather path on the unpacked one: logits,
+    and the written pools read back through ``unpack_rows``."""
     dec = wide_decoder
+    hd = dec.cfg.head_dim
     rng = np.random.default_rng(8)
     pools, tables, ids, pos, live = _step_operands(
         dec, rng, [40, 0, BS, BS + 1, MB * BS])
     w = dec._weights()
     want, pools_g = dec._step_blocks_impl(w, pools, tables, ids, pos)
-    got, pools_k = dec._step_blocks_impl(w, pools, tables, ids, pos,
-                                         paged_kernel=True)
+    got, pools_k = dec._step_blocks_impl(
+        w, [_stored(pair, hd) for pair in pools], tables, ids, pos,
+        paged_kernel=True)
+    pools_k = [tuple(pa.unpack_rows(p, 128 // hd) for p in pair)
+               for pair in pools_k]
     # the scatter of the new row is the same XLA update on both paths:
     # bit for bit in the first layer, whose input no attention has touched
     for a, b in zip(pools_g[0], pools_k[0]):
@@ -248,8 +274,34 @@ def test_step_program_through_kernel_matches_gather(wide_decoder):
     assert (got[live].argmax(-1) == want[live].argmax(-1)).all()
 
 
+def test_gather_path_reads_a_packed_pool(wide_decoder):
+    """The step's and the verify's gather branches on a packed pool (no
+    engine builds that pair; the branch must not rot) equal themselves
+    on the unpacked one, bit for bit."""
+    dec = wide_decoder
+    hd = dec.cfg.head_dim
+    rng = np.random.default_rng(10)
+    pools, tables, ids, pos, live = _step_operands(
+        dec, rng, [40, 0, BS, BS + 1, MB * BS - 3])
+    packed = [_stored(pair, hd) for pair in pools]
+    w = dec._weights()
+    toks = jnp.asarray(rng.integers(1, 250, size=(len(live), 3)), jnp.int32)
+    for impl, arg in ((dec._step_blocks_impl, ids),
+                      (dec._verify_blocks_impl, toks)):
+        want, pools_u = impl(w, pools, tables, arg, pos)
+        got, pools_p = impl(w, packed, tables, arg, pos)
+        assert np.array_equal(np.asarray(got, np.float32)[live],
+                              np.asarray(want, np.float32)[live])
+        for pu, pp in zip(pools_u, pools_p):
+            for a, b in zip(pu, pp):
+                assert np.array_equal(
+                    np.asarray(a, np.float32),
+                    np.asarray(pa.unpack_rows(b, 128 // hd), np.float32))
+
+
 def test_verify_program_through_kernel_matches_gather(wide_decoder):
     dec = wide_decoder
+    hd = dec.cfg.head_dim
     rng = np.random.default_rng(9)
     kk = 4
     lengths = [40, 0, BS - 1, MB * BS - kk + 1]
@@ -260,28 +312,128 @@ def test_verify_program_through_kernel_matches_gather(wide_decoder):
                        jnp.int32)
     w = dec._weights()
     want, _ = dec._verify_blocks_impl(w, pools, tables, toks, pos0)
-    got, _ = dec._verify_blocks_impl(w, pools, tables, toks, pos0,
-                                     paged_kernel=True)
+    got, _ = dec._verify_blocks_impl(
+        w, [_stored(pair, hd) for pair in pools], tables, toks, pos0,
+        paged_kernel=True)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     np.testing.assert_allclose(got[live], want[live], atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("pack", [1, 2, 4])
+def test_pack_rows_round_trip(pack):
+    """Head ``r * pack + p`` lands in lanes ``[p * hd, (p + 1) * hd)`` of
+    head row ``r``, any leading axes, and ``unpack_rows`` undoes it."""
+    hd = 128 // pack
+    a = jnp.arange(3 * 8 * 5 * hd, dtype=jnp.float32).reshape(3, 8, 5, hd)
+    p = pa.pack_rows(a, pack)
+    assert p.shape == (3, 8 // pack, 5, pack * hd)
+    for head in range(8):
+        r, at = divmod(head, pack)
+        assert np.array_equal(p[:, r, :, at * hd:(at + 1) * hd], a[:, head])
+    assert np.array_equal(pa.unpack_rows(p, pack), a)
+    assert np.array_equal(pa.unpack_rows(pa.pack_rows(a[None], pack), pack),
+                          a[None])
+
+
 # --- which path an engine takes ----------------------------------------------
 
-@pytest.mark.parametrize("platform,mesh,head_dim,block,dtype,want", [
-    ("tpu", None, 128, 16, "bfloat16", True),
-    ("tpu", None, 256, 32, "bfloat16", True),
-    ("tpu", None, 128, 8, "float32", True),
-    ("cpu", None, 128, 16, "bfloat16", False),      # the tier-1 tests
-    ("tpu", "a mesh", 128, 16, "bfloat16", False),  # tp-sharded pool
-    ("tpu", None, 16, 16, "bfloat16", False),       # llama_tiny's heads
-    ("tpu", None, 64, 16, "bfloat16", False),
-    ("tpu", None, 128, 8, "bfloat16", False),       # half a bf16 tile
-    ("tpu", None, 128, 16, "int8", False),
+@pytest.mark.parametrize("platform,mesh,head_dim,kv_heads,block,dtype,want", [
+    ("tpu", None, 128, 8, 16, "bfloat16", 1),
+    ("tpu", None, 256, 8, 32, "bfloat16", 1),
+    ("tpu", None, 128, 8, 8, "float32", 1),
+    ("cpu", None, 128, 8, 16, "bfloat16", 0),       # the tier-1 tests
+    ("tpu", "a mesh", 128, 8, 16, "bfloat16", 0),   # tp-sharded pool
+    ("tpu", None, 16, 2, 16, "bfloat16", 0),        # llama_tiny's heads
+    ("tpu", None, 64, 8, 16, "bfloat16", 2),        # two heads a lane row
+    ("tpu", None, 64, 2, 16, "bfloat16", 2),
+    ("tpu", None, 64, 3, 16, "bfloat16", 0),        # an odd head left over
+    ("tpu", None, 64, 1, 16, "bfloat16", 0),
+    ("cpu", None, 64, 8, 16, "bfloat16", 0),
+    ("tpu", "a mesh", 64, 8, 16, "bfloat16", 0),
+    ("tpu", None, 32, 8, 16, "bfloat16", 0),        # four a row: not built
+    ("tpu", None, 96, 8, 16, "bfloat16", 0),        # fills no lane row
+    ("tpu", None, 256, 3, 16, "bfloat16", 1),       # no pairing needed
+    ("tpu", None, 128, 8, 8, "bfloat16", 0),        # half a bf16 tile
+    ("tpu", None, 128, 8, 16, "int8", 0),
 ])
-def test_applicable(platform, mesh, head_dim, block, dtype, want):
-    assert pa.applicable(platform, mesh, head_dim, block,
-                         jnp.dtype(dtype)) is want
+def test_applicable(platform, mesh, head_dim, kv_heads, block, dtype, want):
+    """0: the gather path; else the KV heads a stored lane row holds."""
+    got = pa.applicable(platform, mesh, head_dim, kv_heads, block,
+                        jnp.dtype(dtype))
+    assert got == want and isinstance(got, int)
+
+
+@pytest.fixture
+def as_on_a_chip(monkeypatch):
+    """Steer an engine built here as a TPU would: ``applicable`` sees
+    the platform ``tpu`` whatever the weights live on, and the kernel
+    runs through the interpreter."""
+    real = pa.applicable
+    monkeypatch.setattr(pa, "applicable",
+                        lambda platform, *rest: real("tpu", *rest))
+    monkeypatch.setattr(pa, "paged_decode_attention", functools.partial(
+        pa._paged_decode_attention, interpret=_interpret()))
+
+
+def _engine_hd64(**kw):
+    from mxnet_tpu.serving.generative import LlamaServingEngine
+
+    net = llama_tiny(hidden_size=256, intermediate_size=256, num_heads=4,
+                     num_kv_heads=2, num_layers=2)
+    net.initialize()
+    net.cast("bfloat16")
+    return LlamaServingEngine(net, max_len=64, num_slots=2, kv_mode="paged",
+                              block_size=16, **kw)
+
+
+def test_engine_at_heads_of_64_here_stores_one_head_a_row():
+    eng = _engine_hd64()
+    assert (eng.decode_attention, eng.kv_pack) == ("gather", 1)
+    assert eng._pool[0][0].shape == (8, 2, 16, 64)
+
+
+def test_packed_engine_scatter_step_gather_round_trip(as_on_a_chip):
+    """An engine whose ``applicable`` says two heads a row: prefilled rows
+    go in through the scatter, the step writes one more row, and
+    ``gather_prefix`` (the radix cache's read) returns, unpacked, the
+    rows that went in; the step through the kernel on that pool agrees
+    with the gather path on the unpacked one."""
+    eng = _engine_hd64()
+    assert (eng.decode_attention, eng.kv_pack) == ("paged_kernel", 2)
+    assert eng._pool[0][0].shape == (8, 1, 16, 128)
+    t0 = 21
+    ids = np.zeros((1, 32), np.int32)
+    ids[0, :t0] = np.random.default_rng(11).integers(1, 250, size=t0)
+    first, rows = eng.prefill_rows(ids, np.asarray([t0], np.int32))
+    blocks = [5, 2, 7, 0]                       # slot 1, shuffled
+    eng.commit_rows(rows, np.asarray([1]), [blocks], np.asarray([t0]),
+                    np.asarray(first))
+    dec, w = eng._dec, eng._w
+    tables = jnp.asarray(eng._tables)
+    ids_t = jnp.asarray([0, 9], jnp.int32)
+    pos = jnp.asarray([0, t0], jnp.int32)
+    want, pool_g = dec._step_blocks_impl(
+        w, [tuple(pa.unpack_rows(p, 2) for p in pair)
+            for pair in eng._pool], tables, ids_t, pos)
+    got, eng._pool = dec._step_blocks_impl(w, eng._pool, tables, ids_t, pos,
+                                           paged_kernel=True)
+    np.testing.assert_allclose(np.asarray(got[1], np.float32),
+                               np.asarray(want[1], np.float32),
+                               atol=3e-2, rtol=3e-2)
+    f32 = functools.partial(np.asarray, dtype=np.float32)
+    views = eng.gather_prefix(np.asarray([blocks[:2]], np.int32))
+    for l, (view, row, pair_g) in enumerate(zip(views, rows, pool_g)):
+        for g, r, u in zip(view, row, pair_g):
+            assert g.shape == (1, 2, 32, 64)
+            g, stepped = f32(g)[0], f32(u)[blocks[1], :, t0 - 16]
+            # the prefilled rows, then the step's own at position t0
+            assert np.array_equal(g[:, :t0], f32(r)[0, :, :t0])
+            assert g[:, t0].any()
+            if l == 0:      # no attention has touched the first layer's
+                assert np.array_equal(g[:, t0], stepped)
+            else:
+                np.testing.assert_allclose(g[:, t0], stepped, atol=3e-2,
+                                           rtol=3e-2)
 
 
 def test_engines_here_take_the_gather_path():
@@ -300,9 +452,9 @@ def test_engines_here_take_the_gather_path():
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     eng = LlamaServingEngine(net, max_len=64, num_slots=2, kv_mode="paged",
                              mesh=mesh)
-    assert eng.decode_attention == "gather"
+    assert eng.decode_attention == "gather" and eng.kv_pack == 1
     # the mesh alone decides it, whatever the platform and the shapes
-    assert pa.applicable("tpu", mesh, 128, 16, jnp.bfloat16) is False
+    assert pa.applicable("tpu", mesh, 128, 8, 16, jnp.bfloat16) == 0
     assert LlamaServingEngine(net, max_len=64, num_slots=2,
                               kv_mode="slots").decode_attention == "gather"
 
@@ -314,10 +466,12 @@ def test_engines_here_take_the_gather_path():
         srv.generate(prompt, max_new_tokens=4)
         stats = srv.stats()
     assert srv.engine.decode_attention == "gather"
-    assert stats["decode_attention"] == "gather"
+    assert stats["decode_attention"] == "gather" and stats["kv_pack"] == 1
     ticks = tracing.lane_log("decode.tick", since=since)
     assert ticks[0]["decode_attention"] == "gather"
-    assert all("decode_attention" not in r for r in ticks[1:])
+    assert ticks[0]["kv_pack"] == 1
+    assert all("decode_attention" not in r and "kv_pack" not in r
+               for r in ticks[1:])
     # one request: tick k wrote row len(prompt) + k - 1 and attended it
     assert [r["kv_tokens"] for r in ticks] == \
         [len(prompt) + k for k in range(1, len(ticks) + 1)]
@@ -338,25 +492,33 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("slots,max_blocks,num_blocks,cols,dtype", [
-    (64, 64, 4096, 1, "bfloat16"),      # mistral7b.chat_decode_sat
-    (16, 256, 2048, 1, "bfloat16"),     # mistral7b.doc_prefill
-    (64, 64, 4096, 4, "bfloat16"),      # a verify window of k = 3
-    (16, 256, 2048, 1, "float32"),      # a net served as it was trained
-], ids=["chat_64x1024", "doc_16x4096", "verify_k3", "float32"])
+@pytest.mark.parametrize(
+    "slots,max_blocks,num_blocks,cols,dtype,heads,kv_heads,hd", [
+        (64, 64, 4096, 1, "bfloat16", 32, 8, 128),  # mistral7b.chat_decode_sat
+        (16, 256, 2048, 1, "bfloat16", 32, 8, 128),     # mistral7b.doc_prefill
+        (64, 64, 4096, 4, "bfloat16", 32, 8, 128),  # a verify window of k = 3
+        (16, 256, 2048, 1, "float32", 32, 8, 128),  # a net served as trained
+        (128, 64, 8192, 1, "bfloat16", 32, 8, 64),  # lfm2_24b.chat_decode_sat
+        (128, 64, 8192, 4, "bfloat16", 32, 8, 64),  # heads of 64, verify k = 3
+    ], ids=["chat_64x1024", "doc_16x4096", "verify_k3", "float32",
+            "lfm2_128x1024_hd64", "verify_k3_hd64"])
 def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
-                                 cols, dtype):
-    """Mosaic takes the kernel at the benchmark cells' shapes (32 / 8
-    heads of 128, blocks of 16) and the compiled program holds no array
-    of a gathered view's size."""
+                                 cols, dtype, heads, kv_heads, hd):
+    """Mosaic takes the kernel at the benchmark cells' shapes (32 query
+    and 8 KV heads of 128, or of 64 stored two to a lane row:
+    ``(8192, 4, 16, 128)``; blocks of 16) and the compiled program holds
+    no array of a gathered view's size and no copy of the pool."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    q = (slots, 32, 128) if cols == 1 else (slots, cols, 32, 128)
+    q = (slots, heads, hd) if cols == 1 else (slots, cols, heads, hd)
     dtype = jnp.dtype(dtype)
-    pool = sds((num_blocks, 8, 16, 128), dtype)
+    pack = pa.applicable("tpu", None, hd, kv_heads, 16, dtype)
+    stored = (num_blocks, kv_heads // pack, 16, pack * hd)
+    assert stored[-1] % 128 == 0
+    pool = sds(stored, dtype)
     # as the chip runs it: 32-bit (Mosaic takes no 64-bit index, and
     # tests/conftest.py turns x64 on), and outside the persistent cache,
     # which a compile for a described chip can write but never read back
@@ -373,5 +535,12 @@ def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
         jax.config.update("jax_enable_compilation_cache", cache_was)
         cc.reset_cache()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    for kv in (8, 32):
+    for kv in (kv_heads // pack, kv_heads, heads):
+        assert f"[{slots},{kv},{max_blocks * 16},{hd}]" not in text
         assert f"[{slots},{kv},{max_blocks * 16},128]" not in text
+    # the pool goes to the kernel as it came in: nothing re-lays it
+    shape = ",".join(map(str, stored))
+    assert not [ln for ln in text.splitlines()
+                if f"[{shape}]" in ln.split("=")[0]
+                and (" copy(" in ln or " convert(" in ln
+                     or " transpose(" in ln)]

@@ -1,11 +1,14 @@
 """Paged decode attention on the chip (``mxnet_tpu/ops/paged_attention.py``):
-the Mosaic kernel against the gather path at the two serving cells' shapes,
-and what the compiled step program holds.
+the Mosaic kernel against the gather path at the serving cells' shapes, and
+what the compiled step program holds.
 
 Shapes: ``mistral7b.chat_decode_sat`` (64 slots x 1024 tokens, pool of 4096
-blocks) and ``mistral7b.doc_prefill`` (16 x 4096, 2048 blocks); blocks of 16,
-32 query / 8 KV heads of 128, bf16.  The model is cut to two layers: every
-layer's arrays have the cells' shapes.
+blocks) and ``mistral7b.doc_prefill`` (16 x 4096, 2048 blocks), 32 query / 8
+KV heads of 128; ``lfm2_24b.chat_decode_sat`` (128 x 1024, 8192 blocks), 32 /
+8 heads of 64, the pool stored two KV heads to a 128-lane row, ``(8192, 4,
+16, 128)``; blocks of 16, bf16.  The step programs are a dense decoder's cut
+to two layers (at heads of 64, Llama-3.2-1B's widths): every layer's arrays
+have the cells' shapes.
 
 Tolerance: one contraction over softmax weights that sum to 1 and values of
 unit scale, probabilities and output rounded to bf16 on both sides
@@ -20,8 +23,11 @@ import numpy as np
 import pytest
 
 EPS = 2.0 ** -8
-CELLS = {"chat_64x1024": (64, 1024, 4096), "doc_16x4096": (16, 4096, 2048)}
-BS, H, HKV, HD = 16, 32, 8, 128
+#: cell -> (slots, max_len, num_blocks, head_dim)
+CELLS = {"chat_64x1024": (64, 1024, 4096, 128),
+         "doc_16x4096": (16, 4096, 2048, 128),
+         "lfm2_128x1024_hd64": (128, 1024, 8192, 64)}
+BS, H, HKV = 16, 32, 8
 
 
 def _case(rng, slots, max_len, num_blocks):
@@ -45,16 +51,17 @@ def _case(rng, slots, max_len, num_blocks):
 
 
 def _gathered(q, kp, vp, tables, lengths):
+    """The gather path's arithmetic on UNPACKED pools (NB, HKV, BS, hd)."""
     import jax
     import jax.numpy as jnp
 
-    s = q.shape[0]
+    s, hd = q.shape[0], q.shape[-1]
     gat = jnp.minimum(tables, kp.shape[0] - 1)
-    kc = kp[gat].transpose(0, 2, 1, 3, 4).reshape(s, HKV, -1, HD)
-    vc = vp[gat].transpose(0, 2, 1, 3, 4).reshape(s, HKV, -1, HD)
+    kc = kp[gat].transpose(0, 2, 1, 3, 4).reshape(s, HKV, -1, hd)
+    vc = vp[gat].transpose(0, 2, 1, 3, 4).reshape(s, HKV, -1, hd)
     kc, vc = (jnp.repeat(a, H // HKV, axis=1) for a in (kc, vc))
     sc = jnp.einsum("shd,shtd->sht", q, kc,
-                    preferred_element_type=jnp.float32) / np.sqrt(HD)
+                    preferred_element_type=jnp.float32) / np.sqrt(hd)
     mask = jnp.arange(kc.shape[2])[None, :] < lengths[:, None]
     sc = jnp.where(mask[:, None], sc, -jnp.inf)
     p = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
@@ -66,19 +73,22 @@ def test_kernel_matches_gather_path(cell, parity_record):
     import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.paged_attention import paged_decode_attention
+    from mxnet_tpu.ops import paged_attention as pa
 
-    slots, max_len, num_blocks = CELLS[cell]
+    slots, max_len, num_blocks, hd = CELLS[cell]
     rng = np.random.default_rng(11)
     tables, lengths, live = _case(rng, slots, max_len, num_blocks)
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
-    pool = (num_blocks, HKV, BS, HD)
+    pool = (num_blocks, HKV, BS, hd)
     kp = jax.random.normal(keys[0], pool, jnp.bfloat16)
     vp = jax.random.normal(keys[1], pool, jnp.bfloat16)
-    q = jax.random.normal(keys[2], (slots, H, HD), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (slots, H, hd), jnp.bfloat16)
     tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
-    got = np.asarray(jax.jit(paged_decode_attention)(
-        q, kp, vp, tables, lengths), np.float32)
+    pack = pa.applicable("tpu", None, hd, HKV, BS, kp.dtype)
+    assert pack == 128 // hd
+    got = np.asarray(jax.jit(pa.paged_decode_attention)(
+        q, pa.pack_rows(kp, pack), pa.pack_rows(vp, pack), tables, lengths),
+        np.float32)
     want = np.asarray(jax.jit(_gathered)(q, kp, vp, tables, lengths),
                       np.float32)
     assert np.isfinite(got).all()
@@ -91,37 +101,50 @@ def test_kernel_matches_gather_path(cell, parity_record):
 
 
 @pytest.fixture(scope="module")
-def net():
-    """Mistral-7B widths, two layers, bf16, seeded."""
+def nets():
+    """head_dim -> a two-layer bf16 decoder, seeded, built once: Mistral-7B
+    widths at 128, Llama-3.2-1B's at 64."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
-    mx.random.seed(3)
-    net = LlamaForCausalLM(LlamaConfig(
-        hidden_size=4096, intermediate_size=14336, num_layers=2,
-        num_heads=H, num_kv_heads=HKV, vocab_size=32768, max_seq_len=4096,
-        rope_theta=1e6, tie_embeddings=False))
-    net.cast("bfloat16")
-    net.collect_params().setattr("grad_req", "null")
-    net.initialize(mx.init.Normal(0.02))
+    built = {}
+
+    def net(hd):
+        if hd not in built:
+            mx.random.seed(3)
+            hidden, inter = {128: (4096, 14336), 64: (2048, 8192)}[hd]
+            net = LlamaForCausalLM(LlamaConfig(
+                hidden_size=hidden, intermediate_size=inter, num_layers=2,
+                num_heads=H, num_kv_heads=HKV, vocab_size=32768,
+                max_seq_len=4096, rope_theta=1e6, tie_embeddings=False))
+            assert net.config.head_dim == hd
+            net.cast("bfloat16")
+            net.collect_params().setattr("grad_req", "null")
+            net.initialize(mx.init.Normal(0.02))
+            built[hd] = net
+        return built[hd]
+
     return net
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
-def test_step_program(cell, net):
-    """The engine picks the kernel here; its step program keeps its name
-    and its one signature, holds no array of a gathered view's size and
-    no pool-sized copy; its logits follow the gather path's."""
+def test_step_program(cell, nets):
+    """The engine picks the kernel here and stores the pool in whole lane
+    rows; its step program keeps its name and its one signature, holds no
+    array of a gathered view's size and no pool-sized copy or convert;
+    its logits follow the gather path's."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.serving.generative import LlamaServingEngine
 
-    slots, max_len, num_blocks = CELLS[cell]
-    mb = max_len // BS
-    eng = LlamaServingEngine(net, max_len=max_len, num_slots=slots,
+    slots, max_len, num_blocks, hd = CELLS[cell]
+    mb, pack = max_len // BS, 128 // hd
+    eng = LlamaServingEngine(nets(hd), max_len=max_len, num_slots=slots,
                              kv_mode="paged", block_size=BS,
                              num_blocks=num_blocks)
+    stored = (num_blocks, HKV // pack, BS, 128)
+    assert eng.kv_pack == pack and eng._pool[0][0].shape == stored
     assert eng.decode_attention == "paged_kernel"
     rng = np.random.default_rng(12)
     tables, lengths, live = _case(rng, slots, max_len, num_blocks)
@@ -141,11 +164,13 @@ def test_step_program(cell, net):
     # one kernel a layer
     assert text.count('custom_call_target="tpu_custom_call"') == \
         len(eng._pool)
-    for kv in (HKV, H):
-        assert f"[{slots},{kv},{max_len},128]" not in text
-    assert f"[{slots},{mb},{HKV},{BS},128]" not in text
+    for kv in (HKV // pack, HKV, H):
+        for lanes in (hd, 128):
+            assert f"[{slots},{kv},{max_len},{lanes}]" not in text
+            assert f"[{slots},{mb},{kv},{BS},{lanes}]" not in text
     pool_copy = re.compile(
-        rf"= bf16\[{num_blocks},{HKV},{BS},{HD}\]\S* copy\(")
+        r"= \w+\[" + ",".join(map(str, stored)) +
+        r"\]\S* (copy|convert|transpose)\(")
     assert not pool_copy.search(text), pool_copy.search(text).group(0)
 
     dec = eng._dec
