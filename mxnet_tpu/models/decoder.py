@@ -1,0 +1,401 @@
+"""What every served decoder shares: the cache spec, the cache views
+its one attention runs over, and the four paged programs, written once.
+
+Reference: NONE (the reference predates LLM serving).
+
+A served architecture is a :class:`PagedDecoder` subclass, built by the
+net's ``serving_decoder(max_len)`` hook, that supplies ``cache_spec()``,
+``_weights()`` (``layers``: a dict a layer; ``emb``), ``layer(p, x, rope,
+view) -> (x, kept, expert rows or None)``, ``_logits(w, x)`` and, for a
+state layer, ``_sequence_state(kept, t0)``: what prefill keeps of a
+whole sequence.
+
+A cache view holds where K and V live and answers one call, ``attend(q,
+k, v) -> (context, kept)``: this call's queries, keys and values after
+RoPE, heads-major (``(B, H, T, hd)``; a step's T is 1); the view stores
+k/v as its kind stores them and gives back the context with the heads
+beside their channels (``(B, T, H, hd)``; a step may leave its unit T
+where it is) and what a cache keeps of the call.  A new cache kind is
+one more view and, if it has a storage format, that format's functions
+under ``ops/``.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+from ..base import MXNetError
+from ..ops import paged_attention
+from ..ops.attention import masked_attention
+
+__all__ = ["CacheSpec", "PagedDecoder", "Causal", "BehindPrefix",
+           "DenseCache", "StepView", "rms_norm", "split_heads",
+           "rope_tables", "apply_rope"]
+
+
+class CacheSpec:
+    """What a served model's decoder keeps between steps, layer by
+    layer — the answer to the engine's question (``decoder.cache_spec()``).
+
+    ``layers[l]`` is ``"kv"`` (the layer owns a K and a V block pool
+    ``(num_blocks, num_kv_heads, block_size, head_dim)``, addressed
+    through the slots' block tables) or ``"state"`` (it owns one array
+    ``(num_slots,) + state_shape``: a fixed-size state a slot, written
+    whole at admission and in place by every step).  ``expert_layers``
+    x ``num_experts`` is the shape of the per-expert row counts that
+    the step and prefill programs of a model with routed experts
+    return beside their tokens (0: none)."""
+
+    __slots__ = ("layers", "num_kv_heads", "head_dim", "state_shape",
+                 "expert_layers", "num_experts")
+
+    def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
+                 expert_layers=0, num_experts=0):
+        self.layers = tuple(layers)
+        if any(kind not in ("kv", "state") for kind in self.layers):
+            raise MXNetError(f"unknown cache kind in {self.layers}")
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.state_shape = None if state_shape is None \
+            else tuple(int(d) for d in state_shape)
+        self.expert_layers = int(expert_layers)
+        self.num_experts = int(num_experts)
+        if self.state_layers and self.state_shape is None:
+            raise MXNetError("state layers need a state_shape")
+
+    @property
+    def kv_layers(self):
+        return self.layers.count("kv")
+
+    @property
+    def state_layers(self):
+        return self.layers.count("state")
+
+    def kv_bytes_per_block(self, block_size, itemsize):
+        """Bytes one block holds over every K/V layer (K and V)."""
+        return 2 * self.kv_layers * self.num_kv_heads * int(block_size) \
+            * self.head_dim * int(itemsize)
+
+    def state_bytes_per_slot(self, itemsize):
+        """Bytes of one slot's state over every state layer."""
+        if not self.state_layers:
+            return 0
+        return self.state_layers * math.prod(self.state_shape) \
+            * int(itemsize)
+
+
+# -- what the models' layers share ---------------------------------------------
+
+def rms_norm(x, w, eps):
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    var = (xf * xf).mean(axis=-1, keepdims=True)
+    return (xf / jnp.sqrt(var + eps) * w.astype(jnp.float32)) \
+        .astype(x.dtype)
+
+
+def rope_tables(t, head_dim, theta):
+    """cos/sin tables (T, head_dim/2) — compile-time constants."""
+    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2,
+                                     dtype=np.float64) / head_dim))
+    pos = np.arange(t, dtype=np.float64)
+    ang = np.outer(pos, inv)
+    return (np.cos(ang).astype(np.float32),
+            np.sin(ang).astype(np.float32))
+
+
+def apply_rope(x, cos, sin):
+    """x (B, H, T, D) with D even; rotate pairs (x[..., ::2], x[..., 1::2])."""
+    import jax.numpy as jnp
+
+    x1 = x[..., ::2]
+    x2 = x[..., 1::2]
+    xr1 = x1 * cos - x2 * sin
+    xr2 = x1 * sin + x2 * cos
+    out = jnp.stack([xr1, xr2], axis=-1).reshape(x.shape)
+    return out.astype(x.dtype)
+
+
+def split_heads(a, n):
+    """A projection's output, heads-major as the views take it:
+    ``(B, T, n * hd) -> (B, n, T, hd)``; a step's ``(S, n * hd)`` has
+    T = 1."""
+    if a.ndim == 2:
+        return a.reshape(a.shape[0], n, 1, -1)
+    b, t, _ = a.shape
+    return a.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
+
+
+# -- cache views ------------------------------------------------------------------
+
+class Causal:
+    """Whole sequences, each attending itself causally (prefill).
+    Nothing is stored: what a cache would keep is the raw (k, v) rows
+    ``(B, Hkv, T, hd)``.  ``live`` (B, T): the positions a request owns
+    (not a padded end's), for a layer that counts rows."""
+
+    pos = None
+
+    def __init__(self, t, live=None):
+        import jax.numpy as jnp
+
+        self.mask = jnp.tril(jnp.ones((t, t), bool))        # (Q, T)
+        self.live = live
+
+    def attend(self, q, k, v):
+        return masked_attention(q, k, v, self.mask).transpose(0, 2, 1, 3), \
+            (k, v)
+
+
+class BehindPrefix:
+    """A suffix behind a reused prefix: ``entry = (K, V)`` each ``(B,
+    Hkv, Lpre, hd)``, dense copies gathered from shared pool blocks,
+    sentinel-padded past ``s0[b]``.  Suffix row j attends every real
+    prefix column (``t < s0[b]``) plus the suffix causally —
+    bit-identical attention to a full prefill; a row with no cache hit
+    has ``s0[b] = 0``: every prefix column masked.  Keeps the suffix's
+    own rows, for the request's PRIVATE blocks."""
+
+    pos = live = None
+
+    def __init__(self, entry, mask):
+        self.entry, self.mask = entry, mask
+
+    @staticmethod
+    def mask_of(s0, lpre, ls):
+        """(B, 1, Ls, Lpre + Ls), shared by the layers."""
+        import jax.numpy as jnp
+
+        b = s0.shape[0]
+        mask_pre = (jnp.arange(lpre)[None, None, None, :]
+                    < s0[:, None, None, None])      # (B,1,1,Lpre)
+        mask_pre = jnp.broadcast_to(mask_pre, (b, 1, ls, lpre))
+        mask_suf = jnp.broadcast_to(
+            jnp.tril(jnp.ones((ls, ls), bool))[None, None],
+            (b, 1, ls, ls))
+        return jnp.concatenate([mask_pre, mask_suf], axis=-1)
+
+    def attend(self, q, k, v):
+        import jax.numpy as jnp
+
+        kc = jnp.concatenate([self.entry[0], k], axis=2)
+        vc = jnp.concatenate([self.entry[1], v], axis=2)
+        return masked_attention(q, kc, vc, self.mask) \
+            .transpose(0, 2, 1, 3), (k, v)
+
+
+class DenseCache:
+    """A dense ``(B, Hkv, max_len, hd)`` K and V cache, one new token a
+    row, written at ``pos`` and masked ``t <= pos``: ``pos`` () for a
+    batch decoded in lockstep (offline ``generate``), (S,) where every
+    slot carries its OWN position (the slots engine).  There a vacant
+    slot runs with pos=0/ids=0: its garbage write lands in its own row
+    only, and admission replaces the whole slot cache."""
+
+    live = None
+
+    def __init__(self, entry, pos, mask):
+        self.entry, self.pos, self.mask = entry, pos, mask
+
+    @staticmethod
+    def mask_of(pos, max_len):
+        """(1, T), or (S, 1, 1, T) a slot each; shared by the layers."""
+        import jax.numpy as jnp
+
+        t = jnp.arange(max_len)
+        if pos.ndim == 0:
+            return (t <= pos)[None, :]
+        return (t[None, :] <= pos[:, None])[:, None, None, :]
+
+    def _write(self, cache, new):
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        z = jnp.zeros((), jnp.int32)
+        if self.pos.ndim == 0:
+            return lax.dynamic_update_slice(cache, new, (z, z, self.pos, z))
+        return jax.vmap(
+            lambda c, u, p: lax.dynamic_update_slice(c, u, (z, p, z)))(
+                cache, new, self.pos)
+
+    def attend(self, q, k, v):
+        kc, vc = self._write(self.entry[0], k), self._write(self.entry[1], v)
+        return masked_attention(q, kc, vc, self.mask), (kc, vc)
+
+
+class StepView:
+    """What a decode call's layer sees of the paged cache: its own
+    ``entry`` (a ``(K pool, V pool)`` pair, or a ``(slots, L, hidden)``
+    state), each slot's position ``pos`` and, for a K/V layer, the
+    call's ``ops.paged_attention.Window``.  The pool's layout is that
+    module's; this view only says when to write and when to attend."""
+
+    __slots__ = ("entry", "pos", "win")
+
+    def __init__(self, entry, pos, win=None):
+        self.entry, self.pos, self.win = entry, pos, win
+
+    @property
+    def live(self):
+        return self.win.live
+
+    def attend(self, q, k, v):
+        kp = paged_attention.write_rows(self.entry[0], self.win, k)
+        vp = paged_attention.write_rows(self.entry[1], self.win, v)
+        return paged_attention.window_attention(q, kp, vp, self.win), \
+            (kp, vp)
+
+
+# -- the programs -----------------------------------------------------------------
+
+class PagedDecoder:
+    """The four paged programs every served model shares.  Each embeds,
+    builds what its layers' views share once (RoPE rows, the window or
+    the mask), runs ``self.layer`` over every layer with that layer's
+    view, takes the last position and ends in ``self._logits``; a model
+    with routed experts gets its row counts ``(expert layers, E)`` back
+    third."""
+
+    def __init__(self, net, max_len):
+        import jax.numpy as jnp
+
+        self.cfg = cfg = net.config
+        self.max_len = int(max_len)
+        self._net = net
+        cos, sin = rope_tables(self.max_len, cfg.head_dim, cfg.rope_theta)
+        self._cos, self._sin = jnp.asarray(cos), jnp.asarray(sin)
+
+    def _layers(self, w, x, rope, views):
+        """-> (x, what each layer's view kept, the expert rows as the
+        programs return them: () or a 1-tuple)."""
+        import jax.numpy as jnp
+
+        kept_all, counts = [], []
+        for p, view in zip(w["layers"], views):
+            x, kept, c = self.layer(p, x, rope, view)
+            kept_all.append(kept)
+            if c is not None:
+                counts.append(c)
+        return x, kept_all, ((jnp.stack(counts),) if counts else ())
+
+    def _decode(self, w, cache, tables, x, pos, rope, paged_kernel):
+        pool = next(e for e in cache if isinstance(e, tuple))[0]
+        win = paged_attention.window(pool, tables, pos, self.max_len,
+                                     paged_kernel)
+        x, cache, counts = self._layers(
+            w, x, rope, (StepView(e, pos, win) for e in cache))
+        return (self._logits(w, x), cache) + counts
+
+    def _step_blocks_impl(self, w, cache, tables, ids_t, pos,
+                          paged_kernel=False):
+        """Per-slot decode step against the PAGED cache, the core of
+        continuous batching: requests admitted at different times
+        decode in one program, each slot at its own position.
+        ``cache[l]`` is a ``(K pool, V pool)`` pair shared by every slot
+        or a ``(S, L, hidden)`` state, by the cache spec; ``tables``
+        (S, MB) int32 holds each slot's block ids in logical order,
+        vacant entries = ``num_blocks``; ``ids_t``, ``pos`` (S,) int32.
+        -> (logits (S, V), cache[, expert rows]).  MB is static, so the
+        compute cost matches a slot ledger's while HBM capacity is the
+        POOL size — bounded by tokens in flight, not max_len × slots.
+
+        Vacant slots run at pos 0 with token 0: their K/V write drops at
+        the sentinel block, their state write lands in their own row,
+        which admission overwrites whole, and the experts they are
+        routed to do not count them (a slot is vacant while its table
+        starts with the sentinel).  ``paged_kernel`` (static; the engine
+        decides it from ``ops.paged_attention.applicable``) picks the
+        Pallas kernel over the gathered view."""
+        import jax.numpy as jnp
+
+        pos = jnp.asarray(pos, jnp.int32)
+        rope = (self._cos[pos][:, None, None, :],   # (S,1,1,hd/2)
+                self._sin[pos][:, None, None, :])
+        x = w["emb"][ids_t]                         # (S, H)
+        return self._decode(w, cache, tables, x, pos, rope, paged_kernel)
+
+    def _verify_blocks_impl(self, w, cache, tables, toks, pos0,
+                            paged_kernel=False):
+        """Speculative VERIFY forward: a widened :meth:`_step_blocks_impl`
+        that advances every slot K = k+1 candidate positions in ONE
+        dispatch.  ``toks`` (S, K) int32 is ``[last_committed, draft_1 ..
+        draft_k]`` per slot; ``pos0`` (S,) each slot's committed write
+        cursor, so window column j carries absolute position ``pos0[s]
+        + j`` and sees ``t <= pos0[s] + j``: draft_j attends the
+        in-window K/V of draft_1..j-1 it was conditioned on.  Returns the
+        (S, K, V) logits — column j is the target model's next-token
+        choice AFTER consuming ``toks[s, :j+1]``, exactly what the
+        acceptance rule compares drafts against.  Rejected columns need
+        no cleanup (``ops.paged_attention.write_rows``).  A decoder with
+        per-slot state has no roll-back: its engine refuses
+        speculation."""
+        import jax.numpy as jnp
+
+        kk = toks.shape[1]
+        pos0 = jnp.asarray(pos0, jnp.int32)
+        pw = pos0[:, None] + jnp.arange(kk, dtype=jnp.int32)[None, :]
+        rope = (self._cos[pw][:, None], self._sin[pw][:, None])
+        x = w["emb"][toks]                          # (S, K, H)
+        return self._decode(w, cache, tables, x, pw, rope, paged_kernel)
+
+    def _last(self, w, x, t0):
+        import jax.numpy as jnp
+
+        if t0.ndim == 0:
+            return self._logits(w, jnp.take(x, t0 - 1, axis=1))
+        # per-row true lengths (B,): serving admits prompts of different
+        # lengths in one padded prefill, each row gathers its own last
+        # real position
+        return self._logits(w, jnp.take_along_axis(
+            x, (t0 - 1)[:, None, None], axis=1)[:, 0])
+
+    def _prefill_rows_impl(self, w, ids, t0):
+        """Batched full-sequence prompt pass over PADDED ids (B, Lp) with
+        true lengths ``t0`` (scalar, or (B,) a row each) -> (rows, logits
+        at each row's last real position[, expert rows]).  ``rows[l]`` is
+        the layer's raw post-RoPE (k, v) ``(B, Hkv, Lp, hd)`` — no
+        max_len cache allocation, so the CALLER picks the storage
+        layout: the offline path pads rows into per-batch max_len
+        caches, the paged serving engine scatters them into pool blocks
+        (the prefill→decode KV handoff) — or a state layer's state of
+        the TRUE length (``_sequence_state``)."""
+        import jax.numpy as jnp
+
+        lp = ids.shape[1]
+        rope = (self._cos[:lp][None, None], self._sin[:lp][None, None])
+        x = w["emb"][ids]                                   # (B, Lp, H)
+        t0 = jnp.asarray(t0, jnp.int32)
+        # not the padded end
+        real = jnp.arange(lp)[None] < (t0[:, None] if t0.ndim else t0)
+        causal = Causal(lp, real)
+        x, rows, counts = self._layers(w, x, rope,
+                                       (causal for _ in w["layers"]))
+        rows = [r if isinstance(r, tuple) else self._sequence_state(r, t0)
+                for r in rows]
+        return (rows, self._last(w, x, t0)) + counts
+
+    def _prefill_suffix_impl(self, w, prefix_kv, ids, t0, s0):
+        """Prompt-SUFFIX prefill attending a reused prefix: the radix
+        prefix cache supplies each row's leading ``s0[b]`` tokens of K/V
+        (``prefix_kv[l]``, see :class:`BehindPrefix`), and only the
+        novel suffix ``ids`` (B, Ls) runs through the transformer, row j
+        at absolute position ``s0[b] + j`` (RoPE + mask) — at
+        suffix-sized projection/MLP cost.  Returns the suffix rows'
+        post-RoPE K/V and logits at each row's true last suffix
+        position ``t0[b] - 1``."""
+        import jax.numpy as jnp
+
+        ls = ids.shape[1]
+        lpre = prefix_kv[0][0].shape[2]
+        s0 = jnp.asarray(s0, jnp.int32)
+        pw = s0[:, None] + jnp.arange(ls, dtype=jnp.int32)[None, :]
+        pw = jnp.minimum(pw, jnp.int32(self.max_len - 1))
+        rope = (self._cos[pw][:, None], self._sin[pw][:, None])
+        x = w["emb"][ids]                           # (B, Ls, H)
+        mask = BehindPrefix.mask_of(s0, lpre, ls)
+        x, rows, counts = self._layers(
+            w, x, rope, (BehindPrefix(e, mask) for e in prefix_kv))
+        return (rows, self._last(w, x, jnp.asarray(t0, jnp.int32))) + counts

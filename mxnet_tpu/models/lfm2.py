@@ -18,9 +18,10 @@ Reference: NONE (the reference predates it).  Layer equations, with
   renormalised over the chosen experts, no shared expert, no capacity.
 
 One definition of the mathematics: :meth:`Lfm2Math.layer`
-``(params, x, positions, cache view) -> (x, cache view, expert rows)``
-is what the Gluon blocks' ``hybrid_forward`` runs over a whole sequence
-(view ``None``) and what the served programs of :class:`Lfm2Decoder`
+``(params, x, rope rows, cache view) -> (x, what the view kept, expert
+rows)`` is what the Gluon blocks' ``hybrid_forward`` runs over a whole
+sequence (a :class:`~.decoder.Causal` view) and what the paged programs
+that :class:`Lfm2Decoder` inherits (``models.decoder.PagedDecoder``)
 run against the paged cache.  A conv layer's cache is not keys and
 values: it is the last ``conv_L_cache`` columns of ``z`` a slot, kept as
 a ring by position (row ``t % L`` holds ``z_t``), so that a decode step
@@ -32,8 +33,9 @@ from __future__ import annotations
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from ..serving.kv_cache import CacheSpec
-from .llama import LlamaDecoder, RMSNorm, _apply_rope, _rope_tables
+from .decoder import (CacheSpec, Causal, PagedDecoder, StepView, apply_rope,
+                      rms_norm, rope_tables, split_heads)
+from .llama import RMSNorm
 from .moe import routed_ffn
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeLayer", "Lfm2MoeForCausalLM",
@@ -129,36 +131,18 @@ def _layer_param_shapes(cfg, l):
     return out
 
 
-class StepView:
-    """What a decode step's layer sees of the paged cache: its own
-    entry (a ``(K pool, V pool)`` pair or a ``(slots, L, hidden)``
-    state), each slot's position, where its new K/V row goes
-    (``blk``, ``off``), and ``attend(q, K pool, V pool)``: the step's
-    decode attention over the slots' blocks."""
-
-    __slots__ = ("entry", "pos", "blk", "off", "attend")
-
-    def __init__(self, entry, pos, blk=None, off=None, attend=None):
-        self.entry, self.pos = entry, pos
-        self.blk, self.off, self.attend = blk, off, attend
-
-
 class Lfm2Math:
-    """The layer mathematics, once.  Attention's scores, softmax and
-    context and the RMSNorm are the Llama decoder's own code."""
-
-    _attend = LlamaDecoder._attend          # reads self.cfg
-    _rms = staticmethod(LlamaDecoder._rms)
+    """The layer mathematics, once."""
 
     def __init__(self, cfg):
         self.cfg = cfg
 
     # -- operators ------------------------------------------------------------
     def short_conv(self, p, u, view):
-        """Gated short convolution.  Whole sequences (``view`` None):
-        ``u`` (B, T, H) -> (y, z) with z (B, T, H) the conv's input, from
-        which prefill takes the state.  A step: ``u`` (S, H) against
-        the state ring -> (y, new state)."""
+        """Gated short convolution.  Whole sequences (any ``view`` but a
+        :class:`~.decoder.StepView`): ``u`` (B, T, H) -> (y, z) with z
+        (B, T, H) the conv's input, from which prefill takes the state.
+        A step: ``u`` (S, H) against the state ring -> (y, new state)."""
         import jax
         import jax.numpy as jnp
 
@@ -169,7 +153,7 @@ class Lfm2Math:
             bcx = u @ p["in_proj"].T
             b, c, x = bcx[..., :h], bcx[..., h:2 * h], bcx[..., 2 * h:]
             z = b * x
-            if view is None:
+            if not isinstance(view, StepView):
                 t = z.shape[1]
                 zp = jnp.pad(z, ((0, 0), (kk - 1, 0), (0, 0)))
                 conv = sum(w[j] * zp[:, j:j + t] for j in range(kk))
@@ -182,41 +166,20 @@ class Lfm2Math:
                        for j in range(kk))
             return (c * conv) @ p["out_proj"].T, state
 
-    def attention(self, p, u, positions, view):
-        """GQA with per-head q/k RMSNorm before RoPE.  Whole sequences:
-        ``u`` (B, T, H), ``positions`` (T,) -> (y, (k, v) rows
-        (B, Hkv, T, hd)).  A step: ``u`` (S, H), ``positions`` (S,),
-        the row write and decode attention of the Llama step -> (y,
-        (K pool, V pool))."""
-        import jax.numpy as jnp
-
+    def attention(self, p, u, rope, view):
+        """GQA with per-head q/k RMSNorm before RoPE, over a cache view:
+        ``u`` (B, T, H), or a step's (S, H); ``rope`` the (cos, sin) rows
+        of the call's positions over heads-major q and k -> (y, what the
+        view kept)."""
         cfg = self.cfg
-        hd, nq, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
-        lead = u.shape[:-1]
-        q = self._rms((u @ p["q"].T).reshape(*lead, nq, hd), p["q_norm"],
-                      cfg.norm_eps)
-        k = self._rms((u @ p["k"].T).reshape(*lead, nkv, hd), p["k_norm"],
-                      cfg.norm_eps)
-        v = (u @ p["v"].T).reshape(*lead, nkv, hd)
-        cos = self._cos[positions][..., None, :]        # (.., 1, hd/2)
-        sin = self._sin[positions][..., None, :]
-        q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
-        if view is None:
-            b, t = lead
-            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-            causal = jnp.tril(jnp.ones((t, t), bool))
-            ctx = self._attend(q, k, v, causal).transpose(0, 2, 1, 3)
-            return ctx.reshape(b, t, nq * hd) @ p["o"].T, (k, v)
-        (kp, vp), s = view.entry, lead[0]
-        # whole stored rows: ``nkv`` of hd, or packed for the kernel
-        rows, lanes = kp.shape[1], kp.shape[3]
-        heads = jnp.arange(rows)[None, :]
-        kp = kp.at[view.blk, heads, view.off].set(
-            k.reshape(s, rows, lanes), mode="drop")
-        vp = vp.at[view.blk, heads, view.off].set(
-            v.reshape(s, rows, lanes), mode="drop")
-        ctx = view.attend(q, kp, vp)
-        return ctx.reshape(s, nq * hd) @ p["o"].T, (kp, vp)
+        q = rms_norm(split_heads(u @ p["q"].T, cfg.num_heads),
+                     p["q_norm"], cfg.norm_eps)
+        k = rms_norm(split_heads(u @ p["k"].T, cfg.num_kv_heads),
+                     p["k_norm"], cfg.norm_eps)
+        v = split_heads(u @ p["v"].T, cfg.num_kv_heads)
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        ctx, kept = view.attend(q, k, v)
+        return ctx.reshape(*u.shape[:-1], -1) @ p["o"].T, kept
 
     def ffn(self, p, u, live=None):
         """Dense SwiGLU, or the routed expert block -> (y, rows each
@@ -244,30 +207,23 @@ class Lfm2Math:
         return y.reshape(*lead, -1), counts
 
     # -- the layer ------------------------------------------------------------
-    def layer(self, p, x, positions, view, live=None):
-        """``(params, x, positions, cache view) -> (x, cache view,
-        expert rows)``.  ``view`` None: whole sequences, ``x`` (B, T, H),
-        and the view that comes back is what a cache would keep of them
-        (the conv's input ``z``, or the (k, v) rows).  A
-        :class:`StepView`: one token a slot, ``x`` (S, H), and the
-        layer's updated cache entry comes back.  ``live``: see
-        :meth:`ffn`."""
+    def layer(self, p, x, rope, view):
+        """``(params, x, rope rows, cache view) -> (x, what the view
+        kept, expert rows)``.  A :class:`~.decoder.Causal` view: whole
+        sequences, ``x`` (B, T, H), and what comes back is what a cache
+        would keep of them (the conv's input ``z``, or the (k, v)
+        rows).  A :class:`~.decoder.StepView`: one token a slot, ``x``
+        (S, H), and the layer's updated cache entry comes back.
+        ``view.live``: see :meth:`ffn`."""
         eps = self.cfg.norm_eps
-        h = self._rms(x, p["op_norm"], eps)
+        h = rms_norm(x, p["op_norm"], eps)
         if "in_proj" in p:
             y, kept = self.short_conv(p, h, view)
         else:
-            y, kept = self.attention(p, h, positions, view)
+            y, kept = self.attention(p, h, rope, view)
         x = x + y
-        y, counts = self.ffn(p, self._rms(x, p["ffn_norm"], eps), live)
+        y, counts = self.ffn(p, rms_norm(x, p["ffn_norm"], eps), view.live)
         return x + y, kept, counts
-
-    def _rope_to(self, max_len):
-        import jax.numpy as jnp
-
-        cos, sin = _rope_tables(max_len, self.cfg.head_dim,
-                                self.cfg.rope_theta)
-        self._cos, self._sin = jnp.asarray(cos), jnp.asarray(sin)
 
 
 class Lfm2MoeLayer(HybridBlock):
@@ -292,13 +248,10 @@ class Lfm2MoeLayer(HybridBlock):
         t = x.shape[1]
 
         def _f(xr, *raw):
-            import jax.numpy as jnp
-
-            math = Lfm2Math(cfg)
-            if "q" in names:
-                math._rope_to(t)
-            return math.layer(dict(zip(names, raw)), xr, jnp.arange(t),
-                              None)[0]
+            cos, sin = rope_tables(t, cfg.head_dim, cfg.rope_theta)
+            return Lfm2Math(cfg).layer(
+                dict(zip(names, raw)), xr,
+                (cos[None, None], sin[None, None]), Causal(t))[0]
 
         return apply_op(_f, x, *(params[n] for n in names),
                         name="lfm2_moe_layer")
@@ -341,15 +294,11 @@ class Lfm2MoeForCausalLM(HybridBlock):
         return Lfm2Decoder(self, max_len)
 
 
-class Lfm2Decoder(Lfm2Math):
-    """The three paged programs of the served path — step, prefill rows
-    and commit — and the cache spec, over :meth:`Lfm2Math.layer`."""
-
-    def __init__(self, net, max_len):
-        super().__init__(net.config)
-        self.max_len = int(max_len)
-        self._net = net
-        self._rope_to(self.max_len)
+class Lfm2Decoder(PagedDecoder, Lfm2Math):
+    """What the shared paged programs (step, verify, prefill rows,
+    prefill suffix) need of this family: the cache spec, the weights,
+    :meth:`Lfm2Math.layer`, the logits and what prefill keeps of a conv
+    layer's sequence."""
 
     def cache_spec(self):
         cfg = self.cfg
@@ -370,91 +319,21 @@ class Lfm2Decoder(Lfm2Math):
                     norm=raw(net.norm.weight))
 
     def _logits(self, w, x):
-        return self._rms(x, w["norm"], self.cfg.norm_eps) @ w["emb"].T
+        return rms_norm(x, w["norm"], self.cfg.norm_eps) @ w["emb"].T
 
-    @staticmethod
-    def _stack_counts(counts):
-        import jax.numpy as jnp
-
-        return jnp.stack([c for c in counts if c is not None])
-
-    def _step_blocks_impl(self, w, cache, tables, ids_t, pos,
-                          paged_kernel=False):
-        """One token a slot against the paged cache: ``cache[l]`` is a
-        ``(K pool, V pool)`` pair or a ``(S, L, hidden)`` state by the
-        cache spec.  -> (logits (S, V), cache, expert rows (layers, E)).
-        Vacant slots run at pos 0 with token 0: their K/V write drops at
-        the sentinel block, their state write lands in their own row,
-        which admission overwrites whole, and the experts they are
-        routed to do not count them (a slot is vacant while its table
-        starts with the sentinel)."""
-        import jax.numpy as jnp
-
-        from ..ops.paged_attention import (gathered_view,
-                                           paged_decode_attention)
-
-        kv = next(e for e in cache if isinstance(e, tuple))
-        nb, _, bs, lanes = kv[0].shape              # as stored
-        pack = lanes // self.cfg.head_dim
-        t = tables.shape[1] * bs
-        pos = jnp.asarray(pos, jnp.int32)
-        gat = jnp.minimum(tables, nb - 1)           # clamp the sentinel
-        mask = (jnp.arange(t)[None, :]
-                <= pos[:, None])[:, None, None, :]  # (S,1,1,T)
-
-        def gathered(q, kp, vp):
-            # the Llama step's gather path: a dense view of every slot's
-            # blocks through the clamped table, masked at its length
-            kc, vc = (gathered_view(p, gat, pack) for p in (kp, vp))
-            return self._attend(q[:, :, None, :], kc, vc, mask)
-
-        def in_place(q, kp, vp):
-            return paged_decode_attention(q, kp, vp, tables, pos + 1)
-
-        shared = dict(
-            pos=pos,
-            blk=jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1),
-            off=(pos % bs)[:, None],
-            attend=in_place if paged_kernel else gathered)
-        live = tables[:, 0] < nb
-        x = w["emb"][ids_t]
-        new_cache, counts = [], []
-        for p, entry in zip(w["layers"], cache):
-            x, entry, c = self.layer(p, x, pos, StepView(entry, **shared),
-                                     live)
-            new_cache.append(entry)
-            counts.append(c)
-        return self._logits(w, x), new_cache, self._stack_counts(counts)
-
-    def _prefill_rows_impl(self, w, ids, t0):
-        """Padded prompts (B, Lp) with true lengths ``t0`` (B,) -> (rows,
-        logits at each row's last real position, expert rows).
-        ``rows[l]`` is the layer's raw (k, v) rows (B, Hkv, Lp, hd), or
-        its state of the TRUE length: ``z`` at ``t0-L .. t0-1`` laid out
+    def _sequence_state(self, z, t0):
+        """A conv layer's state of the TRUE length, from the whole
+        sequence's ``z`` (B, Lp, H): ``z`` at ``t0-L .. t0-1`` laid out
         as the ring keeps it (row ``t % L``), zeros where the prompt is
         shorter, never the padded end's."""
         import jax.numpy as jnp
 
         kk = self.cfg.conv_L_cache
-        _b, lp = ids.shape
-        t0 = jnp.asarray(t0, jnp.int32)
         # ring row r holds the one position p in [t0-L, t0) with p % L == r
         src = t0[:, None] - 1 - (t0[:, None] - 1 - jnp.arange(kk)[None]) % kk
-        live = (src >= 0)[:, :, None]
-        take = jnp.clip(src, 0, lp - 1)[:, :, None]            # (B, L, 1)
-        real = jnp.arange(lp)[None] < t0[:, None]   # not the padded end
-        x = w["emb"][ids]
-        rows, counts = [], []
-        for p in w["layers"]:
-            x, kept, c = self.layer(p, x, jnp.arange(lp), None, real)
-            if "in_proj" in p:
-                kept = jnp.where(live, jnp.take_along_axis(kept, take,
-                                                           axis=1), 0)
-            rows.append(kept)
-            counts.append(c)
-        x_last = jnp.take_along_axis(x, (t0 - 1)[:, None, None],
-                                     axis=1)[:, 0]
-        return rows, self._logits(w, x_last), self._stack_counts(counts)
+        take = jnp.clip(src, 0, z.shape[1] - 1)[:, :, None]     # (B, L, 1)
+        return jnp.where((src >= 0)[:, :, None],
+                         jnp.take_along_axis(z, take, axis=1), 0)
 
 
 def lfm2_moe_tiny(**overrides):

@@ -27,6 +27,10 @@ from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon import nn
 from ..telemetry import numerics as _numerics
+from .decoder import (CacheSpec, DenseCache, PagedDecoder, rms_norm,
+                      split_heads)
+from .decoder import apply_rope as _apply_rope
+from .decoder import rope_tables as _rope_tables
 
 __all__ = ["LlamaConfig", "RMSNorm", "LlamaAttention", "LlamaMLP",
            "LlamaDecoderLayer", "LlamaModel", "LlamaForCausalLM",
@@ -127,28 +131,6 @@ class RMSNorm(HybridBlock):
         return apply_op(_f, x, weight, name="rms_norm")
 
 
-def _rope_tables(t, head_dim, theta):
-    """cos/sin tables (T, head_dim/2) — compile-time constants."""
-    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2,
-                                     dtype=np.float64) / head_dim))
-    pos = np.arange(t, dtype=np.float64)
-    ang = np.outer(pos, inv)
-    return (np.cos(ang).astype(np.float32),
-            np.sin(ang).astype(np.float32))
-
-
-def _apply_rope(x, cos, sin):
-    """x (B, H, T, D) with D even; rotate pairs (x[..., ::2], x[..., 1::2])."""
-    import jax.numpy as jnp
-
-    x1 = x[..., ::2]
-    x2 = x[..., 1::2]
-    xr1 = x1 * cos - x2 * sin
-    xr2 = x1 * sin + x2 * cos
-    out = jnp.stack([xr1, xr2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
-
-
 class LlamaAttention(HybridBlock):
     """GQA self-attention with RoPE + flash kernel."""
 
@@ -235,7 +217,7 @@ class LlamaAttention(HybridBlock):
 
         def _attend_packed(qr, kr, vr, segr):
             # packed-batch path: causal AND same-segment, the serving
-            # slots' mask shape (LlamaDecoder._attend) applied to
+            # slots' mask shape (ops.attention.masked_attention) applied to
             # training.  Flash/ring modes have no segment support, so
             # packing always takes the dense masked sdpa.
             qh, kh, vh = _heads(qr, kr, vr)
@@ -254,7 +236,7 @@ class LlamaAttention(HybridBlock):
 def _segment_causal_mask(seg):
     """(B, T) int segment ids → (B, 1, T, T) bool attention mask:
     causal AND same-segment, the packed-batch analogue of the per-slot
-    mask the serving step builds in ``LlamaDecoder._attend``.  The
+    mask the serving step hands ``ops.attention.masked_attention``.  The
     diagonal is always legal (``seg[q] == seg[q]``), so no query row is
     fully masked and the dense softmax stays NaN-free even on padding
     rows (segment id 0); padding positions only see other padding and
@@ -270,7 +252,7 @@ def _segment_causal_mask(seg):
 
 def _sdpa_segmented(q, k, v, seg, scale):
     """Dense sdpa with the segment-causal mask — f32 score accumulation
-    like ``_sdpa_ref``/the serving ``_attend``.  q/k/v (B, H, T, D)
+    like ``_sdpa_ref``/the serving ``masked_attention``.  q/k/v (B, H, T, D)
     post-GQA-repeat, seg (B, T) int."""
     import jax
     import jax.numpy as jnp
@@ -474,7 +456,7 @@ class LlamaForCausalLM(HybridBlock):
         return nd.NDArray(ids).astype(input_ids.dtype)
 
 
-class LlamaDecoder:
+class LlamaDecoder(PagedDecoder):
     """Jitted incremental decoder with a static-shape KV cache.
 
     Reference: NONE (the reference predates LLM serving).  TPU-first
@@ -486,31 +468,30 @@ class LlamaDecoder:
     every call), so generation always sees current weights and XLA does
     not bake multi-GB constants into the executable.
 
-    The math mirrors ``LlamaAttention``/``LlamaMLP``; attention scores
-    accumulate in float32 (``preferred_element_type``) exactly like the
-    training ``_sdpa_ref`` path, and tests/test_llama.py pins cached ==
-    uncached logits so the paths cannot drift.  Dense MLP only: a
-    routed expert layer is served by the dropless
-    ``models.moe.routed_ffn`` (as ``models.lfm2`` does), not by the
-    fixed-capacity ``MoEMLP`` this family trains with.
+    One :meth:`attention` and one :meth:`layer` over a cache view
+    (``models.decoder``): the paged programs the serving engine runs are
+    the shared base's, and the dense-cache programs here (offline
+    ``generate``, the slots engine) run the same layer over a
+    :class:`~.decoder.DenseCache`.  The math mirrors
+    ``LlamaAttention``/``LlamaMLP``; attention scores accumulate in
+    float32 (``preferred_element_type``) exactly like the training
+    ``_sdpa_ref`` path, and tests/test_llama.py pins cached == uncached
+    logits so the paths cannot drift.  Dense MLP only: a routed expert
+    layer is served by the dropless ``models.moe.routed_ffn`` (as
+    ``models.lfm2`` does), not by the fixed-capacity ``MoEMLP`` this
+    family trains with.
     """
 
     def __init__(self, net: "LlamaForCausalLM", max_len: int):
         import jax
-        import jax.numpy as jnp
 
-        cfg = net.config
-        if cfg.num_experts:
+        if net.config.num_experts:
             raise MXNetError(
                 "LlamaDecoder serves dense MLP configs: num_experts > 0 "
                 "builds the fixed-capacity MoEMLP, which drops tokens; "
                 "a served expert layer is models.moe.routed_ffn "
                 "(dropless), as models.lfm2 wires it")
-        self.cfg = cfg
-        self.max_len = int(max_len)
-        self._net = net
-        cos, sin = _rope_tables(self.max_len, cfg.head_dim, cfg.rope_theta)
-        self._cos, self._sin = jnp.asarray(cos), jnp.asarray(sin)
+        super().__init__(net, max_len)
         self._step = jax.jit(self._step_impl, donate_argnums=(1,))
         self._gen = jax.jit(self._generate_impl,
                             static_argnums=(6, 7, 8, 9))
@@ -538,8 +519,6 @@ class LlamaDecoder:
 
     def cache_spec(self):
         """The serving engine's question: every layer owns a K/V pool."""
-        from ..serving.kv_cache import CacheSpec
-
         cfg = self.cfg
         return CacheSpec(("kv",) * cfg.num_layers, cfg.num_kv_heads,
                          cfg.head_dim)
@@ -550,184 +529,54 @@ class LlamaDecoder:
         cfg = self.cfg
         shape = (batch, cfg.num_kv_heads, self.max_len, cfg.head_dim)
         dt = self._net.model.embed_tokens.weight.data().dtype
-        import numpy as np
-
         dt = np.dtype(dt)
         return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
                 for _ in range(cfg.num_layers)]
 
-    @staticmethod
-    def _rms(x, w, eps):
-        import jax.numpy as jnp
-
-        xf = x.astype(jnp.float32)
-        var = (xf * xf).mean(axis=-1, keepdims=True)
-        return (xf / jnp.sqrt(var + eps) * w.astype(jnp.float32)) \
-            .astype(x.dtype)
-
-    def _attend(self, q, k, v, mask):
-        """Scores in f32 accumulation (matches _sdpa_ref), masked
-        softmax, context.  q (B,H,Q,D); k/v (B,Hkv,T,D); mask (Q,T)
-        shared across the batch, or already broadcastable to
-        (B,H,Q,T) — the per-slot serving step masks each batch row at
-        its own cache length."""
-        import jax
-        import jax.numpy as jnp
-
+    def attention(self, p, h, rope, view):
+        """GQA over a cache view: ``h`` (B, T, hidden), or a step's
+        (S, hidden); ``rope`` the (cos, sin) rows of the call's
+        positions, broadcastable over heads-major q and k -> (y, what
+        the view kept)."""
         cfg = self.cfg
-        rep = cfg.num_heads // cfg.num_kv_heads
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
-        scores = jnp.einsum("bhqd,bhtd->bhqt", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim))
-        if mask.ndim == 2:
-            mask = mask[None, None]
-        scores = jnp.where(mask, scores, -jnp.inf)
-        attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqt,bhtd->bhqd", attn, v)
+        q = split_heads(h @ p["q"].T, cfg.num_heads)
+        k = split_heads(h @ p["k"].T, cfg.num_kv_heads)
+        v = split_heads(h @ p["v"].T, cfg.num_kv_heads)
+        q, k = _apply_rope(q, *rope), _apply_rope(k, *rope)
+        ctx, kept = view.attend(q, k, v)
+        return ctx.reshape(*h.shape[:-1], -1) @ p["o"].T, kept
 
-    def _layer(self, L, x, ctx_fn):
-        """Shared residual wiring: x + attn(ln(x)) then + mlp(ln(x))."""
+    def layer(self, p, x, rope, view):
+        """x + attn(ln(x)) then + mlp(ln(x)) -> (x, what the view kept,
+        None: no expert rows)."""
         import jax
 
-        cfg = self.cfg
-        h = self._rms(x, L["ln_in"], cfg.rms_eps)
-        x = x + ctx_fn(h)
-        h2 = self._rms(x, L["ln_post"], cfg.rms_eps)
-        g = h2 @ L["gate"].T
-        return x + (g * jax.nn.sigmoid(g) * (h2 @ L["up"].T)) @ L["down"].T
+        eps = self.cfg.rms_eps
+        y, kept = self.attention(p, rms_norm(x, p["ln_in"], eps), rope,
+                                 view)
+        x = x + y
+        h2 = rms_norm(x, p["ln_post"], eps)
+        g = h2 @ p["gate"].T
+        return x + (g * jax.nn.sigmoid(g) * (h2 @ p["up"].T)) \
+            @ p["down"].T, kept, None
+
+    def _logits(self, w, x):
+        return rms_norm(x, w["norm"], self.cfg.rms_eps) @ w["head"].T
 
     def _step_impl(self, w, caches, ids_t, pos):
-        """ids_t (B,) int32, pos () int32 → (logits (B, V), caches)."""
+        """One token a row against dense caches: ids_t (B,) int32, pos
+        () int32 (offline ``generate``) or (S,) (the slots engine: a
+        position a slot) → (logits (B, V), caches)."""
         import jax.numpy as jnp
-        from jax import lax
 
-        cfg = self.cfg
-        hd = cfg.head_dim
-        b = ids_t.shape[0]
         pos = jnp.asarray(pos, jnp.int32)
-        z = jnp.zeros((), jnp.int32)
-        cos = lax.dynamic_slice(self._cos, (pos, z), (1, hd // 2))
-        sin = lax.dynamic_slice(self._sin, (pos, z), (1, hd // 2))
+        rope = (self._cos[pos][..., None, None, :],
+                self._sin[pos][..., None, None, :])
         x = w["emb"][ids_t]                                     # (B, H)
-        new_caches = []
-        mask = (jnp.arange(self.max_len) <= pos)[None, :]       # (1, T)
-        for L, (kc, vc) in zip(w["layers"], caches):
-
-            def ctx_fn(h, L=L, kc=kc, vc=vc):
-                q = (h @ L["q"].T).reshape(b, cfg.num_heads, 1, hd)
-                k = (h @ L["k"].T).reshape(b, cfg.num_kv_heads, 1, hd)
-                v = (h @ L["v"].T).reshape(b, cfg.num_kv_heads, 1, hd)
-                q = _apply_rope(q, cos[None, None], sin[None, None])
-                k = _apply_rope(k, cos[None, None], sin[None, None])
-                kc2 = lax.dynamic_update_slice(kc, k, (z, z, pos, z))
-                vc2 = lax.dynamic_update_slice(vc, v, (z, z, pos, z))
-                new_caches.append((kc2, vc2))
-                ctx = self._attend(q, kc2, vc2, mask)
-                return ctx.reshape(b, cfg.num_heads * hd) @ L["o"].T
-
-            x = self._layer(L, x, ctx_fn)
-        x = self._rms(x, w["norm"], cfg.rms_eps)
-        return x @ w["head"].T, new_caches
-
-    def _step_slots_impl(self, w, caches, ids_t, pos):
-        """Per-slot decode step for continuous-batching serving:
-        ids_t (S,) int32, pos (S,) int32 → (logits (S, V), caches).
-
-        Unlike ``_step_impl`` (one shared scalar position — a
-        homogeneous batch decoded in lockstep), every cache slot here
-        carries its OWN position: RoPE tables are gathered per slot,
-        each slot's K/V row is written at its own ``pos`` (vmapped
-        dynamic_update_slice), and the causal mask is per-slot
-        (``t <= pos[s]``).  That is the core of continuous batching —
-        requests admitted at different times decode in one program.
-        Vacant slots run with pos=0/ids=0: their garbage K/V write lands
-        in their own slot row only and admission's prefill scatter
-        replaces the whole slot cache, so they never perturb live
-        slots."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        cfg = self.cfg
-        hd = cfg.head_dim
-        s = ids_t.shape[0]
-        pos = jnp.asarray(pos, jnp.int32)
-        cos = self._cos[pos][:, None, None, :]      # (S,1,1,hd/2)
-        sin = self._sin[pos][:, None, None, :]
-        x = w["emb"][ids_t]                         # (S, H)
-        new_caches = []
-        mask = (jnp.arange(self.max_len)[None, :]
-                <= pos[:, None])[:, None, None, :]  # (S,1,1,T)
-        z = jnp.zeros((), jnp.int32)
-        upd = jax.vmap(
-            lambda c, u, p: lax.dynamic_update_slice(c, u, (z, p, z)))
-        for L, (kc, vc) in zip(w["layers"], caches):
-
-            def ctx_fn(h, L=L, kc=kc, vc=vc):
-                q = (h @ L["q"].T).reshape(s, cfg.num_heads, 1, hd)
-                k = (h @ L["k"].T).reshape(s, cfg.num_kv_heads, 1, hd)
-                v = (h @ L["v"].T).reshape(s, cfg.num_kv_heads, 1, hd)
-                q = _apply_rope(q, cos, sin)
-                k = _apply_rope(k, cos, sin)
-                kc2 = upd(kc, k, pos)
-                vc2 = upd(vc, v, pos)
-                new_caches.append((kc2, vc2))
-                ctx = self._attend(q, kc2, vc2, mask)
-                return ctx.reshape(s, cfg.num_heads * hd) @ L["o"].T
-
-            x = self._layer(L, x, ctx_fn)
-        x = self._rms(x, w["norm"], cfg.rms_eps)
-        return x @ w["head"].T, new_caches
-
-    def _prefill_rows_impl(self, w, ids, t0):
-        """Batched full-sequence prompt pass over PADDED ids (B, Lp)
-        returning each layer's raw post-RoPE K/V rows ``(B, Hkv, Lp,
-        hd)`` — no max_len cache allocation, so the CALLER picks the
-        storage layout: the offline path pads rows into per-batch
-        max_len caches (:meth:`_prefill_impl`), the paged serving
-        engine scatters them into pool blocks (the prefill→decode KV
-        handoff).  Logits are gathered at each row's true last position
-        (scalar or per-row vector ``t0``)."""
-        import jax.numpy as jnp
-
-        cfg = self.cfg
-        hd = cfg.head_dim
-        b, lp = ids.shape
-        cos, sin = self._cos[:lp], self._sin[:lp]
-        x = w["emb"][ids]                                   # (B, Lp, H)
-        causal = jnp.tril(jnp.ones((lp, lp), bool))         # (Q, T)
-        rows = []
-        for L in w["layers"]:
-
-            def ctx_fn(h, L=L):
-                q = (h @ L["q"].T).reshape(b, lp, cfg.num_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                k = (h @ L["k"].T).reshape(b, lp, cfg.num_kv_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                v = (h @ L["v"].T).reshape(b, lp, cfg.num_kv_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                q = _apply_rope(q, cos[None, None], sin[None, None])
-                k = _apply_rope(k, cos[None, None], sin[None, None])
-                rows.append((k, v))
-                ctx = self._attend(q, k, v, causal)
-                return ctx.transpose(0, 2, 1, 3) \
-                    .reshape(b, lp, cfg.num_heads * hd) @ L["o"].T
-
-            x = self._layer(L, x, ctx_fn)
-        t0v = jnp.asarray(t0, jnp.int32)
-        if t0v.ndim == 0:
-            x_last = jnp.take(x, t0v - 1, axis=1)
-        else:
-            # per-row true lengths (B,): serving admits prompts of
-            # different lengths in one padded prefill, each row gathers
-            # its own last real position
-            x_last = jnp.take_along_axis(
-                x, (t0v - 1)[:, None, None], axis=1)[:, 0]
-        x_last = self._rms(x_last, w["norm"], cfg.rms_eps)
-        return rows, x_last @ w["head"].T
+        mask = DenseCache.mask_of(pos, self.max_len)
+        x, caches, _ = self._layers(
+            w, x, rope, (DenseCache(e, pos, mask) for e in caches))
+        return self._logits(w, x), caches
 
     def _prefill_impl(self, w, ids, t0):
         """Prompt pass + full-length caches: K/V rows land at [0:Lp] of
@@ -750,237 +599,6 @@ class LlamaDecoder:
                                       (z, z, z, z)))
             for k, v in rows]
         return caches, logits
-
-    def _step_blocks_impl(self, w, pools, tables, ids_t, pos,
-                          paged_kernel=False):
-        """Per-slot decode step against a PAGED KV pool: same vector-
-        position continuous-batching contract as
-        :meth:`_step_slots_impl`, but K/V storage is block-granular.
-        ``pools[l]`` is ``(kp, vp)`` each ``(num_blocks, Hkv,
-        block_size, hd)`` shared by every slot (or packed for the
-        kernel, ``ops.paged_attention.pack_rows``: ``(num_blocks,
-        Hkv // pack, block_size, pack * hd)``); ``tables`` (S, MB)
-        int32 holds each slot's block ids in logical order, vacant
-        entries = ``num_blocks``.  The step scatters each slot's new
-        K/V at ``(tables[s, pos//bs], pos%bs)`` — the sentinel id is
-        out of bounds, so vacant slots' writes DROP — and gathers each
-        slot's logical view ``(S, Hkv, MB*bs, hd)`` through a clamped
-        table; garbage read through clamped sentinel entries sits at
-        positions the causal mask (``t <= pos``) never exposes.  MB is
-        static, so the compute cost matches the slot-ledger step while
-        HBM capacity is the POOL size — bounded by tokens in flight,
-        not max_len × slots.
-
-        ``paged_kernel`` (static; the engine decides it from
-        ``ops.paged_attention.applicable``) replaces gather + ``_attend``
-        by the Pallas kernel that reads the pool in place through
-        ``tables``, bounded by each slot's ``pos + 1``: no view, no GQA
-        repeat.  A vacant slot's context is then zeros instead of
-        attention over clamped garbage; neither is ever read."""
-        import jax.numpy as jnp
-
-        from ..ops.paged_attention import (gathered_view,
-                                           paged_decode_attention)
-
-        cfg = self.cfg
-        hd = cfg.head_dim
-        s = ids_t.shape[0]
-        # as stored: ``pack`` KV heads to a row of ``lanes`` (1, hd
-        # unless the engine packed the pool for the kernel)
-        nb, hkv, bs, lanes = pools[0][0].shape
-        pack = lanes // hd
-        mb = tables.shape[1]
-        t = mb * bs
-        pos = jnp.asarray(pos, jnp.int32)
-        cos = self._cos[pos][:, None, None, :]      # (S,1,1,hd/2)
-        sin = self._sin[pos][:, None, None, :]
-        x = w["emb"][ids_t]                         # (S, H)
-        mask = (jnp.arange(t)[None, :]
-                <= pos[:, None])[:, None, None, :]  # (S,1,1,T)
-        blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
-                                  axis=1)           # (S,1) physical block
-        off = (pos % bs)[:, None]
-        # the new row is written head row by head row, (block, row,
-        # offset) -> ``lanes`` contiguous values: a scatter with the
-        # heads as a window makes XLA:TPU re-lay the whole pool, in and
-        # out, every layer
-        heads = jnp.arange(hkv)[None, :]            # (1,rows)
-        gat = jnp.minimum(tables, nb - 1)           # clamp the sentinel
-        new_pools = []
-        for L, (kp, vp) in zip(w["layers"], pools):
-
-            def ctx_fn(h, L=L, kp=kp, vp=vp):
-                q = (h @ L["q"].T).reshape(s, cfg.num_heads, 1, hd)
-                k = (h @ L["k"].T).reshape(s, cfg.num_kv_heads, 1, hd)
-                v = (h @ L["v"].T).reshape(s, cfg.num_kv_heads, 1, hd)
-                q = _apply_rope(q, cos, sin)
-                k = _apply_rope(k, cos, sin)
-                kp2 = kp.at[blk, heads, off].set(
-                    k[:, :, 0, :].reshape(s, hkv, lanes), mode="drop")
-                vp2 = vp.at[blk, heads, off].set(
-                    v[:, :, 0, :].reshape(s, hkv, lanes), mode="drop")
-                new_pools.append((kp2, vp2))
-                if paged_kernel:
-                    ctx = paged_decode_attention(q[:, :, 0, :], kp2, vp2,
-                                                 tables, pos + 1)
-                else:
-                    kc, vc = (gathered_view(p, gat, pack)
-                              for p in (kp2, vp2))
-                    ctx = self._attend(q, kc, vc, mask)
-                return ctx.reshape(s, cfg.num_heads * hd) @ L["o"].T
-
-            x = self._layer(L, x, ctx_fn)
-        x = self._rms(x, w["norm"], cfg.rms_eps)
-        return x @ w["head"].T, new_pools
-
-    def _verify_blocks_impl(self, w, pools, tables, toks, pos0,
-                            paged_kernel=False):
-        """Speculative VERIFY forward against the paged pool: a widened
-        :meth:`_step_blocks_impl` that advances every slot K = k+1
-        candidate positions in ONE dispatch.  ``toks`` (S, K) int32 is
-        ``[last_committed, draft_1 .. draft_k]`` per slot; ``pos0``
-        (S,) is each slot's committed write cursor, so window column j
-        carries absolute position ``pos0[s] + j``.  Returns greedy
-        argmax over the (S, K, V) logits — column j is the target
-        model's next-token choice AFTER consuming ``toks[s, :j+1]``,
-        exactly what the acceptance rule compares drafts against.
-
-        K/V for all K window tokens scatter into the slots' own blocks
-        at their absolute positions (``mode="drop"`` on the sentinel
-        id, and ids past ``max_len`` are forced to the sentinel, so
-        vacant slots and over-budget columns write nothing).  Rejected
-        columns need no cleanup: their rows sit beyond the rolled-back
-        cursor where the causal mask (``t <= pos``) never exposes them,
-        and the next verify window overwrites them in place — the
-        stale-row invariant, now doing rollback duty.  The causal mask
-        here is per-COLUMN (``t <= pos0[s] + j``), so draft_j attends
-        the in-window K/V of draft_1..j-1 it was conditioned on.
-        ``paged_kernel`` as in :meth:`_step_blocks_impl`: the same
-        kernel with K query columns, column j bounded by
-        ``pos0 + j + 1`` rows."""
-        import jax.numpy as jnp
-
-        from ..ops.paged_attention import (gathered_view,
-                                           paged_decode_attention)
-
-        cfg = self.cfg
-        hd = cfg.head_dim
-        s, kk = toks.shape
-        nb, hkv, bs, lanes = pools[0][0].shape      # as stored
-        pack = lanes // hd
-        mb = tables.shape[1]
-        t = mb * bs
-        pos0 = jnp.asarray(pos0, jnp.int32)
-        pw = pos0[:, None] + jnp.arange(kk, dtype=jnp.int32)[None, :]
-        cos = self._cos[pw][:, None]                # (S,1,K,hd/2)
-        sin = self._sin[pw][:, None]
-        x = w["emb"][toks]                          # (S, K, H)
-        mask = (jnp.arange(t)[None, None, :]
-                <= pw[:, :, None])[:, None]         # (S,1,K,T)
-        blk = jnp.take_along_axis(tables,
-                                  jnp.minimum(pw // bs, mb - 1), axis=1)
-        # columns past max_len have no legal row: force the sentinel so
-        # the scatter drops instead of wrapping into a clamped block
-        blk = jnp.where(pw < jnp.int32(self.max_len), blk,
-                        nb)[:, :, None]                     # (S,K,1)
-        off = (pw % bs)[:, :, None]
-        heads = jnp.arange(hkv)[None, None, :]              # (1,1,rows)
-        gat = jnp.minimum(tables, nb - 1)
-        new_pools = []
-        for L, (kp, vp) in zip(w["layers"], pools):
-
-            def ctx_fn(h, L=L, kp=kp, vp=vp):
-                q = (h @ L["q"].T).reshape(s, kk, cfg.num_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                k = (h @ L["k"].T).reshape(s, kk, cfg.num_kv_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                v = (h @ L["v"].T).reshape(s, kk, cfg.num_kv_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                q = _apply_rope(q, cos, sin)
-                k = _apply_rope(k, cos, sin)
-                # scatter indices (S,K,rows) pair with update
-                # (S,K,rows,lanes): whole stored rows, as in the step
-                kp2 = kp.at[blk, heads, off].set(
-                    k.transpose(0, 2, 1, 3).reshape(s, kk, hkv, lanes),
-                    mode="drop")
-                vp2 = vp.at[blk, heads, off].set(
-                    v.transpose(0, 2, 1, 3).reshape(s, kk, hkv, lanes),
-                    mode="drop")
-                new_pools.append((kp2, vp2))
-                if paged_kernel:
-                    ctx = paged_decode_attention(
-                        q.transpose(0, 2, 1, 3), kp2, vp2, tables,
-                        pos0 + 1)                       # (S,K,H,hd)
-                else:
-                    kc, vc = (gathered_view(p, gat, pack)
-                              for p in (kp2, vp2))
-                    ctx = self._attend(q, kc, vc, mask) \
-                        .transpose(0, 2, 1, 3)
-                return ctx.reshape(s, kk, cfg.num_heads * hd) @ L["o"].T
-
-            x = self._layer(L, x, ctx_fn)
-        x = self._rms(x, w["norm"], cfg.rms_eps)
-        return x @ w["head"].T, new_pools               # (S, K, V)
-
-    def _prefill_suffix_impl(self, w, prefix_kv, ids, t0, s0):
-        """Prompt-SUFFIX prefill attending a reused prefix: the radix
-        prefix cache supplies each row's leading ``s0[b]`` tokens of
-        K/V (``prefix_kv[l] = (K, V)`` each (B, Hkv, Lpre, hd), dense
-        copies gathered from shared pool blocks, sentinel-padded past
-        ``s0[b]``), and only the novel suffix ``ids`` (B, Ls) runs
-        through the transformer.  Suffix row j sits at absolute
-        position ``s0[b] + j`` (RoPE + mask), attends every real prefix
-        column (``t < s0[b]``) plus the suffix causally — bit-identical
-        attention to a full prefill, at suffix-sized projection/MLP
-        cost.  Returns the suffix rows' post-RoPE K/V (for the pool
-        scatter into the request's PRIVATE blocks) and logits at each
-        row's true last suffix position ``t0[b] - 1``.  Rows with no
-        cache hit run with ``s0[b] = 0``: every prefix column masked,
-        plain prefill semantics."""
-        import jax.numpy as jnp
-
-        cfg = self.cfg
-        hd = cfg.head_dim
-        b, ls = ids.shape
-        lpre = prefix_kv[0][0].shape[2]
-        s0 = jnp.asarray(s0, jnp.int32)
-        pw = s0[:, None] + jnp.arange(ls, dtype=jnp.int32)[None, :]
-        pw = jnp.minimum(pw, jnp.int32(self.max_len - 1))
-        cos = self._cos[pw][:, None]                # (B,1,Ls,hd/2)
-        sin = self._sin[pw][:, None]
-        x = w["emb"][ids]                           # (B, Ls, H)
-        mask_pre = (jnp.arange(lpre)[None, None, None, :]
-                    < s0[:, None, None, None])      # (B,1,1,Lpre)
-        mask_pre = jnp.broadcast_to(mask_pre, (b, 1, ls, lpre))
-        mask_suf = jnp.broadcast_to(
-            jnp.tril(jnp.ones((ls, ls), bool))[None, None],
-            (b, 1, ls, ls))
-        mask = jnp.concatenate([mask_pre, mask_suf], axis=-1)
-        rows = []
-        for L, (pk, pv) in zip(w["layers"], prefix_kv):
-
-            def ctx_fn(h, L=L, pk=pk, pv=pv):
-                q = (h @ L["q"].T).reshape(b, ls, cfg.num_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                k = (h @ L["k"].T).reshape(b, ls, cfg.num_kv_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                v = (h @ L["v"].T).reshape(b, ls, cfg.num_kv_heads, hd) \
-                    .transpose(0, 2, 1, 3)
-                q = _apply_rope(q, cos, sin)
-                k = _apply_rope(k, cos, sin)
-                rows.append((k, v))
-                kc = jnp.concatenate([pk, k], axis=2)
-                vc = jnp.concatenate([pv, v], axis=2)
-                ctx = self._attend(q, kc, vc, mask)
-                return ctx.transpose(0, 2, 1, 3) \
-                    .reshape(b, ls, cfg.num_heads * hd) @ L["o"].T
-
-            x = self._layer(L, x, ctx_fn)
-        t0v = jnp.asarray(t0, jnp.int32)
-        x_last = jnp.take_along_axis(
-            x, (t0v - 1)[:, None, None], axis=1)[:, 0]
-        x_last = self._rms(x_last, w["norm"], cfg.rms_eps)
-        return rows, x_last @ w["head"].T
 
     def logits_at(self, ids):
         """Teacher-forced per-step decode over ``ids`` (B, T) returning

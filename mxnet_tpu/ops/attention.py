@@ -50,6 +50,27 @@ def sdpa_raw(q, k, v, m=None, scale=None, causal=False):
         q, k, v, mask=m, scale=scale, is_causal=causal)
 
 
+def masked_attention(q, k, v, mask):
+    """The served decoders' dense attention, and the paged pool's gather
+    path: scores in f32 accumulation (matches ``_sdpa_ref``), masked
+    softmax, context.  q (B,H,Q,D); k/v (B,Hkv,T,D), repeated here for
+    GQA; mask (Q,T) shared across the batch, or already broadcastable
+    to (B,H,Q,T) — the per-slot serving step masks each batch row at
+    its own cache length."""
+    rep = q.shape[1] // k.shape[1]
+    if rep > 1:
+        k = jnp.repeat(k, rep, axis=1)
+        v = jnp.repeat(v, rep, axis=1)
+    scores = jnp.einsum("bhqd,bhtd->bhqt", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
+    if mask.ndim == 2:
+        mask = mask[None, None]
+    scores = jnp.where(mask, scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqt,bhtd->bhqd", attn, v)
+
+
 def dot_product_attention(query, key, value, mask=None, scale=None,
                           dropout=0.0, causal=False, **kwargs):
     """Fused scaled-dot-product attention.

@@ -2,10 +2,11 @@
 in place, through the block table.
 
 Reference: NONE (the reference predates LLM serving).  The serving step
-(``LlamaDecoder._step_blocks_impl``) used to gather every slot's whole
-``(Hkv, MB*bs, hd)`` logical view out of the pool, repeat it
-``H / Hkv`` times for GQA and attend over all of it, whatever the slots
-held.  This kernel never builds a view:
+(``models.decoder.PagedDecoder._step_blocks_impl``, through a
+``StepView``) used to gather every slot's whole ``(Hkv, MB*bs, hd)``
+logical view out of the pool, repeat it ``H / Hkv`` times for GQA and
+attend over all of it, whatever the slots held.  This kernel never
+builds a view:
 
 - ``tables`` and the per-slot schedule ride as scalar-prefetch operands
   (SMEM), the pools stay in HBM (``memory_space=pl.ANY``);
@@ -22,7 +23,7 @@ held.  This kernel never builds a view:
   pool;
 - the ``H / Hkv`` query heads of a KV head meet that head's keys once:
   no repeat, in HBM or in VMEM;
-- arithmetic as ``LlamaDecoder._attend``: operands in the pool's dtype,
+- arithmetic as ``ops.attention.masked_attention``: operands in the pool's dtype,
   float32 scores and online-softmax state (running max, sum,
   accumulator), the probabilities cast to the pool's dtype for the
   second product, float32 accumulation, output in ``q``'s dtype.
@@ -49,15 +50,26 @@ Rows of an owned block past the slot's length are fetched with their
 block and meet probability 0, as in the gather path: both count on the
 pool holding finite numbers (it is born zero and only ever written with
 K/V rows).
+
+This module owns the pool's storage format, all of it:
+:func:`pool_shape`, the prefill scatter and the prefix gather
+(:func:`scatter_rows`, :func:`gather_rows`), where a decode call's rows
+go and what its queries see (:func:`window`), the row write
+(:func:`write_rows`) and the decode attention, kernel or gather
+(:func:`window_attention`).  No other module indexes a pool's axes,
+computes with ``pack`` or writes a pool with ``mode="drop"``.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .attention import masked_attention
 
 #: reviewed signature budget (mxlint T15): the jit at the foot of this
 #: module is inlined into the step or verify program that calls it and
@@ -102,6 +114,13 @@ def applicable(platform, mesh, head_dim, num_kv_heads, block_size, dtype):
     return pack if ok else 0
 
 
+def pool_shape(num_blocks, num_kv_heads, head_dim, block_size, pack):
+    """A K or V pool as stored: ``pack`` KV heads to a row of
+    ``pack * head_dim`` lanes (``pack`` from :func:`applicable`, 1 on
+    the gather path: one head a row)."""
+    return (num_blocks, num_kv_heads // pack, block_size, pack * head_dim)
+
+
 def pack_rows(a, pack):
     """Logical K/V rows ``(.., Hkv, n, hd)`` as a packed pool stores
     them, ``(.., Hkv // pack, n, pack * hd)``: head ``r * pack + p`` in
@@ -131,6 +150,124 @@ def gathered_view(pool, gat, pack):
     _, rows, bs, lanes = pool.shape
     return unpack_rows(pool[gat].transpose(0, 2, 1, 3, 4)
                        .reshape(s, rows, mb * bs, lanes), pack)
+
+
+def scatter_rows(pool, rows, flat_idx):
+    """The prefill's hand-over: ``rows`` (KB, Hkv, Lp, hd) raw K or V of
+    KB prompts, chunked into ``ceil(Lp / bs)`` block-sized pieces a
+    prompt and written at ``flat_idx`` (KB * nbp,) physical block ids —
+    sentinel ids (== num_blocks) drop, covering vacant batch rows AND
+    chunks past a short prompt's allocation."""
+    _, hkv, bs, lanes = pool.shape                  # as stored
+    kb, lp = rows.shape[0], rows.shape[2]
+    nbp = flat_idx.shape[0] // kb
+    pad = ((0, 0), (0, 0), (0, nbp * bs - lp), (0, 0))
+    chunks = jnp.pad(pack_rows(rows, lanes // rows.shape[-1]), pad) \
+        .reshape(kb, hkv, nbp, bs, lanes) \
+        .transpose(0, 2, 1, 3, 4) \
+        .reshape(kb * nbp, hkv, bs, lanes)
+    return pool.at[flat_idx].set(chunks, mode="drop")
+
+
+def gather_rows(pool, block_ids, pack):
+    """Dense copies of whole blocks: ``block_ids`` (KB, NBP) physical
+    ids in logical order, sentinel-padded -> (KB, Hkv, NBP * bs, hd),
+    unpacked (the radix cache's prefix, for the suffix prefill).
+    Sentinel entries clamp to garbage rows the reader's mask never
+    exposes."""
+    return gathered_view(pool, jnp.minimum(block_ids, pool.shape[0] - 1),
+                         pack)
+
+
+class Window(NamedTuple):
+    """Where a decode call's new K/V rows go in a pool and what its
+    queries see of it: built once a program (:func:`window`), shared by
+    its layers, whose pools are all of one shape."""
+
+    blk: jax.Array      #: physical block of each new row; sentinel: dropped
+    off: jax.Array      #: the row's offset in its block
+    heads: jax.Array    #: the stored head rows, the scatter's middle index
+    tables: jax.Array   #: (S, MB) block ids in logical order
+    first: jax.Array    #: (S,) position of each slot's first query column
+    gat: jax.Array      #: the table, sentinel clamped; None under the kernel
+    mask: jax.Array     #: (S, 1, K, T) what a column sees (gather path)
+    live: jax.Array     #: the columns a request owns: no vacant slot's
+
+
+def window(pool, tables, pos, max_len, paged_kernel):
+    """The :class:`Window` of a decode call over ``tables`` (S, MB),
+    vacant entries = ``num_blocks``.  ``pos`` (S,): a step, each slot's
+    one new row at ``(tables[s, pos // bs], pos % bs)`` — the sentinel
+    id is out of bounds, so vacant slots' writes DROP.  ``pos`` (S, K):
+    K columns a slot (the speculative verify), each at its own absolute
+    position.  The gather path reads each slot's logical view through a
+    clamped table; garbage read through clamped sentinel entries sits
+    at positions the causal mask (``t <= pos``, per column) never
+    exposes."""
+    nb, rows, bs, _ = pool.shape                    # as stored
+    mb = tables.shape[1]
+    t = jnp.arange(mb * bs)
+    if pos.ndim == 1:
+        mask = (t[None, :] <= pos[:, None])[:, None, None, :]
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)
+        off = (pos % bs)[:, None]
+        heads = jnp.arange(rows)[None, :]
+        first, live = pos, tables[:, 0] < nb
+    else:
+        mask = (t[None, None, :] <= pos[:, :, None])[:, None]
+        blk = jnp.take_along_axis(tables, jnp.minimum(pos // bs, mb - 1),
+                                  axis=1)
+        # columns past max_len have no legal row: force the sentinel so
+        # the scatter drops instead of wrapping into a clamped block
+        blk = jnp.where(pos < jnp.int32(max_len), blk, nb)[:, :, None]
+        off = (pos % bs)[:, :, None]
+        heads = jnp.arange(rows)[None, None, :]
+        first = pos[:, 0]
+        live = jnp.broadcast_to(tables[:, :1] < nb, pos.shape)
+    if paged_kernel:
+        return Window(blk, off, heads, tables, first, None, None, live)
+    return Window(blk, off, heads, tables, first,
+                  jnp.minimum(tables, nb - 1), mask, live)
+
+
+def write_rows(pool, win, rows):
+    """``rows`` (S, Hkv, K, hd), the call's new K or V after RoPE (K = 1
+    for a step), written in place at the window's addresses, whole
+    stored rows whatever ``pack``.
+
+    Head row by head row, (block, row, offset) -> ``lanes`` contiguous
+    values: a scatter with the heads as a window makes XLA:TPU re-lay
+    the whole pool, in and out, every layer.  Rejected columns of a
+    verify window need no cleanup: their rows sit beyond the
+    rolled-back cursor where the causal mask never exposes them, and
+    the next window overwrites them in place (the stale-row
+    invariant)."""
+    s, _, kk, _ = rows.shape
+    nrows, lanes = pool.shape[1], pool.shape[3]
+    if win.blk.ndim == 2:
+        new = rows[:, :, 0, :].reshape(s, nrows, lanes)
+    else:
+        new = rows.transpose(0, 2, 1, 3).reshape(s, kk, nrows, lanes)
+    return pool.at[win.blk, win.heads, win.off].set(new, mode="drop")
+
+
+def window_attention(q, k_pool, v_pool, win):
+    """Decode attention of ``q`` (S, H, K, hd) over the pools, the
+    call's own rows already written: the kernel, bounded by each
+    column's position + 1 (a vacant slot's context is then zeros
+    instead of attention over clamped garbage; neither is ever read),
+    or the gathered view under the window's mask.  -> the context,
+    heads beside their channels: (S, K, H, hd), or a step's
+    (S, H[, 1], hd)."""
+    step = win.blk.ndim == 2
+    if win.gat is None:
+        return paged_decode_attention(
+            q[:, :, 0, :] if step else q.transpose(0, 2, 1, 3),
+            k_pool, v_pool, win.tables, win.first + 1)
+    pack = k_pool.shape[3] // q.shape[-1]
+    kc, vc = (gathered_view(p, win.gat, pack) for p in (k_pool, v_pool))
+    ctx = masked_attention(q, kc, vc, win.mask)
+    return ctx if step else ctx.transpose(0, 2, 1, 3)
 
 
 def _schedule(tables, lengths, num_blocks, block_size, chunk):

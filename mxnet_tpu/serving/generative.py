@@ -4,31 +4,30 @@ The engine owns the device half of serving: weights (optionally int8),
 the cache storage, and a fixed family of compiled programs that stay
 shape-stable under arbitrary request traffic.  It asks the model for
 two things (``net.serving_decoder(max_len)``): the decoder's paged
-programs (``_step_blocks_impl``, ``_prefill_rows_impl``; the commit is
-the engine's scatter over what they return) and its **cache spec**
-(:class:`~.kv_cache.CacheSpec`): which layers own a K/V block pool and
+programs (``_step_blocks_impl``, ``_prefill_rows_impl``, written once in
+``models.decoder.PagedDecoder``; the commit is the engine's scatter over
+what they return) and its **cache spec**
+(:class:`~mxnet_tpu.models.decoder.CacheSpec`): which layers own a K/V block pool and
 which a fixed per-slot state, of what shape.  A layer's state lives in
 one array ``(num_slots,) + state_shape`` beside the pools, is donated
 through the step like them, and is written whole at admission.  Two
 storage modes share one surface (``kv_mode=``):
 
 * **paged** (default since r11) — K/V lives in a shared block pool per
-  layer, ``(num_blocks, Hkv, block_size, head_dim)`` (under the paged
-  kernel at heads of 64, two KV heads to a 128-lane row: ``(num_blocks,
-  Hkv // 2, block_size, 128)``, ``kv_pack``); each slot carries
-  a block-table row (vacant entries = ``num_blocks``, the out-of-bounds
-  sentinel XLA's scatter rule DROPS).  Capacity is bounded by tokens in
-  flight, not ``max_len × num_slots``.  Programs: **step**
-  (``LlamaDecoder._step_blocks_impl`` — one signature, ever),
-  **prefill** (``_prefill_rows_impl`` at one (admit_bucket,
-  prompt_bucket) shape per bucket pair, returning RAW K/V rows — no
-  max_len allocation), and **scatter** (pad rows to block chunks and
-  write them at the admitted physical block ids — the prefill→decode KV
-  handoff).
+  layer whose stored format is ``ops.paged_attention``'s alone
+  (``pool_shape``; ``kv_pack`` KV heads to a stored row); each slot
+  carries a block-table row (vacant entries = ``num_blocks``, the
+  out-of-bounds sentinel XLA's scatter rule DROPS).  Capacity is bounded
+  by tokens in flight, not ``max_len × num_slots``.  Programs: **step**
+  (``_step_blocks_impl`` — one signature, ever), **prefill**
+  (``_prefill_rows_impl`` at one (admit_bucket, prompt_bucket) shape per
+  bucket pair, returning RAW K/V rows — no max_len allocation), and
+  **scatter** (``ops.paged_attention.scatter_rows`` at the admitted
+  physical block ids — the prefill→decode KV handoff).
 * **slots** — the r8 ledger layout, one ``(num_slots, Hkv, max_len,
-  head_dim)`` cache per layer, kept behind the pool for A/B
-  (``ServerConfig(kv_mode="slots")``) and the legacy single-loop
-  scheduler.
+  head_dim)`` cache per layer (the decoder's ``DenseCache`` view), kept
+  behind the pool for A/B (``ServerConfig(kv_mode="slots")``) and the
+  legacy single-loop scheduler.
 
 With ``mesh=`` the engine is mesh-native: every weight (and the KV
 pool) is committed to the mesh via the serving partition-rule table
@@ -237,8 +236,9 @@ class LlamaServingEngine:
                 spec.head_dim, spec.num_kv_heads, self.block_size, dt)
             paged_kernel = pack > 0
             self.kv_pack = pack = max(1, pack)
-            pshape = (self.num_blocks, spec.num_kv_heads // pack,
-                      self.block_size, pack * spec.head_dim)
+            pshape = paged_attention.pool_shape(
+                self.num_blocks, spec.num_kv_heads, spec.head_dim,
+                self.block_size, pack)
             # one entry a layer, by the spec: a (K, V) pool pair, or the
             # layer's per-slot state
             self._pool = [
@@ -330,16 +330,11 @@ class LlamaServingEngine:
                     return tok, pools, _numerics.stats_of(logits)
                 return tok, pools
 
-            nb_total = self.num_blocks
-
             def _gather_fn(pools, rows_idx):
                 # rows_idx (KB, NBP) int32 physical block ids in logical
                 # order, sentinel-padded — dense per-row prefix K/V
-                # copies (KB, Hkv, NBP*bs, hd), unpacked, for the suffix
-                # prefill; sentinel entries clamp to garbage rows the
-                # suffix mask (t < s0) never exposes
-                g = jnp.minimum(rows_idx, nb_total - 1)
-                return [tuple(paged_attention.gathered_view(p, g, pack)
+                # copies (KB, Hkv, NBP*bs, hd) for the suffix prefill
+                return [tuple(paged_attention.gather_rows(p, rows_idx, pack)
                               for p in pair) for pair in pools]
 
             def _prefill_sfx_fn(wq, pre_kv, ids, t0, s0):
@@ -348,44 +343,24 @@ class LlamaServingEngine:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
                     rows
 
-            bs = self.block_size
-
             def _scatter_fn(pools, rows, flat_idx, slots=None):
-                # rows[l]: (KB, Hkv, Lp, hd) raw prefill K/V; chunk each
-                # row into ceil(Lp/bs) block-sized pieces and write them
-                # at flat_idx (KB*nbp,) physical block ids — sentinel
-                # ids (== num_blocks) drop, covering vacant batch rows
-                # AND chunks past a short prompt's allocation.  A state
+                # rows[l]: (KB, Hkv, Lp, hd) raw prefill K/V, written
+                # block by block at flat_idx (the prefill→decode KV
+                # handoff, ``paged_attention.scatter_rows``).  A state
                 # layer's rows (KB,) + state_shape replace the WHOLE
                 # state of ``slots`` (vacant rows: slot id num_slots,
                 # dropped), so a reused slot never sees its predecessor's
-                out = []
-                for entry, row in zip(pools, rows):
-                    if not isinstance(entry, tuple):
-                        out.append(entry.at[slots].set(row, mode="drop"))
-                        continue
-                    (kp, vp), (k, v) = entry, row
-                    kb, lp = k.shape[0], k.shape[2]
-                    _, hkv, _, hd = kp.shape        # as stored
-                    nbp = flat_idx.shape[0] // kb
-                    pad = ((0, 0), (0, 0), (0, nbp * bs - lp), (0, 0))
-
-                    def chunk(a):
-                        return jnp.pad(paged_attention.pack_rows(a, pack),
-                                       pad) \
-                            .reshape(kb, hkv, nbp, bs, hd) \
-                            .transpose(0, 2, 1, 3, 4) \
-                            .reshape(kb * nbp, hkv, bs, hd)
-
-                    out.append((kp.at[flat_idx].set(chunk(k), mode="drop"),
-                                vp.at[flat_idx].set(chunk(v), mode="drop")))
-                return out
+                return [
+                    tuple(paged_attention.scatter_rows(p, r, flat_idx)
+                          for p, r in zip(entry, row))
+                    if isinstance(entry, tuple)
+                    else entry.at[slots].set(row, mode="drop")
+                    for entry, row in zip(pools, rows)]
 
         else:
 
             def _step_fn(wq, caches, ids, pos):
-                logits, caches = dec._step_slots_impl(deq(wq), caches,
-                                                      ids, pos)
+                logits, caches = dec._step_impl(deq(wq), caches, ids, pos)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if numerics_on:
                     return tok, caches, _numerics.stats_of(logits)
@@ -698,28 +673,20 @@ class LlamaServingEngine:
             with TraceAnnotation("mxt.decode.dispatch",
                                  seq=self.steps + 1,
                                  replica=self.replica_id):
+                # (tokens, storage[, logit stats under numerics])
                 if self.kv_mode == "paged":
-                    if self._numerics:
-                        toks, pool, lstats = self._step(
-                            self._w, self._pool,
-                            self._dev(self._tables),
-                            self._dev(self._last), self._dev(self._pos))
-                    else:
-                        toks, pool = self._step(
-                            self._w, self._pool,
-                            self._dev(self._tables),
-                            self._dev(self._last), self._dev(self._pos))
-                    self._pool = pool
+                    out = self._step(
+                        self._w, self._pool, self._dev(self._tables),
+                        self._dev(self._last), self._dev(self._pos))
+                    self._pool = out[1]
                 else:
-                    if self._numerics:
-                        toks, caches, lstats = self._step(
-                            self._w, self._caches, self._dev(self._last),
-                            self._dev(self._pos))
-                    else:
-                        toks, caches = self._step(
-                            self._w, self._caches, self._dev(self._last),
-                            self._dev(self._pos))
-                    self._caches = caches
+                    out = self._step(
+                        self._w, self._caches, self._dev(self._last),
+                        self._dev(self._pos))
+                    self._caches = out[1]
+                toks = out[0]
+                if self._numerics:
+                    lstats = out[2]
             self.steps += 1
             seq = self.steps
         t_disp1 = time.perf_counter()
@@ -767,15 +734,12 @@ class LlamaServingEngine:
                 toks_mat = np.concatenate(
                     [self._last[:, None], np.asarray(drafts, np.int32)],
                     axis=1)
+                res = self._verify(
+                    self._w, self._pool, self._dev(self._tables),
+                    self._dev(toks_mat), self._dev(self._pos))
+                out, self._pool = res[:2]
                 if self._numerics:
-                    out, pool, lstats = self._verify(
-                        self._w, self._pool, self._dev(self._tables),
-                        self._dev(toks_mat), self._dev(self._pos))
-                else:
-                    out, pool = self._verify(
-                        self._w, self._pool, self._dev(self._tables),
-                        self._dev(toks_mat), self._dev(self._pos))
-                self._pool = pool
+                    lstats = res[2]
             self.steps += 1
             seq = self.steps
         t_disp1 = time.perf_counter()

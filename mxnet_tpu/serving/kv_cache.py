@@ -33,64 +33,13 @@ that lets the slot ledger skip zeroing slot rows.
 """
 from __future__ import annotations
 
-import math
 import threading
 
 from ..base import MXNetError
+from ..models.decoder import CacheSpec  # noqa: F401  (its home; kept importable here)
 
 __all__ = ["KVCacheManager", "PagedKVCacheManager", "BlockAllocator",
            "SlotState", "CacheSpec"]
-
-
-class CacheSpec:
-    """What a served model's decoder keeps between steps, layer by
-    layer — the answer to the engine's question (``decoder.cache_spec()``).
-
-    ``layers[l]`` is ``"kv"`` (the layer owns a K and a V block pool
-    ``(num_blocks, num_kv_heads, block_size, head_dim)``, addressed
-    through the slots' block tables) or ``"state"`` (it owns one array
-    ``(num_slots,) + state_shape``: a fixed-size state a slot, written
-    whole at admission and in place by every step).  ``expert_layers``
-    x ``num_experts`` is the shape of the per-expert row counts that
-    the step and prefill programs of a model with routed experts
-    return beside their tokens (0: none)."""
-
-    __slots__ = ("layers", "num_kv_heads", "head_dim", "state_shape",
-                 "expert_layers", "num_experts")
-
-    def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
-                 expert_layers=0, num_experts=0):
-        self.layers = tuple(layers)
-        if any(kind not in ("kv", "state") for kind in self.layers):
-            raise MXNetError(f"unknown cache kind in {self.layers}")
-        self.num_kv_heads = int(num_kv_heads)
-        self.head_dim = int(head_dim)
-        self.state_shape = None if state_shape is None \
-            else tuple(int(d) for d in state_shape)
-        self.expert_layers = int(expert_layers)
-        self.num_experts = int(num_experts)
-        if self.state_layers and self.state_shape is None:
-            raise MXNetError("state layers need a state_shape")
-
-    @property
-    def kv_layers(self):
-        return self.layers.count("kv")
-
-    @property
-    def state_layers(self):
-        return self.layers.count("state")
-
-    def kv_bytes_per_block(self, block_size, itemsize):
-        """Bytes one block holds over every K/V layer (K and V)."""
-        return 2 * self.kv_layers * self.num_kv_heads * int(block_size) \
-            * self.head_dim * int(itemsize)
-
-    def state_bytes_per_slot(self, itemsize):
-        """Bytes of one slot's state over every state layer."""
-        if not self.state_layers:
-            return 0
-        return self.state_layers * math.prod(self.state_shape) \
-            * int(itemsize)
 
 
 class SlotState:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import nd, serving
 from mxnet_tpu.models import lfm2
+from mxnet_tpu.models.decoder import rms_norm
 from mxnet_tpu.serving import ServerConfig
 from mxnet_tpu.telemetry import tracing
 
@@ -145,7 +146,7 @@ def test_prefill_hands_on_the_state_of_the_true_length(tiny, t0):
     math = lfm2.Lfm2Math(net.config)
     p = w["layers"][0]
     x = w["emb"][jnp.asarray(ids)]
-    _y, z = math.short_conv(p, math._rms(x, p["op_norm"], 1e-5), None)
+    _y, z = math.short_conv(p, rms_norm(x, p["op_norm"], 1e-5), None)
     state = np.asarray(rows[0])[0]
     for r in range(3):
         src = [p_ for p_ in range(t0 - 3, t0) if p_ % 3 == r][0]

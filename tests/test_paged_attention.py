@@ -1,10 +1,10 @@
 """Paged decode attention (``mxnet_tpu/ops/paged_attention.py``): the
-Pallas kernel under the TPU interpreter against ``LlamaDecoder._attend``
-on the gathered view, the step and verify programs through it, which
+Pallas kernel under the TPU interpreter against
+``ops.attention.masked_attention`` on the gathered view, the step and verify programs through it, which
 path an engine picks, and a compile of the kernel for the v5e at the
 benchmark cells' shapes (no chip: the described topology).  Heads of 64
 run the same tests on a PACKED pool (two KV heads to a 128-lane row)
-against ``_attend`` on the gathered unpacked one."""
+against ``masked_attention`` on the gathered unpacked one."""
 import functools
 import time
 
@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu.models.llama import LlamaDecoder, llama_tiny
 from mxnet_tpu.ops import paged_attention as pa
+from mxnet_tpu.ops.attention import masked_attention
 
 BS, MB, NB = 16, 12, 96
 
@@ -30,17 +31,9 @@ def _interpret():
     return pltpu.InterpretParams()
 
 
-class _Cfg:
-    """What ``_attend`` reads of a decoder's config."""
-
-    def __init__(self, heads, kv_heads, hd):
-        self.num_heads, self.num_kv_heads, self.head_dim = \
-            heads, kv_heads, hd
-
-
 def _gathered(q, kp, vp, tables, lengths, heads, kv_heads):
     """The present step's attention: the clamped gather of every slot's
-    whole view, then ``_attend`` under the per-slot (and, for a verify
+    whole view, then ``masked_attention`` under the per-slot (and, for a verify
     window, per-column) length mask.  q (S, H, hd) or (S, K, H, hd);
     the pools UNPACKED, (NB, Hkv, BS, hd)."""
     s, hd = q.shape[0], q.shape[-1]
@@ -52,9 +45,8 @@ def _gathered(q, kp, vp, tables, lengths, heads, kv_heads):
     bound = lengths[:, None] + jnp.arange(cols)[None, :]        # (S, K)
     mask = (jnp.arange(kc.shape[2])[None, None, :]
             < bound[:, :, None])[:, None]                       # (S,1,K,T)
-    dec = LlamaDecoder.__new__(LlamaDecoder)
-    dec.cfg = _Cfg(heads, kv_heads, hd)
-    out = dec._attend(q4.transpose(0, 2, 1, 3), kc, vc, mask)   # (S,H,K,hd)
+    out = masked_attention(q4.transpose(0, 2, 1, 3), kc, vc,
+                           mask)                                # (S,H,K,hd)
     return out.transpose(0, 2, 1, 3).reshape(q.shape)
 
 
@@ -333,6 +325,162 @@ def test_pack_rows_round_trip(pack):
     assert np.array_equal(pa.unpack_rows(p, pack), a)
     assert np.array_equal(pa.unpack_rows(pa.pack_rows(a[None], pack), pack),
                           a[None])
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+def test_layout_round_trip_scatter_write_gather(pack):
+    """The pool's format, end to end through its one owner: rows that
+    the prefill scatter wrote and a row the step write added read back
+    through ``gathered_view`` as the logical ``(S, Hkv, T, hd)`` rows,
+    whatever ``pack``; writes at the sentinel id (a short prompt's
+    unallocated chunk, a vacant slot, a slot whose next block is not
+    there, a verify column past ``max_len``) drop."""
+    hd, hkv, nb, mb = 128 // pack, 4, 8, 4
+    rng = np.random.default_rng(20 + pack)
+    pool = jnp.zeros(pa.pool_shape(nb, hkv, hd, BS, pack), jnp.float32)
+    assert pool.shape == (nb, hkv // pack, BS, 128)
+    rows = jnp.asarray(rng.normal(size=(2, hkv, 40, hd)), jnp.float32)
+    # prompt 0 owns three blocks, prompt 1 only its first chunk's
+    flat = jnp.asarray([5, 2, 7, 1, nb, nb], jnp.int32)
+    pool = pa.scatter_rows(pool, rows, flat)
+    tables = jnp.asarray([[5, 2, 7, nb], [1, nb, nb, nb], [nb] * 4],
+                         jnp.int32)
+    pos = jnp.asarray([40, BS, 0], jnp.int32)
+    new = jnp.asarray(rng.normal(size=(3, hkv, 1, hd)), jnp.float32)
+    win = pa.window(pool, tables, pos, mb * BS, False)
+    assert np.array_equal(win.live, [True, True, False])
+    written = pa.write_rows(pool, win, new)
+    view = np.asarray(pa.gathered_view(written, win.gat, pack))
+    assert view.shape == (3, hkv, mb * BS, hd)
+    assert np.array_equal(view[0, :, :40], rows[0])
+    assert np.array_equal(view[0, :, 40], new[0, :, 0])
+    assert np.array_equal(view[1, :, :BS], rows[1, :, :BS])
+    # one row changed in the whole pool: slots 1 and 2 wrote nothing
+    changed = np.argwhere((np.asarray(written) != np.asarray(pool))
+                          .any(axis=(1, 3)))
+    assert changed.tolist() == [[7, 40 % BS]]
+    # a window of two columns a slot: the second of slot 0 lies past
+    # max_len and drops, slot 1's block is not there
+    cols = jnp.asarray(rng.normal(size=(3, hkv, 2, hd)), jnp.float32)
+    pw = jnp.asarray([[47, 48], [BS, BS + 1], [0, 1]], jnp.int32)
+    win2 = pa.window(pool, tables, pw, 48, False)
+    wide = pa.write_rows(pool, win2, cols)
+    view2 = np.asarray(pa.gathered_view(wide, win2.gat, pack))
+    assert np.array_equal(view2[0, :, 47], cols[0, :, 0])
+    changed = np.argwhere((np.asarray(wide) != np.asarray(pool))
+                          .any(axis=(1, 3)))
+    assert changed.tolist() == [[7, 47 % BS]]
+
+
+def _tiny_net(name):
+    if name == "llama_tiny":
+        net = llama_tiny()
+    else:
+        from mxnet_tpu.models.lfm2 import lfm2_moe_tiny
+
+        net = lfm2_moe_tiny()
+    net.initialize()
+    return net
+
+
+@pytest.mark.parametrize("name", ["llama_tiny", "lfm2_moe_tiny"])
+def test_prefill_then_paged_steps_equal_the_gluon_forward(name):
+    """The programs both families inherit from ``PagedDecoder``: prefill,
+    the hand-over into blocks, then one paged step a token give, at
+    every position, the logits of the net's own ``hybrid_forward`` over
+    the whole sequence."""
+    from mxnet_tpu import nd
+    from mxnet_tpu.models.decoder import PagedDecoder
+    from mxnet_tpu.serving.generative import LlamaServingEngine
+
+    net = _tiny_net(name)
+    seq = np.random.default_rng(5).integers(1, 250, size=19)
+    want = net(nd.array(seq[None], dtype="int32")).asnumpy()[0]
+    eng = LlamaServingEngine(net, max_len=32, num_slots=2, kv_mode="paged",
+                             block_size=4)
+    dec, w, t0, slot = eng._dec, eng._w, 6, 1
+    for impl in ("_step_blocks_impl", "_verify_blocks_impl",
+                 "_prefill_rows_impl", "_prefill_suffix_impl"):
+        assert getattr(type(dec), impl) is getattr(PagedDecoder, impl)
+    ids = np.zeros((1, 8), np.int32)
+    ids[0, :t0] = seq[:t0]
+    rows, lg = dec._prefill_rows_impl(w, jnp.asarray(ids),
+                                      jnp.asarray([t0]))[:2]
+    got = [np.asarray(lg)[0]]
+    eng.commit_rows(rows, np.asarray([slot]), [list(range(8))],
+                    np.asarray([t0]), np.asarray([seq[t0 - 1]]))
+    for t in range(t0, len(seq)):
+        ids_t, pos = np.zeros(2, np.int32), np.zeros(2, np.int32)
+        ids_t[slot], pos[slot] = seq[t], t
+        lg, eng._pool = dec._step_blocks_impl(
+            w, eng._pool, jnp.asarray(eng._tables), jnp.asarray(ids_t),
+            jnp.asarray(pos))[:2]
+        got.append(np.asarray(lg)[slot])
+    got, want = np.stack(got), want[t0 - 1:]
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() < 2e-4 * np.abs(want).max()
+
+
+def test_layering_models_and_ops_below_serving_and_one_owner_of_the_pool():
+    """``serving`` -> ``models`` -> ``ops``, one way: no module under
+    ``mxnet_tpu/models`` or ``mxnet_tpu/ops`` imports
+    ``mxnet_tpu.serving``.  And the pool's storage format has one owner:
+    an indexed update that drops out-of-bounds ids
+    (``x.at[...].set(..., mode="drop")``, how a pool is written around
+    its sentinel) appears in ``ops/paged_attention.py`` and nowhere
+    else in the package, but for the engine's write of a state layer's
+    rows by slot."""
+    import ast
+    import os
+
+    import mxnet_tpu
+
+    root = os.path.dirname(mxnet_tpu.__file__)
+    reaching_up, drop_writes = [], []
+    for folder, _dirs, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(folder, name)
+            rel = os.path.relpath(path, root).replace(os.sep, "/")
+            with open(path) as fh:
+                tree = ast.parse(fh.read(), path)
+            depth = rel.count("/")
+            for node in ast.walk(tree):
+                mods = []
+                if isinstance(node, ast.ImportFrom):
+                    base = node.module or ""
+                    # ``from ..serving`` one folder down is the package's
+                    if node.level and node.level - 1 == depth:
+                        mods = ["mxnet_tpu." + base] + [
+                            "mxnet_tpu." + a.name for a in node.names
+                            if not base]
+                    elif not node.level:
+                        mods = [base] + [base + "." + a.name
+                                         for a in node.names]
+                elif isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                if rel.startswith(("models/", "ops/")) and any(
+                        m == "mxnet_tpu.serving"
+                        or m.startswith("mxnet_tpu.serving.") for m in mods):
+                    reaching_up.append((rel, node.lineno))
+                if (isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "set"
+                        and isinstance(node.func.value, ast.Subscript)
+                        and isinstance(node.func.value.value, ast.Attribute)
+                        and node.func.value.value.attr == "at"
+                        and any(k.arg == "mode"
+                                and getattr(k.value, "value", None) == "drop"
+                                for k in node.keywords)):
+                    drop_writes.append(
+                        (rel, ast.unparse(node.func.value.slice)))
+    assert reaching_up == []
+    assert {w for w in drop_writes
+            if w[0] != "ops/paged_attention.py"} == \
+        {("serving/generative.py", "slots")}
+    assert len([w for w in drop_writes
+                if w[0] == "ops/paged_attention.py"]) == 2
 
 
 # --- which path an engine takes ----------------------------------------------
