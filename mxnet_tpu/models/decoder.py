@@ -28,6 +28,7 @@ import numpy as np
 from ..base import MXNetError
 from ..ops import paged_attention
 from ..ops.attention import masked_attention
+from ..ops.flash_attention import prefill_flash_attention
 
 __all__ = ["CacheSpec", "PagedDecoder", "Causal", "BehindPrefix",
            "DenseCache", "StepView", "rms_norm", "split_heads",
@@ -134,19 +135,27 @@ class Causal:
     """Whole sequences, each attending itself causally (prefill).
     Nothing is stored: what a cache would keep is the raw (k, v) rows
     ``(B, Hkv, T, hd)``.  ``live`` (B, T): the positions a request owns
-    (not a padded end's), for a layer that counts rows."""
+    (not a padded end's), for a layer that counts rows.  ``lengths``
+    (B,): with them the attention is the flash forward kernel
+    (``ops.flash_attention.prefill_flash_attention``: no score tensor
+    and no repeated K/V in HBM, tiles past a row's length skipped);
+    without, ``masked_attention`` under ``tril``."""
 
     pos = None
 
-    def __init__(self, t, live=None):
+    def __init__(self, t, live=None, lengths=None):
         import jax.numpy as jnp
 
-        self.mask = jnp.tril(jnp.ones((t, t), bool))        # (Q, T)
-        self.live = live
+        self.live, self.lengths = live, lengths
+        if lengths is None:
+            self.mask = jnp.tril(jnp.ones((t, t), bool))    # (Q, T)
 
     def attend(self, q, k, v):
-        return masked_attention(q, k, v, self.mask).transpose(0, 2, 1, 3), \
-            (k, v)
+        if self.lengths is None:
+            ctx = masked_attention(q, k, v, self.mask)
+        else:
+            ctx = prefill_flash_attention(q, k, v, self.lengths)
+        return ctx.transpose(0, 2, 1, 3), (k, v)
 
 
 class BehindPrefix:
@@ -352,7 +361,7 @@ class PagedDecoder:
         return self._logits(w, jnp.take_along_axis(
             x, (t0 - 1)[:, None, None], axis=1)[:, 0])
 
-    def _prefill_rows_impl(self, w, ids, t0):
+    def _prefill_rows_impl(self, w, ids, t0, flash=False):
         """Batched full-sequence prompt pass over PADDED ids (B, Lp) with
         true lengths ``t0`` (scalar, or (B,) a row each) -> (rows, logits
         at each row's last real position[, expert rows]).  ``rows[l]`` is
@@ -361,16 +370,20 @@ class PagedDecoder:
         layout: the offline path pads rows into per-batch max_len
         caches, the paged serving engine scatters them into pool blocks
         (the prefill→decode KV handoff) — or a state layer's state of
-        the TRUE length (``_sequence_state``)."""
+        the TRUE length (``_sequence_state``).  ``flash`` (static; the
+        caller decides it from
+        ``ops.flash_attention.prefill_applicable``) picks the flash
+        forward kernel over ``masked_attention``."""
         import jax.numpy as jnp
 
-        lp = ids.shape[1]
+        b, lp = ids.shape
         rope = (self._cos[:lp][None, None], self._sin[:lp][None, None])
         x = w["emb"][ids]                                   # (B, Lp, H)
         t0 = jnp.asarray(t0, jnp.int32)
         # not the padded end
         real = jnp.arange(lp)[None] < (t0[:, None] if t0.ndim else t0)
-        causal = Causal(lp, real)
+        causal = Causal(lp, real,
+                        jnp.broadcast_to(t0, (b,)) if flash else None)
         x, rows, counts = self._layers(w, x, rope,
                                        (causal for _ in w["layers"]))
         rows = [r if isinstance(r, tuple) else self._sequence_state(r, t0)

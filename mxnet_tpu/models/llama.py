@@ -26,6 +26,7 @@ from .. import autograd
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon import nn
+from ..ops.flash_attention import prefill_applicable
 from ..telemetry import numerics as _numerics
 from .decoder import (CacheSpec, DenseCache, PagedDecoder, rms_norm,
                       split_heads)
@@ -494,7 +495,7 @@ class LlamaDecoder(PagedDecoder):
         super().__init__(net, max_len)
         self._step = jax.jit(self._step_impl, donate_argnums=(1,))
         self._gen = jax.jit(self._generate_impl,
-                            static_argnums=(6, 7, 8, 9))
+                            static_argnums=(6, 7, 8, 9, 10))
 
     def _weights(self):
         """Fresh raw-weight pytree from the net's Parameters (cheap: just
@@ -578,7 +579,7 @@ class LlamaDecoder(PagedDecoder):
             w, x, rope, (DenseCache(e, pos, mask) for e in caches))
         return self._logits(w, x), caches
 
-    def _prefill_impl(self, w, ids, t0):
+    def _prefill_impl(self, w, ids, t0, flash=False):
         """Prompt pass + full-length caches: K/V rows land at [0:Lp] of
         fresh (B, Hkv, max_len, hd) caches (pad rows are overwritten by
         decode steps starting at ``t0``, and the causal mask keeps them
@@ -589,7 +590,7 @@ class LlamaDecoder(PagedDecoder):
 
         cfg = self.cfg
         b = ids.shape[0]
-        rows, logits = self._prefill_rows_impl(w, ids, t0)
+        rows, logits = self._prefill_rows_impl(w, ids, t0, flash)
         z = jnp.zeros((), jnp.int32)
         shape = (b, cfg.num_kv_heads, self.max_len, cfg.head_dim)
         caches = [
@@ -645,16 +646,17 @@ class LlamaDecoder(PagedDecoder):
         return jax.random.categorical(key, lg, axis=-1).astype(jnp.int32)
 
     def _generate_impl(self, w, ids, t0, key, temperature, top_p,
-                       n_steps, top_k, do_sample, use_top_p):
+                       n_steps, top_k, do_sample, use_top_p, flash=False):
         """Padded ids (B, Lp) + traced true length ``t0`` → (B, n_steps)
-        continuation in one XLA program: batched prefill, then a decode
-        scan (first new token comes from the prefill logits; decode
-        steps overwrite the pad K/V rows starting at ``t0``)."""
+        continuation in one XLA program: batched prefill (``flash``: see
+        ``_prefill_rows_impl``), then a decode scan (first new token
+        comes from the prefill logits; decode steps overwrite the pad
+        K/V rows starting at ``t0``)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        caches, logits = self._prefill_impl(w, ids, t0)
+        caches, logits = self._prefill_impl(w, ids, t0, flash)
         key, sub = jax.random.split(key)
         cur = self._pick(logits, sub, temperature, top_p, top_k,
                          do_sample, use_top_p)
@@ -714,11 +716,18 @@ class LlamaDecoder(PagedDecoder):
             key = mx_random.next_key()
         else:
             key = jax.random.PRNGKey(int(seed))
-        toks = self._gen(self._weights(), jnp.asarray(ids_pad),
+        w = self._weights()
+        # the prefill's attention, from where the weights live (sharded
+        # weights stand for a mesh: the dense path)
+        devs = w["emb"].devices()
+        flash = prefill_applicable(
+            next(iter(devs)).platform, None if len(devs) == 1 else devs,
+            self.cfg.head_dim, lp)
+        toks = self._gen(w, jnp.asarray(ids_pad),
                          jnp.int32(t0), key,
                          jnp.float32(temperature), jnp.float32(top_p),
                          int(nb), int(top_k), bool(do_sample),
-                         bool(do_sample and top_p < 1.0))
+                         bool(do_sample and top_p < 1.0), flash)
         return np.concatenate([ids, np.asarray(toks)[:, :n]], axis=1)
 
 

@@ -51,12 +51,22 @@ def sdpa_raw(q, k, v, m=None, scale=None, causal=False):
 
 
 def masked_attention(q, k, v, mask):
-    """The served decoders' dense attention, and the paged pool's gather
-    path: scores in f32 accumulation (matches ``_sdpa_ref``), masked
-    softmax, context.  q (B,H,Q,D); k/v (B,Hkv,T,D), repeated here for
-    GQA; mask (Q,T) shared across the batch, or already broadcastable
-    to (B,H,Q,T) — the per-slot serving step masks each batch row at
-    its own cache length."""
+    """The served decoders' dense attention: scores in f32 accumulation
+    (matches ``_sdpa_ref``), masked softmax, context.  q (B,H,Q,D); k/v
+    (B,Hkv,T,D), repeated here for GQA; mask (Q,T) shared across the
+    batch, or already broadcastable to (B,H,Q,T) — the per-slot serving
+    step masks each batch row at its own cache length.
+
+    Where it still runs (``models/decoder.py``'s views): the suffix
+    behind a radix prefix (``BehindPrefix``), the dense caches of
+    offline ``generate`` and the slots engine (``DenseCache``), the
+    paged pool's gather path (``ops.paged_attention.window_attention``),
+    and whole-sequence prefill (``Causal``) on the CPU, on a mesh-placed
+    engine and at the buckets
+    ``ops.flash_attention.prefill_applicable`` refuses.  It is the
+    reference the Pallas kernels are tested against: the score tensor
+    ``(B, H, Q, T)`` in float32 and the repeated K/V are real arrays
+    here."""
     rep = q.shape[1] // k.shape[1]
     if rep > 1:
         k = jnp.repeat(k, rep, axis=1)

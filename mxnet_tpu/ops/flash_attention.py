@@ -6,7 +6,11 @@ transformer.cc:?``, SURVEY §2.2 contrib row) which materialise the full
 (T, T) score matrix in HBM.  This kernel is the TPU-native replacement:
 scores live in VMEM one (block_q × block_k) tile at a time, the online
 softmax keeps running (m, l) statistics, and the MXU sees two back-to-back
-matmuls per tile.  HBM traffic drops from O(T²) to O(T·D).
+matmuls per tile.  HBM traffic drops from O(T²) to O(T·D).  One forward
+body serves the trainer (``flash_attention_raw``: equal heads, the
+log-sum-exp saved for the backward) and the served decoders' prefill
+(``prefill_flash_attention``: the query heads of a KV head in one tile,
+each prompt's true length bounding the tiles computed).
 
 Backward: ``jax.custom_vjp`` with a K-block-chunked jnp backward
 (``lax.scan``) — recompute-based, so backward memory is O(T·block) too.
@@ -23,6 +27,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+#: reviewed signature budget (mxlint T15): the jit of the served
+#: prefill's entry is inlined into the prefill program that calls it and
+#: compiles nothing of its own there; called alone (tests_tpu/, tools/)
+#: it is one program per operand shapes
+__compile_signatures__ = {
+    "prefill_flash_attention":
+        "0 inside a serving program; 1 per operand shapes when called "
+        "alone",
+}
 
 
 def _on_tpu():
@@ -126,15 +140,51 @@ def _fa_forward_chunked(q, k, v, causal, scale, block=512):
 
 # --- pallas forward kernel ---------------------------------------------------
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-               acc_ref, *, block_q, block_k, causal, scale, nk):
-    """Canonical 3-D-grid flash kernel: grid (BH, nq, nk), kv innermost;
-    running (m, l, acc) live in VMEM scratch across the kv sweep so pallas
-    double-buffers the K/V block loads."""
+def _tile_runs(qi, kj, n, *, block_q, block_k, causal):
+    """Whether the (qi, kj) tile holds anything a row needs: not wholly
+    above the causal diagonal and, where the batch row's true length
+    ``n`` is known (prefill: rows and keys past it are padding that no
+    real row reads), not wholly past it.  The kernel's predicate and the
+    K/V index map's: a tile that does not run is not fetched."""
+    run = kj >= 0
+    if causal:
+        run = (qi + 1) * block_q > kj * block_k
+    if n is not None:
+        run = run & (kj * block_k < n) & (qi * block_q < n)
+    return run
+
+
+def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
+               with_lse, bounded):
+    """Canonical 3-D-grid flash kernel: grid (B * Hkv, nq, nk), kv
+    innermost; running (m, l, acc) live in VMEM scratch across the kv
+    sweep so pallas double-buffers the K/V block loads.
+
+    A grid row is one KV head and the ``G = H / Hkv`` query heads it
+    serves (G = 1 without GQA): their ``block_q`` rows each are laid as
+    one ``(G * block_q, d)`` tile against each K tile, so K and V are
+    fetched once a group and never repeated.  The products take the
+    operands in their stored dtype (bf16: one MXU pass) with float32
+    results; the running max, sum and accumulator are float32 and the
+    probabilities are cast to V's dtype for the second product — the
+    arithmetic of ``ops.attention.masked_attention``.
+
+    ``bounded``: the first ref is a scalar-prefetched ``(B,)`` of true
+    lengths, and tiles wholly past a row's length are skipped (a q tile
+    of padding alone yields zeros).  The mask is written on positions
+    (``qpos >= kpos``), so a query offset behind a reused prefix is one
+    more scalar added to ``qpos``, not another kernel."""
     from jax.experimental import pallas as pl
 
+    len_ref = refs[0] if bounded else None
+    q_ref, k_ref, v_ref, o_ref = refs[bounded:bounded + 4]
+    lse_ref = refs[bounded + 4] if with_lse else None
+    m_ref, l_ref, acc_ref = refs[-3:]
+    group, _, d = q_ref.shape[1:]
+    rows = group * block_q
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    n = len_ref[pl.program_id(0) // kv_heads] if bounded else None
 
     @pl.when(kj == 0)
     def _init():
@@ -142,51 +192,55 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # blocks fully above the causal diagonal contribute nothing
-    pred = ((qi + 1) * block_q > kj * block_k) if causal \
-        else (kj == kj)
-
-    @pl.when(pred)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
+    def tile(masked):
+        q = q_ref[0].reshape(rows, d)
+        v = v_ref[0]
+        s = lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        if masked:
             qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
+                jnp.int32, (group, block_q, block_k), 1).reshape(
+                    rows, block_k)
             kpos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+                jnp.int32, (rows, block_k), 1)
             s = jnp.where(qpos >= kpos, s, -jnp.inf)
-        m = m_ref[...][:, 0]
-        l = l_ref[...][:, 0]
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - safe_m[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
-        l_new = l * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
+        # tq == tk and the kj == 0 tile runs first, so every row has met
+        # key 0 and its running max is finite from its first tile on
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    run = _tile_runs(qi, kj, n, block_q=block_q, block_k=block_k,
+                     causal=causal)
+    if causal:
+        # only a tile the diagonal crosses pays for the mask
+        crossed = kj * block_k + block_k - 1 > qi * block_q
+        pl.when(run & crossed)(lambda: tile(True))
+        pl.when(run & jnp.logical_not(crossed))(lambda: tile(False))
+    else:
+        pl.when(run)(lambda: tile(False))
 
     @pl.when(kj == nk - 1)
     def _finish():
-        m = m_ref[...][:, 0]
-        l = l_ref[...][:, 0]
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        # log-sum-exp per query row, saved for the pallas backward;
-        # fully-masked rows keep -inf (their backward p is zeroed).
-        # Stored (…, block_q, 1): mosaic requires the last two block
-        # dims (8, 128)-aligned or equal to the array's — a trailing
-        # singleton satisfies that where a 2-D (1, block_q) cannot.
-        lse_ref[0] = jnp.where(
-            jnp.isfinite(m) & (l > 0.0),
-            jnp.where(jnp.isfinite(m), m, 0.0) +
-            jnp.log(jnp.maximum(l, 1e-30)),
-            -jnp.inf)[:, None]
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
+            group, block_q, d).astype(o_ref.dtype)
+        if with_lse:
+            # log-sum-exp per query row, saved for the pallas backward;
+            # a row no tile ran for keeps -inf (its backward p is
+            # zeroed).  Stored (…, block_q, 1): mosaic requires the
+            # last two block dims (8, 128)-aligned or equal to the
+            # array's — a trailing singleton satisfies that where a 2-D
+            # (1, block_q) cannot.
+            lse_ref[0] = jnp.where(
+                l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
+                -jnp.inf).reshape(group, block_q, 1)
 
 
 def _divisor_block(t, pref):
@@ -197,50 +251,135 @@ def _divisor_block(t, pref):
 
 
 def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
-                       with_lse=False, interpret=False):
+                       with_lse=False, interpret=False, lengths=None,
+                       name=None):
+    """q (B, H, T, D), k/v (B, Hkv, T, D) with Hkv dividing H -> (B, H,
+    T, D)[, lse (B, H, T)].  ``lengths`` (B,) int32: each batch row's
+    true length (see ``_fa_kernel``'s ``bounded``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
-    bh = b * h
-    qf = q.reshape(bh, tq, d)
+    hkv, tk = k.shape[1], k.shape[2]
+    g = h // hkv
+    bh = b * hkv
+    qf = q.reshape(bh, g, tq, d)
     kf = k.reshape(bh, tk, d)
     vf = v.reshape(bh, tk, d)
     block_q = _divisor_block(tq, min(block_q, tq))
     block_k = _divisor_block(tk, min(block_k, tk))
     nk = tk // block_k
-    grid = (bh, tq // block_q, nk)
-    out, lse = pl.pallas_call(
+    bounded = lengths is not None
+
+    def q_map(b_, i, j, *_):
+        return (b_, 0, i, 0)
+
+    def kv_map(b_, i, j, *lens):
+        if not (causal or bounded):
+            return (b_, j, 0)
+        # a tile that does not run is not fetched: its step asks for
+        # block 0, the first the next q tile needs
+        n = lens[0][b_ // hkv] if bounded else None
+        return (b_, jnp.where(
+            _tile_runs(i, j, n, block_q=block_q, block_k=block_k,
+                       causal=causal), j, 0), 0)
+
+    out_specs = [pl.BlockSpec((1, g, block_q, d), q_map)]
+    out_shape = [_pallas_out_shape((bh, g, tq, d), q.dtype, q, k, v)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, g, block_q, 1), q_map))
+        out_shape.append(
+            _pallas_out_shape((bh, g, tq, 1), jnp.float32, q, k, v))
+    out = pl.pallas_call(
         functools.partial(_fa_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
-        ],
-        out_shape=[
-            _pallas_out_shape((bh, tq, d), q.dtype, q, k, v),
-            _pallas_out_shape((bh, tq, 1), jnp.float32, q, k, v),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # m
-            pltpu.VMEM((block_q, 1), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32),   # acc
-        ],
+                          causal=causal, scale=scale, nk=nk, kv_heads=hkv,
+                          with_lse=with_lse, bounded=bounded),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(bounded),
+            grid=(bh, tq // block_q, nk),
+            in_specs=[
+                pl.BlockSpec((1, g, block_q, d), q_map),
+                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((1, block_k, d), kv_map),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((g * block_q, 1), jnp.float32),   # m
+                pltpu.VMEM((g * block_q, 1), jnp.float32),   # l
+                pltpu.VMEM((g * block_q, d), jnp.float32),   # acc
+            ]),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=name,
         interpret=interpret,
-    )(qf, kf, vf)
-    out = out.reshape(b, h, tq, d)
+    )(*((jnp.asarray(lengths, jnp.int32),) if bounded else ()),
+      qf, kf, vf)
     if with_lse:
-        return out, lse.reshape(b, h, tq)
-    return out
+        return out[0].reshape(b, h, tq, d), out[1].reshape(b, h, tq)
+    return out[0].reshape(b, h, tq, d)
+
+
+# --- the serving prefill's entry --------------------------------------------
+
+#: shortest prompt bucket the served prefill sends through the kernel.
+#: On the v5e (PERF.md, PR 29) the two-layer prefill program at
+#: Mistral-7B's widths reads, dense / kernel, 2.62 / 2.65 ms at 128,
+#: 3.04 / 3.02 at 256, 4.44 / 4.34 at 512, 9.06 / 7.26 at 1,024, 20.4 /
+#: 13.0 at 2,048 and 54.1 / 25.7 at 4,096: a bucket of 128 gains
+#: nothing, keeps ``masked_attention`` (its scores are 2 MB) and is
+#: spared the kernel's lowering at every start of a server
+PREFILL_MIN_LEN = 256
+
+
+def prefill_applicable(platform, mesh, head_dim, seq_len):
+    """Whether a served prefill of ``seq_len`` padded positions runs the
+    forward kernel instead of ``ops.attention.masked_attention``, from
+    what the caller observes: the platform its weights live on, the
+    engine's mesh (a sharded call would need the ``shard_map`` wrapper:
+    it keeps the dense path) and the shapes Mosaic tiles without
+    padding rows."""
+    return (platform == "tpu" and mesh is None
+            and head_dim in (64, 128, 256)
+            and seq_len >= PREFILL_MIN_LEN and seq_len % 128 == 0)
+
+
+def prefill_tiles(group, seq_len):
+    """(block_q, block_k) of the served prefill's kernel for ``group``
+    query heads a KV head: a score tile of 1,024 rows (the group's
+    ``block_q`` rows each) by 1,024 keys.
+
+    On the v5e at 4 x 8 heads of 128 in bf16, TFLOP/s of the causal
+    half at 4,096 / 2,048 / 1,024 (PERF.md, PR 29): 128 x 128 17 / 16 /
+    13; 256 x 256 31 / 27 / 21; 256 x 512 56 / 45 / 28; 512 x 512 70 /
+    54 / 32; 128 x 1,024 88 / 62 / 35; **256 x 1,024 96 / 67 / 36**;
+    256 x 2,048 79 / 52; 128 x 4,096 61.  A grid step costs about 3.8
+    ns a score row whatever the keys' width (the row reductions and the
+    rescale of the accumulator), so wide key tiles win until the
+    diagonal tile's masked half outweighs them; 512 x 1,024 needs more
+    VMEM than a kernel gets by default.  At heads of 64, 256 x 512
+    reads 29 / 23 / 15 and 256 x 1,024 49 / 34 / 18."""
+    return min(max(128, 1024 // group), seq_len), min(1024, seq_len)
+
+
+def _prefill_flash_attention(q, k, v, lengths, interpret=False):
+    """Causal attention of whole prompts, GQA inside the kernel: ``q``
+    (B, H, Lp, hd), ``k`` / ``v`` (B, Hkv, Lp, hd) after RoPE,
+    ``lengths`` (B,) int32 true lengths -> (B, H, Lp, hd).  Rows below
+    a length are ``masked_attention``'s under ``tril``; padded rows are
+    finite and read by nothing."""
+    hd, lp = q.shape[-1], q.shape[2]
+    bq, bk = prefill_tiles(q.shape[1] // k.shape[1], lp)
+    return _fa_forward_pallas(
+        q, k, v, True, 1.0 / float(np.sqrt(hd)), bq, bk,
+        lengths=lengths, name="prefill_flash_attention",
+        interpret=interpret)
+
+
+#: jitted, so that the layers of a prefill program share one trace and one
+#: Mosaic lowering of the kernel, as ``ops.paged_attention`` does
+prefill_flash_attention = jax.jit(_prefill_flash_attention,
+                                  static_argnames=("interpret",))
 
 
 # --- pallas backward kernels -------------------------------------------------

@@ -69,6 +69,7 @@ from ..telemetry import numerics as _numerics
 from ..telemetry import retrace as _retrace
 from ..telemetry import tracing
 from ..base import MXNetError
+from ..ops import flash_attention
 from .bucketing import BucketPolicy, pad_batch
 from .kv_cache import KVCacheManager
 from .protocol import ServerClosedError
@@ -215,6 +216,9 @@ class LlamaServingEngine:
         #: bytes of one cached value (K, V and state share the weights'
         #: load dtype): what the manager prices blocks and states with
         self.cache_itemsize = int(np.dtype(dt).itemsize)
+        #: where the weights (and so the cache) live: with the mesh and
+        #: the shapes, what the attention kernels are chosen from
+        platform = next(iter(w["emb"].devices())).platform
         if kv_mode == "paged":
             self.block_size = int(block_size)
             if self.block_size < 1:
@@ -232,8 +236,8 @@ class LlamaServingEngine:
             # 64 are stored ``kv_pack`` = 2 to a row, (blocks, Hkv // 2,
             # bs, 128); the gather path keeps one head a row
             pack = paged_attention.applicable(
-                next(iter(w["emb"].devices())).platform, mesh,
-                spec.head_dim, spec.num_kv_heads, self.block_size, dt)
+                platform, mesh, spec.head_dim, spec.num_kv_heads,
+                self.block_size, dt)
             paged_kernel = pack > 0
             self.kv_pack = pack = max(1, pack)
             pshape = paged_attention.pool_shape(
@@ -286,6 +290,14 @@ class LlamaServingEngine:
         #: table); ``kv_pack`` beside it says how many KV heads a stored
         #: row holds (above 1 only under the kernel)
         self.decode_attention = "paged_kernel" if paged_kernel else "gather"
+        self._platform = platform
+        #: which attention the prefill programs run, decided a bucket
+        #: (``prefill_attention_at``): "flash" where the engine's
+        #: longest 128-aligned prompt goes through
+        #: ``ops.flash_attention.prefill_flash_attention``, "dense"
+        #: where every bucket keeps ``masked_attention``
+        self.prefill_attention = self.prefill_attention_at(
+            self.max_len // 128 * 128)
         #: per-expert row counts that ride behind the tokens of every
         #: step and prefill fetch (0: the model routes nothing)
         self._n_counts = spec.expert_layers * spec.num_experts
@@ -317,7 +329,8 @@ class LlamaServingEngine:
                 return tok, pools
 
             def _prefill_fn(wq, ids, t0):
-                out = dec._prefill_rows_impl(deq(wq), ids, t0)
+                out = dec._prefill_rows_impl(
+                    deq(wq), ids, t0, flash=self._prefill_flash(ids.shape[1]))
                 rows, logits = out[:2]
                 return _tokens(logits, out), rows
 
@@ -367,7 +380,8 @@ class LlamaServingEngine:
                 return tok, caches
 
             def _prefill_fn(wq, ids, t0):
-                caches, logits = dec._prefill_impl(deq(wq), ids, t0)
+                caches, logits = dec._prefill_impl(
+                    deq(wq), ids, t0, flash=self._prefill_flash(ids.shape[1]))
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
                     caches
 
@@ -483,6 +497,17 @@ class LlamaServingEngine:
                     "serving_" + str(key[0]), id(self), comps,
                     site="mxnet_tpu.serving.generative:"
                          "LlamaServingEngine (%s)" % (key[0],))
+
+    def _prefill_flash(self, lp):
+        """The rule at a bucket of ``lp`` positions, from what the
+        engine observes: the static its prefill program is traced with."""
+        return flash_attention.prefill_applicable(
+            self._platform, self.mesh, self.cache_spec.head_dim, lp)
+
+    def prefill_attention_at(self, lp):
+        """``"flash"`` or ``"dense"``: which attention the prefill
+        program of a bucket ``lp`` positions long runs."""
+        return "flash" if self._prefill_flash(lp) else "dense"
 
     def compiled_signatures(self):
         """Every (program, *bucket) shape this engine has compiled."""

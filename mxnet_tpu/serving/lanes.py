@@ -243,11 +243,15 @@ class PrefillLane:
     def _forward(self, group, prompts, matched, skip, block_lists,
                  t0s_suf, s0s, kb):
         """Dispatch the prompt forward (unlocked); returns the device
-        first-token vector and the raw K/V rows for the commit."""
+        first-token vector, the raw K/V rows for the commit and which
+        attention the program ran (``"flash"`` or ``"dense"``: the
+        engine's rule at this bucket; the suffix path is dense)."""
         r = self.r
         eng = r.engine
         if r.radix is None or not any(matched):
-            return eng.prefill_rows(prompts, t0s_suf)
+            toks, rows = eng.prefill_rows(prompts, t0s_suf)
+            at = getattr(eng, "prefill_attention_at", None)
+            return toks, rows, at(prompts.shape[1]) if at else "dense"
         # radix-hit path: dense prefix copies (locked gather) feed the
         # suffix-only forward (unlocked); the commit scatters ONLY the
         # suffix rows into the request's private blocks past the shared
@@ -258,7 +262,8 @@ class PrefillLane:
         for i in range(len(group)):
             rows_idx[i, :skip[i]] = block_lists[i][:skip[i]]
         pre_kv = eng.gather_prefix(rows_idx)
-        return eng.prefill_suffix(pre_kv, prompts, t0s_suf, s0s)
+        toks, rows = eng.prefill_suffix(pre_kv, prompts, t0s_suf, s0s)
+        return toks, rows, "dense"
 
     def _prefill_group(self, group):
         """The admitted ``group`` through forward, commit and handoff,
@@ -330,7 +335,7 @@ class PrefillLane:
                                  "batch": kb, "length": lb}):
                 with TraceAnnotation("mxt.prefill.dispatch", seq=seq,
                                      replica=r.index):
-                    toks, rows = self._forward(
+                    toks, rows, attention = self._forward(
                         group, prompts, matched, skip, block_lists,
                         t0s_suf, s0s, kb)
                 t_disp1 = time.perf_counter()
@@ -394,7 +399,8 @@ class PrefillLane:
             n_tokens=int(t0s_suf[:len(group)].sum()), bucket=(kb, lb),
             radix_hit_tokens=int(sum(matched)), t_start=t_start,
             t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
-            t_commit1=t_commit1, t_first=t_first, **extra)
+            t_commit1=t_commit1, t_first=t_first,
+            prefill_attention=attention, **extra)
         capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
             req.t_first = t_first

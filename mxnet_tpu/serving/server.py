@@ -614,6 +614,7 @@ class GenerativeServer(_ServerBase):
                 "kv_cache": self._sched.mgr.stats(),
                 "compiled_signatures": self.engine.compiled_signatures(),
                 "decode_attention": self.engine.decode_attention,
+                "prefill_attention": self.engine.prefill_attention,
                 "kv_pack": self.engine.kv_pack,
             }
             telemetry.gauge("serving.kv_occupancy",
@@ -632,6 +633,7 @@ class GenerativeServer(_ServerBase):
             "compiled_signatures":
                 reps[0].engine.compiled_signatures(),
             "decode_attention": reps[0].engine.decode_attention,
+            "prefill_attention": reps[0].engine.prefill_attention,
             "kv_pack": reps[0].engine.kv_pack,
             "num_replicas": len(reps),
             "kv_layers": reps[0].engine.cache_spec.kv_layers,
@@ -652,6 +654,7 @@ class GenerativeServer(_ServerBase):
                 "kv_cache": r.mgr.stats(),
                 "compiled_signatures": r.engine.compiled_signatures(),
                 "decode_attention": r.engine.decode_attention,
+                "prefill_attention": r.engine.prefill_attention,
             } for r in reps]
         if any(r.spec_k for r in reps):
             drafted = sum(r.draft_tokens for r in reps)

@@ -615,6 +615,7 @@ def test_engines_here_take_the_gather_path():
         stats = srv.stats()
     assert srv.engine.decode_attention == "gather"
     assert stats["decode_attention"] == "gather" and stats["kv_pack"] == 1
+    assert stats["prefill_attention"] == "dense"
     ticks = tracing.lane_log("decode.tick", since=since)
     assert ticks[0]["decode_attention"] == "gather"
     assert ticks[0]["kv_pack"] == 1
@@ -692,3 +693,68 @@ def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
                 if f"[{shape}]" in ln.split("=")[0]
                 and (" copy(" in ln or " convert(" in ln
                      or " transpose(" in ln)]
+
+
+@pytest.mark.parametrize("flash", [True, False], ids=["flash", "dense"])
+def test_prefill_program_compiles_for_v5e_without_scores(one_chip, flash):
+    """Mistral-7B's prefill program at the 4,096 bucket (its widths: 32
+    query / 8 KV heads of 128, hidden 4,096, feed-forward 14,336; two
+    layers: every layer's arrays have the cell's shapes, and a
+    program's temporaries are one layer's) through the flash forward
+    kernel: one Mosaic call a layer, no ``(1, 32, 4096, 4096)`` score
+    tensor in any dtype, K and V never broadcast over their group, and
+    temporaries under 1 GB — the dense path's 5 GB, which the second
+    case pins so that the first cannot pass by looking at the wrong
+    text."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    lp, heads, kv_heads, hd, hidden, ffn, vocab = \
+        4096, 32, 8, 128, 4096, 14336, 32768
+    net = LlamaForCausalLM(LlamaConfig(
+        hidden_size=hidden, intermediate_size=ffn, num_layers=2,
+        num_heads=heads, num_kv_heads=kv_heads, vocab_size=vocab,
+        max_seq_len=lp, rope_theta=1e6, tie_embeddings=False))
+    dec = LlamaDecoder(net, max_len=lp)     # never initialized: shapes only
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layer = dict(ln_in=sds(hidden), q=sds(heads * hd, hidden),
+                 k=sds(kv_heads * hd, hidden), v=sds(kv_heads * hd, hidden),
+                 o=sds(hidden, heads * hd), ln_post=sds(hidden),
+                 gate=sds(ffn, hidden), up=sds(ffn, hidden),
+                 down=sds(hidden, ffn))
+    w = dict(layers=[layer, layer], emb=sds(vocab, hidden),
+             norm=sds(hidden), head=sds(vocab, hidden))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            compiled = jax.jit(functools.partial(
+                dec._prefill_rows_impl, flash=flash)).lower(
+                    w, sds(1, lp, dtype=jnp.int32),
+                    sds(1, dtype=jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    text = compiled.as_text()
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    # the compiler drops the unit batch axis
+    scores = re.findall(rf"\w+\[(?:1,)?{heads},{lp},{lp}\]", text)
+    # jnp.repeat of K or V: a broadcast over the group, then a reshape
+    repeats = re.findall(
+        rf"= \w+\[(?:1,)?{kv_heads},{heads // kv_heads},{lp},{hd}\]\S* "
+        r"broadcast\(", text)
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    if flash:
+        assert kernels == 2 and not scores and not repeats
+        assert "prefill_flash_attention" in text
+        assert temps < 1e9, temps
+    else:
+        assert kernels == 0 and f"f32[{heads},{lp},{lp}]" in scores
+        assert repeats and temps > 4e9, temps

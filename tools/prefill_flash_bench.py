@@ -1,0 +1,176 @@
+#!/usr/bin/env python
+"""The served prefill's flash forward kernel alone, on the chip: TFLOP/s
+of the causal half at 32 query / 8 KV heads over tile sizes and prompt
+buckets, against ``ops.attention.masked_attention`` at the same shapes,
+and the two-layer prefill program a bucket, kernel against dense path.
+
+    chiprun -- python tools/prefill_flash_bench.py [--quick]
+
+Each timing is one jitted program of ``CALLS`` chained calls (the output
+feeds the next call's queries, as a decoder's layers do), run ``REPS``
+times after a warm-up; the median over REPS, over CALLS.  Operations
+counted: QK^T and PV over the ``n (n + 1) / 2`` pairs a causal prompt of
+``n`` tokens needs (``chipbench/flops_bytes/llama_prefill.py``'s count).
+Lines of JSON to stdout and ``chiprun_out/prefill_flash_bench.jsonl``.
+There is no CPU mode: a CPU time is no reading of the kernel.
+"""
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
+CALLS, REPS = 8, 7
+H, HKV = 32, 8
+#: (block_q, block_k) tried at 4 query heads a KV head of 128; 512 x 1024
+#: and wider need more VMEM than a kernel gets by default
+SWEEP = [(128, 128), (128, 512), (256, 256), (256, 512), (512, 256),
+         (512, 512), (128, 1024), (256, 1024), (128, 2048), (256, 2048),
+         (128, 4096)]
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true",
+                    help="the chosen tiles only, no sweep")
+    ap.add_argument("--tiles", default=",".join(
+        f"{a}:{b}" for a, b in SWEEP),
+        help="block_q:block_k pairs of the sweep at heads of 128")
+    ap.add_argument("--kernel-only", action="store_true",
+                    help="skip the two-layer prefill programs")
+    args = ap.parse_args()
+    sweep = [tuple(int(n) for n in t.split(":"))
+             for t in args.tiles.split(",")]
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops.attention import masked_attention
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit("prefill_flash_bench measures the chip: no TPU")
+    os.makedirs("chiprun_out", exist_ok=True)
+    sink = open("chiprun_out/prefill_flash_bench.jsonl", "a")
+
+    def say(**rec):
+        line = json.dumps(rec)
+        print(line, flush=True)
+        sink.write(line + "\n")
+        sink.flush()
+
+    def timed(fn, *operands, calls=CALLS):
+        """Median seconds of one of the ``calls`` chained in ``fn``."""
+        out = fn(*operands)
+        jax.block_until_ready(out)
+        took = []
+        for _ in range(REPS):
+            t = time.perf_counter()
+            jax.block_until_ready(fn(*operands))
+            took.append(time.perf_counter() - t)
+        return statistics.median(took) / calls
+
+    def chain(attend):
+        def run(q, k, v, n):
+            for _ in range(CALLS):
+                q = attend(q, k, v, n)
+            return q
+        return jax.jit(run)
+
+    def operands(hd, lp, hkv=HKV):
+        keys = jax.random.split(jax.random.PRNGKey(lp + hd), 3)
+        return (jax.random.normal(keys[0], (1, H, lp, hd), jnp.bfloat16),
+                jax.random.normal(keys[1], (1, hkv, lp, hd), jnp.bfloat16),
+                jax.random.normal(keys[2], (1, hkv, lp, hd), jnp.bfloat16))
+
+    def flops(hd, n):
+        return 4 * H * hd * (n * (n + 1) // 2)
+
+    def kernel(bq, bk, hd):
+        return chain(lambda q, k, v, n: fa._fa_forward_pallas(
+            q, k, v, True, 1.0 / float(np.sqrt(hd)), bq, bk, lengths=n,
+            name="prefill_flash_attention"))
+
+    # -- the kernel alone ---------------------------------------------------
+    for hd in (128, 64):
+        for lp in (4096, 2048, 1024, 512, 256, 128):
+            q, k, v = operands(hd, lp)
+            full = jnp.full((1,), lp, jnp.int32)
+            mask = jnp.tril(jnp.ones((lp, lp), bool))
+            dense = timed(chain(lambda q, k, v, n: masked_attention(
+                q, k, v, mask)), q, k, v, full)
+            say(what="dense", hd=hd, lp=lp, ms=dense * 1e3,
+                tflops=flops(hd, lp) / dense / 1e12)
+            chosen = fa.prefill_tiles(H // HKV, lp)
+            tiles = [chosen] if args.quick or hd == 64 or lp < 512 else \
+                sorted({(min(a, lp), min(b, lp)) for a, b in sweep}
+                       | {chosen})
+            for bq, bk in tiles:
+                try:
+                    took = timed(kernel(bq, bk, hd), q, k, v, full)
+                except Exception as e:      # a tile Mosaic refuses
+                    say(what="kernel", hd=hd, lp=lp, bq=bq, bk=bk,
+                        error=str(e)[:200])
+                    continue
+                say(what="kernel", hd=hd, lp=lp, bq=bq, bk=bk,
+                    chosen=(bq, bk) == chosen, ms=took * 1e3,
+                    tflops=flops(hd, lp) / took / 1e12)
+            # the p90 prompt of mistral7b.doc_prefill fills 63% of its
+            # bucket: what skipping the tiles past the true length buys
+            n63 = int(lp * 0.63)
+            bq, bk = chosen
+            took = timed(kernel(bq, bk, hd), q, k, v,
+                         jnp.full((1,), n63, jnp.int32))
+            say(what="kernel_len63", hd=hd, lp=lp, bq=bq, bk=bk,
+                ms=took * 1e3, tflops=flops(hd, n63) / took / 1e12)
+            if hd == 128 and lp >= 512 and not args.quick:
+                # a K/V index map h -> h // G in place of the group in
+                # the tile reads K and V once a query head: the same
+                # bytes as this call on repeated K/V, a head a grid row
+                kr, vr = (jnp.repeat(a, H // HKV, axis=1) for a in (k, v))
+                for bq, bk in ((512, 512), (256, 512), (512, 1024)):
+                    bq, bk = min(bq, lp), min(bk, lp)
+                    took = timed(kernel(bq, bk, hd), q, kr, vr, full)
+                    say(what="kernel_head_a_row", hd=hd, lp=lp, bq=bq,
+                        bk=bk, ms=took * 1e3,
+                        tflops=flops(hd, lp) / took / 1e12)
+
+    if args.kernel_only:
+        return
+    # -- the prefill program, two layers at Mistral-7B's widths -------------
+    import mxnet_tpu as mx
+    from mxnet_tpu.models.llama import (LlamaConfig, LlamaDecoder,
+                                        LlamaForCausalLM)
+
+    mx.random.seed(3)
+    net = LlamaForCausalLM(LlamaConfig(
+        hidden_size=4096, intermediate_size=14336, num_layers=2,
+        num_heads=H, num_kv_heads=HKV, vocab_size=32768, max_seq_len=4096,
+        rope_theta=1e6, tie_embeddings=False))
+    net.cast("bfloat16")
+    net.collect_params().setattr("grad_req", "null")
+    net.initialize(mx.init.Normal(0.02))
+    dec = LlamaDecoder(net, max_len=4096)
+    w = dec._weights()
+    prog = jax.jit(lambda w, ids, t0, flash: dec._prefill_rows_impl(
+        w, ids, t0, flash)[1], static_argnums=3)
+    for lp in (32, 64, 128, 256, 512, 1024, 2048, 4096):
+        ids = jnp.ones((1, lp), jnp.int32)
+        t0 = jnp.full((1,), lp, jnp.int32)
+        rec = {"what": "program_2_layers", "lp": lp}
+        for flash in (False, True):
+            if flash and lp % 128:
+                continue
+            rec["flash_ms" if flash else "dense_ms"] = \
+                timed(prog, w, ids, t0, flash, calls=1) * 1e3
+        say(**rec)
+
+
+if __name__ == "__main__":
+    main()
