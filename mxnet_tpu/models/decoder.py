@@ -4,7 +4,9 @@ its one attention runs over, and the four paged programs, written once.
 Reference: NONE (the reference predates LLM serving).
 
 A served architecture is a :class:`PagedDecoder` subclass, built by the
-net's ``serving_decoder(max_len)`` hook, that supplies ``cache_spec()``,
+net's ``serving_decoder(max_len)`` hook, that supplies ``cache_spec()``
+(which layers keep what, and how the model decodes: the next token a
+step, or :class:`BlockDecoding`, the positions of a block in any order),
 ``_weights()`` (``layers``: a dict a layer; ``emb``), ``layer(p, x, rope,
 view) -> (x, kept, expert rows or None)``, ``_logits(w, x)`` and, for a
 state layer, ``_sequence_state(kept, t0)``: what prefill keeps of a
@@ -22,6 +24,7 @@ under ``ops/``.
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import numpy as np
 
@@ -30,9 +33,30 @@ from ..ops import paged_attention
 from ..ops.attention import masked_attention
 from ..ops.flash_attention import prefill_flash_attention
 
-__all__ = ["CacheSpec", "PagedDecoder", "Causal", "BehindPrefix",
-           "DenseCache", "StepView", "rms_norm", "split_heads",
-           "rope_tables", "apply_rope"]
+__all__ = ["CacheSpec", "BlockDecoding", "PagedDecoder", "Causal",
+           "BehindPrefix", "DenseCache", "StepView", "rms_norm",
+           "split_heads", "rope_tables", "apply_rope",
+           "headnorm_attention", "block_commit"]
+
+
+class BlockDecoding(NamedTuple):
+    """How a block-diffusion decoder generates (``CacheSpec.decoding``):
+    a block of ``block_len`` positions at a time, each position holding
+    ``mask_id`` until a pass commits it; ``steps`` denoising passes a
+    block at most, ``block_len / steps`` commits a pass at least (the
+    remainder on the first passes), every masked position whose
+    confidence passes ``threshold`` where those are more; then one pass
+    more over the finished block, whose keys and values stay."""
+
+    block_len: int
+    mask_id: int
+    steps: int
+    threshold: float
+
+    def schedule(self):
+        """Commits a pass at least, pass by pass: ``(steps,)`` ints."""
+        base, rem = divmod(self.block_len, self.steps)
+        return tuple(base + (i < rem) for i in range(self.steps))
 
 
 class CacheSpec:
@@ -46,13 +70,16 @@ class CacheSpec:
     whole at admission and in place by every step).  ``expert_layers``
     x ``num_experts`` is the shape of the per-expert row counts that
     the step and prefill programs of a model with routed experts
-    return beside their tokens (0: none)."""
+    return beside their tokens (0: none).  ``decoding``: None for a
+    decoder that yields the next token a step, left to right, or the
+    :class:`BlockDecoding` of one that commits the positions of a block
+    in any order."""
 
     __slots__ = ("layers", "num_kv_heads", "head_dim", "state_shape",
-                 "expert_layers", "num_experts")
+                 "expert_layers", "num_experts", "decoding")
 
     def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
-                 expert_layers=0, num_experts=0):
+                 expert_layers=0, num_experts=0, decoding=None):
         self.layers = tuple(layers)
         if any(kind not in ("kv", "state") for kind in self.layers):
             raise MXNetError(f"unknown cache kind in {self.layers}")
@@ -62,6 +89,7 @@ class CacheSpec:
             else tuple(int(d) for d in state_shape)
         self.expert_layers = int(expert_layers)
         self.num_experts = int(num_experts)
+        self.decoding = decoding
         if self.state_layers and self.state_shape is None:
             raise MXNetError("state layers need a state_shape")
 
@@ -129,6 +157,52 @@ def split_heads(a, n):
     return a.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
 
 
+def headnorm_attention(p, u, rope, view, num_heads, num_kv_heads, eps):
+    """GQA with an RMSNorm over each head of q and k (one learned weight
+    of ``head_dim``, shared by the heads) BEFORE RoPE, over a cache
+    view: ``u`` (B, T, H), or a step's (S, H); ``rope`` the (cos, sin)
+    rows of the call's positions over heads-major q and k; ``p`` holds
+    ``q`` / ``k`` / ``v`` / ``o`` (out, in) and ``q_norm`` / ``k_norm``
+    -> (y, what the view kept)."""
+    q = rms_norm(split_heads(u @ p["q"].T, num_heads), p["q_norm"], eps)
+    k = rms_norm(split_heads(u @ p["k"].T, num_kv_heads), p["k_norm"], eps)
+    v = split_heads(u @ p["v"].T, num_kv_heads)
+    q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+    ctx, kept = view.attend(q, k, v)
+    return ctx.reshape(*u.shape[:-1], -1) @ p["o"].T, kept
+
+
+def block_commit(logits, ids, masked, step, decoding):
+    """A block decoder's commit rule, in float32 on the device:
+    ``logits`` (S, B, V) of one pass over each slot's block, ``ids``
+    (S, B) what the block holds (the mask id where ``masked`` (S, B)),
+    ``step`` (S,) the block's denoising pass, ``decoding`` the
+    :class:`BlockDecoding`.  A row's candidate is its argmax and its
+    confidence the candidate's softmax probability; among the masked
+    rows those above the threshold are committed where they are at
+    least the pass's share of the schedule, else that many of the most
+    confident (a tie goes to the earlier position).  A block without
+    masks commits nothing: its pass is the one that leaves its keys and
+    values.  -> (ids with the committed rows decided, commit (S, B))."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("block_commit"):
+        lf = logits.astype(jnp.float32)
+        x0 = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+        conf = 1.0 / jnp.exp(lf - lf.max(axis=-1, keepdims=True)).sum(-1)
+        conf = jnp.where(masked, conf, -jnp.inf)
+        need = jnp.asarray(decoding.schedule(), jnp.int32)[
+            jnp.clip(step, 0, decoding.steps - 1)]            # (S,)
+        high = conf > decoding.threshold
+        j = jnp.arange(ids.shape[1])
+        a, b = conf[:, :, None], conf[:, None, :]
+        ahead = (b > a) | ((b == a) & (j[None, None, :] < j[None, :, None]))
+        most = masked & (ahead.sum(-1) < need[:, None])
+        commit = jnp.where((high.sum(-1) >= need)[:, None], high, most)
+        return jnp.where(commit, x0, ids), commit
+
+
 # -- cache views ------------------------------------------------------------------
 
 class Causal:
@@ -139,22 +213,31 @@ class Causal:
     (B,): with them the attention is the flash forward kernel
     (``ops.flash_attention.prefill_flash_attention``: no score tensor
     and no repeated K/V in HBM, tiles past a row's length skipped);
-    without, ``masked_attention`` under ``tril``."""
+    without, ``masked_attention`` under ``tril``.  ``block``: a block
+    decoder's block length; position ``p`` then sees ``t < (p // block
+    + 1) * block``, whole earlier blocks and its own in both
+    directions."""
 
     pos = None
 
-    def __init__(self, t, live=None, lengths=None):
+    def __init__(self, t, live=None, lengths=None, block=None):
         import jax.numpy as jnp
 
-        self.live, self.lengths = live, lengths
-        if lengths is None:
+        self.live, self.lengths, self.block = live, lengths, block
+        if lengths is None and block is None:
             self.mask = jnp.tril(jnp.ones((t, t), bool))    # (Q, T)
+        elif lengths is None:
+            p = jnp.arange(t)
+            self.mask = p[None, :] < (p[:, None] // block + 1) * block
 
     def attend(self, q, k, v):
         if self.lengths is None:
             ctx = masked_attention(q, k, v, self.mask)
-        else:
+        elif self.block is None:
             ctx = prefill_flash_attention(q, k, v, self.lengths)
+        else:
+            ctx = prefill_flash_attention(q, k, v, self.lengths,
+                                          span=self.block)
         return ctx.transpose(0, 2, 1, 3), (k, v)
 
 
@@ -268,6 +351,11 @@ class PagedDecoder:
     with routed experts gets its row counts ``(expert layers, E)`` back
     third."""
 
+    #: a block decoder's block length (None: the next token a step): the
+    #: one thing that says its prefill mask and the windows of its
+    #: ``_verify_blocks_impl`` are a block's, not causal
+    block_len = None
+
     def __init__(self, net, max_len):
         import jax.numpy as jnp
 
@@ -293,7 +381,8 @@ class PagedDecoder:
     def _decode(self, w, cache, tables, x, pos, rope, paged_kernel):
         pool = next(e for e in cache if isinstance(e, tuple))[0]
         win = paged_attention.window(pool, tables, pos, self.max_len,
-                                     paged_kernel)
+                                     paged_kernel,
+                                     block=self.block_len is not None)
         x, cache, counts = self._layers(
             w, x, rope, (StepView(e, pos, win) for e in cache))
         return (self._logits(w, x), cache) + counts
@@ -340,7 +429,16 @@ class PagedDecoder:
         acceptance rule compares drafts against.  Rejected columns need
         no cleanup (``ops.paged_attention.write_rows``).  A decoder with
         per-slot state has no roll-back: its engine refuses
-        speculation."""
+        speculation.
+
+        A block decoder (``block_len``): the K columns are one block,
+        ``toks`` its current ids (the mask id where a position
+        is undecided) and ``pos0`` the block's first position: every
+        column sees the whole block and all before it, and column j's
+        logits are the distribution of token ``pos0 + j`` ITSELF (not
+        shifted).  The block's K/V is written in place every pass; the
+        pass over the finished block leaves what later blocks read (the
+        stale-row invariant covers the passes before it)."""
         import jax.numpy as jnp
 
         kk = toks.shape[1]
@@ -382,8 +480,8 @@ class PagedDecoder:
         t0 = jnp.asarray(t0, jnp.int32)
         # not the padded end
         real = jnp.arange(lp)[None] < (t0[:, None] if t0.ndim else t0)
-        causal = Causal(lp, real,
-                        jnp.broadcast_to(t0, (b,)) if flash else None)
+        lengths = jnp.broadcast_to(t0, (b,)) if flash else None
+        causal = Causal(lp, real, lengths, self.block_len)
         x, rows, counts = self._layers(w, x, rope,
                                        (causal for _ in w["layers"]))
         rows = [r if isinstance(r, tuple) else self._sequence_state(r, t0)

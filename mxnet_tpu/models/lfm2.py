@@ -33,8 +33,8 @@ from __future__ import annotations
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from .decoder import (CacheSpec, Causal, PagedDecoder, StepView, apply_rope,
-                      rms_norm, rope_tables, split_heads)
+from .decoder import (CacheSpec, Causal, PagedDecoder, StepView,
+                      headnorm_attention, rms_norm, rope_tables)
 from .llama import RMSNorm
 from .moe import routed_ffn
 
@@ -172,14 +172,8 @@ class Lfm2Math:
         of the call's positions over heads-major q and k -> (y, what the
         view kept)."""
         cfg = self.cfg
-        q = rms_norm(split_heads(u @ p["q"].T, cfg.num_heads),
-                     p["q_norm"], cfg.norm_eps)
-        k = rms_norm(split_heads(u @ p["k"].T, cfg.num_kv_heads),
-                     p["k_norm"], cfg.norm_eps)
-        v = split_heads(u @ p["v"].T, cfg.num_kv_heads)
-        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
-        ctx, kept = view.attend(q, k, v)
-        return ctx.reshape(*u.shape[:-1], -1) @ p["o"].T, kept
+        return headnorm_attention(p, u, rope, view, cfg.num_heads,
+                                  cfg.num_kv_heads, cfg.norm_eps)
 
     def ffn(self, p, u, live=None):
         """Dense SwiGLU, or the routed expert block -> (y, rows each

@@ -155,7 +155,7 @@ def _tile_runs(qi, kj, n, *, block_q, block_k, causal):
 
 
 def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
-               with_lse, bounded):
+               with_lse, bounded, span=1):
     """Canonical 3-D-grid flash kernel: grid (B * Hkv, nq, nk), kv
     innermost; running (m, l, acc) live in VMEM scratch across the kv
     sweep so pallas double-buffers the K/V block loads.
@@ -173,7 +173,13 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     lengths, and tiles wholly past a row's length are skipped (a q tile
     of padding alone yields zeros).  The mask is written on positions
     (``qpos >= kpos``), so a query offset behind a reused prefix is one
-    more scalar added to ``qpos``, not another kernel."""
+    more scalar added to ``qpos``, not another kernel.
+
+    ``span`` (static; divides both tiles): a block decoder's block
+    length.  A row then sees its whole block of ``span`` positions and
+    every block before it, ``kpos < (qpos // span + 1) * span``; the
+    blocks end where the tiles do, so the same tiles run and the same
+    ones pay for the mask."""
     from jax.experimental import pallas as pl
 
     len_ref = refs[0] if bounded else None
@@ -203,6 +209,8 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
                     rows, block_k)
             kpos = kj * block_k + lax.broadcasted_iota(
                 jnp.int32, (rows, block_k), 1)
+            if span > 1:
+                qpos = qpos // span * span + (span - 1)
             s = jnp.where(qpos >= kpos, s, -jnp.inf)
         # tq == tk and the kj == 0 tile runs first, so every row has met
         # key 0 and its running max is finite from its first tile on
@@ -252,10 +260,11 @@ def _divisor_block(t, pref):
 
 def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
                        with_lse=False, interpret=False, lengths=None,
-                       name=None):
+                       name=None, span=1):
     """q (B, H, T, D), k/v (B, Hkv, T, D) with Hkv dividing H -> (B, H,
     T, D)[, lse (B, H, T)].  ``lengths`` (B,) int32: each batch row's
-    true length (see ``_fa_kernel``'s ``bounded``)."""
+    true length (see ``_fa_kernel``'s ``bounded``); ``span``: a block
+    decoder's block length (its ``span``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -293,7 +302,7 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     out = pl.pallas_call(
         functools.partial(_fa_kernel, block_q=block_q, block_k=block_k,
                           causal=causal, scale=scale, nk=nk, kv_heads=hkv,
-                          with_lse=with_lse, bounded=bounded),
+                          with_lse=with_lse, bounded=bounded, span=span),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(bounded),
             grid=(bh, tq // block_q, nk),
@@ -362,24 +371,26 @@ def prefill_tiles(group, seq_len):
     return min(max(128, 1024 // group), seq_len), min(1024, seq_len)
 
 
-def _prefill_flash_attention(q, k, v, lengths, interpret=False):
+def _prefill_flash_attention(q, k, v, lengths, interpret=False, span=1):
     """Causal attention of whole prompts, GQA inside the kernel: ``q``
     (B, H, Lp, hd), ``k`` / ``v`` (B, Hkv, Lp, hd) after RoPE,
     ``lengths`` (B,) int32 true lengths -> (B, H, Lp, hd).  Rows below
     a length are ``masked_attention``'s under ``tril``; padded rows are
-    finite and read by nothing."""
+    finite and read by nothing.  ``span`` (static): a block decoder's
+    block length, which divides the lengths: a row then also sees the
+    rest of its own block (``_fa_kernel``)."""
     hd, lp = q.shape[-1], q.shape[2]
     bq, bk = prefill_tiles(q.shape[1] // k.shape[1], lp)
     return _fa_forward_pallas(
         q, k, v, True, 1.0 / float(np.sqrt(hd)), bq, bk,
         lengths=lengths, name="prefill_flash_attention",
-        interpret=interpret)
+        interpret=interpret, span=span)
 
 
 #: jitted, so that the layers of a prefill program share one trace and one
 #: Mosaic lowering of the kernel, as ``ops.paged_attention`` does
 prefill_flash_attention = jax.jit(_prefill_flash_attention,
-                                  static_argnames=("interpret",))
+                                  static_argnames=("interpret", "span"))
 
 
 # --- pallas backward kernels -------------------------------------------------

@@ -30,7 +30,9 @@ builds a view:
 
 The speculative verify is the same kernel with ``K`` query columns a
 slot: column ``j`` sees ``lengths[s] + j`` rows, and the ``K * H / Hkv``
-rows of a KV head share its keys.
+rows of a KV head share its keys.  A block decoder's pass (``block``,
+static) is that window without a causal order inside it: every column
+sees the whole window, ``lengths[s] + K - 1`` rows.
 
 Heads narrower than a 128-lane row (``head_dim`` 64) are stored packed:
 ``pack = 128 // head_dim`` consecutive KV heads share one lane row, the
@@ -192,18 +194,20 @@ class Window(NamedTuple):
     gat: jax.Array      #: the table, sentinel clamped; None under the kernel
     mask: jax.Array     #: (S, 1, K, T) what a column sees (gather path)
     live: jax.Array     #: the columns a request owns: no vacant slot's
+    block: bool = False  #: static: every column sees the whole window
 
 
-def window(pool, tables, pos, max_len, paged_kernel):
+def window(pool, tables, pos, max_len, paged_kernel, block=False):
     """The :class:`Window` of a decode call over ``tables`` (S, MB),
     vacant entries = ``num_blocks``.  ``pos`` (S,): a step, each slot's
     one new row at ``(tables[s, pos // bs], pos % bs)`` — the sentinel
     id is out of bounds, so vacant slots' writes DROP.  ``pos`` (S, K):
     K columns a slot (the speculative verify), each at its own absolute
-    position.  The gather path reads each slot's logical view through a
-    clamped table; garbage read through clamped sentinel entries sits
-    at positions the causal mask (``t <= pos``, per column) never
-    exposes."""
+    position; with ``block`` (static) the K columns are one block of a
+    block decoder and each sees all of them (``t <= pos[:, -1]``).  The
+    gather path reads each slot's logical view through a clamped table;
+    garbage read through clamped sentinel entries sits at positions the
+    mask (``t <= pos``, per column) never exposes."""
     nb, rows, bs, _ = pool.shape                    # as stored
     mb = tables.shape[1]
     t = jnp.arange(mb * bs)
@@ -214,7 +218,8 @@ def window(pool, tables, pos, max_len, paged_kernel):
         heads = jnp.arange(rows)[None, :]
         first, live = pos, tables[:, 0] < nb
     else:
-        mask = (t[None, None, :] <= pos[:, :, None])[:, None]
+        sees = pos[:, -1:] if block else pos
+        mask = (t[None, None, :] <= sees[:, :, None])[:, None]
         blk = jnp.take_along_axis(tables, jnp.minimum(pos // bs, mb - 1),
                                   axis=1)
         # columns past max_len have no legal row: force the sentinel so
@@ -225,9 +230,10 @@ def window(pool, tables, pos, max_len, paged_kernel):
         first = pos[:, 0]
         live = jnp.broadcast_to(tables[:, :1] < nb, pos.shape)
     if paged_kernel:
-        return Window(blk, off, heads, tables, first, None, None, live)
+        return Window(blk, off, heads, tables, first, None, None, live,
+                      block)
     return Window(blk, off, heads, tables, first,
-                  jnp.minimum(tables, nb - 1), mask, live)
+                  jnp.minimum(tables, nb - 1), mask, live, block)
 
 
 def write_rows(pool, win, rows):
@@ -263,7 +269,7 @@ def window_attention(q, k_pool, v_pool, win):
     if win.gat is None:
         return paged_decode_attention(
             q[:, :, 0, :] if step else q.transpose(0, 2, 1, 3),
-            k_pool, v_pool, win.tables, win.first + 1)
+            k_pool, v_pool, win.tables, win.first + 1, block=win.block)
     pack = k_pool.shape[3] // q.shape[-1]
     kc, vc = (gathered_view(p, win.gat, pack) for p in (k_pool, v_pool))
     ctx = masked_attention(q, kc, vc, win.mask)
@@ -294,7 +300,7 @@ def _schedule(tables, lengths, num_blocks, block_size, chunk):
 def _kernel(len_ref, nblk_ref, par_ref, nxt_ref, tab_ref,
             q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, k_sem, v_sem, m_ref, l_ref, acc_ref,
-            *, chunk, max_blocks, group, scale):
+            *, chunk, max_blocks, group, scale, block):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -346,9 +352,12 @@ def _kernel(len_ref, nblk_ref, par_ref, nxt_ref, tab_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # a row of a KV head's tile is (column j, query head g): it sees
-        # lengths + j rows of what was fetched (pad rows ride along)
+        # lengths + j rows of what was fetched (pad rows ride along);
+        # the columns of a block decoder's window all see its last row
         col = lax.broadcasted_iota(jnp.int32, (m_ref.shape[1], 1),
                                    0) // group
+        if block:
+            col = block - 1       # ``block``: the window's columns, or 0
         bound = jnp.minimum(len_ref[s] + col, nblk * bs)
         par = par_ref[s]
         follower = nxt_ref[s + 1]
@@ -398,7 +407,7 @@ def _kernel(len_ref, nblk_ref, par_ref, nxt_ref, tab_ref,
 
 def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
                             blocks_per_chunk=BLOCKS_PER_CHUNK,
-                            interpret=False):
+                            interpret=False, block=False):
     """Decode attention of one new token per slot over a paged KV pool.
 
     ``q`` (S, H, hd) after RoPE; ``k_pool`` / ``v_pool`` ``(num_blocks,
@@ -410,7 +419,9 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
     owned block yields zeros.
 
     With ``q`` (S, K, H, hd), the speculative verify's window, column
-    ``j`` attends ``lengths + j`` rows; returns (S, K, H, hd)."""
+    ``j`` attends ``lengths + j`` rows; returns (S, K, H, hd).  With
+    ``block`` (static) the K columns are a block decoder's block: each
+    attends ``lengths + K - 1`` rows, the whole window."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -445,7 +456,8 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
     qspec = pl.BlockSpec((1, hkv, gp, lanes), lambda i, *_: (i, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, max_blocks=mb, group=g,
-                          scale=1.0 / float(np.sqrt(hd))),
+                          scale=1.0 / float(np.sqrt(hd)),
+                          block=cols if block else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(s,),
@@ -486,4 +498,4 @@ def _paged_decode_attention(q, k_pool, v_pool, tables, lengths,
 #: eagerly: its simulated copies deadlock now and then inside a jit.
 paged_decode_attention = jax.jit(
     _paged_decode_attention, static_argnames=("blocks_per_chunk",
-                                              "interpret"))
+                                              "interpret", "block"))

@@ -39,6 +39,16 @@ engine lifetime per mesh.  A dp axis is NOT this engine's business:
 the server splits a dp×tp mesh into per-replica tp submeshes and runs
 one engine per replica (serving/lanes.py).
 
+How a model decodes is its decoder's to say (``cache_spec().decoding``).
+None: the next token a slot a step, left to right.  A
+:class:`~mxnet_tpu.models.decoder.BlockDecoding`: the engine's decode
+program is one PASS over every slot's block of positions (still
+``_step_fn``: the decoder's ``_verify_blocks_impl``, then the commit rule
+on the device), :meth:`LlamaServingEngine.step` answers a
+:class:`BlockTick`, the prefill stores the prompt's whole blocks and
+yields the opening block instead of a token, and the host keeps each
+slot's block (ids, which are undecided, its pass) between ticks.
+
 Thread discipline: the prefill lane and the decode lane share one
 engine.  ``dev_lock`` serializes every dispatch that MUTATES the KV
 storage (decode step, handoff scatter, slot clears); the prefill
@@ -60,6 +70,7 @@ from __future__ import annotations
 
 import threading
 import time
+from typing import NamedTuple
 
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -166,14 +177,41 @@ _STATE_REFUSALS = {
             "its load dtype",
 }
 
+#: and for a model that decodes by blocks (``CacheSpec.decoding``)
+_BLOCK_REFUSALS = {
+    "slots": "kv_mode='slots' decodes one token a slot a step under a "
+             "causal mask: a block decoder needs kv_mode='paged'",
+    "spec": "speculative decoding (draft_net / spec_k) verifies a "
+            "left-to-right draft; a block decoder commits the positions "
+            "of a block in any order",
+    "mesh": _STATE_REFUSALS["mesh"],
+    "int8": _STATE_REFUSALS["int8"],
+}
+
+
+class BlockTick(NamedTuple):
+    """What one pass of a block-decoding engine did, slot by slot
+    (``LlamaServingEngine.step`` of such an engine), vacant and
+    unadopted slots reading as nothing done."""
+
+    pos0: np.ndarray     #: (S,) each block's first position
+    ids: np.ndarray      #: (S, B) what the blocks hold after the pass
+    commit: np.ndarray   #: (S, B) the positions this pass decided
+    step: np.ndarray     #: (S,) which denoising pass of its block it was
+    #: (S,) the slots whose block held no mask: their pass left the
+    #: block's keys and values, their cursor moved on, and the block
+    #: took ``step + 1`` passes in all
+    stored: np.ndarray
+
 
 class LlamaServingEngine:
     """Device-side half of continuous batching for any model that
     answers ``serving_decoder(max_len)`` with a decoder holding the
     paged programs (step, prefill rows) and a ``cache_spec()`` —
-    ``LlamaForCausalLM`` (every layer a K/V pool) and
+    ``LlamaForCausalLM`` (every layer a K/V pool),
     ``Lfm2MoeForCausalLM`` (K/V pools beside per-slot states, routed
-    experts) today."""
+    experts) and ``SdarMoeForCausalLM`` (a block decoder: its
+    ``cache_spec().decoding`` says so) today."""
 
     def __init__(self, net, max_len=None, num_slots=4, int8=False,
                  kv_mode="slots", block_size=16, num_blocks=None,
@@ -201,13 +239,18 @@ class LlamaServingEngine:
         self._dec = dec
         #: the model's answer: which layers keep K/V, which a state
         spec = self.cache_spec = dec.cache_spec()
-        if spec.state_layers or spec.expert_layers:
+        #: how the model decodes: None (the next token a slot a step),
+        #: or the decoder's ``BlockDecoding``
+        block = self.block = spec.decoding
+        self.decoding = "next_token" if block is None else "block_diffusion"
+        if spec.state_layers or spec.expert_layers or block is not None:
+            why = _STATE_REFUSALS if block is None else _BLOCK_REFUSALS
             for key, bad in (("slots", kv_mode != "paged"),
                              ("spec", self.spec_k),
                              ("mesh", mesh is not None),
                              ("int8", self.int8)):
                 if bad:
-                    raise MXNetError(_STATE_REFUSALS[key])
+                    raise MXNetError(why[key])
         w = dec._weights()
         self._w = _quantize_tree(w) if self.int8 else w
         deq = _dequantize_tree if self.int8 else (lambda t: t)
@@ -268,6 +311,18 @@ class LlamaServingEngine:
         # host mirrors: last emitted token + next write position per slot
         self._last = np.zeros(self.num_slots, np.int32)
         self._pos = np.zeros(self.num_slots, np.int32)
+        if block is not None:
+            # a block decoder's mirrors: ``_pos`` is the block's first
+            # position; what the block holds, which of its positions are
+            # undecided and which denoising pass comes next
+            bl = block.block_len
+            self._blk_ids = np.zeros((self.num_slots, bl), np.int32)
+            self._blk_masked = np.zeros((self.num_slots, bl), bool)
+            self._blk_step = np.zeros(self.num_slots, np.int32)
+            #: passes that stored blocks took, blocks stored, tokens
+            #: committed: over every tick (``server.stats()``)
+            self.block_totals = {"block_passes": 0, "blocks_committed": 0,
+                                 "committed_tokens": 0}
         self.steps = 0
         #: (t_lock, t_disp0, t_disp1, t_tok) of the last step()/verify():
         #: before dev_lock, lock held, jitted call returned and lock
@@ -309,14 +364,19 @@ class LlamaServingEngine:
                               "experts_touched": 0, "expert_rows_max": 0}
         if kv_mode == "paged":
 
-            def _tokens(logits, out):
+            def _behind(first, out):
                 # a model with routed experts returns their row counts
-                # third: they go out in the same array as the tokens
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # third: they go out in the same array as what the host
+                # fetches, behind it
+                first = first.reshape(-1)
                 if self._n_counts:
-                    tok = jnp.concatenate(
-                        [tok, out[2].reshape(-1).astype(jnp.int32)])
-                return tok
+                    first = jnp.concatenate(
+                        [first, out[2].reshape(-1).astype(jnp.int32)])
+                return first
+
+            def _tokens(logits, out):
+                return _behind(jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                               out)
 
             def _step_fn(wq, pools, tables, ids, pos):
                 out = dec._step_blocks_impl(
@@ -333,6 +393,46 @@ class LlamaServingEngine:
                     deq(wq), ids, t0, flash=self._prefill_flash(ids.shape[1]))
                 rows, logits = out[:2]
                 return _tokens(logits, out), rows
+
+            if block is not None:
+                from ..models.decoder import block_commit
+
+                def _step_fn(wq, pools, tables, ids, pos0, masked, nstep):
+                    # one pass over every slot's block: (S, B) ids in,
+                    # the block's K/V written in place, the commit rule
+                    # on the device; out go the ids after the pass and
+                    # what it committed.  A block without masks commits
+                    # nothing: its pass is the one whose K/V stays
+                    out = dec._verify_blocks_impl(
+                        deq(wq), pools, tables, ids, pos0,
+                        paged_kernel=paged_kernel)
+                    logits, pools = out[:2]
+                    ids, commit = block_commit(logits, ids, masked, nstep,
+                                               block)
+                    tok = _behind(jnp.concatenate(
+                        [ids, commit.astype(jnp.int32)], axis=1), out)
+                    if numerics_on:
+                        return tok, pools, _numerics.stats_of(logits)
+                    return tok, pools
+
+                def _prefill_fn(wq, ids, t0):
+                    # the prompt's whole blocks only, and no token: what
+                    # comes back first is the opening block, the rest
+                    # of the prompt and then mask ids (the last row's
+                    # logits are returned to nobody: dead to the compiler)
+                    bl = block.block_len
+                    whole = t0 // bl * bl
+                    out = dec._prefill_rows_impl(
+                        deq(wq), ids, whole,
+                        flash=self._prefill_flash(ids.shape[1]))
+                    at = whole[:, None] \
+                        + jnp.arange(bl, dtype=jnp.int32)[None]
+                    opening = jnp.where(
+                        at < t0[:, None],
+                        jnp.take_along_axis(
+                            ids, jnp.minimum(at, ids.shape[1] - 1), axis=1),
+                        jnp.int32(block.mask_id))
+                    return _behind(opening, out), out[0]
 
             def _verify_fn(wq, pools, tables, toks, pos0):
                 logits, pools = dec._verify_blocks_impl(
@@ -538,14 +638,20 @@ class LlamaServingEngine:
         return int(blocks + state)
 
     def split_fetch(self, fetched, n):
-        """A step's or prefill's fetched vector -> (its ``n`` tokens,
-        the lane-log fields of the expert row counts behind them; {}
-        for a model that routes nothing).  Counts enter the totals."""
+        """A step's or prefill's fetched vector -> (the tokens of its
+        ``n`` rows, the lane-log fields of the expert row counts behind
+        them; {} for a model that routes nothing).  Counts enter the
+        totals.  A block decoder's rows are ``(n, ...)``: a prefill's
+        opening blocks, a pass's ids beside what it committed."""
+        head = len(fetched) - self._n_counts
+        toks = fetched[:head]
+        if self.block is not None:
+            toks = toks.reshape(n, -1)
         if not self._n_counts:
-            return fetched, {}
+            return toks, {}
         spec = self.cache_spec
-        counts = np.asarray(fetched[n:]).reshape(spec.expert_layers,
-                                                 spec.num_experts)
+        counts = np.asarray(fetched[head:]).reshape(spec.expert_layers,
+                                                    spec.num_experts)
         touched = counts > 0
         fields = {
             "experts_touched": int(touched.sum()),
@@ -558,7 +664,7 @@ class LlamaServingEngine:
         tot["experts_touched"] += fields["experts_touched"]
         tot["expert_rows_max"] = max(tot["expert_rows_max"],
                                      fields["expert_rows_max"])
-        return fetched[:n], fields
+        return toks, fields
 
     # -- transitions (slots mode: legacy single-loop scheduler) ---------------
     def admit(self, prompts_pad, t0s, slots):
@@ -644,8 +750,17 @@ class LlamaServingEngine:
                     blocks = block_lists[i]
                     row[:len(blocks)] = blocks
                     self._tables[s] = row
-                    self._last[s] = first[i]
-                    self._pos[s] = t0s[i]
+                    if self.block is None:
+                        self._last[s] = first[i]
+                        self._pos[s] = t0s[i]
+                    else:
+                        # the cursor stands at the prompt's last whole
+                        # block; ``first[i]`` is the block it opens
+                        bl = self.block.block_len
+                        self._pos[s] = t0s[i] // bl * bl
+                        self._blk_ids[s] = first[i]
+                        self._blk_masked[s] = np.arange(bl) >= t0s[i] % bl
+                        self._blk_step[s] = 0
         return t_lock, time.perf_counter()
 
     def gather_prefix(self, rows_idx):
@@ -684,7 +799,8 @@ class LlamaServingEngine:
     def step(self, active):
         """One decode step over ALL slots; returns the (num_slots,)
         next-token vector on host and advances the ``active`` slots'
-        mirrors.  Vacant slots run at pos 0 with token 0 — their output
+        mirrors (a block decoder: one pass over every slot's block,
+        and the :class:`BlockTick` of :meth:`_book_block`).  Vacant slots run at pos 0 with token 0 — their output
         is never read, and their K/V write lands in their own slot row
         (slots mode) or is dropped at the sentinel block (paged).  The
         device lock covers dispatch and mirror updates, NOT the host
@@ -699,7 +815,14 @@ class LlamaServingEngine:
                                  seq=self.steps + 1,
                                  replica=self.replica_id):
                 # (tokens, storage[, logit stats under numerics])
-                if self.kv_mode == "paged":
+                if self.block is not None:
+                    out = self._step(
+                        self._w, self._pool, self._dev(self._tables),
+                        self._dev(self._blk_ids), self._dev(self._pos),
+                        self._dev(self._blk_masked, bool),
+                        self._dev(self._blk_step))
+                    self._pool = out[1]
+                elif self.kv_mode == "paged":
                     out = self._step(
                         self._w, self._pool, self._dev(self._tables),
                         self._dev(self._last), self._dev(self._pos))
@@ -724,6 +847,8 @@ class LlamaServingEngine:
             out = _materialize([toks])[0]
         self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
         out, self.tick_experts = self.split_fetch(out, self.num_slots)
+        if self.block is not None:
+            return self._book_block(out, active)
         with self.dev_lock:
             for s in active:
                 self._last[s] = out[s]
@@ -731,6 +856,38 @@ class LlamaServingEngine:
             # the step attended pos + 1 rows: the cursors as they are now
             self.tick_kv_tokens = int(self._pos[list(active)].sum())
         return out
+
+    def _book_block(self, out, active):
+        """A block pass's fetched ``(S, 2B)`` (ids, then what was
+        committed) into the ``active`` slots' mirrors -> the
+        :class:`BlockTick`.  A slot whose block held no mask has had the
+        pass that leaves the block's K/V: its cursor moves a block on
+        and a block of masks opens; any other takes the pass's commits
+        and goes to its next denoising pass."""
+        bl = self.block.block_len
+        act = np.asarray(active, np.intp)
+        with self.dev_lock:
+            mine = np.zeros(self.num_slots, bool)
+            mine[act] = True
+            tick = BlockTick(self._pos.copy(), out[:, :bl],
+                             out[:, bl:].astype(bool) & mine[:, None],
+                             self._blk_step.copy(),
+                             mine & ~self._blk_masked.any(axis=1))
+            done, going = act[tick.stored[act]], act[~tick.stored[act]]
+            self._pos[done] += bl
+            self._blk_ids[done] = self.block.mask_id
+            self._blk_masked[done] = True
+            self._blk_step[done] = 0
+            self._blk_ids[going] = tick.ids[going]
+            self._blk_masked[going] &= ~tick.commit[going]
+            self._blk_step[going] += 1
+            # every column of a block attends to the block's end
+            self.tick_kv_tokens = int(tick.pos0[act].sum()) + bl * len(act)
+            tot = self.block_totals
+            tot["block_passes"] += int(tick.step[done].sum()) + len(done)
+            tot["blocks_committed"] += len(done)
+            tot["committed_tokens"] += int(tick.commit.sum())
+        return tick
 
     def verify(self, drafts):
         """Speculative decode: ONE multi-position target forward over
@@ -799,6 +956,10 @@ class LlamaServingEngine:
             self._pos[slot] = 0
             if self._tables is not None:
                 self._tables[slot] = self.num_blocks
+            if self.block is not None:
+                self._blk_ids[slot] = 0
+                self._blk_masked[slot] = False
+                self._blk_step[slot] = 0
 
 
 class GenerativeScheduler:

@@ -21,6 +21,11 @@ connected by an explicit KV handoff:
   never sees a prompt forward: while a long prompt prefills, decode
   ticks keep dispatching (the device lock covers only the KV-mutating
   dispatches, not the prefill compute).
+  For a model that decodes by blocks (the engine's ``block``: what its
+  decoder's cache spec says) the tick is :meth:`DecodeLane._tick_block`:
+  one pass a slot's block, 0 to a block's length of tokens committed a
+  slot in any order of position, the cursor moved only by the pass over
+  a finished block; the prefill lane then hands over no first token.
 * :class:`Replica` — one engine + manager + lane pair over one (tp)
   submesh.  A dp mesh axis becomes N independent replicas behind one
   front queue, routed by :class:`ReplicaDispatcher` to the
@@ -102,7 +107,7 @@ class _Handoff:
     def __init__(self, req, slot, first):
         self.req = req
         self.slot = slot
-        self.first = first
+        self.first = first      # None: the prefill yielded no token
 
 
 class PrefillLane:
@@ -389,6 +394,9 @@ class PrefillLane:
         t_first = time.perf_counter()
         self.clock.enter("idle", t_first)
         mates = [req.id for req in group]
+        # a block decoder's prefill stores the prompt's whole blocks and
+        # yields no token: its requests' first comes from the decode lane
+        yields = getattr(eng, "block", None) is None
         if seq == 1:
             extra.update(_cache_layers(eng))
         # one stamp set for every consumer: the lane log, the capacity
@@ -403,7 +411,9 @@ class PrefillLane:
             prefill_attention=attention, **extra)
         capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
-            req.t_first = t_first
+            req.t_commit = t_first
+            if yields:
+                req.t_first = t_first
             if rx is not None and matched[i] and t0s_suf[i] > 0:
                 # prefill cost scales ~linearly in prompt tokens, so
                 # the saved share is the reused fraction scaled onto
@@ -425,7 +435,9 @@ class PrefillLane:
                               kv_blocks=req.kv_blocks,
                               bucket=list(req.bucket),
                               mates=[m for m in mates if m != req.id])
-            if mgr.consume(req.slot):
+            if not yields:
+                r.decode.hand_off(_Handoff(req, req.slot, None))
+            elif mgr.consume(req.slot):
                 # max_new_tokens == 1: done at prefill, never decodes
                 r.finish(req, [int(first[i])])
             else:
@@ -521,6 +533,8 @@ class DecodeLane:
     def _run(self):
         spec = self.r.spec_k > 0 and self.r.draft is not None
         tick = self._tick_spec if spec else self._tick
+        if getattr(self.r.engine, "block", None) is not None:
+            tick = self._tick_block
         while True:
             if self.pending():
                 # one turn: adopt, then advance every slot one tick
@@ -551,40 +565,45 @@ class DecodeLane:
                 h = self._handoffs.popleft()
             self._n_adopted += 1
             h.req.t_handoff = time.perf_counter()
-            hand_ms = (h.req.t_handoff - h.req.t_first) * 1e3
+            hand_ms = (h.req.t_handoff - h.req.t_commit) * 1e3
             telemetry.hist("serving.handoff_ms", hand_ms)
             telemetry.hist(f"serving.handoff_ms|replica={self.r.index}",
                            hand_ms)
             if h.req.trace is not None:
-                h.req.trace.add("handoff", h.req.t_first,
+                h.req.trace.add("handoff", h.req.t_commit,
                                 h.req.t_handoff, replica=self.r.index,
                                 slot=h.slot)
+            if h.first is None:
+                # a block decoder: output offset -> token, filled in any
+                # order, and the request's log of every commit
+                h.req.commits = []
+                tokens = {}
+            else:
+                tokens = [h.first]
             with self._hand_lock:
-                self._seqs[h.slot] = (h.req, [h.first])
+                self._seqs[h.slot] = (h.req, tokens)
 
-    def _tick(self):
+    def _abort(self, active, exc):
+        """The engine call of a tick raised: fail every active request
+        and free its slot."""
         r = self.r
-        with self._hand_lock:
-            active = sorted(self._seqs)
-            ids = tuple(self._seqs[s][0].id for s in active)
-        try:
-            toks = r.engine.step(active)
-        except Exception as exc:
-            for slot in active:
-                with self._hand_lock:
-                    req, _ = self._seqs.pop(slot)
-                r.mgr.evict(slot)
-                r.engine.clear_slot(slot)
-                req.future.set_exception(exc)
-                r.fail(req, exc, lane="decode")
-            r.capacity_evt.set()
-            tracing.incident("replica_exception",
-                             context={"replica": r.index,
-                                      "lane": "decode",
-                                      "error": repr(exc)})
-            return
-        stamps = self._engine_stamps()
-        _t_lock, t_disp0, _t_disp1, t_tok = stamps
+        for slot in active:
+            with self._hand_lock:
+                req, _ = self._seqs.pop(slot)
+            r.mgr.evict(slot)
+            r.engine.clear_slot(slot)
+            if r.draft is not None:
+                r.draft.clear_slot(slot)
+            req.future.set_exception(exc)
+            r.fail(req, exc, lane="decode")
+        r.capacity_evt.set()
+        tracing.incident("replica_exception",
+                         context={"replica": r.index, "lane": "decode",
+                                  "error": repr(exc)})
+
+    def _note_tick(self, active, t_busy0, t_tok):
+        """What every kind of tick counts once its engine call is back."""
+        r = self.r
         r.batches += 1
         telemetry.hist("serving.batch_size", len(active))
         telemetry.gauge("serving.kv_blocks_in_use",
@@ -597,9 +616,23 @@ class DecodeLane:
         if capacity.is_enabled():
             capacity.note_tick(r.index, len(active),
                                getattr(r.engine, "num_slots", len(active)),
-                               t_disp0, t_tok)
+                               t_busy0, t_tok)
             capacity.note_kv(r.index, r.mgr.allocator.free_blocks,
                              r.mgr.num_blocks)
+
+    def _tick(self):
+        r = self.r
+        with self._hand_lock:
+            active = sorted(self._seqs)
+            ids = tuple(self._seqs[s][0].id for s in active)
+        try:
+            toks = r.engine.step(active)
+        except Exception as exc:
+            self._abort(active, exc)
+            return
+        stamps = self._engine_stamps()
+        _t_lock, t_disp0, _t_disp1, t_tok = stamps
+        self._note_tick(active, t_disp0, t_tok)
         step_idx = r.engine.steps
         n_finished = 0
         with TraceAnnotation("mxt.decode.book", seq=step_idx,
@@ -627,6 +660,76 @@ class DecodeLane:
                           getattr(r.engine, "tick_kv_tokens", 0),
                           **getattr(r.engine, "tick_experts", {}))
 
+    def _tick_block(self):
+        """A block decoder's tick: one pass over every active slot's
+        block (``engine.step`` -> ``generative.BlockTick``).  A slot
+        commits 0 to a block's length of tokens, in any order of
+        position; its cursor, and the manager's, move only when the
+        pass was the one over its finished block.  A request's output
+        is the tokens at its first ``max_new_tokens`` positions behind
+        the prompt: it ends with the pass that commits the last of
+        them, wherever in a block that is.  ``req.commits`` keeps every
+        commit ``(position, token, the block's pass)``, those past the
+        output's end too: what each pass saw can be rebuilt from it."""
+        r = self.r
+        eng = r.engine
+        bl = eng.block.block_len
+        with self._hand_lock:
+            active = sorted(self._seqs)
+            ids = tuple(self._seqs[s][0].id for s in active)
+        try:
+            tick = eng.step(active)
+        except Exception as exc:
+            self._abort(active, exc)
+            return
+        stamps = self._engine_stamps()
+        _t_lock, t_disp0, _t_disp1, t_tok = stamps
+        self._note_tick(active, t_disp0, t_tok)
+        step_idx = eng.steps
+        n_finished = 0
+        with TraceAnnotation("mxt.decode.book", seq=step_idx,
+                             replica=r.index):
+            for slot in active:
+                with self._hand_lock:
+                    req, tokens = self._seqs[slot]
+                if req.first_tick is None:
+                    req.first_tick = step_idx
+                if req.trace is not None:
+                    req.trace.add("decode.step", t_disp0, t_tok,
+                                  step=step_idx, batch=len(active),
+                                  replica=r.index, slot=slot)
+                if tick.stored[slot]:
+                    # the block's K/V stays: the cursor is past it
+                    st = r.mgr.state(slot)
+                    r.mgr.advance_n(slot, min(int(tick.pos0[slot]) + bl,
+                                              int(st.reserved))
+                                    - int(st.pos))
+                    continue
+                done = False
+                first = int(tick.pos0[slot]) - len(req.prompt_ids)
+                for j in np.flatnonzero(tick.commit[slot]):
+                    tok = int(tick.ids[slot, j])
+                    req.commits.append((int(tick.pos0[slot]) + int(j), tok,
+                                        int(tick.step[slot])))
+                    if first + j < req.max_new_tokens:
+                        tokens[first + int(j)] = tok
+                        if req.t_first is None:
+                            req.t_first = t_tok
+                        done = r.mgr.consume(slot) or done
+                if done:
+                    with self._hand_lock:
+                        del self._seqs[slot]
+                    r.finish(req, [tokens[i]
+                                   for i in range(req.max_new_tokens)])
+                    n_finished += 1
+        self._record_tick(step_idx, ids, n_finished, stamps,
+                          eng.tick_kv_tokens, block_len=bl,
+                          rows=len(active) * bl,
+                          n_store=int(tick.stored.sum()),
+                          committed=int(tick.commit.sum()),
+                          block_passes=int((tick.step[tick.stored] + 1).sum()),
+                          **eng.tick_experts)
+
     def _record_tick(self, seq, ids, n_finished, stamps, kv_tokens,
                      **extra):
         """The turn's ``decode.tick`` record, its bookkeeping done.
@@ -641,6 +744,10 @@ class DecodeLane:
             extra["decode_attention"] = getattr(
                 self.r.engine, "decode_attention", None)
             extra["kv_pack"] = getattr(self.r.engine, "kv_pack", None)
+            extra["decoding"] = getattr(self.r.engine, "decoding", None)
+            block = getattr(self.r.engine, "block", None)
+            if block is not None:
+                extra["block_decoding"] = block._asdict()
             extra.update(_cache_layers(self.r.engine))
         tracing.lane_record(
             "decode.tick", replica=self.r.index, seq=seq,
@@ -689,33 +796,12 @@ class DecodeLane:
             pos0 = r.engine.positions()
             out = r.engine.verify(proposals)
         except Exception as exc:
-            for slot in active:
-                with self._hand_lock:
-                    req, _ = self._seqs.pop(slot)
-                r.mgr.evict(slot)
-                r.engine.clear_slot(slot)
-                r.draft.clear_slot(slot)
-                req.future.set_exception(exc)
-                r.fail(req, exc, lane="decode")
-            r.capacity_evt.set()
-            tracing.incident("replica_exception",
-                             context={"replica": r.index,
-                                      "lane": "decode",
-                                      "error": repr(exc)})
+            self._abort(active, exc)
             return
         # the verify's stamps; the k draft steps lie in [t0, t_lock]
         stamps = self._engine_stamps()
         t_lock, t_tok = stamps[0], stamps[3]
-        r.batches += 1
-        telemetry.hist("serving.batch_size", len(active))
-        telemetry.gauge("serving.kv_blocks_in_use",
-                        r.mgr.allocator.blocks_in_use)
-        if capacity.is_enabled():
-            capacity.note_tick(r.index, len(active),
-                               getattr(r.engine, "num_slots", len(active)),
-                               t0, t_tok)
-            capacity.note_kv(r.index, r.mgr.allocator.free_blocks,
-                             r.mgr.num_blocks)
+        self._note_tick(active, t0, t_tok)
         accepted_this_tick = 0
         accepted = {}       # request id -> tokens this tick committed
         n_finished = 0
@@ -820,6 +906,12 @@ class Replica:
                 self.engine.block_size, itemsize),
             state_bytes_per_slot=spec.state_bytes_per_slot(itemsize))
         self.radix = None
+        if radix_cache and spec.decoding is not None:
+            raise MXNetError(
+                "radix_cache=True shares a prompt prefix's K/V blocks "
+                "behind a causal suffix; a block decoder's prompt ends "
+                "inside a block that the decode lane opens, and its "
+                "prefill has no suffix path")
         if radix_cache and spec.state_layers:
             raise MXNetError(
                 "radix_cache=True shares a prompt prefix's K/V blocks; a "

@@ -53,14 +53,18 @@ class Request:
     pipeline and land verbatim in the per-request telemetry record:
     ``t_submit`` → ``t_start`` (dequeued into a batch; the delta is
     ``queue_wait_ms``) → ``t_first`` (generative: first token emitted;
-    delta from submit is ``ttft_ms``) → ``t_done``.
+    delta from submit is ``ttft_ms``) → ``t_done``.  ``t_commit``: the
+    prefill lane committed the prompt's K/V (with ``t_first`` where the
+    prefill forward yields the first token; a block decoder's comes
+    from the decode lane).  ``commits``: a block decoder's log of
+    every commit, ``(position, token, the block's pass)``.
     """
 
     __slots__ = ("id", "inputs", "length", "prompt_ids", "max_new_tokens",
                  "future", "t_submit", "t_start", "t_first", "t_done",
                  "batch_size", "bucket", "slot", "joined_step",
                  "first_tick", "done_step", "replica", "t_handoff",
-                 "kv_blocks",
+                 "kv_blocks", "t_commit", "commits",
                  "trace", "tenant", "draft_tokens", "accepted_tokens",
                  "prefix_hit_tokens", "prefill_saved_ms")
 
@@ -88,7 +92,9 @@ class Request:
         self.done_step = None
         # disaggregated-lane fields (paged path; see docs/observability.md)
         self.replica = None     # which dp replica served the request
+        self.t_commit = None    # prefill committed the prompt's K/V
         self.t_handoff = None   # decode lane adopted the prefilled KV
+        self.commits = None     # a block decoder's (position, token, pass)
         self.kv_blocks = None   # blocks reserved for the request
         # observability (r12): the request-scoped span context (a
         # telemetry.tracing.Trace, None while tracing is off — every
@@ -150,10 +156,12 @@ class Request:
             rec["replica"] = self.replica
         if self.kv_blocks is not None:
             rec["kv_blocks"] = self.kv_blocks
-        if self.t_handoff is not None and self.t_first is not None:
-            # prefill→decode KV handoff latency: first token emitted by
-            # the prefill forward → decode lane adopted the slot
-            rec["handoff_ms"] = (self.t_handoff - self.t_first) * 1e3
+        committed = self.t_first if self.t_commit is None else self.t_commit
+        if self.t_handoff is not None and committed is not None:
+            # prefill→decode KV handoff latency: the prompt's K/V
+            # committed (with the first token, where the prefill forward
+            # yields one) → decode lane adopted the slot
+            rec["handoff_ms"] = (self.t_handoff - committed) * 1e3
         if self.t_first is not None and self.t_start is not None:
             # prompt-processing wall time (dequeue → first token): the
             # figure the radix prefix cache exists to shrink

@@ -638,7 +638,16 @@ class GenerativeServer(_ServerBase):
             "num_replicas": len(reps),
             "kv_layers": reps[0].engine.cache_spec.kv_layers,
             "state_layers": reps[0].engine.cache_spec.state_layers,
+            # "next_token", or "block_diffusion" with the block sizes
+            "decoding": reps[0].engine.decoding,
         }
+        if reps[0].engine.block is not None:
+            out["block_decoding"] = reps[0].engine.block._asdict()
+            # passes that stored blocks took, blocks stored and tokens
+            # committed, over every tick (the lane log's block_passes /
+            # n_store / committed)
+            tots = [r.engine.block_totals for r in reps]
+            out["blocks"] = {k: sum(t[k] for t in tots) for k in tots[0]}
         if reps[0].engine.cache_spec.expert_layers:
             # what the lane log's experts_touched / expert_rows_max add
             # up to, over every step and prefill program

@@ -308,26 +308,53 @@ def test_train_dispatch_records(drive):
 
 # --- the profiler's clock ----------------------------------------------------
 
-def test_mxt_spans_land_in_the_xplane_with_the_logs_seq(tmp_path):
+def _tiny_block_decoder():
+    from mxnet_tpu.models.sdar import sdar_moe_tiny
+
+    net = sdar_moe_tiny()
+    net.initialize()
+    return net
+
+
+@pytest.mark.parametrize("make", [_tiny, _tiny_block_decoder],
+                         ids=["next_token", "block_diffusion"])
+def test_mxt_spans_land_in_the_xplane_with_the_logs_seq(tmp_path, make):
+    """Every tick and every prefill batch of the traced stretch has its
+    spans in the xplane under the log's ``seq``.  A request's future
+    resolves INSIDE its last tick (``mxt.decode.book``), so the trace
+    must not start or stop on a result alone: a tick that straddles
+    either end has its record in the log and its enclosing span outside
+    the trace (that failed one run in a few under load).  The trace
+    starts once the warm-up's last tick has written its record, and
+    stops after the server has: its lanes are joined, every span is
+    closed."""
     import jax
     from jax.profiler import ProfileData
 
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
-    srv = serving.GenerativeServer(_tiny(), ServerConfig(
+    srv = serving.GenerativeServer(make(), ServerConfig(
         max_batch=2, max_length=64, min_length=8, num_slots=2))
     rs = np.random.RandomState(1)
-    with srv:
-        srv.generate(rs.randint(1, 250, size=6), max_new_tokens=2)  # compile
-        since = time.perf_counter()
-        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
-        try:
+    try:
+        with srv:
+            srv.generate(rs.randint(1, 250, size=6), max_new_tokens=2)
+            lane = srv.replicas[0].decode
+            for _ in range(3000):       # the warm-up's last record is in
+                log = tracing.lane_log("decode.tick")
+                if not lane.pending() and log \
+                        and log[-1]["seq"] == srv.engine.steps:
+                    break
+                time.sleep(0.01)
+            time.sleep(0.1)             # and its span, closed right after
+            since = time.perf_counter()
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
             for f in [srv.submit(rs.randint(1, 250, size=6), max_new_tokens=4)
                       for _ in range(3)]:
                 f.result(120)
-        finally:
-            jax.profiler.stop_trace()
+    finally:
+        jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
                             / "*.xplane.pb"))
     seen = {}

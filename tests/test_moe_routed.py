@@ -17,11 +17,11 @@ from mxnet_tpu.models import moe
 N, H, I, E = 24, 16, 12, 8
 
 
-def _bank(seed=0):
+def _bank(seed=0, e=E):
     rs = np.random.RandomState(seed)
     f = lambda *s: (rs.randn(*s) * 0.3).astype(np.float32)  # noqa: E731
-    return dict(x=f(N, H), rw=f(E, H), wg=f(E, H, I), wu=f(E, H, I),
-                wd=f(E, I, H), b=(rs.randn(E) * 0.5).astype(np.float32))
+    return dict(x=f(N, H), rw=f(e, H), wg=f(e, H, I), wu=f(e, H, I),
+                wd=f(e, I, H), b=(rs.randn(e) * 0.5).astype(np.float32))
 
 
 def _oracle(t, k, score, bias, renormalize, scale, held=None):
@@ -35,7 +35,7 @@ def _oracle(t, k, score, bias, renormalize, scale, held=None):
         s = ex / ex.sum(-1, keepdims=True)
     pick = s + (t["b"] if bias else 0.0)
     y = np.zeros((len(t["x"]), H), np.float32)
-    counts = np.zeros(E, np.int64)
+    counts = np.zeros(len(t["rw"]), np.int64)
     for n in range(len(t["x"])):
         idx = np.argsort(-pick[n], kind="stable")[:k]
         w = s[n, idx]
@@ -115,18 +115,23 @@ def test_the_choice_uses_score_plus_bias_and_the_weights_the_score():
     assert sorted(np.asarray(idx0)[0].tolist()) == [0, 1]
 
 
-@pytest.mark.parametrize("k", [2, 3])
+@pytest.mark.parametrize("k,score,bias,experts", [
+    (2, "sigmoid", True, 8), (3, "sigmoid", True, 8),
+    (8, "softmax", False, 16),          # the Qwen3-MoE router's shape
+], ids=["2", "3", "8_of_16_softmax"])
 @pytest.mark.parametrize("share", [1, 2, 4])
-def test_the_shares_of_experts_held_add_up_to_the_uncut_layer(k, share):
-    """Eight experts over 8, 4 or 2 chips: every share routes over all
-    experts and computes its own experts' part; the parts add up to the
-    whole layer, and each agrees with the oracle restricted to its range."""
-    t = _bank(3)
-    whole, counts = _run(t, k)
+def test_the_shares_of_experts_held_add_up_to_the_uncut_layer(
+        k, score, bias, experts, share):
+    """Eight (or sixteen) experts over chips that hold 1, 2 or 4 each:
+    every share routes over all experts and computes its own experts'
+    part; the parts add up to the whole layer, and each agrees with the
+    oracle restricted to its range."""
+    t = _bank(3, experts)
+    whole, counts = _run(t, k, score, bias)
     total = np.zeros_like(whole)
-    for first in range(0, E, share):
-        part, c = _run(t, k, held=(first, share))
-        want, _ = _oracle(t, k, "sigmoid", True, True, 1.0,
+    for first in range(0, experts, share):
+        part, c = _run(t, k, score, bias, held=(first, share))
+        want, _ = _oracle(t, k, score, bias, True, 1.0,
                           held=(first, share))
         assert np.abs(part - want).max() < 1e-5
         assert (c == counts).all()              # routing is over all experts

@@ -649,8 +649,9 @@ def one_chip():
         (16, 256, 2048, 1, "float32", 32, 8, 128),  # a net served as trained
         (128, 64, 8192, 1, "bfloat16", 32, 8, 64),  # lfm2_24b.chat_decode_sat
         (128, 64, 8192, 4, "bfloat16", 32, 8, 64),  # heads of 64, verify k = 3
+        (128, 64, 8192, -4, "bfloat16", 32, 4, 128),  # sdar_30b: a BLOCK of 4
     ], ids=["chat_64x1024", "doc_16x4096", "verify_k3", "float32",
-            "lfm2_128x1024_hd64", "verify_k3_hd64"])
+            "lfm2_128x1024_hd64", "verify_k3_hd64", "sdar_block4"])
 def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
                                  cols, dtype, heads, kv_heads, hd):
     """Mosaic takes the kernel at the benchmark cells' shapes (32 query
@@ -662,6 +663,9 @@ def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    # negative columns: a block decoder's window, every column seeing
+    # all of them (8 query heads a KV head x 4 columns: 32 score rows)
+    block, cols = cols < 0, abs(cols)
     q = (slots, heads, hd) if cols == 1 else (slots, cols, heads, hd)
     dtype = jnp.dtype(dtype)
     pack = pa.applicable("tpu", None, hd, kv_heads, 16, dtype)
@@ -676,7 +680,8 @@ def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
     cc.reset_cache()
     try:
         with jax.enable_x64(False):
-            text = jax.jit(pa.paged_decode_attention).lower(
+            text = jax.jit(functools.partial(
+                pa.paged_decode_attention, block=block)).lower(
                 sds(q, dtype), pool, pool,
                 sds((slots, max_blocks), jnp.int32),
                 sds((slots,), jnp.int32)).compile().as_text()

@@ -135,7 +135,7 @@ class _StubEngine:
 class _StubReq:
     def __init__(self, rid):
         self.id = rid
-        self.t_first = 0.0
+        self.t_first = self.t_commit = 0.0
         self.t_handoff = None
         self.first_tick = None
         self.trace = None
