@@ -32,6 +32,7 @@ from ..base import MXNetError
 from ..ops import paged_attention
 from ..ops.attention import masked_attention
 from ..ops.flash_attention import prefill_flash_attention
+from .moe import expert_product
 
 __all__ = ["CacheSpec", "BlockDecoding", "PagedDecoder", "Causal",
            "BehindPrefix", "DenseCache", "StepView", "rms_norm",
@@ -364,6 +365,18 @@ class PagedDecoder:
         self._net = net
         cos, sin = rope_tables(self.max_len, cfg.head_dim, cfg.rope_theta)
         self._cos, self._sin = jnp.asarray(cos), jnp.asarray(sin)
+
+    def expert_product(self, rows, dtype):
+        """Which form the routed expert layers of a program of ``rows``
+        rows take over a bank of ``dtype`` (``moe.expert_product``:
+        ``"grouped_kernel"`` or ``"every_expert"``); None for a model
+        without routed experts."""
+        cfg = self.cfg
+        if not self.cache_spec().expert_layers:
+            return None
+        return expert_product(rows, cfg.num_experts_per_tok,
+                              cfg.num_experts, cfg.hidden_size,
+                              cfg.moe_intermediate_size, dtype)
 
     def _layers(self, w, x, rope, views):
         """-> (x, what each layer's view kept, the expert rows as the

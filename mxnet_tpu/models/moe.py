@@ -35,8 +35,10 @@ import math
 
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
+from ..ops import grouped_ffn
 
-__all__ = ["MoEMLP", "collect_aux", "shard_moe", "route", "routed_ffn"]
+__all__ = ["MoEMLP", "collect_aux", "shard_moe", "route", "routed_ffn",
+           "expert_product"]
 
 
 # --- aux-loss collection ----------------------------------------------------
@@ -240,15 +242,30 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
     row is computed, only those are counted (a served program also runs
     vacant slots and the padded end of a prompt).
 
-    Every held expert computes every row, weighted by a (N, count)
-    combine matrix that is zero where the expert was not chosen: where
-    rows are few the expert weights have to be streamed whole anyway
-    (on a v5e this beat rows sorted by expert and ``jax.lax.ragged_dot``
-    at every size measured, up to 512 tokens x 4: PERF.md, PR 26)."""
+    Two forms, one meaning, chosen by :func:`expert_product` from the
+    platform, the mesh and static shapes:
+
+    * ``every_expert``: every held expert computes every row, weighted
+      by a (N, count) combine matrix that is zero where the expert was
+      not chosen.  Where rows are few the bank has to be streamed whole
+      anyway; the product is finished (92% of the v5e's bf16 peak at
+      512 rows) and turns bound by operations nobody asked for near 240
+      rows a call.  It stays on a CPU, under a mesh and below
+      ``ops.grouped_ffn.GROUPED_MIN_ROWS`` rows.
+    * ``grouped_kernel``: ``ops.grouped_ffn.grouped_expert_ffn``, the
+      (row, expert) pairs sorted by expert, each touched expert
+      streamed once and computed on its own rows, every row included
+      (``live`` changes the counts alone, as above).  Same routing to
+      the bit; float32 accumulation, weights and sum over a row's
+      ``k``, so not lower than the other form anywhere, and not equal
+      to it in the last bit.
+
+    Measured on the v5e (PERF.md, PR 31; before it rows sorted by expert
+    through ``jax.lax.ragged_dot`` lost at every size, PRs 26 and 30)."""
     import jax
     import jax.numpy as jnp
 
-    n, _h = x.shape
+    n, h = x.shape
     e = router_w.shape[0]
     first, held = experts_held if experts_held is not None else (0, e)
     if w_gate.shape[0] != held:
@@ -259,6 +276,10 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
     ones = jnp.ones((n,), jnp.int32) if live is None \
         else live.astype(jnp.int32)
     counts = jnp.zeros((e,), jnp.int32).at[idx].add(ones[:, None])
+    if expert_product(n, k, held, h, w_gate.shape[2],
+                      w_gate.dtype) == "grouped_kernel":
+        return grouped_ffn.grouped_expert_ffn(
+            x, idx - first, w, w_gate, w_up, w_down), counts
     comb = jnp.zeros((n, e), jnp.float32) \
         .at[jnp.arange(n)[:, None], idx].add(w)
     comb = comb[:, first:first + held]
@@ -266,6 +287,24 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
     u = jnp.einsum("nh,ehi->nei", x, w_up)
     act = g * jax.nn.sigmoid(g) * u * comb.astype(x.dtype)[:, :, None]
     return jnp.einsum("nei,eih->nh", act, w_down), counts
+
+
+def expert_product(rows, k, held, hidden, width, dtype):
+    """Which form :func:`routed_ffn` evaluates ``rows`` rows a call in,
+    here and now: ``"grouped_kernel"`` or ``"every_expert"``, from the
+    platform programs are compiled for, the active mesh and the static
+    shapes (``ops.grouped_ffn.applicable``).  The served programs'
+    ``expert_product`` counter asks the same question of the same
+    function."""
+    import jax
+    import numpy as np
+
+    from .. import parallel
+
+    ok = grouped_ffn.applicable(
+        jax.default_backend(), parallel.current_mesh(), rows, k, held,
+        hidden, width, np.dtype(dtype).itemsize)
+    return "grouped_kernel" if ok else "every_expert"
 
 
 def moe_param_specs(block, ep_axis="ep", tp_axis=None):

@@ -353,6 +353,14 @@ class LlamaServingEngine:
         #: where every bucket keeps ``masked_attention``
         self.prefill_attention = self.prefill_attention_at(
             self.max_len // 128 * 128)
+        #: which form the routed expert layers of the step program take
+        #: (``models.moe.routed_ffn`` chooses from the platform, the
+        #: mesh and the rows a call): "grouped_kernel"
+        #: (ops/grouped_ffn.py) or "every_expert"; None for a model
+        #: without experts.  A prefill bucket decides for itself
+        #: (``expert_product_at``)
+        self.expert_product = self.expert_product_at(
+            self.num_slots * (1 if block is None else block.block_len))
         #: per-expert row counts that ride behind the tokens of every
         #: step and prefill fetch (0: the model routes nothing)
         self._n_counts = spec.expert_layers * spec.num_experts
@@ -608,6 +616,11 @@ class LlamaServingEngine:
         """``"flash"`` or ``"dense"``: which attention the prefill
         program of a bucket ``lp`` positions long runs."""
         return "flash" if self._prefill_flash(lp) else "dense"
+
+    def expert_product_at(self, rows):
+        """``"grouped_kernel"``, ``"every_expert"`` or None: the form
+        of the routed expert layers in a program of ``rows`` rows."""
+        return self._dec.expert_product(rows, self._w["emb"].dtype)
 
     def compiled_signatures(self):
         """Every (program, *bucket) shape this engine has compiled."""

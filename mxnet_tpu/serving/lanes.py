@@ -399,6 +399,9 @@ class PrefillLane:
         yields = getattr(eng, "block", None) is None
         if seq == 1:
             extra.update(_cache_layers(eng))
+        # a bucket decides its routed experts' product for itself, as
+        # it does its attention (None: a model without experts)
+        product = getattr(eng, "expert_product_at", None)
         # one stamp set for every consumer: the lane log, the capacity
         # duty cycle and (below) the request's span tree
         tracing.lane_record(
@@ -408,7 +411,8 @@ class PrefillLane:
             radix_hit_tokens=int(sum(matched)), t_start=t_start,
             t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
             t_commit1=t_commit1, t_first=t_first,
-            prefill_attention=attention, **extra)
+            prefill_attention=attention,
+            expert_product=product(kb * lb) if product else None, **extra)
         capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
             req.t_commit = t_first
@@ -736,7 +740,8 @@ class DecodeLane:
         ``kv_tokens``: K/V rows the step attended, summed over the
         active slots.  The lane's first record also says which
         attention the engine's step program was built with, how many KV
-        heads a stored pool row holds (``kv_pack``) and how many layers
+        heads a stored pool row holds (``kv_pack``), which product its
+        routed experts run (``expert_product``) and how many layers
         keep K/V and how many a per-slot state."""
         t_lock, t_disp0, t_disp1, t_tok = stamps
         if not self._said_attention:
@@ -744,6 +749,8 @@ class DecodeLane:
             extra["decode_attention"] = getattr(
                 self.r.engine, "decode_attention", None)
             extra["kv_pack"] = getattr(self.r.engine, "kv_pack", None)
+            extra["expert_product"] = getattr(
+                self.r.engine, "expert_product", None)
             extra["decoding"] = getattr(self.r.engine, "decoding", None)
             block = getattr(self.r.engine, "block", None)
             if block is not None:

@@ -616,6 +616,7 @@ class GenerativeServer(_ServerBase):
                 "decode_attention": self.engine.decode_attention,
                 "prefill_attention": self.engine.prefill_attention,
                 "kv_pack": self.engine.kv_pack,
+                "expert_product": self.engine.expert_product,
             }
             telemetry.gauge("serving.kv_occupancy",
                             out["kv_cache"]["occupancy"])
@@ -635,6 +636,7 @@ class GenerativeServer(_ServerBase):
             "decode_attention": reps[0].engine.decode_attention,
             "prefill_attention": reps[0].engine.prefill_attention,
             "kv_pack": reps[0].engine.kv_pack,
+            "expert_product": reps[0].engine.expert_product,
             "num_replicas": len(reps),
             "kv_layers": reps[0].engine.cache_spec.kv_layers,
             "state_layers": reps[0].engine.cache_spec.state_layers,

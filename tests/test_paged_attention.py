@@ -763,3 +763,47 @@ def test_prefill_program_compiles_for_v5e_without_scores(one_chip, flash):
     else:
         assert kernels == 0 and f"f32[{heads},{lp},{lp}]" in scores
         assert repeats and temps > 4e9, temps
+
+
+@pytest.mark.parametrize("rows,k,held,width", [
+    (512, 8, 128, 768),       # sdar_30b.chat_decode_sat: a block pass
+    (512, 4, 64, 1536),       # lfm2_24b: its 512 prefill bucket
+], ids=["sdar_512x8_of_128", "lfm2_512x4_of_64"])
+def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
+                                                width):
+    """Mosaic takes ``ops.grouped_ffn`` at the two sparse cells' widths (it
+    lives in this file because the described chip is this file's: one
+    worker loads the library).  Two whole experts in VMEM; the program
+    holds the bank as it came in and nothing of ``(rows, experts, width)``,
+    the other form's intermediate."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.ops import grouped_ffn
+
+    hidden, bf = 2048, jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert grouped_ffn.applicable("tpu", None, rows, k, held, hidden, width)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            text = grouped_ffn.grouped_expert_ffn.lower(
+                sds((rows, hidden)), sds((rows, k), jnp.int32),
+                sds((rows, k), jnp.float32), sds((held, hidden, width)),
+                sds((held, hidden, width)),
+                sds((held, width, hidden))).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "grouped_expert_ffn" in text
+    assert f"[{rows},{held},{width}]" not in text
+    bank = (f"[{held},{hidden},{width}]", f"[{held},{width},{hidden}]")
+    assert not [ln for ln in text.splitlines()
+                if any(b in ln.split("=")[0] for b in bank)
+                and (" copy(" in ln or " convert(" in ln
+                     or " transpose(" in ln)]
