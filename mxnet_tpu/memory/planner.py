@@ -222,7 +222,8 @@ def _registry_workspace(axes, remat):
 
 def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
                  block_size, dtype=np.float32, mesh=None, rules=None,
-                 state_layers=0, state_shape=None, num_slots=0):
+                 state_layers=0, state_shape=None, num_slots=0,
+                 latent_layers=0, latent_dim=0, index_dim=0):
     """Per-device bytes of the serving engine's paged cache.  The block
     pool: 2 (K and V) × ``num_layers`` × ``num_blocks × num_kv_heads ×
     block_size × head_dim`` × itemsize, sharded the way the serving rule
@@ -231,7 +232,11 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
     pool (``CacheSpec.kv_layers``), not the model's depth.  Plus, for a
     model whose other layers keep a fixed per-slot state:
     ``state_layers × num_slots × prod(state_shape)`` × itemsize
-    (unsharded).  This is the serving analog of the allreduce-bytes
+    (unsharded).  Plus, for the layers that keep latent rows and index
+    keys in the same block tables (``CacheSpec.latent_layers``, with
+    its ``latent_dim`` and ``index_dim``): ``latent_layers ×
+    num_blocks`` × the bytes a block as ``ops.latent_cache`` stores it
+    (rows padded to whole lanes; unsharded).  This is the serving analog of the allreduce-bytes
     planning the trainer gets: size the cache BEFORE building the
     engine, and feed the figure to :func:`plan_model` via
     ``kv_pool_bytes=`` to get a fit verdict that includes serving
@@ -253,8 +258,15 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
     if state_layers:
         state = int(state_layers) * int(num_slots) \
             * int(np.prod(state_shape)) * dtype.itemsize
+    latent = 0
+    if latent_layers:
+        from ..ops import latent_cache
+
+        latent = int(latent_layers) * int(num_blocks) \
+            * latent_cache.bytes_per_block(block_size, latent_dim,
+                                           index_dim, dtype.itemsize)
     return 2 * int(num_layers) * _ceil_div(n_elem * dtype.itemsize, div) \
-        + state
+        + state + latent
 
 
 def plan_model(params, mesh=None, rules=None, optimizer=None,

@@ -29,13 +29,13 @@ from typing import NamedTuple
 import numpy as np
 
 from ..base import MXNetError
-from ..ops import paged_attention
+from ..ops import latent_cache, paged_attention
 from ..ops.attention import masked_attention
 from ..ops.flash_attention import prefill_flash_attention
 from .moe import expert_product
 
 __all__ = ["CacheSpec", "BlockDecoding", "PagedDecoder", "Causal",
-           "BehindPrefix", "DenseCache", "StepView", "rms_norm",
+           "SelectingCausal", "BehindPrefix", "DenseCache", "StepView", "rms_norm",
            "split_heads", "rope_tables", "apply_rope",
            "headnorm_attention", "block_commit"]
 
@@ -66,9 +66,13 @@ class CacheSpec:
 
     ``layers[l]`` is ``"kv"`` (the layer owns a K and a V block pool
     ``(num_blocks, num_kv_heads, block_size, head_dim)``, addressed
-    through the slots' block tables) or ``"state"`` (it owns one array
+    through the slots' block tables), ``"state"`` (it owns one array
     ``(num_slots,) + state_shape``: a fixed-size state a slot, written
-    whole at admission and in place by every step).  ``expert_layers``
+    whole at admission and in place by every step) or ``"latent"`` (it
+    owns, in the same block tables, a pool of latent rows, ``latent_dim``
+    values a token, and a pool of index keys, ``index_dim`` values a
+    token, stored as ``ops.latent_cache`` stores them; a query reads
+    the ``select_topk`` latent rows its indexer selects).  ``expert_layers``
     x ``num_experts`` is the shape of the per-expert row counts that
     the step and prefill programs of a model with routed experts
     return beside their tokens (0: none).  ``decoding``: None for a
@@ -77,12 +81,15 @@ class CacheSpec:
     in any order."""
 
     __slots__ = ("layers", "num_kv_heads", "head_dim", "state_shape",
-                 "expert_layers", "num_experts", "decoding")
+                 "expert_layers", "num_experts", "decoding", "latent_dim",
+                 "index_dim", "select_topk")
 
     def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
-                 expert_layers=0, num_experts=0, decoding=None):
+                 expert_layers=0, num_experts=0, decoding=None,
+                 latent_dim=0, index_dim=0, select_topk=0):
         self.layers = tuple(layers)
-        if any(kind not in ("kv", "state") for kind in self.layers):
+        if any(kind not in ("kv", "state", "latent")
+               for kind in self.layers):
             raise MXNetError(f"unknown cache kind in {self.layers}")
         self.num_kv_heads = int(num_kv_heads)
         self.head_dim = int(head_dim)
@@ -91,8 +98,15 @@ class CacheSpec:
         self.expert_layers = int(expert_layers)
         self.num_experts = int(num_experts)
         self.decoding = decoding
+        self.latent_dim = int(latent_dim)
+        self.index_dim = int(index_dim)
+        self.select_topk = int(select_topk)
         if self.state_layers and self.state_shape is None:
             raise MXNetError("state layers need a state_shape")
+        if self.latent_layers and not (self.latent_dim and self.index_dim
+                                       and self.select_topk):
+            raise MXNetError("latent layers need latent_dim, index_dim "
+                             "and select_topk")
 
     @property
     def kv_layers(self):
@@ -102,10 +116,19 @@ class CacheSpec:
     def state_layers(self):
         return self.layers.count("state")
 
+    @property
+    def latent_layers(self):
+        return self.layers.count("latent")
+
     def kv_bytes_per_block(self, block_size, itemsize):
-        """Bytes one block holds over every K/V layer (K and V)."""
+        """Bytes one block holds over every layer that keeps rows in
+        the block tables: K and V of the K/V layers, the latent rows
+        and index keys of the latent layers as stored (padding
+        counted)."""
         return 2 * self.kv_layers * self.num_kv_heads * int(block_size) \
-            * self.head_dim * int(itemsize)
+            * self.head_dim * int(itemsize) \
+            + self.latent_layers * latent_cache.bytes_per_block(
+                block_size, self.latent_dim, self.index_dim, itemsize)
 
     def state_bytes_per_slot(self, itemsize):
         """Bytes of one slot's state over every state layer."""
@@ -242,6 +265,38 @@ class Causal:
         return ctx.transpose(0, 2, 1, 3), (k, v)
 
 
+class SelectingCausal:
+    """Whole sequences of a latent layer (prefill): row ``t`` attends
+    the ``topk`` rows ``s <= t`` its indexer selects, all of them while
+    they are fewer.  Nothing is stored: what a cache would keep is the
+    sequence's logical ``(latent rows, index keys)``.  ``live`` (B, T)
+    the positions a request owns.  With ``lengths`` (B,) the selection
+    and the attention over the selected rows run in tiles of query rows
+    and skip those past every row's end
+    (``ops.latent_cache.causal_attention``); without, every row at once
+    in the plain expanded form
+    (``ops.latent_cache.plain_causal_attention``)."""
+
+    pos = None
+
+    def __init__(self, topk, live=None, lengths=None):
+        self.topk, self.live, self.lengths = topk, live, lengths
+
+    def attend_latent(self, make_query, latent, index_keys, per_row,
+                      w_uk, w_uv, scale, finish):
+        """``make_query(*rows of per_row) -> (q_nope, q_rope, q_idx,
+        w_idx)``; ``finish(heads (.., H, dv)) -> y`` -> (y, kept)."""
+        if self.lengths is None:
+            y = latent_cache.plain_causal_attention(
+                make_query, latent, index_keys, per_row, self.topk, w_uk,
+                w_uv, scale, finish)
+        else:
+            y = latent_cache.causal_attention(
+                make_query, latent, index_keys, per_row, self.lengths,
+                self.topk, w_uk, w_uv, scale, finish)
+        return y, (latent, index_keys)
+
+
 class BehindPrefix:
     """A suffix behind a reused prefix: ``entry = (K, V)`` each ``(B,
     Hkv, Lpre, hd)``, dense copies gathered from shared pool blocks,
@@ -326,10 +381,13 @@ class StepView:
     call's ``ops.paged_attention.Window``.  The pool's layout is that
     module's; this view only says when to write and when to attend."""
 
-    __slots__ = ("entry", "pos", "win")
+    __slots__ = ("entry", "pos", "win", "topk", "selected")
 
-    def __init__(self, entry, pos, win=None):
-        self.entry, self.pos, self.win = entry, pos, win
+    def __init__(self, entry, pos, win=None, topk=0):
+        self.entry, self.pos, self.win, self.topk = entry, pos, win, topk
+        #: a latent layer's step leaves here what it selected: (S, k)
+        #: positions, -1 where a slot sees fewer than k
+        self.selected = None
 
     @property
     def live(self):
@@ -340,6 +398,25 @@ class StepView:
         vp = paged_attention.write_rows(self.entry[1], self.win, v)
         return paged_attention.window_attention(q, kp, vp, self.win), \
             (kp, vp)
+
+    def attend_latent(self, make_query, latent, index_keys, per_row,
+                      w_uk, w_uv, scale, finish):
+        """A latent layer's step: the new token's latent row and index
+        key (``(S, width)`` each) written in place, the slot's cached
+        index keys scored up to its position, the exact ``topk`` taken
+        and those latent rows alone attended, through the block table
+        (``ops.latent_cache``)."""
+        import jax.numpy as jnp
+
+        lp = latent_cache.write_rows(self.entry[0], self.win, latent)
+        ip = latent_cache.write_rows(self.entry[1], self.win, index_keys)
+        q_nope, q_rope, q_idx, w_idx = make_query(*per_row)
+        idx, valid = latent_cache.window_select(q_idx, w_idx, ip, self.win,
+                                                self.topk)
+        self.selected = jnp.where(valid, idx, -1)
+        heads = latent_cache.window_attention(
+            q_nope, q_rope, lp, self.win, idx, valid, w_uk, w_uv, scale)
+        return finish(heads), (lp, ip)
 
 
 # -- the programs -----------------------------------------------------------------
@@ -392,13 +469,20 @@ class PagedDecoder:
         return x, kept_all, ((jnp.stack(counts),) if counts else ())
 
     def _decode(self, w, cache, tables, x, pos, rope, paged_kernel):
+        import jax.numpy as jnp
+
         pool = next(e for e in cache if isinstance(e, tuple))[0]
         win = paged_attention.window(pool, tables, pos, self.max_len,
                                      paged_kernel,
                                      block=self.block_len is not None)
-        x, cache, counts = self._layers(
-            w, x, rope, (StepView(e, pos, win) for e in cache))
-        return (self._logits(w, x), cache) + counts
+        topk = self.cache_spec().select_topk
+        views = [StepView(e, pos, win, topk) for e in cache]
+        x, cache, counts = self._layers(w, x, rope, views)
+        # a selecting model's step says, last, what each layer read:
+        # (layers, S, k) positions
+        picked = [v.selected for v in views if v.selected is not None]
+        return (self._logits(w, x), cache) + counts \
+            + ((jnp.stack(picked),) if picked else ())
 
     def _step_blocks_impl(self, w, cache, tables, ids_t, pos,
                           paged_kernel=False):
@@ -472,6 +556,12 @@ class PagedDecoder:
         return self._logits(w, jnp.take_along_axis(
             x, (t0 - 1)[:, None, None], axis=1)[:, 0])
 
+    def _prefill_view(self, lp, real, lengths, t0):
+        """The one view a prefill's layers share: whole sequences under
+        the causal (or a block decoder's) mask; a model whose layers
+        select what they read answers with its own."""
+        return Causal(lp, real, lengths, self.block_len)
+
     def _prefill_rows_impl(self, w, ids, t0, flash=False):
         """Batched full-sequence prompt pass over PADDED ids (B, Lp) with
         true lengths ``t0`` (scalar, or (B,) a row each) -> (rows, logits
@@ -494,7 +584,7 @@ class PagedDecoder:
         # not the padded end
         real = jnp.arange(lp)[None] < (t0[:, None] if t0.ndim else t0)
         lengths = jnp.broadcast_to(t0, (b,)) if flash else None
-        causal = Causal(lp, real, lengths, self.block_len)
+        causal = self._prefill_view(lp, real, lengths, t0)
         x, rows, counts = self._layers(w, x, rope,
                                        (causal for _ in w["layers"]))
         rows = [r if isinstance(r, tuple) else self._sequence_state(r, t0)

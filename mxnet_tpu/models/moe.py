@@ -198,6 +198,13 @@ def _expert_ffn(ein, gw, uw, dw):
     return jnp.einsum("eci,ehi->ech", act, dw.astype(ein.dtype))
 
 
+#: most rows the ``every_expert`` form of :func:`routed_ffn` computes at
+#: once: its ``(rows, held, width)`` intermediates are 134 MB each at 16
+#: held experts of 2,048 in bfloat16; a call of more rows (whole
+#: multiples: a prefill bucket of 8k to 32k) goes in chunks of this many
+EVERY_EXPERT_ROWS = 2048
+
+
 def route(x, router_w, k, score="softmax", choice_bias=None,
           renormalize=True, scale=1.0):
     """Top-``k`` routing over ALL experts, in float32: ``x`` (N, H),
@@ -247,7 +254,8 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
 
     * ``every_expert``: every held expert computes every row, weighted
       by a (N, count) combine matrix that is zero where the expert was
-      not chosen.  Where rows are few the bank has to be streamed whole
+      not chosen (past ``EVERY_EXPERT_ROWS`` rows a call, in chunks of
+      that many).  Where rows are few the bank has to be streamed whole
       anyway; the product is finished (92% of the v5e's bf16 peak at
       512 rows) and turns bound by operations nobody asked for near 240
       rows a call.  It stays on a CPU, under a mesh and below
@@ -283,10 +291,27 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
     comb = jnp.zeros((n, e), jnp.float32) \
         .at[jnp.arange(n)[:, None], idx].add(w)
     comb = comb[:, first:first + held]
-    g = jnp.einsum("nh,ehi->nei", x, w_gate)
-    u = jnp.einsum("nh,ehi->nei", x, w_up)
-    act = g * jax.nn.sigmoid(g) * u * comb.astype(x.dtype)[:, :, None]
-    return jnp.einsum("nei,eih->nh", act, w_down), counts
+
+    def every_expert(x, comb):
+        g = jnp.einsum("nh,ehi->nei", x, w_gate)
+        u = jnp.einsum("nh,ehi->nei", x, w_up)
+        act = g * jax.nn.sigmoid(g) * u * comb.astype(x.dtype)[:, :, None]
+        return jnp.einsum("nei,eih->nh", act, w_down)
+
+    if n <= EVERY_EXPERT_ROWS or n % EVERY_EXPERT_ROWS:
+        return every_expert(x, comb), counts
+    # a long prefill: the (rows, held, width) intermediates a chunk of
+    # rows at a time, so that they are never whole; a chunk that holds no
+    # live row (the padded end of a bucket) is zeros, not computed
+    def chunk(c):
+        xc, cc, any_live = c
+        return jax.lax.cond(any_live, every_expert,
+                            lambda xc, cc: jnp.zeros_like(xc), xc, cc)
+
+    y = jax.lax.map(chunk, (x.reshape(-1, EVERY_EXPERT_ROWS, h),
+                            comb.reshape(-1, EVERY_EXPERT_ROWS, held),
+                            ones.reshape(-1, EVERY_EXPERT_ROWS).any(axis=1)))
+    return y.reshape(n, -1), counts
 
 
 def expert_product(rows, k, held, hidden, width, dtype):

@@ -189,6 +189,23 @@ _BLOCK_REFUSALS = {
 }
 
 
+#: and for a model whose layers keep latent rows and select what a query
+#: reads (a ``"latent"`` cache kind); ``radix`` is the replica's to say
+_LATENT_REFUSALS = {
+    "slots": "kv_mode='slots' keeps dense keys and values: a latent "
+             "layer's rows and index keys live in kv_mode='paged' pools",
+    "spec": "speculative decoding (draft_net / spec_k) verifies a window "
+            "of columns a slot; the latent step selects and attends one "
+            "new token a slot",
+    "mesh": "a mesh-placed engine (mesh=) has no partition rule for a "
+            "latent pool, an index-key pool or an expert bank",
+    "int8": _STATE_REFUSALS["int8"],
+    "radix": "radix_cache=True reuses K/V blocks behind a causal suffix; "
+             "the suffix prefill has no view over latent blocks and their "
+             "index keys",
+}
+
+
 class BlockTick(NamedTuple):
     """What one pass of a block-decoding engine did, slot by slot
     (``LlamaServingEngine.step`` of such an engine), vacant and
@@ -243,8 +260,10 @@ class LlamaServingEngine:
         #: or the decoder's ``BlockDecoding``
         block = self.block = spec.decoding
         self.decoding = "next_token" if block is None else "block_diffusion"
-        if spec.state_layers or spec.expert_layers or block is not None:
-            why = _STATE_REFUSALS if block is None else _BLOCK_REFUSALS
+        if spec.state_layers or spec.expert_layers or spec.latent_layers \
+                or block is not None:
+            why = _LATENT_REFUSALS if spec.latent_layers else \
+                _STATE_REFUSALS if block is None else _BLOCK_REFUSALS
             for key, bad in (("slots", kv_mode != "paged"),
                              ("spec", self.spec_k),
                              ("mesh", mesh is not None),
@@ -271,7 +290,7 @@ class LlamaServingEngine:
             self.max_blocks = -(-self.max_len // self.block_size)
             self.num_blocks = int(num_blocks or
                                   self.num_slots * self.max_blocks)
-            from ..ops import paged_attention
+            from ..ops import latent_cache, paged_attention
 
             # decided once, before the pool is made, from where the
             # weights (and so the pool) live, the mesh and the shapes:
@@ -280,17 +299,23 @@ class LlamaServingEngine:
             # bs, 128); the gather path keeps one head a row
             pack = paged_attention.applicable(
                 platform, mesh, spec.head_dim, spec.num_kv_heads,
-                self.block_size, dt)
+                self.block_size, dt) if spec.kv_layers else 0
             paged_kernel = pack > 0
             self.kv_pack = pack = max(1, pack)
             pshape = paged_attention.pool_shape(
                 self.num_blocks, spec.num_kv_heads, spec.head_dim,
                 self.block_size, pack)
-            # one entry a layer, by the spec: a (K, V) pool pair, or the
-            # layer's per-slot state
+            lshapes = latent_cache.pool_shapes(
+                self.num_blocks, self.block_size, spec.latent_dim,
+                spec.index_dim)
+            # one entry a layer, by the spec: a (K, V) pool pair, a
+            # (latent rows, index keys) pool pair, or the layer's
+            # per-slot state
             self._pool = [
                 (jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
                 if kind == "kv" else
+                tuple(jnp.zeros(shape, dt) for shape in lshapes)
+                if kind == "latent" else
                 jnp.zeros((self.num_slots,) + spec.state_shape, dt)
                 for kind in spec.layers]
             self._tables = np.full((self.num_slots, self.max_blocks),
@@ -344,8 +369,16 @@ class LlamaServingEngine:
         #: in place) or "gather" (a dense per-slot view through the
         #: table); ``kv_pack`` beside it says how many KV heads a stored
         #: row holds (above 1 only under the kernel)
-        self.decode_attention = "paged_kernel" if paged_kernel else "gather"
+        self.decode_attention = "latent_sparse" if spec.latent_layers \
+            else "paged_kernel" if paged_kernel else "gather"
         self._platform = platform
+        #: a selecting model: positions the last step's active slots
+        #: could see and positions they read, a layer (the
+        #: ``decode.tick`` record's ``kv_visible`` / ``kv_selected``),
+        #: and what each layer of the last step read, on the device
+        #: (:meth:`selection_of`)
+        self.tick_selection = {}
+        self._tick_selected = None
         #: which attention the prefill programs run, decided a bucket
         #: (``prefill_attention_at``): "flash" where the engine's
         #: longest 128-aligned prompt goes through
@@ -364,6 +397,9 @@ class LlamaServingEngine:
         #: per-expert row counts that ride behind the tokens of every
         #: step and prefill fetch (0: the model routes nothing)
         self._n_counts = spec.expert_layers * spec.num_experts
+        #: (first, count): the part of each routed layer's bank that
+        #: the model holds (its config's to say); None: all of it
+        self.experts_held = getattr(cfg, "experts_held", None)
         #: the last step's ``experts_touched`` / ``expert_rows_max`` /
         #: ``expert_rows_mean`` (the ``decode.tick`` record), and the
         #: totals over every step and prefill (``server.stats()``)
@@ -392,9 +428,11 @@ class LlamaServingEngine:
                     paged_kernel=paged_kernel)
                 logits, pools = out[:2]
                 tok = _tokens(logits, out)
+                # a selecting model's step says, last, what it read
+                picked = out[-1:] if spec.select_topk else ()
                 if numerics_on:
-                    return tok, pools, _numerics.stats_of(logits)
-                return tok, pools
+                    return (tok, pools, _numerics.stats_of(logits)) + picked
+                return (tok, pools) + picked
 
             def _prefill_fn(wq, ids, t0):
                 out = dec._prefill_rows_impl(
@@ -471,12 +509,16 @@ class LlamaServingEngine:
                 # layer's rows (KB,) + state_shape replace the WHOLE
                 # state of ``slots`` (vacant rows: slot id num_slots,
                 # dropped), so a reused slot never sees its predecessor's
+                # a latent layer's rows (KB, Lp, width) go block by
+                # block as its format stores them
+                by_block = {"kv": paged_attention.scatter_rows,
+                            "latent": latent_cache.scatter_rows}
                 return [
-                    tuple(paged_attention.scatter_rows(p, r, flat_idx)
+                    tuple(by_block[kind](p, r, flat_idx)
                           for p, r in zip(entry, row))
-                    if isinstance(entry, tuple)
+                    if kind in by_block
                     else entry.at[slots].set(row, mode="drop")
-                    for entry, row in zip(pools, rows)]
+                    for kind, entry, row in zip(spec.layers, pools, rows)]
 
         else:
 
@@ -608,14 +650,48 @@ class LlamaServingEngine:
 
     def _prefill_flash(self, lp):
         """The rule at a bucket of ``lp`` positions, from what the
-        engine observes: the static its prefill program is traced with."""
+        engine observes: the static its prefill program is traced with
+        (a latent layer takes no notice: its view is its decoder's)."""
         return flash_attention.prefill_applicable(
             self._platform, self.mesh, self.cache_spec.head_dim, lp)
 
     def prefill_attention_at(self, lp):
         """``"flash"`` or ``"dense"``: which attention the prefill
-        program of a bucket ``lp`` positions long runs."""
+        program of a bucket ``lp`` positions long runs;
+        ``"latent_sparse"`` where the layers select what they read."""
+        if self.cache_spec.latent_layers:
+            return "latent_sparse"
         return "flash" if self._prefill_flash(lp) else "dense"
+
+    def selection_counts(self, seen, whole=False):
+        """``kv_visible`` / ``kv_selected`` of a selecting model, a
+        layer: over rows that each see ``seen`` positions (a step's
+        active slots) or, ``whole``, over every row of prompts ``seen``
+        tokens long, the positions visible and the positions read (at
+        most ``select_topk`` a row); {} for a model that reads all it
+        sees."""
+        k = self.cache_spec.select_topk
+        if not k:
+            return {}
+        n = np.asarray(seen, np.int64)
+        if whole:
+            visible = n * (n + 1) // 2
+            m = np.minimum(n, k)
+            read = m * (m + 1) // 2 + (n - m) * k
+        else:
+            visible, read = n, np.minimum(n, k)
+        return {"kv_visible": int(visible.sum()),
+                "kv_selected": int(read.sum())}
+
+    def selection_of(self, slot):
+        """What each layer of the LAST step read for ``slot``: (layers,
+        k) positions, -1 where the slot saw fewer; None for a model
+        that selects nothing.  One small fetch, for a request's end."""
+        with self.dev_lock:
+            picked = self._tick_selected
+            if picked is None:
+                return None
+            return np.asarray(picked[:, slot])
 
     def expert_product_at(self, rows):
         """``"grouped_kernel"``, ``"every_expert"`` or None: the form
@@ -631,7 +707,9 @@ class LlamaServingEngine:
         K and V over the layers that own them, plus the per-slot states
         of the layers that keep one — the figure the memory planner's
         ``plan_kv_pool`` predicts pre-build.  ``by_kind`` splits it:
-        ``{"kv_blocks": ..., "slot_state": ...}``.  On a tp mesh each
+        ``{"kv_blocks": ..., "slot_state": ...}`` and, where a layer
+        keeps them, ``"latent_blocks"`` and ``"index_key_blocks"`` (as
+        stored, padding counted).  On a tp mesh each
         device holds one shard of the pool's head axis, so this is the
         single-shard footprint, not the global array size."""
         def shard_bytes(a):
@@ -642,13 +720,21 @@ class LlamaServingEngine:
 
         with self.dev_lock:
             kv = self._pool if self.kv_mode == "paged" else self._caches
+            kinds = self.cache_spec.layers if self.kv_mode == "paged" \
+                else ("kv",) * len(kv)
             blocks = sum(shard_bytes(e[0]) + shard_bytes(e[1])
-                         for e in kv if isinstance(e, tuple))
-            state = sum(shard_bytes(e) for e in kv
-                        if not isinstance(e, tuple))
+                         for k, e in zip(kinds, kv) if k == "kv")
+            state = sum(shard_bytes(e) for k, e in zip(kinds, kv)
+                        if k == "state")
+            latent, keys = (sum(shard_bytes(e[i]) for k, e in zip(kinds, kv)
+                                if k == "latent") for i in (0, 1))
         if by_kind:
-            return {"kv_blocks": int(blocks), "slot_state": int(state)}
-        return int(blocks + state)
+            out = {"kv_blocks": int(blocks), "slot_state": int(state)}
+            if self.cache_spec.latent_layers:
+                out.update(latent_blocks=int(latent),
+                           index_key_blocks=int(keys))
+            return out
+        return int(blocks + state + latent + keys)
 
     def split_fetch(self, fetched, n):
         """A step's or prefill's fetched vector -> (the tokens of its
@@ -739,7 +825,7 @@ class LlamaServingEngine:
         import jax.numpy as jnp
 
         kb = len(slots)
-        lp = next(r for r in rows if isinstance(r, tuple))[0].shape[2]
+        lp = next(r for r in rows if isinstance(r, tuple))[0].shape[-2]
         nbp = -(-lp // self.block_size)
         flat = np.full(kb * nbp, self.num_blocks, np.int32)
         for r, blocks in enumerate(block_lists):
@@ -840,6 +926,8 @@ class LlamaServingEngine:
                         self._w, self._pool, self._dev(self._tables),
                         self._dev(self._last), self._dev(self._pos))
                     self._pool = out[1]
+                    if self.cache_spec.select_topk:
+                        self._tick_selected = out[-1]
                 else:
                     out = self._step(
                         self._w, self._caches, self._dev(self._last),
@@ -868,6 +956,8 @@ class LlamaServingEngine:
                 self._pos[s] += 1
             # the step attended pos + 1 rows: the cursors as they are now
             self.tick_kv_tokens = int(self._pos[list(active)].sum())
+            self.tick_selection = self.selection_counts(
+                self._pos[list(active)])
         return out
 
     def _book_block(self, out, active):

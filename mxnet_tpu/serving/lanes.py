@@ -94,7 +94,10 @@ def _cache_layers(engine):
     spec = getattr(engine, "cache_spec", None)
     if spec is None:
         return {}
-    return {"kv_layers": spec.kv_layers, "state_layers": spec.state_layers}
+    out = {"kv_layers": spec.kv_layers, "state_layers": spec.state_layers}
+    if spec.latent_layers:
+        out["latent_layers"] = spec.latent_layers
+    return out
 
 
 class _Handoff:
@@ -412,7 +415,9 @@ class PrefillLane:
             t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
             t_commit1=t_commit1, t_first=t_first,
             prefill_attention=attention,
-            expert_product=product(kb * lb) if product else None, **extra)
+            expert_product=product(kb * lb) if product else None,
+            **(eng.selection_counts(t0s_suf[:len(group)], whole=True)
+               if hasattr(eng, "selection_counts") else {}), **extra)
         capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
             req.t_commit = t_first
@@ -658,11 +663,16 @@ class DecodeLane:
                 if r.mgr.consume(slot):
                     with self._hand_lock:
                         del self._seqs[slot]
+                    if getattr(r.engine, "tick_selection", None):
+                        # the step's query stood one before the cursor
+                        req.selected = (int(r.engine.positions()[slot]) - 1,
+                                        r.engine.selection_of(slot))
                     r.finish(req, tokens)
                     n_finished += 1
         self._record_tick(step_idx, ids, n_finished, stamps,
                           getattr(r.engine, "tick_kv_tokens", 0),
-                          **getattr(r.engine, "tick_experts", {}))
+                          **getattr(r.engine, "tick_experts", {}),
+                          **getattr(r.engine, "tick_selection", {}))
 
     def _tick_block(self):
         """A block decoder's tick: one pass over every active slot's
@@ -919,6 +929,10 @@ class Replica:
                 "behind a causal suffix; a block decoder's prompt ends "
                 "inside a block that the decode lane opens, and its "
                 "prefill has no suffix path")
+        if radix_cache and spec.latent_layers:
+            from .generative import _LATENT_REFUSALS
+
+            raise MXNetError(_LATENT_REFUSALS["radix"])
         if radix_cache and spec.state_layers:
             raise MXNetError(
                 "radix_cache=True shares a prompt prefix's K/V blocks; a "

@@ -58,13 +58,16 @@ class Request:
     prefill forward yields the first token; a block decoder's comes
     from the decode lane).  ``commits``: a block decoder's log of
     every commit, ``(position, token, the block's pass)``.
+    ``selected``: a selecting (latent) model's ``(position, what each
+    layer of the request's last decode step read: (layers, k)
+    positions, -1 where fewer were visible)``.
     """
 
     __slots__ = ("id", "inputs", "length", "prompt_ids", "max_new_tokens",
                  "future", "t_submit", "t_start", "t_first", "t_done",
                  "batch_size", "bucket", "slot", "joined_step",
                  "first_tick", "done_step", "replica", "t_handoff",
-                 "kv_blocks", "t_commit", "commits",
+                 "kv_blocks", "t_commit", "commits", "selected",
                  "trace", "tenant", "draft_tokens", "accepted_tokens",
                  "prefix_hit_tokens", "prefill_saved_ms")
 
@@ -95,6 +98,7 @@ class Request:
         self.t_commit = None    # prefill committed the prompt's K/V
         self.t_handoff = None   # decode lane adopted the prefilled KV
         self.commits = None     # a block decoder's (position, token, pass)
+        self.selected = None    # a selecting model's last step's reads
         self.kv_blocks = None   # blocks reserved for the request
         # observability (r12): the request-scoped span context (a
         # telemetry.tracing.Trace, None while tracing is off — every

@@ -640,6 +640,10 @@ class GenerativeServer(_ServerBase):
             "num_replicas": len(reps),
             "kv_layers": reps[0].engine.cache_spec.kv_layers,
             "state_layers": reps[0].engine.cache_spec.state_layers,
+            "latent_layers": reps[0].engine.cache_spec.latent_layers,
+            # the cache's bytes a device, by kind: K/V blocks, per-slot
+            # state and, of a latent model, latent rows and index keys
+            "cache_bytes": reps[0].engine.kv_pool_bytes(by_kind=True),
             # "next_token", or "block_diffusion" with the block sizes
             "decoding": reps[0].engine.decoding,
         }
@@ -657,6 +661,9 @@ class GenerativeServer(_ServerBase):
             out["experts"] = {
                 k: (max if k == "expert_rows_max" else sum)(
                     t[k] for t in tots) for k in tots[0]}
+            # (first, count): the part of each layer's bank that this
+            # server holds; None: all of it
+            out["experts_held"] = reps[0].engine.experts_held
         if len(reps) > 1:
             out["replicas"] = [{
                 "completed": r.completed,

@@ -807,3 +807,68 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
                 if any(b in ln.split("=")[0] for b in bank)
                 and (" copy(" in ln or " convert(" in ln
                      or " transpose(" in ln)]
+
+
+def test_latent_sparse_programs_compile_for_v5e_without_whole_arrays(one_chip):
+    """GLM-5's widths (``glm5.longdoc_prefill``; one dense and one expert
+    layer: every layer's arrays have the cell's shapes, and a program's
+    temporaries are one layer's): the prefill program at the 16,384
+    bucket holds no ``(L, L)`` array of scores or of a mask, no ``(rows,
+    16, 2048)`` array of every held expert on every row (the product runs
+    in chunks of 2,048 rows), and under 1.5 GB of temporaries; the step
+    program over 16 slots with tables of 32,768 positions gathers the
+    index keys and 2,048 latent rows a slot, never a slot's latent view."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.models import glm_moe_dsa as glm
+    from mxnet_tpu.models import moe
+    from mxnet_tpu.ops import latent_cache
+
+    lp, slots, nb, bs = 16384, 16, 24576, 16
+    cfg = glm.GlmMoeDsaConfig(num_layers=2, first_k_dense=1,
+                              experts_held=(0, 16), vocab_size=19360,
+                              max_seq_len=32768)
+
+    class Net:                              # never initialized: shapes only
+        config = cfg
+
+    dec = glm.GlmDecoder(Net(), 32768)
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = dict(layers=[{n: sds(*s) for n, s in
+                      glm._layer_param_shapes(cfg, l).items()}
+                     for l in range(2)],
+             emb=sds(19360, 6144), norm=sds(6144), head=sds(19360, 6144))
+    pools = [tuple(sds(*s) for s in latent_cache.pool_shapes(nb, bs, 576, 128))
+             for _ in range(2)]
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            prefill = jax.jit(dec._prefill_rows_impl).lower(
+                w, sds(1, lp, dtype=jnp.int32),
+                sds(1, dtype=jnp.int32)).compile()
+            step = jax.jit(dec._step_blocks_impl, donate_argnums=(1,)).lower(
+                w, pools, sds(slots, 32768 // bs, dtype=jnp.int32),
+                sds(slots, dtype=jnp.int32),
+                sds(slots, dtype=jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    text = prefill.as_text()
+    assert not re.findall(rf"\w+\[(?:\d+,)*{lp},{lp}\]", text)
+    assert not re.findall(rf"\w+\[{lp},16,2048\]", text)
+    assert re.findall(rf"\w+\[{moe.EVERY_EXPERT_ROWS},16,2048\]", text)
+    # a tile's index scores before the heads are summed, at the last extent
+    assert f"f32[{latent_cache.QUERY_TILE},32,{lp}]" in text
+    assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
+    text = step.as_text()
+    assert re.findall(rf"bf16\[{slots},32768,128\]", text)     # index keys
+    assert re.findall(rf"bf16\[{slots},2048,640\]", text)      # selected rows
+    assert not re.findall(rf"\w+\[{slots},32768,640\]", text)
+    assert step.memory_analysis().temp_size_in_bytes < 0.5e9

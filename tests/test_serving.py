@@ -649,6 +649,8 @@ def test_cache_spec_refuses_what_it_cannot_price():
     from mxnet_tpu.serving.kv_cache import CacheSpec
 
     with pytest.raises(mx.MXNetError, match="unknown cache kind"):
+        CacheSpec(("kv", "window"), 2, 16)
+    with pytest.raises(mx.MXNetError, match="latent layers need"):
         CacheSpec(("kv", "latent"), 2, 16)
     with pytest.raises(mx.MXNetError, match="state_shape"):
         CacheSpec(("kv", "state"), 2, 16)
