@@ -357,6 +357,23 @@ class LlamaServingEngine:
         #: K/V rows the last step() attended: ``pos + 1`` summed over
         #: its active slots (the record's ``kv_tokens``)
         self.tick_kv_tokens = 0
+        #: What each lane has on the device's queue, for the other to
+        #: see: the ``seq`` of the step()/verify() whose dispatch has
+        #: returned and whose tokens are not yet on the host, and the
+        #: ``seq``s of the prefill batches in the same state (the
+        #: prefill lane writes it between its forward's return and its
+        #: fetch's).  Each lane says so as its dispatch returns and
+        #: then reads the other's: programs run in the order they were
+        #: queued, so what it reads was queued first.  Plain attributes,
+        #: written by one thread and read by the other, no lock.
+        self.step_in_flight = None
+        self.prefill_in_flight = ()
+        #: ``prefill_in_flight`` as the last step()/verify() found it at
+        #: ``t_disp1`` (the ``decode.tick`` record's ``behind``)
+        self.tick_behind = ()
+        #: the ``mxt.*`` names of step()'s dispatch and fetch spans; a
+        #: replica names its draft engine's apart (``mxt.draft.*``)
+        self.span_names = ("mxt.decode.dispatch", "mxt.decode.fetch")
         self._signatures = set()
 
         # decode-step logit stats behind the same gate as the training
@@ -895,6 +912,14 @@ class LlamaServingEngine:
                                  self._dev(t0s), self._dev(s0s))
 
     # -- transitions (both modes) ---------------------------------------------
+    def _step_queued(self, seq):
+        """Step ``seq`` is on the device's queue: say so to the prefill
+        lane (until its tokens are fetched) and return the prefill
+        batches queued before it and not yet fetched, which it runs
+        behind."""
+        self.step_in_flight = seq
+        return self.prefill_in_flight
+
     def step(self, active):
         """One decode step over ALL slots; returns the (num_slots,)
         next-token vector on host and advances the ``active`` slots'
@@ -907,11 +932,11 @@ class LlamaServingEngine:
         wait."""
         self._note(("step",))
         lstats = None
+        span_dispatch, span_fetch = self.span_names
         t_lock = time.perf_counter()
         with self.dev_lock:
             t_disp0 = time.perf_counter()
-            with TraceAnnotation("mxt.decode.dispatch",
-                                 seq=self.steps + 1,
+            with TraceAnnotation(span_dispatch, seq=self.steps + 1,
                                  replica=self.replica_id):
                 # (tokens, storage[, logit stats under numerics])
                 if self.block is not None:
@@ -938,14 +963,19 @@ class LlamaServingEngine:
                     lstats = out[2]
             self.steps += 1
             seq = self.steps
+        behind = self._step_queued(seq)
         t_disp1 = time.perf_counter()
-        if lstats is not None:
-            # queue the decode-step logit stats (device scalars) for the
-            # stride harvest, outside the device lock
-            _numerics.record_compiled(("serving.logits",), (lstats,))
-        with TraceAnnotation("mxt.decode.fetch", seq=seq,
-                             replica=self.replica_id):
-            out = _materialize([toks])[0]
+        try:
+            if lstats is not None:
+                # queue the decode-step logit stats (device scalars) for
+                # the stride harvest, outside the device lock
+                _numerics.record_compiled(("serving.logits",), (lstats,))
+            with TraceAnnotation(span_fetch, seq=seq,
+                                 replica=self.replica_id):
+                out = _materialize([toks])[0]
+        finally:
+            self.step_in_flight = None
+        self.tick_behind = behind
         self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
         out, self.tick_experts = self.split_fetch(out, self.num_slots)
         if self.block is not None:
@@ -1027,12 +1057,17 @@ class LlamaServingEngine:
                     lstats = res[2]
             self.steps += 1
             seq = self.steps
+        behind = self._step_queued(seq)
         t_disp1 = time.perf_counter()
-        if lstats is not None:
-            _numerics.record_compiled(("serving.logits",), (lstats,))
-        with TraceAnnotation("mxt.decode.fetch", seq=seq,
-                             replica=self.replica_id):
-            out = _materialize([out])[0]
+        try:
+            if lstats is not None:
+                _numerics.record_compiled(("serving.logits",), (lstats,))
+            with TraceAnnotation("mxt.decode.fetch", seq=seq,
+                                 replica=self.replica_id):
+                out = _materialize([out])[0]
+        finally:
+            self.step_in_flight = None
+        self.tick_behind = behind
         self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
         return out
 

@@ -41,6 +41,16 @@ their JSONL records, lanes emit ``serving.prefill`` spans and
 ``serving.handoff_ms`` histograms, and the decode tick publishes the
 ``serving.kv_blocks_in_use`` gauge (see docs/observability.md).
 
+Lane log (``telemetry.tracing``, always on): a ``decode.tick`` record a
+turn of the decode lane, a ``prefill.batch`` record a batch, and a
+``slot.turn`` record an adopted hand-off: the admission from the slot's
+release (:meth:`Replica.release`) over the batch that filled it to the
+tick that took it up, on one clock.  Each lane's record says what of the
+other lane was on the device's queue before its own dispatch (``behind``
+/ ``behind_tick``).  A lane thread is always under a top-level ``mxt.*``
+span — ``mxt.prefill.batch`` or ``mxt.prefill.wait``, ``mxt.decode.tick``
+or ``mxt.decode.wait`` — but for the few lines of the gate.
+
 Tracing (r12): when ``telemetry.tracing`` is on, each request carries
 its span context across the lane threads (``req.trace``): the prefill
 lane records the ``queue`` and ``prefill`` spans at admission, adoption
@@ -105,12 +115,17 @@ class _Handoff:
     KV rows are already scattered into its blocks; the decode lane just
     adopts the slot."""
 
-    __slots__ = ("req", "slot", "first")
+    __slots__ = ("req", "slot", "first", "batch", "freed", "t_handoff")
 
-    def __init__(self, req, slot, first):
+    def __init__(self, req, slot, first, batch=None, freed=None):
         self.req = req
         self.slot = slot
         self.first = first      # None: the prefill yielded no token
+        self.batch = batch      # ``seq`` of the batch that prefilled it
+        # the slot's last release, ``Replica.released[slot]`` as the
+        # admission found it (None: the slot held nothing before)
+        self.freed = freed
+        self.t_handoff = None   # queued for the decode lane (hand_off)
 
 
 class PrefillLane:
@@ -177,11 +192,16 @@ class PrefillLane:
                     # return immediately and busy-spin against decode)
                     self.clock.enter("gated", time.perf_counter(),
                                      self._gate)
-                    self.r.capacity_evt.wait(self.poll_s)
+                    with TraceAnnotation("mxt.prefill.wait",
+                                         reason=self._gate,
+                                         replica=self.r.index):
+                        self.r.capacity_evt.wait(self.poll_s)
                     self.r.capacity_evt.clear()
                 else:
                     self.clock.enter("idle", time.perf_counter())
-                    q.wait_for_item(self.poll_s)
+                    with TraceAnnotation("mxt.prefill.wait", reason="empty",
+                                         replica=self.r.index):
+                        q.wait_for_item(self.poll_s)
         self.clock.enter("idle", time.perf_counter())
 
     def _bucket(self, req):
@@ -203,7 +223,8 @@ class PrefillLane:
         r = self.r
         mgr = r.mgr
         self._gate = None
-        if not len(r.queue):
+        queued = len(r.queue)
+        if not queued:
             return False
         free_slots = mgr.free_slots()
         if not free_slots:
@@ -245,7 +266,7 @@ class PrefillLane:
         self._gate = None   # a refusal after the head only ends the batch
         with TraceAnnotation("mxt.prefill.batch",
                              seq=self.clock.batches + 1, replica=r.index):
-            self._prefill_group(group)
+            self._prefill_group(group, free_slots, queued)
         return True
 
     def _forward(self, group, prompts, matched, skip, block_lists,
@@ -273,10 +294,12 @@ class PrefillLane:
         toks, rows = eng.prefill_suffix(pre_kv, prompts, t0s_suf, s0s)
         return toks, rows, "dense"
 
-    def _prefill_group(self, group):
+    def _prefill_group(self, group, free_slots, queued):
         """The admitted ``group`` through forward, commit and handoff,
         stamped once at each boundary for the lane log, the capacity
-        duty cycle and the requests' span trees alike."""
+        duty cycle and the requests' span trees alike.  ``free_slots``
+        and ``queued``: the counts at the gate that took the group (the
+        group's own requests among the queued), for its record."""
         r = self.r
         mgr = r.mgr
         t_start = time.perf_counter()
@@ -318,6 +341,7 @@ class PrefillLane:
             skip = np.zeros(kb, np.int32)
             slots = np.full(kb, eng.num_slots, np.int32)
             block_lists = [None] * kb
+            freed = [None] * kb
             for i, req in enumerate(group):
                 t0s[i] = len(req.prompt_ids)
                 t0s_suf[i] = t0s[i] - matched[i]
@@ -329,6 +353,7 @@ class PrefillLane:
                                          shared_blocks=shared[i] or None)
                 slots[i] = slot
                 block_lists[i] = blocks
+                freed[i] = r.released.get(int(slot))
                 req.slot = int(slot)
                 req.kv_blocks = len(blocks)
                 if rx is not None:
@@ -346,10 +371,16 @@ class PrefillLane:
                     toks, rows, attention = self._forward(
                         group, prompts, matched, skip, block_lists,
                         t0s_suf, s0s, kb)
+                # the forward is on the device's queue: say so to the
+                # decode lane, whose next step runs behind it, and note
+                # the step that was queued first, which it runs behind
+                eng.prefill_in_flight = (seq,)
+                behind_tick = eng.step_in_flight
                 t_disp1 = time.perf_counter()
                 with TraceAnnotation("mxt.prefill.fetch", seq=seq,
                                      replica=r.index):
                     first = _lane_materialize([toks])[0]
+                eng.prefill_in_flight = ()
                 t_ready = time.perf_counter()
                 # a model with routed experts sends their row counts
                 # behind the first tokens, in the same fetch
@@ -380,11 +411,11 @@ class PrefillLane:
                         r.draft.set_mirror(s, int(first[i]),
                                            int(t0s[i]))
         except Exception as exc:
+            eng.prefill_in_flight = ()
             self.clock.enter("idle", time.perf_counter())
             for req in group:
                 if req.slot is not None and req.slot in mgr._active:
-                    mgr.evict(req.slot)
-                    eng.clear_slot(req.slot)
+                    r.release(req)
                 req.replica = r.index
                 req.future.set_exception(exc)
                 r.fail(req, exc, lane="prefill")
@@ -414,7 +445,8 @@ class PrefillLane:
             radix_hit_tokens=int(sum(matched)), t_start=t_start,
             t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
             t_commit1=t_commit1, t_first=t_first,
-            prefill_attention=attention,
+            prefill_attention=attention, behind_tick=behind_tick,
+            free_slots=free_slots, queued=queued,
             expert_product=product(kb * lb) if product else None,
             **(eng.selection_counts(t0s_suf[:len(group)], whole=True)
                if hasattr(eng, "selection_counts") else {}), **extra)
@@ -445,13 +477,14 @@ class PrefillLane:
                               bucket=list(req.bucket),
                               mates=[m for m in mates if m != req.id])
             if not yields:
-                r.decode.hand_off(_Handoff(req, req.slot, None))
+                r.decode.hand_off(_Handoff(req, req.slot, None, seq,
+                                           freed[i]))
             elif mgr.consume(req.slot):
                 # max_new_tokens == 1: done at prefill, never decodes
                 r.finish(req, [int(first[i])])
             else:
-                r.decode.hand_off(_Handoff(req, req.slot,
-                                           int(first[i])))
+                r.decode.hand_off(_Handoff(req, req.slot, int(first[i]),
+                                           seq, freed[i]))
         telemetry.count("serving.admitted", len(group))
 
 
@@ -471,14 +504,15 @@ class DecodeLane:
         self._stop = threading.Event()
         self._thread = None
         self.error = None
-        # the turn's first stamp and adoptions, set by _adopt for the
-        # tick's lane-log record
+        # the turn's first stamp and the hand-offs it adopted, set by
+        # _adopt for the tick's lane-log record and its turns'
         self._t_loop = None
-        self._n_adopted = 0
+        self._adopted = ()
         # the engine's attention path goes into its first tick record
         self._said_attention = False
 
     def hand_off(self, h):
+        h.t_handoff = time.perf_counter()
         with self._hand_lock:
             self._handoffs.append(h)
         self._wake.set()
@@ -555,7 +589,9 @@ class DecodeLane:
             elif self._stop.is_set():
                 break
             else:
-                self._wake.wait(self.poll_s)
+                with TraceAnnotation("mxt.decode.wait",
+                                     replica=self.r.index):
+                    self._wake.wait(self.poll_s)
                 self._wake.clear()
 
     def _adopt(self):
@@ -564,33 +600,41 @@ class DecodeLane:
         committed them before handing off), so adoption is pure
         bookkeeping — decode only ever advances slots it has adopted,
         never a slot whose commit is still in flight.  Stamps the top
-        of the lane's turn (``t_loop``) for the tick's record."""
+        of the lane's turn (``t_loop``) and keeps the hand-offs for the
+        tick's record and their ``slot.turn`` records, which the tick
+        writes once its ``t_tok`` is known."""
         self._t_loop = time.perf_counter()
-        self._n_adopted = 0
-        while True:
-            with self._hand_lock:
-                if not self._handoffs:
-                    return
-                h = self._handoffs.popleft()
-            self._n_adopted += 1
-            h.req.t_handoff = time.perf_counter()
-            hand_ms = (h.req.t_handoff - h.req.t_commit) * 1e3
-            telemetry.hist("serving.handoff_ms", hand_ms)
-            telemetry.hist(f"serving.handoff_ms|replica={self.r.index}",
-                           hand_ms)
-            if h.req.trace is not None:
-                h.req.trace.add("handoff", h.req.t_commit,
-                                h.req.t_handoff, replica=self.r.index,
-                                slot=h.slot)
-            if h.first is None:
-                # a block decoder: output offset -> token, filled in any
-                # order, and the request's log of every commit
-                h.req.commits = []
-                tokens = {}
-            else:
-                tokens = [h.first]
-            with self._hand_lock:
-                self._seqs[h.slot] = (h.req, tokens)
+        with self._hand_lock:
+            taken = tuple(self._handoffs)
+            self._handoffs.clear()
+            for h in taken:
+                # a block decoder (no first token): output offset ->
+                # token, filled in any order
+                self._seqs[h.slot] = (h.req,
+                                      {} if h.first is None else [h.first])
+        self._adopted = taken
+        if not taken:
+            return
+        # a turn crosses threads, so the xplane is linked by numbers:
+        # this tick's seq and the batches whose requests it takes up
+        with TraceAnnotation(
+                "mxt.decode.adopt", seq=self.r.engine.steps + 1,
+                replica=self.r.index,
+                batch=" ".join(str(b) for b in sorted(
+                    {h.batch for h in taken if h.batch is not None}))):
+            for h in taken:
+                h.req.t_handoff = time.perf_counter()
+                hand_ms = (h.req.t_handoff - h.req.t_commit) * 1e3
+                telemetry.hist("serving.handoff_ms", hand_ms)
+                telemetry.hist(
+                    f"serving.handoff_ms|replica={self.r.index}", hand_ms)
+                if h.req.trace is not None:
+                    h.req.trace.add("handoff", h.req.t_commit,
+                                    h.req.t_handoff, replica=self.r.index,
+                                    slot=h.slot)
+                if h.first is None:
+                    # the request's log of every commit
+                    h.req.commits = []
 
     def _abort(self, active, exc):
         """The engine call of a tick raised: fail every active request
@@ -599,10 +643,7 @@ class DecodeLane:
         for slot in active:
             with self._hand_lock:
                 req, _ = self._seqs.pop(slot)
-            r.mgr.evict(slot)
-            r.engine.clear_slot(slot)
-            if r.draft is not None:
-                r.draft.clear_slot(slot)
+            r.release(req)
             req.future.set_exception(exc)
             r.fail(req, exc, lane="decode")
         r.capacity_evt.set()
@@ -746,7 +787,8 @@ class DecodeLane:
 
     def _record_tick(self, seq, ids, n_finished, stamps, kv_tokens,
                      **extra):
-        """The turn's ``decode.tick`` record, its bookkeeping done.
+        """The turn's ``decode.tick`` record, its bookkeeping done, and
+        a ``slot.turn`` record for each hand-off the turn adopted.
         ``kv_tokens``: K/V rows the step attended, summed over the
         active slots.  The lane's first record also says which
         attention the engine's step program was built with, how many KV
@@ -768,11 +810,23 @@ class DecodeLane:
             extra.update(_cache_layers(self.r.engine))
         tracing.lane_record(
             "decode.tick", replica=self.r.index, seq=seq,
-            n_active=len(ids), n_adopted=self._n_adopted,
+            n_active=len(ids), n_adopted=len(self._adopted),
             n_finished=n_finished, kv_tokens=int(kv_tokens),
-            request_ids=ids, t_loop=self._t_loop,
+            request_ids=ids,
+            behind=self.r.engine.tick_behind,
+            t_loop=self._t_loop,
             t_lock=t_lock, t_disp0=t_disp0, t_disp1=t_disp1, t_tok=t_tok,
             t_book=time.perf_counter(), **extra)
+        for h in self._adopted:
+            # every stamp is one a boundary already took: the release's
+            # ``t_done``, the batch's, the hand-off's, this tick's
+            t_free, prev_id, freed_by = h.freed or (None, None, None)
+            tracing.lane_record(
+                "slot.turn", replica=self.r.index, slot=h.slot,
+                request_id=h.req.id, batch=h.batch, tick=seq,
+                freed_by=freed_by, prev_request_id=prev_id, t_free=t_free,
+                t_start=h.req.t_start, t_first=h.req.t_commit,
+                t_handoff=h.t_handoff, t_adopt=h.req.t_handoff, t_tok=t_tok)
 
     def _engine_stamps(self):
         """``(t_lock, t_disp0, t_disp1, t_tok)`` of the engine call
@@ -823,52 +877,54 @@ class DecodeLane:
         accepted = {}       # request id -> tokens this tick committed
         n_finished = 0
         step_idx = r.engine.steps
-        for slot in active:
-            d, g = proposals[slot], out[slot]
-            m = 0
-            while m < k and d[m] == g[m]:
-                m += 1
-            st = r.mgr.state(slot)
-            # accepted = matched drafts + the target's own next token,
-            # capped at k (on full acceptance the bonus token is NOT
-            # taken: the draft's cache only holds rows for [last,
-            # d1..d_{k-1}], so emitting g_{k+1} would leave the draft a
-            # KV row short and poison every later proposal) and clamped
-            # to the tokens still owed (never over-emit)
-            acc = min(m + 1, k, int(st.remaining))
-            adv = min(k + 1, int(st.reserved) - int(st.pos))
-            r.mgr.advance_n(slot, adv)
-            r.mgr.truncate(slot, int(pos0[slot]) + acc)
-            last = int(g[acc - 1])
-            r.engine.set_mirror(slot, last, int(pos0[slot]) + acc)
-            r.draft.set_mirror(slot, last, int(pos0[slot]) + acc)
-            with self._hand_lock:
-                req, tokens = self._seqs[slot]
-            tokens.extend(int(t) for t in g[:acc])
-            accepted[req.id] = acc
-            if req.first_tick is None:
-                req.first_tick = step_idx
-            got = min(m, acc)
-            req.draft_tokens += k
-            req.accepted_tokens += got
-            r.draft_tokens += k
-            r.accepted_tokens += got
-            accepted_this_tick += got
-            telemetry.count("serving.accepted_tokens", got)
-            if req.trace is not None:
-                req.trace.add("draft", t0, t_lock, step=step_idx,
-                              k=k, replica=r.index, slot=slot)
-                req.trace.add("verify", t_lock, t_tok, step=step_idx,
-                              accepted=acc, replica=r.index, slot=slot)
-            done = False
-            for _ in range(acc):
-                if r.mgr.consume(slot):
-                    done = True
-            if done:
+        with TraceAnnotation("mxt.decode.book", seq=step_idx,
+                             replica=r.index):
+            for slot in active:
+                d, g = proposals[slot], out[slot]
+                m = 0
+                while m < k and d[m] == g[m]:
+                    m += 1
+                st = r.mgr.state(slot)
+                # accepted = matched drafts + the target's own next token,
+                # capped at k (on full acceptance the bonus token is NOT
+                # taken: the draft's cache only holds rows for [last,
+                # d1..d_{k-1}], so emitting g_{k+1} would leave the draft a
+                # KV row short and poison every later proposal) and clamped
+                # to the tokens still owed (never over-emit)
+                acc = min(m + 1, k, int(st.remaining))
+                adv = min(k + 1, int(st.reserved) - int(st.pos))
+                r.mgr.advance_n(slot, adv)
+                r.mgr.truncate(slot, int(pos0[slot]) + acc)
+                last = int(g[acc - 1])
+                r.engine.set_mirror(slot, last, int(pos0[slot]) + acc)
+                r.draft.set_mirror(slot, last, int(pos0[slot]) + acc)
                 with self._hand_lock:
-                    del self._seqs[slot]
-                r.finish(req, tokens)
-                n_finished += 1
+                    req, tokens = self._seqs[slot]
+                tokens.extend(int(t) for t in g[:acc])
+                accepted[req.id] = acc
+                if req.first_tick is None:
+                    req.first_tick = step_idx
+                got = min(m, acc)
+                req.draft_tokens += k
+                req.accepted_tokens += got
+                r.draft_tokens += k
+                r.accepted_tokens += got
+                accepted_this_tick += got
+                telemetry.count("serving.accepted_tokens", got)
+                if req.trace is not None:
+                    req.trace.add("draft", t0, t_lock, step=step_idx,
+                                  k=k, replica=r.index, slot=slot)
+                    req.trace.add("verify", t_lock, t_tok, step=step_idx,
+                                  accepted=acc, replica=r.index, slot=slot)
+                done = False
+                for _ in range(acc):
+                    if r.mgr.consume(slot):
+                        done = True
+                if done:
+                    with self._hand_lock:
+                        del self._seqs[slot]
+                    r.finish(req, tokens)
+                    n_finished += 1
         # the verify's last column attended pos0 + k + 1 rows
         kv_tokens = sum(int(pos0[slot]) + k + 1 for slot in active)
         self._record_tick(step_idx, ids, n_finished, stamps, kv_tokens,
@@ -913,6 +969,8 @@ class Replica:
                 num_slots=num_slots, int8=int8, kv_mode="slots",
                 mesh=mesh, partition_rules=partition_rules,
                 replica_id=self.index)
+            self.draft.span_names = ("mxt.draft.dispatch",
+                                     "mxt.draft.fetch")
         spec = self.engine.cache_spec
         itemsize = self.engine.cache_itemsize
         self.mgr = PagedKVCacheManager(
@@ -958,6 +1016,11 @@ class Replica:
         self.decode = DecodeLane(self)
         self.capacity_evt = threading.Event()  # set on evict: re-admit
         self.slo = slo   # shared SLOTracker (metrics.py) or None
+        # slot -> (t_free, request id, tick) of its last release: the
+        # leaving request's ``t_done``, its id and ``engine.steps``
+        # (release() writes it on the thread that frees the slot, the
+        # prefill lane reads it as it admits into the slot)
+        self.released = {}
         self.completed = 0
         self.failed = 0
         self.batches = 0
@@ -997,13 +1060,22 @@ class Replica:
                 ServerClosedError("server stopped before execution"))
 
     # -- completion -----------------------------------------------------------
-    def finish(self, req, tokens):
+    def release(self, req):
+        """Free ``req``'s slot, its blocks and its mirrors.  The stamp of
+        the release is the request's ``t_done``, taken and kept under
+        the slot BEFORE the manager can hand the slot on, so the next
+        admission into it finds its own predecessor (the ``slot.turn``
+        record's ``t_free``, ``prev_request_id``, ``freed_by``)."""
+        req.t_done = time.perf_counter()
+        self.released[req.slot] = (req.t_done, req.id, self.engine.steps)
         self.mgr.evict(req.slot)
         self.engine.clear_slot(req.slot)
         if self.draft is not None:
             self.draft.clear_slot(req.slot)
+
+    def finish(self, req, tokens):
+        self.release(req)
         self.capacity_evt.set()
-        req.t_done = time.perf_counter()
         req.done_step = self.engine.steps
         n = req.max_new_tokens
         req.future.set_result(np.concatenate(
@@ -1048,7 +1120,8 @@ class Replica:
         self.failed += 1
         telemetry.count("serving.failed")
         telemetry.count(f"serving.failed|replica={self.index}")
-        req.t_done = time.perf_counter()
+        if req.t_done is None:    # it held no slot: release() stamps it
+            req.t_done = time.perf_counter()
         telemetry.emit(req.record(lane=lane, status="error",
                                   error=repr(exc)))
         if req.trace is not None:

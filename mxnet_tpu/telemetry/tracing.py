@@ -40,12 +40,14 @@ nothing else.  Completed traces go three places:
 
 The **lane log** (further down) is the always-on half: one bounded
 ring of coarse records — a decode tick, a prefill batch, a stretch the
-prefill lane spent gated, a train dispatch — appended by the thread that
-did the work, from ``perf_counter`` stamps taken once at each boundary.
+prefill lane spent gated, a slot's turn from its release to its next
+tick, a train dispatch — appended by the thread that did the work, from
+``perf_counter`` stamps taken once at each boundary.
 It needs no ``enable()``; the per-request span trees above stay opt-in.
 The same boundaries open ``jax.profiler.TraceAnnotation`` spans named
 ``mxt.*`` (one atomic load while no profile runs), which land on
-``/host:CPU`` of the xplane beside the device planes.
+``/host:CPU`` of the xplane beside the device planes; a lane thread is
+always under one of them, its waits too.
 
 Cost contract (same as the rest of telemetry): disabled →
 ``start_trace`` is one module-boolean check returning None, and every
@@ -300,7 +302,9 @@ def incident(reason, context=None, path=None):
 
 # -- lane log ----------------------------------------------------------------
 
-#: records the ring keeps (a 100 ms decode tick fills it in 27 minutes)
+#: records the ring keeps (a 100 ms decode tick fills it in 27 minutes; a
+#: replica flat out at 40 ticks and 25 admissions a second, each admission
+#: a batch and a turn, in 3)
 LANE_LOG_CAPACITY = 16384
 #: lane records a flight-record dump carries
 LANE_TAIL = 256
@@ -311,6 +315,7 @@ _lane_clocks = {}   # replica -> the prefill lane's LaneClock
 _LANE_SPAN = {"decode.tick": ("t_loop", "t_book"),
               "prefill.batch": ("t_start", "t_first"),
               "prefill.gated": ("t0", "t1"),
+              "slot.turn": ("t_start", "t_tok"),
               "train.dispatch": ("t0", "t_end")}
 
 

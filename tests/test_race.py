@@ -121,6 +121,8 @@ class _StubMgr:
 
 
 class _StubEngine:
+    tick_behind = ()
+
     def __init__(self):
         self.steps = 0
 
@@ -135,7 +137,7 @@ class _StubEngine:
 class _StubReq:
     def __init__(self, rid):
         self.id = rid
-        self.t_first = self.t_commit = 0.0
+        self.t_start = self.t_first = self.t_commit = 0.0
         self.t_handoff = None
         self.first_tick = None
         self.trace = None

@@ -612,7 +612,11 @@ def test_benchmark_config_keeps_every_published_width():
         "out_tok_per_s", "tpot_p50_ms.sat", "decode_step_ms", "tick_host_ms",
         "device_idle_share.decode", "expert_rows_max_over_mean",
         "experts_touched_share", "passes_per_block", "block_slot_occupancy",
-        "block_step_roofline"}
+        "block_step_roofline",
+        # the admission, from the lane log's slot.turn records (PR 34)
+        "slot_turn_ms", "slot_wait_lane_ms", "handoff_wait_ms",
+        "tick_stretch_ms", "ticks_behind_prefill_share",
+        "free_slots_at_admit"}
 
 
 @pytest.fixture
