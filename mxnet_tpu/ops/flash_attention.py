@@ -12,7 +12,20 @@ log-sum-exp saved for the backward) and the served decoders' prefill
 (``prefill_flash_attention``: the query heads of a KV head in one tile,
 each prompt's true length bounding the tiles computed).
 
-Backward: ``jax.custom_vjp`` with a K-block-chunked jnp backward
+A grid step.  The grid is ``(B * Hkv / hb, nq, nk)`` (``dkv``: ``nk``
+before ``nq``): a step is one (q tile, k tile) pair of ``hb`` (batch,
+head) rows.  ``hb`` is 1 — a step is a tile pair of one head of one
+batch row — for the served prefill and wherever a sequence spans more
+than one tile.  Where one tile holds a head's whole sequence (the
+trainer at sequences up to 512: BERT's 128) a step of one row is all
+fixed cost, so it takes ``train_tiles`` rows at once, a leading batch
+axis of the same arithmetic; the choice is static, from shapes, and the
+gauges ``flash.rows_per_step.fwd`` / ``.dq`` / ``.dkv`` record it where
+the program is traced, and the row statistics (``lse``, ``delta``) then
+travel along lanes, ``(bh, 1, T)``, not one number a lane tile.
+
+Backward: ``jax.custom_vjp``; on the TPU the two Pallas kernels below
+(``dq``, ``dkv``), elsewhere a K-block-chunked jnp backward
 (``lax.scan``) — recompute-based, so backward memory is O(T·block) too.
 Non-TPU platforms (the CPU test mesh) fall back to a jnp online-softmax
 scan with identical semantics AND the same O(T*block) score memory, so
@@ -27,6 +40,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .. import telemetry
 
 #: reviewed signature budget (mxlint T15): the jit of the served
 #: prefill's entry is inlined into the prefill program that calls it and
@@ -154,11 +169,28 @@ def _tile_runs(qi, kj, n, *, block_q, block_k, causal):
     return run
 
 
+def _dot(a, b, ca, cb):
+    """``a`` . ``b`` over axis ``ca`` of ``a``'s matrix and ``cb`` of
+    ``b``'s (0 or 1, counted within the last two axes), float32
+    results; a leading axis, where the operands have one, is batched: a
+    step's ``hb`` rows."""
+    n = a.ndim - 2
+    batch = tuple(range(n))
+    return lax.dot_general(a, b, (((n + ca,), (n + cb,)), (batch, batch)),
+                           preferred_element_type=jnp.float32)
+
+
 def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
-               with_lse, bounded, span=1):
-    """Canonical 3-D-grid flash kernel: grid (B * Hkv, nq, nk), kv
+               with_lse, bounded, span=1, hb=1):
+    """Canonical 3-D-grid flash kernel: grid (B * Hkv / hb, nq, nk), kv
     innermost; running (m, l, acc) live in VMEM scratch across the kv
     sweep so pallas double-buffers the K/V block loads.
+
+    A grid step is one (q tile, k tile) pair of ``hb`` rows of
+    ``B * Hkv``: one row everywhere but where ``train_tiles`` says
+    otherwise (a head's whole sequence in one tile); the ``hb`` rows are
+    then a leading batch axis of every array in the body, each row's
+    arithmetic what it is alone.
 
     A grid row is one KV head and the ``G = H / Hkv`` query heads it
     serves (G = 1 without GQA): their ``block_q`` rows each are laid as
@@ -188,6 +220,9 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     m_ref, l_ref, acc_ref = refs[-3:]
     group, _, d = q_ref.shape[1:]
     rows = group * block_q
+    # the step's rows of ``bh``: one (the program every caller had), or
+    # ``hb`` of them as a leading batch axis of every array below
+    lead, blk = ((), 0) if hb == 1 else ((hb,), slice(None))
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     n = len_ref[pl.program_id(0) // kv_heads] if bounded else None
@@ -199,10 +234,9 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile(masked):
-        q = q_ref[0].reshape(rows, d)
-        v = v_ref[0]
-        s = lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+        q = q_ref[blk].reshape(*lead, rows, d)
+        v = v_ref[blk]
+        s = _dot(q, k_ref[blk], 1, 1) * scale
         if masked:
             qpos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (group, block_q, block_k), 1).reshape(
@@ -219,9 +253,8 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + _dot(
+            p.astype(v.dtype), v, 1, 0)
         m_ref[...] = m_new
 
     run = _tile_runs(qi, kj, n, block_q=block_q, block_k=block_k,
@@ -237,18 +270,17 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     @pl.when(kj == nk - 1)
     def _finish():
         l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
-            group, block_q, d).astype(o_ref.dtype)
+        o_ref[blk] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
+            *lead, group, block_q, d).astype(o_ref.dtype)
         if with_lse:
             # log-sum-exp per query row, saved for the pallas backward;
             # a row no tile ran for keeps -inf (its backward p is
-            # zeroed).  Stored (…, block_q, 1): mosaic requires the
-            # last two block dims (8, 128)-aligned or equal to the
-            # array's — a trailing singleton satisfies that where a 2-D
-            # (1, block_q) cannot.
-            lse_ref[0] = jnp.where(
+            # zeroed).  A step of one row stores it (group, block_q, 1),
+            # as it is computed; a step of several rows along lanes,
+            # (hb, group, block_q): see ``_fa_forward_pallas``
+            lse_ref[blk] = jnp.where(
                 l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
-                -jnp.inf).reshape(group, block_q, 1)
+                -jnp.inf).reshape(*lead, *lse_ref.shape[1:])
 
 
 def _divisor_block(t, pref):
@@ -258,13 +290,66 @@ def _divisor_block(t, pref):
     return t
 
 
+#: what a step's rows may ask of the 16 MiB of VMEM a kernel gets by
+#: default: three quarters.  At BERT's shape 24 rows (15.7 MB by
+#: ``train_row_bytes``) compile alone and are refused inside the whole
+#: vjp's program (16.56 MB); a raised ``vmem_limit_bytes`` let 32-64 rows
+#: compile and made ``dq`` and ``dkv`` a quarter slower at every ``hb``
+#: (PERF.md, PR 36)
+TRAIN_VMEM_BYTES = 12 << 20
+
+
+def train_row_bytes(tq, tk, d, itemsize=2):
+    """VMEM a (batch, head) row of a one-tile step asks for, by the
+    largest of the three kernels (``dkv``): its four operand and two
+    result blocks double-buffered, heads narrower than a tile's 128
+    lanes padded to them, and four score-shaped float32 arrays (the
+    float32 casts and the accumulators reuse what is dead).  0.655 MB at
+    T = 128, D = 64 in bf16; the compiler counts 0.636 (20.35 MB at 32
+    rows)."""
+    lanes = -(-d // 128) * 128
+    return 2 * (4 * tq + 2 * tk) * lanes * itemsize + 4 * tq * tk * 4
+
+
+def train_tiles(bh, tq, tk, d, itemsize=2):
+    """Rows of ``bh = B * H`` a grid step of the three training kernels
+    takes: 1, today's grid, unless one tile holds a head's whole
+    sequence (``nq == nk == 1`` at the entries' default blocks: sequences
+    up to 512); then the largest divisor of ``bh`` whose working set fits
+    ``TRAIN_VMEM_BYTES``.
+
+    On the v5e at BERT-base's ``(128 x 12, 128, 128, 64)`` in bf16, ms a
+    layer-call, forward / dq / dkv (PERF.md, PR 36; the program before
+    the rule is the first line): 1 row a step 1.07 / 1.13 / 1.43; with
+    the statistics still one number a lane tile 4 rows 0.58 / 0.72 /
+    0.90 and 16 rows 0.48 / 0.70 / 0.85; with them along lanes 4 rows
+    0.57 / 0.50 / 0.60, 8 rows 0.60 / 0.46 / 0.60, 12 rows 0.53 / 0.44 /
+    0.54, **16 rows 0.52 / 0.43 / 0.53**, 24 rows 0.53 / 0.42 / 0.52.
+    A step of one row cost 0.70-0.93 us whatever it computed; at 16 rows
+    the forward is bound by its elementwise work on the scores (0.33 us
+    a row) and the backward by its float32-operand products.  A loop
+    over the rows inside the step gained nothing in the forward (0.99:
+    a row's two dependent products are latency that only independent
+    rows in one block hide), so the rows are a batch axis."""
+    one_tile = (_divisor_block(tq, min(512, tq)) == tq
+                and _divisor_block(tk, min(512, tk)) == tk)
+    cap = TRAIN_VMEM_BYTES // train_row_bytes(tq, tk, d, itemsize) \
+        if one_tile else 1
+    return max(c for c in range(1, max(1, min(cap, bh)) + 1) if bh % c == 0)
+
+
 def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
                        with_lse=False, interpret=False, lengths=None,
                        name=None, span=1):
     """q (B, H, T, D), k/v (B, Hkv, T, D) with Hkv dividing H -> (B, H,
     T, D)[, lse (B, H, T)].  ``lengths`` (B,) int32: each batch row's
     true length (see ``_fa_kernel``'s ``bounded``); ``span``: a block
-    decoder's block length (its ``span``)."""
+    decoder's block length (its ``span``).
+
+    A grid step is a (q tile, k tile) pair of one KV head of one batch
+    row and its query heads; without ``lengths`` (the trainer), with
+    equal heads and the whole sequence in one tile, it is that pair of
+    ``train_tiles`` rows of ``B * H``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -279,6 +364,12 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     block_k = _divisor_block(tk, min(block_k, tk))
     nk = tk // block_k
     bounded = lengths is not None
+    hb = 1
+    if not bounded:
+        if g == 1 and (block_q, block_k) == (tq, tk):
+            hb = train_tiles(bh, tq, tk, d, q.dtype.itemsize)
+        telemetry.gauge("flash.rows_per_step.fwd", hb)
+    lead = () if hb == 1 else (hb,)
 
     def q_map(b_, i, j, *_):
         return (b_, 0, i, 0)
@@ -293,29 +384,45 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
             _tile_runs(i, j, n, block_q=block_q, block_k=block_k,
                        causal=causal), j, 0), 0)
 
-    out_specs = [pl.BlockSpec((1, g, block_q, d), q_map)]
+    out_specs = [pl.BlockSpec((hb, g, block_q, d), q_map)]
     out_shape = [_pallas_out_shape((bh, g, tq, d), q.dtype, q, k, v)]
-    if with_lse:
+    if with_lse and hb == 1:
+        # a trailing singleton: mosaic requires the last two block dims
+        # (8, 128)-aligned or equal to the array's, which a 2-D
+        # (1, block_q) cannot be
         out_specs.append(pl.BlockSpec((1, g, block_q, 1), q_map))
         out_shape.append(
             _pallas_out_shape((bh, g, tq, 1), jnp.float32, q, k, v))
+    elif with_lse:
+        # that layout lies one number a 128-lane tile in HBM: a block of
+        # (128, 1) is 64 KB of DMA for 512 bytes, a third of what a
+        # one-tile step of a row moves.  Several rows a step go along
+        # lanes, (hb, g, block_q) of (bh, g, tq): the middle axis is
+        # whole, so any hb is legal.  Not at hb == 1: past one tile the
+        # relayout at every q tile's end costs the forward 6-9% (T =
+        # 1,024 and 2,048; PERF.md, PR 36)
+        out_specs.append(pl.BlockSpec((hb, g, block_q),
+                                      lambda b_, i, j, *_: (b_, 0, i)))
+        out_shape.append(
+            _pallas_out_shape((bh, g, tq), jnp.float32, q, k, v))
     out = pl.pallas_call(
         functools.partial(_fa_kernel, block_q=block_q, block_k=block_k,
                           causal=causal, scale=scale, nk=nk, kv_heads=hkv,
-                          with_lse=with_lse, bounded=bounded, span=span),
+                          with_lse=with_lse, bounded=bounded, span=span,
+                          hb=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(bounded),
-            grid=(bh, tq // block_q, nk),
+            grid=(bh // hb, tq // block_q, nk),
             in_specs=[
-                pl.BlockSpec((1, g, block_q, d), q_map),
-                pl.BlockSpec((1, block_k, d), kv_map),
-                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((hb, g, block_q, d), q_map),
+                pl.BlockSpec((hb, block_k, d), kv_map),
+                pl.BlockSpec((hb, block_k, d), kv_map),
             ],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((g * block_q, 1), jnp.float32),   # m
-                pltpu.VMEM((g * block_q, 1), jnp.float32),   # l
-                pltpu.VMEM((g * block_q, d), jnp.float32),   # acc
+                pltpu.VMEM((*lead, g * block_q, 1), jnp.float32),   # m
+                pltpu.VMEM((*lead, g * block_q, 1), jnp.float32),   # l
+                pltpu.VMEM((*lead, g * block_q, d), jnp.float32),   # acc
             ]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -402,11 +509,28 @@ prefill_flash_attention = jax.jit(_prefill_flash_attention,
 # the forward kernel), delta = rowsum(dO * O) is a cheap fused
 # elementwise computed outside.
 
+def _stat(ref, blk, as_row):
+    """A statistics block (``lse`` or ``delta``) for scores laid keys by
+    queries (``as_row``: beside them it is a row ``(..., 1, block_q)``)
+    or queries by keys (a column ``(..., block_q, 1)``) -> the block, and
+    the function that lays what is computed from it beside the scores.
+    Where a step is one row it travels as a column and is read as the
+    1-D vector the kernels always made of it, laid out after (their
+    program unchanged); where a step is several it travels along lanes
+    (``_fa_backward_pallas``) and is laid out once, here."""
+    if ref.shape[-1] == 1:
+        return ref[blk][:, 0], (
+            lambda x: x[None, :]) if as_row else (lambda x: x[:, None])
+    x = ref[blk]
+    return x if as_row else x[..., 0, :][..., None], lambda x: x
+
+
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, acc_ref, *, block_q, block_k, causal,
-                      scale, nk):
+                      scale, nk, hb=1):
     from jax.experimental import pallas as pl
 
+    blk = 0 if hb == 1 else slice(None)
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -418,13 +542,13 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(pred)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, 0]
-        delta = delta_ref[0][:, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        q = q_ref[blk].astype(jnp.float32)
+        k = k_ref[blk].astype(jnp.float32)
+        v = v_ref[blk].astype(jnp.float32)
+        do = do_ref[blk].astype(jnp.float32)
+        lse, col = _stat(lse_ref, blk, False)
+        delta = _stat(delta_ref, blk, False)[0]
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             qpos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -432,24 +556,24 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos >= kpos, s, -jnp.inf)
         # fully-masked rows carry lse=-inf: zero their p explicitly
-        p = jnp.where(jnp.isfinite(s) & jnp.isfinite(lse)[:, None],
-                      jnp.exp(s - jnp.where(jnp.isfinite(lse), lse,
-                                            0.0)[:, None]), 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        acc_ref[...] += jnp.dot(ds, k,
-                                preferred_element_type=jnp.float32)
+        p = jnp.where(jnp.isfinite(s) & col(jnp.isfinite(lse)),
+                      jnp.exp(s - col(jnp.where(jnp.isfinite(lse), lse,
+                                                0.0))), 0.0)
+        dp = _dot(do, v, 1, 1)
+        ds = p * (dp - col(delta)) * scale
+        acc_ref[...] += _dot(ds, k, 1, 0)
 
     @pl.when(kj == nk - 1)
     def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[blk] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                       block_k, causal, scale, nq):
+                       block_k, causal, scale, nq, hb=1):
     from jax.experimental import pallas as pl
 
+    blk = 0 if hb == 1 else slice(None)
     ki = pl.program_id(1)
     qj = pl.program_id(2)
 
@@ -464,38 +588,39 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(pred)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, 0]
-        delta = delta_ref[0][:, 0]
-        st = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
+        q = q_ref[blk].astype(jnp.float32)
+        k = k_ref[blk].astype(jnp.float32)
+        v = v_ref[blk].astype(jnp.float32)
+        do = do_ref[blk].astype(jnp.float32)
+        lse, row = _stat(lse_ref, blk, True)
+        delta = _stat(delta_ref, blk, True)[0]
+        st = _dot(k, q, 1, 1) * scale
         if causal:
             kpos = ki * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 0)
             qpos = qj * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
             st = jnp.where(qpos >= kpos, st, -jnp.inf)
-        pt = jnp.where(jnp.isfinite(st) & jnp.isfinite(lse)[None, :],
-                       jnp.exp(st - jnp.where(jnp.isfinite(lse), lse,
-                                              0.0)[None, :]), 0.0)
-        dv_acc[...] += jnp.dot(pt, do,
-                               preferred_element_type=jnp.float32)
-        dpt = jnp.dot(v, do.T, preferred_element_type=jnp.float32)
-        dst = pt * (dpt - delta[None, :]) * scale
-        dk_acc[...] += jnp.dot(dst, q,
-                               preferred_element_type=jnp.float32)
+        pt = jnp.where(jnp.isfinite(st) & row(jnp.isfinite(lse)),
+                       jnp.exp(st - row(jnp.where(jnp.isfinite(lse), lse,
+                                                  0.0))), 0.0)
+        dv_acc[...] += _dot(pt, do, 1, 0)
+        dpt = _dot(v, do, 1, 1)
+        dst = pt * (dpt - row(delta)) * scale
+        dk_acc[...] += _dot(dst, q, 1, 0)
 
     @pl.when(qj == nq - 1)
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[blk] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[blk] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
                         block_k=512, interpret=False):
-    """dq/dk/dv via the two pallas kernels; (B, H, T, D) in and out."""
+    """dq/dk/dv via the two pallas kernels; (B, H, T, D) in and out.
+    A grid step of either is a (q tile, k tile) pair of one head of one
+    batch row, or of ``train_tiles`` rows of ``B * H`` where the whole
+    sequence is one tile; float32 operands in all five products."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -506,63 +631,77 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
     kf = k.reshape(bh, tk, d)
     vf = v.reshape(bh, tk, d)
     dof = do.reshape(bh, tq, d)
-    # trailing singleton: see the forward's lse block-alignment note
-    lsef = lse.reshape(bh, tq, 1)
-    # delta = rowsum(dO * O): one fused elementwise pass outside the
-    # kernels (XLA fuses it into the surrounding graph)
-    delta = (dof.astype(jnp.float32) *
-             o.reshape(bh, tq, d).astype(jnp.float32)).sum(
-                 -1, keepdims=True)
     block_q = _divisor_block(tq, min(block_q, tq))
     block_k = _divisor_block(tk, min(block_k, tk))
     nq, nk = tq // block_q, tk // block_k
+    hb = 1
+    if nq == nk == 1:
+        hb = train_tiles(bh, tq, tk, d, q.dtype.itemsize)
+    telemetry.gauge("flash.rows_per_step.dq", hb)
+    telemetry.gauge("flash.rows_per_step.dkv", hb)
+    lead = () if hb == 1 else (hb,)
+    # the statistics in the forward's layouts (see its lse notes): a
+    # trailing singleton where a step is one row, along lanes where it
+    # is several
+    if hb == 1:
+        stat_shape, stat_block = (bh, tq, 1), (1, block_q, 1)
+        stat_at = lambda b_, i: (b_, i, 0)
+    else:
+        stat_shape, stat_block = (bh, 1, tq), (hb, 1, block_q)
+        stat_at = lambda b_, i: (b_, 0, i)
+    lsef = lse.reshape(stat_shape)
+    # delta = rowsum(dO * O): one fused elementwise pass outside the
+    # kernels (XLA fuses it into the surrounding graph)
+    delta = (dof.astype(jnp.float32) *
+             o.reshape(bh, tq, d).astype(jnp.float32)).sum(-1).reshape(
+                 stat_shape)
 
-    # dq: grid (bh, nq, nk) — K innermost, q/do/lse/delta follow i
+    # dq: grid (bh / hb, nq, nk) — K innermost, q/do/lse/delta follow i
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
-                          nk=nk),
-        grid=(bh, nq, nk),
+                          nk=nk, hb=hb),
+        grid=(bh // hb, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, i)),
+            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d),
+        out_specs=pl.BlockSpec((hb, block_q, d),
                                lambda b_, i, j: (b_, i, 0)),
         out_shape=_pallas_out_shape((bh, tq, d), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((*lead, block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
-    # dkv: grid (bh, nk, nq) — Q innermost, k/v follow i
+    # dkv: grid (bh / hb, nk, nq) — Q innermost, k/v follow i
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
-                          nq=nq),
-        grid=(bh, nk, nq),
+                          nq=nq, hb=hb),
+        grid=(bh // hb, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, j)),
+            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
         ],
         out_shape=[
             _pallas_out_shape((bh, tk, d), k.dtype, q, k, v, do),
             _pallas_out_shape((bh, tk, d), v.dtype, q, k, v, do),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((*lead, block_k, d), jnp.float32),
+                        pltpu.VMEM((*lead, block_k, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,

@@ -624,33 +624,59 @@ def _interp_kernels(monkeypatch):
     return fa
 
 
+def _train_operands(shape, dtype, seed=3, n=3):
+    import jax.numpy as jnp
+
+    rng = onp.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.normal(size=shape).astype("f"), dtype)
+                 for _ in range(n))
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_pallas_backward_kernels_match_oracle(monkeypatch, causal):
+@pytest.mark.parametrize("shape,dtype", [
+    ((2, 2, 256, 32), "float32"),
+    ((4, 12, 128, 64), "bfloat16"),     # BERT-base's heads, rows of 128
+], ids=["f32_2x2x256x32", "bf16_4x12x128x64"])
+def test_flash_pallas_backward_kernels_match_oracle(monkeypatch, causal,
+                                                    shape, dtype):
     """The full custom-vjp path with PALLAS kernels both directions
     (interpret mode): forward saves lse, backward runs the two-kernel
-    dq/dkv design, gradients match the dense vjp oracle."""
+    dq/dkv design, gradients match the dense vjp oracle.  Both shapes are
+    one tile a head, so a grid step takes several (batch, head) rows
+    (``train_tiles``); in bf16 the oracle is the float32 attention of the
+    same rounded operands and the tolerance a few bf16 steps of the
+    largest value."""
     import jax
     import jax.numpy as jnp
 
     fa = _interp_kernels(monkeypatch)
-    rng = onp.random.RandomState(3)
-    B, H, T, D = 2, 2, 256, 32
-    q, k, v = (jnp.asarray(rng.normal(size=(B, H, T, D)).astype("f"))
-               for _ in range(3))
-    scale = 1 / float(onp.sqrt(D))
+    assert fa.train_tiles(shape[0] * shape[1], shape[2], shape[2],
+                          shape[3]) > 1
+    q, k, v = _train_operands(shape, dtype)
+    scale = 1 / float(onp.sqrt(shape[-1]))
+    f32 = dtype == "float32"
 
     def loss(fn):
-        return lambda a, b, c: (fn(a, b, c) ** 2).sum()
+        return lambda a, b, c: (fn(a, b, c).astype(jnp.float32) ** 2).sum()
+
+    def oracle(a, b, c):
+        return fa._sdpa_ref(*(x.astype(jnp.float32) for x in (a, b, c)),
+                            causal, scale)
+
+    def close(got, want, tol):
+        got, want = (onp.asarray(x, onp.float32) for x in (got, want))
+        bound = tol if f32 else tol * float(onp.abs(want).max())
+        assert float(onp.abs(got - want).max()) < bound
 
     out = fa.flash_attention_raw(q, k, v, causal, scale)
-    ref = fa._sdpa_ref(q, k, v, causal, scale)
-    assert float(jnp.abs(out - ref).max()) < 1e-4
+    assert out.dtype == q.dtype
+    close(out, oracle(q, k, v), 1e-4 if f32 else 2 ** -7)
     g = jax.grad(loss(lambda a, b, c: fa.flash_attention_raw(
         a, b, c, causal, scale)), argnums=(0, 1, 2))(q, k, v)
-    r = jax.grad(loss(lambda a, b, c: fa._sdpa_ref(
-        a, b, c, causal, scale)), argnums=(0, 1, 2))(q, k, v)
+    r = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
     for got, want in zip(g, r):
-        assert float(jnp.abs(got - want).max()) < 2e-4
+        assert got.dtype == q.dtype
+        close(got, want, 2e-4 if f32 else 2 ** -6)
 
 
 def test_flash_pallas_backward_sharded(monkeypatch):

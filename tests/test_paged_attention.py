@@ -765,6 +765,49 @@ def test_prefill_program_compiles_for_v5e_without_scores(one_chip, flash):
         assert repeats and temps > 4e9, temps
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((128, 12, 128, 64), "bfloat16"),   # bert_base.pretrain_s128
+    ((128, 12, 128, 64), "float32"),    # the same without autocast
+    ((16, 8, 512, 128), "bfloat16"),    # the longest one-tile sequence
+    ((2, 8, 1024, 64), "bfloat16"),     # past one tile: a row a step
+], ids=["bert_s128_bf16", "bert_s128_f32", "t512_hd128", "t1024_hd64"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_training_flash_kernels_compile_for_v5e(one_chip, shape, dtype,
+                                                causal):
+    """The trainer's three flash kernels at the rows a grid step that
+    ``train_tiles`` gives (16 at BERT-base's shape): the step's working
+    set fits the VMEM a kernel gets, or this compile raises as the
+    chip's would; three Mosaic calls; the statistics of a step of
+    several rows lie along lanes, ``f32[bh,1,T]``, not one number a lane
+    tile, and a step of one row keeps the program it had."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, h, t, d = shape
+    hb = fa.train_tiles(b * h, t, t, d, jnp.dtype(dtype).itemsize)
+    assert (hb > 1) == (t <= 512)
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    def step(q, k, v, do):
+        o, lse = fa._fa_forward_pallas(q, k, v, causal, 0.125,
+                                       with_lse=True)
+        return fa._fa_backward_pallas(q, k, v, o, do, lse, causal, 0.125)
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(step).lower(x, x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    lanes, column = f"f32[{b * h},1,{t}]", f"f32[{b * h},{t},1]"
+    assert (lanes in text) == (hb > 1) and (column in text) == (hb == 1)
+
+
 @pytest.mark.parametrize("rows,k,held,hidden,width", [
     (512, 8, 128, 2048, 768),     # sdar_30b.chat_decode_sat: a block pass
     (512, 4, 64, 2048, 1536),     # lfm2_24b: its 512 prefill bucket

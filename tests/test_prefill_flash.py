@@ -4,8 +4,11 @@ the operands fed in their own dtype): the kernel under the Pallas
 interpreter against ``ops.attention.masked_attention`` under ``tril``, the
 prefill programs of the tiny Llama and LFM2 decoders through it against
 the dense path, the rule that picks it, and what an engine and its lane
-log say of it.  The compile for a described v5e is in
-``tests/test_paged_attention.py``, beside the paged kernel's."""
+log say of it; and the trainer's three kernels by the rows a grid step
+takes (``train_tiles``; ``tests/test_llama.py``, which holds the whole
+custom-vjp to the oracle, is a ``slow`` module that tier 1 leaves out).
+The compiles for a described v5e are in ``tests/test_paged_attention.py``,
+beside the paged kernel's."""
 import functools
 import time
 
@@ -15,6 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from mxnet_tpu import telemetry
 from mxnet_tpu.models import decoder as decoder_mod
 from mxnet_tpu.models import lfm2
 from mxnet_tpu.models.llama import LlamaDecoder, llama_tiny
@@ -98,6 +102,116 @@ def test_training_forward_keeps_its_lse_for_the_backward():
         np.testing.assert_allclose(_f32(out),
                                    _f32(fa._sdpa_ref(q, k, v, causal, scale)),
                                    atol=2e-2, rtol=2e-2)
+
+
+# --- the trainer's kernels: the rows a grid step takes ------------------------
+
+def _train_kernels(q, k, v, do, causal):
+    """Output, lse and the three gradients straight from the kernels'
+    entries, as numpy float32."""
+    scale = 1 / float(np.sqrt(q.shape[-1]))
+    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, with_lse=True,
+                                   interpret=True)
+    grads = fa._fa_backward_pallas(q, k, v, o, do, lse, causal, scale,
+                                   interpret=True)
+    return [np.asarray(x, np.float32) for x in (o, lse, *grads)]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_training_kernels_match_the_dense_vjp(causal):
+    """Forward, dq and dkv at BERT-base's heads (rows of 128, heads of 64,
+    bf16: one tile a head, so ``train_tiles`` rows a step) against the
+    float32 attention of the same rounded operands and its vjp, within a
+    few bf16 steps of each tensor's largest value."""
+    rng = np.random.default_rng(3)
+    q, k, v, do = (jnp.asarray(rng.normal(size=(2, 6, 128, 64)),
+                               jnp.bfloat16) for _ in range(4))
+    assert fa.train_tiles(12, 128, 128, 64) == 12
+    o, lse, *grads = _train_kernels(q, k, v, do, causal)
+    want, pull = jax.vjp(
+        lambda a, b, c: fa._sdpa_ref(a, b, c, causal, 0.125),
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, got, ref, tol in zip(
+            ("o", "dq", "dk", "dv"), (o, *grads),
+            (want, *pull(do.astype(jnp.float32))),
+            (2 ** -7, 2 ** -6, 2 ** -6, 2 ** -6)):
+        ref = _f32(ref)
+        assert np.abs(got - ref).max() < tol * np.abs(ref).max(), name
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("hb", [1, 2, 4, 12])
+def test_flash_rows_a_step_change_no_bit(monkeypatch, hb, causal):
+    """However many (batch, head) rows a grid step takes, the forward's
+    output and log-sum-exp and all three gradients are one row a step's
+    to the bit (the arithmetic of a row is the same; rows share
+    nothing), and the three gauges say what was chosen."""
+    rng = np.random.default_rng(11)
+    operands = tuple(jnp.asarray(rng.normal(size=(4, 12, 128, 64)),
+                                 jnp.bfloat16) for _ in range(4))
+    monkeypatch.setattr(fa, "train_tiles", lambda *a: 1)
+    want = _train_kernels(*operands, causal)
+    monkeypatch.setattr(fa, "train_tiles", lambda *a: hb)
+    telemetry.enable()
+    try:
+        got = _train_kernels(*operands, causal)
+        gauges = telemetry.gauges()
+    finally:
+        telemetry.disable()
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert np.isfinite(a).all(), name
+        assert np.array_equal(a, b), name
+    assert [gauges[f"flash.rows_per_step.{k}"]
+            for k in ("fwd", "dq", "dkv")] == [hb] * 3
+
+
+@pytest.mark.parametrize("tq,tk", [(640, 640), (1024, 1024), (2048, 2048),
+                                   (4096, 4096), (128, 1024), (1024, 128)])
+def test_train_tiles_is_one_past_one_tile(tq, tk):
+    """More than one tile along either side: the grid is today's, a row
+    a step."""
+    assert tq // fa._divisor_block(tq, min(512, tq)) > 1 or \
+        tk // fa._divisor_block(tk, min(512, tk)) > 1
+    for bh in (8, 48, 1536):
+        for d in (64, 128):
+            assert fa.train_tiles(bh, tq, tk, d) == 1
+
+
+@pytest.mark.parametrize("bh,t,d", [(1536, 128, 64), (48, 128, 64),
+                                    (4, 256, 32), (64, 512, 128),
+                                    (7, 128, 64), (1, 128, 64)])
+def test_train_tiles_divides_and_fits(bh, t, d):
+    """One tile a head: the rows a step divide ``bh`` and their working
+    set stays inside what a kernel gets."""
+    hb = fa.train_tiles(bh, t, t, d)
+    assert 1 <= hb <= bh and bh % hb == 0
+    assert hb * fa.train_row_bytes(t, t, d) <= fa.TRAIN_VMEM_BYTES \
+        or hb == 1
+    if bh in (1536, 48) and t == 128:
+        assert hb > 1
+
+
+@pytest.mark.parametrize("lp", [256, 1024, 4096])
+@pytest.mark.parametrize("group", [4, 8])
+def test_served_prefill_keeps_its_grid(monkeypatch, group, lp):
+    """The served prefill's calls are ``bounded`` (true lengths): the
+    rule is not consulted and the traced program's text is the one-row
+    form's, whatever the rule would say."""
+    q = jax.ShapeDtypeStruct((1, 2 * group, lp, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2, lp, 128), jnp.bfloat16)
+    n = jax.ShapeDtypeStruct((1,), jnp.int32)
+
+    def text():
+        return str(jax.make_jaxpr(fa._prefill_flash_attention)(q, kv, kv, n))
+
+    monkeypatch.setattr(fa, "train_tiles", lambda *a: 1)
+    one_row = text()
+    asked = []
+    monkeypatch.setattr(fa, "train_tiles",
+                        lambda *a: asked.append(a) or 8)
+    assert text() == one_row and not asked
+    bq, bk = fa.prefill_tiles(group, lp)
+    assert f"GridMapping(grid=(2, {lp // bq}, {lp // bk})," in one_row
 
 
 # --- the rule ----------------------------------------------------------------

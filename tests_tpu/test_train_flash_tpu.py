@@ -1,0 +1,100 @@
+"""The training flash kernels on the chip at ``bert_base.pretrain_s128``'s
+shape, ``(128, 12, 128, 64)`` in bf16, where one tile holds a head's whole
+sequence and a grid step takes several (batch, head) rows
+(``mxnet_tpu/ops/flash_attention.py`` ``train_tiles``): the output and the
+three gradients against ``_sdpa_ref`` in float32, what the three
+``flash.rows_per_step.*`` gauges say, and that the step's working set
+compiles inside the VMEM a kernel gets.
+
+Tolerance: ``chip_smoke.py``'s kernel phase's — on the tensor, not the
+element: relative rms error at most 2^-6 and no element further off than
+2^-4 of the tensor's largest value (bf16 operands into the MXU, bf16
+results, the backward's ``dp - delta`` cancellation)."""
+import numpy as np
+import pytest
+
+SHAPE = (128, 12, 128, 64)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_kernels_match_reference_and_say_their_rows(causal, parity_record):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, h, t, d = SHAPE
+    hb = fa.train_tiles(b * h, t, t, d)
+    assert hb > 1 and (b * h) % hb == 0
+    scale = 1.0 / float(np.sqrt(d))
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, k, v, g = (jax.random.normal(kk, SHAPE, jnp.float32)
+                  .astype(jnp.bfloat16) for kk in keys)
+
+    def kern(q, k, v, g):
+        out, pull = jax.vjp(lambda a, b_, c: fa.flash_attention_raw(
+            a, b_, c, causal, scale), q, k, v)
+        return (out,) + pull(g)
+
+    def ref(q, k, v, g):
+        out, pull = jax.vjp(lambda a, b_, c: fa._sdpa_ref(
+            a, b_, c, causal, scale),
+            *(a.astype(jnp.float32) for a in (q, k, v)))
+        return (out,) + pull(g.astype(jnp.float32))
+
+    telemetry.enable()
+    try:
+        compiled = jax.jit(kern).lower(q, k, v, g).compile()
+        gauges = telemetry.gauges()
+    finally:
+        telemetry.disable()
+    # the choice is static: recorded where the program is traced
+    assert [gauges[f"flash.rows_per_step.{n}"]
+            for n in ("fwd", "dq", "dkv")] == [hb] * 3
+    # three Mosaic calls; a step whose working set passed the VMEM a
+    # kernel gets would not have compiled
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    assert hb * fa.train_row_bytes(t, t, d) <= fa.TRAIN_VMEM_BYTES
+    got = jax.block_until_ready(compiled(q, k, v, g))
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(ref)(q, k, v, g))
+    for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(a).all(), name
+        rel_rms = float(np.sqrt(np.mean((a - w) ** 2))
+                        / np.sqrt(np.mean(w ** 2)))
+        rel_max = float(np.abs(a - w).max() / np.abs(w).max())
+        parity_record("train_flash_attention",
+                      f"{name}_{'causal' if causal else 'full'}", rel_max)
+        assert rel_rms <= 2.0 ** -6 and rel_max <= 2.0 ** -4, \
+            (name, rel_rms, rel_max)
+
+
+def test_rows_a_step_change_no_result_on_the_chip(monkeypatch):
+    """One row a step (the program before the rule) and the rule's
+    choice: the same arithmetic a row, so the same bf16 results."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, h, t, d = 16, 12, 128, 64
+    keys = jax.random.split(jax.random.PRNGKey(6), 4)
+    q, k, v, g = (jax.random.normal(kk, (b, h, t, d), jnp.bfloat16)
+                  for kk in keys)
+
+    def run():
+        def kern(q, k, v, g):
+            o, lse = fa._fa_forward_pallas(q, k, v, False, 0.125,
+                                           with_lse=True)
+            return (o, lse) + fa._fa_backward_pallas(
+                q, k, v, o, g, lse, False, 0.125)
+        return [np.asarray(x, np.float32)
+                for x in jax.jit(lambda *a: kern(*a))(q, k, v, g)]
+
+    chosen = run()
+    monkeypatch.setattr(fa, "train_tiles", lambda *a: 1)
+    for name, a, w in zip(("o", "lse", "dq", "dk", "dv"), chosen, run()):
+        assert np.array_equal(a, w), name

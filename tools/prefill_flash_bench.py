@@ -5,6 +5,11 @@ buckets, against ``ops.attention.masked_attention`` at the same shapes,
 and the two-layer prefill program a bucket, kernel against dense path.
 
     chiprun -- python tools/prefill_flash_bench.py [--quick]
+    chiprun -- python tools/prefill_flash_bench.py --train 128,12,128,64
+
+``--train B,H,T,D`` times the trainer's three kernels instead (forward,
+dq, dkv, and the whole custom-vjp with the XLA around it) by the rows a
+grid step takes (``ops.flash_attention.train_tiles``).
 
 Each timing is one jitted program of ``CALLS`` chained calls (the output
 feeds the next call's queries, as a decoder's layers do), run ``REPS``
@@ -42,6 +47,13 @@ def main():
         help="block_q:block_k pairs of the sweep at heads of 128")
     ap.add_argument("--kernel-only", action="store_true",
                     help="skip the two-layer prefill programs")
+    ap.add_argument("--train", metavar="B,H,T,D",
+                    help="the training kernels alone at this shape "
+                         "(forward, dq, dkv), by rows a grid step; "
+                         "nothing of the prefill")
+    ap.add_argument("--rows", default="1,4,8,12,16,24",
+                    help="rows a grid step of the --train sweep; the "
+                         "rule's own choice is always timed")
     args = ap.parse_args()
     sweep = [tuple(int(n) for n in t.split(":"))
              for t in args.tiles.split(",")]
@@ -91,6 +103,10 @@ def main():
 
     def flops(hd, n):
         return 4 * H * hd * (n * (n + 1) // 2)
+
+    if args.train:
+        train(args, fa, say, timed)
+        return
 
     def kernel(bq, bk, hd):
         return chain(lambda q, k, v, n: fa._fa_forward_pallas(
@@ -170,6 +186,81 @@ def main():
             rec["flash_ms" if flash else "dense_ms"] = \
                 timed(prog, w, ids, t0, flash, calls=1) * 1e3
         say(**rec)
+
+
+def train(args, fa, say, timed):
+    """Forward, dq and dkv at one (B, H, T, D) in bf16, non-causal as
+    BERT runs them: each a program of ``CALLS`` chained calls (the
+    result feeds the next call's q, or k and v), by rows a grid step
+    (``train_tiles`` patched, as tier 1 patches it).  A program that
+    returns dq alone holds no dkv call and the other way round (XLA
+    drops a kernel whose results nothing reads); ``delta`` is computed
+    once a program, its operands being the same in every call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, h, t, d = (int(n) for n in args.train.split(","))
+    scale = 1.0 / float(np.sqrt(d))
+    keys = jax.random.split(jax.random.PRNGKey(t + d), 4)
+    q, k, v, do = (jax.random.normal(kk, (b, h, t, d), jnp.bfloat16)
+                   for kk in keys)
+    rule = fa.train_tiles
+    chosen = rule(b * h, t, t, d)
+    o, lse = jax.jit(lambda q, k, v: fa._fa_forward_pallas(
+        q, k, v, False, scale, with_lse=True))(q, k, v)
+
+    def fwd(q, k, v, o, do, lse):
+        for _ in range(CALLS):
+            q = fa._fa_forward_pallas(q, k, v, False, scale,
+                                      with_lse=True)[0]
+        return q
+
+    def dq(q, k, v, o, do, lse):
+        for _ in range(CALLS):
+            q = fa._fa_backward_pallas(q, k, v, o, do, lse, False,
+                                       scale)[0]
+        return q
+
+    def dkv(q, k, v, o, do, lse):
+        for _ in range(CALLS):
+            _, k, v = fa._fa_backward_pallas(q, k, v, o, do, lse, False,
+                                             scale)
+        return k, v
+
+    def vjp(q, k, v, o, do, lse):
+        for _ in range(CALLS):
+            out, pull = jax.vjp(lambda a, b_, c: fa.flash_attention_raw(
+                a, b_, c, False, scale), q, k, v)
+            q, k, v = pull(do)
+            q = q + out
+        return q, k, v
+
+    rows = sorted({int(r) for r in args.rows.split(",") if r} | {chosen})
+    try:
+        for hb in rows:
+            if (b * h) % hb:
+                continue
+            fa.train_tiles = lambda *_: hb
+            rec = {"what": "train", "shape": [b, h, t, d], "hb": hb,
+                   "chosen": hb == chosen}
+            for name, fn in (("fwd", fwd), ("dq", dq), ("dkv", dkv),
+                             ("vjp", vjp)):
+                try:
+                    rec[name + "_ms"] = round(timed(
+                        jax.jit(lambda *a, fn=fn: fn(*a)),
+                        q, k, v, o, do, lse) * 1e3, 4)
+                except Exception as e:      # a step Mosaic refuses
+                    rec[name + "_error"] = str(e)[-300:]
+            if all(n + "_ms" in rec for n in ("fwd", "dq", "dkv")):
+                rec["layer_ms"] = round(
+                    rec["fwd_ms"] + rec["dq_ms"] + rec["dkv_ms"], 4)
+                rec["step_us"] = {n: round(rec[n + "_ms"] * 1e3 * hb
+                                           / (b * h), 3)
+                                  for n in ("fwd", "dq", "dkv")}
+            say(**rec)
+    finally:
+        fa.train_tiles = rule
 
 
 if __name__ == "__main__":
