@@ -223,7 +223,8 @@ def _registry_workspace(axes, remat):
 def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
                  block_size, dtype=np.float32, mesh=None, rules=None,
                  state_layers=0, state_shape=None, num_slots=0,
-                 latent_layers=0, latent_dim=0, index_dim=0):
+                 latent_layers=0, latent_dim=0, index_dim=0,
+                 state_arrays=None):
     """Per-device bytes of the serving engine's paged cache.  The block
     pool: 2 (K and V) × ``num_layers`` × ``num_blocks × num_kv_heads ×
     block_size × head_dim`` × itemsize, sharded the way the serving rule
@@ -232,7 +233,10 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
     pool (``CacheSpec.kv_layers``), not the model's depth.  Plus, for a
     model whose other layers keep a fixed per-slot state:
     ``state_layers × num_slots × prod(state_shape)`` × itemsize
-    (unsharded).  Plus, for the layers that keep latent rows and index
+    (unsharded); a layer that owns several arrays states them as
+    ``state_arrays``, ``((shape, dtype), ...)``
+    (``CacheSpec.state_arrays``), each priced by its own dtype (None:
+    ``dtype``).  Plus, for the layers that keep latent rows and index
     keys in the same block tables (``CacheSpec.latent_layers``, with
     its ``latent_dim`` and ``index_dim``): ``latent_layers ×
     num_blocks`` × the bytes a block as ``ops.latent_cache`` stores it
@@ -256,8 +260,11 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
     n_elem = int(np.prod(shape))
     state = 0
     if state_layers:
-        state = int(state_layers) * int(num_slots) \
-            * int(np.prod(state_shape)) * dtype.itemsize
+        if state_arrays is None:
+            state_arrays = ((state_shape, None),)
+        state = int(state_layers) * int(num_slots) * sum(
+            int(np.prod(shape)) * np.dtype(sdt or dtype).itemsize
+            for shape, sdt in state_arrays)
     latent = 0
     if latent_layers:
         from ..ops import latent_cache

@@ -10,7 +10,7 @@ step, or :class:`BlockDecoding`, the positions of a block in any order),
 ``_weights()`` (``layers``: a dict a layer; ``emb``), ``layer(p, x, rope,
 view) -> (x, kept, expert rows or None)``, ``_logits(w, x)`` and, for a
 state layer, ``_sequence_state(kept, t0)``: what prefill keeps of a
-whole sequence.
+whole sequence, as ``CacheSpec.state_entry`` lays a layer's arrays out.
 
 A cache view holds where K and V live and answers one call, ``attend(q,
 k, v) -> (context, kept)``: this call's queries, keys and values after
@@ -66,9 +66,13 @@ class CacheSpec:
 
     ``layers[l]`` is ``"kv"`` (the layer owns a K and a V block pool
     ``(num_blocks, num_kv_heads, block_size, head_dim)``, addressed
-    through the slots' block tables), ``"state"`` (it owns one array
-    ``(num_slots,) + state_shape``: a fixed-size state a slot, written
-    whole at admission and in place by every step) or ``"latent"`` (it
+    through the slots' block tables), ``"state"`` (it owns the arrays
+    of ``state_arrays``, ``((shape, dtype), ...)``: of each a
+    ``(num_slots,) + shape`` array of ITS dtype, None the weights': a
+    fixed-size state a slot, written whole at admission and in place by
+    every step; ``state_shape`` is the one-array case in the weights'
+    dtype.  A layer's cache entry is that array where it owns one, a
+    tuple of them where several: :meth:`state_entry`) or ``"latent"`` (it
     owns, in the same block tables, a pool of latent rows, ``latent_dim``
     values a token, and a pool of index keys, ``index_dim`` values a
     token, stored as ``ops.latent_cache`` stores them; a query reads
@@ -80,29 +84,35 @@ class CacheSpec:
     :class:`BlockDecoding` of one that commits the positions of a block
     in any order."""
 
-    __slots__ = ("layers", "num_kv_heads", "head_dim", "state_shape",
+    __slots__ = ("layers", "num_kv_heads", "head_dim", "state_arrays",
                  "expert_layers", "num_experts", "decoding", "latent_dim",
                  "index_dim", "select_topk")
 
     def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
                  expert_layers=0, num_experts=0, decoding=None,
-                 latent_dim=0, index_dim=0, select_topk=0):
+                 latent_dim=0, index_dim=0, select_topk=0,
+                 state_arrays=None):
         self.layers = tuple(layers)
         if any(kind not in ("kv", "state", "latent")
                for kind in self.layers):
             raise MXNetError(f"unknown cache kind in {self.layers}")
         self.num_kv_heads = int(num_kv_heads)
         self.head_dim = int(head_dim)
-        self.state_shape = None if state_shape is None \
-            else tuple(int(d) for d in state_shape)
+        if state_arrays is None and state_shape is not None:
+            state_arrays = ((state_shape, None),)
+        self.state_arrays = tuple(
+            (tuple(int(d) for d in shape),
+             None if dtype is None else np.dtype(dtype))
+            for shape, dtype in state_arrays or ())
         self.expert_layers = int(expert_layers)
         self.num_experts = int(num_experts)
         self.decoding = decoding
         self.latent_dim = int(latent_dim)
         self.index_dim = int(index_dim)
         self.select_topk = int(select_topk)
-        if self.state_layers and self.state_shape is None:
-            raise MXNetError("state layers need a state_shape")
+        if self.state_layers and not self.state_arrays:
+            raise MXNetError("state layers need a state_shape, or the "
+                             "state_arrays a layer owns")
         if self.latent_layers and not (self.latent_dim and self.index_dim
                                        and self.select_topk):
             raise MXNetError("latent layers need latent_dim, index_dim "
@@ -130,12 +140,32 @@ class CacheSpec:
             + self.latent_layers * latent_cache.bytes_per_block(
                 block_size, self.latent_dim, self.index_dim, itemsize)
 
+    @staticmethod
+    def state_entry(arrays):
+        """A state layer's cache entry from its arrays in the spec's
+        order: the array itself where the layer owns one, the tuple
+        where several."""
+        arrays = tuple(arrays)
+        return arrays[0] if len(arrays) == 1 else arrays
+
+    @staticmethod
+    def entry_arrays(entry):
+        """:meth:`state_entry`'s inverse: always a tuple."""
+        return entry if isinstance(entry, tuple) else (entry,)
+
+    def state_array_bytes(self, itemsize):
+        """Bytes of each of a state layer's arrays a slot, in their
+        order; an array without a dtype of its own holds values of
+        ``itemsize`` bytes (the weights')."""
+        return tuple(
+            math.prod(shape) * (int(itemsize) if dtype is None
+                                else dtype.itemsize)
+            for shape, dtype in self.state_arrays)
+
     def state_bytes_per_slot(self, itemsize):
-        """Bytes of one slot's state over every state layer."""
-        if not self.state_layers:
-            return 0
-        return self.state_layers * math.prod(self.state_shape) \
-            * int(itemsize)
+        """Bytes of one slot's state over every state layer, each array
+        by its own dtype."""
+        return self.state_layers * sum(self.state_array_bytes(itemsize))
 
 
 # -- what the models' layers share ---------------------------------------------
@@ -376,8 +406,9 @@ class DenseCache:
 
 class StepView:
     """What a decode call's layer sees of the paged cache: its own
-    ``entry`` (a ``(K pool, V pool)`` pair, or a ``(slots, L, hidden)``
-    state), each slot's position ``pos`` and, for a K/V layer, the
+    ``entry`` (a ``(K pool, V pool)`` pair, or a state layer's
+    ``CacheSpec.state_entry``: a ``(slots, L, hidden)`` state, or a
+    tuple of such), each slot's position ``pos`` and, for a K/V layer, the
     call's ``ops.paged_attention.Window``.  The pool's layout is that
     module's; this view only says when to write and when to attend."""
 
@@ -455,6 +486,12 @@ class PagedDecoder:
                               cfg.num_experts, cfg.hidden_size,
                               cfg.moe_intermediate_size, dtype)
 
+    def linear_attention(self):
+        """Which form a step's linear-attention layers take
+        (``ops.gated_delta.step_form``); None for a model without
+        them."""
+        return None
+
     def _layers(self, w, x, rope, views):
         """-> (x, what each layer's view kept, the expert rows as the
         programs return them: () or a 1-tuple)."""
@@ -471,11 +508,15 @@ class PagedDecoder:
     def _decode(self, w, cache, tables, x, pos, rope, paged_kernel):
         import jax.numpy as jnp
 
-        pool = next(e for e in cache if isinstance(e, tuple))[0]
+        spec = self.cache_spec()
+        # the window is sized by a pool of rows in the block tables: any
+        # layer's but a state layer's
+        pool = next(e for kind, e in zip(spec.layers, cache)
+                    if kind != "state")[0]
         win = paged_attention.window(pool, tables, pos, self.max_len,
                                      paged_kernel,
                                      block=self.block_len is not None)
-        topk = self.cache_spec().select_topk
+        topk = spec.select_topk
         views = [StepView(e, pos, win, topk) for e in cache]
         x, cache, counts = self._layers(w, x, rope, views)
         # a selecting model's step says, last, what each layer read:
@@ -490,7 +531,8 @@ class PagedDecoder:
         continuous batching: requests admitted at different times
         decode in one program, each slot at its own position.
         ``cache[l]`` is a ``(K pool, V pool)`` pair shared by every slot
-        or a ``(S, L, hidden)`` state, by the cache spec; ``tables``
+        or a state layer's ``(S,) + shape`` array (a tuple of them
+        where it owns several), by the cache spec; ``tables``
         (S, MB) int32 holds each slot's block ids in logical order,
         vacant entries = ``num_blocks``; ``ids_t``, ``pos`` (S,) int32.
         -> (logits (S, V), cache[, expert rows]).  MB is static, so the
@@ -587,8 +629,8 @@ class PagedDecoder:
         causal = self._prefill_view(lp, real, lengths, t0)
         x, rows, counts = self._layers(w, x, rope,
                                        (causal for _ in w["layers"]))
-        rows = [r if isinstance(r, tuple) else self._sequence_state(r, t0)
-                for r in rows]
+        rows = [self._sequence_state(r, t0) if kind == "state" else r
+                for kind, r in zip(self.cache_spec().layers, rows)]
         return (rows, self._last(w, x, t0)) + counts
 
     def _prefill_suffix_impl(self, w, prefix_kv, ids, t0, s0):

@@ -8,9 +8,12 @@ programs (``_step_blocks_impl``, ``_prefill_rows_impl``, written once in
 ``models.decoder.PagedDecoder``; the commit is the engine's scatter over
 what they return) and its **cache spec**
 (:class:`~mxnet_tpu.models.decoder.CacheSpec`): which layers own a K/V block pool and
-which a fixed per-slot state, of what shape.  A layer's state lives in
-one array ``(num_slots,) + state_shape`` beside the pools, is donated
-through the step like them, and is written whole at admission.  Two
+which a fixed per-slot state, of what arrays.  A layer's state lives in
+one array ``(num_slots,) + shape`` for each ``(shape, dtype)`` its spec
+states (``CacheSpec.state_arrays``: a convolution's ring in the weights'
+dtype, a linear-attention layer's float32 matrices beside it), each
+beside the pools, donated through the step like them, and written whole
+at admission.  Two
 storage modes share one surface (``kv_mode=``):
 
 * **paged** (default since r11) — K/V lives in a shared block pool per
@@ -275,8 +278,9 @@ class LlamaServingEngine:
         deq = _dequantize_tree if self.int8 else (lambda t: t)
         cfg = net.config
         dt = w["emb"].dtype
-        #: bytes of one cached value (K, V and state share the weights'
-        #: load dtype): what the manager prices blocks and states with
+        #: bytes of one cached value (K, V and a state array that names no
+        #: dtype of its own share the weights' load dtype): what the
+        #: manager prices blocks and states with
         self.cache_itemsize = int(np.dtype(dt).itemsize)
         #: where the weights (and so the cache) live: with the mesh and
         #: the shapes, what the attention kernels are chosen from
@@ -309,14 +313,16 @@ class LlamaServingEngine:
                 self.num_blocks, self.block_size, spec.latent_dim,
                 spec.index_dim)
             # one entry a layer, by the spec: a (K, V) pool pair, a
-            # (latent rows, index keys) pool pair, or the layer's
-            # per-slot state
+            # (latent rows, index keys) pool pair, or the arrays of the
+            # layer's per-slot state, each of its own dtype
             self._pool = [
                 (jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
                 if kind == "kv" else
                 tuple(jnp.zeros(shape, dt) for shape in lshapes)
                 if kind == "latent" else
-                jnp.zeros((self.num_slots,) + spec.state_shape, dt)
+                spec.state_entry(
+                    jnp.zeros((self.num_slots,) + shape, sdt or dt)
+                    for shape, sdt in spec.state_arrays)
                 for kind in spec.layers]
             self._tables = np.full((self.num_slots, self.max_blocks),
                                    self.num_blocks, np.int32)
@@ -357,6 +363,11 @@ class LlamaServingEngine:
         #: K/V rows the last step() attended: ``pos + 1`` summed over
         #: its active slots (the record's ``kv_tokens``)
         self.tick_kv_tokens = 0
+        #: bytes of per-slot state a slot's layers read and write a
+        #: step, by the spec (the record's ``state_bytes`` is this times
+        #: the active slots)
+        self.state_bytes_per_step = 2 * spec.state_bytes_per_slot(
+            self.cache_itemsize)
         #: What each lane has on the device's queue, for the other to
         #: see: the ``seq`` of the step()/verify() whose dispatch has
         #: returned and whose tokens are not yet on the host, and the
@@ -388,6 +399,11 @@ class LlamaServingEngine:
         #: row holds (above 1 only under the kernel)
         self.decode_attention = "latent_sparse" if spec.latent_layers \
             else "paged_kernel" if paged_kernel else "gather"
+        #: which form the step's linear-attention layers take
+        #: (``ops.gated_delta.step_form``): "step_kernel" (the state
+        #: read and written once, in place) or "step_xla"; None for a
+        #: model without such layers
+        self.linear_attention = dec.linear_attention()
         self._platform = platform
         #: a selecting model: positions the last step's active slots
         #: could see and positions they read, a layer (the
@@ -523,18 +539,20 @@ class LlamaServingEngine:
                 # rows[l]: (KB, Hkv, Lp, hd) raw prefill K/V, written
                 # block by block at flat_idx (the prefill→decode KV
                 # handoff, ``paged_attention.scatter_rows``).  A state
-                # layer's rows (KB,) + state_shape replace the WHOLE
-                # state of ``slots`` (vacant rows: slot id num_slots,
-                # dropped), so a reused slot never sees its predecessor's
-                # a latent layer's rows (KB, Lp, width) go block by
-                # block as its format stores them
+                # layer's rows, (KB,) + shape for each of its arrays,
+                # replace the WHOLE state of ``slots`` (vacant rows:
+                # slot id num_slots, dropped), so a reused slot never
+                # sees its predecessor's; a latent layer's rows (KB, Lp,
+                # width) go block by block as its format stores them
                 by_block = {"kv": paged_attention.scatter_rows,
                             "latent": latent_cache.scatter_rows}
                 return [
                     tuple(by_block[kind](p, r, flat_idx)
                           for p, r in zip(entry, row))
                     if kind in by_block
-                    else entry.at[slots].set(row, mode="drop")
+                    else jax.tree_util.tree_map(
+                        lambda e, r: e.at[slots].set(r, mode="drop"),
+                        entry, row)
                     for kind, entry, row in zip(spec.layers, pools, rows)]
 
         else:
@@ -726,7 +744,9 @@ class LlamaServingEngine:
         ``plan_kv_pool`` predicts pre-build.  ``by_kind`` splits it:
         ``{"kv_blocks": ..., "slot_state": ...}`` and, where a layer
         keeps them, ``"latent_blocks"`` and ``"index_key_blocks"`` (as
-        stored, padding counted).  On a tp mesh each
+        stored, padding counted) and, where a state layer owns several
+        arrays, ``"slot_state_arrays"``: the bytes of each over the
+        layers, in the spec's order.  On a tp mesh each
         device holds one shard of the pool's head axis, so this is the
         single-shard footprint, not the global array size."""
         def shard_bytes(a):
@@ -741,12 +761,16 @@ class LlamaServingEngine:
                 else ("kv",) * len(kv)
             blocks = sum(shard_bytes(e[0]) + shard_bytes(e[1])
                          for k, e in zip(kinds, kv) if k == "kv")
-            state = sum(shard_bytes(e) for k, e in zip(kinds, kv)
-                        if k == "state")
+            arrays = [sum(shard_bytes(self.cache_spec.entry_arrays(e)[i])
+                          for k, e in zip(kinds, kv) if k == "state")
+                      for i in range(len(self.cache_spec.state_arrays))]
+            state = sum(arrays)
             latent, keys = (sum(shard_bytes(e[i]) for k, e in zip(kinds, kv)
                                 if k == "latent") for i in (0, 1))
         if by_kind:
             out = {"kv_blocks": int(blocks), "slot_state": int(state)}
+            if len(arrays) > 1:
+                out["slot_state_arrays"] = tuple(int(a) for a in arrays)
             if self.cache_spec.latent_layers:
                 out.update(latent_blocks=int(latent),
                            index_key_blocks=int(keys))
@@ -774,6 +798,12 @@ class LlamaServingEngine:
             "expert_rows_max": int(counts.max()),
             "expert_rows_mean": float(counts.sum() / max(1, touched.sum())),
         }
+        first, held = self.experts_held or (0, spec.num_experts)
+        if held < spec.num_experts:
+            # a bank that holds a part of the router's experts: the
+            # touched ones among those it holds, whose weights are here
+            fields["experts_touched_held"] = int(
+                touched[:, first:first + held].sum())
         tot = self.expert_totals
         tot["programs"] += 1
         tot["rows"] += int(counts.sum())
@@ -842,7 +872,8 @@ class LlamaServingEngine:
         import jax.numpy as jnp
 
         kb = len(slots)
-        lp = next(r for r in rows if isinstance(r, tuple))[0].shape[-2]
+        lp = next(r for kind, r in zip(self.cache_spec.layers, rows)
+                  if kind != "state")[0].shape[-2]
         nbp = -(-lp // self.block_size)
         flat = np.full(kb * nbp, self.num_blocks, np.int32)
         for r, blocks in enumerate(block_lists):
@@ -947,8 +978,18 @@ class LlamaServingEngine:
                         self._dev(self._blk_step))
                     self._pool = out[1]
                 elif self.kv_mode == "paged":
+                    tables = self._tables
+                    if self.linear_attention:
+                        # a step moves a recurrent state on by a token,
+                        # so it is not idempotent: a slot committed and
+                        # not yet adopted runs as a vacant one, and
+                        # keeps its state until a tick owns it
+                        mine = np.zeros(self.num_slots, bool)
+                        mine[list(active)] = True
+                        tables = np.where(mine[:, None], tables,
+                                          np.int32(self.num_blocks))
                     out = self._step(
-                        self._w, self._pool, self._dev(self._tables),
+                        self._w, self._pool, self._dev(tables),
                         self._dev(self._last), self._dev(self._pos))
                     self._pool = out[1]
                     if self.cache_spec.select_topk:

@@ -107,7 +107,18 @@ def _cache_layers(engine):
     out = {"kv_layers": spec.kv_layers, "state_layers": spec.state_layers}
     if spec.latent_layers:
         out["latent_layers"] = spec.latent_layers
+    if getattr(engine, "linear_attention", None):
+        out["linear_attention"] = engine.linear_attention
     return out
+
+
+def _scan_rows(engine, n_tokens, bucket_rows):
+    """``scan_rows`` / ``scan_rows_padded`` of a ``prefill.batch``
+    record: the true rows and the bucket's rows that went through a
+    linear-attention layer's chunked scan ({} for a model without)."""
+    if not getattr(engine, "linear_attention", None):
+        return {}
+    return {"scan_rows": int(n_tokens), "scan_rows_padded": int(bucket_rows)}
 
 
 class _Handoff:
@@ -449,7 +460,8 @@ class PrefillLane:
             free_slots=free_slots, queued=queued,
             expert_product=product(kb * lb) if product else None,
             **(eng.selection_counts(t0s_suf[:len(group)], whole=True)
-               if hasattr(eng, "selection_counts") else {}), **extra)
+               if hasattr(eng, "selection_counts") else {}),
+            **_scan_rows(eng, t0s_suf[:len(group)].sum(), kb * lb), **extra)
         capacity.lane_busy(r.index, "prefill", t_start, t_first)
         for i, req in enumerate(group):
             req.t_commit = t_first
@@ -793,9 +805,16 @@ class DecodeLane:
         active slots.  The lane's first record also says which
         attention the engine's step program was built with, how many KV
         heads a stored pool row holds (``kv_pack``), which product its
-        routed experts run (``expert_product``) and how many layers
-        keep K/V and how many a per-slot state."""
+        routed experts run (``expert_product``), how many layers
+        keep K/V and how many a per-slot state, and which form its
+        linear-attention layers take (``linear_attention``).  A model
+        with per-slot state says in every record how many bytes of it
+        the turn read and wrote (``state_bytes``)."""
         t_lock, t_disp0, t_disp1, t_tok = stamps
+        per_slot = getattr(self.r.engine, "state_bytes_per_step", 0)
+        if per_slot:
+            # what the active slots' state layers read and wrote
+            extra["state_bytes"] = per_slot * len(ids)
         if not self._said_attention:
             self._said_attention = True
             extra["decode_attention"] = getattr(

@@ -634,6 +634,8 @@ class GenerativeServer(_ServerBase):
             "compiled_signatures":
                 reps[0].engine.compiled_signatures(),
             "decode_attention": reps[0].engine.decode_attention,
+            # "step_kernel" / "step_xla"; None without such layers
+            "linear_attention": reps[0].engine.linear_attention,
             "prefill_attention": reps[0].engine.prefill_attention,
             "kv_pack": reps[0].engine.kv_pack,
             "expert_product": reps[0].engine.expert_product,
@@ -642,7 +644,8 @@ class GenerativeServer(_ServerBase):
             "state_layers": reps[0].engine.cache_spec.state_layers,
             "latent_layers": reps[0].engine.cache_spec.latent_layers,
             # the cache's bytes a device, by kind: K/V blocks, per-slot
-            # state and, of a latent model, latent rows and index keys
+            # state (and by array where a state layer owns several) and,
+            # of a latent model, latent rows and index keys
             "cache_bytes": reps[0].engine.kv_pool_bytes(by_kind=True),
             # "next_token", or "block_diffusion" with the block sizes
             "decoding": reps[0].engine.decoding,

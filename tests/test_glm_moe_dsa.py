@@ -620,12 +620,17 @@ def test_benchmark_config_keeps_every_published_width():
 
 
 @pytest.fixture
-def harness():
+def harness(monkeypatch, tmp_path):
     for p in (BENCH, REPO):
         if p not in sys.path:
             sys.path.insert(0, p)
     import run as harness
 
+    # a traced run of its own trace directory: the checkout's one
+    # ``.chipbench_trace`` is shared by every test process, and a traced
+    # rehearsal that starts in another worker removes it under this one
+    # ("the profiler wrote no trace")
+    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
     return harness
 
 
