@@ -60,8 +60,14 @@ decode — only its cheap block scatter briefly takes the lock.
 
 Between any two step calls the scheduler may admit new requests
 (prefill + scatter) or evict finished ones — the continuous-batching
-join point.  Weights are frozen at engine build; ``int8=True`` stores
-them as per-output-channel symmetric int8 (scale = max|row|/127) and
+join point.  A step is two halves, :meth:`LlamaServingEngine.dispatch_step`
+(queue it; the cursors move on) and :meth:`LlamaServingEngine.fetch_step`
+(wait for its tokens; book them): a slot's input token is the one the
+step before produced, read on the device, so the decode lane queues
+step K+1 before it fetches step K (``serving/lanes.py``
+``DecodeLane._tick``); ``step()`` is the two back to back.  Weights are
+frozen at engine build; ``int8=True`` stores them as per-output-channel
+symmetric int8 (scale = max|row|/127) and
 dequantizes in-kernel — the weight-only quantization the int8 MXU
 pricing in ``INT8_TOPOLOGY_r05.json`` motivates.
 
@@ -224,6 +230,47 @@ class BlockTick(NamedTuple):
     stored: np.ndarray
 
 
+class StepHandle:
+    """One step on the device's queue, from
+    :meth:`LlamaServingEngine.dispatch_step` to
+    :meth:`LlamaServingEngine.fetch_step`: its tokens on the device and
+    everything the host knew as it was dispatched, so that whoever books
+    it reads the step's own and not the engine's newest."""
+
+    __slots__ = ("seq", "active", "toks", "selected", "behind", "ahead",
+                 "t_lock", "t_disp0", "t_disp1", "t_tok", "pos",
+                 "kv_tokens", "selection", "experts")
+
+    def __init__(self, seq, active, toks, t_lock, t_disp0, t_disp1,
+                 behind=(), ahead=False, selected=None, pos=None,
+                 kv_tokens=0, selection=None):
+        self.seq = seq            #: ``engine.steps`` as it was dispatched
+        self.active = active      #: the slots it advances
+        self.toks = toks          #: what the host fetches, on the device
+        #: a selecting model: what each layer read, (layers, S, k), on
+        #: the device (:meth:`LlamaServingEngine.selection_of`)
+        self.selected = selected
+        #: the prefill batches that were queued before it and not yet
+        #: fetched as its dispatch returned
+        self.behind = behind
+        #: the step before it had not been fetched as it was dispatched
+        self.ahead = ahead
+        #: before dev_lock, lock held, the jitted call returned and the
+        #: lock released; ``t_tok``: its tokens on the host (fetch_step)
+        self.t_lock, self.t_disp0, self.t_disp1 = t_lock, t_disp0, t_disp1
+        self.t_tok = None
+        #: (S,) the cursors after it (a block decoder's move as it is
+        #: booked: None), the K/V rows it attended (``pos + 1`` over the
+        #: active slots) and a selecting model's ``kv_visible`` /
+        #: ``kv_selected``
+        self.pos = pos
+        self.kv_tokens = kv_tokens
+        self.selection = selection or {}
+        #: ``experts_touched`` / ``expert_rows_max`` / ``expert_rows_mean``
+        #: of a model that routes, fetched behind the tokens
+        self.experts = {}
+
+
 class LlamaServingEngine:
     """Device-side half of continuous batching for any model that
     answers ``serving_decoder(max_len)`` with a decoder holding the
@@ -354,24 +401,29 @@ class LlamaServingEngine:
             #: committed: over every tick (``server.stats()``)
             self.block_totals = {"block_passes": 0, "blocks_committed": 0,
                                  "committed_tokens": 0}
+        #: which slots' ``_last`` the host wrote (a prefill's commit,
+        #: ``set_mirror``, ``clear_slot``) since a step last advanced
+        #: them: a slot's next input is the token the step before
+        #: produced, still on the device, and the host's where this says
+        #: so (every slot to start with)
+        self._fresh = np.ones(self.num_slots, bool)
         self.steps = 0
-        #: (t_lock, t_disp0, t_disp1, t_tok) of the last step()/verify():
-        #: before dev_lock, lock held, jitted call returned and lock
-        #: released, tokens on host.  Written and read by the decode
-        #: thread alone (the lane log's ``decode.tick`` record).
-        self.tick_stamps = None
-        #: K/V rows the last step() attended: ``pos + 1`` summed over
-        #: its active slots (the record's ``kv_tokens``)
-        self.tick_kv_tokens = 0
+        #: the :class:`StepHandle` of the last step()/verify() whose
+        #: tokens reached the host: its stamps, ``behind``, ``kv_tokens``
+        #: and ``experts`` are the lane log's ``decode.tick`` record.
+        #: Written and read by the decode thread alone.
+        self.booked = None
         #: bytes of per-slot state a slot's layers read and write a
         #: step, by the spec (the record's ``state_bytes`` is this times
         #: the active slots)
         self.state_bytes_per_step = 2 * spec.state_bytes_per_slot(
             self.cache_itemsize)
         #: What each lane has on the device's queue, for the other to
-        #: see: the ``seq`` of the step()/verify() whose dispatch has
-        #: returned and whose tokens are not yet on the host, and the
-        #: ``seq``s of the prefill batches in the same state (the
+        #: see: the ``seq`` of the newest step()/verify() whose dispatch
+        #: has returned and whose tokens are not yet on the host (the
+        #: decode lane keeps a step queued behind the one it books, so
+        #: there may be an older one too), and the ``seq``s of the
+        #: prefill batches in the same state (the
         #: prefill lane writes it between its forward's return and its
         #: fetch's).  Each lane says so as its dispatch returns and
         #: then reads the other's: programs run in the order they were
@@ -379,9 +431,6 @@ class LlamaServingEngine:
         #: written by one thread and read by the other, no lock.
         self.step_in_flight = None
         self.prefill_in_flight = ()
-        #: ``prefill_in_flight`` as the last step()/verify() found it at
-        #: ``t_disp1`` (the ``decode.tick`` record's ``behind``)
-        self.tick_behind = ()
         #: the ``mxt.*`` names of step()'s dispatch and fetch spans; a
         #: replica names its draft engine's apart (``mxt.draft.*``)
         self.span_names = ("mxt.decode.dispatch", "mxt.decode.fetch")
@@ -405,13 +454,6 @@ class LlamaServingEngine:
         #: model without such layers
         self.linear_attention = dec.linear_attention()
         self._platform = platform
-        #: a selecting model: positions the last step's active slots
-        #: could see and positions they read, a layer (the
-        #: ``decode.tick`` record's ``kv_visible`` / ``kv_selected``),
-        #: and what each layer of the last step read, on the device
-        #: (:meth:`selection_of`)
-        self.tick_selection = {}
-        self._tick_selected = None
         #: which attention the prefill programs run, decided a bucket
         #: (``prefill_attention_at``): "flash" where the engine's
         #: longest 128-aligned prompt goes through
@@ -433,12 +475,18 @@ class LlamaServingEngine:
         #: (first, count): the part of each routed layer's bank that
         #: the model holds (its config's to say); None: all of it
         self.experts_held = getattr(cfg, "experts_held", None)
-        #: the last step's ``experts_touched`` / ``expert_rows_max`` /
-        #: ``expert_rows_mean`` (the ``decode.tick`` record), and the
-        #: totals over every step and prefill (``server.stats()``)
-        self.tick_experts = {}
+        #: ``experts_touched`` / ``expert_rows_max`` summed over every
+        #: step and prefill (``server.stats()``)
         self.expert_totals = {"programs": 0, "rows": 0,
                               "experts_touched": 0, "expert_rows_max": 0}
+
+        def _carried(ids, prev):
+            # a slot's input is the token the step before produced for
+            # it, read where that step left it on the device (``prev``,
+            # that step's whole output: its tokens first); the host's id
+            # where it wrote the slot's mirror since, -1 elsewhere
+            return jnp.where(ids >= 0, ids, prev[:ids.shape[0]])
+
         if kv_mode == "paged":
 
             def _behind(first, out):
@@ -455,9 +503,9 @@ class LlamaServingEngine:
                 return _behind(jnp.argmax(logits, axis=-1).astype(jnp.int32),
                                out)
 
-            def _step_fn(wq, pools, tables, ids, pos):
+            def _step_fn(wq, pools, tables, ids, prev, pos):
                 out = dec._step_blocks_impl(
-                    deq(wq), pools, tables, ids, pos,
+                    deq(wq), pools, tables, _carried(ids, prev), pos,
                     paged_kernel=paged_kernel)
                 logits, pools = out[:2]
                 tok = _tokens(logits, out)
@@ -557,8 +605,9 @@ class LlamaServingEngine:
 
         else:
 
-            def _step_fn(wq, caches, ids, pos):
-                logits, caches = dec._step_impl(deq(wq), caches, ids, pos)
+            def _step_fn(wq, caches, ids, prev, pos):
+                logits, caches = dec._step_impl(
+                    deq(wq), caches, _carried(ids, prev), pos)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if numerics_on:
                     return tok, caches, _numerics.stats_of(logits)
@@ -575,6 +624,15 @@ class LlamaServingEngine:
                         for (kc, vc), (nk, nv) in zip(caches, rows)]
 
         self._step = jax.jit(_step_fn, donate_argnums=(1,))
+        #: the last token-at-a-time step's output, on the device: the
+        #: next one's ``prev`` (zeros before the first, which takes every
+        #: id from the host; committed to the weights' device as a step's
+        #: output is, so the second step is the first's compiled program)
+        self._toks = None
+        if block is None:
+            zeros = np.zeros(self.num_slots + self._n_counts, np.int32)
+            self._toks = self._dev(zeros) if mesh is not None else \
+                jax.device_put(zeros, next(iter(w["emb"].devices())))
         self._prefill = jax.jit(_prefill_fn)
         self._scatter = jax.jit(_scatter_fn, donate_argnums=(0,))
         if kv_mode == "paged":
@@ -718,15 +776,15 @@ class LlamaServingEngine:
         return {"kv_visible": int(visible.sum()),
                 "kv_selected": int(read.sum())}
 
-    def selection_of(self, slot):
-        """What each layer of the LAST step read for ``slot``: (layers,
-        k) positions, -1 where the slot saw fewer; None for a model
-        that selects nothing.  One small fetch, for a request's end."""
+    def selection_of(self, slot, step):
+        """What each layer of ``step`` (its :class:`StepHandle`) read for
+        ``slot``: (layers, k) positions, -1 where the slot saw fewer;
+        None for a model that selects nothing.  One small fetch, for a
+        request's end."""
+        if step.selected is None:
+            return None
         with self.dev_lock:
-            picked = self._tick_selected
-            if picked is None:
-                return None
-            return np.asarray(picked[:, slot])
+            return np.asarray(step.selected[:, slot])
 
     def expert_product_at(self, rows):
         """``"grouped_kernel"``, ``"every_expert"`` or None: the form
@@ -834,6 +892,7 @@ class LlamaServingEngine:
             for i, s in enumerate(slots):
                 if s < self.num_slots:
                     self._last[s] = first[i]
+                    self._fresh[s] = True
                     self._pos[s] = t0s[i]
         return first
 
@@ -899,6 +958,7 @@ class LlamaServingEngine:
                     self._tables[s] = row
                     if self.block is None:
                         self._last[s] = first[i]
+                        self._fresh[s] = True
                         self._pos[s] = t0s[i]
                     else:
                         # the cursor stands at the prompt's last whole
@@ -945,29 +1005,61 @@ class LlamaServingEngine:
     # -- transitions (both modes) ---------------------------------------------
     def _step_queued(self, seq):
         """Step ``seq`` is on the device's queue: say so to the prefill
-        lane (until its tokens are fetched) and return the prefill
-        batches queued before it and not yet fetched, which it runs
-        behind."""
+        lane (until its tokens are fetched) -> (whether the step before
+        it is still unfetched, the prefill batches queued before it and
+        not yet fetched, which it runs behind)."""
+        ahead = self.step_in_flight is not None
         self.step_in_flight = seq
-        return self.prefill_in_flight
+        return ahead, self.prefill_in_flight
+
+    def _step_fetched(self, seq):
+        if self.step_in_flight == seq:    # no newer step is queued
+            self.step_in_flight = None
+
+    def drop_steps(self):
+        """The decode lane gives up the steps it has queued and not
+        fetched (a turn of it raised, and it releases every slot they
+        advanced): none is in flight for the prefill lane to see."""
+        self.step_in_flight = None
 
     def step(self, active):
         """One decode step over ALL slots; returns the (num_slots,)
         next-token vector on host and advances the ``active`` slots'
         mirrors (a block decoder: one pass over every slot's block,
-        and the :class:`BlockTick` of :meth:`_book_block`).  Vacant slots run at pos 0 with token 0 — their output
-        is never read, and their K/V write lands in their own slot row
-        (slots mode) or is dropped at the sentinel block (paged).  The
-        device lock covers dispatch and mirror updates, NOT the host
-        materialization wait — handoff scatters interleave with the
-        wait."""
+        and the :class:`BlockTick` of :meth:`_book_block`): its two
+        halves back to back.  The device lock covers dispatch and mirror
+        updates, NOT the host materialization wait — handoff scatters
+        interleave with the wait."""
+        return self.fetch_step(self.dispatch_step(active))
+
+    def dispatch_step(self, active):
+        """The half of :meth:`step` that does not wait: upload the host's
+        mirrors, queue the step program and move the ``active`` slots'
+        cursors on -> the :class:`StepHandle` that :meth:`fetch_step`
+        takes.  Every other slot runs as a vacant one, its table row the
+        sentinel (paged: its K/V write drops, a state layer leaves its
+        state as it is, the experts it is routed to do not count it;
+        slots mode: the write lands in its own row) and its output read
+        by nobody: so a slot committed and not yet adopted, or finished
+        by a step whose tokens are not yet booked, is never stepped.
+
+        A slot's input token is the one the step before produced for
+        it, read on the device; the host's ``_last`` where it wrote it
+        since that slot was last stepped (``_fresh``).  So the next step
+        can be queued before this one's tokens have reached the host.
+        The device runs programs in the order they were queued, and both
+        lanes queue what touches the pool under ``dev_lock`` on the
+        array the last such program returned: a commit's scatter into
+        blocks that a finished slot gave back runs behind every step
+        that was queued while the slot still held them."""
         self._note(("step",))
-        lstats = None
-        span_dispatch, span_fetch = self.span_names
+        lstats = selected = pos = None
+        kv_tokens, selection = 0, {}
+        act = np.asarray(active, np.intp)
         t_lock = time.perf_counter()
         with self.dev_lock:
             t_disp0 = time.perf_counter()
-            with TraceAnnotation(span_dispatch, seq=self.steps + 1,
+            with TraceAnnotation(self.span_names[0], seq=self.steps + 1,
                                  replica=self.replica_id):
                 # (tokens, storage[, logit stats under numerics])
                 if self.block is not None:
@@ -977,69 +1069,78 @@ class LlamaServingEngine:
                         self._dev(self._blk_masked, bool),
                         self._dev(self._blk_step))
                     self._pool = out[1]
-                elif self.kv_mode == "paged":
-                    tables = self._tables
-                    if self.linear_attention:
-                        # a step moves a recurrent state on by a token,
-                        # so it is not idempotent: a slot committed and
-                        # not yet adopted runs as a vacant one, and
-                        # keeps its state until a tick owns it
-                        mine = np.zeros(self.num_slots, bool)
-                        mine[list(active)] = True
-                        tables = np.where(mine[:, None], tables,
-                                          np.int32(self.num_blocks))
-                    out = self._step(
-                        self._w, self._pool, self._dev(tables),
-                        self._dev(self._last), self._dev(self._pos))
-                    self._pool = out[1]
-                    if self.cache_spec.select_topk:
-                        self._tick_selected = out[-1]
                 else:
-                    out = self._step(
-                        self._w, self._caches, self._dev(self._last),
-                        self._dev(self._pos))
-                    self._caches = out[1]
-                toks = out[0]
+                    ids = self._dev(np.where(self._fresh, self._last,
+                                             np.int32(-1)))
+                    # a copy goes up: the mirror moves on below, and an
+                    # upload may read the host's array after this returns
+                    at = self._dev(self._pos.copy())
+                    if self.kv_mode == "paged":
+                        mine = np.zeros(self.num_slots, bool)
+                        mine[act] = True
+                        tables = np.where(mine[:, None], self._tables,
+                                          np.int32(self.num_blocks))
+                        out = self._step(
+                            self._w, self._pool, self._dev(tables), ids,
+                            self._toks, at)
+                        self._pool = out[1]
+                        if self.cache_spec.select_topk:
+                            selected = out[-1]
+                    else:
+                        out = self._step(self._w, self._caches, ids,
+                                         self._toks, at)
+                        self._caches = out[1]
+                    self._toks = out[0]
+                    self._fresh[act] = False
+                    self._pos[act] += 1
+                    # the step attends pos + 1 rows: the cursors as they
+                    # are now
+                    pos = self._pos.copy()
+                    kv_tokens = int(pos[act].sum())
+                    selection = self.selection_counts(pos[act])
                 if self._numerics:
                     lstats = out[2]
             self.steps += 1
             seq = self.steps
-        behind = self._step_queued(seq)
+        ahead, behind = self._step_queued(seq)
         t_disp1 = time.perf_counter()
+        if lstats is not None:
+            # queue the decode-step logit stats (device scalars) for
+            # the stride harvest, outside the device lock
+            _numerics.record_compiled(("serving.logits",), (lstats,))
+        return StepHandle(seq, act, out[0], t_lock, t_disp0, t_disp1,
+                          behind=behind, ahead=ahead, selected=selected,
+                          pos=pos, kv_tokens=kv_tokens, selection=selection)
+
+    def fetch_step(self, step):
+        """The half of :meth:`step` that waits: ``step``'s tokens to the
+        host, its ``t_tok`` and ``experts`` filled in, the ``_last``
+        mirror of its slots booked -> the (num_slots,) vector (a block
+        decoder: the :class:`BlockTick`)."""
         try:
-            if lstats is not None:
-                # queue the decode-step logit stats (device scalars) for
-                # the stride harvest, outside the device lock
-                _numerics.record_compiled(("serving.logits",), (lstats,))
-            with TraceAnnotation(span_fetch, seq=seq,
+            with TraceAnnotation(self.span_names[1], seq=step.seq,
                                  replica=self.replica_id):
-                out = _materialize([toks])[0]
+                out = _materialize([step.toks])[0]
         finally:
-            self.step_in_flight = None
-        self.tick_behind = behind
-        self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
-        out, self.tick_experts = self.split_fetch(out, self.num_slots)
+            self._step_fetched(step.seq)
+        step.t_tok = time.perf_counter()
+        out, step.experts = self.split_fetch(out, self.num_slots)
+        self.booked = step
         if self.block is not None:
-            return self._book_block(out, active)
+            return self._book_block(out, step)
         with self.dev_lock:
-            for s in active:
-                self._last[s] = out[s]
-                self._pos[s] += 1
-            # the step attended pos + 1 rows: the cursors as they are now
-            self.tick_kv_tokens = int(self._pos[list(active)].sum())
-            self.tick_selection = self.selection_counts(
-                self._pos[list(active)])
+            self._last[step.active] = out[step.active]
         return out
 
-    def _book_block(self, out, active):
+    def _book_block(self, out, step):
         """A block pass's fetched ``(S, 2B)`` (ids, then what was
-        committed) into the ``active`` slots' mirrors -> the
+        committed) into the mirrors of ``step``'s slots -> the
         :class:`BlockTick`.  A slot whose block held no mask has had the
         pass that leaves the block's K/V: its cursor moves a block on
         and a block of masks opens; any other takes the pass's commits
         and goes to its next denoising pass."""
         bl = self.block.block_len
-        act = np.asarray(active, np.intp)
+        act = step.active
         with self.dev_lock:
             mine = np.zeros(self.num_slots, bool)
             mine[act] = True
@@ -1056,7 +1157,7 @@ class LlamaServingEngine:
             self._blk_masked[going] &= ~tick.commit[going]
             self._blk_step[going] += 1
             # every column of a block attends to the block's end
-            self.tick_kv_tokens = int(tick.pos0[act].sum()) + bl * len(act)
+            step.kv_tokens = int(tick.pos0[act].sum()) + bl * len(act)
             tot = self.block_totals
             tot["block_passes"] += int(tick.step[done].sum()) + len(done)
             tot["blocks_committed"] += len(done)
@@ -1098,8 +1199,9 @@ class LlamaServingEngine:
                     lstats = res[2]
             self.steps += 1
             seq = self.steps
-        behind = self._step_queued(seq)
-        t_disp1 = time.perf_counter()
+        ahead, behind = self._step_queued(seq)
+        step = StepHandle(seq, None, out, t_lock, t_disp0,
+                          time.perf_counter(), behind=behind, ahead=ahead)
         try:
             if lstats is not None:
                 _numerics.record_compiled(("serving.logits",), (lstats,))
@@ -1107,9 +1209,9 @@ class LlamaServingEngine:
                                  replica=self.replica_id):
                 out = _materialize([out])[0]
         finally:
-            self.step_in_flight = None
-        self.tick_behind = behind
-        self.tick_stamps = (t_lock, t_disp0, t_disp1, time.perf_counter())
+            self._step_fetched(seq)
+        step.t_tok = time.perf_counter()
+        self.booked = step
         return out
 
     def last_tokens(self):
@@ -1127,11 +1229,13 @@ class LlamaServingEngine:
         aligning a draft engine's cursor with the target's)."""
         with self.dev_lock:
             self._last[slot] = int(last)
+            self._fresh[slot] = True
             self._pos[slot] = int(pos)
 
     def clear_slot(self, slot):
         with self.dev_lock:
             self._last[slot] = 0
+            self._fresh[slot] = True
             self._pos[slot] = 0
             if self._tables is not None:
                 self._tables[slot] = self.num_blocks

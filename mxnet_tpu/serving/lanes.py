@@ -20,7 +20,14 @@ connected by an explicit KV handoff:
   pending handoffs, then advances *its own* slot set one token.  It
   never sees a prompt forward: while a long prompt prefills, decode
   ticks keep dispatching (the device lock covers only the KV-mutating
-  dispatches, not the prefill compute).
+  dispatches, not the prefill compute).  The token-at-a-time tick
+  (:meth:`DecodeLane._tick`) runs ONE STEP AHEAD of its bookkeeping: a
+  turn adopts, queues step K+1 and only then fetches and books step K
+  (the engine's ``dispatch_step`` / ``fetch_step``: tokens pass from
+  step to step on the device), so the device has a step queued while
+  the host books; where the prefill lane has a forward behind step K,
+  or is about to (``_prefill_covers``), that forward is what the device
+  runs meanwhile and step K+1 is queued behind it.
   For a model that decodes by blocks (the engine's ``block``: what its
   decoder's cache spec says) the tick is :meth:`DecodeLane._tick_block`:
   one pass a slot's block, 0 to a block's length of tokens committed a
@@ -42,7 +49,7 @@ their JSONL records, lanes emit ``serving.prefill`` spans and
 ``serving.kv_blocks_in_use`` gauge (see docs/observability.md).
 
 Lane log (``telemetry.tracing``, always on): a ``decode.tick`` record a
-turn of the decode lane, a ``prefill.batch`` record a batch, and a
+step of the decode lane, a ``prefill.batch`` record a batch, and a
 ``slot.turn`` record an adopted hand-off: the admission from the slot's
 release (:meth:`Replica.release`) over the batch that filled it to the
 tick that took it up, on one clock.  Each lane's record says what of the
@@ -66,6 +73,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from typing import NamedTuple
 
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -137,6 +145,17 @@ class _Handoff:
         # admission found it (None: the slot held nothing before)
         self.freed = freed
         self.t_handoff = None   # queued for the decode lane (hand_off)
+
+
+class _Flight(NamedTuple):
+    """A step the decode lane has queued and not yet booked: the
+    engine's handle and what the turn that dispatched it knew."""
+
+    step: object        #: the engine's ``StepHandle``
+    t_loop: float       #: the top of the turn that queued it
+    ids: tuple          #: the request of each of the step's slots
+    ending: frozenset   #: the slots whose last token the step produces
+    adopted: tuple      #: the hand-offs whose first step it is
 
 
 class PrefillLane:
@@ -520,6 +539,14 @@ class DecodeLane:
         # _adopt for the tick's lane-log record and its turns'
         self._t_loop = None
         self._adopted = ()
+        # the token-at-a-time tick runs a step ahead of its bookkeeping:
+        # the step it has queued and not yet booked, and when the last
+        # booked step's tokens came (a step has the device from then)
+        self._flight = None
+        self._t_tok = 0.0
+        # the hand-offs it has adopted that no step has carried yet (a
+        # turn may queue nothing): the first step's ``slot.turn`` records
+        self._unstepped = ()
         # the engine's attention path goes into its first tick record
         self._said_attention = False
 
@@ -592,10 +619,18 @@ class DecodeLane:
             tick = self._tick_block
         while True:
             if self.pending():
-                # one turn: adopt, then advance every slot one tick
-                with TraceAnnotation("mxt.decode.tick",
-                                     seq=self.r.engine.steps + 1,
-                                     replica=self.r.index):
+                # one turn: adopt, then advance every slot one tick (a
+                # slot stays in _seqs until its last step is booked, so
+                # a step queued and not yet booked keeps the lane turning)
+                # ``seq``: the step the turn queues; ``books``: the one
+                # whose tokens it fetches and books (the same step, but
+                # for the tick that runs ahead: the step in flight, 0
+                # where none is)
+                seq = books = self.r.engine.steps + 1
+                if tick == self._tick:
+                    books = self._flight.step.seq if self._flight else 0
+                with TraceAnnotation("mxt.decode.tick", seq=seq,
+                                     replica=self.r.index, books=books):
                     self._adopt()
                     tick()
             elif self._stop.is_set():
@@ -649,8 +684,8 @@ class DecodeLane:
                     h.req.commits = []
 
     def _abort(self, active, exc):
-        """The engine call of a tick raised: fail every active request
-        and free its slot."""
+        """An engine call of a turn raised: fail the request of every
+        slot the lane holds (``active``) and free the slot."""
         r = self.r
         for slot in active:
             with self._hand_lock:
@@ -682,50 +717,119 @@ class DecodeLane:
             capacity.note_kv(r.index, r.mgr.allocator.free_blocks,
                              r.mgr.num_blocks)
 
-    def _tick(self):
+    def _prefill_covers(self, step):
+        """Whether the device will have a prefill's forward to run when
+        ``step``, the one in flight, ends: one is queued behind it, or
+        the prefill lane is about to queue one (a request waits and a
+        slot is free: the lane dispatches within a millisecond or two).
+        The host's turn then hides behind that forward as it would
+        behind a step queued ahead, and a step queued now would only
+        stand between this admission's forward and the next one's: the
+        prefill lane fetches each forward's first token before it queues
+        the next, so with two steps always on the queue it admits one
+        request every two steps, and a cell that admits more stands with
+        slots empty (``lfm2_24b.chat_decode_sat``, 0.8 a step: 94 -> 60%
+        of the slots held, ``PERF.md`` section 6, PR 39).  A guess either
+        way costs time, never a token: wrongly true, the device rests
+        one turn of the host's, as it did every tick before the lane ran
+        ahead; wrongly false, that forward runs a step later."""
         r = self.r
+        if any(b not in step.behind for b in r.engine.prefill_in_flight):
+            return True
+        return len(r.queue) > 0 and r.mgr.free_slots() > 0
+
+    def _tick(self):
+        """A turn of the token-at-a-time lane, one step ahead of its
+        bookkeeping: queue step K+1, then fetch and book step K, so a
+        step is on the device's queue while the host works.  Step K+1
+        needs nothing of step K's that the host does not know already:
+        its tokens pass from step to step on the device
+        (``engine.dispatch_step``), blocks were reserved at admission, a
+        cursor moves by one and a request ends by count.  So the
+        manager's count is taken as a step is QUEUED: a slot whose last
+        token step K produces is left out of step K+1 (it runs vacant
+        there) and its request is finished, and the slot released, when
+        step K's tokens are booked.  A hand-off adopted in a turn rides
+        the step that turn queues, or the next one queued.  A lane's first turn, and the first
+        after it stood empty, books nothing; the turn that finds nothing
+        more to step queues nothing, and so does a turn that finds the
+        prefill lane about to use the device when step K ends
+        (:meth:`_prefill_covers`): the turn after it queues step K+1
+        behind that forward."""
+        r = self.r
+        eng = r.engine
+        prev, self._flight = self._flight, None
         with self._hand_lock:
-            active = sorted(self._seqs)
+            active = [s for s in sorted(self._seqs)
+                      if prev is None or s not in prev.ending]
             ids = tuple(self._seqs[s][0].id for s in active)
+        if prev is not None and self._prefill_covers(prev.step):
+            active = ()
+        adopted, self._unstepped = self._unstepped + self._adopted, ()
+        # the turn's own dispatch, for its record: an instant where it
+        # queues nothing
+        t_lock = t_disp0 = t_disp1 = time.perf_counter()
         try:
-            toks = r.engine.step(active)
+            if active:
+                step = eng.dispatch_step(active)
+                ending = set()
+                for slot in active:
+                    r.mgr.advance(slot)   # the step writes K/V at its pos
+                    if r.mgr.consume(slot):
+                        ending.add(slot)
+                self._flight = _Flight(step, self._t_loop, ids,
+                                       frozenset(ending), adopted)
+                # the count is the host's work, not a wait for tokens: the
+                # turn's ``t_disp1`` is where the lane turns to the fetch
+                t_lock, t_disp0 = step.t_lock, step.t_disp0
+                t_disp1 = time.perf_counter()
+            else:
+                self._unstepped = adopted
+            if prev is None:
+                return
+            toks = eng.fetch_step(prev.step)
         except Exception as exc:
-            self._abort(active, exc)
+            # both steps' requests are in _seqs, each once
+            self._flight, self._unstepped = None, ()
+            eng.drop_steps()
+            with self._hand_lock:
+                held = sorted(self._seqs)
+            self._abort(held, exc)
             return
-        stamps = self._engine_stamps()
-        _t_lock, t_disp0, _t_disp1, t_tok = stamps
-        self._note_tick(active, t_disp0, t_tok)
-        step_idx = r.engine.steps
+        step = prev.step
+        # the device was the step's from its dispatch or, run ahead, from
+        # the tokens of the step before it
+        t_busy0, self._t_tok = max(step.t_disp0, self._t_tok), step.t_tok
+        self._note_tick(step.active, t_busy0, step.t_tok)
         n_finished = 0
-        with TraceAnnotation("mxt.decode.book", seq=step_idx,
+        with TraceAnnotation("mxt.decode.book", seq=step.seq,
                              replica=r.index):
-            for slot in active:
-                r.mgr.advance(slot)   # the step wrote K/V at slot's pos
+            for slot in map(int, step.active):
                 with self._hand_lock:
                     req, tokens = self._seqs[slot]
                 tokens.append(int(toks[slot]))
                 if req.first_tick is None:
-                    req.first_tick = step_idx
+                    req.first_tick = step.seq
                 if req.trace is not None:
                     # one span per traced slot per tick: the per-request
                     # decode slice (cost: one dict append — the tracing
                     # A/B lane in benchmark/serving_latency.py bounds it)
-                    req.trace.add("decode.step", t_disp0, t_tok,
-                                  step=step_idx, batch=len(active),
+                    req.trace.add("decode.step", t_busy0, step.t_tok,
+                                  step=step.seq, batch=len(step.active),
                                   replica=r.index, slot=slot)
-                if r.mgr.consume(slot):
+                if slot in prev.ending:
                     with self._hand_lock:
                         del self._seqs[slot]
-                    if getattr(r.engine, "tick_selection", None):
+                    if step.selected is not None:
                         # the step's query stood one before the cursor
-                        req.selected = (int(r.engine.positions()[slot]) - 1,
-                                        r.engine.selection_of(slot))
-                    r.finish(req, tokens)
+                        req.selected = (int(step.pos[slot]) - 1,
+                                        eng.selection_of(slot, step))
+                    r.finish(req, tokens, step=step.seq)
                     n_finished += 1
-        self._record_tick(step_idx, ids, n_finished, stamps,
-                          getattr(r.engine, "tick_kv_tokens", 0),
-                          **getattr(r.engine, "tick_experts", {}),
-                          **getattr(r.engine, "tick_selection", {}))
+        self._record_tick(step, prev.ids, n_finished, prev.adopted,
+                          queued_at=prev.t_loop,
+                          turn=(self._t_loop, t_lock, t_disp0, t_disp1),
+                          **step.experts, **step.selection)
 
     def _tick_block(self):
         """A block decoder's tick: one pass over every active slot's
@@ -749,10 +853,10 @@ class DecodeLane:
         except Exception as exc:
             self._abort(active, exc)
             return
-        stamps = self._engine_stamps()
-        _t_lock, t_disp0, _t_disp1, t_tok = stamps
+        step = eng.booked
+        t_disp0, t_tok = step.t_disp0, step.t_tok
         self._note_tick(active, t_disp0, t_tok)
-        step_idx = eng.steps
+        step_idx = step.seq
         n_finished = 0
         with TraceAnnotation("mxt.decode.book", seq=step_idx,
                              replica=r.index):
@@ -789,73 +893,82 @@ class DecodeLane:
                     r.finish(req, [tokens[i]
                                    for i in range(req.max_new_tokens)])
                     n_finished += 1
-        self._record_tick(step_idx, ids, n_finished, stamps,
-                          eng.tick_kv_tokens, block_len=bl,
-                          rows=len(active) * bl,
+        self._record_tick(step, ids, n_finished, self._adopted,
+                          block_len=bl, rows=len(active) * bl,
                           n_store=int(tick.stored.sum()),
                           committed=int(tick.commit.sum()),
                           block_passes=int((tick.step[tick.stored] + 1).sum()),
-                          **eng.tick_experts)
+                          **step.experts)
 
-    def _record_tick(self, seq, ids, n_finished, stamps, kv_tokens,
-                     **extra):
-        """The turn's ``decode.tick`` record, its bookkeeping done, and
-        a ``slot.turn`` record for each hand-off the turn adopted.
-        ``kv_tokens``: K/V rows the step attended, summed over the
-        active slots.  The lane's first record also says which
-        attention the engine's step program was built with, how many KV
-        heads a stored pool row holds (``kv_pack``), which product its
-        routed experts run (``expert_product``), how many layers
-        keep K/V and how many a per-slot state, and which form its
-        linear-attention layers take (``linear_attention``).  A model
-        with per-slot state says in every record how many bytes of it
-        the turn read and wrote (``state_bytes``)."""
-        t_lock, t_disp0, t_disp1, t_tok = stamps
-        per_slot = getattr(self.r.engine, "state_bytes_per_step", 0)
+    def _record_tick(self, step, ids, n_finished, adopted, queued_at=None,
+                     turn=None, **extra):
+        """The ``decode.tick`` record of ``step`` (the engine's
+        ``StepHandle``), its bookkeeping done, and a ``slot.turn`` record
+        for each hand-off that it was the first step of (``adopted``).
+        ``seq``, ``request_ids``, ``n_active``, ``n_adopted``,
+        ``behind``, ``ahead``, ``kv_tokens`` (K/V rows the step attended,
+        summed over its slots) and ``t_tok`` are the step's.  ``t_loop,
+        t_lock, t_disp0, t_disp1`` are the stamps of the turn that
+        fetched and booked it, in the order the lane thread passed them:
+        a tick that runs ahead passes them as ``turn`` (its dispatch was
+        of the NEXT step) and the top of the turn that queued the step
+        as ``queued_at``; for a serial tick they are the step's own,
+        which every record also carries as ``t_step_loop``,
+        ``t_step_lock``, ``t_step_disp0``, ``t_step_disp1``.  The lane's
+        first record also says which attention the engine's step program
+        was built with, how many KV heads a stored pool row holds
+        (``kv_pack``), which product its routed experts run
+        (``expert_product``), how many layers keep K/V and how many a
+        per-slot state, and which form its linear-attention layers take
+        (``linear_attention``).  A model with per-slot state says in
+        every record how many bytes of it the step read and wrote
+        (``state_bytes``)."""
+        r = self.r
+        t_lock, t_disp0, t_disp1, t_tok = (
+            step.t_lock, step.t_disp0, step.t_disp1, step.t_tok)
+        t_loop = self._t_loop if queued_at is None else queued_at
+        extra.update(t_step_loop=t_loop, t_step_lock=t_lock,
+                     t_step_disp0=t_disp0, t_step_disp1=t_disp1)
+        if turn is not None:
+            t_loop, t_lock, t_disp0, t_disp1 = turn
+        extra.setdefault("kv_tokens", int(step.kv_tokens))
+        per_slot = getattr(r.engine, "state_bytes_per_step", 0)
         if per_slot:
             # what the active slots' state layers read and wrote
             extra["state_bytes"] = per_slot * len(ids)
         if not self._said_attention:
             self._said_attention = True
             extra["decode_attention"] = getattr(
-                self.r.engine, "decode_attention", None)
-            extra["kv_pack"] = getattr(self.r.engine, "kv_pack", None)
+                r.engine, "decode_attention", None)
+            extra["kv_pack"] = getattr(r.engine, "kv_pack", None)
             extra["expert_product"] = getattr(
-                self.r.engine, "expert_product", None)
-            extra["decoding"] = getattr(self.r.engine, "decoding", None)
-            block = getattr(self.r.engine, "block", None)
+                r.engine, "expert_product", None)
+            extra["decoding"] = getattr(r.engine, "decoding", None)
+            block = getattr(r.engine, "block", None)
             if block is not None:
                 extra["block_decoding"] = block._asdict()
-            extra.update(_cache_layers(self.r.engine))
+            extra.update(_cache_layers(r.engine))
+        r.steps_ahead += step.ahead
+        telemetry.count("serving.decode.steps")
+        if step.ahead:
+            telemetry.count("serving.decode.steps_ahead")
         tracing.lane_record(
-            "decode.tick", replica=self.r.index, seq=seq,
-            n_active=len(ids), n_adopted=len(self._adopted),
-            n_finished=n_finished, kv_tokens=int(kv_tokens),
-            request_ids=ids,
-            behind=self.r.engine.tick_behind,
-            t_loop=self._t_loop,
-            t_lock=t_lock, t_disp0=t_disp0, t_disp1=t_disp1, t_tok=t_tok,
-            t_book=time.perf_counter(), **extra)
-        for h in self._adopted:
+            "decode.tick", replica=r.index, seq=step.seq,
+            n_active=len(ids), n_adopted=len(adopted),
+            n_finished=n_finished, request_ids=ids,
+            behind=step.behind, ahead=step.ahead,
+            t_loop=t_loop, t_lock=t_lock, t_disp0=t_disp0, t_disp1=t_disp1,
+            t_tok=t_tok, t_book=time.perf_counter(), **extra)
+        for h in adopted:
             # every stamp is one a boundary already took: the release's
-            # ``t_done``, the batch's, the hand-off's, this tick's
+            # ``t_done``, the batch's, the hand-off's, this step's
             t_free, prev_id, freed_by = h.freed or (None, None, None)
             tracing.lane_record(
-                "slot.turn", replica=self.r.index, slot=h.slot,
-                request_id=h.req.id, batch=h.batch, tick=seq,
+                "slot.turn", replica=r.index, slot=h.slot,
+                request_id=h.req.id, batch=h.batch, tick=step.seq,
                 freed_by=freed_by, prev_request_id=prev_id, t_free=t_free,
                 t_start=h.req.t_start, t_first=h.req.t_commit,
                 t_handoff=h.t_handoff, t_adopt=h.req.t_handoff, t_tok=t_tok)
-
-    def _engine_stamps(self):
-        """``(t_lock, t_disp0, t_disp1, t_tok)`` of the engine call
-        that just returned (``LlamaServingEngine.tick_stamps``); an
-        engine that keeps none gets the turn's own edges."""
-        stamps = getattr(self.r.engine, "tick_stamps", None)
-        if stamps is None:
-            now = time.perf_counter()
-            stamps = (self._t_loop, self._t_loop, now, now)
-        return stamps
 
     def _tick_spec(self):
         """Speculative tick: k sequential DRAFT steps propose a window,
@@ -889,13 +1002,13 @@ class DecodeLane:
             self._abort(active, exc)
             return
         # the verify's stamps; the k draft steps lie in [t0, t_lock]
-        stamps = self._engine_stamps()
-        t_lock, t_tok = stamps[0], stamps[3]
+        step = r.engine.booked
+        t_lock, t_tok = step.t_lock, step.t_tok
         self._note_tick(active, t0, t_tok)
         accepted_this_tick = 0
         accepted = {}       # request id -> tokens this tick committed
         n_finished = 0
-        step_idx = r.engine.steps
+        step_idx = step.seq
         with TraceAnnotation("mxt.decode.book", seq=step_idx,
                              replica=r.index):
             for slot in active:
@@ -946,8 +1059,8 @@ class DecodeLane:
                     n_finished += 1
         # the verify's last column attended pos0 + k + 1 rows
         kv_tokens = sum(int(pos0[slot]) + k + 1 for slot in active)
-        self._record_tick(step_idx, ids, n_finished, stamps, kv_tokens,
-                          accepted=accepted)
+        self._record_tick(step, ids, n_finished, self._adopted,
+                          kv_tokens=kv_tokens, accepted=accepted)
         telemetry.count("serving.draft_tokens", k * len(active))
         capacity.note_spec(r.index, k * len(active), accepted_this_tick)
         if r.draft_tokens:
@@ -1042,7 +1155,10 @@ class Replica:
         self.released = {}
         self.completed = 0
         self.failed = 0
+        # decode steps booked, and those of them that were queued before
+        # the step ahead of them had been fetched
         self.batches = 0
+        self.steps_ahead = 0
 
     # -- dispatcher-facing ----------------------------------------------------
     def load(self):
@@ -1079,23 +1195,29 @@ class Replica:
                 ServerClosedError("server stopped before execution"))
 
     # -- completion -----------------------------------------------------------
-    def release(self, req):
+    def release(self, req, step=None):
         """Free ``req``'s slot, its blocks and its mirrors.  The stamp of
         the release is the request's ``t_done``, taken and kept under
         the slot BEFORE the manager can hand the slot on, so the next
         admission into it finds its own predecessor (the ``slot.turn``
-        record's ``t_free``, ``prev_request_id``, ``freed_by``)."""
+        record's ``t_free``, ``prev_request_id``, ``freed_by``).
+        ``step``: the ``seq`` of the step whose booking frees it, where
+        that is not the engine's newest."""
         req.t_done = time.perf_counter()
-        self.released[req.slot] = (req.t_done, req.id, self.engine.steps)
+        if step is None:
+            step = self.engine.steps
+        self.released[req.slot] = (req.t_done, req.id, step)
         self.mgr.evict(req.slot)
         self.engine.clear_slot(req.slot)
         if self.draft is not None:
             self.draft.clear_slot(req.slot)
 
-    def finish(self, req, tokens):
-        self.release(req)
+    def finish(self, req, tokens, step=None):
+        if step is None:
+            step = self.engine.steps
+        self.release(req, step)
         self.capacity_evt.set()
-        req.done_step = self.engine.steps
+        req.done_step = step
         n = req.max_new_tokens
         req.future.set_result(np.concatenate(
             [np.asarray(req.prompt_ids, np.int32),
@@ -1155,6 +1277,8 @@ class Replica:
             "completed": self.completed,
             "failed": self.failed,
             "batches": self.batches,
+            "steps_ahead_share": round(self.steps_ahead / self.batches, 4)
+            if self.batches else None,
             "queue_wait_ms": telemetry.hist_summary("serving.queue_wait_ms"),
             "total_ms": telemetry.hist_summary("serving.total_ms"),
             "ttft_ms": telemetry.hist_summary("serving.ttft_ms"),

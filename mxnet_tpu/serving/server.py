@@ -628,6 +628,9 @@ class GenerativeServer(_ServerBase):
             "completed": sum(r.completed for r in reps),
             "failed": sum(r.failed for r in reps),
             "decode_steps": sum(r.engine.steps for r in reps),
+            # those of the booked steps that were queued before the step
+            # ahead of them had been fetched (the lane log's ``ahead``)
+            "decode_steps_ahead": sum(r.steps_ahead for r in reps),
             "rejected": self.queue.rejected,
             "pending": len(self.queue) + sum(len(r.queue) for r in reps),
             "kv_cache": reps[0].mgr.stats(),

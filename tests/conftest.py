@@ -70,3 +70,15 @@ def _seed():
 
     mx.random.seed(42)
     yield
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _own_lane_log():
+    """The lane log is one ring a process and outlives every server, and the
+    benchmark's readers take it from its start (``token_gaps`` gives up at a
+    speculative tick).  Every test module starts with an empty one, so what
+    a file reads does not depend on which files ran before it on its worker."""
+    from mxnet_tpu.telemetry import tracing
+
+    tracing._lane_log.clear()
+    yield

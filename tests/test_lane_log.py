@@ -230,7 +230,10 @@ def _check_turns(reqs, log):
         assert (turn["t_start"], turn["t_first"]) \
             == (batch["t_start"], batch["t_first"]) == (q.t_start, q.t_commit)
         assert turn["t_adopt"] == q.t_handoff
-        assert tick["t_loop"] <= turn["t_adopt"] <= tick["t_lock"]
+        # adopted by the turn that queued the step (``t_step_*``), or by one
+        # before it that queued none; that step's tokens came a turn later,
+        # in the turn of ``t_loop``
+        assert turn["t_adopt"] <= tick["t_step_lock"]
         assert turn["t_tok"] == tick["t_tok"]
         assert (turn["slot"], turn["replica"]) == (q.slot, 0)
         # the first tick the request was active in is the one that took it up
@@ -405,8 +408,8 @@ def test_each_lanes_record_names_what_it_was_dispatched_behind(monkeypatch):
     assert behind and all(t["behind"] == (held_batch,) for t in behind)
     assert behind[0]["seq"] == held_seq + 1
     # the ticks queued behind it: after its forward, before its first tokens
-    assert all(batches[1]["t_disp1"] <= t["t_disp1"] <= batches[1]["t_ready"]
-               for t in behind)
+    assert all(batches[1]["t_disp1"] <= t["t_step_disp1"]
+               <= batches[1]["t_ready"] for t in behind)
     assert all(t["behind"] == () for t in ticks if t["seq"] <= held_seq)
 
 
@@ -647,11 +650,18 @@ def test_mxt_spans_land_in_the_xplane_with_the_logs_seq(traced):
     seen = _by_name(lines)
     ticks, batches = _kind(log, "decode.tick"), _kind(log, "prefill.batch")
     assert ticks and batches
-    for name in ("mxt.decode.tick", "mxt.decode.dispatch", "mxt.decode.fetch",
-                 "mxt.decode.book"):
+    for name in ("mxt.decode.dispatch", "mxt.decode.fetch", "mxt.decode.book"):
         assert sorted(s["seq"] for s in seen[name]) \
             == [t["seq"] for t in ticks], name
         assert all(s["replica"] == 0 for s in seen[name])
+    # a turn's span names the step it queues and the one it books: the same
+    # step, but in the lane that runs a step ahead (a stretch's first turn
+    # books none, its last queues none)
+    turns = seen["mxt.decode.tick"]
+    assert sorted(s["books"] for s in turns if s["books"]) \
+        == [t["seq"] for t in ticks]
+    assert all(s["replica"] == 0 and s["books"] in (0, s["seq"] - 1, s["seq"])
+               for s in turns)
     for name in ("mxt.prefill.batch", "mxt.prefill.dispatch",
                  "mxt.prefill.fetch", "mxt.prefill.commit"):
         assert sorted(s["seq"] for s in seen[name]) \
@@ -740,6 +750,20 @@ def test_the_speculative_tick_books_under_its_span_and_the_draft_has_its_names(
     assert len(_kind(log, "slot.turn")) == sum(t["n_adopted"] for t in ticks)
 
 
+def test_the_speculative_tick_stays_serial():
+    """``_tick_spec`` calls ``step()`` / ``verify()`` whole: no record says
+    ``ahead``, and the step's own dispatch stamps are the turn's."""
+    net = _tiny()
+    _reqs, log, _stats, _eng = _serve(3, 6, lambda: net, draft_net=net,
+                                      spec_k=2)
+    ticks = _kind(log, "decode.tick")
+    assert ticks and all("accepted" in t for t in ticks)
+    assert not any(t["ahead"] for t in ticks)
+    for t in ticks:
+        assert [t["t_step_" + s[2:]] for s in TICK_STAMPS[:4]] \
+            == [t[s] for s in TICK_STAMPS[:4]]
+
+
 # --- names the benchmark reads -----------------------------------------------
 
 def test_compiled_program_names_match_the_benchmarks_regexes():
@@ -756,7 +780,8 @@ def test_compiled_program_names_match_the_benchmarks_regexes():
     flat = np.full(2, eng.num_blocks, np.int32)
     lowered = {
         "step": eng._step.lower(eng._w, eng._pool, eng._dev(eng._tables),
-                                eng._dev(eng._last), eng._dev(eng._pos)),
+                                eng._dev(eng._last), eng._toks,
+                                eng._dev(eng._pos)),
         "prefill": eng._prefill.lower(eng._w, eng._dev(ids), eng._dev(t0s)),
         "scatter": eng._scatter.lower(eng._pool, rows, eng._dev(flat)),
     }

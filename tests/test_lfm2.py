@@ -469,7 +469,8 @@ def test_compiled_program_names_are_the_benchmarks(tiny):
     flat = np.full(2, eng.num_blocks, np.int32)
     lowered = {
         "step": eng._step.lower(eng._w, eng._pool, eng._dev(eng._tables),
-                                eng._dev(eng._last), eng._dev(eng._pos)),
+                                eng._dev(eng._last), eng._toks,
+                                eng._dev(eng._pos)),
         "prefill": eng._prefill.lower(eng._w, eng._dev(ids), eng._dev(t0s)),
         "scatter": eng._scatter.lower(eng._pool, rows, eng._dev(flat),
                                       eng._dev(np.zeros(1, np.int32))),

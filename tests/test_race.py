@@ -14,6 +14,7 @@ the same wrapped locks on timing-dependent paths."""
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -121,14 +122,25 @@ class _StubMgr:
 
 
 class _StubEngine:
-    tick_behind = ()
+    """The two halves of ``LlamaServingEngine.step`` that the lane calls
+    apart: it queues step K+1 before it fetches step K."""
+
+    prefill_in_flight = ()
 
     def __init__(self):
         self.steps = 0
 
-    def step(self, active):
+    def dispatch_step(self, active):
+        from mxnet_tpu.serving.generative import StepHandle
+
         self.steps += 1
-        return {s: 100 * (s + 1) + self.steps for s in active}
+        now = time.perf_counter()
+        toks = {s: 100 * (s + 1) + self.steps for s in active}
+        return StepHandle(self.steps, list(active), toks, now, now, now)
+
+    def fetch_step(self, step):
+        step.t_tok = time.perf_counter()
+        return step.toks
 
     def clear_slot(self, slot):
         pass
@@ -146,15 +158,16 @@ class _StubReq:
 
 class _StubReplica:
     index = 0
+    queue = ()      # nothing waits for a slot
 
     def __init__(self, budgets):
         self.engine = _StubEngine()
         self.mgr = _StubMgr(budgets)
         self.capacity_evt = threading.Event()
-        self.batches = 0
+        self.batches = self.steps_ahead = 0
         self.finished = []
 
-    def finish(self, req, tokens):
+    def finish(self, req, tokens, step=None):
         self.finished.append((req.id, tuple(tokens)))
 
     def fail(self, req, exc, lane=None):

@@ -472,7 +472,7 @@ def _lowered(eng):
     if eng.block is None:
         out["step"] = eng._step.lower(
             eng._w, eng._pool, eng._dev(eng._tables), eng._dev(eng._last),
-            eng._dev(eng._pos))
+            eng._toks, eng._dev(eng._pos))
     else:
         out["step"] = eng._step.lower(
             eng._w, eng._pool, eng._dev(eng._tables),
@@ -507,11 +507,15 @@ def test_compiled_program_names_are_the_benchmarks(tiny):
 #: (PR 29, fe17b9d): the window's, the mask's and the kernel's new
 #: static flags leave the default path's programs letter for letter.
 #: A PR that changes those programs on purpose reads the new values off
-#: this test's failure.
+#: this test's failure.  PR 39 did, for the two ``step`` programs alone
+#: (they were 0d534b8b0dc2a49b and cb6ce8a7aabce20f): the step takes the
+#: output of the step before it as one more argument and reads a slot's
+#: token from it where the host wrote none (``_carried``), so that the
+#: next step can be queued before this one's tokens are fetched.
 PARENT_PROGRAMS = {
-    "llama": {"step": "0d534b8b0dc2a49b", "prefill": "b61a136db24e5190",
+    "llama": {"step": "27e214ef9335e8e9", "prefill": "b61a136db24e5190",
               "verify": "d321f3b3237cf784"},
-    "lfm2": {"step": "cb6ce8a7aabce20f", "prefill": "a5ad11b502cdbab8"},
+    "lfm2": {"step": "aa9bcd45d31ee381", "prefill": "a5ad11b502cdbab8"},
 }
 
 
@@ -732,9 +736,9 @@ def test_a_block_whose_store_pass_is_skipped_is_not_correct(harness, capsys,
 
     whole = LlamaServingEngine._book_block
 
-    def skipping(self, out, active):
-        tick = whole(self, out, active)
-        for s in active:
+    def skipping(self, out, step):
+        tick = whole(self, out, step)
+        for s in step.active:
             if not tick.stored[s] and not self._blk_masked[s].any():
                 self._pos[s] += BL
                 self._blk_ids[s] = self.block.mask_id

@@ -175,7 +175,8 @@ def test_compiled_step_holds_no_copy_of_a_state_array():
     assert (state.shape, str(state.dtype)) == ((SLOTS, HEADS, DK, DV), "float32")
     assert (ring.shape, str(ring.dtype)) == ((SLOTS, 3, 8192), "bfloat16")
     comp = eng._step.lower(eng._w, eng._pool, eng._dev(eng._tables),
-                           eng._dev(eng._last), eng._dev(eng._pos)).compile()
+                           eng._dev(eng._last), eng._toks,
+                           eng._dev(eng._pos)).compile()
     text = comp.as_text()
     assert text.count("gated_delta_step") >= 3
     shapes = (f"f32[{SLOTS},{HEADS},{DK},{DV}]", f"bf16[{SLOTS},3,8192]")

@@ -159,7 +159,9 @@ def test_step_program(cell, nets):
     args = (eng._w, eng._pool, jnp.asarray(eng._tables),
             jnp.asarray(eng._last), jnp.asarray(eng._pos))
 
-    text = eng._step.lower(*args).compile().as_text()
+    # the engine's step also takes the step before's output (``prev``)
+    text = eng._step.lower(*args[:4], eng._toks,
+                           args[4]).compile().as_text()
     assert re.search(r"^HloModule jit__step_fn\b", text, re.M)
     # one kernel a layer
     assert text.count('custom_call_target="tpu_custom_call"') == \
