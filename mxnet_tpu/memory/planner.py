@@ -224,10 +224,12 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
                  block_size, dtype=np.float32, mesh=None, rules=None,
                  state_layers=0, state_shape=None, num_slots=0,
                  latent_layers=0, latent_dim=0, index_dim=0,
-                 state_arrays=None):
+                 state_arrays=None, passes=1):
     """Per-device bytes of the serving engine's paged cache.  The block
-    pool: 2 (K and V) × ``num_layers`` × ``num_blocks × num_kv_heads ×
-    block_size × head_dim`` × itemsize, sharded the way the serving rule
+    pool: 2 (K and V) × ``num_layers`` × ``passes`` (``CacheSpec.passes``:
+    how many times the stack runs a token, each pass keeping its own
+    rows) × ``num_blocks × num_kv_heads × block_size × head_dim`` ×
+    itemsize, sharded the way the serving rule
     table places the pool (``layers.{i}.kv_pool`` — KV-head axis over
     ``tp`` by default); ``num_layers`` counts the layers that OWN a K/V
     pool (``CacheSpec.kv_layers``), not the model's depth.  Plus, for a
@@ -246,8 +248,8 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
     ``kv_pool_bytes=`` to get a fit verdict that includes serving
     state.  Matches ``LlamaServingEngine.kv_pool_bytes()`` exactly."""
     dtype = np.dtype(dtype)
-    shape = (int(num_blocks), int(num_kv_heads), int(block_size),
-             int(head_dim))
+    shape = (int(passes) * int(num_blocks), int(num_kv_heads),
+             int(block_size), int(head_dim))
     div = 1
     if mesh is not None:
         from ..parallel import partition as pt

@@ -11,6 +11,9 @@ step, or :class:`BlockDecoding`, the positions of a block in any order),
 view) -> (x, kept, expert rows or None)``, ``_logits(w, x)`` and, for a
 state layer, ``_sequence_state(kept, t0)``: what prefill keeps of a
 whole sequence, as ``CacheSpec.state_entry`` lays a layer's arrays out.
+A model that runs its stack several times a token says so in its spec
+(``CacheSpec.passes``) and supplies ``end_pass(w, x)``, what follows the
+stack after every pass.
 
 A cache view holds where K and V live and answers one call, ``attend(q,
 k, v) -> (context, kept)``: this call's queries, keys and values after
@@ -82,16 +85,23 @@ class CacheSpec:
     return beside their tokens (0: none).  ``decoding``: None for a
     decoder that yields the next token a step, left to right, or the
     :class:`BlockDecoding` of one that commits the positions of a block
-    in any order."""
+    in any order.  ``passes``: how many times the stack of ``layers``
+    runs a token, every pass with the same weights and its OWN rows: a
+    token then keeps ``passes`` x ``layers`` K/V rows, and a layer's K
+    and V pool hold ``passes x num_blocks`` blocks, pass ``t``'s at the
+    slots' block ids + ``t x num_blocks``
+    (``ops.paged_attention.pass_blocks``).  1 for a stack run once;
+    above 1 every layer keeps K/V, none routes, and the model decodes
+    the next token a step."""
 
     __slots__ = ("layers", "num_kv_heads", "head_dim", "state_arrays",
                  "expert_layers", "num_experts", "decoding", "latent_dim",
-                 "index_dim", "select_topk")
+                 "index_dim", "select_topk", "passes")
 
     def __init__(self, layers, num_kv_heads, head_dim, state_shape=None,
                  expert_layers=0, num_experts=0, decoding=None,
                  latent_dim=0, index_dim=0, select_topk=0,
-                 state_arrays=None):
+                 state_arrays=None, passes=1):
         self.layers = tuple(layers)
         if any(kind not in ("kv", "state", "latent")
                for kind in self.layers):
@@ -117,6 +127,17 @@ class CacheSpec:
                                        and self.select_topk):
             raise MXNetError("latent layers need latent_dim, index_dim "
                              "and select_topk")
+        self.passes = int(passes)
+        if self.passes < 1:
+            raise MXNetError("a stack runs at least once: passes >= 1")
+        if self.passes > 1 and (self.kv_layers != len(self.layers)
+                                or self.expert_layers
+                                or decoding is not None):
+            raise MXNetError(
+                "a stack run several times a token (passes > 1) keeps K/V "
+                "rows a pass: a per-slot state, latent rows, routed "
+                "experts' row counts and block decoding have no per-pass "
+                "form yet")
 
     @property
     def kv_layers(self):
@@ -132,11 +153,11 @@ class CacheSpec:
 
     def kv_bytes_per_block(self, block_size, itemsize):
         """Bytes one block holds over every layer that keeps rows in
-        the block tables: K and V of the K/V layers, the latent rows
-        and index keys of the latent layers as stored (padding
-        counted)."""
-        return 2 * self.kv_layers * self.num_kv_heads * int(block_size) \
-            * self.head_dim * int(itemsize) \
+        the block tables: K and V of the K/V layers, once a pass, the
+        latent rows and index keys of the latent layers as stored
+        (padding counted)."""
+        return 2 * self.passes * self.kv_layers * self.num_kv_heads \
+            * int(block_size) * self.head_dim * int(itemsize) \
             + self.latent_layers * latent_cache.bytes_per_block(
                 block_size, self.latent_dim, self.index_dim, itemsize)
 
@@ -497,6 +518,12 @@ class PagedDecoder:
         programs return them: () or a 1-tuple)."""
         import jax.numpy as jnp
 
+        views = list(views)
+        if len(views) != len(w["layers"]):
+            raise MXNetError(
+                f"{len(views)} cache views for {len(w['layers'])} layers: "
+                "the cache does not hold what the decoder's cache_spec() "
+                "states")
         kept_all, counts = [], []
         for p, view in zip(w["layers"], views):
             x, kept, c = self.layer(p, x, rope, view)
@@ -505,10 +532,56 @@ class PagedDecoder:
                 counts.append(c)
         return x, kept_all, ((jnp.stack(counts),) if counts else ())
 
+    def end_pass(self, w, x):
+        """What follows the stack after EVERY pass of a model that runs
+        it several times (``CacheSpec.passes``): the model's to say."""
+        raise NotImplementedError
+
+    def _passes(self, one, carry):
+        """The stack ``CacheSpec.passes`` times as ONE loop on the
+        device: ``one(carry, t) -> (carry, what pass t kept)`` is traced
+        once, whatever the passes, so a program holds the layers once
+        -> (carry, what the passes kept, stacked on a new first axis)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        with jax.named_scope("loop_pass"):
+            return lax.scan(one, carry, jnp.arange(self.cache_spec().passes,
+                                                   dtype=jnp.int32))
+
+    def _decode_passes(self, w, cache, tables, x, pos, rope, paged_kernel):
+        """:meth:`_decode` of a stack run several times: pass ``t``
+        writes and reads its own rows of every layer's pool, through
+        the slots' block tables moved to that pass's blocks; the pools
+        ride the loop and are written in place."""
+        passes = self.cache_spec().passes
+        num_blocks = cache[0][0].shape[0] // passes
+
+        def one(carry, t):
+            x, cache = carry
+            win = paged_attention.window(
+                cache[0][0],
+                paged_attention.pass_blocks(tables, t, num_blocks, passes),
+                pos, self.max_len, paged_kernel)
+            x, cache, _ = self._layers(
+                w, x, rope, [StepView(e, pos, win) for e in cache])
+            return (self.end_pass(w, x), cache), None
+
+        (x, cache), _ = self._passes(one, (x, list(cache)))
+        return self._logits(w, x), cache
+
     def _decode(self, w, cache, tables, x, pos, rope, paged_kernel):
         import jax.numpy as jnp
 
         spec = self.cache_spec()
+        if len(cache) != len(spec.layers):
+            raise MXNetError(
+                f"a cache of {len(cache)} entries for the "
+                f"{len(spec.layers)} layers of the decoder's cache_spec()")
+        if spec.passes > 1:
+            return self._decode_passes(w, cache, tables, x, pos, rope,
+                                       paged_kernel)
         # the window is sized by a pool of rows in the block tables: any
         # layer's but a state layer's
         pool = next(e for kind, e in zip(spec.layers, cache)
@@ -616,7 +689,9 @@ class PagedDecoder:
         the TRUE length (``_sequence_state``).  ``flash`` (static; the
         caller decides it from
         ``ops.flash_attention.prefill_applicable``) picks the flash
-        forward kernel over ``masked_attention``."""
+        forward kernel over ``masked_attention``.  A stack run several
+        times (``CacheSpec.passes``) hands every pass's rows over:
+        ``rows[l]`` is then (k, v) each ``(passes, B, Hkv, Lp, hd)``."""
         import jax.numpy as jnp
 
         b, lp = ids.shape
@@ -627,6 +702,15 @@ class PagedDecoder:
         real = jnp.arange(lp)[None] < (t0[:, None] if t0.ndim else t0)
         lengths = jnp.broadcast_to(t0, (b,)) if flash else None
         causal = self._prefill_view(lp, real, lengths, t0)
+        if self.cache_spec().passes > 1:
+            # rows[l]: the layer's (k, v), each (passes, B, Hkv, Lp, hd)
+            def one(x, _t):
+                x, rows, _ = self._layers(w, x, rope,
+                                          (causal for _ in w["layers"]))
+                return self.end_pass(w, x), rows
+
+            x, rows = self._passes(one, x)
+            return rows, self._last(w, x, t0)
         x, rows, counts = self._layers(w, x, rope,
                                        (causal for _ in w["layers"]))
         rows = [self._sequence_state(r, t0) if kind == "state" else r
@@ -644,6 +728,11 @@ class PagedDecoder:
         position ``t0[b] - 1``."""
         import jax.numpy as jnp
 
+        if self.cache_spec().passes > 1:
+            raise MXNetError(
+                "the suffix prefill has no loop over passes: a stack run "
+                "several times a token is served without the radix "
+                "prefix cache")
         ls = ids.shape[1]
         lpre = prefix_kv[0][0].shape[2]
         s0 = jnp.asarray(s0, jnp.int32)

@@ -55,7 +55,9 @@ K/V rows).
 
 This module owns the pool's storage format, all of it:
 :func:`pool_shape`, the prefill scatter and the prefix gather
-(:func:`scatter_rows`, :func:`gather_rows`), where a decode call's rows
+(:func:`scatter_rows`, :func:`gather_rows`), where a pass of a stack run
+several times keeps its rows (:func:`pass_blocks`,
+:func:`scatter_pass_rows`), where a decode call's rows
 go and what its queries see (:func:`window`), the row write
 (:func:`write_rows`) and the decode attention, kernel or gather
 (:func:`window_attention`).  No other module indexes a pool's axes,
@@ -169,6 +171,30 @@ def scatter_rows(pool, rows, flat_idx):
         .transpose(0, 2, 1, 3, 4) \
         .reshape(kb * nbp, hkv, bs, lanes)
     return pool.at[flat_idx].set(chunks, mode="drop")
+
+
+def pass_blocks(block_ids, t, num_blocks, passes):
+    """Where pass ``t`` of a stack run ``passes`` times a token keeps
+    its rows, in a pool of ``passes x num_blocks`` blocks: the slots'
+    ``block_ids`` (any shape; ``num_blocks`` and above: the sentinel)
+    moved ``t x num_blocks`` on, the sentinel kept a drop (the larger
+    pool's own: ``passes x num_blocks``).  So one block table addresses
+    every pass, and the window, the row write and the kernel are called
+    as they are."""
+    return jnp.where(block_ids < num_blocks, block_ids + t * num_blocks,
+                     passes * num_blocks)
+
+
+def scatter_pass_rows(pool, rows, flat_idx):
+    """:func:`scatter_rows` of a stack run several times: ``rows``
+    (passes, KB, Hkv, Lp, hd), a prefill's raw K or V pass by pass, into
+    a pool of ``passes x num_blocks`` blocks, pass ``t``'s at
+    :func:`pass_blocks` of ``flat_idx`` (KB * nbp,), in one scatter."""
+    passes = rows.shape[0]
+    num_blocks = pool.shape[0] // passes
+    idx = jnp.concatenate([pass_blocks(flat_idx, t, num_blocks, passes)
+                           for t in range(passes)])
+    return scatter_rows(pool, rows.reshape((-1,) + rows.shape[2:]), idx)
 
 
 def gather_rows(pool, block_ids, pack):

@@ -215,6 +215,25 @@ _LATENT_REFUSALS = {
 }
 
 
+#: and for a model that runs its stack several times a token
+#: (``CacheSpec.passes`` above 1); ``radix`` is the replica's to say
+_LOOP_REFUSALS = {
+    "slots": "kv_mode='slots' keeps one dense K and V cache a layer: a "
+             "stack run several times a token keeps rows a pass, in "
+             "kv_mode='paged' pools",
+    "spec": "speculative decoding (draft_net / spec_k) has not been "
+            "carried through the loop over passes: a stack run several "
+            "times a token decodes one token a slot a step",
+    "mesh": "a mesh-placed engine (mesh=) has no partition rule for a "
+            "pool that holds a layer's blocks once a pass",
+    "int8": "int8=True quantizes the dense decoder's weight tree; a "
+            "stack run several times a token is served in its load dtype",
+    "radix": "radix_cache=True prefills a suffix behind shared prefix "
+             "blocks; the suffix prefill has no loop over the passes of a "
+             "stack run several times a token",
+}
+
+
 class BlockTick(NamedTuple):
     """What one pass of a block-decoding engine did, slot by slot
     (``LlamaServingEngine.step`` of such an engine), vacant and
@@ -311,8 +330,9 @@ class LlamaServingEngine:
         block = self.block = spec.decoding
         self.decoding = "next_token" if block is None else "block_diffusion"
         if spec.state_layers or spec.expert_layers or spec.latent_layers \
-                or block is not None:
-            why = _LATENT_REFUSALS if spec.latent_layers else \
+                or block is not None or spec.passes > 1:
+            why = _LOOP_REFUSALS if spec.passes > 1 else \
+                _LATENT_REFUSALS if spec.latent_layers else \
                 _STATE_REFUSALS if block is None else _BLOCK_REFUSALS
             for key, bad in (("slots", kv_mode != "paged"),
                              ("spec", self.spec_k),
@@ -353,9 +373,11 @@ class LlamaServingEngine:
                 self.block_size, dt) if spec.kv_layers else 0
             paged_kernel = pack > 0
             self.kv_pack = pack = max(1, pack)
+            # a stack run several times keeps a pass's blocks behind the
+            # pass before's, in one pool a layer
             pshape = paged_attention.pool_shape(
-                self.num_blocks, spec.num_kv_heads, spec.head_dim,
-                self.block_size, pack)
+                spec.passes * self.num_blocks, spec.num_kv_heads,
+                spec.head_dim, self.block_size, pack)
             lshapes = latent_cache.pool_shapes(
                 self.num_blocks, self.block_size, spec.latent_dim,
                 spec.index_dim)
@@ -371,6 +393,10 @@ class LlamaServingEngine:
                     jnp.zeros((self.num_slots,) + shape, sdt or dt)
                     for shape, sdt in spec.state_arrays)
                 for kind in spec.layers]
+            if len(self._pool) != len(w["layers"]):
+                raise MXNetError(
+                    f"the decoder's cache_spec() states {len(self._pool)} "
+                    f"layers and its weights hold {len(w['layers'])}")
             self._tables = np.full((self.num_slots, self.max_blocks),
                                    self.num_blocks, np.int32)
             self._caches = None
@@ -418,6 +444,12 @@ class LlamaServingEngine:
         #: the active slots)
         self.state_bytes_per_step = 2 * spec.state_bytes_per_slot(
             self.cache_itemsize)
+        #: bytes a token keeps in the block tables over every layer and
+        #: pass, by the spec (a record's ``kv_bytes`` is this times its
+        #: tokens); 0 for the slot ledger, which has no blocks
+        self.kv_bytes_per_token = 0 if kv_mode != "paged" else \
+            spec.kv_bytes_per_block(self.block_size, self.cache_itemsize) \
+            // self.block_size
         #: What each lane has on the device's queue, for the other to
         #: see: the ``seq`` of the newest step()/verify() whose dispatch
         #: has returned and whose tokens are not yet on the host (the
@@ -592,7 +624,11 @@ class LlamaServingEngine:
                 # slot id num_slots, dropped), so a reused slot never
                 # sees its predecessor's; a latent layer's rows (KB, Lp,
                 # width) go block by block as its format stores them
-                by_block = {"kv": paged_attention.scatter_rows,
+                # (of a stack run several times: (passes, KB, Hkv, Lp,
+                # hd), pass t's into that pass's blocks of the pool)
+                by_block = {"kv": paged_attention.scatter_pass_rows
+                            if spec.passes > 1
+                            else paged_attention.scatter_rows,
                             "latent": latent_cache.scatter_rows}
                 return [
                     tuple(by_block[kind](p, r, flat_idx)

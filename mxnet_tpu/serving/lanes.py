@@ -112,7 +112,11 @@ def _cache_layers(engine):
     spec = getattr(engine, "cache_spec", None)
     if spec is None:
         return {}
-    out = {"kv_layers": spec.kv_layers, "state_layers": spec.state_layers}
+    out = {"kv_layers": spec.kv_layers, "state_layers": spec.state_layers,
+           "cache_passes": spec.passes,
+           "kv_bytes_per_token": getattr(engine, "kv_bytes_per_token", 0)}
+    if getattr(engine, "num_blocks", None):
+        out["pool_tokens"] = engine.num_blocks * engine.block_size
     if spec.latent_layers:
         out["latent_layers"] = spec.latent_layers
     if getattr(engine, "linear_attention", None):
@@ -172,6 +176,14 @@ class PrefillLane:
         # busy / gated / idle seconds and the batch count (the ``seq``
         # of ``prefill.batch`` records), always on (tracing.lane_state)
         self.clock = tracing.LaneClock(replica.index)
+
+    @property
+    def gate(self):
+        """What the FIFO head waited for when the lane last looked and
+        ran nothing: ``"slot"``, ``"block"``, ``"tokens"``; None where
+        it took a batch or found the queue empty.  Read by the decode
+        lane (one attribute, no lock)."""
+        return self._gate
 
     def start(self):
         if self._thread is None:
@@ -477,6 +489,9 @@ class PrefillLane:
             t_commit1=t_commit1, t_first=t_first,
             prefill_attention=attention, behind_tick=behind_tick,
             free_slots=free_slots, queued=queued,
+            passes=getattr(getattr(eng, "cache_spec", None), "passes", 1),
+            kv_bytes=int(t0s_suf[:len(group)].sum())
+            * getattr(eng, "kv_bytes_per_token", 0),
             expert_product=product(kb * lb) if product else None,
             **(eng.selection_counts(t0s_suf[:len(group)], whole=True)
                if hasattr(eng, "selection_counts") else {}),
@@ -720,8 +735,9 @@ class DecodeLane:
     def _prefill_covers(self, step):
         """Whether the device will have a prefill's forward to run when
         ``step``, the one in flight, ends: one is queued behind it, or
-        the prefill lane is about to queue one (a request waits and a
-        slot is free: the lane dispatches within a millisecond or two).
+        the prefill lane is about to queue one (a request waits, a slot
+        is free and the lane is not gated on blocks: it dispatches
+        within a millisecond or two).
         The host's turn then hides behind that forward as it would
         behind a step queued ahead, and a step queued now would only
         stand between this admission's forward and the next one's: the
@@ -736,7 +752,13 @@ class DecodeLane:
         r = self.r
         if any(b not in step.behind for b in r.engine.prefill_in_flight):
             return True
-        return len(r.queue) > 0 and r.mgr.free_slots() > 0
+        # a lane that its last look at the queue left gated on BLOCKS
+        # queues nothing while slots stand free: it waits for a request
+        # to end, which is this lane's to book, and the device would
+        # rest a turn of the host's every step (a pool smaller than
+        # slots x max_length: ``PERF.md`` section 6, PR 40)
+        return len(r.queue) > 0 and r.mgr.free_slots() > 0 \
+            and r.prefill.gate != "block"
 
     def _tick(self):
         """A turn of the token-at-a-time lane, one step ahead of its
@@ -932,6 +954,16 @@ class DecodeLane:
         if turn is not None:
             t_loop, t_lock, t_disp0, t_disp1 = turn
         extra.setdefault("kv_tokens", int(step.kv_tokens))
+        # the K/V rows' bytes by the engine's spec, every pass counted,
+        # and how much of the pool its requests have reserved
+        spec = getattr(r.engine, "cache_spec", None)
+        passes = getattr(spec, "passes", 1)
+        extra.update(
+            passes=passes,
+            kv_bytes=extra["kv_tokens"]
+            * getattr(r.engine, "kv_bytes_per_token", 0),
+            pool_reserved_tokens=r.mgr.allocator.blocks_in_use
+            * r.mgr.block_size)
         per_slot = getattr(r.engine, "state_bytes_per_step", 0)
         if per_slot:
             # what the active slots' state layers read and wrote
@@ -950,6 +982,9 @@ class DecodeLane:
             extra.update(_cache_layers(r.engine))
         r.steps_ahead += step.ahead
         telemetry.count("serving.decode.steps")
+        if spec is not None:
+            telemetry.count("serving.decode.layer_applications",
+                            passes * len(spec.layers))
         if step.ahead:
             telemetry.count("serving.decode.steps_ahead")
         tracing.lane_record(
@@ -1123,6 +1158,10 @@ class Replica:
             from .generative import _LATENT_REFUSALS
 
             raise MXNetError(_LATENT_REFUSALS["radix"])
+        if radix_cache and spec.passes > 1:
+            from .generative import _LOOP_REFUSALS
+
+            raise MXNetError(_LOOP_REFUSALS["radix"])
         if radix_cache and spec.state_layers:
             raise MXNetError(
                 "radix_cache=True shares a prompt prefix's K/V blocks; a "

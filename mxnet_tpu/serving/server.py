@@ -646,6 +646,14 @@ class GenerativeServer(_ServerBase):
             "kv_layers": reps[0].engine.cache_spec.kv_layers,
             "state_layers": reps[0].engine.cache_spec.state_layers,
             "latent_layers": reps[0].engine.cache_spec.latent_layers,
+            # how many times the stack runs a token, what a token keeps
+            # in the block tables over every layer and pass, and how much
+            # of the pool (in tokens) the requests in flight have reserved
+            "cache_passes": reps[0].engine.cache_spec.passes,
+            "kv_bytes_per_token": reps[0].engine.kv_bytes_per_token,
+            "pool_reserved_tokens": reps[0].mgr.allocator.blocks_in_use
+            * reps[0].mgr.block_size,
+            "pool_tokens": reps[0].mgr.num_blocks * reps[0].mgr.block_size,
             # the cache's bytes a device, by kind: K/V blocks, per-slot
             # state (and by array where a state layer owns several) and,
             # of a latent model, latent rows and index keys

@@ -375,10 +375,11 @@ class _Stub:
     touches, by hand: the decision a turn takes is a matter of what the
     prefill lane has on the device's queue, which a server cannot pin."""
 
-    index, queue, prefill_in_flight, blocks_in_use = 0, (), (), 0
+    index, queue, prefill_in_flight, blocks_in_use, block_size = 0, (), (), 0, 4
+    gate = None          # the prefill lane's: what its last look waited for
 
     def __init__(self, budget):
-        self.engine = self.mgr = self.allocator = self
+        self.engine = self.mgr = self.allocator = self.prefill = self
         self.steps = self.batches = self.steps_ahead = 0
         self.left, self.done = dict(budget), {}
 
@@ -454,6 +455,31 @@ def test_a_covered_turn_queues_nothing_and_the_hand_off_rides_the_next_step():
         == ("a", 1, "b", 3)
     assert tb["t_adopt"] < ticks[2]["t_step_loop"] and tb["t_tok"] \
         == ticks[2]["t_tok"]
+
+
+def test_a_lane_gated_on_blocks_covers_nothing():
+    """A request waits and a slot is free, but the prefill lane waits for
+    BLOCKS (a pool smaller than slots x max_length): no forward is coming, so
+    the turn queues its step ahead (PR 40)."""
+    from mxnet_tpu.serving.lanes import DecodeLane, _Handoff
+
+    r = _Stub({0: 6})
+    r.queue = ("c",)
+    lane = DecodeLane(r)
+
+    def turn():
+        lane._adopt()
+        lane._tick()
+        return lane._flight.step.seq if lane._flight else None
+
+    lane.hand_off(_Handoff(_Req("a"), 0, 1))
+    assert turn() == 1
+    assert turn() is None and r.steps == 1     # about to admit: covered
+    r.gate = "block"
+    assert turn() == 2 and turn() == 3 and turn() == 4
+    assert lane._flight.step.ahead is False    # the stub's steps say nothing
+    r.gate = "slot"
+    assert turn() is None and r.steps == 4
 
 
 # --- the lane log under the benchmark's readers, as they stand -----------------

@@ -650,8 +650,11 @@ def one_chip():
         (128, 64, 8192, 1, "bfloat16", 32, 8, 64),  # lfm2_24b.chat_decode_sat
         (128, 64, 8192, 4, "bfloat16", 32, 8, 64),  # heads of 64, verify k = 3
         (128, 64, 8192, -4, "bfloat16", 32, 4, 128),  # sdar_30b: a BLOCK of 4
+        # ouro_2_6b: ONE query head a KV head, a pool of 4 passes x 320 blocks
+        (8, 96, 1280, 1, "bfloat16", 16, 16, 128),
     ], ids=["chat_64x1024", "doc_16x4096", "verify_k3", "float32",
-            "lfm2_128x1024_hd64", "verify_k3_hd64", "sdar_block4"])
+            "lfm2_128x1024_hd64", "verify_k3_hd64", "sdar_block4",
+            "ouro_8x1536_group1"])
 def test_kernel_compiles_for_v5e(one_chip, slots, max_blocks, num_blocks,
                                  cols, dtype, heads, kv_heads, hd):
     """Mosaic takes the kernel at the benchmark cells' shapes (32 query
@@ -763,6 +766,72 @@ def test_prefill_program_compiles_for_v5e_without_scores(one_chip, flash):
     else:
         assert kernels == 0 and f"f32[{heads},{lp},{lp}]" in scores
         assert repeats and temps > 4e9, temps
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_512"])
+def test_looped_programs_compile_for_v5e_with_the_stack_held_once(one_chip,
+                                                                  program):
+    """Ouro-2.6B's step and 512 prefill bucket at the published widths and
+    ``ouro_2_6b.reason_decode_sat``'s shapes (8 slots x 1,536, 4 passes x 320
+    blocks a layer; three layers: every layer's arrays have the cell's
+    shapes): ONE loop over the four passes, a kernel a LAYER and not a layer a
+    pass, the pools aliased through the loop with no pool-sized copy, and
+    temporaries far under a pool's 80 MiB."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.models import ouro
+
+    layers, slots, max_len, blocks = 3, 8, 1536, 320
+    conf = ouro.OuroConfig(num_layers=layers, max_seq_len=max_len)
+    dec = ouro.OuroDecoder(ouro.OuroForCausalLM(conf), max_len)
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layer = {n: sds(*shape)
+             for n, shape in ouro._layer_param_shapes(conf).items()}
+    w = dict(layers=[layer] * layers, emb=sds(49152, 2048), norm=sds(2048),
+             head=sds(49152, 2048))
+    stored = (4 * blocks, 16, 16, 128)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            if program == "step":
+                compiled = jax.jit(
+                    functools.partial(dec._step_blocks_impl, paged_kernel=True),
+                    donate_argnums=1).lower(
+                        w, [(sds(*stored), sds(*stored))] * layers,
+                        sds(slots, max_len // 16, dtype=jnp.int32),
+                        sds(slots, dtype=jnp.int32),
+                        sds(slots, dtype=jnp.int32)).compile()
+            else:
+                compiled = jax.jit(functools.partial(
+                    dec._prefill_rows_impl, flash=True)).lower(
+                        w, sds(1, 512, dtype=jnp.int32),
+                        sds(1, dtype=jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    assert text.count(" while(") == 1
+    if program == "step":
+        assert "paged_decode_attention" in text
+        pool_bytes = 2 * int(np.prod(stored))
+        assert mem.alias_size_in_bytes == 2 * layers * pool_bytes
+        assert mem.temp_size_in_bytes < pool_bytes // 4
+        shape = ",".join(map(str, stored))
+        assert not re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(", text)
+    else:
+        assert "prefill_flash_attention" in text
+        # what goes out: a pass's K and V rows a layer, four passes stacked
+        assert mem.output_size_in_bytes >= 4 * layers * 2 * 16 * 512 * 128 * 2
+        assert mem.temp_size_in_bytes < 2 ** 28
 
 
 @pytest.mark.parametrize("shape,dtype", [

@@ -106,6 +106,8 @@ class _StubAllocator:
 
 
 class _StubMgr:
+    block_size = 4     # a tick record prices the blocks in use in tokens
+
     def __init__(self, budgets):
         self.allocator = _StubAllocator()
         self._left = dict(budgets)     # slot -> decode steps remaining
