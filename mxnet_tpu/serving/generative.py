@@ -429,9 +429,10 @@ class LlamaServingEngine:
                                  "committed_tokens": 0}
         #: which slots' ``_last`` the host wrote (a prefill's commit,
         #: ``set_mirror``, ``clear_slot``) since a step last advanced
-        #: them: a slot's next input is the token the step before
-        #: produced, still on the device, and the host's where this says
-        #: so (every slot to start with)
+        #: them, and which the last step left out (its vacant row's
+        #: output stands where their token did): a slot's next input is
+        #: the token the step before produced, still on the device, and
+        #: the host's where this says so (every slot to start with)
         self._fresh = np.ones(self.num_slots, bool)
         self.steps = 0
         #: the :class:`StepHandle` of the last step()/verify() whose
@@ -1081,7 +1082,11 @@ class LlamaServingEngine:
 
         A slot's input token is the one the step before produced for
         it, read on the device; the host's ``_last`` where it wrote it
-        since that slot was last stepped (``_fresh``).  So the next step
+        since that slot was last stepped, or where the step before left
+        the slot out (``_fresh``: a slot parked for want of a block
+        comes back with the token the host booked for it, whose step
+        has been fetched by then: the lane is at most one step ahead).
+        So the next step
         can be queued before this one's tokens have reached the host.
         The device runs programs in the order they were queued, and both
         lanes queue what touches the pool under ``dev_lock`` on the
@@ -1127,6 +1132,7 @@ class LlamaServingEngine:
                                          self._toks, at)
                         self._caches = out[1]
                     self._toks = out[0]
+                    self._fresh[:] = True
                     self._fresh[act] = False
                     self._pos[act] += 1
                     # the step attends pos + 1 rows: the cursors as they
@@ -1267,6 +1273,16 @@ class LlamaServingEngine:
             self._last[slot] = int(last)
             self._fresh[slot] = True
             self._pos[slot] = int(pos)
+
+    def set_blocks(self, slot, at, blocks=()):
+        """``slot``'s row of the block tables from index ``at`` on:
+        ``blocks``, then the sentinel.  The manager's grant behind a
+        cursor appends (``PagedKVCacheManager.grant_step``); a rollback
+        that gave blocks back passes none."""
+        with self.dev_lock:
+            row = self._tables[slot]
+            row[at:] = self.num_blocks
+            row[at:at + len(blocks)] = blocks
 
     def clear_slot(self, slot):
         with self.dev_lock:

@@ -12,12 +12,17 @@ connected by an explicit KV handoff:
   (free decode slots, free KV blocks, a cumulative prompt-token ceiling
   — prefill batches greedily by token count, not request count), admits
   each request to the :class:`~.kv_cache.PagedKVCacheManager` (which
-  reserves the request's whole block budget up front — no mid-decode
-  allocation stall), runs the prompt forward OUTSIDE the engine's
+  takes the prompt's blocks and books the request's whole budget as a
+  claim: the request is admitted only if every admitted request can
+  still finish, the manager's safe-state rule), runs the prompt forward OUTSIDE the engine's
   device lock, then commits the raw K/V rows into the admitted blocks
   (one brief locked scatter) and hands the slot to the decode lane.
 * :class:`DecodeLane` — latency-structured.  Every tick it adopts
-  pending handoffs, then advances *its own* slot set one token.  It
+  pending handoffs, then advances *its own* slot set one token, each
+  slot first granted the block its write lands in
+  (:meth:`DecodeLane._grant`: a slot the safe-state rule refuses is
+  parked for that step, left out of it and asked about again at the
+  next).  It
   never sees a prompt forward: while a long prompt prefills, decode
   ticks keep dispatching (the device lock covers only the KV-mutating
   dispatches, not the prefill compute).  The token-at-a-time tick
@@ -70,6 +75,7 @@ replica + lane and trip the flight recorder (``tracing.incident``).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -160,6 +166,7 @@ class _Flight(NamedTuple):
     ids: tuple          #: the request of each of the step's slots
     ending: frozenset   #: the slots whose last token the step produces
     adopted: tuple      #: the hand-offs whose first step it is
+    parked: int         #: the slots it left out for want of a block
 
 
 class PrefillLane:
@@ -272,43 +279,47 @@ class PrefillLane:
         if not free_slots:
             self._gate = "slot"
             return False
-        free_blocks = mgr.allocator.free_blocks
-        budget = {"n": 0, "blocks": 0, "tokens": 0}
+        taken = []      # (prompt, max_new_tokens, shared blocks) each
+        tokens = 0
 
         def accept(req):
-            # the lane's own batch policy: greedy by token count under
-            # the block budget, not a fixed request count (a radix hit
-            # shrinks the fresh-block need by the shared prefix)
-            need = mgr.blocks_for(len(req.prompt_ids),
-                                  req.max_new_tokens)
-            if r.radix is not None:
-                need -= r.radix.match_len(req.prompt_ids) \
-                    // mgr.block_size
+            # the lane's own batch policy: greedy by token count while
+            # the manager finds the state with the batch admitted safe,
+            # not a fixed request count (a radix hit's shared prefix
+            # takes no fresh block)
+            nonlocal tokens
+            shared = 0 if r.radix is None else \
+                r.radix.match_len(req.prompt_ids) // mgr.block_size
+            entry = (len(req.prompt_ids), req.max_new_tokens, shared)
             # a refusal of the FIFO head (nothing taken) gates the lane
-            if budget["n"] >= free_slots:
+            if len(taken) >= free_slots:
                 self._gate = "slot"
                 return False
-            if budget["blocks"] + need > free_blocks:
+            if not mgr.admissible(taken + [entry]):
                 self._gate = "block"
+                telemetry.count("serving.kv.unsafe_refusals|at=admit")
                 return False
-            if budget["tokens"] and (budget["tokens"]
-                                     + len(req.prompt_ids)
-                                     > r.max_prefill_tokens):
+            if tokens and tokens + entry[0] > r.max_prefill_tokens:
                 self._gate = "tokens"
                 return False
-            budget["n"] += 1
-            budget["blocks"] += need
-            budget["tokens"] += len(req.prompt_ids)
+            taken.append(entry)
+            tokens += entry[0]
             return True
 
-        group = r.queue.take_batch(
-            self._bucket, min(free_slots, r.policy.max_batch), accept)
-        if not group:
-            return False
-        self._gate = None   # a refusal after the head only ends the batch
-        with TraceAnnotation("mxt.prefill.batch",
-                             seq=self.clock.batches + 1, replica=r.index):
-            self._prefill_group(group, free_slots, queued)
+        with contextlib.ExitStack() as gate:
+            # held from the gate to the admits (_prefill_group lets go):
+            # growth asks for the same lock, so no grant lands between
+            # what the gate found safe and the admission
+            gate.enter_context(mgr.admission())
+            group = r.queue.take_batch(
+                self._bucket, min(free_slots, r.policy.max_batch), accept)
+            if not group:
+                return False
+            self._gate = None   # a refusal after the head only ends the batch
+            with TraceAnnotation("mxt.prefill.batch",
+                                 seq=self.clock.batches + 1,
+                                 replica=r.index):
+                self._prefill_group(group, free_slots, queued, gate)
         return True
 
     def _forward(self, group, prompts, matched, skip, block_lists,
@@ -336,12 +347,14 @@ class PrefillLane:
         toks, rows = eng.prefill_suffix(pre_kv, prompts, t0s_suf, s0s)
         return toks, rows, "dense"
 
-    def _prefill_group(self, group, free_slots, queued):
-        """The admitted ``group`` through forward, commit and handoff,
-        stamped once at each boundary for the lane log, the capacity
-        duty cycle and the requests' span trees alike.  ``free_slots``
-        and ``queued``: the counts at the gate that took the group (the
-        group's own requests among the queued), for its record."""
+    def _prefill_group(self, group, free_slots, queued, gate):
+        """The ``group`` the gate took through admission, forward,
+        commit and handoff, stamped once at each boundary for the lane
+        log, the capacity duty cycle and the requests' span trees alike.
+        ``free_slots`` and ``queued``: the counts at the gate that took
+        the group (the group's own requests among the queued), for its
+        record.  ``gate``: holds the manager's lock since the gate
+        looked; closed here once the group is admitted."""
         r = self.r
         mgr = r.mgr
         t_start = time.perf_counter()
@@ -397,7 +410,9 @@ class PrefillLane:
                 block_lists[i] = blocks
                 freed[i] = r.released.get(int(slot))
                 req.slot = int(slot)
-                req.kv_blocks = len(blocks)
+                # its claim on the pool; it holds the prompt's now
+                req.kv_blocks = mgr.blocks_for(int(t0s[i]),
+                                               req.max_new_tokens)
                 if rx is not None:
                     req.prefix_hit_tokens = matched[i]
                 req.replica = r.index
@@ -405,6 +420,7 @@ class PrefillLane:
                 req.t_start = t_start
                 req.bucket = (kb, lb)
                 req.batch_size = len(group)
+            gate.close()
             with telemetry.span("serving.prefill",
                                 {"lane": "prefill", "replica": r.index,
                                  "batch": kb, "length": lb}):
@@ -453,6 +469,7 @@ class PrefillLane:
                         r.draft.set_mirror(s, int(first[i]),
                                            int(t0s[i]))
         except Exception as exc:
+            gate.close()
             eng.prefill_in_flight = ()
             self.clock.enter("idle", time.perf_counter())
             for req in group:
@@ -698,11 +715,14 @@ class DecodeLane:
                     # the request's log of every commit
                     h.req.commits = []
 
-    def _abort(self, active, exc):
+    def _abort(self, exc):
         """An engine call of a turn raised: fail the request of every
-        slot the lane holds (``active``) and free the slot."""
+        slot the lane holds (the stepped and the parked alike) and free
+        the slot."""
         r = self.r
-        for slot in active:
+        with self._hand_lock:
+            held = sorted(self._seqs)
+        for slot in held:
             with self._hand_lock:
                 req, _ = self._seqs.pop(slot)
             r.release(req)
@@ -712,6 +732,61 @@ class DecodeLane:
         tracing.incident("replica_exception",
                          context={"replica": r.index, "lane": "decode",
                                   "error": repr(exc)})
+
+    def _grant(self, active, n=1):
+        """Before a step is queued: the manager grants each of
+        ``active`` the blocks its next ``n`` writes land in
+        (``grant_step``: oldest admission first, under the safe-state
+        rule) and the engine's tables take them -> (the slots the step
+        takes, how many it leaves out).  A slot refused is PARKED for
+        this step: left out of ``active``, it runs as a vacant row (the
+        token tick; a block pass or a verify computes its row and books
+        nothing of it, and what it would write past its blocks drops at
+        the sentinel), its cursor, its count and its blocks as they
+        were, and is asked about again before the next step; nothing is
+        evicted and no token differs."""
+        r = self.r
+        if not active:
+            return active, 0
+        grants, parked = r.mgr.grant_step(active, n)
+        for slot, (at, blocks) in grants.items():
+            r.engine.set_blocks(slot, at, blocks)
+        if grants:
+            telemetry.count("serving.kv.grants",
+                            sum(len(b) for _, b in grants.values()))
+        if parked:
+            telemetry.count("serving.kv.parked_slot_ticks", len(parked))
+            telemetry.count("serving.kv.unsafe_refusals|at=grant",
+                            len(parked))
+            active = [s for s in active if s not in parked]
+        return active, len(parked)
+
+    def _rest(self):
+        """A turn that found every slot parked and nothing to fetch:
+        only a hand-off (or a failed prefill's release) changes that,
+        so wait for one as an empty lane does."""
+        with TraceAnnotation("mxt.decode.wait", replica=self.r.index):
+            self._wake.wait(self.poll_s)
+        self._wake.clear()
+
+    def _serial_step(self, n):
+        """What a tick that books its own step (the block tick, the
+        speculative tick) steps: every slot the lane holds that is
+        granted the blocks of its next ``n`` writes -> (slots, their
+        requests' ids, slots parked, the hand-offs whose first step
+        this is), or None where every slot is parked: the turn has
+        rested, and its hand-offs' records wait for the next step."""
+        with self._hand_lock:
+            held = sorted(self._seqs)
+        adopted, self._unstepped = self._unstepped + self._adopted, ()
+        active, n_parked = self._grant(held, n)
+        if not active:
+            self._unstepped = adopted
+            self._rest()
+            return None
+        with self._hand_lock:
+            ids = tuple(self._seqs[s][0].id for s in active)
+        return active, ids, n_parked, adopted
 
     def _note_tick(self, active, t_busy0, t_tok):
         """What every kind of tick counts once its engine call is back."""
@@ -766,7 +841,10 @@ class DecodeLane:
         step is on the device's queue while the host works.  Step K+1
         needs nothing of step K's that the host does not know already:
         its tokens pass from step to step on the device
-        (``engine.dispatch_step``), blocks were reserved at admission, a
+        (``engine.dispatch_step``), the block a write lands in is
+        granted from the cursor the host already has (:meth:`_grant`: a
+        slot refused is parked, left out of step K+1 and tried again
+        for K+2, its token then the host's), a
         cursor moves by one and a request ends by count.  So the
         manager's count is taken as a step is QUEUED: a slot whose last
         token step K produces is left out of step K+1 (it runs vacant
@@ -784,9 +862,11 @@ class DecodeLane:
         with self._hand_lock:
             active = [s for s in sorted(self._seqs)
                       if prev is None or s not in prev.ending]
-            ids = tuple(self._seqs[s][0].id for s in active)
         if prev is not None and self._prefill_covers(prev.step):
             active = ()
+        active, n_parked = self._grant(active)
+        with self._hand_lock:
+            ids = tuple(self._seqs[s][0].id for s in active)
         adopted, self._unstepped = self._unstepped + self._adopted, ()
         # the turn's own dispatch, for its record: an instant where it
         # queues nothing
@@ -800,7 +880,8 @@ class DecodeLane:
                     if r.mgr.consume(slot):
                         ending.add(slot)
                 self._flight = _Flight(step, self._t_loop, ids,
-                                       frozenset(ending), adopted)
+                                       frozenset(ending), adopted,
+                                       n_parked)
                 # the count is the host's work, not a wait for tokens: the
                 # turn's ``t_disp1`` is where the lane turns to the fetch
                 t_lock, t_disp0 = step.t_lock, step.t_disp0
@@ -808,15 +889,15 @@ class DecodeLane:
             else:
                 self._unstepped = adopted
             if prev is None:
+                if n_parked and not active:
+                    self._rest()
                 return
             toks = eng.fetch_step(prev.step)
         except Exception as exc:
             # both steps' requests are in _seqs, each once
             self._flight, self._unstepped = None, ()
             eng.drop_steps()
-            with self._hand_lock:
-                held = sorted(self._seqs)
-            self._abort(held, exc)
+            self._abort(exc)
             return
         step = prev.step
         # the device was the step's from its dispatch or, run ahead, from
@@ -851,6 +932,7 @@ class DecodeLane:
         self._record_tick(step, prev.ids, n_finished, prev.adopted,
                           queued_at=prev.t_loop,
                           turn=(self._t_loop, t_lock, t_disp0, t_disp1),
+                          n_parked=prev.parked,
                           **step.experts, **step.selection)
 
     def _tick_block(self):
@@ -867,13 +949,15 @@ class DecodeLane:
         r = self.r
         eng = r.engine
         bl = eng.block.block_len
-        with self._hand_lock:
-            active = sorted(self._seqs)
-            ids = tuple(self._seqs[s][0].id for s in active)
+        # a pass writes its whole block's K/V, the first pass too
+        taken = self._serial_step(bl)
+        if taken is None:
+            return
+        active, ids, n_parked, adopted = taken
         try:
             tick = eng.step(active)
         except Exception as exc:
-            self._abort(active, exc)
+            self._abort(exc)
             return
         step = eng.booked
         t_disp0, t_tok = step.t_disp0, step.t_tok
@@ -915,19 +999,21 @@ class DecodeLane:
                     r.finish(req, [tokens[i]
                                    for i in range(req.max_new_tokens)])
                     n_finished += 1
-        self._record_tick(step, ids, n_finished, self._adopted,
+        self._record_tick(step, ids, n_finished, adopted,
                           block_len=bl, rows=len(active) * bl,
                           n_store=int(tick.stored.sum()),
                           committed=int(tick.commit.sum()),
                           block_passes=int((tick.step[tick.stored] + 1).sum()),
-                          **step.experts)
+                          n_parked=n_parked, **step.experts)
 
     def _record_tick(self, step, ids, n_finished, adopted, queued_at=None,
                      turn=None, **extra):
         """The ``decode.tick`` record of ``step`` (the engine's
         ``StepHandle``), its bookkeeping done, and a ``slot.turn`` record
         for each hand-off that it was the first step of (``adopted``).
-        ``seq``, ``request_ids``, ``n_active``, ``n_adopted``,
+        ``seq``, ``request_ids``, ``n_active`` (the slots it stepped),
+        ``n_parked`` (passed in ``extra``: the slots held and left out
+        of it for want of a block), ``n_adopted``,
         ``behind``, ``ahead``, ``kv_tokens`` (K/V rows the step attended,
         summed over its slots) and ``t_tok`` are the step's.  ``t_loop,
         t_lock, t_disp0, t_disp1`` are the stamps of the turn that
@@ -955,7 +1041,7 @@ class DecodeLane:
             t_loop, t_lock, t_disp0, t_disp1 = turn
         extra.setdefault("kv_tokens", int(step.kv_tokens))
         # the K/V rows' bytes by the engine's spec, every pass counted,
-        # and how much of the pool its requests have reserved
+        # and how much of the pool its requests hold (blocks granted)
         spec = getattr(r.engine, "cache_spec", None)
         passes = getattr(spec, "passes", 1)
         extra.update(
@@ -1021,9 +1107,11 @@ class DecodeLane:
         contract)."""
         r = self.r
         k = r.spec_k
-        with self._hand_lock:
-            active = sorted(self._seqs)
-            ids = tuple(self._seqs[s][0].id for s in active)
+        # the verify writes the window's k + 1 rows
+        taken = self._serial_step(k + 1)
+        if taken is None:
+            return
+        active, ids, n_parked, adopted = taken
         t0 = time.perf_counter()
         proposals = np.zeros((r.engine.num_slots, k), np.int32)
         try:
@@ -1034,7 +1122,7 @@ class DecodeLane:
             pos0 = r.engine.positions()
             out = r.engine.verify(proposals)
         except Exception as exc:
-            self._abort(active, exc)
+            self._abort(exc)
             return
         # the verify's stamps; the k draft steps lie in [t0, t_lock]
         step = r.engine.booked
@@ -1061,7 +1149,9 @@ class DecodeLane:
                 acc = min(m + 1, k, int(st.remaining))
                 adv = min(k + 1, int(st.reserved) - int(st.pos))
                 r.mgr.advance_n(slot, adv)
-                r.mgr.truncate(slot, int(pos0[slot]) + acc)
+                if r.mgr.truncate(slot, int(pos0[slot]) + acc):
+                    # blocks that held rejected rows only went back
+                    r.engine.set_blocks(slot, len(st.blocks))
                 last = int(g[acc - 1])
                 r.engine.set_mirror(slot, last, int(pos0[slot]) + acc)
                 r.draft.set_mirror(slot, last, int(pos0[slot]) + acc)
@@ -1094,8 +1184,9 @@ class DecodeLane:
                     n_finished += 1
         # the verify's last column attended pos0 + k + 1 rows
         kv_tokens = sum(int(pos0[slot]) + k + 1 for slot in active)
-        self._record_tick(step, ids, n_finished, self._adopted,
-                          kv_tokens=kv_tokens, accepted=accepted)
+        self._record_tick(step, ids, n_finished, adopted,
+                          kv_tokens=kv_tokens, accepted=accepted,
+                          n_parked=n_parked)
         telemetry.count("serving.draft_tokens", k * len(active))
         capacity.note_spec(r.index, k * len(active), accepted_this_tick)
         if r.draft_tokens:

@@ -99,7 +99,7 @@ class Request:
         self.t_handoff = None   # decode lane adopted the prefilled KV
         self.commits = None     # a block decoder's (position, token, pass)
         self.selected = None    # a selecting model's last step's reads
-        self.kv_blocks = None   # blocks reserved for the request
+        self.kv_blocks = None   # the request's claim on the pool, in blocks
         # observability (r12): the request-scoped span context (a
         # telemetry.tracing.Trace, None while tracing is off — every
         # serving call site guards on that None) and the SLO tenant

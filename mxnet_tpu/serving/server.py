@@ -648,7 +648,8 @@ class GenerativeServer(_ServerBase):
             "latent_layers": reps[0].engine.cache_spec.latent_layers,
             # how many times the stack runs a token, what a token keeps
             # in the block tables over every layer and pass, and how much
-            # of the pool (in tokens) the requests in flight have reserved
+            # of the pool (in tokens) the requests in flight hold: blocks
+            # are granted as a request grows, so these hold tokens
             "cache_passes": reps[0].engine.cache_spec.passes,
             "kv_bytes_per_token": reps[0].engine.kv_bytes_per_token,
             "pool_reserved_tokens": reps[0].mgr.allocator.blocks_in_use
@@ -713,8 +714,10 @@ class GenerativeServer(_ServerBase):
                         sum(r.mgr.allocator.blocks_in_use for r in reps))
         if capacity.is_enabled():
             out["capacity"] = [capacity.snapshot(r.index) for r in reps]
-        # always on: where each prefill lane's wall time went
-        out["lanes"] = [r.prefill.clock.snapshot() for r in reps]
+        # always on: where each prefill lane's wall time went, and what
+        # growth on demand granted, parked and refused behind it
+        out["lanes"] = [dict(r.prefill.clock.snapshot(), **r.mgr.growth())
+                        for r in reps]
         if self.slo is not None:
             out["slo"] = self.slo.snapshot()
         return out

@@ -395,6 +395,9 @@ class _Stub:
         step.t_tok = time.perf_counter()
         return step.toks
 
+    def grant_step(self, slots, n=1):
+        return {}, []        # every slot holds the block it writes
+
     def advance(self, slot):
         pass
 

@@ -542,9 +542,10 @@ def test_a_stack_run_once_says_one_pass():
 
 def test_a_pool_too_small_gates_a_request_and_admits_it_when_blocks_free(tiny):
     """Eight blocks of four tokens under three slots: a request of 9 + 14
-    tokens reserves six, so the second waits for BLOCKS with two slots free
-    (``prefill.gated`` reason ``block``) and is admitted when the first
-    leaves."""
+    tokens holds three and claims six, and with a second one of the kind
+    neither could reach its six, so the second waits for BLOCKS with two
+    slots free (``prefill.gated`` reason ``block``) and is admitted when the
+    first leaves."""
     rs = np.random.RandomState(8)
     prompts = [rs.randint(1, 256, size=9) for _ in range(2)]
     since = time.perf_counter()
@@ -668,6 +669,38 @@ def test_rehearsal_of_the_cell_on_the_cpu(harness, capsys, trace):
         assert held is None or 0 < held["value"] <= 100
     else:
         assert set(res["metrics"]) == {"out_tok_per_s", "setup_s"}
+
+
+def test_rehearsal_reports_the_parked_share(harness, tmp_path):
+    """The rehearsal's cell with ``parked_slot_share`` listed for it, as the
+    repo's ``BENCHMARK.json`` lists it for ``ouro_2_6b.reason_decode_sat``: 20
+    blocks of 4 under 4 slots whose requests claim up to 12, so slots park;
+    the traced run's line carries the share, every request finishes and the
+    check holds as it does with nothing parked."""
+    import shutil
+
+    data = str(tmp_path / "data")
+    shutil.copytree(DATA, data)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[
+            "parked_slot_share"]
+    assert entry["workloads"] == ["ouro_2_6b.reason_decode_sat"]
+    path = os.path.join(data, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["per_layer"].append(dict(entry, workloads=["tiny_ouro.closed"]))
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    since = time.perf_counter()
+    res = harness.run(["--workload", "tiny_ouro.closed", "--seed", "2147483659",
+                       "--seconds", "2", "--trace", "1"],
+                      require_tpu=False, data_dir=data)
+    assert res["correct"] is True and res["failed"] == 0
+    ticks = tracing.lane_log("decode.tick", since=since)
+    assert ticks and all("n_parked" in t for t in ticks)
+    share = res["metrics"].get("parked_slot_share")
+    if share is not None:           # the window held a tick
+        assert share["unit"] == "%" and 0 <= share["value"] < 100
 
 
 def test_the_final_norm_once_is_not_correct(harness, capsys, monkeypatch):

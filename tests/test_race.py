@@ -112,6 +112,9 @@ class _StubMgr:
         self.allocator = _StubAllocator()
         self._left = dict(budgets)     # slot -> decode steps remaining
 
+    def grant_step(self, slots, n=1):
+        return {}, []        # every slot holds the block it writes
+
     def advance(self, slot):
         pass
 

@@ -315,6 +315,45 @@ def test_tokens_and_commit_order_through_the_lanes_follow_the_reference(
     assert most == 1 if which == "seeded" else most > 1
 
 
+def test_a_pool_that_parks_gives_the_block_ticks_tokens_and_commits(tiny):
+    """Blocks granted as a request grows, under the block tick: a pass writes
+    its whole block, so a slot is granted the K/V block that holds it before
+    the block's first pass.  The same requests through a pool at parity and
+    through one a little over the largest maximum: the same tokens and the
+    same commits ``(position, token, pass)`` request by request (a parked
+    slot's pass is left out and made again, its cursor and its block's pass
+    count untouched), parked slots counted in the small pool's lane log and
+    none in the large one's."""
+    net, _weights, _cfg = tiny
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(1, 255, size=n) for n in (1, 3, 8, 14, 17)]
+    max_new = [30, 28, 35, 26, 20]          # maxima of 8 to 11 blocks of 4
+    runs = []
+    for num_blocks in (None, 12):           # parity: 3 slots x 16
+        since = time.perf_counter()
+        with _server(net, num_blocks=num_blocks) as srv:
+            reqs, outs = _generate(srv, prompts, max_new)
+            srv.replicas[0].mgr.check()
+            kv = srv.stats()["kv_cache"]
+        ticks = tracing.lane_log("decode.tick", since=since)
+        runs.append((reqs, outs, ticks, kv))
+    (reqs_w, outs_w, ticks_w, kv_w), (reqs_s, outs_s, ticks_s, kv_s) = runs
+    for p, n, a, b, ra, rb in zip(prompts, max_new, outs_w, outs_s,
+                                  reqs_w, reqs_s):
+        assert len(a) == len(p) + n and a.tolist() == b.tolist()
+        assert ra.commits == rb.commits
+    assert all(t["n_parked"] == 0 for t in ticks_w)
+    assert kv_w["parked_slot_ticks"] == 0
+    assert kv_w["unsafe_refusals"] == {"admit": 0, "grant": 0}
+    parked = sum(t["n_parked"] for t in ticks_s)
+    assert 0 < parked <= kv_s["parked_slot_ticks"]
+    assert all(t["n_active"] >= 1 for t in ticks_s)
+    assert all(t["n_active"] * BL == t["rows"] for t in ticks_s)
+    assert kv_s["peak_blocks_in_use"] <= 12
+    for kv in (kv_w, kv_s):
+        assert kv["admits"] == kv["evictions"] == 5 and kv["grants"] > 0
+
+
 def test_a_request_that_ends_inside_a_block(ref, tiny):
     """Six tokens behind a prompt of 7: the output ends at position 12,
     the first of its block; the request finishes with the pass that

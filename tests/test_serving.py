@@ -387,8 +387,8 @@ def test_generative_paged_lanes_late_join_and_parity():
     assert r2rec["joined_step"] >= base + 2
     assert r2rec["done_step"] < r1rec["done_step"]
     assert r1rec["ttft_ms"] > 0 and r2rec["ttft_ms"] > 0
-    # lane fields: served by replica 0, KV block budget reserved up
-    # front (5+40 tokens -> 3 blocks of 16), handoff measured
+    # lane fields: served by replica 0, the request's claim on the pool
+    # (5+40 tokens -> 3 blocks of 16), handoff measured
     for rec in (r1rec, r2rec):
         assert rec["replica"] == 0
         assert rec["lane"] == "decode"
@@ -399,7 +399,11 @@ def test_generative_paged_lanes_late_join_and_parity():
     assert stats["kv_cache"]["peak_occupancy"] == 2
     assert stats["kv_cache"]["occupancy"] == 0
     assert stats["kv_cache"]["blocks_in_use"] == 0
-    assert stats["kv_cache"]["peak_blocks_in_use"] >= 4
+    # blocks are granted as a request grows: the long one's third comes
+    # after the short one has left with its one
+    assert stats["kv_cache"]["peak_blocks_in_use"] == 3
+    assert stats["kv_cache"]["grants"] == 2
+    assert stats["kv_cache"]["parked_slot_ticks"] == 0
     # ONE decode-step signature for the server lifetime, prefill per
     # prompt bucket
     sigs = stats["compiled_signatures"]
@@ -464,21 +468,23 @@ def test_block_allocator_invariants():
 
 
 def test_paged_manager_admit_advance_evict():
-    """Upfront block reservation sized by prompt+budget; advancing past
-    the reservation raises; eviction returns every block."""
+    """A claim sized by prompt+budget, the prompt's blocks held at
+    admission; advancing past the budget raises; eviction returns every
+    block."""
     from mxnet_tpu.serving import PagedKVCacheManager
 
     mgr = PagedKVCacheManager(num_slots=2, max_len=64, num_blocks=8,
                               block_size=16)
     assert mgr.blocks_for(9, 4) == 1       # 13 tokens -> 1 block
     assert mgr.blocks_for(9, 8) == 2       # 17 tokens -> 2 blocks
-    slot, blocks = mgr.admit("r1", 17, 15)  # 32 tokens -> 2 blocks
+    slot, blocks = mgr.admit("r1", 17, 15)  # 32 tokens: 2 held, 2 at most
     assert len(blocks) == 2
     assert mgr.allocator.blocks_in_use == 2
     for _ in range(15):
+        assert mgr.grant_step([slot]) == ({}, [])
         mgr.advance(slot)
     with pytest.raises(mx.MXNetError):
-        mgr.advance(slot)                  # past the 32-token reserve
+        mgr.advance(slot)                  # past the 32-token budget
     mgr.evict(slot)
     assert mgr.allocator.blocks_in_use == 0
     mgr.check()
@@ -486,6 +492,96 @@ def test_paged_manager_admit_advance_evict():
     assert st["capacity_tokens"] == 8 * 16
     assert st["peak_tokens"] >= 17
     assert st["tokens_in_flight"] == 0
+
+
+# --- blocks granted as a request grows: a pool that parks -----------------------
+
+def _through_a_pool(net, prompts, max_new, num_blocks, lead=0, **cfg):
+    """The requests at once (the first ``lead`` a few steps ahead, so that a
+    prefix cache holds their prompt) through a server with ``num_blocks`` ->
+    (results, the run's ``decode.tick`` records, ``server.stats()``)."""
+    from mxnet_tpu.telemetry import tracing
+
+    since = time.perf_counter()
+    srv = serving.GenerativeServer(net, ServerConfig(
+        max_batch=2, max_length=64, min_length=8, summary_every=1 << 30,
+        num_blocks=num_blocks, **cfg))
+    with srv:
+        futs = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts[:lead], max_new)]
+        deadline = time.time() + 60
+        while lead and srv.engine.steps < 3 and time.time() < deadline:
+            time.sleep(0.01)
+        futs += [srv.submit(p, max_new_tokens=n)
+                 for p, n in zip(prompts[lead:], max_new[lead:])]
+        outs = [f.result(120) for f in futs]
+        srv.replicas[0].mgr.check()
+        stats = srv.stats()
+    return outs, tracing.lane_log("decode.tick", since=since), stats
+
+
+@pytest.mark.parametrize("tick", ["ahead", "speculative", "radix"])
+def test_a_pool_that_parks_gives_the_tokens_of_one_that_never_does(tick):
+    """The same requests, greedy, through a pool at parity (slots x
+    max_blocks: every remaining need fits at once, the rule's one comparison)
+    and through one a little over the largest request's maximum: the same
+    tokens request by request, all finish, nothing is evicted; the small
+    pool's lane log counts parked slots and the large one's none.  The
+    token-at-a-time tick running a step ahead, the speculative tick (a draft
+    that the target rejects, so windows roll back and give blocks back) and a
+    prompt prefix shared through the radix cache."""
+    from mxnet_tpu.models.llama import llama_tiny
+
+    net = llama_tiny()
+    net.initialize()
+    rs = np.random.RandomState(7)
+    lens, max_new, lead = (5, 9, 7, 6, 11), [40, 30, 44, 35, 25], 0
+    cfg = dict(num_slots=3, block_size=4)
+    tight = 16                  # the largest maximum is 13 blocks; parity 48
+    if tick == "speculative":
+        draft = llama_tiny()
+        draft.initialize()      # other weights: the target rejects
+        cfg.update(draft_net=draft, spec_k=3)
+    prompts = [rs.randint(1, 250, size=n) for n in lens]
+    if tick == "radix":
+        system = rs.randint(1, 250, size=20)
+        prompts = [np.concatenate([system, p]) for p in prompts]
+        max_new, lead = [30, 24, 26, 28, 20], 1
+        cfg.update(block_size=8, radix_cache=True)
+        tight = 10              # maxima of 7 and 8 blocks of 8; parity 24
+    wide, ticks_w, stats_w = _through_a_pool(net, prompts, max_new, None,
+                                             lead, **cfg)
+    small, ticks_s, stats_s = _through_a_pool(net, prompts, max_new, tight,
+                                              lead, **cfg)
+    for p, n, a, b in zip(prompts, max_new, wide, small):
+        assert len(a) == len(p) + n
+        assert np.array_equal(a, b)
+    o = net.generate(nd.array(prompts[2][None]), max_new[2]).asnumpy()[0]
+    assert np.array_equal(small[2], o)
+    assert ticks_w and all(t["n_parked"] == 0 for t in ticks_w)
+    assert sum(t["n_parked"] for t in ticks_s) > 0
+    assert all(t["n_active"] >= 1 for t in ticks_s)
+    kv_w, kv_s = stats_w["kv_cache"], stats_s["kv_cache"]
+    assert kv_w["parked_slot_ticks"] == 0
+    assert kv_w["unsafe_refusals"] == {"admit": 0, "grant": 0}
+    assert kv_s["parked_slot_ticks"] >= sum(t["n_parked"] for t in ticks_s)
+    assert kv_s["unsafe_refusals"]["grant"] == kv_s["parked_slot_ticks"]
+    assert kv_s["peak_blocks_in_use"] <= tight
+    for kv in (kv_w, kv_s):
+        # every request admitted once and finished: nothing evicted for room
+        assert kv["admits"] == kv["evictions"] == len(prompts)
+        assert kv["grants"] > 0 and kv["occupancy"] == 0
+    assert stats_s["lanes"][0]["parked_slot_ticks"] \
+        == kv_s["parked_slot_ticks"]
+    assert stats_s["completed"] == len(prompts) and stats_s["failed"] == 0
+    if tick == "ahead":
+        assert any(t["ahead"] for t in ticks_s)
+    elif tick == "speculative":
+        assert stats_s["speculative"]["draft_tokens"] \
+            > stats_s["speculative"]["accepted_tokens"]
+    else:
+        assert stats_s["radix_cache"]["hits"] >= 3
+        assert kv_s["peak_shared_blocks"] >= 2
 
 
 def test_legacy_ledger_stats_fields():
@@ -635,9 +731,9 @@ def test_paged_manager_prices_blocks_and_slot_state(layers, state_shape):
                               kv_bytes_per_block=per_block,
                               state_bytes_per_slot=per_slot)
     mgr.admit("a", 5, 3)          # 2 blocks
-    mgr.admit("b", 9, 4)          # 4 blocks
+    mgr.admit("b", 9, 4)          # 3 blocks held, 4 at most
     st = mgr.stats()
-    assert st["kv_block_bytes_in_use"] == 6 * per_block
+    assert st["kv_block_bytes_in_use"] == 5 * per_block
     assert st["state_bytes_per_slot"] == per_slot
     assert st["state_bytes_in_use"] == 2 * per_slot
     mgr.evict(mgr.active_slots()[0])

@@ -68,17 +68,22 @@ def test_paged_truncate_releases_tail_blocks():
     slot, blocks = m.admit("r1", prompt_len=10, max_new_tokens=20)
     st = m.state(slot)
     st.pos = 10                            # prefill wrote the prompt
-    assert st.reserved == 30 and len(blocks) == 8
-    for _ in range(5):
-        m.advance(slot)
+    assert st.reserved == 30 and len(blocks) == 3   # of 8 at most
+    # a window of 5 writes: rows 10..14 end in the fourth block
+    grants, parked = m.grant_step([slot], 5)
+    assert grants == {slot: (3, st.blocks[3:])} and not parked
+    assert len(st.blocks) == 4
+    m.advance_n(slot, 5)
     for _ in range(5):
         m.consume(slot)
-    # 15 tokens remain owed; rolling back to pos 12 shrinks the
-    # reservation to 12 + 15 = 27 tokens = 7 blocks: one block frees
+    # 15 tokens remain owed; rolling back to pos 12 shrinks the budget
+    # to 12 + 15 = 27 tokens, the claim to 7 blocks, and the block that
+    # held rejected rows only (12..14 are in the fourth) goes back
     freed = m.truncate(slot, 12)
     assert len(freed) == 1
-    assert st.pos == 12 and st.reserved == 27 and len(st.blocks) == 7
-    assert m.allocator.free_blocks == 9
+    assert st.pos == 12 and st.reserved == 27 and len(st.blocks) == 3
+    assert m._claim(st) == 7
+    assert m.allocator.free_blocks == 13
     m.check()
     with pytest.raises(mx.MXNetError):
         m.truncate(slot, 13)               # cannot truncate forward
@@ -91,6 +96,10 @@ def test_paged_advance_n_respects_reservation():
                             block_size=4)
     slot, _ = m.admit("r1", prompt_len=4, max_new_tokens=4)
     m.state(slot).pos = 4
+    with pytest.raises(mx.MXNetError, match="wrote past the 1 blocks"):
+        m.advance_n(slot, 4)               # no grant before the writes
+    m.state(slot).pos = 4
+    m.grant_step([slot], 4)
     m.advance_n(slot, 4)                   # up to reserved is fine
     with pytest.raises(mx.MXNetError):
         m.advance_n(slot, 1)               # past the reservation raises
