@@ -278,6 +278,30 @@ def block_commit(logits, ids, masked, step, decoding):
         return jnp.where(commit, x0, ids), commit
 
 
+def block_advance(xp, state, ids, commit, stepped, decoding):
+    """A block decoder's bookkeeping of one pass, the same lines on the
+    device (``xp`` ``jax.numpy``: inside the pass's own program, so the
+    next pass can be queued before this one is fetched) and on the host
+    (``numpy``: the engine's mirrors, a pass late).  ``state``: ``(ids
+    (S, B), masked (S, B), step (S,), pos0 (S,))`` as the pass read
+    them; ``ids`` / ``commit`` what :func:`block_commit` made of it;
+    ``stepped`` (S,) the rows the pass was made for.  A stepped block
+    that held no mask has had the pass that leaves its keys and values:
+    its cursor moves a block on, a block of masks opens, the count
+    starts again.  Any other stepped block takes the pass's ids, loses
+    the masks it committed and counts a pass.  A row not stepped is
+    left as it was.  -> the state the next pass reads."""
+    held, masked, step, pos0 = state
+    stored = stepped & ~masked.any(axis=1)
+    going = stepped & ~stored
+    return (xp.where(stored[:, None], decoding.mask_id,
+                     xp.where(going[:, None], ids, held)),
+            xp.where(stored[:, None], True,
+                     masked & ~(commit & going[:, None])),
+            xp.where(stored, 0, step + going),
+            xp.where(stored, pos0 + decoding.block_len, pos0))
+
+
 # -- cache views ------------------------------------------------------------------
 
 class Causal:

@@ -195,6 +195,11 @@ _BLOCK_REFUSALS = {
             "of a block in any order",
     "mesh": _STATE_REFUSALS["mesh"],
     "int8": _STATE_REFUSALS["int8"],
+    "state": "a block decoder's pass is made again for a slot that was "
+             "left out of the booking (parked, or carried a pass past "
+             "its request's end: the tick runs a pass ahead), which a "
+             "per-slot state layer's step, not idempotent, does not "
+             "allow",
 }
 
 
@@ -236,9 +241,14 @@ _LOOP_REFUSALS = {
 
 class BlockTick(NamedTuple):
     """What one pass of a block-decoding engine did, slot by slot
-    (``LlamaServingEngine.step`` of such an engine), vacant and
-    unadopted slots reading as nothing done."""
+    (``LlamaServingEngine.step`` of such an engine), every slot but
+    ``live`` reading as nothing done."""
 
+    #: (S,) the slots the pass is booked for: those it was made for,
+    #: but for a slot the host has written since the pass was queued
+    #: (its request ended with the pass before, which had not been
+    #: fetched then: the row was computed and is booked for nobody)
+    live: np.ndarray
     pos0: np.ndarray     #: (S,) each block's first position
     ids: np.ndarray      #: (S, B) what the blocks hold after the pass
     commit: np.ndarray   #: (S, B) the positions this pass decided
@@ -258,11 +268,11 @@ class StepHandle:
 
     __slots__ = ("seq", "active", "toks", "selected", "behind", "ahead",
                  "t_lock", "t_disp0", "t_disp1", "t_tok", "pos",
-                 "kv_tokens", "selection", "experts")
+                 "kv_tokens", "selection", "experts", "writes")
 
     def __init__(self, seq, active, toks, t_lock, t_disp0, t_disp1,
                  behind=(), ahead=False, selected=None, pos=None,
-                 kv_tokens=0, selection=None):
+                 kv_tokens=0, selection=None, writes=None):
         self.seq = seq            #: ``engine.steps`` as it was dispatched
         self.active = active      #: the slots it advances
         self.toks = toks          #: what the host fetches, on the device
@@ -288,6 +298,9 @@ class StepHandle:
         #: ``experts_touched`` / ``expert_rows_max`` / ``expert_rows_mean``
         #: of a model that routes, fetched behind the tokens
         self.experts = {}
+        #: a block decoder's pass: the engine's count of the host's
+        #: writes to each slot's row as it was queued (``_book_block``)
+        self.writes = writes
 
 
 class LlamaServingEngine:
@@ -340,6 +353,8 @@ class LlamaServingEngine:
                              ("int8", self.int8)):
                 if bad:
                     raise MXNetError(why[key])
+            if block is not None and spec.state_layers:
+                raise MXNetError(_BLOCK_REFUSALS["state"])
         w = dec._weights()
         self._w = _quantize_tree(w) if self.int8 else w
         deq = _dequantize_tree if self.int8 else (lambda t: t)
@@ -423,16 +438,21 @@ class LlamaServingEngine:
             self._blk_ids = np.zeros((self.num_slots, bl), np.int32)
             self._blk_masked = np.zeros((self.num_slots, bl), bool)
             self._blk_step = np.zeros(self.num_slots, np.int32)
+            #: how often the host has written each slot's row (an
+            #: admission's commit, ``clear_slot``): a pass whose slot
+            #: was written after it was queued is booked for nobody
+            self._writes = np.zeros(self.num_slots, np.int64)
             #: passes that stored blocks took, blocks stored, tokens
             #: committed: over every tick (``server.stats()``)
             self.block_totals = {"block_passes": 0, "blocks_committed": 0,
                                  "committed_tokens": 0}
-        #: which slots' ``_last`` the host wrote (a prefill's commit,
-        #: ``set_mirror``, ``clear_slot``) since a step last advanced
-        #: them, and which the last step left out (its vacant row's
-        #: output stands where their token did): a slot's next input is
-        #: the token the step before produced, still on the device, and
-        #: the host's where this says so (every slot to start with)
+        #: which slots' ``_last`` (a block decoder: row of the block
+        #: mirrors) the host wrote (a prefill's commit, ``set_mirror``,
+        #: ``clear_slot``) since a step last advanced them, and which
+        #: the last step left out (its vacant row's output stands where
+        #: their token did): a slot's next input is what the step before
+        #: produced, still on the device, and the host's where this says
+        #: so (every slot to start with)
         self._fresh = np.ones(self.num_slots, bool)
         self.steps = 0
         #: the :class:`StepHandle` of the last step()/verify() whose
@@ -555,14 +575,30 @@ class LlamaServingEngine:
                 return _tokens(logits, out), rows
 
             if block is not None:
-                from ..models.decoder import block_commit
+                from ..models.decoder import block_advance, block_commit
 
-                def _step_fn(wq, pools, tables, ids, pos0, masked, nstep):
+                def _step_fn(wq, pools, tables, carried, host):
                     # one pass over every slot's block: (S, B) ids in,
                     # the block's K/V written in place, the commit rule
                     # on the device; out go the ids after the pass and
                     # what it committed.  A block without masks commits
-                    # nothing: its pass is the one whose K/V stays
+                    # nothing: its pass is the one whose K/V stays.
+                    # What a block holds, its masks, its pass count and
+                    # its cursor are the pass before's own (``carried``,
+                    # its last output); the host's row (``host``: the
+                    # same four, then ``fresh``, then ``stepped``) where
+                    # it wrote the slot since.  The pass books itself:
+                    # out goes, last, what the next one reads
+                    bl = block.block_len
+                    rows = (host[:, :bl], host[:, bl:2 * bl],
+                            host[:, 2 * bl], host[:, 2 * bl + 1])
+                    fresh, stepped = (host[:, 2 * bl + 2] != 0,
+                                      host[:, 2 * bl + 3] != 0)
+                    state = tuple(
+                        jnp.where(fresh[:, None] if c.ndim > 1 else fresh,
+                                  h.astype(c.dtype), c)
+                        for h, c in zip(rows, carried))
+                    ids, masked, nstep, pos0 = state
                     out = dec._verify_blocks_impl(
                         deq(wq), pools, tables, ids, pos0,
                         paged_kernel=paged_kernel)
@@ -571,9 +607,11 @@ class LlamaServingEngine:
                                                block)
                     tok = _behind(jnp.concatenate(
                         [ids, commit.astype(jnp.int32)], axis=1), out)
+                    nxt = block_advance(jnp, state, ids, commit, stepped,
+                                        block)
                     if numerics_on:
-                        return tok, pools, _numerics.stats_of(logits)
-                    return tok, pools
+                        return tok, pools, _numerics.stats_of(logits), nxt
+                    return tok, pools, nxt
 
                 def _prefill_fn(wq, ids, t0):
                     # the prompt's whole blocks only, and no token: what
@@ -666,10 +704,22 @@ class LlamaServingEngine:
         #: id from the host; committed to the weights' device as a step's
         #: output is, so the second step is the first's compiled program)
         self._toks = None
-        if block is None:
-            zeros = np.zeros(self.num_slots + self._n_counts, np.int32)
-            self._toks = self._dev(zeros) if mesh is not None else \
+
+        def born(zeros):
+            return self._dev(zeros, zeros.dtype) if mesh is not None else \
                 jax.device_put(zeros, next(iter(w["emb"].devices())))
+
+        if block is None:
+            self._toks = born(np.zeros(self.num_slots + self._n_counts,
+                                       np.int32))
+        else:
+            #: the last pass's own booking, on the device: what the
+            #: blocks hold, their masks, pass counts and cursors as the
+            #: next pass reads them (``block_advance``; the mirrors'
+            #: shapes, and like them zeros before the first pass, which
+            #: takes every row from the host)
+            self._blk_dev = tuple(born(np.zeros_like(m)) for m in (
+                self._blk_ids, self._blk_masked, self._blk_step, self._pos))
         self._prefill = jax.jit(_prefill_fn)
         self._scatter = jax.jit(_scatter_fn, donate_argnums=(0,))
         if kv_mode == "paged":
@@ -993,9 +1043,9 @@ class LlamaServingEngine:
                     blocks = block_lists[i]
                     row[:len(blocks)] = blocks
                     self._tables[s] = row
+                    self._fresh[s] = True
                     if self.block is None:
                         self._last[s] = first[i]
-                        self._fresh[s] = True
                         self._pos[s] = t0s[i]
                     else:
                         # the cursor stands at the prompt's last whole
@@ -1005,6 +1055,7 @@ class LlamaServingEngine:
                         self._blk_ids[s] = first[i]
                         self._blk_masked[s] = np.arange(bl) >= t0s[i] % bl
                         self._blk_step[s] = 0
+                        self._writes[s] += 1
         return t_lock, time.perf_counter()
 
     def gather_prefix(self, rows_idx):
@@ -1056,8 +1107,11 @@ class LlamaServingEngine:
     def drop_steps(self):
         """The decode lane gives up the steps it has queued and not
         fetched (a turn of it raised, and it releases every slot they
-        advanced): none is in flight for the prefill lane to see."""
+        advanced): none is in flight for the prefill lane to see, and
+        no slot's next input is what they left on the device."""
         self.step_in_flight = None
+        with self.dev_lock:
+            self._fresh[:] = True
 
     def step(self, active):
         """One decode step over ALL slots; returns the (num_slots,)
@@ -1088,13 +1142,21 @@ class LlamaServingEngine:
         has been fetched by then: the lane is at most one step ahead).
         So the next step
         can be queued before this one's tokens have reached the host.
+        A block decoder's pass is carried the same way: what each block
+        holds, its masks, its pass count and its cursor are the pass
+        before's own booking, on the device, and the host's row of the
+        mirrors under the same ``_fresh``.  Its table rows go up whole
+        (a slot held and left out is computed and booked for nobody, its
+        writes idempotent), and so a pass may carry a slot whose request
+        the pass before it ended, which nobody knew as it was queued:
+        :meth:`_book_block` books that row for nobody.
         The device runs programs in the order they were queued, and both
         lanes queue what touches the pool under ``dev_lock`` on the
         array the last such program returned: a commit's scatter into
         blocks that a finished slot gave back runs behind every step
         that was queued while the slot still held them."""
         self._note(("step",))
-        lstats = selected = pos = None
+        lstats = selected = pos = writes = None
         kv_tokens, selection = 0, {}
         act = np.asarray(active, np.intp)
         t_lock = time.perf_counter()
@@ -1104,12 +1166,12 @@ class LlamaServingEngine:
                                  replica=self.replica_id):
                 # (tokens, storage[, logit stats under numerics])
                 if self.block is not None:
-                    out = self._step(
-                        self._w, self._pool, self._dev(self._tables),
-                        self._dev(self._blk_ids), self._dev(self._pos),
-                        self._dev(self._blk_masked, bool),
-                        self._dev(self._blk_step))
-                    self._pool = out[1]
+                    out = self._step(self._w, self._pool,
+                                     *self._block_args(act))
+                    self._pool, self._blk_dev = out[1], out[-1]
+                    self._fresh[:] = True
+                    self._fresh[act] = False
+                    writes = self._writes.copy()
                 else:
                     ids = self._dev(np.where(self._fresh, self._last,
                                              np.int32(-1)))
@@ -1152,7 +1214,27 @@ class LlamaServingEngine:
             _numerics.record_compiled(("serving.logits",), (lstats,))
         return StepHandle(seq, act, out[0], t_lock, t_disp0, t_disp1,
                           behind=behind, ahead=ahead, selected=selected,
-                          pos=pos, kv_tokens=kv_tokens, selection=selection)
+                          pos=pos, kv_tokens=kv_tokens, selection=selection,
+                          writes=writes)
+
+    def _block_args(self, act):
+        """What a block pass over the slots ``act`` takes behind the
+        weights and the pool: the tables (a copy: a grant may write the
+        mirror while the upload reads), the pass before's own booking,
+        and one ``(S, 2B + 4)`` array of the host's: each slot's row of
+        the four mirrors, whether the pass takes it (``_fresh``) and
+        whether the pass is made for the slot."""
+        bl = self.block.block_len
+        host = np.zeros((self.num_slots, 2 * bl + 4), np.int32)
+        host[act, 2 * bl + 3] = 1
+        with self.dev_lock:       # re-entrant: the dispatch holds it
+            host[:, :bl] = self._blk_ids
+            host[:, bl:2 * bl] = self._blk_masked
+            host[:, 2 * bl] = self._blk_step
+            host[:, 2 * bl + 1] = self._pos
+            host[:, 2 * bl + 2] = self._fresh
+            return (self._dev(self._tables.copy()), self._blk_dev,
+                    self._dev(host))
 
     def fetch_step(self, step):
         """The half of :meth:`step` that waits: ``step``'s tokens to the
@@ -1177,32 +1259,31 @@ class LlamaServingEngine:
     def _book_block(self, out, step):
         """A block pass's fetched ``(S, 2B)`` (ids, then what was
         committed) into the mirrors of ``step``'s slots -> the
-        :class:`BlockTick`.  A slot whose block held no mask has had the
-        pass that leaves the block's K/V: its cursor moves a block on
-        and a block of masks opens; any other takes the pass's commits
-        and goes to its next denoising pass."""
+        :class:`BlockTick`: the lines the pass ran on the device for
+        the pass behind it (``block_advance``), a pass late, for the
+        lane's bookkeeping.  A slot the host has written since the pass
+        was queued (released, or admitted into again) is left out: the
+        mirrors there are another request's, or nobody's."""
+        from ..models.decoder import block_advance
+
         bl = self.block.block_len
-        act = step.active
         with self.dev_lock:
-            mine = np.zeros(self.num_slots, bool)
-            mine[act] = True
-            tick = BlockTick(self._pos.copy(), out[:, :bl],
-                             out[:, bl:].astype(bool) & mine[:, None],
+            live = np.zeros(self.num_slots, bool)
+            live[step.active] = True
+            live &= step.writes == self._writes
+            tick = BlockTick(live, self._pos.copy(), out[:, :bl],
+                             out[:, bl:].astype(bool) & live[:, None],
                              self._blk_step.copy(),
-                             mine & ~self._blk_masked.any(axis=1))
-            done, going = act[tick.stored[act]], act[~tick.stored[act]]
-            self._pos[done] += bl
-            self._blk_ids[done] = self.block.mask_id
-            self._blk_masked[done] = True
-            self._blk_step[done] = 0
-            self._blk_ids[going] = tick.ids[going]
-            self._blk_masked[going] &= ~tick.commit[going]
-            self._blk_step[going] += 1
+                             live & ~self._blk_masked.any(axis=1))
+            (self._blk_ids, self._blk_masked, self._blk_step,
+             self._pos) = block_advance(
+                np, (self._blk_ids, self._blk_masked, tick.step, tick.pos0),
+                tick.ids, tick.commit, live, self.block)
             # every column of a block attends to the block's end
-            step.kv_tokens = int(tick.pos0[act].sum()) + bl * len(act)
+            step.kv_tokens = int(tick.pos0[live].sum()) + bl * int(live.sum())
             tot = self.block_totals
-            tot["block_passes"] += int(tick.step[done].sum()) + len(done)
-            tot["blocks_committed"] += len(done)
+            tot["block_passes"] += int((tick.step[tick.stored] + 1).sum())
+            tot["blocks_committed"] += int(tick.stored.sum())
             tot["committed_tokens"] += int(tick.commit.sum())
         return tick
 
@@ -1295,6 +1376,7 @@ class LlamaServingEngine:
                 self._blk_ids[slot] = 0
                 self._blk_masked[slot] = False
                 self._blk_step[slot] = 0
+                self._writes[slot] += 1
 
 
 class GenerativeScheduler:

@@ -25,7 +25,7 @@ connected by an explicit KV handoff:
   next).  It
   never sees a prompt forward: while a long prompt prefills, decode
   ticks keep dispatching (the device lock covers only the KV-mutating
-  dispatches, not the prefill compute).  The token-at-a-time tick
+  dispatches, not the prefill compute).  The tick
   (:meth:`DecodeLane._tick`) runs ONE STEP AHEAD of its bookkeeping: a
   turn adopts, queues step K+1 and only then fetches and books step K
   (the engine's ``dispatch_step`` / ``fetch_step``: tokens pass from
@@ -34,10 +34,14 @@ connected by an explicit KV handoff:
   or is about to (``_prefill_covers``), that forward is what the device
   runs meanwhile and step K+1 is queued behind it.
   For a model that decodes by blocks (the engine's ``block``: what its
-  decoder's cache spec says) the tick is :meth:`DecodeLane._tick_block`:
-  one pass a slot's block, 0 to a block's length of tokens committed a
-  slot in any order of position, the cursor moved only by the pass over
-  a finished block; the prefill lane then hands over no first token.
+  decoder's cache spec says) a step is one pass a slot's block, queued
+  ahead the same way (what a block holds, its masks, pass count and
+  cursor pass from pass to pass on the device) and booked by
+  :meth:`DecodeLane._book_blocks`: 0 to a block's length of tokens
+  committed a slot in any order of position, the cursor moved only by
+  the pass over a finished block; the prefill lane then hands over no
+  first token.  The speculative tick (:meth:`DecodeLane._tick_spec`)
+  books its own step.
 * :class:`Replica` — one engine + manager + lane pair over one (tp)
   submesh.  A dp mesh axis becomes N independent replicas behind one
   front queue, routed by :class:`ReplicaDispatcher` to the
@@ -647,19 +651,19 @@ class DecodeLane:
     def _run(self):
         spec = self.r.spec_k > 0 and self.r.draft is not None
         tick = self._tick_spec if spec else self._tick
-        if getattr(self.r.engine, "block", None) is not None:
-            tick = self._tick_block
         while True:
-            if self.pending():
+            if self.pending() or self._flight is not None:
                 # one turn: adopt, then advance every slot one tick (a
                 # slot stays in _seqs until its last step is booked, so
-                # a step queued and not yet booked keeps the lane turning)
+                # a step queued and not yet booked keeps the lane turning;
+                # a block pass may carry none but slots whose requests
+                # the pass before it ended, and is fetched all the same)
                 # ``seq``: the step the turn queues; ``books``: the one
                 # whose tokens it fetches and books (the same step, but
                 # for the tick that runs ahead: the step in flight, 0
                 # where none is)
                 seq = books = self.r.engine.steps + 1
-                if tick == self._tick:
+                if not spec:
                     books = self._flight.step.seq if self._flight else 0
                 with TraceAnnotation("mxt.decode.tick", seq=seq,
                                      replica=self.r.index, books=books):
@@ -770,8 +774,8 @@ class DecodeLane:
         self._wake.clear()
 
     def _serial_step(self, n):
-        """What a tick that books its own step (the block tick, the
-        speculative tick) steps: every slot the lane holds that is
+        """What a tick that books its own step (the speculative tick)
+        steps: every slot the lane holds that is
         granted the blocks of its next ``n`` writes -> (slots, their
         requests' ids, slots parked, the hand-offs whose first step
         this is), or None where every slot is parked: the turn has
@@ -836,21 +840,33 @@ class DecodeLane:
             and r.prefill.gate != "block"
 
     def _tick(self):
-        """A turn of the token-at-a-time lane, one step ahead of its
-        bookkeeping: queue step K+1, then fetch and book step K, so a
-        step is on the device's queue while the host works.  Step K+1
-        needs nothing of step K's that the host does not know already:
-        its tokens pass from step to step on the device
-        (``engine.dispatch_step``), the block a write lands in is
-        granted from the cursor the host already has (:meth:`_grant`: a
-        slot refused is parked, left out of step K+1 and tried again
-        for K+2, its token then the host's), a
-        cursor moves by one and a request ends by count.  So the
+        """A turn of the lane, one step ahead of its bookkeeping: queue
+        step K+1, then fetch and book step K, so a step is on the
+        device's queue while the host works.  Step K+1 needs nothing of
+        step K's that the host does not know already: its tokens pass
+        from step to step on the device (``engine.dispatch_step``), the
+        block a write lands in is granted from the cursor the host
+        already has (:meth:`_grant`: a slot refused is parked, left out
+        of step K+1 and tried again for K+2, its token then the host's),
+        a cursor moves by one and a request ends by count.  So the
         manager's count is taken as a step is QUEUED: a slot whose last
         token step K produces is left out of step K+1 (it runs vacant
         there) and its request is finished, and the slot released, when
-        step K's tokens are booked.  A hand-off adopted in a turn rides
-        the step that turn queues, or the next one queued.  A lane's first turn, and the first
+        step K's tokens are booked.
+
+        A block decoder's pass (``engine.block``) is queued the same
+        way, its blocks' ids, masks, pass counts and cursors carried on
+        the device.  A block's progress depends on data, so two things
+        differ.  The cursor may move a block on in the pass not yet
+        fetched: a slot is granted the blocks of two blocks' writes
+        behind the manager's cursor, a pass late.  And a request ends
+        with the pass that commits the last of its positions, which
+        nobody knows as pass K+1 is queued: that pass may carry a slot
+        that pass K finished, a row computed and booked for nobody
+        (``BlockTick.live``), the slot released as pass K is booked.
+
+        A hand-off adopted in a turn rides the step that turn queues, or
+        the next one queued.  A lane's first turn, and the first
         after it stood empty, books nothing; the turn that finds nothing
         more to step queues nothing, and so does a turn that finds the
         prefill lane about to use the device when step K ends
@@ -858,13 +874,15 @@ class DecodeLane:
         behind that forward."""
         r = self.r
         eng = r.engine
+        block = getattr(eng, "block", None)
         prev, self._flight = self._flight, None
         with self._hand_lock:
             active = [s for s in sorted(self._seqs)
                       if prev is None or s not in prev.ending]
         if prev is not None and self._prefill_covers(prev.step):
             active = ()
-        active, n_parked = self._grant(active)
+        active, n_parked = self._grant(
+            active, 1 if block is None else 2 * block.block_len)
         with self._hand_lock:
             ids = tuple(self._seqs[s][0].id for s in active)
         adopted, self._unstepped = self._unstepped + self._adopted, ()
@@ -875,10 +893,11 @@ class DecodeLane:
             if active:
                 step = eng.dispatch_step(active)
                 ending = set()
-                for slot in active:
-                    r.mgr.advance(slot)   # the step writes K/V at its pos
-                    if r.mgr.consume(slot):
-                        ending.add(slot)
+                if block is None:
+                    for slot in active:
+                        r.mgr.advance(slot)   # the step writes K/V at its pos
+                        if r.mgr.consume(slot):
+                            ending.add(slot)
                 self._flight = _Flight(step, self._t_loop, ids,
                                        frozenset(ending), adopted,
                                        n_parked)
@@ -892,7 +911,7 @@ class DecodeLane:
                 if n_parked and not active:
                     self._rest()
                 return
-            toks = eng.fetch_step(prev.step)
+            out = eng.fetch_step(prev.step)
         except Exception as exc:
             # both steps' requests are in _seqs, each once
             self._flight, self._unstepped = None, ()
@@ -903,6 +922,20 @@ class DecodeLane:
         # the device was the step's from its dispatch or, run ahead, from
         # the tokens of the step before it
         t_busy0, self._t_tok = max(step.t_disp0, self._t_tok), step.t_tok
+        book = self._book_tokens if block is None else self._book_blocks
+        ids, n_finished, extra = book(prev, out, t_busy0)
+        self._record_tick(step, ids, n_finished, prev.adopted,
+                          queued_at=prev.t_loop,
+                          turn=(self._t_loop, t_lock, t_disp0, t_disp1),
+                          n_parked=prev.parked, **extra,
+                          **step.experts, **step.selection)
+
+    def _book_tokens(self, flight, toks, t_busy0):
+        """Step ``flight``'s tokens, one a slot, to their requests ->
+        (the record's ``request_ids``, requests finished, no further
+        field)."""
+        r = self.r
+        step = flight.step
         self._note_tick(step.active, t_busy0, step.t_tok)
         n_finished = 0
         with TraceAnnotation("mxt.decode.book", seq=step.seq,
@@ -920,60 +953,46 @@ class DecodeLane:
                     req.trace.add("decode.step", t_busy0, step.t_tok,
                                   step=step.seq, batch=len(step.active),
                                   replica=r.index, slot=slot)
-                if slot in prev.ending:
+                if slot in flight.ending:
                     with self._hand_lock:
                         del self._seqs[slot]
                     if step.selected is not None:
                         # the step's query stood one before the cursor
                         req.selected = (int(step.pos[slot]) - 1,
-                                        eng.selection_of(slot, step))
+                                        r.engine.selection_of(slot, step))
                     r.finish(req, tokens, step=step.seq)
                     n_finished += 1
-        self._record_tick(step, prev.ids, n_finished, prev.adopted,
-                          queued_at=prev.t_loop,
-                          turn=(self._t_loop, t_lock, t_disp0, t_disp1),
-                          n_parked=prev.parked,
-                          **step.experts, **step.selection)
+        return flight.ids, n_finished, {}
 
-    def _tick_block(self):
-        """A block decoder's tick: one pass over every active slot's
-        block (``engine.step`` -> ``generative.BlockTick``).  A slot
-        commits 0 to a block's length of tokens, in any order of
-        position; its cursor, and the manager's, move only when the
-        pass was the one over its finished block.  A request's output
-        is the tokens at its first ``max_new_tokens`` positions behind
-        the prompt: it ends with the pass that commits the last of
-        them, wherever in a block that is.  ``req.commits`` keeps every
-        commit ``(position, token, the block's pass)``, those past the
-        output's end too: what each pass saw can be rebuilt from it."""
+    def _book_blocks(self, flight, tick, t_busy0):
+        """A block pass's ``generative.BlockTick`` to the requests of
+        its live slots -> (their ids, requests finished, the record's
+        block fields, which count the live rows alone).  A slot commits
+        0 to a block's length of tokens, in any order of position; its
+        cursor, and the manager's, move only when the pass was the one
+        over its finished block.  A request's output is the tokens at
+        its first ``max_new_tokens`` positions behind the prompt: it
+        ends with the pass that commits the last of them, wherever in a
+        block that is.  ``req.commits`` keeps every commit ``(position,
+        token, the block's pass)``, those past the output's end too:
+        what each pass saw can be rebuilt from it."""
         r = self.r
-        eng = r.engine
-        bl = eng.block.block_len
-        # a pass writes its whole block's K/V, the first pass too
-        taken = self._serial_step(bl)
-        if taken is None:
-            return
-        active, ids, n_parked, adopted = taken
-        try:
-            tick = eng.step(active)
-        except Exception as exc:
-            self._abort(exc)
-            return
-        step = eng.booked
-        t_disp0, t_tok = step.t_disp0, step.t_tok
-        self._note_tick(active, t_disp0, t_tok)
-        step_idx = step.seq
+        step = flight.step
+        bl = r.engine.block.block_len
+        live = [(int(s), rid) for s, rid in zip(step.active, flight.ids)
+                if tick.live[s]]
+        self._note_tick(live, t_busy0, step.t_tok)
         n_finished = 0
-        with TraceAnnotation("mxt.decode.book", seq=step_idx,
+        with TraceAnnotation("mxt.decode.book", seq=step.seq,
                              replica=r.index):
-            for slot in active:
+            for slot, _rid in live:
                 with self._hand_lock:
                     req, tokens = self._seqs[slot]
                 if req.first_tick is None:
-                    req.first_tick = step_idx
+                    req.first_tick = step.seq
                 if req.trace is not None:
-                    req.trace.add("decode.step", t_disp0, t_tok,
-                                  step=step_idx, batch=len(active),
+                    req.trace.add("decode.step", t_busy0, step.t_tok,
+                                  step=step.seq, batch=len(live),
                                   replica=r.index, slot=slot)
                 if tick.stored[slot]:
                     # the block's K/V stays: the cursor is past it
@@ -991,20 +1010,20 @@ class DecodeLane:
                     if first + j < req.max_new_tokens:
                         tokens[first + int(j)] = tok
                         if req.t_first is None:
-                            req.t_first = t_tok
+                            req.t_first = step.t_tok
                         done = r.mgr.consume(slot) or done
                 if done:
                     with self._hand_lock:
                         del self._seqs[slot]
                     r.finish(req, [tokens[i]
-                                   for i in range(req.max_new_tokens)])
+                                   for i in range(req.max_new_tokens)],
+                             step=step.seq)
                     n_finished += 1
-        self._record_tick(step, ids, n_finished, adopted,
-                          block_len=bl, rows=len(active) * bl,
-                          n_store=int(tick.stored.sum()),
-                          committed=int(tick.commit.sum()),
-                          block_passes=int((tick.step[tick.stored] + 1).sum()),
-                          n_parked=n_parked, **step.experts)
+        return tuple(rid for _s, rid in live), n_finished, dict(
+            block_len=bl, rows=len(live) * bl,
+            n_store=int(tick.stored.sum()),
+            committed=int(tick.commit.sum()),
+            block_passes=int((tick.step[tick.stored] + 1).sum()))
 
     def _record_tick(self, step, ids, n_finished, adopted, queued_at=None,
                      turn=None, **extra):

@@ -1,15 +1,16 @@
-"""The token-at-a-time decode tick one step ahead of its bookkeeping
-(``serving/lanes.py`` ``DecodeLane._tick`` over the two halves of
-``LlamaServingEngine.step``): step K+1 is queued from the device's own tokens
-before the host has fetched step K's.
+"""The decode tick one step ahead of its bookkeeping (``serving/lanes.py``
+``DecodeLane._tick`` over the two halves of ``LlamaServingEngine.step``): step
+K+1 is queued from the device's own tokens before the host has fetched step
+K's; a block decoder's pass K+1 from the blocks, masks, pass counts and
+cursors that pass K booked for itself on the device.
 
-Held here, on tiny models on the CPU: a request's tokens are the serial
-``step()`` loop's, token for token, whatever finishes, is admitted or takes
-over a freed slot's blocks while a step is queued; an exception in either half
-fails each request once and frees every slot; the lane log still means what
-the benchmark's readers (``chipbench/lane_spans.py``, ``turn_spans.py``,
-imported as they stand) take it to mean; and the block and verify programs,
-whose ticks stay serial, lower as before.
+Held here, on tiny models on the CPU: a request's tokens (a block decoder's
+commits too) are the serial ``step()`` loop's, token for token, whatever
+finishes, is admitted or takes over a freed slot's blocks while a step is
+queued; an exception in either half fails each request once and frees every
+slot; the lane log still means what the benchmark's readers
+(``chipbench/lane_spans.py``, ``turn_spans.py``, imported as they stand) take
+it to mean; and the verify program, whose tick stays serial, lowers as before.
 """
 import hashlib
 import os
@@ -91,7 +92,8 @@ def _commit(eng, slot, prompt, blocks):
     toks, rows = eng.prefill_rows(ids, t0s)
     first, _counts = eng.split_fetch(np.asarray(toks), 1)
     eng.commit_rows(rows, np.asarray([slot]), [blocks], t0s, first)
-    return int(first[0])
+    # a block decoder's prefill yields the block it opens, not a token
+    return int(first[0]) if first.ndim == 1 else first[0]
 
 
 def _blocks(eng, slot):
@@ -649,48 +651,430 @@ def test_the_counters_and_the_summary_say_the_share(monkeypatch):
     assert emitted[-1]["steps_ahead_share"] == 0.9
 
 
-# --- the ticks that stay serial -------------------------------------------------
+# --- the tick that stays serial -------------------------------------------------
 
-#: sha256 (16 hex digits) of the lowered text of the tiny SDAR's block pass
-#: and the tiny Llama's verify program on the commit before the tick ran
-#: ahead (PR 38, e24d6dc): ``_tick_block`` and ``_tick_spec`` call
-#: ``step()`` / ``verify()`` as before, and their programs are the parent's
-#: letter for letter.  (The token-at-a-time step takes one more argument,
-#: the step before's output: ``tests/test_sdar.py`` ``PARENT_PROGRAMS``.)
-SERIAL_PROGRAMS = {"sdar_moe_tiny": ("step", "63269f1e81bbf6a5"),
-                   "llama_tiny": ("verify", "d321f3b3237cf784")}
+#: sha256 (16 hex digits) of the lowered text of the tiny Llama's verify
+#: program on the commit before the tick ran ahead (PR 38, e24d6dc):
+#: ``_tick_spec`` calls ``verify()`` as before, and its program is the
+#: parent's letter for letter.  (The token-at-a-time step takes one more
+#: argument, the step before's output: ``tests/test_sdar.py``
+#: ``PARENT_PROGRAMS``; a block pass, since it runs ahead too, the pass
+#: before's own booking and one array of the host's rows.)
+SERIAL_PROGRAMS = {"llama_tiny": "d321f3b3237cf784"}
 
 
 @pytest.mark.parametrize("model", sorted(SERIAL_PROGRAMS))
-def test_block_and_verify_programs_lower_as_before(model):
+def test_the_verify_program_lowers_as_before(model):
     eng = _server(_make(model), max_batch=2).engine
-    which, want = SERIAL_PROGRAMS[model]
-    if which == "step":
-        low = eng._step.lower(
-            eng._w, eng._pool, eng._dev(eng._tables), eng._dev(eng._blk_ids),
-            eng._dev(eng._pos), eng._dev(eng._blk_masked, bool),
-            eng._dev(eng._blk_step))
-    else:
-        low = eng._verify.lower(
-            eng._w, eng._pool, eng._dev(eng._tables),
-            eng._dev(np.zeros((eng.num_slots, 3), np.int32)),
-            eng._dev(eng._pos))
-    assert hashlib.sha256(low.as_text().encode()).hexdigest()[:16] == want
+    low = eng._verify.lower(
+        eng._w, eng._pool, eng._dev(eng._tables),
+        eng._dev(np.zeros((eng.num_slots, 3), np.int32)), eng._dev(eng._pos))
+    assert hashlib.sha256(low.as_text().encode()).hexdigest()[:16] \
+        == SERIAL_PROGRAMS[model]
 
 
-def test_the_block_tick_stays_serial():
-    """Its records say ``ahead`` false on every tick, and the step's own
-    stamps are the turn's.  (The speculative tick's:
-    ``tests/test_lane_log.py``.)"""
+# --- a block decoder's pass, a pass ahead of its bookkeeping ---------------------
+
+BL, MASK = 4, 255        # the tiny SDAR's block length and mask id
+
+
+class _Taken:
+    """What a request takes of its passes, as the lane books them: the tokens
+    at its first ``n_new`` positions behind the prompt and every commit
+    ``(position, token, the block's pass)``."""
+
+    def __init__(self, prompt, n_new):
+        self.n_prompt, self.n_new = len(prompt), n_new
+        self.out, self.commits = {}, []
+
+    def take(self, tick, slot):
+        """-> whether the pass committed the last of its positions."""
+        for j in np.flatnonzero(tick.commit[slot]):
+            pos, tok = int(tick.pos0[slot]) + int(j), int(tick.ids[slot, j])
+            self.commits.append((pos, tok, int(tick.step[slot])))
+            if pos - self.n_prompt < self.n_new:
+                self.out[pos - self.n_prompt] = tok
+        return len(self.out) == self.n_new
+
+    def tokens(self):
+        return [self.out[i] for i in range(self.n_new)]
+
+
+class _Block:
+    """The tiny block decoder and one engine of six slots, driven by hand."""
+
+    def __init__(self):
+        self.net = _make("sdar_moe_tiny")
+        self.eng = _server(self.net, num_slots=6).engine
+
+    def cleared(self):
+        for slot in range(self.eng.num_slots):
+            self.eng.clear_slot(slot)
+        return self.eng
+
+    def serial(self, prompt, n_new, slot=0):
+        """The serial loop: the request alone in the engine, each pass
+        fetched and booked (``step()``) before the next is dispatched ->
+        (its tokens, its commits)."""
+        eng = self.cleared()
+        _commit(eng, slot, prompt, _blocks(eng, slot))
+        got = _Taken(prompt, n_new)
+        while not got.take(eng.step([slot]), slot):
+            pass
+        return got.tokens(), got.commits
+
+
+@pytest.fixture(scope="module")
+def block():
+    return _Block()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_pass_books_itself_as_the_host_books_it(block, seed):
+    """Random blocks, masks, pass counts and cursors; two passes over random
+    sets of slots, the second queued before the first is fetched and made to
+    take every row from the device (``_fresh`` cleared by hand, which the
+    lane never does: it reads the host's row of a slot that a pass left out).
+    What the second pass leaves on the device is, row for row, what
+    ``_book_block`` has made of the host's mirrors by then, and what the
+    arithmetic written out slot by slot makes of the passes' own outputs: a
+    block without masks stored, any other a pass on, a row that a pass did not
+    step as it was."""
+    eng = block.cleared()
+    rs = np.random.RandomState(seed)
+    n = eng.num_slots
+    masked = rs.rand(n, BL) < 0.5
+    masked[rs.randint(n)] = False          # a block whose next pass stores it
+    with eng.dev_lock:
+        for s in range(n):
+            eng._tables[s] = _blocks(eng, s)
+        eng._blk_masked[:] = masked
+        eng._blk_ids[:] = np.where(masked, MASK, rs.randint(1, 250, (n, BL)))
+        eng._blk_step[:] = rs.randint(0, 4, n)
+        eng._pos[:] = rs.randint(0, 12, n) * BL
+        eng._fresh[:] = True
+
+    def mirrors():
+        return eng._blk_ids, eng._blk_masked, eng._blk_step, eng._pos
+
+    ids, masks, count, pos = want = [a.copy() for a in mirrors()]
+    # one slot is in neither pass, and no pass is empty
+    rest = np.delete(np.arange(n), rs.randint(n))
+    sets = [np.union1d(rest[rs.rand(n - 1) < 0.6], rest[rs.randint(n - 1)])
+            for _ in range(2)]
+    first = eng.dispatch_step(sets[0])
+    eng._fresh[:] = False
+    second = eng.dispatch_step(sets[1])
+    assert second.ahead and not first.ahead
+    for step in (first, second):
+        tick = eng.fetch_step(step)
+        assert tick.live.nonzero()[0].tolist() == step.active.tolist()
+        for s in step.active:
+            if not masks[s].any():
+                assert tick.stored[s] and not tick.commit[s].any()
+                pos[s] += BL
+                ids[s], masks[s], count[s] = MASK, True, 0
+            else:
+                assert tick.commit[s].any() and not tick.stored[s]
+                assert not (tick.commit[s] & ~masks[s]).any()
+                ids[s] = tick.ids[s]
+                masks[s] &= ~tick.commit[s]
+                count[s] += 1
+    for dev, host, by_hand in zip(eng._blk_dev, mirrors(), want):
+        dev = np.asarray(dev)
+        assert dev.dtype == host.dtype == by_hand.dtype
+        assert np.array_equal(dev, host) and np.array_equal(host, by_hand)
+
+
+def test_block_passes_run_ahead_equal_the_serial_loop(block):
+    """Slot 0 decodes throughout.  Slot 1's request ends inside a block with
+    pass K: pass K+1, queued before pass K was fetched, carries the slot all
+    the same, a row computed and booked for nobody.  The slot is cleared as
+    pass K is booked and ITS BLOCKS go straight to the next prompt, whose
+    commit is queued behind pass K+1 and whose first pass, K+2, takes the
+    host's row, queued before pass K+1 is fetched.  Every token and every
+    commit ``(position, token, pass)`` is the serial loop's."""
+    prompts = dict(zip("abc", _prompts(61, (9, 6, 13))))
+    n_new = {"a": 17, "b": 3, "c": 6}
+    eng = block.cleared()
+    taken = {k: _Taken(prompts[k], n_new[k]) for k in prompts}
+    owner, flights, dead = {0: "a", 1: "b"}, [], []
+    for slot, k in owner.items():
+        _commit(eng, slot, prompts[k], _blocks(eng, slot))
+
+    def queue():
+        flights.append((eng.dispatch_step(sorted(owner)), dict(owner)))
+
+    def book():
+        step, owned = flights.pop(0)
+        tick = eng.fetch_step(step)
+        for slot, k in owned.items():
+            if not tick.live[slot]:
+                dead.append((step.seq, k))
+                assert not tick.commit[slot].any() and not tick.stored[slot]
+            elif taken[k].take(tick, slot):
+                del owner[slot]
+                eng.clear_slot(slot)        # released as the pass is booked
+                if k == "b":                # ... its blocks handed on at once
+                    assert flights[0][1][slot] == "b"
+                    _commit(eng, slot, prompts["c"], _blocks(eng, slot))
+                    owner[slot] = "c"
+
+    queue()
+    while flights:
+        if owner:
+            queue()
+            assert len(flights) == 1 or flights[-1][0].ahead
+        book()
+    assert eng.step_in_flight is None
+    # each request's last pass was followed by one queued before it was
+    # fetched, which carried its slot for nobody
+    assert sorted(k for _seq, k in dead) == ["a", "b", "c"]
+    for k, slot in (("a", 0), ("b", 1), ("c", 1)):
+        tokens, commits = block.serial(prompts[k], n_new[k], slot=slot)
+        assert taken[k].tokens() == tokens, k
+        assert taken[k].commits == commits, k
+
+
+def _serve_blocks(block, jobs, since=None, **cfg):
+    """``jobs`` ((prompt, n_new), ...) through a server of the tiny block
+    decoder -> (requests, outputs, ``decode.tick`` records, stats), each
+    output and each ``req.commits`` held to the serial loop's."""
+    since = time.perf_counter() if since is None else since
+    with _server(block.net, **cfg) as srv:
+        futs = [srv.submit(p, max_new_tokens=n) for p, n in jobs]
+        outs = [f.result(180) for f in futs]
+        rep = srv.replicas[0]
+        for _ in range(20000):       # a last pass, for nobody, has its record
+            if tracing.lane_log("decode.tick", since=since)[-1]["seq"] \
+                    == srv.engine.steps and rep.decode._flight is None:
+                break
+            time.sleep(0.001)
+        assert rep.mgr.check()
+        stats = srv.stats()
+        assert srv.engine._step._cache_size() == 1
+    reqs = [f.request for f in futs]
+    for (p, n), req, out in zip(jobs, reqs, outs):
+        tokens, commits = block.serial(p, n, slot=req.slot)
+        assert out[:len(p)].tolist() == list(p)
+        assert out[len(p):].tolist() == tokens
+        assert req.commits == commits
+    assert stats["failed"] == 0 and stats["completed"] == len(jobs)
+    return reqs, outs, tracing.lane_log("decode.tick", since=since), stats
+
+
+SERVED = {
+    # six tokens behind a prompt of 7 end at position 12, a block's first;
+    # three behind 5 at position 7, a block's last
+    "ends_inside_a_block": (((7, 6), (5, 3), (10, 1)), {}),
+    # every remainder of the prompt over the block length
+    "the_first_block_holds_the_rest_of_the_prompt":
+        (((1, 5), (3, 7), (6, 4), (9, 6), (14, 9)), {}),
+    # a pool of 12 blocks of 4 under maxima of 8 to 11: slots are parked
+    "parked_by_a_pool_under_parity":
+        (((1, 30), (3, 28), (8, 35), (14, 26), (17, 20)),
+         {"num_blocks": 12}),
+    # one slot: each request takes over the slot and the blocks of the last
+    "a_freed_slot_readmitted": (((11, 5), (4, 9), (7, 2)), {"num_slots": 1}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SERVED))
+def test_served_block_tokens_and_commits_are_the_serial_loops(block, case):
+    sizes, cfg = SERVED[case]
+    jobs = [(p, n) for p, (_len, n) in zip(
+        _prompts(70, [size for size, _n in sizes]), sizes)]
+    _reqs, _outs, ticks, stats = _serve_blocks(block, jobs, **cfg)
+    assert stats["decode_steps"] == len(ticks)
+    # a pass queued before the one ahead of it was fetched may carry slots
+    # whose requests that one ended: left out of what the record counts
+    assert all(t["rows"] == t["n_active"] * BL == len(t["request_ids"]) * BL
+               for t in ticks)
+    assert any(t["ahead"] for t in ticks)
+    assert sum(t["n_finished"] for t in ticks) == len(jobs)
+    assert stats["blocks"] == {
+        "block_passes": sum(t["block_passes"] for t in ticks),
+        "blocks_committed": sum(t["n_store"] for t in ticks),
+        "committed_tokens": sum(t["committed"] for t in ticks)}
+    if case == "parked_by_a_pool_under_parity":
+        assert sum(t["n_parked"] for t in ticks) > 0
+
+
+def test_a_hand_off_adopted_while_a_pass_is_in_flight(block, monkeypatch):
+    """A decodes; the lane is held in a fetch with the pass after queued
+    behind it; B is prefilled and committed behind that pass, adopted by the
+    next turn and carried, from the host's row, by the pass that turn
+    queues."""
+    import threading
+
+    hold, held = threading.Event(), threading.Event()
+    real_fetch = generative._materialize
+
+    def gated_fetch(arrays):
+        if hold.is_set():
+            held.set()
+            while hold.is_set():
+                time.sleep(0.001)
+        return real_fetch(arrays)
+
+    monkeypatch.setattr(generative, "_materialize", gated_fetch)
+    a, b = _prompts(71, (9, 6))
     since = time.perf_counter()
-    with _server(_make("sdar_moe_tiny"), max_batch=2) as srv:
-        futs = [srv.submit(p, max_new_tokens=6)
-                for p in _prompts(51, (6, 9, 5))]
-        for f in futs:
-            f.result(180)
+    try:
+        with _server(block.net, num_slots=2) as srv:
+            lane = srv.replicas[0].decode
+            fa = srv.submit(a, max_new_tokens=40)
+            while fa.request.first_tick is None:
+                time.sleep(0.001)
+            hold.set()
+            assert held.wait(60) and lane._flight is not None
+            queued = lane._flight.step.seq
+            assert srv.engine.step_in_flight == queued
+            fb = srv.submit(b, max_new_tokens=7)
+            while fb.request.t_commit is None:
+                time.sleep(0.001)
+            hold.clear()
+            outs = [f.result(180) for f in (fa, fb)]
+    finally:
+        hold.clear()
+    for p, n, f, out in ((a, 40, fa, outs[0]), (b, 7, fb, outs[1])):
+        tokens, commits = block.serial(p, n, slot=f.request.slot)
+        assert out[len(p):].tolist() == tokens
+        assert f.request.commits == commits
+    turn, = [t for t in tracing.lane_log("slot.turn", since=since)
+             if t["request_id"] == fb.request.id]
+    ticks = {t["seq"]: t for t in tracing.lane_log("decode.tick", since=since)}
+    assert turn["tick"] > queued and ticks[queued]["ahead"]
+    assert ticks[queued]["request_ids"] == (fa.request.id,)
+    assert (ticks[turn["tick"]]["n_adopted"],
+            ticks[turn["tick"]]["n_active"]) == (1, 2)
+
+
+def test_block_ticks_say_ahead_and_leave_the_dead_pass_out(block, monkeypatch):
+    """A lone request: nothing covers, so every pass but the first is queued
+    before the one ahead of it is fetched, the one after its last too, which
+    carries its slot for nobody: a record of no row.  The step's own dispatch
+    lies a turn before the turn's, and the counters and the summary count the
+    passes queued ahead."""
+    from mxnet_tpu import telemetry
+
+    seen = []
+    monkeypatch.setattr(telemetry, "count",
+                        lambda name, n=1: seen.append((name, n)))
+    (p,) = _prompts(72, (6,))
+    reqs, _outs, ticks, stats = _serve_blocks(block, [(p, 6)])
+    assert [t["ahead"] for t in ticks] == [False] + [True] * (len(ticks) - 1)
+    assert [t["seq"] for t in ticks] == list(range(1, len(ticks) + 1))
+    *live, dead = ticks
+    assert all(t["request_ids"] == (reqs[0].id,) and t["rows"] == BL
+               for t in live)
+    assert (live[-1]["n_finished"], dead["n_finished"]) == (1, 0)
+    assert (dead["n_active"], dead["rows"], dead["n_store"], dead["committed"],
+            dead["block_passes"], dead["request_ids"]) == (0, 0, 0, 0, 0, ())
+    for a, b in zip(ticks, ticks[1:]):
+        assert b["t_step_disp1"] < a["t_tok"]
+        assert b["t_step_disp1"] <= b["t_disp0"] <= b["t_disp1"] <= b["t_tok"]
+    # 6 tokens behind a prompt of 6: one commit a pass on seeded weights
+    assert sum(t["committed"] for t in live) >= 6
+    assert sum(t["n_store"] for t in live) >= 1
+    steps = sum(n for name, n in seen if name == "serving.decode.steps")
+    ahead = sum(n for name, n in seen if name == "serving.decode.steps_ahead")
+    assert (steps, ahead) == (len(ticks), len(ticks) - 1)
+    assert stats["decode_steps_ahead"] == ahead
+
+
+def test_a_covered_block_turn_queues_nothing(block, monkeypatch):
+    """Where the prefill lane has the device behind the pass in flight the
+    turn queues nothing: no pass runs ahead, none is made for nobody, and
+    the tokens are the same."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.serving.lanes import DecodeLane
+
+    monkeypatch.setattr(DecodeLane, "_prefill_covers", lambda self, step: True)
+    emitted = []
+    jobs = list(zip(_prompts(73, (5, 10)), (6, 4)))
+    since = time.perf_counter()
+    with _server(block.net) as srv:
+        for p, n in jobs:
+            srv.generate(p, max_new_tokens=n)
+        monkeypatch.setattr(telemetry, "emit", emitted.append)
+        srv.replicas[0].emit_summary()
+        assert srv.stats()["decode_steps_ahead"] == 0
     ticks = tracing.lane_log("decode.tick", since=since)
     assert ticks and not any(t["ahead"] for t in ticks)
-    for t in ticks:
-        assert (t["t_step_loop"], t["t_step_lock"], t["t_step_disp0"],
-                t["t_step_disp1"]) == (t["t_loop"], t["t_lock"],
-                                       t["t_disp0"], t["t_disp1"])
+    assert all(t["n_active"] == 1 for t in ticks)
+    assert emitted[-1]["steps_ahead_share"] == 0.0
+    _reqs, _outs, ahead, _stats = _serve_blocks(block, jobs[:1])
+    assert [t["committed"] for t in ticks[:len(ahead) - 1]] \
+        == [t["committed"] for t in ahead[:-1]]
+
+
+@pytest.mark.parametrize("half", ["dispatch", "fetch"])
+def test_an_exception_in_either_half_of_a_block_pass(block, half, monkeypatch):
+    """Two passes may be in flight when a half raises: each request fails
+    once, every slot and block comes back, no pass is left in flight and no
+    slot's next pass reads what the dropped ones left on the device; the
+    server goes on serving the serial loop's tokens."""
+    srv = _server(block.net, num_slots=2)
+    eng, rep = srv.engine, srv.replicas[0]
+    prompts = _prompts(74, (6, 9, 5))
+    real_fetch, real_step = generative._materialize, eng._step
+    armed = {"on": False}
+
+    def planted(real, what):
+        def call(*args):
+            if armed["on"]:
+                armed["on"] = False
+                raise RuntimeError("planted: the %s half" % what)
+            return real(*args)
+        return call
+
+    with srv:
+        if half == "fetch":
+            monkeypatch.setattr(generative, "_materialize",
+                                planted(real_fetch, half))
+        else:
+            eng._step = planted(real_step, half)
+        lost = [srv.submit(p, max_new_tokens=40) for p in prompts[:2]]
+        while any(f.request.first_tick is None for f in lost):
+            time.sleep(0.001)         # both decode: two passes in flight
+        armed["on"] = True
+        for f in lost:
+            with pytest.raises(RuntimeError, match="planted"):
+                f.result(120)
+        monkeypatch.setattr(generative, "_materialize", real_fetch)
+        eng._step = real_step
+        for _ in range(5000):         # the count follows the future
+            if rep.failed == 2:
+                break
+            time.sleep(0.001)
+        assert rep.failed == 2 and srv.stats()["failed"] == 2
+        assert eng.step_in_flight is None and rep.decode._flight is None
+        assert eng._fresh.all()
+        assert rep.mgr.free_slots() == 2
+        assert rep.mgr.allocator.blocks_in_use == 0
+        kept = srv.submit(prompts[2], max_new_tokens=5)
+        out = kept.result(120)
+        assert rep.failed == 2 and rep.completed == 1
+    tokens, commits = block.serial(prompts[2], 5, slot=kept.request.slot)
+    assert out[len(prompts[2]):].tolist() == tokens
+    assert kept.request.commits == commits
+    assert rep.mgr.check()
+
+
+def test_the_carried_blocks_add_no_compile_after_warm_up(block):
+    """The first pass takes zeros committed as a pass's output is, so the
+    second is the first's compiled program; requests of warmed buckets then
+    compile nothing."""
+    with _server(block.net) as srv:
+        eng = srv.engine
+        for p in _prompts(75, (6, 12)):         # buckets 8 and 16
+            srv.generate(p, max_new_tokens=2)
+        warm = eng.compiled_signatures()
+        assert ("step",) in warm and eng._step._cache_size() == 1
+        futs = [srv.submit(p, max_new_tokens=n) for p, n in
+                zip(_prompts(76, (5, 9, 13, 7)), (6, 9, 3, 11))]
+        for f in futs:
+            f.result(180)
+        assert eng.compiled_signatures() == warm
+        assert eng._step._cache_size() == 1

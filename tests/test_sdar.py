@@ -347,7 +347,10 @@ def test_a_pool_that_parks_gives_the_block_ticks_tokens_and_commits(tiny):
     assert kv_w["unsafe_refusals"] == {"admit": 0, "grant": 0}
     parked = sum(t["n_parked"] for t in ticks_s)
     assert 0 < parked <= kv_s["parked_slot_ticks"]
-    assert all(t["n_active"] >= 1 for t in ticks_s)
+    # a pass with no live row carried none but slots whose requests the
+    # pass before it, not yet fetched as it was queued, had just ended
+    for before, t in zip(ticks_s, ticks_s[1:]):
+        assert t["n_active"] >= 1 or (t["ahead"] and before["n_finished"])
     assert all(t["n_active"] * BL == t["rows"] for t in ticks_s)
     assert kv_s["peak_blocks_in_use"] <= 12
     for kv in (kv_w, kv_s):
@@ -432,6 +435,25 @@ def test_options_refused_for_a_block_decoder(tiny, name, kw, says):
     assert says in str(exc.value)
 
 
+def test_a_block_decoder_with_a_state_layer_is_refused(tiny, monkeypatch):
+    """The block tick makes a pass again for a parked slot and carries a slot
+    a pass past its request's end: a state layer's step is not idempotent, so
+    such a model (none exists) is refused by name, not served by a serial
+    tick kept for it."""
+    real = sdar.SdarDecoder.cache_spec
+
+    def with_a_state_layer(self):
+        spec = real(self)
+        return decoder.CacheSpec(
+            layers=("state",) + spec.layers[1:],
+            num_kv_heads=spec.num_kv_heads, head_dim=spec.head_dim,
+            state_shape=(3, 8), decoding=spec.decoding)
+
+    monkeypatch.setattr(sdar.SdarDecoder, "cache_spec", with_a_state_layer)
+    with pytest.raises(mx.MXNetError, match="not idempotent"):
+        _server(tiny[0])
+
+
 def test_no_sampling_option_exists_to_refuse():
     """Greedy is the only decoding ``ServerConfig`` has: a sampling option
     that arrives has to be refused for a block decoder by name."""
@@ -514,9 +536,7 @@ def _lowered(eng):
             eng._toks, eng._dev(eng._pos))
     else:
         out["step"] = eng._step.lower(
-            eng._w, eng._pool, eng._dev(eng._tables),
-            eng._dev(eng._blk_ids), eng._dev(eng._pos),
-            eng._dev(eng._blk_masked, bool), eng._dev(eng._blk_step))
+            eng._w, eng._pool, *eng._block_args(np.arange(eng.num_slots)))
     if not eng.cache_spec.expert_layers:
         out["verify"] = eng._verify.lower(
             eng._w, eng._pool, eng._dev(eng._tables),
@@ -768,24 +788,21 @@ def test_a_causal_order_inside_a_block_is_not_correct(harness, capsys,
 
 def test_a_block_whose_store_pass_is_skipped_is_not_correct(harness, capsys,
                                                             monkeypatch):
-    """Planted: a block that has just lost its last mask is taken as
-    stored, so what later blocks read of it is the K/V of its last
-    denoising pass, which still saw a mask id."""
-    from mxnet_tpu.serving.generative import LlamaServingEngine
+    """Planted, in the bookkeeping the pass runs on the device and the
+    host runs a pass late: a block that has just lost its last mask is
+    taken as stored, so what later blocks read of it is the K/V of its
+    last denoising pass, which still saw a mask id."""
+    whole = decoder.block_advance
 
-    whole = LlamaServingEngine._book_block
+    def skipping(xp, state, ids, commit, stepped, decoding):
+        held, masked, step, pos0 = whole(xp, state, ids, commit, stepped,
+                                         decoding)
+        skip = stepped & state[1].any(axis=1) & ~masked.any(axis=1)
+        return (xp.where(skip[:, None], decoding.mask_id, held),
+                masked | skip[:, None], xp.where(skip, 0, step),
+                xp.where(skip, pos0 + decoding.block_len, pos0))
 
-    def skipping(self, out, step):
-        tick = whole(self, out, step)
-        for s in step.active:
-            if not tick.stored[s] and not self._blk_masked[s].any():
-                self._pos[s] += BL
-                self._blk_ids[s] = self.block.mask_id
-                self._blk_masked[s] = True
-                self._blk_step[s] = 0
-        return tick
-
-    monkeypatch.setattr(LlamaServingEngine, "_book_block", skipping)
+    monkeypatch.setattr(decoder, "block_advance", skipping)
     compared = _run_planted(harness, capsys)
     assert compared["commits_not_the_output"] == 0
 
