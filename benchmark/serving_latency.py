@@ -25,13 +25,10 @@ queue-wait percentiles, the batch-size distribution, throughput, and
 the predictor's compile-cache stats (signatures must stay within the
 bucket grid's ceiling).
 
-Round 11 adds the GENERATIVE lanes: an r8-vs-r11 A/B (the slot-ledger
-single-loop server vs the paged disaggregated server) swept open-loop
-over a request-rate ladder to saturation.  Per (engine, rate):
+Round 11 adds the GENERATIVE lanes: the paged disaggregated server
+swept open-loop over a request-rate ladder to saturation.  Per rate:
 p50/p99 total latency, queue-wait percentiles, ttft, and
-tokens/sec-per-chip; the acceptance block checks queue-wait p99 is
-reduced at the r8 offered rate and the max sustainable rate is higher
-for the paged multi-replica server.
+tokens/sec-per-chip.
 
 Round 12 (observability) extends the sweep with TPOT percentiles and
 per-rate goodput against TTFT/TPOT SLO targets
@@ -97,7 +94,7 @@ SEED = int(os.environ.get("BENCH_SERVING_SEED", 0))
 IN_DIM = 8
 HIDDEN = 8
 
-# generative A/B + saturation sweep knobs
+# generative saturation sweep knobs
 GEN_REQUESTS = int(os.environ.get("BENCH_SERVING_GEN_REQUESTS", 48))
 GEN_RATE = float(os.environ.get("BENCH_SERVING_GEN_RATE", 512.0))
 GEN_RATES = tuple(float(r) for r in os.environ.get(
@@ -279,7 +276,7 @@ def _open_loop(srv, inputs, rng):
         f.result(timeout=300.0)
 
 
-# --- generative lanes: r8 slot-ledger vs r11 paged/dp, rate ladder ---------
+# --- generative lanes: the paged/dp server, rate ladder --------------------
 
 def _gen_workload(n, rng):
     """Mixed-length prompts spanning the 8/16 prompt buckets."""
@@ -287,29 +284,22 @@ def _gen_workload(n, rng):
     return [rng.randint(1, 250, size=l).astype(np.int32) for l in lens]
 
 
-def _make_gen_server(net, engine):
-    """engine="slots_r8": the r8 single-loop slot-ledger server on one
-    device.  engine="paged": the paged disaggregated server, dp2 mesh
-    (two single-device replicas) when >=2 devices are available.
-
-    The KV budget is held EQUAL: the ledger reserves ``GEN_SLOTS ×
-    GEN_MAX_LEN`` token-rows; the paged pool gets the same
-    ``num_blocks × block_size`` tokens but — because requests only
+def _make_gen_server(net):
+    """The paged disaggregated server, dp2 mesh (two single-device
+    replicas) when >=2 devices are available.  The pool holds
+    ``GEN_SLOTS × GEN_MAX_LEN`` tokens and — because requests only
     reserve what they can use — serves 2× the decode slots from it."""
     import jax
     from mxnet_tpu import serving
 
-    paged = engine != "slots_r8"
     cfg = serving.ServerConfig(
         max_batch=GEN_SLOTS, max_length=GEN_MAX_LEN, min_batch=1,
         min_length=8, queue_capacity=max(64, GEN_REQUESTS),
-        num_slots=2 * GEN_SLOTS if paged else GEN_SLOTS,
-        max_new_tokens=GEN_MAX_NEW,
-        kv_mode="paged" if paged else "slots", block_size=16,
-        num_blocks=GEN_SLOTS * (GEN_MAX_LEN // 16) if paged else None,
+        num_slots=2 * GEN_SLOTS, max_new_tokens=GEN_MAX_NEW,
+        block_size=16, num_blocks=GEN_SLOTS * (GEN_MAX_LEN // 16),
         batch_window_ms=2.0, summary_every=max(64, GEN_REQUESTS))
     mesh = None
-    if paged and len(jax.devices()) >= 2:
+    if len(jax.devices()) >= 2:
         from jax.sharding import Mesh
         mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
     return serving.GenerativeServer(net, cfg, mesh=mesh)
@@ -342,25 +332,21 @@ def _warm_grid(srv):
     live KV is touched) — the measured passes never hit a cold
     compile."""
     pol = srv.config.policy
-    engines = [rep.engine for rep in srv.replicas] or [srv.engine]
-    for eng in engines:
+    for eng in [rep.engine for rep in srv.replicas]:
         eng.step([])
         for kb in pol.batch_buckets():
             for lb in pol.length_buckets():
                 prompts = np.zeros((kb, lb), np.int32)
                 t0s = np.full(kb, lb, np.int32)
                 slots = np.full(kb, eng.num_slots, np.int32)
-                if eng.kv_mode == "slots":
-                    eng.admit(prompts, t0s, slots)
-                else:
-                    toks, rows = eng.prefill_rows(prompts, t0s)
-                    eng.commit_rows(rows, slots, [None] * kb, t0s,
-                                    np.zeros(kb, np.int64))
+                toks, rows = eng.prefill_rows(prompts, t0s)
+                eng.commit_rows(rows, slots, [None] * kb, t0s,
+                                np.zeros(kb, np.int64))
 
 
-def _run_gen_engine(net, engine, rates):
-    """Build ONE server per engine (so the rate ladder shares its
-    compiles), warm the signature grid on every replica, then sweep."""
+def _run_gen_engine(net, rates):
+    """Build ONE server (so the rate ladder shares its compiles), warm
+    the signature grid on every replica, then sweep."""
     from mxnet_tpu import telemetry
     from mxnet_tpu.telemetry.sinks import ListSink
 
@@ -374,9 +360,9 @@ def _run_gen_engine(net, engine, rates):
     cap.enable()
     sink = ListSink()
     telemetry.add_sink(sink)
-    srv = _make_gen_server(net, engine)
-    chips = max(1, len(srv.replicas))
-    out = {"engine": engine, "replicas": chips, "rates": {}}
+    srv = _make_gen_server(net)
+    chips = len(srv.replicas)
+    out = {"engine": "paged", "replicas": chips, "rates": {}}
     try:
         _warm_grid(srv)
         with srv:
@@ -461,8 +447,7 @@ def _gen_sweep():
     net = llama_tiny()
     net.initialize()
     rates = sorted(set(GEN_RATES) | {GEN_RATE})
-    engines = {eng: _run_gen_engine(net, eng, rates)
-               for eng in ("slots_r8", "paged")}
+    engines = {"paged": _run_gen_engine(net, rates)}
     return (engines, _tracing_ab(net), _capacity_ab(net),
             _saturation_burst(net), rates)
 
@@ -477,15 +462,13 @@ def _ab_arm(srv, prompts, traced):
 
     (tracing.enable if traced else tracing.disable)()
     try:
-        steps0 = sum(rep.engine.steps for rep in srv.replicas) \
-            if srv.replicas else srv.engine.steps
+        steps0 = sum(rep.engine.steps for rep in srv.replicas)
         t0 = time.perf_counter()
         futs = [srv.submit(p, max_new_tokens=AB_MAX_NEW) for p in prompts]
         for f in futs:
             f.result(timeout=300.0)
         wall = time.perf_counter() - t0
-        steps1 = sum(rep.engine.steps for rep in srv.replicas) \
-            if srv.replicas else srv.engine.steps
+        steps1 = sum(rep.engine.steps for rep in srv.replicas)
     finally:
         tracing.disable()
         tracing.clear()
@@ -506,7 +489,7 @@ def _tracing_ab(net):
         max_batch=GEN_SLOTS, max_length=GEN_MAX_LEN, min_batch=1,
         min_length=8, queue_capacity=max(64, AB_REQUESTS),
         num_slots=GEN_SLOTS, max_new_tokens=AB_MAX_NEW,
-        kv_mode="paged", block_size=16,
+        block_size=16,
         batch_window_ms=2.0, summary_every=1 << 30)
     telemetry.enable(memory=False, cost=False)
     srv = serving.GenerativeServer(net, cfg)
@@ -559,7 +542,7 @@ def _saturation_burst(net):
     cfg = serving.ServerConfig(
         max_batch=2, max_length=GEN_MAX_LEN, min_batch=1, min_length=8,
         num_slots=2, queue_capacity=max(64, 4 * CAP_BURST),
-        max_new_tokens=8, kv_mode="paged", block_size=16,
+        max_new_tokens=8, block_size=16,
         batch_window_ms=2.0, summary_every=1 << 30)
     mesh = None
     if len(jax.devices()) >= 2:
@@ -619,15 +602,13 @@ def _cap_arm(srv, prompts, on):
 
     (cap.enable if on else cap.disable)()
     try:
-        steps0 = sum(rep.engine.steps for rep in srv.replicas) \
-            if srv.replicas else srv.engine.steps
+        steps0 = sum(rep.engine.steps for rep in srv.replicas)
         t0 = time.perf_counter()
         futs = [srv.submit(p, max_new_tokens=AB_MAX_NEW) for p in prompts]
         for f in futs:
             f.result(timeout=300.0)
         wall = time.perf_counter() - t0
-        steps1 = sum(rep.engine.steps for rep in srv.replicas) \
-            if srv.replicas else srv.engine.steps
+        steps1 = sum(rep.engine.steps for rep in srv.replicas)
     finally:
         cap.disable()
     return wall, steps1 - steps0
@@ -653,7 +634,7 @@ def _capacity_ab(net):
         max_batch=GEN_SLOTS, max_length=GEN_MAX_LEN, min_batch=1,
         min_length=8, queue_capacity=max(64, CAP_AB_REQUESTS),
         num_slots=GEN_SLOTS, max_new_tokens=AB_MAX_NEW,
-        kv_mode="paged", block_size=16,
+        block_size=16,
         batch_window_ms=2.0, summary_every=1 << 30)
     telemetry.enable(memory=False, cost=False)
     srv = serving.GenerativeServer(net, cfg)
@@ -776,7 +757,7 @@ def _spec_radix_lane(net, prompts, spec, radix):
     cfg = serving.ServerConfig(
         max_batch=1, max_length=SPEC_MAX_LEN, min_batch=1, min_length=8,
         queue_capacity=max(64, SPEC_REQUESTS), num_slots=2,
-        max_new_tokens=SPEC_MAX_NEW, kv_mode="paged", block_size=16,
+        max_new_tokens=SPEC_MAX_NEW, block_size=16,
         batch_window_ms=0.5, summary_every=1 << 30,
         draft_net=net if spec else None, spec_k=SPEC_K,
         radix_cache=radix)
@@ -883,11 +864,6 @@ def main():
         max_batch=MAX_BATCH, max_length=MAX_LENGTH,
         min_batch=1, min_length=8).signatures())
 
-    ab = f"{GEN_RATE:g}"
-    w_slots = gen["slots_r8"]["rates"][ab]["queue_wait_ms"]["p99"]
-    w_paged = gen["paged"]["rates"][ab]["queue_wait_ms"]["p99"]
-    s_slots = gen["slots_r8"]["max_sustainable_rate_req_per_s"]
-    s_paged = gen["paged"]["max_sustainable_rate_req_per_s"]
     record = {
         "metric": "serving_open_loop_p99_ms",
         "value": lanes["open_loop"]["total_ms"]["p99"],
@@ -915,13 +891,6 @@ def main():
             "batched": any(int(k) > 1 for l in lanes.values()
                            for k in l["batch_size_dist"]),
             "no_rejections": all(l["rejected"] == 0 for l in lanes.values()),
-            "gen_queue_wait_p99_reduced_vs_r8": (
-                w_slots is not None and w_paged is not None
-                and w_paged <= w_slots),
-            "gen_max_sustainable_rate_higher": (
-                s_paged is not None
-                and (s_slots is None or s_paged > s_slots
-                     or (s_paged == s_slots == max(GEN_RATES)))),
             "tracing_step_overhead_under_3pct":
                 tracing_ab["overhead_frac"] < 0.03,
             # r19 speed multipliers (all four arms decode the identical

@@ -320,7 +320,7 @@ def serve_phase(preset, clock, prompt_lens=(24, 200, 600, 200, 24, 600),
     mesh = parallel.make_mesh({"dp": dp}) if dp else None
     server = GenerativeServer(
         net, ServerConfig(max_batch=4, max_length=max_length,
-                          num_slots=num_slots, kv_mode="paged",
+                          num_slots=num_slots,
                           max_new_tokens=max_new_tokens), mesh=mesh)
     setup = clock.lap()
 
@@ -415,7 +415,7 @@ def serve_phase(preset, clock, prompt_lens=(24, 200, 600, 200, 24, 600),
         "hidden": cfg.hidden_size, "ffn": cfg.intermediate_size,
         "heads": [cfg.num_heads, cfg.num_kv_heads],
         "vocab": cfg.vocab_size, "params": n_params,
-        "dtype": "bfloat16", "kv_mode": "paged", "max_length": max_length,
+        "dtype": "bfloat16", "max_length": max_length,
         "dp": dp, "requests": 2 * len(prompts),
         "prompt_lens": list(prompt_lens), "max_new_tokens": max_new_tokens,
         "prefill_length_buckets": prefill_lengths,

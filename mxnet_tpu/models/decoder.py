@@ -412,10 +412,7 @@ class BehindPrefix:
 class DenseCache:
     """A dense ``(B, Hkv, max_len, hd)`` K and V cache, one new token a
     row, written at ``pos`` and masked ``t <= pos``: ``pos`` () for a
-    batch decoded in lockstep (offline ``generate``), (S,) where every
-    slot carries its OWN position (the slots engine).  There a vacant
-    slot runs with pos=0/ids=0: its garbage write lands in its own row
-    only, and admission replaces the whole slot cache."""
+    batch decoded in lockstep (offline ``generate``)."""
 
     live = None
 
@@ -424,25 +421,17 @@ class DenseCache:
 
     @staticmethod
     def mask_of(pos, max_len):
-        """(1, T), or (S, 1, 1, T) a slot each; shared by the layers."""
+        """(1, T), shared by the rows and the layers."""
         import jax.numpy as jnp
 
-        t = jnp.arange(max_len)
-        if pos.ndim == 0:
-            return (t <= pos)[None, :]
-        return (t[None, :] <= pos[:, None])[:, None, None, :]
+        return (jnp.arange(max_len) <= pos)[None, :]
 
     def _write(self, cache, new):
-        import jax
         import jax.numpy as jnp
         from jax import lax
 
         z = jnp.zeros((), jnp.int32)
-        if self.pos.ndim == 0:
-            return lax.dynamic_update_slice(cache, new, (z, z, self.pos, z))
-        return jax.vmap(
-            lambda c, u, p: lax.dynamic_update_slice(c, u, (z, p, z)))(
-                cache, new, self.pos)
+        return lax.dynamic_update_slice(cache, new, (z, z, self.pos, z))
 
     def attend(self, q, k, v):
         kc, vc = self._write(self.entry[0], k), self._write(self.entry[1], v)

@@ -472,7 +472,7 @@ class LlamaDecoder(PagedDecoder):
     One :meth:`attention` and one :meth:`layer` over a cache view
     (``models.decoder``): the paged programs the serving engine runs are
     the shared base's, and the dense-cache programs here (offline
-    ``generate``, the slots engine) run the same layer over a
+    ``generate``) run the same layer over a
     :class:`~.decoder.DenseCache`.  The math mirrors
     ``LlamaAttention``/``LlamaMLP``; attention scores accumulate in
     float32 (``preferred_element_type``) exactly like the training
@@ -566,8 +566,7 @@ class LlamaDecoder(PagedDecoder):
 
     def _step_impl(self, w, caches, ids_t, pos):
         """One token a row against dense caches: ids_t (B,) int32, pos
-        () int32 (offline ``generate``) or (S,) (the slots engine: a
-        position a slot) → (logits (B, V), caches)."""
+        () int32 → (logits (B, V), caches)."""
         import jax.numpy as jnp
 
         pos = jnp.asarray(pos, jnp.int32)

@@ -59,7 +59,7 @@ def masked_attention(q, k, v, mask):
 
     Where it still runs (``models/decoder.py``'s views): the suffix
     behind a radix prefix (``BehindPrefix``), the dense caches of
-    offline ``generate`` and the slots engine (``DenseCache``), the
+    offline ``generate`` (``DenseCache``), the
     paged pool's gather path (``ops.paged_attention.window_attention``),
     and whole-sequence prefill (``Causal``) on the CPU, on a mesh-placed
     engine and at the buckets

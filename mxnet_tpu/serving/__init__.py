@@ -21,9 +21,7 @@ places weights tensor-parallel (a ``dp`` axis → independent replicas
 behind one queue, least-loaded routed), K/V lives in a paged block
 pool (``kv_cache.PagedKVCacheManager`` — capacity bounded by tokens in
 flight, not ``max_len × slots``), and prefill/decode run as separate
-lanes with explicit KV handoff (``lanes.py``).  The r8 slot ledger
-(``KVCacheManager``) stays importable behind
-``ServerConfig(kv_mode="slots")`` for A/B.
+lanes with explicit KV handoff (``lanes.py``).
 
 Observability (r12, docs/observability.md): every request can carry a
 span context (``telemetry.tracing``) yielding one connected trace per
@@ -34,7 +32,7 @@ request across the queue → prefill → handoff → decode thread hops;
 ``ServerConfig(slo={...})`` turns on per-tenant TTFT/TPOT goodput
 accounting (``metrics.SLOTracker``).
 
-Speed multipliers (r19, paged only): ``ServerConfig(draft_net=...)``
+Speed multipliers (r19): ``ServerConfig(draft_net=...)``
 turns on greedy speculative decoding — a small draft llama proposes
 ``spec_k`` tokens per slot, the target scores the whole window in ONE
 batched multi-position forward, and rejected suffixes roll back via
@@ -58,8 +56,7 @@ Quick start::
 from .protocol import (Request, ServerClosedError,     # noqa: F401
                        ServerOverloadedError)
 from .bucketing import BucketPolicy, pad_batch, pow2_bucket  # noqa: F401
-from .kv_cache import (BlockAllocator, KVCacheManager,  # noqa: F401
-                       PagedKVCacheManager)
+from .kv_cache import BlockAllocator, PagedKVCacheManager  # noqa: F401
 from .radix import RadixPrefixCache                    # noqa: F401
 from .scheduler import BatchScheduler, RequestQueue    # noqa: F401
 from .lanes import (DecodeLane, PrefillLane, Replica,  # noqa: F401
@@ -70,7 +67,7 @@ from .metrics import (MetricsServer, SLOTracker,       # noqa: F401
                       prometheus_text)
 
 __all__ = ["Request", "ServerOverloadedError", "ServerClosedError",
-           "BucketPolicy", "pow2_bucket", "pad_batch", "KVCacheManager",
+           "BucketPolicy", "pow2_bucket", "pad_batch",
            "PagedKVCacheManager", "BlockAllocator", "RadixPrefixCache",
            "RequestQueue", "BatchScheduler", "ServerConfig",
            "InferenceServer", "GenerativeServer",

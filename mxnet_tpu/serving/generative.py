@@ -13,24 +13,18 @@ one array ``(num_slots,) + shape`` for each ``(shape, dtype)`` its spec
 states (``CacheSpec.state_arrays``: a convolution's ring in the weights'
 dtype, a linear-attention layer's float32 matrices beside it), each
 beside the pools, donated through the step like them, and written whole
-at admission.  Two
-storage modes share one surface (``kv_mode=``):
+at admission.
 
-* **paged** (default since r11) — K/V lives in a shared block pool per
-  layer whose stored format is ``ops.paged_attention``'s alone
-  (``pool_shape``; ``kv_pack`` KV heads to a stored row); each slot
-  carries a block-table row (vacant entries = ``num_blocks``, the
-  out-of-bounds sentinel XLA's scatter rule DROPS).  Capacity is bounded
-  by tokens in flight, not ``max_len × num_slots``.  Programs: **step**
-  (``_step_blocks_impl`` — one signature, ever), **prefill**
-  (``_prefill_rows_impl`` at one (admit_bucket, prompt_bucket) shape per
-  bucket pair, returning RAW K/V rows — no max_len allocation), and
-  **scatter** (``ops.paged_attention.scatter_rows`` at the admitted
-  physical block ids — the prefill→decode KV handoff).
-* **slots** — the r8 ledger layout, one ``(num_slots, Hkv, max_len,
-  head_dim)`` cache per layer (the decoder's ``DenseCache`` view), kept
-  behind the pool for A/B (``ServerConfig(kv_mode="slots")``) and the
-  legacy single-loop scheduler.
+K/V lives in a shared block pool per layer whose stored format is
+``ops.paged_attention``'s alone (``pool_shape``; ``kv_pack`` KV heads to
+a stored row); each slot carries a block-table row (vacant entries =
+``num_blocks``, the out-of-bounds sentinel XLA's scatter rule DROPS).
+Capacity is bounded by tokens in flight, not ``max_len × num_slots``.
+Programs: **step** (``_step_blocks_impl`` — one signature, ever),
+**prefill** (``_prefill_rows_impl`` at one (admit_bucket, prompt_bucket)
+shape per bucket pair, returning RAW K/V rows — no max_len allocation),
+and **scatter** (``ops.paged_attention.scatter_rows`` at the admitted
+physical block ids — the prefill→decode KV handoff).
 
 With ``mesh=`` the engine is mesh-native: every weight (and the KV
 pool) is committed to the mesh via the serving partition-rule table
@@ -69,11 +63,8 @@ step K+1 before it fetches step K (``serving/lanes.py``
 frozen at engine build; ``int8=True`` stores them as per-output-channel
 symmetric int8 (scale = max|row|/127) and
 dequantizes in-kernel — the weight-only quantization the int8 MXU
-pricing in ``INT8_TOPOLOGY_r05.json`` motivates.
-
-The scheduler half (:class:`GenerativeScheduler`) runs the legacy
-single-thread admit/step/evict loop for the slots mode; the paged path
-is driven by the disaggregated lanes in :mod:`.lanes`.
+pricing in ``INT8_TOPOLOGY_r05.json`` motivates.  The engine is driven
+by the disaggregated lanes in :mod:`.lanes`.
 """
 from __future__ import annotations
 
@@ -87,19 +78,16 @@ from jax.profiler import TraceAnnotation
 from .. import telemetry
 from ..telemetry import numerics as _numerics
 from ..telemetry import retrace as _retrace
-from ..telemetry import tracing
 from ..base import MXNetError
 from ..ops import flash_attention
-from .bucketing import BucketPolicy, pad_batch
-from .kv_cache import KVCacheManager
-from .protocol import ServerClosedError
 from .scheduler import _materialize
 
-__all__ = ["LlamaServingEngine", "GenerativeScheduler"]
+__all__ = ["LlamaServingEngine", "REFUSALS", "refuse"]
 
 #: reviewed signature budget (mxlint T15): one decode-step program per
 #: (batch bucket, cache length bucket) plus one prefill program per
-#: prompt bucket — the bucket tables are fixed at engine construction
+#: prompt bucket — the prefill lane pads every batch to a bucket of its
+#: replica's ``BucketPolicy`` (``serving/lanes.py``), fixed at construction
 __compile_signatures__ = {
     "serving_step": "1 per (batch bucket, cache bucket); prefill adds "
                     "1 per prompt bucket",
@@ -171,72 +159,93 @@ def _named_weight_items(w):
     return items
 
 
-#: why an engine refuses an option for a model whose cache spec has
-#: per-slot state (or routed experts): named, so nothing falls back
-_STATE_REFUSALS = {
-    "slots": "kv_mode='slots' keeps keys and values only: a model with "
-             "per-slot state needs kv_mode='paged'",
-    "spec": "speculative decoding (draft_net / spec_k) over per-slot "
-            "state needs the state rolled back when a draft is "
-            "rejected, which this engine does not do",
-    "mesh": "a mesh-placed engine (mesh=) has no partition rule for a "
-            "per-slot state or an expert bank",
-    "int8": "int8=True quantizes the dense decoder's matrices only; a "
-            "model with per-slot state or routed experts is served in "
-            "its load dtype",
+_NO_RULE = ("a mesh-placed engine (mesh=) has no partition rule for a "
+            "per-slot state or an expert bank")
+_DENSE_ONLY = ("int8=True quantizes the dense decoder's matrices only; a "
+               "model with per-slot state or routed experts is served in "
+               "its load dtype")
+
+#: what an option needs of a cache that a kind of cache does not give:
+#: (the spec's trait, the option) -> why it is refused, by name, so
+#: nothing falls back.  The traits are :func:`refuse`'s to read off a
+#: spec: ``"loop"`` (a stack run several times a token, ``passes`` above
+#: 1), ``"latent"`` (layers that keep latent rows and select what a
+#: query reads), ``"block"`` (a block decoder, ``decoding``) and
+#: ``"state"`` (per-slot state, or routed experts).  A new cache kind
+#: costs rows here and nothing in the callers.
+REFUSALS = {
+    ("state", "spec"):
+        "speculative decoding (draft_net / spec_k) over per-slot state "
+        "needs the state rolled back when a draft is rejected, which "
+        "this engine does not do",
+    ("state", "mesh"): _NO_RULE,
+    ("state", "int8"): _DENSE_ONLY,
+    ("state", "radix"):
+        "radix_cache=True shares a prompt prefix's K/V blocks; a model "
+        "with per-slot state also needs a snapshot of the state at the "
+        "prefix boundary, which nothing keeps",
+    ("block", "spec"):
+        "speculative decoding (draft_net / spec_k) verifies a "
+        "left-to-right draft; a block decoder commits the positions of a "
+        "block in any order",
+    ("block", "mesh"): _NO_RULE,
+    ("block", "int8"): _DENSE_ONLY,
+    ("block", "radix"):
+        "radix_cache=True shares a prompt prefix's K/V blocks behind a "
+        "causal suffix; a block decoder's prompt ends inside a block "
+        "that the decode lane opens, and its prefill has no suffix path",
+    # not an option: what a block decoder cannot have beside it
+    ("block", "state"):
+        "a block decoder's pass is made again for a slot that was left "
+        "out of the booking (parked, or carried a pass past its "
+        "request's end: the tick runs a pass ahead), which a per-slot "
+        "state layer's step, not idempotent, does not allow",
+    ("latent", "spec"):
+        "speculative decoding (draft_net / spec_k) verifies a window of "
+        "columns a slot; the latent step selects and attends one new "
+        "token a slot",
+    ("latent", "mesh"):
+        "a mesh-placed engine (mesh=) has no partition rule for a latent "
+        "pool, an index-key pool or an expert bank",
+    ("latent", "int8"): _DENSE_ONLY,
+    ("latent", "radix"):
+        "radix_cache=True reuses K/V blocks behind a causal suffix; the "
+        "suffix prefill has no view over latent blocks and their index "
+        "keys",
+    ("loop", "spec"):
+        "speculative decoding (draft_net / spec_k) has not been carried "
+        "through the loop over passes: a stack run several times a token "
+        "decodes one token a slot a step",
+    ("loop", "mesh"):
+        "a mesh-placed engine (mesh=) has no partition rule for a pool "
+        "that holds a layer's blocks once a pass",
+    ("loop", "int8"):
+        "int8=True quantizes the dense decoder's weight tree; a stack run "
+        "several times a token is served in its load dtype",
+    ("loop", "radix"):
+        "radix_cache=True prefills a suffix behind shared prefix blocks; "
+        "the suffix prefill has no loop over the passes of a stack run "
+        "several times a token",
 }
 
-#: and for a model that decodes by blocks (``CacheSpec.decoding``)
-_BLOCK_REFUSALS = {
-    "slots": "kv_mode='slots' decodes one token a slot a step under a "
-             "causal mask: a block decoder needs kv_mode='paged'",
-    "spec": "speculative decoding (draft_net / spec_k) verifies a "
-            "left-to-right draft; a block decoder commits the positions "
-            "of a block in any order",
-    "mesh": _STATE_REFUSALS["mesh"],
-    "int8": _STATE_REFUSALS["int8"],
-    "state": "a block decoder's pass is made again for a slot that was "
-             "left out of the booking (parked, or carried a pass past "
-             "its request's end: the tick runs a pass ahead), which a "
-             "per-slot state layer's step, not idempotent, does not "
-             "allow",
-}
 
-
-#: and for a model whose layers keep latent rows and select what a query
-#: reads (a ``"latent"`` cache kind); ``radix`` is the replica's to say
-_LATENT_REFUSALS = {
-    "slots": "kv_mode='slots' keeps dense keys and values: a latent "
-             "layer's rows and index keys live in kv_mode='paged' pools",
-    "spec": "speculative decoding (draft_net / spec_k) verifies a window "
-            "of columns a slot; the latent step selects and attends one "
-            "new token a slot",
-    "mesh": "a mesh-placed engine (mesh=) has no partition rule for a "
-            "latent pool, an index-key pool or an expert bank",
-    "int8": _STATE_REFUSALS["int8"],
-    "radix": "radix_cache=True reuses K/V blocks behind a causal suffix; "
-             "the suffix prefill has no view over latent blocks and their "
-             "index keys",
-}
-
-
-#: and for a model that runs its stack several times a token
-#: (``CacheSpec.passes`` above 1); ``radix`` is the replica's to say
-_LOOP_REFUSALS = {
-    "slots": "kv_mode='slots' keeps one dense K and V cache a layer: a "
-             "stack run several times a token keeps rows a pass, in "
-             "kv_mode='paged' pools",
-    "spec": "speculative decoding (draft_net / spec_k) has not been "
-            "carried through the loop over passes: a stack run several "
-            "times a token decodes one token a slot a step",
-    "mesh": "a mesh-placed engine (mesh=) has no partition rule for a "
-            "pool that holds a layer's blocks once a pass",
-    "int8": "int8=True quantizes the dense decoder's weight tree; a "
-            "stack run several times a token is served in its load dtype",
-    "radix": "radix_cache=True prefills a suffix behind shared prefix "
-             "blocks; the suffix prefill has no loop over the passes of a "
-             "stack run several times a token",
-}
+def refuse(spec, *, spec_k=0, mesh=None, int8=False, radix=False):
+    """Raise :data:`REFUSALS`' sentence for the first option asked for
+    that ``spec``'s cache does not give (the engine asks for what it is
+    given, the replica for ``radix``); a spec of K/V layers alone,
+    decoded the next token a step, is refused nothing."""
+    trait = "loop" if spec.passes > 1 else \
+        "latent" if spec.latent_layers else \
+        "block" if spec.decoding is not None else \
+        "state" if spec.state_layers or spec.expert_layers else None
+    if trait is None:
+        return
+    for option, asked in (("spec", spec_k), ("mesh", mesh is not None),
+                          ("int8", int8), ("radix", radix)):
+        if asked:
+            raise MXNetError(REFUSALS[trait, option])
+    if trait == "block" and spec.state_layers:
+        raise MXNetError(REFUSALS["block", "state"])
 
 
 class BlockTick(NamedTuple):
@@ -313,23 +322,15 @@ class LlamaServingEngine:
     ``cache_spec().decoding`` says so) today."""
 
     def __init__(self, net, max_len=None, num_slots=4, int8=False,
-                 kv_mode="slots", block_size=16, num_blocks=None,
-                 mesh=None, partition_rules=None, replica_id=0,
-                 spec_k=0):
+                 block_size=16, num_blocks=None, mesh=None,
+                 partition_rules=None, replica_id=0, spec_k=0):
         import jax
         import jax.numpy as jnp
 
-        if kv_mode not in ("paged", "slots"):
-            raise MXNetError(f"unknown kv_mode {kv_mode!r}; "
-                             "expected 'paged' or 'slots'")
         self.spec_k = int(spec_k)
-        if self.spec_k and kv_mode != "paged":
-            raise MXNetError("speculative verify (spec_k > 0) requires "
-                             "kv_mode='paged'")
         self.max_len = int(max_len or net.config.max_seq_len)
         self.num_slots = int(num_slots)
         self.int8 = bool(int8)
-        self.kv_mode = kv_mode
         self.mesh = mesh
         self.partition_rules = partition_rules
         self.replica_id = int(replica_id)
@@ -342,19 +343,7 @@ class LlamaServingEngine:
         #: or the decoder's ``BlockDecoding``
         block = self.block = spec.decoding
         self.decoding = "next_token" if block is None else "block_diffusion"
-        if spec.state_layers or spec.expert_layers or spec.latent_layers \
-                or block is not None or spec.passes > 1:
-            why = _LOOP_REFUSALS if spec.passes > 1 else \
-                _LATENT_REFUSALS if spec.latent_layers else \
-                _STATE_REFUSALS if block is None else _BLOCK_REFUSALS
-            for key, bad in (("slots", kv_mode != "paged"),
-                             ("spec", self.spec_k),
-                             ("mesh", mesh is not None),
-                             ("int8", self.int8)):
-                if bad:
-                    raise MXNetError(why[key])
-            if block is not None and spec.state_layers:
-                raise MXNetError(_BLOCK_REFUSALS["state"])
+        refuse(spec, spec_k=self.spec_k, mesh=mesh, int8=self.int8)
         w = dec._weights()
         self._w = _quantize_tree(w) if self.int8 else w
         deq = _dequantize_tree if self.int8 else (lambda t: t)
@@ -367,65 +356,55 @@ class LlamaServingEngine:
         #: where the weights (and so the cache) live: with the mesh and
         #: the shapes, what the attention kernels are chosen from
         platform = next(iter(w["emb"].devices())).platform
-        if kv_mode == "paged":
-            self.block_size = int(block_size)
-            if self.block_size < 1:
-                raise MXNetError("block_size must be >= 1")
-            #: static block-table width — the step gathers this many
-            #: blocks per slot regardless of actual ownership
-            self.max_blocks = -(-self.max_len // self.block_size)
-            self.num_blocks = int(num_blocks or
-                                  self.num_slots * self.max_blocks)
-            from ..ops import latent_cache, paged_attention
+        self.block_size = int(block_size)
+        if self.block_size < 1:
+            raise MXNetError("block_size must be >= 1")
+        #: static block-table width — the step gathers this many
+        #: blocks per slot regardless of actual ownership
+        self.max_blocks = -(-self.max_len // self.block_size)
+        self.num_blocks = int(num_blocks or
+                              self.num_slots * self.max_blocks)
+        from ..ops import latent_cache, paged_attention
 
-            # decided once, before the pool is made, from where the
-            # weights (and so the pool) live, the mesh and the shapes:
-            # the kernel reads whole 128-lane rows, so under it heads of
-            # 64 are stored ``kv_pack`` = 2 to a row, (blocks, Hkv // 2,
-            # bs, 128); the gather path keeps one head a row
-            pack = paged_attention.applicable(
-                platform, mesh, spec.head_dim, spec.num_kv_heads,
-                self.block_size, dt) if spec.kv_layers else 0
-            paged_kernel = pack > 0
-            self.kv_pack = pack = max(1, pack)
-            # a stack run several times keeps a pass's blocks behind the
-            # pass before's, in one pool a layer
-            pshape = paged_attention.pool_shape(
-                spec.passes * self.num_blocks, spec.num_kv_heads,
-                spec.head_dim, self.block_size, pack)
-            lshapes = latent_cache.pool_shapes(
-                self.num_blocks, self.block_size, spec.latent_dim,
-                spec.index_dim)
-            # one entry a layer, by the spec: a (K, V) pool pair, a
-            # (latent rows, index keys) pool pair, or the arrays of the
-            # layer's per-slot state, each of its own dtype
-            self._pool = [
-                (jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
-                if kind == "kv" else
-                tuple(jnp.zeros(shape, dt) for shape in lshapes)
-                if kind == "latent" else
-                spec.state_entry(
-                    jnp.zeros((self.num_slots,) + shape, sdt or dt)
-                    for shape, sdt in spec.state_arrays)
-                for kind in spec.layers]
-            if len(self._pool) != len(w["layers"]):
-                raise MXNetError(
-                    f"the decoder's cache_spec() states {len(self._pool)} "
-                    f"layers and its weights hold {len(w['layers'])}")
-            self._tables = np.full((self.num_slots, self.max_blocks),
-                                   self.num_blocks, np.int32)
-            self._caches = None
-        else:
-            self.block_size = self.num_blocks = self.max_blocks = None
-            paged_kernel, self.kv_pack = False, 1
-            shape = (self.num_slots, cfg.num_kv_heads, self.max_len,
-                     cfg.head_dim)
-            self._caches = [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                            for _ in range(cfg.num_layers)]
-            self._pool = self._tables = None
+        # decided once, before the pool is made, from where the
+        # weights (and so the pool) live, the mesh and the shapes:
+        # the kernel reads whole 128-lane rows, so under it heads of
+        # 64 are stored ``kv_pack`` = 2 to a row, (blocks, Hkv // 2,
+        # bs, 128); the gather path keeps one head a row
+        pack = paged_attention.applicable(
+            platform, mesh, spec.head_dim, spec.num_kv_heads,
+            self.block_size, dt) if spec.kv_layers else 0
+        paged_kernel = pack > 0
+        self.kv_pack = pack = max(1, pack)
+        # a stack run several times keeps a pass's blocks behind the
+        # pass before's, in one pool a layer
+        pshape = paged_attention.pool_shape(
+            spec.passes * self.num_blocks, spec.num_kv_heads,
+            spec.head_dim, self.block_size, pack)
+        lshapes = latent_cache.pool_shapes(
+            self.num_blocks, self.block_size, spec.latent_dim,
+            spec.index_dim)
+        # one entry a layer, by the spec: a (K, V) pool pair, a
+        # (latent rows, index keys) pool pair, or the arrays of the
+        # layer's per-slot state, each of its own dtype
+        self._pool = [
+            (jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
+            if kind == "kv" else
+            tuple(jnp.zeros(shape, dt) for shape in lshapes)
+            if kind == "latent" else
+            spec.state_entry(
+                jnp.zeros((self.num_slots,) + shape, sdt or dt)
+                for shape, sdt in spec.state_arrays)
+            for kind in spec.layers]
+        if len(self._pool) != len(w["layers"]):
+            raise MXNetError(
+                f"the decoder's cache_spec() states {len(self._pool)} "
+                f"layers and its weights hold {len(w['layers'])}")
+        self._tables = np.full((self.num_slots, self.max_blocks),
+                               self.num_blocks, np.int32)
         with self.dev_lock:
             # uncontended at construction; taken so the placement
-            # writes to _w/_pool/_caches share the KV mutators' guard
+            # writes to _w/_pool share the KV mutators' guard
             self._place_on_mesh_locked()
         # host mirrors: last emitted token + next write position per slot
         self._last = np.zeros(self.num_slots, np.int32)
@@ -467,10 +446,9 @@ class LlamaServingEngine:
             self.cache_itemsize)
         #: bytes a token keeps in the block tables over every layer and
         #: pass, by the spec (a record's ``kv_bytes`` is this times its
-        #: tokens); 0 for the slot ledger, which has no blocks
-        self.kv_bytes_per_token = 0 if kv_mode != "paged" else \
-            spec.kv_bytes_per_block(self.block_size, self.cache_itemsize) \
-            // self.block_size
+        #: tokens)
+        self.kv_bytes_per_token = spec.kv_bytes_per_block(
+            self.block_size, self.cache_itemsize) // self.block_size
         #: What each lane has on the device's queue, for the other to
         #: see: the ``seq`` of the newest step()/verify() whose dispatch
         #: has returned and whose tokens are not yet on the host (the
@@ -540,163 +518,141 @@ class LlamaServingEngine:
             # where it wrote the slot's mirror since, -1 elsewhere
             return jnp.where(ids >= 0, ids, prev[:ids.shape[0]])
 
-        if kv_mode == "paged":
+        def _behind(first, out):
+            # a model with routed experts returns their row counts
+            # third: they go out in the same array as what the host
+            # fetches, behind it
+            first = first.reshape(-1)
+            if self._n_counts:
+                first = jnp.concatenate(
+                    [first, out[2].reshape(-1).astype(jnp.int32)])
+            return first
 
-            def _behind(first, out):
-                # a model with routed experts returns their row counts
-                # third: they go out in the same array as what the host
-                # fetches, behind it
-                first = first.reshape(-1)
-                if self._n_counts:
-                    first = jnp.concatenate(
-                        [first, out[2].reshape(-1).astype(jnp.int32)])
-                return first
+        def _tokens(logits, out):
+            return _behind(jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                           out)
 
-            def _tokens(logits, out):
-                return _behind(jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                               out)
+        def _step_fn(wq, pools, tables, ids, prev, pos):
+            out = dec._step_blocks_impl(
+                deq(wq), pools, tables, _carried(ids, prev), pos,
+                paged_kernel=paged_kernel)
+            logits, pools = out[:2]
+            tok = _tokens(logits, out)
+            # a selecting model's step says, last, what it read
+            picked = out[-1:] if spec.select_topk else ()
+            if numerics_on:
+                return (tok, pools, _numerics.stats_of(logits)) + picked
+            return (tok, pools) + picked
 
-            def _step_fn(wq, pools, tables, ids, prev, pos):
-                out = dec._step_blocks_impl(
-                    deq(wq), pools, tables, _carried(ids, prev), pos,
+        def _prefill_fn(wq, ids, t0):
+            out = dec._prefill_rows_impl(
+                deq(wq), ids, t0, flash=self._prefill_flash(ids.shape[1]))
+            rows, logits = out[:2]
+            return _tokens(logits, out), rows
+
+        if block is not None:
+            from ..models.decoder import block_advance, block_commit
+
+            def _step_fn(wq, pools, tables, carried, host):
+                # one pass over every slot's block: (S, B) ids in,
+                # the block's K/V written in place, the commit rule
+                # on the device; out go the ids after the pass and
+                # what it committed.  A block without masks commits
+                # nothing: its pass is the one whose K/V stays.
+                # What a block holds, its masks, its pass count and
+                # its cursor are the pass before's own (``carried``,
+                # its last output); the host's row (``host``: the
+                # same four, then ``fresh``, then ``stepped``) where
+                # it wrote the slot since.  The pass books itself:
+                # out goes, last, what the next one reads
+                bl = block.block_len
+                rows = (host[:, :bl], host[:, bl:2 * bl],
+                        host[:, 2 * bl], host[:, 2 * bl + 1])
+                fresh, stepped = (host[:, 2 * bl + 2] != 0,
+                                  host[:, 2 * bl + 3] != 0)
+                state = tuple(
+                    jnp.where(fresh[:, None] if c.ndim > 1 else fresh,
+                              h.astype(c.dtype), c)
+                    for h, c in zip(rows, carried))
+                ids, masked, nstep, pos0 = state
+                out = dec._verify_blocks_impl(
+                    deq(wq), pools, tables, ids, pos0,
                     paged_kernel=paged_kernel)
                 logits, pools = out[:2]
-                tok = _tokens(logits, out)
-                # a selecting model's step says, last, what it read
-                picked = out[-1:] if spec.select_topk else ()
+                ids, commit = block_commit(logits, ids, masked, nstep,
+                                           block)
+                tok = _behind(jnp.concatenate(
+                    [ids, commit.astype(jnp.int32)], axis=1), out)
+                nxt = block_advance(jnp, state, ids, commit, stepped,
+                                    block)
                 if numerics_on:
-                    return (tok, pools, _numerics.stats_of(logits)) + picked
-                return (tok, pools) + picked
+                    return tok, pools, _numerics.stats_of(logits), nxt
+                return tok, pools, nxt
 
             def _prefill_fn(wq, ids, t0):
+                # the prompt's whole blocks only, and no token: what
+                # comes back first is the opening block, the rest
+                # of the prompt and then mask ids (the last row's
+                # logits are returned to nobody: dead to the compiler)
+                bl = block.block_len
+                whole = t0 // bl * bl
                 out = dec._prefill_rows_impl(
-                    deq(wq), ids, t0, flash=self._prefill_flash(ids.shape[1]))
-                rows, logits = out[:2]
-                return _tokens(logits, out), rows
+                    deq(wq), ids, whole,
+                    flash=self._prefill_flash(ids.shape[1]))
+                at = whole[:, None] \
+                    + jnp.arange(bl, dtype=jnp.int32)[None]
+                opening = jnp.where(
+                    at < t0[:, None],
+                    jnp.take_along_axis(
+                        ids, jnp.minimum(at, ids.shape[1] - 1), axis=1),
+                    jnp.int32(block.mask_id))
+                return _behind(opening, out), out[0]
 
-            if block is not None:
-                from ..models.decoder import block_advance, block_commit
+        def _verify_fn(wq, pools, tables, toks, pos0):
+            logits, pools = dec._verify_blocks_impl(
+                deq(wq), pools, tables, toks, pos0,
+                paged_kernel=paged_kernel)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if numerics_on:
+                return tok, pools, _numerics.stats_of(logits)
+            return tok, pools
 
-                def _step_fn(wq, pools, tables, carried, host):
-                    # one pass over every slot's block: (S, B) ids in,
-                    # the block's K/V written in place, the commit rule
-                    # on the device; out go the ids after the pass and
-                    # what it committed.  A block without masks commits
-                    # nothing: its pass is the one whose K/V stays.
-                    # What a block holds, its masks, its pass count and
-                    # its cursor are the pass before's own (``carried``,
-                    # its last output); the host's row (``host``: the
-                    # same four, then ``fresh``, then ``stepped``) where
-                    # it wrote the slot since.  The pass books itself:
-                    # out goes, last, what the next one reads
-                    bl = block.block_len
-                    rows = (host[:, :bl], host[:, bl:2 * bl],
-                            host[:, 2 * bl], host[:, 2 * bl + 1])
-                    fresh, stepped = (host[:, 2 * bl + 2] != 0,
-                                      host[:, 2 * bl + 3] != 0)
-                    state = tuple(
-                        jnp.where(fresh[:, None] if c.ndim > 1 else fresh,
-                                  h.astype(c.dtype), c)
-                        for h, c in zip(rows, carried))
-                    ids, masked, nstep, pos0 = state
-                    out = dec._verify_blocks_impl(
-                        deq(wq), pools, tables, ids, pos0,
-                        paged_kernel=paged_kernel)
-                    logits, pools = out[:2]
-                    ids, commit = block_commit(logits, ids, masked, nstep,
-                                               block)
-                    tok = _behind(jnp.concatenate(
-                        [ids, commit.astype(jnp.int32)], axis=1), out)
-                    nxt = block_advance(jnp, state, ids, commit, stepped,
-                                        block)
-                    if numerics_on:
-                        return tok, pools, _numerics.stats_of(logits), nxt
-                    return tok, pools, nxt
+        def _gather_fn(pools, rows_idx):
+            # rows_idx (KB, NBP) int32 physical block ids in logical
+            # order, sentinel-padded — dense per-row prefix K/V
+            # copies (KB, Hkv, NBP*bs, hd) for the suffix prefill
+            return [tuple(paged_attention.gather_rows(p, rows_idx, pack)
+                          for p in pair) for pair in pools]
 
-                def _prefill_fn(wq, ids, t0):
-                    # the prompt's whole blocks only, and no token: what
-                    # comes back first is the opening block, the rest
-                    # of the prompt and then mask ids (the last row's
-                    # logits are returned to nobody: dead to the compiler)
-                    bl = block.block_len
-                    whole = t0 // bl * bl
-                    out = dec._prefill_rows_impl(
-                        deq(wq), ids, whole,
-                        flash=self._prefill_flash(ids.shape[1]))
-                    at = whole[:, None] \
-                        + jnp.arange(bl, dtype=jnp.int32)[None]
-                    opening = jnp.where(
-                        at < t0[:, None],
-                        jnp.take_along_axis(
-                            ids, jnp.minimum(at, ids.shape[1] - 1), axis=1),
-                        jnp.int32(block.mask_id))
-                    return _behind(opening, out), out[0]
+        def _prefill_sfx_fn(wq, pre_kv, ids, t0, s0):
+            rows, logits = dec._prefill_suffix_impl(
+                deq(wq), pre_kv, ids, t0, s0)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
+                rows
 
-            def _verify_fn(wq, pools, tables, toks, pos0):
-                logits, pools = dec._verify_blocks_impl(
-                    deq(wq), pools, tables, toks, pos0,
-                    paged_kernel=paged_kernel)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                if numerics_on:
-                    return tok, pools, _numerics.stats_of(logits)
-                return tok, pools
-
-            def _gather_fn(pools, rows_idx):
-                # rows_idx (KB, NBP) int32 physical block ids in logical
-                # order, sentinel-padded — dense per-row prefix K/V
-                # copies (KB, Hkv, NBP*bs, hd) for the suffix prefill
-                return [tuple(paged_attention.gather_rows(p, rows_idx, pack)
-                              for p in pair) for pair in pools]
-
-            def _prefill_sfx_fn(wq, pre_kv, ids, t0, s0):
-                rows, logits = dec._prefill_suffix_impl(
-                    deq(wq), pre_kv, ids, t0, s0)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                    rows
-
-            def _scatter_fn(pools, rows, flat_idx, slots=None):
-                # rows[l]: (KB, Hkv, Lp, hd) raw prefill K/V, written
-                # block by block at flat_idx (the prefill→decode KV
-                # handoff, ``paged_attention.scatter_rows``).  A state
-                # layer's rows, (KB,) + shape for each of its arrays,
-                # replace the WHOLE state of ``slots`` (vacant rows:
-                # slot id num_slots, dropped), so a reused slot never
-                # sees its predecessor's; a latent layer's rows (KB, Lp,
-                # width) go block by block as its format stores them
-                # (of a stack run several times: (passes, KB, Hkv, Lp,
-                # hd), pass t's into that pass's blocks of the pool)
-                by_block = {"kv": paged_attention.scatter_pass_rows
-                            if spec.passes > 1
-                            else paged_attention.scatter_rows,
-                            "latent": latent_cache.scatter_rows}
-                return [
-                    tuple(by_block[kind](p, r, flat_idx)
-                          for p, r in zip(entry, row))
-                    if kind in by_block
-                    else jax.tree_util.tree_map(
-                        lambda e, r: e.at[slots].set(r, mode="drop"),
-                        entry, row)
-                    for kind, entry, row in zip(spec.layers, pools, rows)]
-
-        else:
-
-            def _step_fn(wq, caches, ids, prev, pos):
-                logits, caches = dec._step_impl(
-                    deq(wq), caches, _carried(ids, prev), pos)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                if numerics_on:
-                    return tok, caches, _numerics.stats_of(logits)
-                return tok, caches
-
-            def _prefill_fn(wq, ids, t0):
-                caches, logits = dec._prefill_impl(
-                    deq(wq), ids, t0, flash=self._prefill_flash(ids.shape[1]))
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                    caches
-
-            def _scatter_fn(caches, rows, slots):
-                return [(kc.at[slots].set(nk), vc.at[slots].set(nv))
-                        for (kc, vc), (nk, nv) in zip(caches, rows)]
+        def _scatter_fn(pools, rows, flat_idx, slots=None):
+            # rows[l]: (KB, Hkv, Lp, hd) raw prefill K/V, written
+            # block by block at flat_idx (the prefill→decode KV
+            # handoff, ``paged_attention.scatter_rows``).  A state
+            # layer's rows, (KB,) + shape for each of its arrays,
+            # replace the WHOLE state of ``slots`` (vacant rows:
+            # slot id num_slots, dropped), so a reused slot never
+            # sees its predecessor's; a latent layer's rows (KB, Lp,
+            # width) go block by block as its format stores them
+            # (of a stack run several times: (passes, KB, Hkv, Lp,
+            # hd), pass t's into that pass's blocks of the pool)
+            by_block = {"kv": paged_attention.scatter_pass_rows
+                        if spec.passes > 1
+                        else paged_attention.scatter_rows,
+                        "latent": latent_cache.scatter_rows}
+            return [
+                tuple(by_block[kind](p, r, flat_idx)
+                      for p, r in zip(entry, row))
+                if kind in by_block
+                else jax.tree_util.tree_map(
+                    lambda e, r: e.at[slots].set(r, mode="drop"),
+                    entry, row)
+                for kind, entry, row in zip(spec.layers, pools, rows)]
 
         self._step = jax.jit(_step_fn, donate_argnums=(1,))
         #: the last token-at-a-time step's output, on the device: the
@@ -722,12 +678,9 @@ class LlamaServingEngine:
                 self._blk_ids, self._blk_masked, self._blk_step, self._pos))
         self._prefill = jax.jit(_prefill_fn)
         self._scatter = jax.jit(_scatter_fn, donate_argnums=(0,))
-        if kv_mode == "paged":
-            self._verify = jax.jit(_verify_fn, donate_argnums=(1,))
-            self._gather = jax.jit(_gather_fn)
-            self._prefill_sfx = jax.jit(_prefill_sfx_fn)
-        else:
-            self._verify = self._gather = self._prefill_sfx = None
+        self._verify = jax.jit(_verify_fn, donate_argnums=(1,))
+        self._gather = jax.jit(_gather_fn)
+        self._prefill_sfx = jax.jit(_prefill_sfx_fn)
 
     # -- mesh placement -------------------------------------------------------
     def _place_on_mesh_locked(self):
@@ -767,9 +720,8 @@ class LlamaServingEngine:
             leaf = tree["layers"][path[1]][path[2]] if len(path) == 3 \
                 else tree[path[0]]
             shapes[name] = leaf_shape(leaf)
-        kv = self._pool if self.kv_mode == "paged" else self._caches
-        for i in range(len(kv)):
-            shapes[f"layers.{i}.kv_pool"] = kv[i][0].shape
+        for i, (kb, _) in enumerate(self._pool):
+            shapes[f"layers.{i}.kv_pool"] = kb.shape
         specs = rules.specs(shapes, mesh)
         for name, path in items:
             spec = specs.get(name, ())
@@ -790,13 +742,10 @@ class LlamaServingEngine:
                 tree[path[0]] = placed
         self._w = tree
         placed_kv = []
-        for i, (kb, vb) in enumerate(kv):
+        for i, (kb, vb) in enumerate(self._pool):
             spec = specs.get(f"layers.{i}.kv_pool", ())
             placed_kv.append((put(kb, spec), put(vb, spec)))
-        if self.kv_mode == "paged":
-            self._pool = placed_kv
-        else:
-            self._caches = placed_kv
+        self._pool = placed_kv
 
     def _dev(self, a, dtype=np.int32):
         """Host array → device, committed to the engine's mesh when
@@ -883,8 +832,7 @@ class LlamaServingEngine:
         return sorted(self._signatures)
 
     def kv_pool_bytes(self, by_kind=False):
-        """PER-DEVICE bytes of the cache storage (pool or slot caches):
-        K and V over the layers that own them, plus the per-slot states
+        """PER-DEVICE bytes of the cache storage: K and V over the layers that own them, plus the per-slot states
         of the layers that keep one — the figure the memory planner's
         ``plan_kv_pool`` predicts pre-build.  ``by_kind`` splits it:
         ``{"kv_blocks": ..., "slot_state": ...}`` and, where a layer
@@ -901,9 +849,7 @@ class LlamaServingEngine:
             return a.nbytes
 
         with self.dev_lock:
-            kv = self._pool if self.kv_mode == "paged" else self._caches
-            kinds = self.cache_spec.layers if self.kv_mode == "paged" \
-                else ("kv",) * len(kv)
+            kv, kinds = self._pool, self.cache_spec.layers
             blocks = sum(shard_bytes(e[0]) + shard_bytes(e[1])
                          for k, e in zip(kinds, kv) if k == "kv")
             arrays = [sum(shard_bytes(self.cache_spec.entry_arrays(e)[i])
@@ -957,40 +903,12 @@ class LlamaServingEngine:
                                      fields["expert_rows_max"])
         return toks, fields
 
-    # -- transitions (slots mode: legacy single-loop scheduler) ---------------
-    def admit(self, prompts_pad, t0s, slots):
-        """Prefill ``prompts_pad`` (kb, lp) with true lengths ``t0s``
-        (kb,) and scatter the resulting cache rows into ``slots`` (kb,)
-        — vacant padding rows carry slot index ``num_slots`` and are
-        dropped by XLA's out-of-bounds scatter rule.  Returns each
-        row's first generated token (kb,) on host."""
-        if self.kv_mode != "slots":
-            raise MXNetError("admit() is the slot-ledger path; the paged "
-                             "engine admits via prefill_rows/commit_rows")
-        kb, lp = prompts_pad.shape
-        self._note(("prefill", kb, lp))
-        toks, rows = self._prefill(self._w, self._dev(prompts_pad),
-                                   self._dev(t0s))
-        with self.dev_lock:
-            self._caches = self._scatter(self._caches, rows,
-                                         self._dev(slots))
-        first = _materialize([toks])[0]
-        with self.dev_lock:
-            for i, s in enumerate(slots):
-                if s < self.num_slots:
-                    self._last[s] = first[i]
-                    self._fresh[s] = True
-                    self._pos[s] = t0s[i]
-        return first
-
-    # -- transitions (paged mode: disaggregated lanes) ------------------------
+    # -- transitions (the prefill lane's) -------------------------------------
     def prefill_rows(self, prompts_pad, t0s):
         """Prefill lane, phase 1: the heavy prompt forward.  Runs
         WITHOUT the device lock — decode steps interleave freely while
         a long prompt prefills.  Returns (first-token device array,
         per-layer raw K/V rows) for :meth:`commit_rows`."""
-        if self.kv_mode != "paged":
-            raise MXNetError("prefill_rows() requires kv_mode='paged'")
         kb, lp = prompts_pad.shape
         self._note(("prefill", kb, lp))
         return self._prefill(self._w, self._dev(prompts_pad),
@@ -1065,8 +983,6 @@ class LlamaServingEngine:
         decode step donates the pool buffer, so an unlocked read could
         alias a donated buffer mid-step; the returned copies are fresh
         arrays, safe to consume outside the lock."""
-        if self.kv_mode != "paged":
-            raise MXNetError("gather_prefix() requires kv_mode='paged'")
         kb, nbp = rows_idx.shape
         self._note(("gather", kb, nbp * self.block_size))
         with self.dev_lock:
@@ -1081,8 +997,6 @@ class LlamaServingEngine:
         length (block-aligned; 0 = no hit).  Returns (first-token
         device array, suffix K/V rows) for
         :meth:`commit_rows(..., skip_blocks=)`."""
-        if self.kv_mode != "paged":
-            raise MXNetError("prefill_suffix() requires kv_mode='paged'")
         kb, ls = prompts_pad.shape
         lpre = prefix_kv[0][0].shape[2]
         self._note(("prefill_sfx", kb, lpre, ls))
@@ -1090,7 +1004,7 @@ class LlamaServingEngine:
                                  self._dev(prompts_pad),
                                  self._dev(t0s), self._dev(s0s))
 
-    # -- transitions (both modes) ---------------------------------------------
+    # -- transitions (the decode lane's) --------------------------------------
     def _step_queued(self, seq):
         """Step ``seq`` is on the device's queue: say so to the prefill
         lane (until its tokens are fetched) -> (whether the step before
@@ -1128,10 +1042,9 @@ class LlamaServingEngine:
         mirrors, queue the step program and move the ``active`` slots'
         cursors on -> the :class:`StepHandle` that :meth:`fetch_step`
         takes.  Every other slot runs as a vacant one, its table row the
-        sentinel (paged: its K/V write drops, a state layer leaves its
-        state as it is, the experts it is routed to do not count it;
-        slots mode: the write lands in its own row) and its output read
-        by nobody: so a slot committed and not yet adopted, or finished
+        sentinel (its K/V write drops, a state layer leaves its
+        state as it is, the experts it is routed to do not count it) and
+        its output read by nobody: so a slot committed and not yet adopted, or finished
         by a step whose tokens are not yet booked, is never stepped.
 
         A slot's input token is the one the step before produced for
@@ -1178,21 +1091,16 @@ class LlamaServingEngine:
                     # a copy goes up: the mirror moves on below, and an
                     # upload may read the host's array after this returns
                     at = self._dev(self._pos.copy())
-                    if self.kv_mode == "paged":
-                        mine = np.zeros(self.num_slots, bool)
-                        mine[act] = True
-                        tables = np.where(mine[:, None], self._tables,
-                                          np.int32(self.num_blocks))
-                        out = self._step(
-                            self._w, self._pool, self._dev(tables), ids,
-                            self._toks, at)
-                        self._pool = out[1]
-                        if self.cache_spec.select_topk:
-                            selected = out[-1]
-                    else:
-                        out = self._step(self._w, self._caches, ids,
-                                         self._toks, at)
-                        self._caches = out[1]
+                    mine = np.zeros(self.num_slots, bool)
+                    mine[act] = True
+                    tables = np.where(mine[:, None], self._tables,
+                                      np.int32(self.num_blocks))
+                    out = self._step(
+                        self._w, self._pool, self._dev(tables), ids,
+                        self._toks, at)
+                    self._pool = out[1]
+                    if self.cache_spec.select_topk:
+                        selected = out[-1]
                     self._toks = out[0]
                     self._fresh[:] = True
                     self._fresh[act] = False
@@ -1301,8 +1209,6 @@ class LlamaServingEngine:
         :meth:`set_mirror`.  The window's K/V lands in the pool
         optimistically; rejected columns stay beyond the rolled-back
         cursor (masked) until the next window overwrites them."""
-        if self._verify is None:
-            raise MXNetError("verify() requires kv_mode='paged'")
         self._note(("verify",))
         lstats = None
         t_lock = time.perf_counter()
@@ -1370,246 +1276,9 @@ class LlamaServingEngine:
             self._last[slot] = 0
             self._fresh[slot] = True
             self._pos[slot] = 0
-            if self._tables is not None:
-                self._tables[slot] = self.num_blocks
+            self._tables[slot] = self.num_blocks
             if self.block is not None:
                 self._blk_ids[slot] = 0
                 self._blk_masked[slot] = False
                 self._blk_step[slot] = 0
                 self._writes[slot] += 1
-
-
-class GenerativeScheduler:
-    """Admit/step/evict loop: continuous batching over the engine.
-
-    This is the LEGACY single-thread loop for the slot-ledger mode
-    (``ServerConfig(kv_mode="slots")``) — one thread interleaves
-    admission (prefill+scatter) with decode steps.  The paged default
-    runs the disaggregated prefill/decode lanes in :mod:`.lanes`
-    instead.  Requests carry ``prompt_ids`` + ``max_new_tokens``.
-    Admission happens between decode steps whenever slots are free — a
-    late request joins the in-flight batch without stopping anyone
-    else's decode (its ``joined_step``/``done_step`` land in the
-    request record, which is how the tier-1 late-join test proves it).
-    """
-
-    def __init__(self, engine, queue, policy=None, summary_every=16,
-                 poll_s=0.02, slo=None):
-        if engine.kv_mode != "slots":
-            raise MXNetError(
-                "GenerativeScheduler drives the slot-ledger engine; "
-                "paged engines are driven by serving.lanes")
-        self.engine = engine
-        self.queue = queue
-        self.slo = slo   # shared SLOTracker (metrics.py) or None
-        self.policy = policy or BucketPolicy(
-            max_batch=engine.num_slots, max_length=engine.max_len,
-            min_batch=1, min_length=8)
-        self.mgr = KVCacheManager(engine.num_slots, engine.max_len)
-        self.summary_every = int(summary_every)
-        self.poll_s = float(poll_s)
-        self.completed = 0
-        self.failed = 0
-        self.batches = 0
-        self._seqs = {}       # slot -> (request, [generated tokens])
-        self._stop = threading.Event()
-        self._thread = None
-
-    # -- lifecycle ------------------------------------------------------------
-    def start(self):
-        if self._thread is not None:
-            return
-        self._thread = threading.Thread(target=self._loop,
-                                        name="mxt-serving-decode",
-                                        daemon=True)
-        self._thread.start()
-
-    def stop(self, drain=True):
-        self._stop.set()
-        self.queue.close()
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-        if drain:
-            while self._seqs or len(self.queue):
-                self._admit_pending()
-                if not self._seqs:
-                    break
-                self._decode_step()
-        for r in self.queue.take_group(lambda r: 0, 1 << 30):
-            r.future.set_exception(
-                ServerClosedError("server stopped before execution"))
-
-    # -- the loop -------------------------------------------------------------
-    def _loop(self):
-        while not self._stop.is_set():
-            admitted = self._admit_pending()
-            if self._seqs:
-                self._decode_step()
-            elif not admitted:
-                self.queue.wait_for_item(self.poll_s)
-
-    def _prompt_bucket(self, req):
-        return self.policy.length_bucket(len(req.prompt_ids))
-
-    def _admit_pending(self):
-        """Admit queued requests into free slots (one prompt-length
-        bucket group per call, the FIFO head's)."""
-        free = self.mgr.free_slots()
-        if not free or not len(self.queue):
-            return False
-        group = self.queue.take_group(
-            self._prompt_bucket, min(free, self.policy.max_batch))
-        if not group:
-            return False
-        t_start = time.perf_counter()
-        lb = self._prompt_bucket(group[0])
-        kb = self.policy.batch_bucket(len(group))
-        try:
-            prompts = pad_batch([np.asarray(r.prompt_ids, np.int32)
-                                 for r in group], kb, lb)
-            t0s = np.full(kb, len(group[0].prompt_ids), np.int32)
-            slots = np.full(kb, self.engine.num_slots, np.int32)
-            for i, r in enumerate(group):
-                t0s[i] = len(r.prompt_ids)
-                slot = self.mgr.admit(r.id, t0s[i], r.max_new_tokens,
-                                      step=self.engine.steps)
-                slots[i] = slot
-                r.slot = int(slot)
-                r.replica = self.engine.replica_id
-                r.joined_step = self.engine.steps
-                r.t_start = t_start
-                r.bucket = (kb, lb)
-                r.batch_size = len(group)
-            first = self.engine.admit(prompts, t0s, slots)
-        except Exception as exc:
-            for r in group:
-                if r.slot is not None and r.slot in self.mgr._active:
-                    self.mgr.evict(r.slot)
-                r.replica = self.engine.replica_id
-                r.future.set_exception(exc)
-                self._fail(r, exc, lane="prefill")
-            tracing.incident("replica_exception",
-                             context={"replica": self.engine.replica_id,
-                                      "lane": "prefill",
-                                      "error": repr(exc)})
-            return False
-        t_first = time.perf_counter()
-        mates = [r.id for r in group]
-        for i, r in enumerate(group):
-            r.t_first = t_first
-            if r.trace is not None:
-                r.trace.add("queue", r.t_submit, t_start,
-                            replica=r.replica)
-                r.trace.add("prefill", t_start, t_first,
-                            replica=r.replica, slot=r.slot,
-                            bucket=list(r.bucket),
-                            mates=[m for m in mates if m != r.id])
-            self._seqs[r.slot] = (r, [int(first[i])])
-            if self.mgr.consume(r.slot):
-                self._finish(r.slot)
-        telemetry.count("serving.admitted", len(group))
-        return True
-
-    def _decode_step(self):
-        active = self.mgr.active_slots()
-        t0 = time.perf_counter()
-        try:
-            toks = self.engine.step(active)
-        except Exception as exc:
-            for slot in list(active):
-                req, _ = self._seqs.pop(slot)
-                self.mgr.evict(slot)
-                self.engine.clear_slot(slot)
-                req.future.set_exception(exc)
-                self._fail(req, exc, lane="decode")
-            tracing.incident("replica_exception",
-                             context={"replica": self.engine.replica_id,
-                                      "lane": "decode",
-                                      "error": repr(exc)})
-            return
-        t1 = time.perf_counter()
-        self.batches += 1
-        telemetry.hist("serving.batch_size", len(active))
-        step_idx = self.engine.steps
-        for slot in active:
-            self.mgr.advance(slot)   # the step wrote K/V at slot's pos
-            req, tokens = self._seqs[slot]
-            tokens.append(int(toks[slot]))
-            if req.trace is not None:
-                req.trace.add("decode.step", t0, t1, step=step_idx,
-                              batch=len(active), replica=req.replica,
-                              slot=slot)
-            if self.mgr.consume(slot):
-                self._finish(slot)
-
-    def _finish(self, slot):
-        req, tokens = self._seqs.pop(slot)
-        self.mgr.evict(slot)
-        self.engine.clear_slot(slot)
-        req.t_done = time.perf_counter()
-        req.done_step = self.engine.steps
-        n = req.max_new_tokens
-        req.future.set_result(np.concatenate(
-            [np.asarray(req.prompt_ids, np.int32),
-             np.asarray(tokens[:n], np.int32)]))
-        self._account(req)
-
-    def _account(self, req):
-        self.completed += 1
-        telemetry.count("serving.completed")
-        telemetry.count(f"serving.completed|replica={req.replica}")
-        rec = req.record(lane="decode")
-        tag = f"|replica={req.replica}"
-        if rec["queue_wait_ms"] is not None:
-            telemetry.hist("serving.queue_wait_ms", rec["queue_wait_ms"])
-            telemetry.hist("serving.queue_wait_ms" + tag,
-                           rec["queue_wait_ms"])
-        if rec["total_ms"] is not None:
-            telemetry.hist("serving.total_ms", rec["total_ms"])
-            telemetry.hist("serving.total_ms" + tag, rec["total_ms"])
-        if rec.get("ttft_ms") is not None:
-            telemetry.hist("serving.ttft_ms", rec["ttft_ms"])
-            telemetry.hist("serving.ttft_ms" + tag, rec["ttft_ms"])
-        if rec.get("tpot_ms") is not None:
-            telemetry.hist("serving.tpot_ms", rec["tpot_ms"])
-            telemetry.hist("serving.tpot_ms" + tag, rec["tpot_ms"])
-        if self.slo is not None:
-            rec["slo_met"] = self.slo.observe(
-                tenant=req.tenant, ttft_ms=rec.get("ttft_ms"),
-                tpot_ms=rec.get("tpot_ms"))
-        telemetry.emit(rec)
-        if req.trace is not None:
-            req.trace.event("evict", replica=req.replica, slot=req.slot)
-            tracing.finish(req.trace, status="ok", replica=req.replica,
-                           lane="decode", request_id=req.id)
-        if self.summary_every and self.completed % self.summary_every == 0:
-            self.emit_summary()
-
-    def _fail(self, req, exc, lane):
-        """Failure-path twin of :meth:`_account`: error record with
-        replica + lane, failed counters, trace seal."""
-        self.failed += 1
-        telemetry.count("serving.failed")
-        telemetry.count(f"serving.failed|replica={req.replica}")
-        req.t_done = time.perf_counter()
-        telemetry.emit(req.record(lane=lane, status="error",
-                                  error=repr(exc)))
-        if req.trace is not None:
-            tracing.finish(req.trace, status="error",
-                           replica=req.replica, lane=lane,
-                           error=repr(exc), request_id=req.id)
-
-    def emit_summary(self):
-        telemetry.emit({
-            "record": "serving.latency",
-            "completed": self.completed,
-            "failed": self.failed,
-            "batches": self.batches,
-            "rejected": self.queue.rejected,
-            "queue_wait_ms": telemetry.hist_summary("serving.queue_wait_ms"),
-            "total_ms": telemetry.hist_summary("serving.total_ms"),
-            "ttft_ms": telemetry.hist_summary("serving.ttft_ms"),
-            "batch_size": telemetry.hist_summary("serving.batch_size"),
-            "kv_cache": self.mgr.stats(),
-        })

@@ -1,40 +1,28 @@
 """KV-cache capacity accounting for continuous-batching decode.
 
-Two generations of the same host-side ledger live here:
+:class:`PagedKVCacheManager` is the host-side ledger of the **paged
+pool**: device K/V lives in fixed-size blocks (``block_size`` tokens
+each) drawn from a shared :class:`BlockAllocator`; each request owns a
+*block list* that holds the tokens it has written and grows with them, a
+block at a time as a step is queued, while its ``prompt_len +
+max_new_tokens`` budget stays on the books as a claim: a block is
+granted, and a request admitted, only if every admitted request can
+still finish afterwards (the safe-state rule,
+:meth:`PagedKVCacheManager._safe`).  So pool capacity is bounded by
+tokens in flight, not by ``max_len × num_slots`` nor by the budgets: a
+long-prompt + short-prompt mix whose worst-case reservations exceed the
+pool outright is admitted (the r11 capacity acceptance test).
 
-* :class:`KVCacheManager` — the r8 **slot ledger**: one fixed
-  ``max_len`` cache row per slot, capacity = ``num_slots × max_len``
-  tokens whether or not a request ever uses its worst case.  Kept
-  importable behind the paged pool for A/B (``ServerConfig(
-  kv_mode="slots")``) and for the legacy single-loop scheduler.
-* :class:`PagedKVCacheManager` — the r11 **paged pool**: device K/V
-  lives in fixed-size blocks (``block_size`` tokens each) drawn from a
-  shared :class:`BlockAllocator`; each request owns a *block list*
-  that holds the tokens it has written and grows with them, a block at
-  a time as a step is queued, while its ``prompt_len +
-  max_new_tokens`` budget stays on the books as a claim: a block is
-  granted, and a request admitted, only if every admitted request can
-  still finish afterwards (the safe-state rule,
-  :meth:`PagedKVCacheManager._safe`).  So pool capacity is bounded by
-  tokens in flight, not by ``max_len × num_slots`` nor by the budgets.
-  A long-prompt + short-prompt mix that the slot ledger could only
-  host with worst-case reservations fits a much smaller pool (the r11
-  capacity acceptance test admits a mix whose slot-ledger worst case
-  exceeds the pool outright).
-
-Both managers expose the same transition surface (``admit`` /
-``advance`` / ``consume`` / ``evict``) plus ``check()`` invariants and
-``stats()`` with fragmentation and peak-token occupancy.  The paged
-manager is touched by TWO lane threads (prefill admits, decode
-grants/advances/evicts — docs/serving.md) and serializes its transitions on an
-internal lock; the slot ledger stays single-threaded under the legacy
-scheduler.
+The manager's transitions (``admit`` / ``advance`` / ``consume`` /
+``evict``) come with ``check()`` invariants and ``stats()`` with
+fragmentation and peak-token occupancy.  It is touched by TWO lane
+threads (prefill admits, decode grants/advances/evicts —
+docs/serving.md) and serializes its transitions on an internal lock.
 
 Device-side block contents are the engine's problem: a freshly
 allocated block may hold a previous tenant's K/V, but the per-slot
 causal mask (``t <= pos``) hides every position the current request has
-not yet written, so stale rows are unreachable — the same invariant
-that lets the slot ledger skip zeroing slot rows.
+not yet written, so stale rows are unreachable.
 """
 from __future__ import annotations
 
@@ -43,8 +31,8 @@ import threading
 from ..base import MXNetError
 from ..models.decoder import CacheSpec  # noqa: F401  (its home; kept importable here)
 
-__all__ = ["KVCacheManager", "PagedKVCacheManager", "BlockAllocator",
-           "SlotState", "CacheSpec"]
+__all__ = ["PagedKVCacheManager", "BlockAllocator", "SlotState",
+           "CacheSpec"]
 
 
 class SlotState:
@@ -185,115 +173,6 @@ class BlockAllocator:
         bad = [b for b, c in self._refs.items() if c < 1]
         if bad:
             raise MXNetError(f"allocated blocks with refcount < 1: {bad}")
-        return True
-
-
-class KVCacheManager:
-    """Fixed-capacity slot ledger (``num_slots`` concurrent sequences),
-    each slot owning a full ``max_len`` cache row."""
-
-    def __init__(self, num_slots, max_len):
-        if num_slots < 1:
-            raise MXNetError("num_slots must be >= 1")
-        self.num_slots = int(num_slots)
-        self.max_len = int(max_len)
-        self._free = list(range(self.num_slots - 1, -1, -1))  # pop() -> 0 first
-        self._active = {}           # slot -> SlotState
-        self._admits = 0
-        self._evictions = 0
-        self._peak_occupancy = 0
-        self._peak_tokens = 0
-
-    # -- queries --------------------------------------------------------------
-    def free_slots(self):
-        return len(self._free)
-
-    def active_slots(self):
-        """Occupied slot ids, ascending."""
-        return sorted(self._active)
-
-    def state(self, slot):
-        return self._active[slot]
-
-    def tokens_in_flight(self):
-        """K/V rows live right now = sum of active write positions."""
-        return sum(st.pos for st in self._active.values())
-
-    def stats(self):
-        """Occupancy counters plus the r11 capacity metrics: the slot
-        ledger reserves ``max_len`` rows for every OCCUPIED slot, so its
-        ``fragmentation`` is the fraction of those reservations holding
-        no live token — the number the paged pool exists to shrink."""
-        reserved = len(self._active) * self.max_len
-        live = self.tokens_in_flight()
-        cap = self.num_slots * self.max_len
-        return {"admits": self._admits, "evictions": self._evictions,
-                "occupancy": len(self._active),
-                "peak_occupancy": self._peak_occupancy,
-                "num_slots": self.num_slots,
-                "capacity_tokens": cap,
-                "tokens_in_flight": int(live),
-                "peak_tokens": int(self._peak_tokens),
-                "utilization": round(live / cap, 4) if cap else 0.0,
-                "fragmentation": round(1.0 - live / reserved, 4)
-                if reserved else 0.0}
-
-    # -- transitions ----------------------------------------------------------
-    def admit(self, request_id, prompt_len, max_new_tokens, step=0):
-        """Claim a slot for a prefilled request: position starts at
-        ``prompt_len`` (the first decode write lands there).  Returns
-        the slot id, or None when the cache is at capacity."""
-        if prompt_len + max_new_tokens > self.max_len:
-            raise MXNetError(
-                f"sequence budget {prompt_len}+{max_new_tokens} exceeds "
-                f"cache max_len {self.max_len}")
-        if not self._free:
-            return None
-        slot = self._free.pop()
-        self._active[slot] = SlotState(request_id, prompt_len,
-                                       max_new_tokens, step)
-        self._admits += 1
-        self._peak_occupancy = max(self._peak_occupancy, len(self._active))
-        self._peak_tokens = max(self._peak_tokens, self.tokens_in_flight())
-        return slot
-
-    def advance(self, slot):
-        """One decode step wrote ``slot``'s K/V at its current position:
-        bump the write cursor.  (The prefill-produced first token never
-        advances — its K/V lands with the next step's write.)"""
-        st = self._active[slot]
-        st.pos += 1
-        if st.pos > self.max_len:
-            raise MXNetError(f"slot {slot} overran max_len {self.max_len}")
-        self._peak_tokens = max(self._peak_tokens, self.tokens_in_flight())
-
-    def consume(self, slot):
-        """One output token was emitted for ``slot``'s request.  Returns
-        True when the token budget is exhausted (caller evicts)."""
-        st = self._active[slot]
-        st.remaining -= 1
-        return st.remaining <= 0
-
-    def evict(self, slot):
-        """Release ``slot`` back to the free list."""
-        if slot not in self._active:
-            raise MXNetError(f"slot {slot} is not active")
-        del self._active[slot]
-        self._free.append(slot)
-        self._evictions += 1
-
-    def check(self):
-        """Assert the ledger invariants (used by tests and debug)."""
-        free = set(self._free)
-        active = set(self._active)
-        if free & active:
-            raise MXNetError(f"slots both free and active: {free & active}")
-        if free | active != set(range(self.num_slots)):
-            raise MXNetError("slot ledger lost track of slots")
-        for slot, st in self._active.items():
-            if not 0 <= st.pos <= self.max_len:
-                raise MXNetError(f"slot {slot} position {st.pos} out of "
-                                 f"range [0, {self.max_len}]")
         return True
 
 

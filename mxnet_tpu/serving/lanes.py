@@ -90,7 +90,6 @@ from jax.profiler import TraceAnnotation
 
 from .. import sanitizer as _san
 from .. import telemetry
-from ..base import MXNetError
 from ..telemetry import capacity
 from ..telemetry import tracing
 from .bucketing import pad_batch
@@ -459,19 +458,18 @@ class PrefillLane:
                 for i, req in enumerate(group):
                     rx.insert(req.prompt_ids, block_lists[i])
             if r.draft is not None:
-                # the draft engine prefills the FULL prompt into its
-                # own slot caches, then aligns its mirror with the
-                # target's first token (draft.admit picked its own)
+                # the draft prefills the FULL prompt into its slots' own
+                # blocks; the commit aligns its mirror with the target's
+                # first token (the draft's own is fetched by nobody)
                 lbf = r.policy.length_bucket(
                     max(len(q.prompt_ids) for q in group))
                 fulls = pad_batch([np.asarray(q.prompt_ids, np.int32)
                                    for q in group], kb, lbf)
-                r.draft.admit(fulls, t0s, slots)
-                for i in range(len(group)):
-                    s = int(slots[i])
-                    if s < eng.num_slots:
-                        r.draft.set_mirror(s, int(first[i]),
-                                           int(t0s[i]))
+                _, rows = r.draft.prefill_rows(fulls, t0s)
+                r.draft.commit_rows(
+                    rows, slots,
+                    [None if b is None else r.draft_blocks(int(s))
+                     for s, b in zip(slots, block_lists)], t0s, first)
         except Exception as exc:
             gate.close()
             eng.prefill_in_flight = ()
@@ -1224,28 +1222,28 @@ class Replica:
                  max_prefill_tokens=None, summary_every=32, slo=None,
                  draft_net=None, spec_k=0, radix_cache=False,
                  prefix_cache_tokens=None):
-        from .generative import LlamaServingEngine
+        from .generative import LlamaServingEngine, refuse
 
         self.index = int(index)
         self.policy = policy
         self.spec_k = int(spec_k) if draft_net is not None else 0
         self.engine = LlamaServingEngine(
             net, max_len=policy.max_length, num_slots=num_slots,
-            int8=int8, kv_mode="paged", block_size=block_size,
-            num_blocks=num_blocks, mesh=mesh,
-            partition_rules=partition_rules, replica_id=self.index,
-            spec_k=self.spec_k)
+            int8=int8, block_size=block_size, num_blocks=num_blocks,
+            mesh=mesh, partition_rules=partition_rules,
+            replica_id=self.index, spec_k=self.spec_k)
         self.draft = None
         if self.spec_k > 0:
-            # the draft runs the r8 slot-ledger engine: fixed per-slot
-            # cache rows, no block bookkeeping to keep consistent with
-            # the target's pool — its k sequential steps are cheap by
-            # model size, not by storage cleverness
+            # the draft's pool is num_slots x max_blocks blocks and slot
+            # s owns blocks s x max_blocks ... for life
+            # (``draft_blocks``): fixed per-slot cache rows, no block
+            # bookkeeping to keep consistent with the target's pool.
+            # ``spec_k``: a draft is refused what speculation is
             self.draft = LlamaServingEngine(
                 draft_net, max_len=policy.max_length,
-                num_slots=num_slots, int8=int8, kv_mode="slots",
+                num_slots=num_slots, int8=int8, block_size=block_size,
                 mesh=mesh, partition_rules=partition_rules,
-                replica_id=self.index)
+                replica_id=self.index, spec_k=self.spec_k)
             self.draft.span_names = ("mxt.draft.dispatch",
                                      "mxt.draft.fetch")
         spec = self.engine.cache_spec
@@ -1258,25 +1256,7 @@ class Replica:
                 self.engine.block_size, itemsize),
             state_bytes_per_slot=spec.state_bytes_per_slot(itemsize))
         self.radix = None
-        if radix_cache and spec.decoding is not None:
-            raise MXNetError(
-                "radix_cache=True shares a prompt prefix's K/V blocks "
-                "behind a causal suffix; a block decoder's prompt ends "
-                "inside a block that the decode lane opens, and its "
-                "prefill has no suffix path")
-        if radix_cache and spec.latent_layers:
-            from .generative import _LATENT_REFUSALS
-
-            raise MXNetError(_LATENT_REFUSALS["radix"])
-        if radix_cache and spec.passes > 1:
-            from .generative import _LOOP_REFUSALS
-
-            raise MXNetError(_LOOP_REFUSALS["radix"])
-        if radix_cache and spec.state_layers:
-            raise MXNetError(
-                "radix_cache=True shares a prompt prefix's K/V blocks; a "
-                "model with per-slot state also needs a snapshot of the "
-                "state at the prefix boundary, which nothing keeps")
+        refuse(spec, radix=radix_cache)
         if radix_cache:
             from .radix import RadixPrefixCache
             cap = int(prefix_cache_tokens
@@ -1308,6 +1288,11 @@ class Replica:
         # the step ahead of them had been fetched
         self.batches = 0
         self.steps_ahead = 0
+
+    def draft_blocks(self, slot):
+        """The draft pool's blocks that are ``slot``'s for life."""
+        n = self.draft.max_blocks
+        return list(range(slot * n, (slot + 1) * n))
 
     # -- dispatcher-facing ----------------------------------------------------
     def load(self):

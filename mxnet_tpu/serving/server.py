@@ -72,15 +72,17 @@ class ServerConfig:
         self.max_new_tokens = int(max_new_tokens)
         self.int8 = bool(int8)
         self.calib_data = calib_data
-        # generative KV storage: "paged" (block pool + disaggregated
-        # prefill/decode lanes, the default) or "slots" (the r8 ledger
-        # + single-loop scheduler, kept for A/B).  ``num_blocks=None``
-        # sizes the pool at ledger parity (num_slots × max_len tokens);
-        # smaller pools bound capacity by tokens in flight instead.
-        if kv_mode not in ("paged", "slots"):
-            raise MXNetError(f"unknown kv_mode {kv_mode!r}; "
-                             "expected 'paged' or 'slots'")
-        self.kv_mode = kv_mode
+        # generative KV storage is the block pool under the prefill and
+        # decode lanes.  ``kv_mode`` once chose between it and a slot
+        # ledger: the keyword is taken for its callers' sake and not
+        # kept.  ``num_blocks=None`` sizes the pool at num_slots ×
+        # max_len tokens; smaller pools bound capacity by tokens in
+        # flight instead.
+        if kv_mode != "paged":
+            raise MXNetError(
+                f"kv_mode={kv_mode!r}: the slot-ledger mode is gone; "
+                "the server keeps K/V in the paged block pool "
+                "(kv_mode='paged', the one value still taken)")
         self.block_size = int(block_size)
         self.num_blocks = num_blocks
         # observability (r12): ``http_port`` starts the live metrics
@@ -93,7 +95,7 @@ class ServerConfig:
         self.http_host = str(http_host)
         self.slo = slo
         self.slo_window = int(slo_window)
-        # speculative decoding + radix prefix cache (r19, paged only):
+        # speculative decoding + radix prefix cache (r19):
         # ``draft_net`` switches speculation on (the small proposer
         # model; ``spec_k`` proposals per slot per verify), and
         # ``radix_cache`` turns on prompt-prefix KV reuse with an LRU
@@ -346,39 +348,16 @@ class GenerativeServer(_ServerBase):
     ``"llama_serving"`` family table) exactly like ``Trainer`` does for
     training; a ``dp`` mesh axis runs one independent replica per dp
     slice behind this one front queue, routed least-loaded by
-    :class:`~.lanes.ReplicaDispatcher`.  ``config.kv_mode`` selects the
-    paged block-pool storage with disaggregated prefill/decode lanes
-    (default) or the legacy r8 slot ledger + single-loop scheduler
-    (``"slots"``, A/B baseline; single replica only).
+    :class:`~.lanes.ReplicaDispatcher`.  K/V lives in the paged block
+    pool, under disaggregated prefill/decode lanes.
     """
 
     def __init__(self, net, config=None, mesh=None, partition_rules=None):
         super().__init__(config)
-        from .generative import GenerativeScheduler, LlamaServingEngine
         from .lanes import Replica, ReplicaDispatcher
 
         cfg = self.config
         self.mesh = mesh
-        self._replicas = None
-        self._dispatcher = None
-        if cfg.kv_mode == "slots":
-            if mesh is not None and "dp" in mesh.axis_names:
-                raise MXNetError(
-                    "kv_mode='slots' runs the single-loop scheduler; "
-                    "dp replicas need kv_mode='paged'")
-            if cfg.draft_net is not None or cfg.radix_cache:
-                raise MXNetError(
-                    "speculative decoding and the radix prefix cache "
-                    "require kv_mode='paged'")
-            self.engine = LlamaServingEngine(
-                net, max_len=cfg.policy.max_length,
-                num_slots=cfg.num_slots, int8=cfg.int8,
-                kv_mode="slots", mesh=mesh,
-                partition_rules=partition_rules)
-            self._sched = GenerativeScheduler(
-                self.engine, self.queue, policy=cfg.policy,
-                summary_every=cfg.summary_every, slo=self.slo)
-            return
         self._replicas = [
             Replica(net, cfg.policy, index=i, mesh=sub,
                     partition_rules=partition_rules,
@@ -392,16 +371,13 @@ class GenerativeServer(_ServerBase):
             for i, sub in enumerate(_split_mesh(mesh))]
         self._dispatcher = ReplicaDispatcher(self.queue, self._replicas)
         self.engine = self._replicas[0].engine
-        self._sched = None
 
     @property
     def replicas(self):
-        return self._replicas or []
+        return self._replicas
 
     # -- lifecycle ------------------------------------------------------------
     def start(self):
-        if self._replicas is None:
-            return super().start()
         # fresh ledgers per server lifetime: replica indices restart at
         # 0, so a previous server's estimators must not leak in
         capacity.reset()
@@ -417,9 +393,6 @@ class GenerativeServer(_ServerBase):
             return
         self._running = False
         self._stop_http()
-        if self._replicas is None:
-            self._sched.stop(drain=drain)
-            return
         # flush the front queue into the replicas first, then drain
         # each replica (prefill lane before decode lane)
         self._dispatcher.stop(drain=drain)
@@ -454,21 +427,6 @@ class GenerativeServer(_ServerBase):
         and KV occupancy/fragmentation — every number a host-side
         counter read, never a device touch.  ``status`` is ``"ok"``
         only when every lane thread is alive."""
-        if self._replicas is None:
-            alive = self._sched._thread is not None \
-                and self._sched._thread.is_alive()
-            kv = self._sched.mgr.stats()
-            if not self._running:
-                status = "stopped"
-            else:
-                status = "ok" if alive else "degraded"
-            return {"status": status, "running": self._running,
-                    "scheduler_alive": alive,
-                    "queue_depth": len(self.queue),
-                    "rejected": self.queue.rejected,
-                    "kv_occupancy": kv["occupancy"],
-                    "kv_utilization": kv["utilization"],
-                    "kv_fragmentation": kv["fragmentation"]}
         reps = []
         all_alive = True
         any_saturated = False
@@ -528,16 +486,6 @@ class GenerativeServer(_ServerBase):
                     for r in items]
 
         rows = queued(self.queue)
-        if self._replicas is None:
-            for slot, (req, tokens) in list(self._sched._seqs.items()):
-                rows.append({"request_id": req.id, "state": "decoding",
-                             "replica": req.replica, "slot": slot,
-                             "tenant": req.tenant,
-                             "trace_id": req.trace.trace_id
-                             if req.trace is not None else None,
-                             "tokens_done": len(tokens),
-                             "max_new_tokens": req.max_new_tokens})
-            return rows
         for r in self._replicas:
             rows.extend(queued(r.queue, replica=r.index))
             rows.extend(r.decode.snapshot())
@@ -547,12 +495,6 @@ class GenerativeServer(_ServerBase):
         """Extend the base scrape gauges with live KV-pool state —
         per replica when there are several."""
         out = super().metrics_gauges()
-        if self._replicas is None:
-            kv = self._sched.mgr.stats()
-            out["serving.kv_occupancy"] = kv["occupancy"]
-            out["serving.kv_utilization"] = kv["utilization"]
-            out["serving.kv_fragmentation"] = kv["fragmentation"]
-            return out
         drafted = accepted = 0
         for r in self._replicas:
             kv = r.mgr.stats()
@@ -604,25 +546,6 @@ class GenerativeServer(_ServerBase):
         return out
 
     def stats(self):
-        if self._replicas is None:
-            out = {
-                "completed": self._sched.completed,
-                "failed": self._sched.failed,
-                "decode_steps": self.engine.steps,
-                "rejected": self.queue.rejected,
-                "pending": len(self.queue),
-                "kv_cache": self._sched.mgr.stats(),
-                "compiled_signatures": self.engine.compiled_signatures(),
-                "decode_attention": self.engine.decode_attention,
-                "prefill_attention": self.engine.prefill_attention,
-                "kv_pack": self.engine.kv_pack,
-                "expert_product": self.engine.expert_product,
-            }
-            telemetry.gauge("serving.kv_occupancy",
-                            out["kv_cache"]["occupancy"])
-            if self.slo is not None:
-                out["slo"] = self.slo.snapshot()
-            return out
         reps = self._replicas
         out = {
             "completed": sum(r.completed for r in reps),

@@ -384,7 +384,6 @@ def _draft():
 
 
 @pytest.mark.parametrize("name,kw,says", [
-    ("slots", dict(kv_mode="slots"), "kv_mode='slots'"),
     ("radix", dict(radix_cache=True), "latent blocks"),
     ("speculation", dict(draft_net="draft", spec_k=2), "one new token"),
     ("int8", dict(int8=True), "int8=True"),
@@ -467,7 +466,7 @@ def test_a_llama_server_has_no_selection_fields():
     net = llama_tiny()
     net.initialize()
     since = time.perf_counter()
-    with _server(net, kv_mode="paged") as srv:
+    with _server(net) as srv:
         req, = _generate(srv, [np.arange(1, 9)], [3])[0]
         st = srv.stats()
     assert req.selected is None and st["latent_layers"] == 0

@@ -340,7 +340,6 @@ def _draft():
 
 
 @pytest.mark.parametrize("name,kw,says", [
-    ("slots", dict(kv_mode="slots"), "kv_mode='slots'"),
     ("radix", dict(radix_cache=True), "snapshot"),
     ("speculation", dict(draft_net="draft", spec_k=2), "rolled back"),
     ("int8", dict(int8=True), "int8=True"),

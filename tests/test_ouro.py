@@ -371,7 +371,6 @@ def test_benchmark_config_keeps_every_published_width():
 # --- what is refused, loudly ---------------------------------------------------
 
 @pytest.mark.parametrize("name,kw,says", [
-    ("slots", dict(kv_mode="slots"), "kv_mode='slots'"),
     ("radix", dict(radix_cache=True), "radix_cache=True"),
     ("int8", dict(int8=True), "int8=True"),
 ])
@@ -391,7 +390,7 @@ def test_speculation_and_a_mesh_are_refused_by_name(tiny):
                      (dict(mesh=object()), "mesh-placed")):
         with pytest.raises(mx.MXNetError) as exc:
             LlamaServingEngine(tiny[0], max_len=64, num_slots=2,
-                               kv_mode="paged", block_size=4, **kw)
+                               block_size=4, **kw)
         assert says in str(exc.value) and "pass" in str(exc.value)
     with pytest.raises(mx.MXNetError) as exc:
         eng = _server(tiny[0]).engine

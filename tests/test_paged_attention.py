@@ -396,8 +396,7 @@ def test_prefill_then_paged_steps_equal_the_gluon_forward(name):
     net = _tiny_net(name)
     seq = np.random.default_rng(5).integers(1, 250, size=19)
     want = net(nd.array(seq[None], dtype="int32")).asnumpy()[0]
-    eng = LlamaServingEngine(net, max_len=32, num_slots=2, kv_mode="paged",
-                             block_size=4)
+    eng = LlamaServingEngine(net, max_len=32, num_slots=2, block_size=4)
     dec, w, t0, slot = eng._dec, eng._w, 6, 1
     for impl in ("_step_blocks_impl", "_verify_blocks_impl",
                  "_prefill_rows_impl", "_prefill_suffix_impl"):
@@ -530,8 +529,8 @@ def _engine_hd64(**kw):
                      num_kv_heads=2, num_layers=2)
     net.initialize()
     net.cast("bfloat16")
-    return LlamaServingEngine(net, max_len=64, num_slots=2, kv_mode="paged",
-                              block_size=16, **kw)
+    return LlamaServingEngine(net, max_len=64, num_slots=2, block_size=16,
+                              **kw)
 
 
 def test_engine_at_heads_of_64_here_stores_one_head_a_row():
@@ -598,13 +597,10 @@ def test_engines_here_take_the_gather_path():
     net = llama_tiny()
     net.initialize()
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
-    eng = LlamaServingEngine(net, max_len=64, num_slots=2, kv_mode="paged",
-                             mesh=mesh)
+    eng = LlamaServingEngine(net, max_len=64, num_slots=2, mesh=mesh)
     assert eng.decode_attention == "gather" and eng.kv_pack == 1
     # the mesh alone decides it, whatever the platform and the shapes
     assert pa.applicable("tpu", mesh, 128, 8, 16, jnp.bfloat16) == 0
-    assert LlamaServingEngine(net, max_len=64, num_slots=2,
-                              kv_mode="slots").decode_attention == "gather"
 
     since = time.perf_counter()
     cfg = ServerConfig(max_batch=2, max_length=64, min_length=8,

@@ -375,7 +375,6 @@ def test_engine_says_which_attention_its_prefill_runs(interpreted,
     from mxnet_tpu.serving.generative import LlamaServingEngine
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
-    eng = LlamaServingEngine(net, max_len=256, num_slots=2,
-                             kv_mode="paged", mesh=mesh)
+    eng = LlamaServingEngine(net, max_len=256, num_slots=2, mesh=mesh)
     assert eng.prefill_attention == "dense"
     assert eng.prefill_attention_at(256) == "dense"

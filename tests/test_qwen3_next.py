@@ -421,7 +421,6 @@ def test_a_slot_committed_and_not_yet_adopted_keeps_its_state(tiny):
 # --- what is refused, loudly ---------------------------------------------------
 
 @pytest.mark.parametrize("name,kw,says", [
-    ("slots", dict(kv_mode="slots"), "kv_mode='slots'"),
     ("radix", dict(radix_cache=True), "snapshot"),
     ("int8", dict(int8=True), "int8=True"),
 ])

@@ -413,7 +413,6 @@ def _draft():
 
 
 @pytest.mark.parametrize("name,kw,says", [
-    ("slots", dict(kv_mode="slots"), "kv_mode='slots'"),
     ("radix", dict(radix_cache=True), "block decoder"),
     ("speculation", dict(draft_net="draft", spec_k=2), "left-to-right"),
     ("int8", dict(int8=True), "int8=True"),

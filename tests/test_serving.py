@@ -12,7 +12,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import nd, serialization, serving, telemetry
 from mxnet_tpu.predictor import Predictor
-from mxnet_tpu.serving import (BucketPolicy, KVCacheManager, RequestQueue,
+from mxnet_tpu.serving import (BucketPolicy, RequestQueue,
                                ServerConfig, ServerOverloadedError,
                                pad_batch, pow2_bucket)
 from mxnet_tpu.serving.protocol import Request, ServerClosedError
@@ -112,47 +112,6 @@ def test_padding_bit_identity_vs_unpadded_oracle(tmp_path):
     for i, x in enumerate(exs):
         solo = pred.predict(x[None]).asnumpy()[0]
         assert np.array_equal(padded[i, :len(x)], solo)
-
-
-# --- KV cache slot ledger ----------------------------------------------------
-
-def test_kv_cache_admit_evict_invariants():
-    m = KVCacheManager(3, 32)
-    s = [m.admit(i, 4, 8) for i in range(3)]
-    assert sorted(s) == [0, 1, 2]
-    assert m.admit(9, 4, 8) is None        # at capacity: admission defers
-    assert m.free_slots() == 0
-    m.check()
-    m.advance(s[0])
-    assert m.state(s[0]).pos == 5
-    assert not m.consume(s[0])
-    for _ in range(7):
-        done = m.consume(s[0])
-    assert done                             # budget of 8 spent
-    m.evict(s[0])
-    m.check()
-    assert m.free_slots() == 1
-    with pytest.raises(mx.MXNetError):
-        m.evict(s[0])                       # double evict
-    with pytest.raises(mx.MXNetError):
-        m.admit(9, 30, 8)                   # 30+8 > max_len 32
-
-
-def test_kv_cache_slot_reuse():
-    m = KVCacheManager(2, 64)
-    a = m.admit(1, 4, 4)
-    b = m.admit(2, 4, 4)
-    m.evict(a)
-    c = m.admit(3, 8, 4)
-    assert c == a                           # freed slot is reused
-    assert m.state(c).request_id == 3
-    assert m.state(c).pos == 8              # fresh position, no leakage
-    m.evict(b)
-    m.evict(c)
-    m.check()
-    st = m.stats()
-    assert st["admits"] == 3 and st["evictions"] == 3
-    assert st["peak_occupancy"] == 2 and st["occupancy"] == 0
 
 
 # --- backpressure ------------------------------------------------------------
@@ -270,67 +229,6 @@ def test_multi_client_continuous_batching_end_to_end(tmp_path):
     last = sums[-1]
     assert last["total_ms"]["p50"] <= last["total_ms"]["p99"]
     assert last["batch_size"]["max"] > 1
-
-
-def test_generative_late_join_and_parity():
-    """A late request joins the in-flight decode batch (continuous
-    batching) and both results match the offline generate() oracle
-    token for token.  Runs the legacy slot-ledger A/B path
-    (``kv_mode="slots"``): its single scheduler loop interleaves
-    prefill with decode, so the done_step ordering below is exact."""
-    from mxnet_tpu.models.llama import llama_tiny
-
-    net = llama_tiny()
-    net.initialize()
-    telemetry.enable(memory=False, cost=False)
-    sink = ListSink()
-    telemetry.add_sink(sink)
-    rs = np.random.RandomState(0)
-    p1 = rs.randint(1, 250, size=5)
-    p2 = rs.randint(1, 250, size=9)
-    cfg = ServerConfig(max_batch=2, max_length=64, min_length=8,
-                       num_slots=2, summary_every=2, kv_mode="slots")
-    srv = serving.GenerativeServer(net, cfg)
-    try:
-        with srv:
-            f1 = srv.submit(p1, max_new_tokens=40)
-            # wait until request 1 is actually decoding, then join late
-            deadline = time.time() + 60
-            while srv.engine.steps < 2 and time.time() < deadline:
-                time.sleep(0.01)
-            assert srv.engine.steps >= 2
-            f2 = srv.submit(p2, max_new_tokens=4)
-            r1 = f1.result(120)
-            r2 = f2.result(120)
-        stats = srv.stats()
-    finally:
-        telemetry.disable()
-
-    o1 = net.generate(nd.array(p1[None]), 40).asnumpy()[0]
-    o2 = net.generate(nd.array(p2[None]), 4).asnumpy()[0]
-    assert np.array_equal(r1, o1)
-    assert np.array_equal(r2, o2)
-
-    recs = {r["request_id"]: r for r in sink.records
-            if r.get("record") == "serving.request"}
-    assert len(recs) == 2
-    r1rec = min(recs.values(), key=lambda r: r["request_id"])
-    r2rec = max(recs.values(), key=lambda r: r["request_id"])
-    # the late request was admitted AFTER decode began and finished
-    # BEFORE the long request: it joined the in-flight batch
-    assert r2rec["joined_step"] >= 2
-    assert r2rec["done_step"] < r1rec["done_step"]
-    assert r1rec["ttft_ms"] > 0 and r2rec["ttft_ms"] > 0
-    # both sequences shared slots concurrently
-    assert stats["kv_cache"]["peak_occupancy"] == 2
-    assert stats["kv_cache"]["occupancy"] == 0
-    # one step signature ever, prefill per prompt bucket
-    sigs = stats["compiled_signatures"]
-    assert sigs.count(("step",)) == 1
-    assert len([s for s in sigs if s[0] == "prefill"]) <= 2
-    # rolling summary carries ttft percentiles for generative traffic
-    sums = [r for r in sink.records if r.get("record") == "serving.latency"]
-    assert sums and sums[-1]["ttft_ms"] is not None
 
 
 def test_generative_paged_lanes_late_join_and_parity():
@@ -584,58 +482,33 @@ def test_a_pool_that_parks_gives_the_tokens_of_one_that_never_does(tick):
         assert kv_s["peak_shared_blocks"] >= 2
 
 
-def test_legacy_ledger_stats_fields():
-    """The r8 slot ledger stays importable for A/B and now reports the
-    same occupancy vocabulary as the paged manager: capacity in tokens,
-    tokens in flight, peak tokens, fragmentation."""
-    mgr = KVCacheManager(num_slots=2, max_len=32)
-    s0 = mgr.stats()
-    assert s0["capacity_tokens"] == 64
-    assert s0["tokens_in_flight"] == 0 and s0["fragmentation"] == 0.0
-    slot = mgr.admit("r1", prompt_len=10, max_new_tokens=4)
-    st = mgr.stats()
-    # the ledger reserves max_len per occupied slot: 10 live tokens out
-    # of a 32-token reservation is mostly fragmentation
-    assert st["tokens_in_flight"] == 10
-    assert st["peak_tokens"] == 10
-    assert st["fragmentation"] == pytest.approx(1 - 10 / 32, abs=1e-4)
-    mgr.evict(slot)
-    assert mgr.stats()["tokens_in_flight"] == 0
-
-
 def test_paged_capacity_beats_ledger():
     """The acceptance mix: a pool whose worst-case ``slots × max_len``
     exceeds its token capacity still admits (and correctly serves) all
-    four short requests — the equal-byte ledger holds two."""
+    four short requests, where a fixed ``max_len`` row a slot would
+    hold two."""
     from mxnet_tpu.models.llama import llama_tiny
     from mxnet_tpu.serving import PagedKVCacheManager
 
     # manager level: 8 blocks × 16 = 128 tokens backs FOUR slots whose
-    # worst case is 4 × 64 = 256; the 128-token ledger holds TWO slots
+    # worst case is 4 × 64 = 256
     mgr = PagedKVCacheManager(num_slots=4, max_len=64, num_blocks=8,
                               block_size=16)
     admits = [mgr.admit(i, 9, 4) for i in range(4)]   # 13 tokens each
     assert all(a is not None for a in admits)
     assert mgr.stats()["occupancy"] == 4
-    ledger = KVCacheManager(num_slots=2, max_len=64)  # same 128 tokens
-    assert ledger.admit("a", 9, 4) is not None
-    assert ledger.admit("b", 9, 4) is not None
-    assert ledger.admit("c", 9, 4) is None            # full
     for slot, _ in admits:
         mgr.evict(slot)
     assert mgr.allocator.free_blocks == 8
     mgr.check()
 
     # server level: the undersized pool serves the same mix token-exact
-    # vs the r8 slots path
+    # vs offline generate
     net = llama_tiny()
     net.initialize()
     rs = np.random.RandomState(3)
     prompts = [rs.randint(1, 250, size=9) for _ in range(4)]
-    oracle_cfg = ServerConfig(max_batch=4, max_length=64, min_length=8,
-                              num_slots=4, kv_mode="slots")
-    with serving.GenerativeServer(net, oracle_cfg) as oracle:
-        want = [oracle.generate(p, max_new_tokens=4) for p in prompts]
+    want = [net.generate(nd.array(p[None]), 4).asnumpy()[0] for p in prompts]
     cfg = ServerConfig(max_batch=4, max_length=64, min_length=8,
                        num_slots=4, num_blocks=8, block_size=16)
     srv = serving.GenerativeServer(net, cfg)
@@ -655,8 +528,8 @@ def test_paged_capacity_beats_ledger():
 
 def test_generative_server_mesh_dp2_tp2_token_exact():
     """dp2×tp2 CPU mesh: weights tensor-parallel per replica, two
-    independent replicas behind one queue.  Token-exact vs the
-    single-device r8 slots path, ONE decode compile per replica, both
+    independent replicas behind one queue.  Token-exact vs
+    single-device offline generate, ONE decode compile per replica, both
     replicas take work, and the engine's pool bytes match the memory
     planner's ``plan_kv_pool`` on the tp submesh."""
     import jax
@@ -670,10 +543,7 @@ def test_generative_server_mesh_dp2_tp2_token_exact():
     net.initialize()
     rs = np.random.RandomState(5)
     prompts = [rs.randint(1, 250, size=n) for n in (5, 9, 12, 7)]
-    oracle_cfg = ServerConfig(max_batch=2, max_length=64, min_length=8,
-                              num_slots=2, kv_mode="slots")
-    with serving.GenerativeServer(net, oracle_cfg) as oracle:
-        want = [oracle.generate(p, max_new_tokens=6) for p in prompts]
+    want = [net.generate(nd.array(p[None]), 6).asnumpy()[0] for p in prompts]
 
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
     cfg = ServerConfig(max_batch=2, max_length=64, min_length=8,
@@ -750,3 +620,94 @@ def test_cache_spec_refuses_what_it_cannot_price():
         CacheSpec(("kv", "latent"), 2, 16)
     with pytest.raises(mx.MXNetError, match="state_shape"):
         CacheSpec(("kv", "state"), 2, 16)
+
+
+# --- what an option needs of a cache: one table, one function ------------------
+
+def _refusal_specs():
+    from mxnet_tpu.models.decoder import BlockDecoding, CacheSpec
+
+    blocks = BlockDecoding(block_len=4, mask_id=7, steps=2, threshold=0.9)
+    kw = dict(num_kv_heads=2, head_dim=16)
+    return {
+        "plain": CacheSpec(("kv", "kv"), **kw),
+        "loop": CacheSpec(("kv", "kv"), passes=2, **kw),
+        "latent": CacheSpec(("latent", "kv"), latent_dim=8, index_dim=4,
+                            select_topk=2, **kw),
+        "block": CacheSpec(("kv",), decoding=blocks, **kw),
+        "state": CacheSpec(("state", "kv"), state_shape=(3, 8), **kw),
+        # routed experts and no state layer: the same trait
+        "experts": CacheSpec(("kv",), expert_layers=1, num_experts=4, **kw),
+        # what a block decoder cannot have beside it
+        "block+state": CacheSpec(("state", "kv"), state_shape=(3, 8),
+                                 decoding=blocks, **kw),
+    }
+
+
+#: an option -> (how a caller asks for it, the keyword its sentence names)
+_ASKED = {"spec": (dict(spec_k=2), "spec_k"),
+          "mesh": (dict(mesh=object()), "mesh="),
+          "int8": (dict(int8=True), "int8=True"),
+          "radix": (dict(radix=True), "radix_cache=True")}
+_EVERYTHING = {k: v for kw, _ in _ASKED.values() for k, v in kw.items()}
+
+
+def _refusal_cases():
+    from mxnet_tpu.serving.generative import REFUSALS
+
+    cases = [(trait, trait, option) for trait, option in sorted(REFUSALS)
+             if option in _ASKED]
+    cases += [("experts", "state", option) for option in sorted(_ASKED)]
+    # every row of the table is one of these cases, or the block decoder's
+    assert {c[1:] for c in cases} | {("block", "state")} == set(REFUSALS)
+    return cases
+
+
+@pytest.mark.parametrize("name,trait,option", _refusal_cases())
+def test_an_option_a_cache_kind_cannot_give_is_refused_by_the_tables_row(
+        name, trait, option):
+    from mxnet_tpu.serving.generative import REFUSALS, refuse
+
+    kw, keyword = _ASKED[option]
+    with pytest.raises(mx.MXNetError) as exc:
+        refuse(_refusal_specs()[name], **kw)
+    assert str(exc.value) == REFUSALS[trait, option]
+    assert keyword in str(exc.value)
+
+
+def test_a_block_decoder_beside_a_state_layer_is_refused_unasked():
+    from mxnet_tpu.serving.generative import REFUSALS, refuse
+
+    with pytest.raises(mx.MXNetError) as exc:
+        refuse(_refusal_specs()["block+state"])
+    assert str(exc.value) == REFUSALS["block", "state"]
+    assert "block decoder" in str(exc.value)
+    refuse(_refusal_specs()["block"])      # alone it is served
+
+
+@pytest.mark.parametrize("option", sorted(_ASKED) + ["all"])
+def test_a_plain_kv_spec_is_refused_nothing(option):
+    from mxnet_tpu.serving.generative import refuse
+
+    kw = _EVERYTHING if option == "all" else _ASKED[option][0]
+    assert refuse(_refusal_specs()["plain"], **kw) is None
+
+
+def test_the_first_option_asked_for_and_the_first_trait_are_the_ones_named():
+    """In the order the engine has always checked them: spec, mesh, int8,
+    then the replica's radix; a stack run several times before any other
+    trait."""
+    from mxnet_tpu.serving.generative import REFUSALS, refuse
+
+    specs = _refusal_specs()
+    for drop, first in ((), "spec"), (("spec_k",), "mesh"), \
+            (("spec_k", "mesh"), "int8"), (("spec_k", "mesh", "int8"), "radix"):
+        kw = {k: v for k, v in _EVERYTHING.items() if k not in drop}
+        with pytest.raises(mx.MXNetError) as exc:
+            refuse(specs["state"], **kw)
+        assert str(exc.value) == REFUSALS["state", first]
+    for name, phrase in (("loop", "several times"), ("latent", "latent"),
+                         ("block", "block decoder"),
+                         ("state", "per-slot state")):
+        with pytest.raises(mx.MXNetError, match=phrase):
+            refuse(specs[name], radix=True)

@@ -293,24 +293,96 @@ def test_radix_prefix_cache_token_exact_and_shared():
     assert all(r["prefill_saved_ms"] > 0 for r in hits)
 
 
-def test_spec_and_radix_rejected_on_slots_mode():
+@pytest.mark.parametrize("kv_mode", ["slots", "ledger", None])
+def test_server_config_refuses_a_kv_mode_but_paged_by_name(kv_mode):
+    with pytest.raises(mx.MXNetError) as exc:
+        ServerConfig(kv_mode=kv_mode)
+    assert f"kv_mode={kv_mode!r}" in str(exc.value)
+    assert "gone" in str(exc.value)
+
+
+def test_server_config_takes_kv_mode_paged_and_keeps_nothing_of_it():
+    cfg = ServerConfig(kv_mode="paged", num_blocks=8)
+    assert not hasattr(cfg, "kv_mode")
+    assert vars(cfg).keys() == vars(ServerConfig()).keys()
+
+
+# --- the draft: a paged engine whose table is fixed ----------------------------
+
+def _draft_server():
     net = _tiny()
-    with pytest.raises(mx.MXNetError):
+    cfg = ServerConfig(max_batch=2, max_length=40, min_length=8,
+                       num_slots=2, block_size=16, num_blocks=4,
+                       summary_every=1 << 30, draft_net=net, spec_k=2)
+    return net, serving.GenerativeServer(net, cfg)
+
+
+def test_the_drafts_pool_is_a_fixed_row_of_blocks_a_slot():
+    """Whatever the target's pool, the draft's holds ``max_blocks`` blocks a
+    slot (max_len 40 at 16 a block: 3), priced as the planner prices them."""
+    from mxnet_tpu.memory import plan_kv_pool
+
+    net, srv = _draft_server()
+    rep = srv.replicas[0]
+    eng, draft = rep.engine, rep.draft
+    assert (eng.num_blocks, draft.num_blocks, draft.max_blocks) == (4, 6, 3)
+    assert draft.block_size == eng.block_size == 16
+    assert [rep.draft_blocks(s) for s in range(2)] == [[0, 1, 2], [3, 4, 5]]
+    cfg = net.config
+    assert draft.kv_pool_bytes() == plan_kv_pool(
+        cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, num_blocks=6,
+        block_size=16)
+    assert draft.decode_attention == eng.decode_attention == "gather"
+    # vacant until an admission writes a row
+    assert (draft._tables == draft.num_blocks).all()
+
+
+def test_the_drafts_table_rows_are_the_same_lists_admission_after_admission():
+    """An admission writes the slot's fixed list, a release the sentinel, a
+    second admission of the slot the same list again; the tokens are the
+    offline oracle's both times."""
+    import time
+
+    net, srv = _draft_server()
+    rep = srv.replicas[0]
+    draft = rep.draft
+    rs = np.random.RandomState(11)
+    seen = []
+    with srv:
+        for n in (5, 9):
+            prompt = rs.randint(1, 250, size=n)
+            fut = srv.submit(prompt, max_new_tokens=20)
+            deadline = time.time() + 60
+            held = None
+            while held is None and time.time() < deadline:
+                with draft.dev_lock:
+                    rows = draft._tables.copy()
+                for slot in range(2):
+                    if rows[slot, 0] != draft.num_blocks:
+                        held = (slot, rows[slot].tolist())
+            assert held is not None
+            assert held[1] == rep.draft_blocks(held[0])
+            seen.append(held[0])
+            assert np.array_equal(
+                fut.result(120),
+                net.generate(nd.array(prompt[None]), 20).asnumpy()[0])
+            # released: the row is vacant again, the pool's size as it was
+            assert (draft._tables == draft.num_blocks).all()
+        assert seen[0] == seen[1]          # the freed slot was taken again
+    assert draft._pool[0][0].shape[0] == draft.num_blocks == 6
+
+
+def test_a_draft_is_refused_what_speculation_is():
+    """The draft steps a token a slot and is rolled back by its cursor alone:
+    a draft whose cache has more than K/V is refused by name."""
+    from mxnet_tpu.models import lfm2
+
+    draft = lfm2.lfm2_moe_tiny()
+    draft.initialize()
+    with pytest.raises(mx.MXNetError, match="rolled back"):
         serving.GenerativeServer(
-            net, ServerConfig(kv_mode="slots", radix_cache=True))
-    with pytest.raises(mx.MXNetError):
-        serving.GenerativeServer(
-            net, ServerConfig(kv_mode="slots", draft_net=net))
-
-
-def test_spec_requires_paged_engine_verify():
-    from mxnet_tpu.serving.generative import LlamaServingEngine
-
-    net = _tiny()
-    eng = LlamaServingEngine(net, max_len=32, num_slots=2,
-                             kv_mode="slots")
-    with pytest.raises(mx.MXNetError):
-        eng.verify(np.zeros((2, 2), np.int32))
+            _tiny(), ServerConfig(max_batch=2, max_length=64, min_length=8,
+                                  num_slots=2, draft_net=draft, spec_k=2))
 
 
 # --- dp2 mesh, both features, retrace-clean ----------------------------------

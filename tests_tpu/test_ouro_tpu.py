@@ -118,8 +118,7 @@ def test_looped_step_program():
     net.collect_params().setattr("grad_req", "null")
     net.initialize(mx.init.Normal(0.02))
     eng = LlamaServingEngine(net, max_len=MAX_LEN, num_slots=SLOTS,
-                             kv_mode="paged", block_size=BS,
-                             num_blocks=NUM_BLOCKS)
+                             block_size=BS, num_blocks=NUM_BLOCKS)
     stored = (PASSES * NUM_BLOCKS, H, BS, HD)
     assert eng.decode_attention == "paged_kernel" and eng.kv_pack == 1
     assert eng._pool[0][0].shape == stored

@@ -141,8 +141,7 @@ def test_step_program(cell, nets):
     slots, max_len, num_blocks, hd = CELLS[cell]
     mb, pack = max_len // BS, 128 // hd
     eng = LlamaServingEngine(nets(hd), max_len=max_len, num_slots=slots,
-                             kv_mode="paged", block_size=BS,
-                             num_blocks=num_blocks)
+                             block_size=BS, num_blocks=num_blocks)
     stored = (num_blocks, HKV // pack, BS, 128)
     assert eng.kv_pack == pack and eng._pool[0][0].shape == stored
     assert eng.decode_attention == "paged_kernel"
@@ -192,3 +191,36 @@ def test_step_program(cell, nets):
         eng.step([s for s in range(slots) if live[s]
                   and eng._pos[s] + 1 < max_len])
     assert eng.compiled_signatures() == [("step",)]
+
+
+def test_the_draft_of_a_speculating_server_runs_the_kernels_too(nets):
+    """The draft is a paged engine whose table never changes: on the chip
+    its step takes the paged kernel and its long prefill the flash kernel,
+    as the target's do, and a same-net draft is accepted most of the time
+    (the draft's step and the target's verify are two programs in bf16: a
+    near tie may flip, so not every proposal is)."""
+    from mxnet_tpu import serving
+
+    net = nets(128)
+    cfg = serving.ServerConfig(
+        max_batch=2, max_length=512, min_length=64, num_slots=4,
+        block_size=BS, summary_every=1 << 30, draft_net=net, spec_k=3)
+    srv = serving.GenerativeServer(net, cfg)
+    rep = srv.replicas[0]
+    draft = rep.draft
+    assert draft.decode_attention == rep.engine.decode_attention \
+        == "paged_kernel"
+    assert draft.prefill_attention == rep.engine.prefill_attention == "flash"
+    assert draft.num_blocks == 4 * (512 // BS)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(1, 32768, size=n) for n in (40, 300, 129, 64)]
+    with srv:
+        outs = [f.result(300) for f in
+                [srv.submit(p, max_new_tokens=24) for p in prompts]]
+        stats = srv.stats()
+    assert all(len(o) == len(p) + 24 for o, p in zip(outs, prompts))
+    assert stats["failed"] == 0
+    spec = stats["speculative"]
+    assert spec["draft_tokens"] > 0 and spec["accept_rate"] >= 0.5, spec
+    assert (draft._tables == draft.num_blocks).all()     # all released
+    assert ("step",) in draft.compiled_signatures()
