@@ -27,10 +27,10 @@ def __getattr__(name):
 
         return FusedTrainStep
     if name == "step_fusion":
-        from . import step_fusion
+        # not ``from . import``: that asks this function first and recurses
+        import importlib
 
-        globals()[name] = step_fusion
-        return step_fusion
+        return importlib.import_module(".step_fusion", __name__)
     if name in _LAZY:
         import importlib
 

@@ -37,6 +37,14 @@ Semantics vs K eager steps:
 
 Inputs may be per-execution constants (a synthetic batch reused K
 times) or stacked ``(K, ...)`` leaves scanned one slice per inner step.
+
+Side values of a step.  A net (or the ``forward_loss`` around it) hands
+small per-step values out of the program with :func:`report`: under the
+fused trace they are stacked over the K steps like the losses and stay on
+the device (``FusedTrainStep.reported``) until the caller takes them with
+``fetch_reported()``, one dispatch behind like the losses; the per-step
+path collects the same values eagerly under :func:`reported`.  Static
+facts of the trace (a str or a number) are kept once.
 """
 from __future__ import annotations
 
@@ -58,7 +66,42 @@ from ..base import MXNetError
 from ..ndarray import NDArray
 from .block import _trace_guard
 
-__all__ = ["FusedTrainStep"]
+__all__ = ["FusedTrainStep", "report", "reported"]
+
+import threading as _threading
+
+_TLS = _threading.local()
+
+
+def report(**values):
+    """Hand named values of this training step to whoever collects them:
+    ``FusedTrainStep`` while it traces (NDArrays, stacked over its K steps
+    and fetched with ``fetch_reported``; a str or a number is a fact of
+    the trace, kept once) or an enclosing :func:`reported` (the per-step
+    path, eagerly).  With neither, nothing happens."""
+    sink = getattr(_TLS, "sink", None)
+    if sink is not None:
+        sink.update(values)
+
+
+class reported:
+    """Collect what the forward :func:`report` s, eagerly::
+
+        with step_fusion.reported() as side:
+            with autograd.record():
+                loss = forward_loss(net, *batch)
+        side["loss_main"]          # an NDArray
+
+    Nests: the innermost collector takes the values."""
+
+    def __enter__(self):
+        self._prev = getattr(_TLS, "sink", None)
+        _TLS.sink = {}
+        return _TLS.sink
+
+    def __exit__(self, *exc):
+        _TLS.sink = self._prev
+        return False
 
 #: reviewed signature budget (mxlint T15): one fused program per
 #: (batch avals, param set, optimizer config, k) — a FusedTrainStep is
@@ -148,6 +191,10 @@ class FusedTrainStep:
         # donated jit program would.
         self._validated_sigs = set()
         self._dispatches = 0   # __call__ count: the lane log's ``seq``
+        #: what the forward reported in the newest dispatch, name ->
+        #: NDArray (K, ...) on the device; facts of the trace, name -> value
+        self.reported, self.facts = {}, {}
+        self._unfetched = []   # (lane record, reported raws), oldest first
 
         optzr = trainer._optimizer
         if type(optzr)._step is opt.Optimizer._step:
@@ -221,7 +268,8 @@ class FusedTrainStep:
                 h._data = r
             args = [NDArray(r) for r in x_raws]
             with ag._RecordingStateScope(False, True), \
-                    mxrand.key_provider(key), _trace_guard():
+                    mxrand.key_provider(key), _trace_guard(), \
+                    reported() as side:
                 loss = self.forward_loss(self.net, *args)
             import jax
 
@@ -229,7 +277,12 @@ class FusedTrainStep:
                 loss, is_leaf=lambda x: isinstance(x, NDArray))
             total = sum(l._data.astype(np.float32).sum() for l in leaves)
             new_aux = tuple(h._data for h in aux_handles)
-            return total, new_aux
+            self.facts = {n: v for n, v in side.items()
+                          if not isinstance(v, NDArray)}
+            values = {n: jax.lax.stop_gradient(v._data)
+                      for n, v in sorted(side.items())
+                      if isinstance(v, NDArray)}
+            return total, (new_aux, values)
         finally:
             for h, s in zip(w_handles, saved_w):
                 h._data = s
@@ -256,7 +309,7 @@ class FusedTrainStep:
             w, m, s, aux, t, key = carry
             key, sub = jax.random.split(key)
             x_raws = list(xr) if stacked_inputs else list(consts)
-            (loss_sum, new_aux), grads = grad_and_aux(
+            (loss_sum, (new_aux, values)), grads = grad_and_aux(
                 list(w), list(aux), x_raws, sub)
             # same traced update contract as the Trainer's fused
             # multi-tensor path (optimizer._fused_param_updates)
@@ -267,7 +320,7 @@ class FusedTrainStep:
                 for g, nw, ow in zip(grads, new_w, w)) \
                 if numerics_on else ()
             return ((new_w, new_m, new_s, new_aux, t + 1, key),
-                    (loss_sum, nstats))
+                    (loss_sum, nstats, values))
 
         def _reduce_k(st):
             # per-param stats stacked (K,) by the scan, folded to one
@@ -284,12 +337,12 @@ class FusedTrainStep:
             def body(carry, xr):
                 return one_step(carry, xr, consts, lr_v, wd_v)
 
-            carry, (losses, nstats) = jax.lax.scan(
+            carry, (losses, nstats, values) = jax.lax.scan(
                 body, (w, m, s, aux, t, key), stacked,
                 length=(None if stacked_inputs else k))
             nstats = tuple((_reduce_k(g), _reduce_k(u))
                            for g, u in nstats)
-            return carry[:5], losses, nstats
+            return carry[:5], losses, nstats, values
 
         # donate weights/masters/states/aux: K steps of updates in place
         return jax.jit(k_steps, donate_argnums=(0, 1, 2, 3))
@@ -421,7 +474,7 @@ class FusedTrainStep:
         key = mxrand.next_key()
 
         snapshot = None if sig in self._validated_sigs else \
-            self._snapshot()
+            self._snapshot(w_raws)
         t_args = time.perf_counter()
         telemetry.gauge("step_fusion.steps_per_execution", self.k)
         telemetry.count("step_fusion.steps", self.k)
@@ -445,8 +498,8 @@ class FusedTrainStep:
             with telemetry.span("step_fusion.compile" if snapshot is not None
                                 else "step_fusion.replay"), \
                     dispatch_platform(platform_of_raws(w_raws)):
-                (new_w, new_m, new_s, new_aux, _new_t), losses, nstats = \
-                    fn(*head, key, *tail)
+                (new_w, new_m, new_s, new_aux, _new_t), losses, nstats, \
+                    values = fn(*head, key, *tail)
             t_disp1 = time.perf_counter()
 
             if _san._enabled:
@@ -490,11 +543,17 @@ class FusedTrainStep:
                 losses.block_until_ready()  # mxlint: allow=T1
                 self._validated_sigs.add(sig)
                 telemetry.count("step_fusion.compile")
-            tracing.lane_record(
+            rec = tracing.lane_record(
                 "train.dispatch", path="fused", seq=self._dispatches,
                 k=self.k, compiled=snapshot is not None, t0=t0,
                 t_args=t_args, t_disp1=t_disp1,
                 t_end=time.perf_counter())
+            if snapshot is not None:
+                rec.update(self.facts)
+            self.reported = {n: NDArray(v) for n, v in values.items()}
+            if values:
+                self._unfetched.append((rec, values))
+                del self._unfetched[:-self._KEPT_UNFETCHED]
             return NDArray(losses)
         except Exception as exc:
             if snapshot is not None:
@@ -513,6 +572,35 @@ class FusedTrainStep:
                 _mw.annotate_oom(exc, context="FusedTrainStep dispatch")
             raise
 
+    #: dispatches whose reported values wait for ``fetch_reported``; a
+    #: caller that never fetches holds no more than these
+    _KEPT_UNFETCHED = 4
+
+    def fetch_reported(self):
+        """The reported values of the oldest dispatch not yet fetched, as
+        host arrays stacked ``(K, ...)``, or None when there is none:
+        called where the losses are fetched, one dispatch behind.  A value
+        of one number a step is also written into that dispatch's
+        ``train.dispatch`` lane record, a list of K."""
+        if not self._unfetched:
+            return None
+        import jax
+
+        rec, values = self._unfetched.pop(0)
+        host = jax.device_get(values)  # mxlint: allow=T1
+        rec.update({n: [float(x) for x in v] for n, v in host.items()
+                    if v.ndim == 1})
+        return host
+
+    def free_grad_buffers(self):
+        """Release the eager gradient buffers of the trained parameters
+        (``attach_grad``'s zeros, 4 bytes a float32 parameter): the fused
+        program computes its gradients inside and never writes them.
+        ``Parameter.grad()`` and the per-step path then need
+        ``Parameter.zero_grad()``'s buffer back (``attach_grad``)."""
+        for i in self._live:
+            self.trainer._params[i]._data._grad = None
+
     def _donated_raws(self, w_raws, m_raws, s_raws, aux_raws):
         return w_raws + m_raws + \
             tuple(r for ss in s_raws for r in ss) + aux_raws
@@ -522,18 +610,41 @@ class FusedTrainStep:
                 f"K={self.k} fused train step, donate_argnums=(0, 1, 2, 3))")
 
     # -- first-call safety ---------------------------------------------------
-    def _snapshot(self):
+    def _snapshot(self, w_raws):
+        """Copies of everything the program donates, for ``_restore``.
+        On the device where they fit beside the originals; else on the
+        host (a model sized to its chip: the first call then pays two
+        transfers instead of failing for want of room)."""
+        import jax
         import jax.numpy as jnp
 
         trainer = self.trainer
         optzr = trainer._optimizer
-        params = [(p, jnp.array(p._data._data)) for p in trainer._params
+        held = [p._data._data for p in trainer._params
+                if p._data is not None]
+        held += [h._data for s in trainer._states if s is not None
+                 for h in opt._flatten_state(s)]
+        need = sum(a.size * a.dtype.itemsize for a in held)
+        stats = None
+        try:
+            stats = next(iter(w_raws[0].devices())).memory_stats()
+        except (AttributeError, IndexError, StopIteration, RuntimeError):
+            pass
+        room = None if not stats or "bytes_limit" not in stats else \
+            stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+        if room is not None and need > 0.5 * room:
+            telemetry.count("step_fusion.snapshot_on_host")
+            def copy(a):
+                return np.asarray(jax.device_get(a))  # mxlint: allow=T1
+        else:
+            copy = jnp.array
+        params = [(p, copy(p._data._data)) for p in trainer._params
                   if p._data is not None]
         state_raws = [
             None if s is None else
-            [(h, jnp.array(h._data)) for h in opt._flatten_state(s)]
+            [(h, copy(h._data)) for h in opt._flatten_state(s)]
             for s in trainer._states]
-        aux = [(p, jnp.array(p._data._data)) for p in self._aux_params]
+        aux = [(p, copy(p._data._data)) for p in self._aux_params]
         return (params, state_raws, list(trainer._states),
                 list(trainer._states_initialized), aux,
                 dict(optzr._index_update_count), optzr.num_update)
@@ -543,16 +654,18 @@ class FusedTrainStep:
          num_update) = snapshot
         trainer = self.trainer
         optzr = trainer._optimizer
+        import jax.numpy as jnp
+
         for p, raw in params:
-            p._data._data = raw
+            p._data._data = jnp.asarray(raw)      # a host copy goes back
         for entry in state_raws:
             if entry:
                 for h, raw in entry:
-                    h._data = raw
+                    h._data = jnp.asarray(raw)
         trainer._states[:] = states
         trainer._states_initialized[:] = inited
         for p, raw in aux:
-            p._data._data = raw
+            p._data._data = jnp.asarray(raw)
         optzr._index_update_count.clear()
         optzr._index_update_count.update(counts)
         optzr.num_update = num_update

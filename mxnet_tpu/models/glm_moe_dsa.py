@@ -48,8 +48,10 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from .decoder import (CacheSpec, PagedDecoder, SelectingCausal, apply_rope,
                       rms_norm, rope_tables)
+from . import mla
 from .llama import RMSNorm
-from .moe import expert_product, routed_ffn
+from .moe import expert_layer_ffn, expert_product
+from .moe import swiglu as _swiglu  # noqa: F401  (the tests' name for it)
 
 __all__ = ["GlmMoeDsaConfig", "GlmMoeDsaLayer", "GlmMoeDsaForCausalLM",
            "GlmMath", "GlmDecoder", "glm_moe_dsa_tiny", "GLM_CONFIGS"]
@@ -163,13 +165,6 @@ def _layer_param_shapes(cfg, l):
     return out
 
 
-def _swiglu(u, gate, up, down):
-    import jax
-
-    g = u @ gate.T
-    return (g * jax.nn.sigmoid(g) * (u @ up.T)) @ down.T
-
-
 class GlmMath:
     """The layer mathematics, once."""
 
@@ -193,16 +188,11 @@ class GlmMath:
         # or whole sequences' (1, T, 1, ..)
         cos, sin = (r[:, 0] if u.ndim == 2 else jnp.swapaxes(r, 1, 2)
                     for r in rope)
-        kv_b = p["kv_b"].reshape(nh, dn + cfg.v_head_dim, kl)
-        w_uk, w_uv = kv_b[:, :dn], kv_b[:, dn:]
+        w_uk, w_uv = mla.kv_b_halves(p["kv_b"], nh, dn, cfg.v_head_dim, kl)
 
         with jax.named_scope("mla_project"):
-            c_q = rms_norm(u @ p["q_a"].T, p["q_a_norm"], eps)
-            kv = u @ p["kv_a"].T
             one = (cos[..., 0, :], sin[..., 0, :])      # no head axis
-            latent = jnp.concatenate(
-                [rms_norm(kv[..., :kl], p["kv_a_norm"], eps),
-                 apply_rope(kv[..., kl:], *one)], axis=-1)
+            c_q, latent = mla.latent_rows(p, u, one, kl, eps)
             k_idx = _layer_norm(u @ p["idx_k"].T, p["idx_k_norm"],
                                 p["idx_k_bias"])
             k_idx = jnp.concatenate(
@@ -213,17 +203,16 @@ class GlmMath:
         def make_query(c_q, w_idx, cos, sin):
             with jax.named_scope("mla_project"):
                 rows = c_q.shape[:-1]
-                q = (c_q @ p["q_b"].T).reshape(rows + (nh, dn + dr))
+                q = mla.query_heads(p, c_q, nh, dn + dr)
                 q_idx = (c_q @ p["idx_q"].T).reshape(rows + (ih, idim))
                 q_idx = jnp.concatenate(
                     [apply_rope(q_idx[..., :dr], cos, sin),
                      q_idx[..., dr:]], axis=-1)
-                return q[..., :dn], apply_rope(q[..., dn:], cos, sin), \
-                    q_idx, w_idx
+                return (*mla.split_query(q, dn, cos, sin), q_idx, w_idx)
 
         def finish(heads):
             with jax.named_scope("mla_project"):
-                return heads.reshape(heads.shape[:-2] + (-1,)) @ p["o"].T
+                return mla.output(p, heads)
 
         y, kept = view.attend_latent(
             make_query, latent, k_idx, (c_q, w_idx, cos, sin), w_uk, w_uv,
@@ -235,25 +224,12 @@ class GlmMath:
         the shared expert -> (y, rows each expert of the layer received
         or None).  ``live``: the rows a request owns, the only ones
         counted."""
-        import jax
-
         cfg = self.cfg
-        if "router" not in p:
-            return _swiglu(u, p["gate"], p["up"], p["down"]), None
-        lead = u.shape[:-1]
-        with jax.named_scope("moe_ffn"):
-            y, counts = routed_ffn(
-                u.reshape(-1, u.shape[-1]), p["router"], p["w_gate"],
-                p["w_up"], p["w_down"], cfg.num_experts_per_tok,
-                score="sigmoid", choice_bias=p["expert_bias"],
-                renormalize=cfg.norm_topk_prob,
-                scale=cfg.routed_scaling_factor,
-                experts_held=cfg.experts_held,
-                live=None if live is None else live.reshape(-1))
-        with jax.named_scope("shared_expert"):
-            shared = _swiglu(u, p["shared_gate"], p["shared_up"],
-                             p["shared_down"])
-        return y.reshape(*lead, -1) + shared, counts
+        return expert_layer_ffn(
+            p, u, cfg.num_experts_per_tok, score="sigmoid",
+            renormalize=cfg.norm_topk_prob,
+            scale=cfg.routed_scaling_factor,
+            experts_held=cfg.experts_held, live=live)
 
     def layer(self, p, x, rope, view):
         """``(params, x, rope rows, cache view) -> (x, what the view

@@ -38,7 +38,7 @@ from ..gluon.block import HybridBlock
 from ..ops import grouped_ffn
 
 __all__ = ["MoEMLP", "collect_aux", "shard_moe", "route", "routed_ffn",
-           "expert_product"]
+           "expert_product", "swiglu", "expert_layer_ffn"]
 
 
 # --- aux-loss collection ----------------------------------------------------
@@ -273,9 +273,15 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
       weights and sum over a row's ``k``, so not lower than the other
       form anywhere, and not equal to it in the last bit.
 
-    Measured on the v5e (PERF.md, PRs 31 and 33; before them rows sorted
-    by expert through ``jax.lax.ragged_dot`` lost at every size, PRs 26
-    and 30)."""
+    Both forms differentiate: ``every_expert`` through XLA, the kernel
+    through its ``jax.custom_vjp`` (``grouped_expert_ffn_dx`` / ``_dw``),
+    so a trainer's step takes the form :func:`expert_product` names; the
+    router learns through the combine weights.
+
+    Measured on the v5e (PERF.md, PRs 31 and 33 served; PR 44 trained:
+    39.7 against 75.2 ms a layer forward and backward at 16,384 rows, 16
+    of 256 experts of 768 held; before them rows sorted by expert
+    through ``jax.lax.ragged_dot`` lost at every size, PRs 26 and 30)."""
     import jax
     import jax.numpy as jnp
 
@@ -318,6 +324,43 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
                             comb.reshape(-1, EVERY_EXPERT_ROWS, held),
                             ones.reshape(-1, EVERY_EXPERT_ROWS).any(axis=1)))
     return y.reshape(n, -1), counts
+
+
+def swiglu(u, gate, up, down):
+    """A dense SwiGLU, matrices (out, in)."""
+    import jax
+
+    g = u @ gate.T
+    return (g * jax.nn.sigmoid(g) * (u @ up.T)) @ down.T
+
+
+def expert_layer_ffn(p, u, k, score="softmax", renormalize=True, scale=1.0,
+                     experts_held=None, live=None):
+    """A layer's feed-forward over its leaves ``p``, written once for
+    the served and the trained models: a dense SwiGLU (``gate``, ``up``,
+    ``down``) where ``p`` has no ``router``; else :func:`routed_ffn` over
+    the held part of the bank (``router``, ``expert_bias`` the choice
+    bias, ``w_gate``, ``w_up``, ``w_down``) plus the shared expert
+    (``shared_gate``, ``shared_up``, ``shared_down``), a SwiGLU every row
+    takes, counted once -> (y, rows each expert of the layer received or
+    None).  ``u`` (.., H); ``live`` (..) the rows a request owns, the
+    only ones counted."""
+    import jax
+
+    if "router" not in p:
+        return swiglu(u, p["gate"], p["up"], p["down"]), None
+    lead = u.shape[:-1]
+    with jax.named_scope("moe_ffn"):
+        y, counts = routed_ffn(
+            u.reshape(-1, u.shape[-1]), p["router"], p["w_gate"],
+            p["w_up"], p["w_down"], k, score=score,
+            choice_bias=p["expert_bias"], renormalize=renormalize,
+            scale=scale, experts_held=experts_held,
+            live=None if live is None else live.reshape(-1))
+    with jax.named_scope("shared_expert"):
+        shared = swiglu(u, p["shared_gate"], p["shared_up"],
+                        p["shared_down"])
+    return y.reshape(*lead, -1) + shared, counts
 
 
 def expert_product(rows, k, held, hidden, width, dtype):

@@ -7,8 +7,11 @@ transformer.cc:?``, SURVEY §2.2 contrib row) which materialise the full
 scores live in VMEM one (block_q × block_k) tile at a time, the online
 softmax keeps running (m, l) statistics, and the MXU sees two back-to-back
 matmuls per tile.  HBM traffic drops from O(T²) to O(T·D).  One forward
-body serves the trainer (``flash_attention_raw``: equal heads, the
-log-sum-exp saved for the backward) and the served decoders' prefill
+body serves the trainer (``flash_attention_raw``: as many KV heads as
+query heads, the log-sum-exp saved for the backward; ``v`` may have a
+width of its own, latent attention's expanded heads being 192 wide for q
+and k and 128 for v, and ``o``, ``do`` and ``dv`` then have v's) and the
+served decoders' prefill
 (``prefill_flash_attention``: the query heads of a KV head in one tile,
 each prompt's true length bounding the tiles computed).
 
@@ -125,7 +128,8 @@ def _fa_forward_chunked(q, k, v, causal, scale, block=512):
     # and fail the carry typematch (jax shard-map vma rules)
     m0 = qf[..., 0] * 0 - jnp.inf
     l0 = qf[..., 0] * 0
-    acc0 = qf * 0
+    acc0 = qf * 0 if vf.shape[-1] == qf.shape[-1] else \
+        qf[..., :1] * 0 + jnp.zeros((vf.shape[-1],), jnp.float32)
 
     def body(carry, inp):
         m, l, acc = carry
@@ -219,6 +223,7 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     lse_ref = refs[bounded + 4] if with_lse else None
     m_ref, l_ref, acc_ref = refs[-3:]
     group, _, d = q_ref.shape[1:]
+    dv = v_ref.shape[-1]          # v's own width: o's and the accumulator's
     rows = group * block_q
     # the step's rows of ``bh``: one (the program every caller had), or
     # ``hb`` of them as a leading batch axis of every array below
@@ -271,7 +276,7 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     def _finish():
         l = l_ref[...]
         o_ref[blk] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
-            *lead, group, block_q, d).astype(o_ref.dtype)
+            *lead, group, block_q, dv).astype(o_ref.dtype)
         if with_lse:
             # log-sum-exp per query row, saved for the pallas backward;
             # a row no tile ran for keeps -inf (its backward p is
@@ -299,7 +304,7 @@ def _divisor_block(t, pref):
 TRAIN_VMEM_BYTES = 12 << 20
 
 
-def train_row_bytes(tq, tk, d, itemsize=2):
+def train_row_bytes(tq, tk, d, itemsize=2):  # d: the wider of q/k's and v's
     """VMEM a (batch, head) row of a one-tile step asks for, by the
     largest of the three kernels (``dkv``): its four operand and two
     result blocks double-buffered, heads narrower than a tile's 128
@@ -341,8 +346,8 @@ def train_tiles(bh, tq, tk, d, itemsize=2):
 def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
                        with_lse=False, interpret=False, lengths=None,
                        name=None, span=1):
-    """q (B, H, T, D), k/v (B, Hkv, T, D) with Hkv dividing H -> (B, H,
-    T, D)[, lse (B, H, T)].  ``lengths`` (B,) int32: each batch row's
+    """q (B, H, T, D), k (B, Hkv, T, D), v (B, Hkv, T, Dv) with Hkv
+    dividing H -> (B, H, T, Dv)[, lse (B, H, T)].  ``lengths`` (B,) int32: each batch row's
     true length (see ``_fa_kernel``'s ``bounded``); ``span``: a block
     decoder's block length (its ``span``).
 
@@ -354,12 +359,12 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
+    hkv, tk, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = h // hkv
     bh = b * hkv
     qf = q.reshape(bh, g, tq, d)
     kf = k.reshape(bh, tk, d)
-    vf = v.reshape(bh, tk, d)
+    vf = v.reshape(bh, tk, dv)
     block_q = _divisor_block(tq, min(block_q, tq))
     block_k = _divisor_block(tk, min(block_k, tk))
     nk = tk // block_k
@@ -367,7 +372,7 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     hb = 1
     if not bounded:
         if g == 1 and (block_q, block_k) == (tq, tk):
-            hb = train_tiles(bh, tq, tk, d, q.dtype.itemsize)
+            hb = train_tiles(bh, tq, tk, max(d, dv), q.dtype.itemsize)
         telemetry.gauge("flash.rows_per_step.fwd", hb)
     lead = () if hb == 1 else (hb,)
 
@@ -384,8 +389,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
             _tile_runs(i, j, n, block_q=block_q, block_k=block_k,
                        causal=causal), j, 0), 0)
 
-    out_specs = [pl.BlockSpec((hb, g, block_q, d), q_map)]
-    out_shape = [_pallas_out_shape((bh, g, tq, d), q.dtype, q, k, v)]
+    out_specs = [pl.BlockSpec((hb, g, block_q, dv), q_map)]
+    out_shape = [_pallas_out_shape((bh, g, tq, dv), q.dtype, q, k, v)]
     if with_lse and hb == 1:
         # a trailing singleton: mosaic requires the last two block dims
         # (8, 128)-aligned or equal to the array's, which a 2-D
@@ -416,13 +421,13 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
             in_specs=[
                 pl.BlockSpec((hb, g, block_q, d), q_map),
                 pl.BlockSpec((hb, block_k, d), kv_map),
-                pl.BlockSpec((hb, block_k, d), kv_map),
+                pl.BlockSpec((hb, block_k, dv), kv_map),
             ],
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((*lead, g * block_q, 1), jnp.float32),   # m
                 pltpu.VMEM((*lead, g * block_q, 1), jnp.float32),   # l
-                pltpu.VMEM((*lead, g * block_q, d), jnp.float32),   # acc
+                pltpu.VMEM((*lead, g * block_q, dv), jnp.float32),  # acc
             ]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -432,8 +437,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     )(*((jnp.asarray(lengths, jnp.int32),) if bounded else ()),
       qf, kf, vf)
     if with_lse:
-        return out[0].reshape(b, h, tq, d), out[1].reshape(b, h, tq)
-    return out[0].reshape(b, h, tq, d)
+        return out[0].reshape(b, h, tq, dv), out[1].reshape(b, h, tq)
+    return out[0].reshape(b, h, tq, dv)
 
 
 # --- the serving prefill's entry --------------------------------------------
@@ -617,26 +622,30 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
                         block_k=512, interpret=False):
-    """dq/dk/dv via the two pallas kernels; (B, H, T, D) in and out.
+    """dq/dk/dv via the two pallas kernels; (B, H, T, D) in and out,
+    ``v``, ``o``, ``do`` and ``dv`` (B, H, T, Dv).
     A grid step of either is a (q tile, k tile) pair of one head of one
     batch row, or of ``train_tiles`` rows of ``B * H`` where the whole
-    sequence is one tile; float32 operands in all five products."""
+    sequence is one tile; float32 operands in all five products (the
+    stored dtype instead, bf16 into the MXU, read the same step time at
+    ``(4, 32, 4096, 192 / 128)`` causal: 758.4 against 759.1 ms, PERF.md,
+    PR 44)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[-1]
     bh = b * h
     qf = q.reshape(bh, tq, d)
     kf = k.reshape(bh, tk, d)
-    vf = v.reshape(bh, tk, d)
-    dof = do.reshape(bh, tq, d)
+    vf = v.reshape(bh, tk, dv)
+    dof = do.reshape(bh, tq, dv)
     block_q = _divisor_block(tq, min(block_q, tq))
     block_k = _divisor_block(tk, min(block_k, tk))
     nq, nk = tq // block_q, tk // block_k
     hb = 1
     if nq == nk == 1:
-        hb = train_tiles(bh, tq, tk, d, q.dtype.itemsize)
+        hb = train_tiles(bh, tq, tk, max(d, dv), q.dtype.itemsize)
     telemetry.gauge("flash.rows_per_step.dq", hb)
     telemetry.gauge("flash.rows_per_step.dkv", hb)
     lead = () if hb == 1 else (hb,)
@@ -653,7 +662,7 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
     # delta = rowsum(dO * O): one fused elementwise pass outside the
     # kernels (XLA fuses it into the surrounding graph)
     delta = (dof.astype(jnp.float32) *
-             o.reshape(bh, tq, d).astype(jnp.float32)).sum(-1).reshape(
+             o.reshape(bh, tq, dv).astype(jnp.float32)).sum(-1).reshape(
                  stat_shape)
 
     # dq: grid (bh / hb, nq, nk) — K innermost, q/do/lse/delta follow i
@@ -665,8 +674,8 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
         in_specs=[
             pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_k, dv), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((hb, block_q, dv), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, i)),
             pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, i)),
         ],
@@ -679,7 +688,7 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
     # dkv: grid (bh / hb, nk, nq) — Q innermost, k/v follow i
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
                           nq=nq, hb=hb),
@@ -687,27 +696,27 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
         in_specs=[
             pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, j, 0)),
             pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((hb, block_k, dv), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_q, dv), lambda b_, i, j: (b_, j, 0)),
             pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, j)),
             pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, j)),
         ],
         out_specs=[
             pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((hb, block_k, dv), lambda b_, i, j: (b_, i, 0)),
         ],
         out_shape=[
             _pallas_out_shape((bh, tk, d), k.dtype, q, k, v, do),
-            _pallas_out_shape((bh, tk, d), v.dtype, q, k, v, do),
+            _pallas_out_shape((bh, tk, dv), v.dtype, q, k, v, do),
         ],
         scratch_shapes=[pltpu.VMEM((*lead, block_k, d), jnp.float32),
-                        pltpu.VMEM((*lead, block_k, d), jnp.float32)],
+                        pltpu.VMEM((*lead, block_k, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, d))
+            dv_out.reshape(b, h, tk, dv))
 
 
 # --- chunked jnp backward ----------------------------------------------------
@@ -909,7 +918,8 @@ def _pallas_applicable(q, k):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention_raw(q, k, v, causal=False, scale=None):
-    """q/k/v (B, H, T, D) → (B, H, T, D).  Pallas on TPU, jnp fallback."""
+    """q/k (B, H, T, D), v (B, H, T, Dv) → (B, H, T, Dv).  Pallas on TPU,
+    jnp fallback."""
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(q.shape[-1]))
     if _pallas_applicable(q, k):
         return _pallas_maybe_sharded(q, k, v, causal, scale)
@@ -940,6 +950,21 @@ def _bwd(causal, scale, res, g):
 
 
 flash_attention_raw.defvjp(_fwd, _bwd)
+
+
+def train_form(q_shape, dv=None, itemsize=2):
+    """Which form ``flash_attention_raw`` and its backward take for ``q``
+    (B, H, T, D) (``k`` alike) and values ``dv`` wide, here and now, as one
+    word for a log: ``pallas:<block_q>x<block_k>:d<D>/<Dv>:hb<rows a
+    step>`` or ``chunked``."""
+    b, h, t, d = q_shape
+    dv = d if dv is None else dv
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    if not (_pallas_applicable(q, q) and _pallas_bwd_enabled()):
+        return "chunked"
+    blk = _divisor_block(t, min(512, t))
+    hb = train_tiles(b * h, t, t, max(d, dv), itemsize) if blk == t else 1
+    return f"pallas:{blk}x{blk}:d{d}/{dv}:hb{hb}"
 
 
 def flash_attention(query, key, value, causal=False, scale=None, **kwargs):

@@ -46,10 +46,14 @@ bound by operations nobody asked for (PERF.md, PRs 31 and 33).  Here:
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .. import telemetry
 
 #: reviewed signature budget (mxlint T15): inlined into the step or
 #: prefill program that calls it; alone (tests_tpu/, tools/) one
@@ -332,24 +336,24 @@ def _one_window(x, idx, weights, w_gate, w_up, w_down, live, tm, wt,
     return y.sum(axis=1).astype(x.dtype)
 
 
-def _held_windows(x, idx, weights, w_gate, w_up, w_down, live, tm, wt, win,
-                  interpret):
-    """A long prefill: the pairs HELD here alone, expert by expert and
-    row by row, ``win`` of them at a time.  No sort (XLA's sort of 2^15
-    keys and more compiles for half a minute) and nothing of N x k
+def _held_pairs(idx, held, live):
+    """A long prefill's, and every backward's, listing: the pairs HELD
+    here alone, expert by expert and row by row.  No sort (XLA's sort of
+    2^15 keys and more compiles for half a minute) and nothing of N x k
     rows: ``upto[e * N + r]`` counts the held pairs up to expert ``e``'s
     row ``r``, so sorted pair ``p`` is the first (e, r) with ``upto >
-    p``."""
-    n, h = x.shape
-    held = w_gate.shape[0]
+    p``.  -> ``(pairs held, window)``; ``window(w, win)`` names window
+    ``w``'s ``win`` pairs: each one's row, its expert (``held`` past the
+    last pair) and which of its row's ``k`` choices it is, (win, k)
+    bool."""
+    n = idx.shape[0]
     key = _keys(idx, held, live)
-    weights = weights.astype(jnp.float32)
     chose = (key[:, :, None] == jnp.arange(held, dtype=jnp.int32)).any(1)
     flat = chose.T.reshape(-1)
     upto = jnp.cumsum(jnp.pad(flat, (0, -flat.shape[0] % 128)),
                       dtype=jnp.int32).reshape(-1, 128)
 
-    def one(w, y):
+    def window(w, win):
         p = w * win + jnp.arange(win, dtype=jnp.int32)
         # the entries of ``upto`` that are <= p, counted in two steps
         # (whole lines of 128, then inside the line that is left): a
@@ -360,15 +364,31 @@ def _held_windows(x, idx, weights, w_gate, w_up, w_down, live, tm, wt, win,
         at = jnp.minimum(at, held * n - 1).astype(jnp.int32)
         key_w = jnp.where(p < upto[-1, -1], at // n, held)
         rows = at % n
-        ws = jnp.where(key[rows] == key_w[:, None], weights[rows], 0.0)
+        return rows, key_w, key[rows] == key_w[:, None]
+
+    return upto[-1, -1], window
+
+
+def _held_windows(x, idx, weights, w_gate, w_up, w_down, live, tm, wt, win,
+                  interpret):
+    """A long prefill: the held pairs (:func:`_held_pairs`), ``win`` of
+    them at a time, each window's rows added into a float32 sum."""
+    n, h = x.shape
+    held = w_gate.shape[0]
+    weights = weights.astype(jnp.float32)
+    pairs, window = _held_pairs(idx, held, live)
+
+    def one(w, y):
+        rows, key_w, slot = window(w, win)
+        ws = jnp.where(slot, weights[rows], 0.0)
         out = _window(x, rows, key_w, ws.sum(axis=1),
                       (w_gate, w_up, w_down), tm, wt, interpret)
         # a pair nobody computed lies in a tile that may never have
         # been written
         return y.at[rows].add(jnp.where((key_w < held)[:, None], out, 0.0))
 
-    windows = (upto[-1, -1] + win - 1) // win
-    y = lax.fori_loop(0, windows, one, jnp.zeros((n, h), jnp.float32))
+    y = lax.fori_loop(0, (pairs + win - 1) // win, one,
+                      jnp.zeros((n, h), jnp.float32))
     return y.astype(x.dtype)
 
 
@@ -378,6 +398,243 @@ _one_window_jit = jax.jit(
     _one_window, static_argnames=("tm", "wt", "interpret"))
 _held_windows_jit = jax.jit(
     _held_windows, static_argnames=("tm", "wt", "win", "interpret"))
+
+
+# --- the backward ---------------------------------------------------------------
+# Over the same listing of the held pairs, expert by expert, a window at a
+# time, and the same visits (expert, row tile).  With ``act = silu(g) * u``,
+# ``g = x Wg``, ``u = x Wu`` and a pair's result ``w * act Wd``:
+#   d act = w * dY Wd^T,      d w  = <act, dY Wd^T>,
+#   dX    = dG Wg^T + dU Wu^T (scatter-added to the rows' order),
+#   dWd[e] = (w * act)_e^T dY_e,  dWg[e] = X_e^T dG_e,  dWu[e] = X_e^T dU_e.
+# ``grouped_expert_ffn_dx`` recomputes ``g`` and ``u`` (under recomputation
+# by layer they would be remade anyway) and returns the pairs' dX, d w and
+# the three (pairs, width) arrays the bank's gradients are products of;
+# ``grouped_expert_ffn_dw`` is one product grouped by expert, ``out[e] +=
+# A_e^T B_e``, that walks an expert's row tiles and sums in float32 into a
+# bank-shaped accumulator carried through the windows (an expert with no
+# row keeps its zeros).
+
+def _mine(v, tid_ref, lo_ref, hi_ref, tm):
+    pair = tid_ref[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return jnp.logical_and(pair >= lo_ref[v], pair < hi_ref[v])
+
+
+def _dx_kernel(eid_ref, tid_ref, lo_ref, hi_ref, total_ref,
+               x_ref, dy_ref, w_ref, gate_ref, up_ref, down_ref,
+               dx_ref, dw_ref, dg_ref, du_ref, act_ref):
+    from jax.experimental import pallas as pl
+
+    v = pl.program_id(0)
+    tm = x_ref.shape[0]
+
+    @pl.when(v < total_ref[0])
+    def _visit():
+        x, dy, w = x_ref[...], dy_ref[...], w_ref[...]
+        g = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+        sig = jax.nn.sigmoid(g)
+        silu = g * sig
+        act = silu * u
+        nt = (((1,), (1,)), ((), ()))
+        dact0 = lax.dot_general(dy, down_ref[0], nt,
+                                preferred_element_type=jnp.float32)
+        dact = dact0 * w
+        dg = (dact * u * (sig + silu * (1.0 - sig))).astype(x.dtype)
+        du = (dact * silu).astype(x.dtype)
+        dx = lax.dot_general(dg, gate_ref[0], nt,
+                             preferred_element_type=jnp.float32) \
+            + lax.dot_general(du, up_ref[0], nt,
+                              preferred_element_type=jnp.float32)
+        mine = _mine(v, tid_ref, lo_ref, hi_ref, tm)
+        # the tile's first visit owes the other experts' rows nothing yet
+        opened = jnp.logical_or(
+            v == 0, tid_ref[jnp.maximum(v - 1, 0)] != tid_ref[v])
+
+        def put(ref, val):
+            rest = jnp.where(opened, jnp.zeros_like(val), ref[...])
+            ref[...] = jnp.where(mine, val, rest)
+
+        put(dx_ref, dx)
+        put(dw_ref, (act * dact0).sum(axis=1, keepdims=True))
+        put(dg_ref, dg)
+        put(du_ref, du)
+        put(act_ref, (act * w).astype(x.dtype))
+
+
+def _dw_kernel(eid_ref, tid_ref, lo_ref, hi_ref, total_ref,
+               a_ref, b_ref, acc_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    v = pl.program_id(0)
+    tm = a_ref.shape[0]
+    first = jnp.logical_or(v == 0,
+                           eid_ref[jnp.maximum(v - 1, 0)] != eid_ref[v])
+
+    @pl.when(first)
+    def _open():
+        o_ref[...] = acc_ref[...]
+
+    @pl.when(v < total_ref[0])
+    def _visit():
+        a = jnp.where(_mine(v, tid_ref, lo_ref, hi_ref, tm), a_ref[...],
+                      jnp.zeros_like(a_ref[...]))
+        o_ref[0] += lax.dot_general(a, b_ref[...], (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+
+
+def _window_bwd(xw, dyw, key, ws, bank, accs, tm, interpret):
+    """One window of the backward: the pairs' rows ``xw`` and ``dyw`` (W,
+    H), experts ``key`` (W,) sorted, weights ``ws`` (W,); ``accs`` the
+    three float32 bank gradients so far -> (dX (W, H) f32, d w (W,) f32,
+    the three accumulators)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    w_gate, w_up, w_down = bank
+    held, h, i = w_gate.shape
+    win = key.shape[0]
+    eid, tid, lo, hi, total = _visits(key, held, tm)
+    itemsize = np.dtype(w_gate.dtype).itemsize
+
+    def at_rows(v, eid, tid, *_):
+        return tid[v], 0
+
+    def at_expert(v, eid, *_):
+        return eid[v], 0, 0
+
+    need = 2 * 3 * h * i * itemsize + 4 * tm * h * (itemsize + 2) \
+        + tm * (8 * i + 2 * h) * 4
+    dx, dw, dg, du, act = pl.pallas_call(
+        _dx_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(eid.shape[0],),
+            in_specs=[pl.BlockSpec((tm, h), at_rows),
+                      pl.BlockSpec((tm, h), at_rows),
+                      pl.BlockSpec((tm, 1), at_rows),
+                      pl.BlockSpec((1, h, i), at_expert),
+                      pl.BlockSpec((1, h, i), at_expert),
+                      pl.BlockSpec((1, i, h), at_expert)],
+            out_specs=[pl.BlockSpec((tm, h), at_rows),
+                       pl.BlockSpec((tm, 1), at_rows),
+                       pl.BlockSpec((tm, i), at_rows),
+                       pl.BlockSpec((tm, i), at_rows),
+                       pl.BlockSpec((tm, i), at_rows)]),
+        out_shape=[jax.ShapeDtypeStruct((win, h), jnp.float32),
+                   jax.ShapeDtypeStruct((win, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((win, i), xw.dtype),
+                   jax.ShapeDtypeStruct((win, i), xw.dtype),
+                   jax.ShapeDtypeStruct((win, i), xw.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(_VMEM_CAP, need + 16 * 2 ** 20)),
+        name="grouped_expert_ffn_dx",
+        interpret=interpret,
+    )(eid, tid, lo, hi, total, xw, dyw, ws[:, None], w_gate, w_up, w_down)
+    there = (key < held)[:, None]
+
+    def by_expert(a, b, acc):
+        """``acc[e] += a_e^T b_e`` over the window's pairs."""
+        ka, kb = a.shape[1], b.shape[1]
+        return pl.pallas_call(
+            _dw_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5, grid=(eid.shape[0],),
+                in_specs=[pl.BlockSpec((tm, ka), at_rows),
+                          pl.BlockSpec((tm, kb), at_rows),
+                          pl.BlockSpec((1, ka, kb), at_expert)],
+                out_specs=pl.BlockSpec((1, ka, kb), at_expert)),
+            out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+            input_output_aliases={7: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=min(
+                    _VMEM_CAP, 4 * ka * kb * 4 + 4 * tm * (ka + kb) * itemsize
+                    + tm * ka * 4 + 16 * 2 ** 20)),
+            name="grouped_expert_ffn_dw",
+            interpret=interpret,
+        )(eid, tid, lo, hi, total, a, b, acc)
+
+    # a pair nobody computed lies in a tile that may never have been
+    # written: zeros, so that the products below add nothing for it
+    dg, du, act = (jnp.where(there, a, jnp.zeros_like(a))
+                   for a in (dg, du, act))
+    d_gate, d_up, d_down = accs
+    accs = (by_expert(xw, dg, d_gate), by_expert(xw, du, d_up),
+            by_expert(act, dyw, d_down))
+    return (jnp.where(there, dx, 0.0), jnp.where(there[:, 0], dw[:, 0], 0.0),
+            accs)
+
+
+def _grouped_bwd(x, idx, weights, w_gate, w_up, w_down, live, dy, tm, win,
+                 interpret):
+    """-> (dX (N, H), d weights (N, k) f32, dWg, dWu, dWd in the bank's
+    dtype)."""
+    n, h = x.shape
+    held = w_gate.shape[0]
+    weights = weights.astype(jnp.float32)
+    dy = dy.astype(x.dtype)
+    pairs, window = _held_pairs(idx, held, live)
+
+    def one(w, carry):
+        dx, dws, accs = carry
+        rows, key_w, slot = window(w, win)
+        ws = jnp.where(slot, weights[rows], 0.0).sum(axis=1)
+        dxw, dwp, accs = _window_bwd(
+            x[rows], dy[rows], key_w, ws, (w_gate, w_up, w_down), accs, tm,
+            interpret)
+        dx = dx.at[rows].add(dxw)
+        dws = dws.at[rows].add(jnp.where(slot, dwp[:, None], 0.0))
+        return dx, dws, accs
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)  # noqa: E731
+    dx, dws, accs = lax.fori_loop(
+        0, (pairs + win - 1) // win, one,
+        (jnp.zeros((n, h), jnp.float32), jnp.zeros(weights.shape, jnp.float32),
+         (zeros(w_gate), zeros(w_up), zeros(w_down))))
+    return (dx.astype(x.dtype), dws,
+            *(a.astype(w_gate.dtype) for a in accs))
+
+
+_grouped_bwd_jit = jax.jit(_grouped_bwd,
+                           static_argnames=("tm", "win", "interpret"))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _grouped(x, idx, weights, w_gate, w_up, w_down, live, static):
+    tm, wt, win, interpret = static
+    if -(-idx.size // tm) * tm <= win:
+        return _one_window_jit(x, idx, weights, w_gate, w_up, w_down, live,
+                               tm=tm, wt=wt, interpret=interpret)
+    return _held_windows_jit(x, idx, weights, w_gate, w_up, w_down, live,
+                             tm=tm, wt=wt, win=win, interpret=interpret)
+
+
+def _grouped_fwd(x, idx, weights, w_gate, w_up, w_down, live, static):
+    return (_grouped(x, idx, weights, w_gate, w_up, w_down, live, static),
+            (x, idx, weights, w_gate, w_up, w_down, live))
+
+
+def _grouped_vjp(static, res, dy):
+    x, idx, weights, w_gate, w_up, w_down, live = res
+    tm, wt, win, interpret = static
+    if wt != w_gate.shape[2]:
+        raise NotImplementedError(
+            "grouped_expert_ffn's backward takes whole experts a visit; an "
+            f"expert walked in width tiles of {wt} has none yet")
+    # the backward's windows are the forward's; a call whose pairs all
+    # fit one window forward lists its held pairs in windows too
+    if -(-idx.size // tm) * tm <= win:
+        win = max(1, WINDOW_PAIRS // tm) * tm
+    telemetry.gauge("grouped_ffn.bwd.row_tile", tm)
+    telemetry.gauge("grouped_ffn.bwd.window_pairs", win)
+    dx, dws, d_gate, d_up, d_down = _grouped_bwd_jit(
+        x, idx, weights, w_gate, w_up, w_down, live, dy, tm=tm, win=win,
+        interpret=interpret)
+    return (dx, None, dws.astype(weights.dtype), d_gate, d_up, d_down, None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_vjp)
 
 
 def grouped_expert_ffn(x, idx, weights, w_gate, w_up, w_down, live=None,
@@ -394,13 +651,15 @@ def grouped_expert_ffn(x, idx, weights, w_gate, w_up, w_down, live=None,
     ``width_tile`` and ``window`` (sorted pairs, whole row tiles)
     default to what the shapes say (:func:`tiles`,
     :func:`window_pairs`); tests and ``tools/routed_ffn_bench.py`` set
-    them."""
+    them.
+
+    Differentiable (``jax.custom_vjp``) in ``x``, ``weights`` and the
+    three banks: the backward runs over the same held pairs and the same
+    tiles (``grouped_expert_ffn_dx``, ``grouped_expert_ffn_dw``), dropless
+    at any skew, an expert with no row getting zeros."""
     h, i = w_gate.shape[1:]
     tm, wt = tiles(h, i, np.dtype(w_gate.dtype).itemsize) or (ROW_TILE, i)
     tm, wt = row_tile or tm, width_tile or wt
     win = window or window_pairs(*idx.shape, h, tm)
-    if -(-idx.size // tm) * tm <= win:
-        return _one_window_jit(x, idx, weights, w_gate, w_up, w_down, live,
-                               tm=tm, wt=wt, interpret=interpret)
-    return _held_windows_jit(x, idx, weights, w_gate, w_up, w_down, live,
-                             tm=tm, wt=wt, win=win, interpret=interpret)
+    return _grouped(x, idx, weights, w_gate, w_up, w_down, live,
+                    (tm, wt, win, bool(interpret)))

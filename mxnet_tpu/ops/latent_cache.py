@@ -245,6 +245,19 @@ def selected_attention(q_nope, q_rope, rows, valid, w_uk, w_uv, scale):
     return jnp.einsum("...hr,hvr->...hv", ctx, w_uv)
 
 
+def expanded_heads(latent, w_uk, w_uv):
+    """The expansion of logical latent rows ``(B, T, rank + rope)`` into
+    every head's key and value: ``(k_nope (B, T, H, dn), v (B, T, H, dv),
+    k_r (B, T, rope))``, ``k_r`` one for all heads.  What the plain form
+    below and a trainer's attention over whole sequences both start
+    from."""
+    rank = w_uk.shape[-1]
+    c_kv, k_r = latent[..., :rank], latent[..., rank:]
+    k_nope = jnp.einsum("btr,hdr->bthd", c_kv, w_uk)
+    v = jnp.einsum("btr,hvr->bthv", c_kv, w_uv)
+    return k_nope, v, k_r
+
+
 def plain_attention(q_nope, q_rope, latent, chosen, w_uk, w_uv, scale):
     """The expanded form, plain: ``q_nope`` (B, Q, H, dn), ``q_rope``
     (B, Q, H, rope), ``latent`` (B, T, rank + rope) logical rows,
@@ -252,10 +265,7 @@ def plain_attention(q_nope, q_rope, latent, chosen, w_uk, w_uv, scale):
     and ``w_uv`` (H, dv, rank) the two halves of ``W_kvb`` -> (B, Q, H,
     dv).  Keys and values of every position are expanded and every
     score computed; what is not chosen meets probability 0."""
-    rank = w_uk.shape[-1]
-    c_kv, k_r = latent[..., :rank], latent[..., rank:]
-    k_nope = jnp.einsum("btr,hdr->bthd", c_kv, w_uk)
-    v = jnp.einsum("btr,hvr->bthv", c_kv, w_uv)
+    k_nope, v, k_r = expanded_heads(latent, w_uk, w_uv)
     s = (jnp.einsum("bqhd,bthd->bhqt", q_nope, k_nope,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bqhd,btd->bhqt", q_rope, k_r,

@@ -324,9 +324,12 @@ def lane_record(kind, **fields):
     deque append (atomic under CPython, so the writers share no lock).
     Every stamp is a ``time.perf_counter()`` the caller took where the
     work happened; the schema of each ``kind`` is in
-    docs/observability.md."""
+    docs/observability.md.  Returns the record, which a writer may
+    complete later (a train dispatch's reported values arrive when they
+    are fetched)."""
     fields["kind"] = kind
     _lane_log.append(fields)
+    return fields
 
 
 def lane_log(kind=None, since=None, until=None):

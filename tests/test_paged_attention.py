@@ -873,6 +873,67 @@ def test_training_flash_kernels_compile_for_v5e(one_chip, shape, dtype,
     assert (lanes in text) == (hb > 1) and (column in text) == (hb == 1)
 
 
+def _compiled_without_cache(fn, *avals):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            return jax.jit(fn).lower(*avals).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+
+
+def test_training_kernels_of_the_latent_expert_cell_compile_for_v5e(one_chip):
+    """``joyai_flash.pretrain_s4k``: the three flash kernels at q/k 192 wide
+    and v 128, causal, 4 x 32 heads x 4,096 (Mosaic takes the one and a half
+    lane tiles as they are: nothing is padded in HBM), and the grouped expert
+    feed-forward's backward at 16,384 rows, 8 of 256 experts a row, 16 of 768
+    held: one ``grouped_expert_ffn_dx`` and three ``grouped_expert_ffn_dw``
+    under the windows' loop, no every-expert intermediate and nothing of
+    ``rows x k`` rows."""
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops import grouped_ffn
+
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, k, v, do):
+        o, lse = fa._fa_forward_pallas(q, k, v, True, 192 ** -0.5,
+                                       with_lse=True)
+        return fa._fa_backward_pallas(q, k, v, o, do, lse, True, 192 ** -0.5)
+
+    wide, narrow = sds((4, 32, 4096, 192)), sds((4, 32, 4096, 128))
+    text = _compiled_without_cache(attend, wide, wide, narrow,
+                                   narrow).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "[128,4096,256]" not in text          # no padded copy of a head
+
+    rows, k, held, h, i = 16384, 8, 16, 2048, 768
+
+    def loss(x, idx, w, wg, wu, wd):
+        return grouped_ffn.grouped_expert_ffn(x, idx, w, wg, wu, wd) \
+            .astype(jnp.float32).sum()
+
+    compiled = _compiled_without_cache(
+        jax.grad(loss, argnums=(0, 2, 3, 4, 5)), sds((rows, h)),
+        sds((rows, k), jnp.int32), sds((rows, k), jnp.float32),
+        sds((held, h, i)), sds((held, h, i)), sds((held, i, h)))
+    text = compiled.as_text()
+    names = [ln.split("=")[0].strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum("grouped_expert_ffn_dx" in n for n in names) == 1
+    assert sum("grouped_expert_ffn_dw" in n for n in names) == 3
+    assert f"[{rows},{held},{i}]" not in text
+    assert f"[{rows * k}," not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 @pytest.mark.parametrize("rows,k,held,hidden,width", [
     (512, 8, 128, 2048, 768),     # sdar_30b.chat_decode_sat: a block pass
     (512, 4, 64, 2048, 1536),     # lfm2_24b: its 512 prefill bucket

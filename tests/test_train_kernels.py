@@ -1,0 +1,207 @@
+"""The trainer's kernels that a model with latent attention and routed
+experts needs: the flash kernels with ``v`` narrower than ``q`` and ``k``
+(forward, ``dq``, ``dkv``; causal, several tiles) against ``_sdpa_ref``'s
+gradients, and the grouped expert feed-forward's backward in Pallas
+interpret mode against the gradients XLA takes of the every-expert form."""
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxnet_tpu.models import moe
+from mxnet_tpu.ops import flash_attention as fa
+from mxnet_tpu.ops import grouped_ffn
+
+rs = np.random.RandomState(7)
+
+
+def _close(a, b, tol):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), \
+        np.abs(a - b).max()
+
+
+# --- flash attention at two head widths ------------------------------------------
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("t,block", [(256, 128), (128, 128)],
+                         ids=["four_tiles", "one_tile"])
+def test_flash_kernels_with_a_narrower_v(causal, t, block):
+    """q and k 24 wide, v 16: the Pallas forward (with its log-sum-exp), dq
+    and dkv in interpret mode, and the chunked ``jax.numpy`` fall-backs."""
+    b, h, d, dv = 2, 3, 24, 16
+    q, k = (jnp.asarray(rs.randn(b, h, t, d), jnp.float32) for _ in "qk")
+    v = jnp.asarray(rs.randn(b, h, t, dv), jnp.float32)
+    do = jnp.asarray(rs.randn(b, h, t, dv), jnp.float32)
+    scale = d ** -0.5
+    want, vjp = jax.vjp(lambda q, k, v: fa._sdpa_ref(q, k, v, causal, scale),
+                        q, k, v)
+    grads = vjp(do)
+    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block, block,
+                                   with_lse=True, interpret=True)
+    assert o.shape == (b, h, t, dv) and lse.shape == (b, h, t)
+    _close(o, want, 2e-5)
+    got = fa._fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block,
+                                 block, interpret=True)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    for g, w in zip(got, grads):
+        _close(g, w, 5e-5)
+    # the fall-backs a CPU run takes
+    _close(fa._fa_forward_chunked(q, k, v, causal, scale, block=block), want,
+           2e-5)
+    for g, w in zip(fa._fa_backward(q, k, v, want, do, causal, scale,
+                                    block=block), grads):
+        _close(g, w, 5e-5)
+    # and the entry, differentiated
+    out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_raw(
+        q, k, v, causal, scale), q, k, v)
+    _close(out, want, 2e-5)
+    for g, w in zip(vjp(do), grads):
+        _close(g, w, 5e-5)
+
+
+#: sha256 (16 hex digits) of the jaxpr (source locations taken out) of the
+#: differentiated ``flash_attention_raw`` with the Pallas kernels forced, on
+#: the commit before ``v`` got a width of its own (PR 43, 5a061cd): at equal
+#: widths the three kernels are what they were, grid, blocks and bodies.
+PARENT_FLASH = {
+    ((128, 12, 128, 64), False): "414ec3460b14916a",    # bert_base.pretrain_s128
+    ((2, 8, 1024, 64), True): "a9493e626e9b3ca3",       # past one tile, causal
+}
+
+
+@pytest.mark.parametrize("shape,causal", list(PARENT_FLASH))
+def test_equal_widths_trace_the_kernels_of_the_parent(monkeypatch, shape,
+                                                      causal):
+    monkeypatch.setenv("MXT_FORCE_PALLAS_FLASH", "1")
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention_raw(q, k, v, causal, 0.125) \
+            .astype(jnp.float32).sum()
+
+    with jax.enable_x64(False):
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x))
+    text = re.sub(r" at [^\s\]]+:\d+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_FLASH[shape, causal]
+    assert fa.train_tiles(shape[0] * shape[1], shape[2], shape[2], shape[3]) \
+        == (16 if shape[2] == 128 else 1)
+
+
+def test_train_form_names_the_tiles(monkeypatch):
+    assert fa.train_form((4, 32, 4096, 192), 128) == "chunked"   # a CPU
+    monkeypatch.setenv("MXT_FORCE_PALLAS_FLASH", "1")
+    assert fa.train_form((4, 32, 4096, 192), 128) \
+        == "pallas:512x512:d192/128:hb1"
+    assert fa.train_form((128, 12, 128, 64)) == "pallas:128x128:d64/64:hb16"
+
+
+# --- the grouped expert feed-forward's backward -------------------------------
+
+def _case(n, h, i, e, first, held, k, skew=True, part_live=True):
+    x = jnp.asarray(rs.randn(n, h), jnp.float32)
+    bank = [jnp.asarray(rs.randn(*s) * 0.1, jnp.float32)
+            for s in ((held, h, i), (held, h, i), (held, i, h))]
+    logits = rs.randn(n, e)
+    if skew:
+        logits[:, first + 1] += 3.0          # most rows take this one
+        logits[:, first + held - 2] -= 100.0    # and this one gets no row
+    idx = jnp.asarray(np.argsort(-logits, axis=1)[:, :k].astype(np.int32))
+    w = jnp.asarray(rs.rand(n, k), jnp.float32)
+    live = jnp.asarray(rs.rand(n) > 0.1) if part_live else None
+    return x, idx - first, w, bank, live
+
+
+def _every_expert(x, idx, w, bank, live):
+    """The other form of ``routed_ffn``, written out: what XLA differentiates."""
+    n, held = x.shape[0], bank[0].shape[0]
+    there = (idx >= 0) & (idx < held)
+    if live is not None:
+        there = there & live[:, None]
+    comb = jnp.zeros((n, held + 1)).at[
+        jnp.arange(n)[:, None], jnp.where(there, idx, held)].add(
+            jnp.where(there, w, 0.0))[:, :held]
+    g = jnp.einsum("nh,ehi->nei", x, bank[0])
+    u = jnp.einsum("nh,ehi->nei", x, bank[1])
+    act = g * jax.nn.sigmoid(g) * u * comb[:, :, None]
+    return jnp.einsum("nei,eih->nh", act, bank[2])
+
+
+@pytest.mark.parametrize("n,window", [(300, None), (300, 256), (129, 128)],
+                         ids=["one_window_forward", "windows_of_256",
+                              "rows_off_the_tile"])
+def test_grouped_backward_matches_every_expert(n, window):
+    """Skewed routing, an expert with no row, rows not a multiple of the
+    tile, a held part (6 of 16 from the 4th) of the router, rows no request
+    owns: dX, the three banks' gradients and the combine weights'."""
+    x, idx, w, bank, live = _case(n, 128, 128, 16, 3, 6, 4)
+    dy = jnp.asarray(rs.randn(n, 128), jnp.float32)
+
+    def ker(x, w, *bank):
+        return grouped_ffn.grouped_expert_ffn(
+            x, idx, w, *bank, live, window=window, interpret=True)
+
+    def ref(x, w, *bank):
+        return _every_expert(x, idx, w, bank, live)
+
+    _close(ker(x, w, *bank), ref(x, w, *bank), 1e-5)
+    got = jax.grad(lambda *a: (ker(*a) * dy).sum(), argnums=range(5))(
+        x, w, *bank)
+    want = jax.grad(lambda *a: (ref(*a) * dy).sum(), argnums=range(5))(
+        x, w, *bank)
+    for g, r in zip(got, want):
+        _close(g, r, 2e-5)
+    # the expert without a row: zeros, not what the buffer held
+    empty = 6 - 2
+    assert not np.asarray(got[2][empty]).any()
+    assert not np.asarray(got[4][empty]).any()
+    # a pair on another chip's expert, or of a row nobody owns, has no say
+    there = np.asarray((idx >= 0) & (idx < 6) & live[:, None])
+    assert not np.asarray(got[1])[~there].any()
+
+
+def test_grouped_backward_in_bfloat16_accumulates_in_float32():
+    x, idx, w, bank, live = _case(256, 128, 128, 8, 0, 8, 2, skew=False,
+                                  part_live=False)
+    dy = jnp.asarray(rs.randn(256, 128), jnp.float32)
+    low = [a.astype(jnp.bfloat16) for a in (x, *bank)]
+
+    def ker(x, *bank):
+        return grouped_ffn.grouped_expert_ffn(
+            x, idx, w, *bank, interpret=True).astype(jnp.float32)
+
+    def ref(x, *bank):
+        return _every_expert(x, idx, w, bank, None)
+
+    got = jax.grad(lambda *a: (ker(*a) * dy).sum(), argnums=range(4))(*low)
+    want = jax.grad(lambda *a: (ref(*a) * dy).sum(), argnums=range(4))(
+        *(a.astype(jnp.float32) for a in low))
+    for g, r in zip(got, want):
+        assert g.dtype == jnp.bfloat16
+        _close(g.astype(jnp.float32), r, 3e-2)
+
+
+def test_a_walked_expert_has_no_backward_yet():
+    x, idx, w, bank, live = _case(256, 128, 256, 8, 0, 8, 2)
+    with pytest.raises(NotImplementedError, match="width tiles"):
+        jax.grad(lambda x: grouped_ffn.grouped_expert_ffn(
+            x, idx, w, *bank, width_tile=128, interpret=True).sum())(x)
+
+
+def test_routed_ffn_differentiates_in_the_form_expert_product_names():
+    """On a CPU ``expert_product`` says ``every_expert`` and XLA takes the
+    gradient; the router learns through the combine weights."""
+    n, h, i, e = 64, 16, 8, 8
+    x = jnp.asarray(rs.randn(n, h), jnp.float32)
+    router = jnp.asarray(rs.randn(e, h), jnp.float32)
+    bank = [jnp.asarray(rs.randn(*s) * 0.3, jnp.float32)
+            for s in ((4, h, i), (4, h, i), (4, i, h))]
+    assert moe.expert_product(n, 2, 4, h, i, jnp.float32) == "every_expert"
+    g = jax.grad(lambda r: moe.routed_ffn(
+        x, r, *bank, 2, score="sigmoid", experts_held=(2, 4))[0].sum())(router)
+    assert np.abs(np.asarray(g)).max() > 0

@@ -149,3 +149,45 @@ def test_no_array_of_every_pair_at_the_longest_bucket():
     assert " while(" in text
     assert f"[{rows * k}," not in text and f"[{rows * k}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
+def test_grouped_backward_matches_xla_at_the_training_cells_shapes():
+    """``joyai_flash.pretrain_s4k``'s expert layer: 16,384 rows, 8 of 256
+    experts a row, 16 of 768 held, bf16: ``jax.grad`` through the kernels
+    (``grouped_expert_ffn_dx``, ``grouped_expert_ffn_dw``) against XLA's
+    gradient of the every-expert form in 2,048-row chunks: dX, the combine
+    weights through the router, and the three banks."""
+    import jax
+    import jax.numpy as jnp
+    from unittest import mock
+
+    from mxnet_tpu.models import moe
+
+    rows, e, held, k, i = 16384, 256, 16, 8, 768
+    assert moe.expert_product(rows, k, held, H, i, jnp.bfloat16) \
+        == "grouped_kernel"
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    bf = jnp.bfloat16
+    x = jax.random.normal(keys[0], (rows, H), bf)
+    rw = jax.random.normal(keys[1], (e, H), jnp.float32) * 0.02
+    bank = tuple(jax.random.normal(kk, s, bf) * 0.02 for kk, s in zip(
+        keys[2:5], ((held, H, i), (held, H, i), (held, i, H))))
+    dy = jax.random.normal(keys[5], (rows, H), bf)
+
+    def grads(form):
+        def loss(x, rw, *bank):
+            with mock.patch.object(moe, "expert_product", lambda *a: form):
+                y, _ = moe.routed_ffn(x, rw, *bank, k, score="sigmoid",
+                                      scale=2.5, experts_held=(0, held))
+            return (y.astype(jnp.float32) * dy.astype(jnp.float32)).sum()
+        return jax.block_until_ready(
+            jax.jit(jax.grad(loss, argnums=range(5)))(x, rw, *bank))
+
+    got, want = grads("grouped_kernel"), grads("every_expert")
+    for name, a, w in zip(("dx", "drouter", "dgate", "dup", "ddown"),
+                          got, want):
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(a).all(), name
+        rel_rms = float(np.sqrt(np.mean((a - w) ** 2))
+                        / np.sqrt(np.mean(w ** 2)))
+        assert rel_rms <= 2.0 ** -5, (name, rel_rms)

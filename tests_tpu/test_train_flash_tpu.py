@@ -98,3 +98,49 @@ def test_rows_a_step_change_no_result_on_the_chip(monkeypatch):
     monkeypatch.setattr(fa, "train_tiles", lambda *a: 1)
     for name, a, w in zip(("o", "lse", "dq", "dk", "dv"), chosen, run()):
         assert np.array_equal(a, w), name
+
+
+def test_latent_heads_of_192_and_128_causal_at_4096():
+    """``joyai_flash.pretrain_s4k``'s attention: q and k 192 wide (128 + 64
+    rotary), v 128, causal, 8 x 8 tiles of 512, one row a step; a quarter of
+    the cell's heads (the reference's float32 scores are 2 GB at 8).  The
+    output and the three gradients against ``_sdpa_ref`` in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, h, t, d, dv = 1, 8, 4096, 192, 128
+    assert fa.train_form((b, h, t, d), dv) == "pallas:512x512:d192/128:hb1"
+    scale = d ** -0.5
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, g = (jax.random.normal(kk, (b, h, t, w), jnp.float32)
+                  .astype(jnp.bfloat16)
+                  for kk, w in zip(keys, (d, d, dv, dv)))
+
+    def kern(q, k, v, g):
+        out, pull = jax.vjp(lambda a, b_, c: fa.flash_attention_raw(
+            a, b_, c, True, scale), q, k, v)
+        return (out,) + pull(g)
+
+    def ref(q, k, v, g):
+        out, pull = jax.vjp(lambda a, b_, c: fa._sdpa_ref(
+            a, b_, c, True, scale),
+            *(a.astype(jnp.float32) for a in (q, k, v)))
+        return (out,) + pull(g.astype(jnp.float32))
+
+    compiled = jax.jit(kern).lower(q, k, v, g).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    got = jax.block_until_ready(compiled(q, k, v, g))
+    assert [a.shape[-1] for a in got] == [dv, d, d, dv]
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(ref)(q, k, v, g))
+    for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(a).all(), name
+        rel_rms = float(np.sqrt(np.mean((a - w) ** 2))
+                        / np.sqrt(np.mean(w ** 2)))
+        rel_max = float(np.abs(a - w).max() / np.abs(w).max())
+        assert rel_rms <= 2.0 ** -6 and rel_max <= 2.0 ** -4, \
+            (name, rel_rms, rel_max)

@@ -47,10 +47,13 @@ def main():
         help="block_q:block_k pairs of the sweep at heads of 128")
     ap.add_argument("--kernel-only", action="store_true",
                     help="skip the two-layer prefill programs")
-    ap.add_argument("--train", metavar="B,H,T,D",
+    ap.add_argument("--train", metavar="B,H,T,D[,Dv]",
                     help="the training kernels alone at this shape "
                          "(forward, dq, dkv), by rows a grid step; "
-                         "nothing of the prefill")
+                         "nothing of the prefill.  Dv: v's own width "
+                         "(latent attention's expanded heads: 192,128)")
+    ap.add_argument("--causal", action="store_true",
+                    help="the --train kernels under the causal mask")
     ap.add_argument("--rows", default="1,4,8,12,16,24",
                     help="rows a grid step of the --train sweep; the "
                          "rule's own choice is always timed")
@@ -189,9 +192,10 @@ def main():
 
 
 def train(args, fa, say, timed):
-    """Forward, dq and dkv at one (B, H, T, D) in bf16, non-causal as
-    BERT runs them: each a program of ``CALLS`` chained calls (the
-    result feeds the next call's q, or k and v), by rows a grid step
+    """Forward, dq and dkv at one (B, H, T, D) in bf16 (``v``, ``o`` and
+    ``do`` Dv wide where a fifth number is given), non-causal as BERT
+    runs them or ``--causal``: each a program of ``CALLS`` chained calls
+    (the result feeds the next call's q, or k and v), by rows a grid step
     (``train_tiles`` patched, as tier 1 patches it).  A program that
     returns dq alone holds no dkv call and the other way round (XLA
     drops a kernel whose results nothing reads); ``delta`` is computed
@@ -200,40 +204,50 @@ def train(args, fa, say, timed):
     import jax.numpy as jnp
     import numpy as np
 
-    b, h, t, d = (int(n) for n in args.train.split(","))
+    b, h, t, d, *rest = (int(n) for n in args.train.split(","))
+    dv, causal = (rest[0] if rest else d), bool(args.causal)
     scale = 1.0 / float(np.sqrt(d))
     keys = jax.random.split(jax.random.PRNGKey(t + d), 4)
-    q, k, v, do = (jax.random.normal(kk, (b, h, t, d), jnp.bfloat16)
-                   for kk in keys)
+    q, k, v, do = (jax.random.normal(kk, (b, h, t, w), jnp.bfloat16)
+                   for kk, w in zip(keys, (d, d, dv, dv)))
     rule = fa.train_tiles
-    chosen = rule(b * h, t, t, d)
+    chosen = rule(b * h, t, t, max(d, dv))
     o, lse = jax.jit(lambda q, k, v: fa._fa_forward_pallas(
-        q, k, v, False, scale, with_lse=True))(q, k, v)
+        q, k, v, causal, scale, with_lse=True))(q, k, v)
+    if dv != d:
+        # the chain feeds a result back as the next call's operand: at two
+        # widths a call's results are summed into a scalar nudge instead
+        def nudge(x, *results):
+            return x + sum(r.astype(jnp.float32).mean() for r in results) \
+                .astype(x.dtype) * 0
+    else:
+        nudge = None
 
     def fwd(q, k, v, o, do, lse):
         for _ in range(CALLS):
-            q = fa._fa_forward_pallas(q, k, v, False, scale,
-                                      with_lse=True)[0]
+            out = fa._fa_forward_pallas(q, k, v, causal, scale,
+                                        with_lse=True)[0]
+            q = nudge(q, out) if nudge else out
         return q
 
     def dq(q, k, v, o, do, lse):
         for _ in range(CALLS):
-            q = fa._fa_backward_pallas(q, k, v, o, do, lse, False,
+            q = fa._fa_backward_pallas(q, k, v, o, do, lse, causal,
                                        scale)[0]
         return q
 
     def dkv(q, k, v, o, do, lse):
         for _ in range(CALLS):
-            _, k, v = fa._fa_backward_pallas(q, k, v, o, do, lse, False,
+            _, k, v = fa._fa_backward_pallas(q, k, v, o, do, lse, causal,
                                              scale)
         return k, v
 
     def vjp(q, k, v, o, do, lse):
         for _ in range(CALLS):
             out, pull = jax.vjp(lambda a, b_, c: fa.flash_attention_raw(
-                a, b_, c, False, scale), q, k, v)
+                a, b_, c, causal, scale), q, k, v)
             q, k, v = pull(do)
-            q = q + out
+            q = nudge(q, out) if nudge else q + out
         return q, k, v
 
     rows = sorted({int(r) for r in args.rows.split(",") if r} | {chosen})
@@ -242,8 +256,8 @@ def train(args, fa, say, timed):
             if (b * h) % hb:
                 continue
             fa.train_tiles = lambda *_: hb
-            rec = {"what": "train", "shape": [b, h, t, d], "hb": hb,
-                   "chosen": hb == chosen}
+            rec = {"what": "train", "shape": [b, h, t, d], "dv": dv,
+                   "causal": causal, "hb": hb, "chosen": hb == chosen}
             for name, fn in (("fwd", fwd), ("dq", dq), ("dkv", dkv),
                              ("vjp", vjp)):
                 try:

@@ -55,6 +55,10 @@ def main():
     ap.add_argument("--rows", default="128,256,512,2048")
     ap.add_argument("--tiles", default=str(grouped_ffn.ROW_TILE))
     ap.add_argument("--score", default="softmax")
+    ap.add_argument("--train", action="store_true",
+                    help="time forward AND backward of each form (the "
+                         "gradient in the rows, the router and the bank), "
+                         "one call a program, not the forward chained")
     args = ap.parse_args()
     e, k, h, i = args.experts, args.k, args.hidden, args.width
     held = args.held or e
@@ -88,6 +92,23 @@ def main():
         return fn
 
     def chained(fn):
+        if args.train:
+            # one layer's forward and backward: XLA's gradient of the
+            # every-expert form, the kernels' custom_vjp of the other
+            def loss(x, bank):
+                return fn(x, *bank).astype(jnp.float32).sum()
+
+            grad = jax.grad(loss, argnums=(0, 1))
+
+            def run(x, bank):
+                for _ in range(8):
+                    dx, dbank = grad(x, bank)
+                    x = (x + dx * 1e-3).astype(bf)
+                    bank = tuple((a + d * 1e-3).astype(a.dtype)
+                                 for a, d in zip(bank, dbank))
+                return x
+            return jax.jit(run)
+
         def run(x, bank):
             for _ in range(8):
                 x = (x + fn(x, *bank)).astype(bf)
@@ -109,7 +130,8 @@ def main():
     dev = jax.devices()[0]
     for n in (int(r) for r in args.rows.split(",")):
         x = jax.random.normal(keys[4], (n, h), bf)
-        row = {"rows": n, "experts": e, "held": held, "k": k, "hidden": h,
+        row = {"train": bool(args.train),
+               "rows": n, "experts": e, "held": held, "k": k, "hidden": h,
                "width": i, "device": dev.device_kind,
                "rule_picks": moe.expert_product(n, k, held, h, i, bf),
                "row_tile": row_tile, "width_tile": width_tile,
