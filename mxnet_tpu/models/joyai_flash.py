@@ -496,7 +496,7 @@ class JoyAIFlashForPretraining(HybridBlock):
             "flash_tiles": flash_attention.train_form(
                 (b, cfg.num_heads, t,
                  cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
-                cfg.v_head_dim, np.dtype(dtype).itemsize)}
+                cfg.v_head_dim, np.dtype(dtype).itemsize, causal=True)}
 
 
 def pretrain_forward_loss(net, ids):

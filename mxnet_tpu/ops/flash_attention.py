@@ -17,15 +17,22 @@ each prompt's true length bounding the tiles computed).
 
 A grid step.  The grid is ``(B * Hkv / hb, nq, nk)`` (``dkv``: ``nk``
 before ``nq``): a step is one (q tile, k tile) pair of ``hb`` (batch,
-head) rows.  ``hb`` is 1 — a step is a tile pair of one head of one
+head) rows.  Under the causal mask past one tile the trainer's three
+kernels walk ``(B * H, live steps)`` instead, the pairs under the
+diagonal as a scalar-prefetched list (``_live_steps``): no step is
+spent above it.  ``hb`` is 1 — a step is a tile pair of one head of one
 batch row — for the served prefill and wherever a sequence spans more
 than one tile.  Where one tile holds a head's whole sequence (the
 trainer at sequences up to 512: BERT's 128) a step of one row is all
 fixed cost, so it takes ``train_tiles`` rows at once, a leading batch
-axis of the same arithmetic; the choice is static, from shapes, and the
-gauges ``flash.rows_per_step.fwd`` / ``.dq`` / ``.dkv`` record it where
-the program is traced, and the row statistics (``lse``, ``delta``) then
-travel along lanes, ``(bh, 1, T)``, not one number a lane tile.
+axis of the same arithmetic; past one tile each of the trainer's three
+kernels has tiles of its own (``train_blocks``).  Both choices are
+static, from shapes, and the gauges ``flash.rows_per_step.fwd`` /
+``.dq`` / ``.dkv``, ``flash.grid_steps.*`` and ``flash.live_steps.*``
+record them where the program is traced.  The backward's row statistics
+(``lse``, ``delta``) travel along lanes, ``(bh, 1, T)``, not one number
+a lane tile, and so does the forward's ``lse`` where a step is several
+rows.
 
 Backward: ``jax.custom_vjp``; on the TPU the two Pallas kernels below
 (``dq``, ``dkv``), elsewhere a K-block-chunked jnp backward
@@ -173,6 +180,20 @@ def _tile_runs(qi, kj, n, *, block_q, block_k, causal):
     return run
 
 
+def _live_steps(nq, nk, block_q, block_k, q_outside=True):
+    """The (q tile, k tile) pairs of a causal grid that ``_tile_runs``, in
+    the order a kernel sweeps them (q tiles outside and k tiles inside,
+    or the other way for ``dkv``), as two tuples: outer tiles, inner
+    tiles.  A kernel whose grid is this list (scalar-prefetched) has no
+    step above the diagonal: on the v5e such a step, which computes and
+    fetches nothing, still cost about a microsecond (PERF.md, PR 45)."""
+    outer, inner = (nq, nk) if q_outside else (nk, nq)
+    pairs = [(a, b) for a in range(outer) for b in range(inner)
+             if _tile_runs(*((a, b) if q_outside else (b, a)), None,
+                           block_q=block_q, block_k=block_k, causal=True)]
+    return tuple(zip(*pairs))
+
+
 def _dot(a, b, ca, cb):
     """``a`` . ``b`` over axis ``ca`` of ``a``'s matrix and ``cb`` of
     ``b``'s (0 or 1, counted within the last two axes), float32
@@ -185,7 +206,7 @@ def _dot(a, b, ca, cb):
 
 
 def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
-               with_lse, bounded, span=1, hb=1):
+               with_lse, bounded, span=1, hb=1, listed=False):
     """Canonical 3-D-grid flash kernel: grid (B * Hkv / hb, nq, nk), kv
     innermost; running (m, l, acc) live in VMEM scratch across the kv
     sweep so pallas double-buffers the K/V block loads.
@@ -215,12 +236,18 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     length.  A row then sees its whole block of ``span`` positions and
     every block before it, ``kpos < (qpos // span + 1) * span``; the
     blocks end where the tiles do, so the same tiles run and the same
-    ones pay for the mask."""
+    ones pay for the mask.
+
+    ``listed``: the grid is ``(B * H, live steps)`` and the first two
+    refs are ``_live_steps``' scalar-prefetched lists, a step's q tile
+    and its k tile (the trainer under the causal mask past one tile;
+    never with ``bounded``)."""
     from jax.experimental import pallas as pl
 
     len_ref = refs[0] if bounded else None
-    q_ref, k_ref, v_ref, o_ref = refs[bounded:bounded + 4]
-    lse_ref = refs[bounded + 4] if with_lse else None
+    first = 2 if listed else int(bounded)     # the scalar-prefetched refs
+    q_ref, k_ref, v_ref, o_ref = refs[first:first + 4]
+    lse_ref = refs[first + 4] if with_lse else None
     m_ref, l_ref, acc_ref = refs[-3:]
     group, _, d = q_ref.shape[1:]
     dv = v_ref.shape[-1]          # v's own width: o's and the accumulator's
@@ -228,8 +255,15 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     # the step's rows of ``bh``: one (the program every caller had), or
     # ``hb`` of them as a leading batch axis of every array below
     lead, blk = ((), 0) if hb == 1 else ((hb,), slice(None))
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    if listed:
+        # grid (bh, live steps), the trainer's causal grid past one tile
+        # (``_live_steps``): the step's tiles are read from the two lists
+        qi, kj = (ref[pl.program_id(1)] for ref in refs[:2])
+        last = jnp.minimum(((qi + 1) * block_q - 1) // block_k, nk - 1)
+    else:
+        qi = pl.program_id(1)
+        kj = pl.program_id(2)
+        last = nk - 1
     n = len_ref[pl.program_id(0) // kv_heads] if bounded else None
 
     @pl.when(kj == 0)
@@ -272,7 +306,7 @@ def _fa_kernel(*refs, block_q, block_k, causal, scale, nk, kv_heads,
     else:
         pl.when(run)(lambda: tile(False))
 
-    @pl.when(kj == nk - 1)
+    @pl.when(kj == last)
     def _finish():
         l = l_ref[...]
         o_ref[blk] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
@@ -304,24 +338,52 @@ def _divisor_block(t, pref):
 TRAIN_VMEM_BYTES = 12 << 20
 
 
-def train_row_bytes(tq, tk, d, itemsize=2):  # d: the wider of q/k's and v's
-    """VMEM a (batch, head) row of a one-tile step asks for, by the
-    largest of the three kernels (``dkv``): its four operand and two
-    result blocks double-buffered, heads narrower than a tile's 128
+def train_row_bytes(tq, tk, d, itemsize=2, kernel=None, dv=None):
+    """VMEM a (batch, head) row of a step of ``tq`` queries by ``tk`` keys
+    asks for.
+
+    A one-tile step (``kernel`` None; ``d`` the wider of q/k's and v's),
+    by the largest of the three kernels (``dkv``): its four operand and
+    two result blocks double-buffered, heads narrower than a tile's 128
     lanes padded to them, and four score-shaped float32 arrays (the
     float32 casts and the accumulators reuse what is dead).  0.655 MB at
     T = 128, D = 64 in bf16; the compiler counts 0.636 (20.35 MB at 32
-    rows)."""
+    rows).
+
+    A step of a grid of several tiles, by ``kernel`` (``"fwd"``,
+    ``"dq"``, ``"dkv"``) at q and k ``d`` wide and v ``dv``: its blocks
+    double-buffered, its float32 accumulators (the forward's ``m``,
+    ``l`` and ``lse`` one number a lane tile), the float32 casts of a
+    backward kernel's operands, and ONE score-shaped float32 array: with
+    no guard on ``lse`` Mosaic walks the elementwise chain between the
+    products in strips.  Against the smallest ``vmem_limit_bytes`` that
+    compiles for the v5e (tiles of 512 and 1,024, heads of 64, 128,
+    192 / 128 and 256 in bf16, 128 in float32; PERF.md, PR 45) this count
+    lies between 2.7 MiB above and 2.3 MiB below: ``dq`` at 1,024 x
+    1,024 is 12.0 MiB here and 11.8 there at heads of 192 / 128, 14.0
+    and 16.3 at heads of 256, which the 16 MiB a kernel gets refuse."""
     lanes = -(-d // 128) * 128
-    return 2 * (4 * tq + 2 * tk) * lanes * itemsize + 4 * tq * tk * 4
+    if kernel is None:
+        return 2 * (4 * tq + 2 * tk) * lanes * itemsize + 4 * tq * tk * 4
+    row = lanes + -(-(dv or d) // 128) * 128      # q or k beside do or v
+    if kernel == "fwd":
+        blocks, acc, casts = (tq + tk) * row, tq * (row - lanes + 512), 0
+    elif kernel == "dq":
+        blocks, acc, casts = tq * (row + lanes) + tk * row, tq * lanes, 1
+    else:
+        blocks, acc, casts = (tq + 2 * tk) * row, tk * row, 1
+    if itemsize >= 4:
+        casts = 0
+    return (2 * blocks * itemsize + 4 * acc + 4 * casts * (tq + tk) * row
+            + 4 * tq * tk)
 
 
 def train_tiles(bh, tq, tk, d, itemsize=2):
     """Rows of ``bh = B * H`` a grid step of the three training kernels
-    takes: 1, today's grid, unless one tile holds a head's whole
-    sequence (``nq == nk == 1`` at the entries' default blocks: sequences
-    up to 512); then the largest divisor of ``bh`` whose working set fits
-    ``TRAIN_VMEM_BYTES``.
+    takes: 1 unless one tile holds a head's whole sequence (``nq == nk ==
+    1`` at blocks of 512: sequences up to 512, where ``train_blocks``
+    gives one tile); then the largest divisor of ``bh`` whose working set
+    fits ``TRAIN_VMEM_BYTES``.
 
     On the v5e at BERT-base's ``(128 x 12, 128, 128, 64)`` in bf16, ms a
     layer-call, forward / dq / dkv (PERF.md, PR 36; the program before
@@ -343,7 +405,83 @@ def train_tiles(bh, tq, tk, d, itemsize=2):
     return max(c for c in range(1, max(1, min(cap, bh)) + 1) if bh % c == 0)
 
 
-def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
+def train_blocks(kernel, tq, tk, d, dv=None, itemsize=2, causal=False):
+    """(block_q, block_k) of training kernel ``kernel`` (``"fwd"``,
+    ``"dq"``, ``"dkv"``) at q and k ``d`` wide and v ``dv``: one tile
+    where 512 positions hold the sequence (``train_tiles``' case, rows a
+    step); past it tiles of 1,024 x 1,024 where they divide the sequence
+    and ``train_row_bytes`` fits them into ``TRAIN_VMEM_BYTES``, which
+    the backward kernels under the causal mask take only where four of
+    them span the sequence; 512 x 512 otherwise.
+
+    On the v5e at ``(4, 32, T, 192 / 128)`` causal in bf16, ms a
+    layer-call, forward / dq / dkv (PERF.md, PR 45; the program before
+    the rule read 1.30 / 1.30 / 1.47 at T = 1,024, 3.83 / 4.21 / 4.92 at
+    2,048 and 12.46 / 14.15 / 17.44 at 4,096, all at 512 x 512):
+
+    ==============  ==================  ==================  =====================
+    tiles           T = 1,024           2,048               4,096
+    ==============  ==================  ==================  =====================
+    256 x 1,024     1.23 / 1.28 / 1.47  3.55 / 3.46 / 4.06  10.73 / 11.14 / 12.99
+    512 x 512       1.21 / 1.01 / 1.17  3.71 / 2.93 / 3.46  12.01 / 10.05 / 11.80
+    512 x 1,024     1.10 / 1.19 / 1.39  3.06 / 3.23 / 3.81   9.15 / 10.27 / 12.09
+    1,024 x 512     1.55 / 1.18 / 1.40  4.44 / 3.21 / 3.83  13.77 / 10.25 / 12.15
+    1,024 x 1,024   0.88 / 1.11 / 1.33  2.91 / 3.11 / 3.71   8.63 /  9.83 / 11.65
+    512 x 2,048                         3.71 / 4.05 / -     10.41 / 11.71 / -
+    2,048 x 512                         5.42 / - / 4.79     15.49 / - / 13.89
+    ==============  ==================  ==================  =====================
+
+    (- : refused, more VMEM than a kernel gets.)  Made again by
+    ``chiprun -- python tools/prefill_flash_bench.py --train
+    4,32,4096,192,128 --causal --tiles
+    256:1024,512:512,512:1024,1024:512,1024:1024,512:2048,2048:512``.
+    The forward is its cost a score row a step (3.8 ns whatever the keys'
+    width: ``prefill_tiles``) and wants few, wide steps.  The backward
+    kernels are bound by their products (five with the scores recomputed,
+    float32 operands), so a tile on the diagonal, half of it masked, is
+    work lost: a third of the work at T = 1,024 in one tile of 1,024,
+    where three of 512 lose a sixth; from four tiles of 1,024 a side the
+    wide ones win (``dq`` by 2.2%; ``dkv`` by 1.3%, but its VMEM count is
+    13.0 MiB at these heads and it stays at 512 x 512; at heads of 128 it
+    goes wide: 8.27 -> 7.80).  A raised ``vmem_limit_bytes`` changed no
+    time at 1,024 x 1,024 (32 and 64 MiB, on the grid before the list:
+    8.92 / 10.64 / 11.90 against 8.91 / 10.65 / 11.90) and the tiles it
+    admits are slower: 1,024 x 2,048 10.42 / 11.96 / 13.74, 2,048 x 1,024
+    10.07 / 11.91 / 13.74, 2,048 x 2,048 10.27 / 11.82 / 13.63.  Without
+    the mask 1,024 x 1,024 wins at every length (T = 4,096: 12.36 / 15.06
+    / 17.96 against 18.99 / 16.47 / 19.40 at 512 x 512; 1,024: 0.99 /
+    1.12 / 1.32 against 1.45 / 1.21 / 1.42); at heads of 128 causal at
+    4,096 it reads 5.39 / 5.89 / 7.80 against 8.82 / 6.40 / 8.27."""
+    default = (_divisor_block(tq, min(512, tq)),
+               _divisor_block(tk, min(512, tk)))
+    # mxlint: allow=T2 (lengths and widths are static shapes, here and below)
+    if default == (tq, tk) or (
+            kernel != "fwd" and causal and min(tq, tk) < 4 * 1024):
+        return default
+    # the forward's second best keeps the keys wide (the table's 512 x
+    # 1,024); the backward kernels gain nothing from tiles that are not
+    # square
+    wide = ((1024, 1024), (512, 1024)) if kernel == "fwd" \
+        else ((1024, 1024),)
+    for bq, bk in wide:
+        # mxlint: allow=T2
+        if tq % bq == 0 and tk % bk == 0 and train_row_bytes(
+                bq, bk, d, itemsize, kernel, dv) <= TRAIN_VMEM_BYTES:
+            return bq, bk
+    return default
+
+
+def _say_grid(kernel, hb, steps):
+    """The gauges of a training kernel's grid where its program is
+    traced: the (batch, head) rows a step takes and the steps of a head's
+    grid, all of which compute (``flash.live_steps``: under the causal
+    mask past one tile the grid is ``_live_steps``' list)."""
+    telemetry.gauge(f"flash.rows_per_step.{kernel}", hb)
+    telemetry.gauge(f"flash.grid_steps.{kernel}", steps)
+    telemetry.gauge(f"flash.live_steps.{kernel}", steps)
+
+
+def _fa_forward_pallas(q, k, v, causal, scale, block_q=None, block_k=None,
                        with_lse=False, interpret=False, lengths=None,
                        name=None, span=1):
     """q (B, H, T, D), k (B, Hkv, T, D), v (B, Hkv, T, Dv) with Hkv
@@ -354,7 +492,8 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     A grid step is a (q tile, k tile) pair of one KV head of one batch
     row and its query heads; without ``lengths`` (the trainer), with
     equal heads and the whole sequence in one tile, it is that pair of
-    ``train_tiles`` rows of ``B * H``."""
+    ``train_tiles`` rows of ``B * H``.  Tiles not given are
+    ``train_blocks``' (the served prefill gives its own)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -365,29 +504,44 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
     qf = q.reshape(bh, g, tq, d)
     kf = k.reshape(bh, tk, d)
     vf = v.reshape(bh, tk, dv)
+    if block_q is None:
+        block_q, block_k = train_blocks("fwd", tq, tk, d, dv,
+                                        q.dtype.itemsize, causal)
     block_q = _divisor_block(tq, min(block_q, tq))
     block_k = _divisor_block(tk, min(block_k, tk))
-    nk = tk // block_k
+    nq, nk = tq // block_q, tk // block_k
     bounded = lengths is not None
     hb = 1
-    if not bounded:
-        if g == 1 and (block_q, block_k) == (tq, tk):
-            hb = train_tiles(bh, tq, tk, max(d, dv), q.dtype.itemsize)
-        telemetry.gauge("flash.rows_per_step.fwd", hb)
+    if not bounded and g == 1 and nq == nk == 1:
+        hb = train_tiles(bh, tq, tk, max(d, dv), q.dtype.itemsize)
     lead = () if hb == 1 else (hb,)
+    # the trainer's causal grid past one tile is the list of its live
+    # steps; the served prefill's, whose lengths arrive with the call,
+    # stays (q tiles, k tiles)
+    steps = _live_steps(nq, nk, block_q, block_k) \
+        if causal and not bounded and nq * nk > 1 else None
+    if not bounded:
+        _say_grid("fwd", hb, len(steps[0]) if steps else nq * nk)
 
-    def q_map(b_, i, j, *_):
-        return (b_, 0, i, 0)
+    if steps:  # mxlint: allow=T2 (a tuple of python ints or None)
+        def q_map(b_, n, qi, kj):
+            return (b_, 0, qi[n], 0)
 
-    def kv_map(b_, i, j, *lens):
-        if not (causal or bounded):
-            return (b_, j, 0)
-        # a tile that does not run is not fetched: its step asks for
-        # block 0, the first the next q tile needs
-        n = lens[0][b_ // hkv] if bounded else None
-        return (b_, jnp.where(
-            _tile_runs(i, j, n, block_q=block_q, block_k=block_k,
-                       causal=causal), j, 0), 0)
+        def kv_map(b_, n, qi, kj):
+            return (b_, kj[n], 0)
+    else:
+        def q_map(b_, i, j, *_):
+            return (b_, 0, i, 0)
+
+        def kv_map(b_, i, j, *lens):
+            if not (causal or bounded):
+                return (b_, j, 0)
+            # a tile that does not run is not fetched: its step asks for
+            # block 0, the first the next q tile needs
+            n = lens[0][b_ // hkv] if bounded else None
+            return (b_, jnp.where(
+                _tile_runs(i, j, n, block_q=block_q, block_k=block_k,
+                           causal=causal), j, 0), 0)
 
     out_specs = [pl.BlockSpec((hb, g, block_q, dv), q_map)]
     out_shape = [_pallas_out_shape((bh, g, tq, dv), q.dtype, q, k, v)]
@@ -414,10 +568,10 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
         functools.partial(_fa_kernel, block_q=block_q, block_k=block_k,
                           causal=causal, scale=scale, nk=nk, kv_heads=hkv,
                           with_lse=with_lse, bounded=bounded, span=span,
-                          hb=hb),
+                          hb=hb, listed=steps is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=int(bounded),
-            grid=(bh // hb, tq // block_q, nk),
+            num_scalar_prefetch=2 if steps else int(bounded),
+            grid=(bh, len(steps[0])) if steps else (bh // hb, nq, nk),
             in_specs=[
                 pl.BlockSpec((hb, g, block_q, d), q_map),
                 pl.BlockSpec((hb, block_k, d), kv_map),
@@ -430,11 +584,12 @@ def _fa_forward_pallas(q, k, v, causal, scale, block_q=512, block_k=512,
                 pltpu.VMEM((*lead, g * block_q, dv), jnp.float32),  # acc
             ]),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel",) * (1 if steps else 2) + ("arbitrary",)),
         name=name,
         interpret=interpret,
-    )(*((jnp.asarray(lengths, jnp.int32),) if bounded else ()),
+    )(*(jnp.asarray(x, jnp.int32)
+        for x in (steps or ((lengths,) if bounded else ()))),
       qf, kf, vf)
     if with_lse:
         return out[0].reshape(b, h, tq, dv), out[1].reshape(b, h, tq)
@@ -515,44 +670,55 @@ prefill_flash_attention = jax.jit(_prefill_flash_attention,
 # elementwise computed outside.
 
 def _stat(ref, blk, as_row):
-    """A statistics block (``lse`` or ``delta``) for scores laid keys by
-    queries (``as_row``: beside them it is a row ``(..., 1, block_q)``)
-    or queries by keys (a column ``(..., block_q, 1)``) -> the block, and
-    the function that lays what is computed from it beside the scores.
-    Where a step is one row it travels as a column and is read as the
-    1-D vector the kernels always made of it, laid out after (their
-    program unchanged); where a step is several it travels along lanes
-    (``_fa_backward_pallas``) and is laid out once, here."""
-    if ref.shape[-1] == 1:
-        return ref[blk][:, 0], (
-            lambda x: x[None, :]) if as_row else (lambda x: x[:, None])
+    """A statistics block (``lse`` or ``delta``), which travels along
+    lanes (``_fa_backward_pallas``), beside scores laid keys by queries
+    (``as_row``: a row ``(..., 1, block_q)``, as it arrives) or queries
+    by keys (a column ``(..., block_q, 1)``, laid out here)."""
     x = ref[blk]
-    return x if as_row else x[..., 0, :][..., None], lambda x: x
+    return x if as_row else x[..., 0, :][..., None]
 
 
-def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, acc_ref, *, block_q, block_k, causal,
-                      scale, nk, hb=1):
+def _bwd_p(s, lse, guard):
+    """The probabilities again from the scores and the saved log-sum-exp.
+    ``guard``: a row may have seen no key and carry ``lse = -inf``; its
+    probabilities are zeroed explicitly.  Without it every ``lse`` is
+    finite, and a masked score's ``exp(-inf - lse)`` is the zero it
+    needs."""
+    if not guard:
+        return jnp.exp(s - lse)
+    return jnp.where(jnp.isfinite(s) & jnp.isfinite(lse),
+                     jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0)),
+                     0.0)
+
+
+def _fa_bwd_dq_kernel(*refs, block_q, block_k, causal, scale, nk, hb=1,
+                      guard=True, listed=False):
     from jax.experimental import pallas as pl
 
     blk = 0 if hb == 1 else slice(None)
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    if listed:
+        # grid (bh, live steps): the step's tiles are read from the list
+        qi_ref, kj_ref, *refs = refs
+        qi, kj = qi_ref[pl.program_id(1)], kj_ref[pl.program_id(1)]
+        last = jnp.minimum(((qi + 1) * block_q - 1) // block_k, nk - 1)
+    else:
+        qi = pl.program_id(1)
+        kj = pl.program_id(2)
+        last = nk - 1
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+     acc_ref) = refs
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pred = ((qi + 1) * block_q > kj * block_k) if causal else (kj == kj)
-
-    @pl.when(pred)
-    def _compute():
+    def compute():
         q = q_ref[blk].astype(jnp.float32)
         k = k_ref[blk].astype(jnp.float32)
         v = v_ref[blk].astype(jnp.float32)
         do = do_ref[blk].astype(jnp.float32)
-        lse, col = _stat(lse_ref, blk, False)
-        delta = _stat(delta_ref, blk, False)[0]
+        lse = _stat(lse_ref, blk, False)
+        delta = _stat(delta_ref, blk, False)
         s = _dot(q, k, 1, 1) * scale
         if causal:
             qpos = qi * block_q + lax.broadcasted_iota(
@@ -560,45 +726,52 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kpos = kj * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos >= kpos, s, -jnp.inf)
-        # fully-masked rows carry lse=-inf: zero their p explicitly
-        p = jnp.where(jnp.isfinite(s) & col(jnp.isfinite(lse)),
-                      jnp.exp(s - col(jnp.where(jnp.isfinite(lse), lse,
-                                                0.0))), 0.0)
+        p = _bwd_p(s, lse, guard)
         dp = _dot(do, v, 1, 1)
-        ds = p * (dp - col(delta)) * scale
+        ds = p * (dp - delta) * scale
         acc_ref[...] += _dot(ds, k, 1, 0)
 
-    @pl.when(kj == nk - 1)
+    # every step of either grid computes (a causal grid past one tile is
+    # listed); the comparison that always holds is the text the one-tile
+    # program had
+    if listed:
+        compute()
+    else:
+        pl.when(kj == kj)(compute)
+
+    @pl.when(kj == last)
     def _finish():
         dq_ref[blk] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                       block_k, causal, scale, nq, hb=1):
+def _fa_bwd_dkv_kernel(*refs, block_q, block_k, causal, scale, nq, hb=1,
+                       guard=True, listed=False):
     from jax.experimental import pallas as pl
 
     blk = 0 if hb == 1 else slice(None)
-    ki = pl.program_id(1)
-    qj = pl.program_id(2)
+    if listed:
+        ki_ref, qj_ref, *refs = refs
+        ki, qj = ki_ref[pl.program_id(1)], qj_ref[pl.program_id(1)]
+        first = jnp.minimum(ki * block_k // block_q, nq - 1)
+    else:
+        ki = pl.program_id(1)
+        qj = pl.program_id(2)
+        first = 0
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+     dk_acc, dv_acc) = refs
 
-    @pl.when(qj == 0)
+    @pl.when(qj == first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # causal: q blocks strictly above the diagonal see none of this k
-    # block
-    pred = ((qj + 1) * block_q > ki * block_k) if causal else (qj == qj)
-
-    @pl.when(pred)
-    def _compute():
+    def compute():
         q = q_ref[blk].astype(jnp.float32)
         k = k_ref[blk].astype(jnp.float32)
         v = v_ref[blk].astype(jnp.float32)
         do = do_ref[blk].astype(jnp.float32)
-        lse, row = _stat(lse_ref, blk, True)
-        delta = _stat(delta_ref, blk, True)[0]
+        lse = _stat(lse_ref, blk, True)
+        delta = _stat(delta_ref, blk, True)
         st = _dot(k, q, 1, 1) * scale
         if causal:
             kpos = ki * block_k + lax.broadcasted_iota(
@@ -606,13 +779,16 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qpos = qj * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
             st = jnp.where(qpos >= kpos, st, -jnp.inf)
-        pt = jnp.where(jnp.isfinite(st) & row(jnp.isfinite(lse)),
-                       jnp.exp(st - row(jnp.where(jnp.isfinite(lse), lse,
-                                                  0.0))), 0.0)
+        pt = _bwd_p(st, lse, guard)
         dv_acc[...] += _dot(pt, do, 1, 0)
         dpt = _dot(v, do, 1, 1)
-        dst = pt * (dpt - row(delta)) * scale
+        dst = pt * (dpt - delta) * scale
         dk_acc[...] += _dot(dst, q, 1, 0)
+
+    if listed:      # as in dq: every step computes
+        compute()
+    else:
+        pl.when(qj == qj)(compute)
 
     @pl.when(qj == nq - 1)
     def _finish():
@@ -620,16 +796,22 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[blk] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
-                        block_k=512, interpret=False):
+def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=None,
+                        block_k=None, interpret=False):
     """dq/dk/dv via the two pallas kernels; (B, H, T, D) in and out,
     ``v``, ``o``, ``do`` and ``dv`` (B, H, T, Dv).
     A grid step of either is a (q tile, k tile) pair of one head of one
-    batch row, or of ``train_tiles`` rows of ``B * H`` where the whole
-    sequence is one tile; float32 operands in all five products (the
-    stored dtype instead, bf16 into the MXU, read the same step time at
+    batch row, each kernel at its own tiles (``train_blocks``; both at
+    ``block_q`` x ``block_k`` where those are given), or of
+    ``train_tiles`` rows of ``B * H`` where the whole sequence is one
+    tile; float32 operands in all five products (the stored dtype
+    instead, bf16 into the MXU, read the same step time at
     ``(4, 32, 4096, 192 / 128)`` causal: 758.4 against 759.1 ms, PERF.md,
-    PR 44)."""
+    PR 44).  Past one tile a causal grid is the list of its live steps
+    (``_live_steps``), so no step lies above the diagonal.  Every live
+    tile is masked: the forward's split, the mask only where the
+    diagonal crosses, was worth 0.02-0.05 ms of 11 here (PERF.md, PR
+    45)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -640,24 +822,29 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
     kf = k.reshape(bh, tk, d)
     vf = v.reshape(bh, tk, dv)
     dof = do.reshape(bh, tq, dv)
-    block_q = _divisor_block(tq, min(block_q, tq))
-    block_k = _divisor_block(tk, min(block_k, tk))
-    nq, nk = tq // block_q, tk // block_k
+
+    def blocks(kernel):
+        bq, bk = (block_q, block_k) if block_q is not None else \
+            train_blocks(kernel, tq, tk, d, dv, q.dtype.itemsize, causal)
+        return (_divisor_block(tq, min(bq, tq)),
+                _divisor_block(tk, min(bk, tk)))
+
+    (bq_q, bk_q), (bq_kv, bk_kv) = blocks("dq"), blocks("dkv")
+    one_tile = (bq_q, bk_q) == (bq_kv, bk_kv) == (tq, tk)
     hb = 1
-    if nq == nk == 1:
+    if one_tile:
         hb = train_tiles(bh, tq, tk, max(d, dv), q.dtype.itemsize)
-    telemetry.gauge("flash.rows_per_step.dq", hb)
-    telemetry.gauge("flash.rows_per_step.dkv", hb)
     lead = () if hb == 1 else (hb,)
-    # the statistics in the forward's layouts (see its lse notes): a
-    # trailing singleton where a step is one row, along lanes where it
-    # is several
-    if hb == 1:
-        stat_shape, stat_block = (bh, tq, 1), (1, block_q, 1)
-        stat_at = lambda b_, i: (b_, i, 0)
-    else:
-        stat_shape, stat_block = (bh, 1, tq), (hb, 1, block_q)
-        stat_at = lambda b_, i: (b_, 0, i)
+    # every row met key 0 (no ``lengths`` here, and the mask is on
+    # positions), so every lse is finite; a step of several rows keeps
+    # its guard with the rest of its text
+    guard = hb > 1 or tq != tk
+    # the statistics along lanes, (bh, 1, T): a trailing singleton lies
+    # one number a 128-lane tile in HBM, and dkv, whose statistics
+    # follow its innermost index, fetched 512 KiB of padding a step for
+    # 4 KiB of numbers.  The forward writes lse as it did (its relayout
+    # at every q tile's end cost it 6-9%, PR 36); this reshape is XLA's
+    stat_shape = (bh, 1, tq)
     lsef = lse.reshape(stat_shape)
     # delta = rowsum(dO * O): one fused elementwise pass outside the
     # kernels (XLA fuses it into the surrounding graph)
@@ -665,56 +852,75 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=512,
              o.reshape(bh, tq, dv).astype(jnp.float32)).sum(-1).reshape(
                  stat_shape)
 
-    # dq: grid (bh / hb, nq, nk) — K innermost, q/do/lse/delta follow i
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          nk=nk, hb=hb),
-        grid=(bh // hb, nq, nk),
-        in_specs=[
-            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((hb, block_k, dv), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((hb, block_q, dv), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, i)),
-            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, i)),
-        ],
-        out_specs=pl.BlockSpec((hb, block_q, d),
-                               lambda b_, i, j: (b_, i, 0)),
-        out_shape=_pallas_out_shape((bh, tq, d), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((*lead, block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-    # dkv: grid (bh / hb, nk, nq) — Q innermost, k/v follow i
-    dk, dv_out = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          nq=nq, hb=hb),
-        grid=(bh // hb, nk, nq),
-        in_specs=[
-            pl.BlockSpec((hb, block_q, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_k, dv), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_q, dv), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, j)),
-            pl.BlockSpec(stat_block, lambda b_, i, j: stat_at(b_, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((hb, block_k, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((hb, block_k, dv), lambda b_, i, j: (b_, i, 0)),
-        ],
-        out_shape=[
-            _pallas_out_shape((bh, tk, d), k.dtype, q, k, v, do),
-            _pallas_out_shape((bh, tk, dv), v.dtype, q, k, v, do),
-        ],
-        scratch_shapes=[pltpu.VMEM((*lead, block_k, d), jnp.float32),
-                        pltpu.VMEM((*lead, block_k, dv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
+    def call(name, kernel, bq, bk, blocks_in, blocks_out, out_shape,
+             scratch):
+        """One backward kernel at tiles ``bq`` x ``bk`` over its grid
+        ``(bh / hb, outer tiles, inner tiles)``, a step's tile pair its
+        two inner indices (``dq``: q tiles outside; ``dkv``: k tiles);
+        under the causal mask past one tile over ``(bh, live steps)``
+        instead, the pair read from ``_live_steps``' two
+        scalar-prefetched lists (at ``tq == tk``, as every caller has
+        it: every k tile then has a live q tile to be written from).  ``blocks_*``: (block shape, which of a
+        step's two tiles it follows: 0 the outer, 1 the inner, and the
+        axis that tile indexes)."""
+        q_outside = name == "dq"
+        nq_, nk_ = tq // bq, tk // bk
+        steps = _live_steps(nq_, nk_, bq, bk, q_outside) \
+            if causal and tq == tk and nq_ * nk_ > 1 else None
+        _say_grid(name, hb, len(steps[0]) if steps else nq_ * nk_)
+
+        def at(which, axis):
+            # index-map arguments: (b, outer, inner), or listed
+            # (b, step, outer tiles' list, inner tiles' list)
+            def index(b_, *rest):
+                t = rest[1 + which][rest[0]] if steps else rest[which]
+                return (b_, t, 0) if axis == 1 else (b_, 0, t)
+            return index
+
+        in_specs, out_specs = (
+            [pl.BlockSpec(shape, at(which, axis))
+             for shape, which, axis in blocks]
+            for blocks in (blocks_in, blocks_out))
+        if len(out_specs) == 1:
+            out_specs, = out_specs
+        inner = {"nk": nk_} if q_outside else {"nq": nq_}
+        return pl.pallas_call(
+            functools.partial(kernel, block_q=bq, block_k=bk, causal=causal,
+                              scale=scale, hb=hb, guard=guard,
+                              listed=steps is not None, **inner),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2 if steps else 0,
+                grid=(bh, len(steps[0])) if steps else
+                (bh // hb, *((nq_, nk_) if q_outside else (nk_, nq_))),
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel",) * (1 if steps else 2) + ("arbitrary",)),
+            interpret=interpret,
+        )(*(jnp.asarray(x, jnp.int32) for x in steps or ()),
+          qf, kf, vf, dof, lsef, delta)
+
+    # dq: K innermost; q/do/lse/delta and dq follow the q tile
+    dq = call(
+        "dq", _fa_bwd_dq_kernel, bq_q, bk_q,
+        [((hb, bq_q, d), 0, 1), ((hb, bk_q, d), 1, 1),
+         ((hb, bk_q, dv), 1, 1), ((hb, bq_q, dv), 0, 1),
+         ((hb, 1, bq_q), 0, 2), ((hb, 1, bq_q), 0, 2)],
+        [((hb, bq_q, d), 0, 1)],
+        _pallas_out_shape((bh, tq, d), q.dtype, q, k, v, do),
+        [pltpu.VMEM((*lead, bq_q, d), jnp.float32)])
+    # dkv: Q innermost; k/v and dk/dv follow the k tile
+    dk, dv_out = call(
+        "dkv", _fa_bwd_dkv_kernel, bq_kv, bk_kv,
+        [((hb, bq_kv, d), 1, 1), ((hb, bk_kv, d), 0, 1),
+         ((hb, bk_kv, dv), 0, 1), ((hb, bq_kv, dv), 1, 1),
+         ((hb, 1, bq_kv), 1, 2), ((hb, 1, bq_kv), 1, 2)],
+        [((hb, bk_kv, d), 0, 1), ((hb, bk_kv, dv), 0, 1)],
+        [_pallas_out_shape((bh, tk, d), k.dtype, q, k, v, do),
+         _pallas_out_shape((bh, tk, dv), v.dtype, q, k, v, do)],
+        [pltpu.VMEM((*lead, bk_kv, d), jnp.float32),
+         pltpu.VMEM((*lead, bk_kv, dv), jnp.float32)])
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
             dv_out.reshape(b, h, tk, dv))
 
@@ -952,19 +1158,23 @@ def _bwd(causal, scale, res, g):
 flash_attention_raw.defvjp(_fwd, _bwd)
 
 
-def train_form(q_shape, dv=None, itemsize=2):
+def train_form(q_shape, dv=None, itemsize=2, causal=False):
     """Which form ``flash_attention_raw`` and its backward take for ``q``
     (B, H, T, D) (``k`` alike) and values ``dv`` wide, here and now, as one
-    word for a log: ``pallas:<block_q>x<block_k>:d<D>/<Dv>:hb<rows a
-    step>`` or ``chunked``."""
+    word for a log: ``pallas:fwd<block_q>x<block_k>,dq<..>,dkv<..>:d<D>/
+    <Dv>:hb<rows a step>`` or ``chunked``."""
     b, h, t, d = q_shape
     dv = d if dv is None else dv
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
     if not (_pallas_applicable(q, q) and _pallas_bwd_enabled()):
         return "chunked"
-    blk = _divisor_block(t, min(512, t))
-    hb = train_tiles(b * h, t, t, max(d, dv), itemsize) if blk == t else 1
-    return f"pallas:{blk}x{blk}:d{d}/{dv}:hb{hb}"
+    tiles = [train_blocks(kern, t, t, d, dv, itemsize, causal)
+             for kern in ("fwd", "dq", "dkv")]
+    hb = train_tiles(b * h, t, t, max(d, dv), itemsize) \
+        if tiles == [(t, t)] * 3 else 1
+    word = ",".join(f"{kern}{bq}x{bk}" for kern, (bq, bk)
+                    in zip(("fwd", "dq", "dkv"), tiles))
+    return f"pallas:{word}:d{d}/{dv}:hb{hb}"
 
 
 def flash_attention(query, key, value, causal=False, scale=None, **kwargs):

@@ -835,16 +835,22 @@ def test_looped_programs_compile_for_v5e_with_the_stack_held_once(one_chip,
     ((128, 12, 128, 64), "float32"),    # the same without autocast
     ((16, 8, 512, 128), "bfloat16"),    # the longest one-tile sequence
     ((2, 8, 1024, 64), "bfloat16"),     # past one tile: a row a step
-], ids=["bert_s128_bf16", "bert_s128_f32", "t512_hd128", "t1024_hd64"])
+    ((1, 4, 4096, 256), "bfloat16"),    # heads of 256: dq and dkv at 512
+    ((1, 4, 2048, 128), "float32"),     # float32 blocks at tiles of 1,024
+    ((1, 4, 4096, 256), "float32"),     # the forward's second-best tiles
+], ids=["bert_s128_bf16", "bert_s128_f32", "t512_hd128", "t1024_hd64",
+        "t4096_hd256", "t2048_hd128_f32", "t4096_hd256_f32"])
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 def test_training_flash_kernels_compile_for_v5e(one_chip, shape, dtype,
                                                 causal):
     """The trainer's three flash kernels at the rows a grid step that
     ``train_tiles`` gives (16 at BERT-base's shape): the step's working
     set fits the VMEM a kernel gets, or this compile raises as the
-    chip's would; three Mosaic calls; the statistics of a step of
-    several rows lie along lanes, ``f32[bh,1,T]``, not one number a lane
-    tile, and a step of one row keeps the program it had."""
+    chip's would, at the tiles ``train_blocks`` gives past one tile;
+    three Mosaic calls; the backward's statistics lie along lanes,
+    ``f32[bh,1,T]``, not one number a lane tile, and so does the
+    forward's ``lse`` where a step is several rows: a step of one row
+    writes it as a column, as it did."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     from mxnet_tpu.ops import flash_attention as fa
@@ -869,8 +875,8 @@ def test_training_flash_kernels_compile_for_v5e(one_chip, shape, dtype,
         jax.config.update("jax_enable_compilation_cache", cache_was)
         cc.reset_cache()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
-    lanes, column = f"f32[{b * h},1,{t}]", f"f32[{b * h},{t},1]"
-    assert (lanes in text) == (hb > 1) and (column in text) == (hb == 1)
+    lanes, column = f"f32[{b * h},1,{t}]", f"f32[{b * h},1,{t},1]"
+    assert lanes in text and (column in text) == (hb == 1)
 
 
 def _compiled_without_cache(fn, *avals):

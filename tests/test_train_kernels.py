@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mxnet_tpu import telemetry
 from mxnet_tpu.models import moe
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import grouped_ffn
@@ -28,11 +29,16 @@ def _close(a, b, tol):
 # --- flash attention at two head widths ------------------------------------------
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-@pytest.mark.parametrize("t,block", [(256, 128), (128, 128)],
-                         ids=["four_tiles", "one_tile"])
+@pytest.mark.parametrize("t,block", [(256, (128, 128)), (128, (128, 128)),
+                                     (512, (128, 256)), (512, (256, 128))],
+                         ids=["four_tiles", "one_tile", "wide_keys",
+                              "tall_queries"])
 def test_flash_kernels_with_a_narrower_v(causal, t, block):
     """q and k 24 wide, v 16: the Pallas forward (with its log-sum-exp), dq
-    and dkv in interpret mode, and the chunked ``jax.numpy`` fall-backs."""
+    and dkv in interpret mode, at square tiles and at ``block_q`` and
+    ``block_k`` apart both ways (the diagonal then crosses tiles off their
+    corners, and a dead tile's step asks for another block than its own),
+    and the chunked ``jax.numpy`` fall-backs."""
     b, h, d, dv = 2, 3, 24, 16
     q, k = (jnp.asarray(rs.randn(b, h, t, d), jnp.float32) for _ in "qk")
     v = jnp.asarray(rs.randn(b, h, t, dv), jnp.float32)
@@ -41,20 +47,20 @@ def test_flash_kernels_with_a_narrower_v(causal, t, block):
     want, vjp = jax.vjp(lambda q, k, v: fa._sdpa_ref(q, k, v, causal, scale),
                         q, k, v)
     grads = vjp(do)
-    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block, block,
+    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, *block,
                                    with_lse=True, interpret=True)
     assert o.shape == (b, h, t, dv) and lse.shape == (b, h, t)
     _close(o, want, 2e-5)
-    got = fa._fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block,
-                                 block, interpret=True)
+    got = fa._fa_backward_pallas(q, k, v, o, do, lse, causal, scale, *block,
+                                 interpret=True)
     assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
     for g, w in zip(got, grads):
         _close(g, w, 5e-5)
     # the fall-backs a CPU run takes
-    _close(fa._fa_forward_chunked(q, k, v, causal, scale, block=block), want,
-           2e-5)
+    _close(fa._fa_forward_chunked(q, k, v, causal, scale, block=block[1]),
+           want, 2e-5)
     for g, w in zip(fa._fa_backward(q, k, v, want, do, causal, scale,
-                                    block=block), grads):
+                                    block=block[1]), grads):
         _close(g, w, 5e-5)
     # and the entry, differentiated
     out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_raw(
@@ -64,13 +70,80 @@ def test_flash_kernels_with_a_narrower_v(causal, t, block):
         _close(g, w, 5e-5)
 
 
+@pytest.mark.parametrize("t", [1024, 2048, 4096])
+def test_the_rules_tiles_divide_the_sequence_and_fit(t):
+    """``joyai_flash.pretrain_s4k``'s heads (192 / 128, bf16, causal) at its
+    length and the two below it: each kernel's tiles divide the sequence, fit
+    the VMEM a step may ask for, and are the ones the sweep found."""
+    tiles = {kern: fa.train_blocks(kern, t, t, 192, 128, 2, True)
+             for kern in ("fwd", "dq", "dkv")}
+    for kern, (bq, bk) in tiles.items():
+        assert t % bq == 0 and t % bk == 0
+        assert fa.train_row_bytes(bq, bk, 192, 2, kern, 128) \
+            <= fa.TRAIN_VMEM_BYTES
+    # the backward under the mask goes wide only where four tiles of 1,024
+    # span the sequence, and dkv's VMEM count refuses them at these heads
+    assert tiles == {"fwd": (1024, 1024), "dkv": (512, 512),
+                     "dq": (1024, 1024) if t == 4096 else (512, 512)}
+    # heads of 256 at tiles of 1,024: Mosaic refuses dq and dkv, so must
+    # the rule
+    assert fa.train_blocks("dq", 4096, 4096, 256, 256, 2, True) == (512, 512)
+    # no mask, no tile half masked: the backward goes wide at every length
+    assert fa.train_blocks("dkv", t, t, 128, 128, 2, False) == (1024, 1024)
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 128), (128, 256), (256, 128)])
+def test_a_causal_grid_past_one_tile_is_the_list_of_its_live_steps(bq, bk):
+    """No step of the three kernels lies above the causal diagonal: their
+    grid is ``_live_steps``' list, which holds every pair ``_tile_runs``
+    and no other, in the order the kernel sweeps (k tiles inside for the
+    forward and ``dq``, q tiles inside for ``dkv``), and the gauges count
+    it; without the mask the grid is every pair."""
+    t = 512
+    nq, nk = t // bq, t // bk
+
+    def runs(i, j):
+        return bool(fa._tile_runs(i, j, None, block_q=bq, block_k=bk,
+                                  causal=True))
+
+    qs, ks = fa._live_steps(nq, nk, bq, bk)
+    assert list(zip(qs, ks)) == [(i, j) for i in range(nq)
+                                 for j in range(nk) if runs(i, j)]
+    ks, qs = fa._live_steps(nq, nk, bq, bk, q_outside=False)
+    assert list(zip(ks, qs)) == [(i, j) for i in range(nk)
+                                 for j in range(nq) if runs(j, i)]
+    live = len(qs)
+    assert 0 < live < nq * nk
+
+    q, k, v, do = (jnp.asarray(rs.randn(1, 1, t, 8), jnp.float32)
+                   for _ in range(4))
+    for causal, steps in ((True, live), (False, nq * nk)):
+        telemetry.enable()
+        try:
+            o, lse = fa._fa_forward_pallas(q, k, v, causal, 0.5, bq, bk,
+                                           with_lse=True, interpret=True)
+            fa._fa_backward_pallas(q, k, v, o, do, lse, causal, 0.5, bq, bk,
+                                   interpret=True)
+            gauges = telemetry.gauges()
+        finally:
+            telemetry.disable()
+        for kern in ("fwd", "dq", "dkv"):
+            assert gauges[f"flash.grid_steps.{kern}"] == steps
+            assert gauges[f"flash.live_steps.{kern}"] == steps
+            assert gauges[f"flash.rows_per_step.{kern}"] == 1
+
+
 #: sha256 (16 hex digits) of the jaxpr (source locations taken out) of the
 #: differentiated ``flash_attention_raw`` with the Pallas kernels forced, on
 #: the commit before ``v`` got a width of its own (PR 43, 5a061cd): at equal
-#: widths the three kernels are what they were, grid, blocks and bodies.
+#: widths the one-tile kernels are what they were, grid, blocks and bodies.
+#: Past one tile PR 45 changed the program on purpose (tiles from
+#: ``train_blocks``, the causal grid a list of its live steps, no guard on
+#: ``lse``, the backward's statistics along lanes) and the second hash is
+#: that program's.
 PARENT_FLASH = {
     ((128, 12, 128, 64), False): "414ec3460b14916a",    # bert_base.pretrain_s128
-    ((2, 8, 1024, 64), True): "a9493e626e9b3ca3",       # past one tile, causal
+    ((2, 8, 1024, 64), True): "37376d5ea75768da",       # past one tile, causal
 }
 
 
@@ -96,9 +169,10 @@ def test_equal_widths_trace_the_kernels_of_the_parent(monkeypatch, shape,
 def test_train_form_names_the_tiles(monkeypatch):
     assert fa.train_form((4, 32, 4096, 192), 128) == "chunked"   # a CPU
     monkeypatch.setenv("MXT_FORCE_PALLAS_FLASH", "1")
-    assert fa.train_form((4, 32, 4096, 192), 128) \
-        == "pallas:512x512:d192/128:hb1"
-    assert fa.train_form((128, 12, 128, 64)) == "pallas:128x128:d64/64:hb16"
+    assert fa.train_form((4, 32, 4096, 192), 128, causal=True) \
+        == "pallas:fwd1024x1024,dq1024x1024,dkv512x512:d192/128:hb1"
+    assert fa.train_form((128, 12, 128, 64)) \
+        == "pallas:fwd128x128,dq128x128,dkv128x128:d64/64:hb16"
 
 
 # --- the grouped expert feed-forward's backward -------------------------------

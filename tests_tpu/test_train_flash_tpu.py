@@ -14,6 +14,8 @@ import numpy as np
 import pytest
 
 SHAPE = (128, 12, 128, 64)
+#: ``joyai_flash.pretrain_s4k``'s kernels by the rule (PR 45)
+CELL_FORM = "pallas:fwd1024x1024,dq1024x1024,dkv512x512:d192/128:hb1"
 
 
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
@@ -102,16 +104,17 @@ def test_rows_a_step_change_no_result_on_the_chip(monkeypatch):
 
 def test_latent_heads_of_192_and_128_causal_at_4096():
     """``joyai_flash.pretrain_s4k``'s attention: q and k 192 wide (128 + 64
-    rotary), v 128, causal, 8 x 8 tiles of 512, one row a step; a quarter of
-    the cell's heads (the reference's float32 scores are 2 GB at 8).  The
-    output and the three gradients against ``_sdpa_ref`` in float32."""
+    rotary), v 128, causal, each kernel at ``train_blocks``' tiles, one row a
+    step; a quarter of the cell's heads (the reference's float32 scores are
+    2 GB at 8).  The output and the three gradients against ``_sdpa_ref`` in
+    float32."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import flash_attention as fa
 
     b, h, t, d, dv = 1, 8, 4096, 192, 128
-    assert fa.train_form((b, h, t, d), dv) == "pallas:512x512:d192/128:hb1"
+    assert fa.train_form((b, h, t, d), dv, causal=True) == CELL_FORM
     scale = d ** -0.5
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
     q, k, v, g = (jax.random.normal(kk, (b, h, t, w), jnp.float32)
@@ -136,6 +139,60 @@ def test_latent_heads_of_192_and_128_causal_at_4096():
     assert [a.shape[-1] for a in got] == [dv, d, d, dv]
     with jax.default_matmul_precision("highest"):
         want = jax.block_until_ready(jax.jit(ref)(q, k, v, g))
+    for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(a).all(), name
+        rel_rms = float(np.sqrt(np.mean((a - w) ** 2))
+                        / np.sqrt(np.mean(w ** 2)))
+        rel_max = float(np.abs(a - w).max() / np.abs(w).max())
+        assert rel_rms <= 2.0 ** -6 and rel_max <= 2.0 ** -4, \
+            (name, rel_rms, rel_max)
+
+
+def test_the_cells_kernels_at_the_rules_tiles_match_the_chunked_fall_back():
+    """``(4, 32, 4096, 192 / 128)`` causal, the cell's whole attention call:
+    forward, ``dq`` and ``dkv`` at ``train_blocks``' tiles against the
+    chunked ``jax.numpy`` fall-back (float32 inside) that a CPU runs; three
+    Mosaic calls that compile inside the VMEM a kernel gets by default (no
+    ``vmem_limit_bytes`` is set: one that asked for more would be refused
+    here), and the gauges of each grid."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, h, t, d, dv = 4, 32, 4096, 192, 128
+    assert fa.train_form((b, h, t, d), dv, causal=True) == CELL_FORM
+    scale = d ** -0.5
+    keys = jax.random.split(jax.random.PRNGKey(13), 4)
+    q, k, v, g = (jax.random.normal(kk, (b, h, t, w), jnp.float32)
+                  .astype(jnp.bfloat16)
+                  for kk, w in zip(keys, (d, d, dv, dv)))
+
+    def kern(q, k, v, g):
+        o, lse = fa._fa_forward_pallas(q, k, v, True, scale, with_lse=True)
+        return (o,) + fa._fa_backward_pallas(q, k, v, o, g, lse, True, scale)
+
+    def ref(q, k, v, g):
+        o = fa._fa_forward_chunked(q, k, v, True, scale)
+        return (o,) + fa._fa_backward(q, k, v, o, g, True, scale)
+
+    telemetry.enable()
+    try:
+        compiled = jax.jit(kern).lower(q, k, v, g).compile()
+        gauges = telemetry.gauges()
+    finally:
+        telemetry.disable()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "vmem_limit_bytes" not in text
+    # a head's grid is the steps under the diagonal: 10 of 4 x 4 tiles of
+    # 1,024, 36 of 8 x 8 of 512
+    assert [(gauges[f"flash.grid_steps.{n}"], gauges[f"flash.live_steps.{n}"])
+            for n in ("fwd", "dq", "dkv")] == [(10, 10), (10, 10), (36, 36)]
+    got = jax.block_until_ready(compiled(q, k, v, g))
+    want = jax.block_until_ready(jax.jit(ref)(q, k, v, g))
     for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
         a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
         assert np.isfinite(a).all(), name

@@ -9,7 +9,9 @@ and the two-layer prefill program a bucket, kernel against dense path.
 
 ``--train B,H,T,D`` times the trainer's three kernels instead (forward,
 dq, dkv, and the whole custom-vjp with the XLA around it) by the rows a
-grid step takes (``ops.flash_attention.train_tiles``).
+grid step takes (``ops.flash_attention.train_tiles``); with ``--tiles``
+each kernel alone at each of those tiles, the other two at the rule's
+(``ops.flash_attention.train_blocks``), and the rule's own choice.
 
 Each timing is one jitted program of ``CALLS`` chained calls (the output
 feeds the next call's queries, as a decoder's layers do), run ``REPS``
@@ -42,9 +44,14 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="the chosen tiles only, no sweep")
-    ap.add_argument("--tiles", default=",".join(
-        f"{a}:{b}" for a, b in SWEEP),
-        help="block_q:block_k pairs of the sweep at heads of 128")
+    ap.add_argument("--tiles",
+                    help="block_q:block_k pairs of the sweep (default: "
+                         "the served prefill's at heads of 128); with "
+                         "--train, of each training kernel in turn")
+    ap.add_argument("--vmem-mib", type=int,
+                    help="with --train --tiles: the kernels' "
+                         "vmem_limit_bytes raised to this (the rule "
+                         "stays inside the default)")
     ap.add_argument("--kernel-only", action="store_true",
                     help="skip the two-layer prefill programs")
     ap.add_argument("--train", metavar="B,H,T,D[,Dv]",
@@ -59,7 +66,7 @@ def main():
                          "rule's own choice is always timed")
     args = ap.parse_args()
     sweep = [tuple(int(n) for n in t.split(":"))
-             for t in args.tiles.split(",")]
+             for t in args.tiles.split(",")] if args.tiles else SWEEP
 
     import jax
     import jax.numpy as jnp
@@ -108,7 +115,7 @@ def main():
         return 4 * H * hd * (n * (n + 1) // 2)
 
     if args.train:
-        train(args, fa, say, timed)
+        train(args, fa, say, timed, sweep if args.tiles else None)
         return
 
     def kernel(bq, bk, hd):
@@ -191,7 +198,7 @@ def main():
         say(**rec)
 
 
-def train(args, fa, say, timed):
+def train(args, fa, say, timed, tiles=None):
     """Forward, dq and dkv at one (B, H, T, D) in bf16 (``v``, ``o`` and
     ``do`` Dv wide where a fifth number is given), non-causal as BERT
     runs them or ``--causal``: each a program of ``CALLS`` chained calls
@@ -250,6 +257,12 @@ def train(args, fa, say, timed):
             q = nudge(q, out) if nudge else q + out
         return q, k, v
 
+    if tiles:
+        train_by_tiles(fa, say, timed, tiles, args.vmem_mib,
+                       (b, h, t, d, dv), causal,
+                       {"fwd": fwd, "dq": dq, "dkv": dkv},
+                       (q, k, v, o, do, lse))
+        return
     rows = sorted({int(r) for r in args.rows.split(",") if r} | {chosen})
     try:
         for hb in rows:
@@ -275,6 +288,45 @@ def train(args, fa, say, timed):
             say(**rec)
     finally:
         fa.train_tiles = rule
+
+
+def train_by_tiles(fa, say, timed, tiles, vmem_mib, shape, causal, programs,
+                   operands):
+    """Each training kernel alone at each of ``tiles`` and at the rule's
+    own (``train_blocks`` patched for that kernel, as the rows a step are
+    above): ms a layer-call, the table of the rule's docstring.
+    ``vmem_mib`` raises every kernel's ``vmem_limit_bytes``."""
+    import functools
+
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, t, d, dv = shape
+    rule, params = fa.train_blocks, pltpu.CompilerParams
+    if vmem_mib:
+        pltpu.CompilerParams = functools.partial(
+            params, vmem_limit_bytes=vmem_mib << 20)
+    try:
+        for kernel, fn in programs.items():
+            chosen = rule(kernel, t, t, d, dv, 2, causal)
+            for bq, bk in [chosen] + [p for p in tiles if p != chosen]:
+                if t % bq or t % bk:
+                    continue
+                fa.train_blocks = lambda kern, *a, **kw: \
+                    (bq, bk) if kern == kernel else rule(kern, *a, **kw)
+                rec = {"what": "train_tiles", "shape": [b, h, t, d],
+                       "dv": dv, "causal": causal, "kernel": kernel,
+                       "bq": bq, "bk": bk, "chosen": (bq, bk) == chosen,
+                       "vmem_mib": vmem_mib}
+                try:
+                    rec["ms"] = round(timed(
+                        jax.jit(lambda *a, fn=fn: fn(*a)), *operands)
+                        * 1e3, 4)
+                except Exception as e:      # tiles Mosaic refuses
+                    rec["error"] = str(e)[-300:]
+                say(**rec)
+    finally:
+        fa.train_blocks, pltpu.CompilerParams = rule, params
 
 
 if __name__ == "__main__":
