@@ -266,22 +266,29 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
       (row, expert) pairs sorted by expert, each touched expert
       computed on its own rows alone: whole experts streamed once where
       two fit VMEM, else walked in width tiles; where the pairs exceed
-      one window (a long prefill over a bank that holds a part of the
-      router's experts) only the pairs held here are gathered, computed
-      and written, a window at a time, dead rows left out.  Same
-      routing to the bit, dropless at any skew; float32 accumulation,
-      weights and sum over a row's ``k``, so not lower than the other
-      form anywhere, and not equal to it in the last bit.
+      one window (a long prefill, a trainer's step, over a bank that
+      holds a part of the router's experts) only the pairs held here
+      are listed, gathered and computed, a window of as many as 128 MiB
+      of float32 rows hold at a time, dead rows left out, and a
+      window's rows go back into the rows' order inside
+      ``grouped_expert_ffn_rows`` (a tile of tokens in VMEM, its pairs
+      added row by row in float32).  Same routing to the bit, dropless
+      at any skew; float32 accumulation, weights and sum over a row's
+      ``k``, so not lower than the other form anywhere, and not equal
+      to it in the last bit.
 
     Both forms differentiate: ``every_expert`` through XLA, the kernel
-    through its ``jax.custom_vjp`` (``grouped_expert_ffn_dx`` / ``_dw``),
-    so a trainer's step takes the form :func:`expert_product` names; the
-    router learns through the combine weights.
+    through its ``jax.custom_vjp`` (``grouped_expert_ffn_dx`` / ``_dw``,
+    the pairs' dX through ``grouped_expert_ffn_rows``), so a trainer's
+    step takes the form :func:`expert_product` names; the router learns
+    through the combine weights.
 
-    Measured on the v5e (PERF.md, PRs 31 and 33 served; PR 44 trained:
-    39.7 against 75.2 ms a layer forward and backward at 16,384 rows, 16
-    of 256 experts of 768 held; before them rows sorted by expert
-    through ``jax.lax.ragged_dot`` lost at every size, PRs 26 and 30)."""
+    Measured on the v5e (PERF.md, PRs 31 and 33 served; trained, at
+    16,384 rows, 16 of 256 experts of 768 held, ``tools/
+    routed_ffn_bench.py --train``: 39.7 against 75.2 ms a layer forward
+    and backward with its routing, PR 44; 25.9 since PR 46, of which the
+    op alone is 6.2; before them rows sorted by expert through
+    ``jax.lax.ragged_dot`` lost at every size, PRs 26 and 30)."""
     import jax
     import jax.numpy as jnp
 

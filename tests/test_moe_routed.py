@@ -30,13 +30,15 @@ N, H, I, E = 24, 16, 12, 8
 TILE = 16
 
 
-def _interpreted(patch, row_tile=TILE, width_tile=None, window=None):
+def _interpreted(patch, row_tile=TILE, width_tile=None, window=None,
+                 token_tile=None):
     """The kernel under the interpreter (its jits trace once a shape).
     ``width_tile`` None: the whole width; ``window`` None: every pair in
-    one (the shapes here say so)."""
+    one (the shapes here say so); ``token_tile`` None: every row in one."""
     patch.setattr(grouped_ffn, "grouped_expert_ffn", functools.partial(
         grouped_ffn.grouped_expert_ffn, row_tile=row_tile,
-        width_tile=width_tile, window=window, interpret=True))
+        width_tile=width_tile, window=window, token_tile=token_tile,
+        interpret=True))
 
 
 @pytest.fixture(params=["every_expert", "grouped_kernel"])
@@ -238,8 +240,10 @@ PART = (6, 2)
                                   "every_row_on_one_held_expert",
                                   "dead_tail"])
 @pytest.mark.parametrize("width_tiles", [1, 2])
+@pytest.mark.parametrize("token_tile", [None, 8],
+                         ids=["one_token_tile", "token_tiles_of_8"])
 def test_the_held_pairs_in_windows_over_a_part_of_the_router(
-        monkeypatch, case, width_tiles):
+        monkeypatch, case, width_tiles, token_tile):
     """24 rows x 4 of 32 experts, 2 of them held: the kernel gathers,
     visits and writes the held prefix of the sorted list alone, 16 sorted
     pairs (two row tiles) a window, as many windows as the held pairs
@@ -247,7 +251,11 @@ def test_the_held_pairs_in_windows_over_a_part_of_the_router(
     no row here (zeros), every one where all rows choose one held expert
     (k = 1: every pair held, the worst case, nothing dropped); and the
     padded end of a bucket (``live`` false) is left out like another
-    chip's pairs: zeros there, the counts the live rows' alone."""
+    chip's pairs: zeros there, the counts the live rows' alone.  The
+    windows' rows go back into the rows' order through
+    ``grouped_expert_ffn_rows``, the 24 rows in one token tile or in
+    three of 8 (the dead tail's last tile has no pair and is never
+    opened)."""
     monkeypatch.setattr(moe, "expert_product", lambda *a: "grouped_kernel")
     t = _bank(8, 32)
     first, count = PART
@@ -264,7 +272,7 @@ def test_the_held_pairs_in_windows_over_a_part_of_the_router(
     if case == "dead_tail":
         live = np.arange(N) < 15
     window = 8 if k == 1 else 16
-    _interpreted(monkeypatch, 8, I // width_tiles, window)
+    _interpreted(monkeypatch, 8, I // width_tiles, window, token_tile)
     got, counts = _run(t, k, held=PART, live=live)
     want, wc = _oracle(t, k, "sigmoid", True, True, 1.0, held=PART)
     owned = np.ones(N, bool) if live is None else live
@@ -288,6 +296,35 @@ def test_the_held_pairs_in_windows_over_a_part_of_the_router(
     _interpreted(monkeypatch, 8, I // width_tiles)
     whole, _ = _run(t, k, held=PART, live=live)
     assert np.abs(got - whole).max() < 1e-6
+
+
+def test_a_walked_experts_windows_go_through_the_combine():
+    """Rows of 384 (three lane tiles), experts of 256 walked in two width
+    tiles of 128, 40 rows x 2 of 3 held experts in windows of 16 pairs and
+    token tiles of 16: the float32 sum over the width tiles leaves the
+    forward kernel a pair's row, and ``grouped_expert_ffn_rows`` adds it
+    to its token's."""
+    rs = np.random.RandomState(11)
+    n, h, i, held, k = 40, 384, 256, 3, 2
+    x = (rs.randn(n, h) * 0.3).astype(np.float32)
+    bank = [(rs.randn(*s) * 0.1).astype(np.float32)
+            for s in ((held, h, i), (held, h, i), (held, i, h))]
+    idx = np.argsort(rs.rand(n, held + 2), axis=1)[:, :k].astype(np.int32)
+    w = rs.rand(n, k).astype(np.float32)
+    got = np.asarray(grouped_ffn.grouped_expert_ffn(
+        jnp.asarray(x), jnp.asarray(idx), jnp.asarray(w),
+        *(jnp.asarray(b) for b in bank), row_tile=8, width_tile=128,
+        window=16, token_tile=16, interpret=True))
+    want = np.zeros((n, h), np.float32)
+    pairs = 0
+    for r in range(n):
+        for e, we in zip(idx[r], w[r]):
+            if e < held:
+                g, u = x[r] @ bank[0][e], x[r] @ bank[1][e]
+                want[r] += we * ((g / (1.0 + np.exp(-g)) * u) @ bank[2][e])
+                pairs += 1
+    assert pairs > 2 * 16 and pairs % 16
+    assert np.abs(got - want).max() < 1e-4
 
 
 def test_the_walk_over_a_window_of_the_sorted_list():
@@ -366,7 +403,10 @@ def test_the_rule_on_shapes_alone(platform, mesh, rows, k, held, hidden,
         # of 512 rows are one window, a long prefill's pairs are many
         win = grouped_ffn.window_pairs(rows, k, hidden, tm)
         assert win % tm == 0
-        assert win == (-(-rows * k // tm) * tm if rows < 8192 else 1792)
+        # (as many as 128 MiB of float32 rows hold)
+        assert win == (-(-rows * k // tm) * tm if rows < 8192
+                       else 2 ** 27 // (4 * hidden) // tm * tm)
+        assert (win < rows * k) is (rows >= 8192)
 
 
 def test_here_the_rule_keeps_every_expert_on_every_row():

@@ -898,9 +898,11 @@ def test_training_kernels_of_the_latent_expert_cell_compile_for_v5e(one_chip):
     and v 128, causal, 4 x 32 heads x 4,096 (Mosaic takes the one and a half
     lane tiles as they are: nothing is padded in HBM), and the grouped expert
     feed-forward's backward at 16,384 rows, 8 of 256 experts a row, 16 of 768
-    held: one ``grouped_expert_ffn_dx`` and three ``grouped_expert_ffn_dw``
-    under the windows' loop, no every-expert intermediate and nothing of
-    ``rows x k`` rows."""
+    held: one ``grouped_expert_ffn_dx``, three ``grouped_expert_ffn_dw``
+    and one ``grouped_expert_ffn_rows`` (the pairs' dX added into the rows'
+    order, token tiles of 1,024) under the windows' loop, no every-expert
+    intermediate, nothing of ``rows x k`` rows and no scatter of rows of
+    the hidden size."""
     from mxnet_tpu.ops import flash_attention as fa
     from mxnet_tpu.ops import grouped_ffn
 
@@ -935,6 +937,10 @@ def test_training_kernels_of_the_latent_expert_cell_compile_for_v5e(one_chip):
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert sum("grouped_expert_ffn_dx" in n for n in names) == 1
     assert sum("grouped_expert_ffn_dw" in n for n in names) == 3
+    assert sum("grouped_expert_ffn_rows" in n for n in names) == 1
+    assert len(names) == 5
+    assert not [ln for ln in text.splitlines()
+                if " scatter(" in ln and f",{h}]" in ln.split("=")[1][:40]]
     assert f"[{rows},{held},{i}]" not in text
     assert f"[{rows * k}," not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
@@ -954,7 +960,9 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
     it came in and nothing of ``(rows, experts, width)``, the other form's
     intermediate, nor, where the pairs exceed a window, anything of
     ``rows x k`` rows (262,144 x 6,144 would be 3.2 GB): the held pairs go
-    a window at a time under a loop."""
+    a window at a time under a loop, and ``grouped_expert_ffn_rows`` adds a
+    window's rows of 6,144 into the rows' order (row tiles of 256, token
+    tiles of 512)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     from mxnet_tpu.ops import grouped_ffn
@@ -979,7 +987,12 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
         jax.config.update("jax_enable_compilation_cache", cache_was)
         cc.reset_cache()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    tm, _ = grouped_ffn.tiles(hidden, width)
+    windows = grouped_ffn.window_pairs(rows, k, hidden, tm) < rows * k
+    names = [ln.split("=")[0].strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(names) == (2 if windows else 1)
+    assert sum("grouped_expert_ffn_rows" in n for n in names) == windows
     assert "grouped_expert_ffn" in text
     assert f"[{rows},{held},{width}]" not in text
     bank = (f"[{held},{hidden},{width}]", f"[{held},{width},{hidden}]")
@@ -987,8 +1000,10 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
                 if any(b in ln.split("=")[0] for b in bank)
                 and (" copy(" in ln or " convert(" in ln
                      or " transpose(" in ln)]
-    tm, _ = grouped_ffn.tiles(hidden, width)
-    if rows * k > grouped_ffn.window_pairs(rows, k, hidden, tm):
+    if windows:
+        assert (tm, grouped_ffn.token_rows(rows, hidden)) == (256, 512)
+        win = grouped_ffn.window_pairs(rows, k, hidden, tm)
+        assert f"f32[{win},{hidden}]" in text       # a window's rows
         assert f"[{rows * k}," not in text and f"[{rows * k}]" not in text
         assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
     else:

@@ -206,19 +206,42 @@ def _every_expert(x, idx, w, bank, live):
     return jnp.einsum("nei,eih->nh", act, bank[2])
 
 
-@pytest.mark.parametrize("n,window", [(300, None), (300, 256), (129, 128)],
-                         ids=["one_window_forward", "windows_of_256",
-                              "rows_off_the_tile"])
-def test_grouped_backward_matches_every_expert(n, window):
+def _sorted_pairs(idx, live, held):
+    """The kernels' listing, in numpy: (expert, row) of every held pair,
+    expert by expert and row by row."""
+    there = np.asarray((idx >= 0) & (idx < held))
+    if live is not None:
+        there = there & np.asarray(live)[:, None]
+    rows, cols = np.nonzero(there)
+    experts = np.asarray(idx)[rows, cols]
+    order = np.lexsort((rows, experts))
+    return experts[order], rows[order]
+
+
+@pytest.mark.parametrize("n,window,token_tile,dead", [
+    (300, None, None, None), (300, 256, None, None), (129, 128, None, None),
+    (300, 256, 64, None), (300, 256, 64, (64, 128)), (129, 128, 64, None),
+    (300, 128, 128, (0, 128))],
+    ids=["one_window_forward", "windows_of_256", "rows_off_the_tile",
+         "token_tiles_of_64", "a_token_tile_without_a_pair",
+         "rows_off_the_token_tile", "the_first_token_tile_dead"])
+def test_grouped_backward_matches_every_expert(n, window, token_tile, dead):
     """Skewed routing, an expert with no row, rows not a multiple of the
     tile, a held part (6 of 16 from the 4th) of the router, rows no request
-    owns: dX, the three banks' gradients and the combine weights'."""
+    owns: dX, the three banks' gradients and the combine weights'.  With
+    token tiles: a row held by several experts inside one window and across
+    two, an expert's group cut by a window's edge, the last window's unused
+    rest, a token tile no pair falls in (``dead`` rows no request owns), a
+    last token tile that is part rows."""
     x, idx, w, bank, live = _case(n, 128, 128, 16, 3, 6, 4)
+    if dead:
+        live = live & ~((jnp.arange(n) >= dead[0]) & (jnp.arange(n) < dead[1]))
     dy = jnp.asarray(rs.randn(n, 128), jnp.float32)
 
     def ker(x, w, *bank):
         return grouped_ffn.grouped_expert_ffn(
-            x, idx, w, *bank, live, window=window, interpret=True)
+            x, idx, w, *bank, live, window=window, token_tile=token_tile,
+            interpret=True)
 
     def ref(x, w, *bank):
         return _every_expert(x, idx, w, bank, live)
@@ -237,6 +260,82 @@ def test_grouped_backward_matches_every_expert(n, window):
     # a pair on another chip's expert, or of a row nobody owns, has no say
     there = np.asarray((idx >= 0) & (idx < 6) & live[:, None])
     assert not np.asarray(got[1])[~there].any()
+    if token_tile and window:
+        # what the case is there to meet, said of its own listing
+        experts, rows = _sorted_pairs(idx, live, 6)
+        wins = np.arange(len(rows)) // window
+        assert len(rows) > window and len(rows) % window
+        twice = [wins[rows == r] for r in np.unique(rows)
+                 if (rows == r).sum() > 1]
+        assert any(len(set(t)) < len(t) for t in twice)   # inside one window
+        assert any(len(set(t)) > 1 for t in twice)        # across two
+        assert any(len(set(wins[experts == e])) > 1 for e in range(6))
+        tiles = set(range(-(-n // token_tile)))
+        assert (tiles - set(rows // token_tile) != set()) == bool(dead)
+        assert n % token_tile
+
+
+def test_the_one_window_call_is_the_parents_program():
+    """The served steps' form (every pair in one window: the two sparse
+    chat cells' 512 rows a call) is not this PR's: its jaxpr, the kernel's
+    with it, is the one of the commit before (sha256, 16 hex digits, at
+    ``sdar_30b``'s shapes)."""
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    rows, k, held, h, i = 512, 8, 128, 2048, 768
+    with jax.enable_x64(False):
+        text = str(jax.make_jaxpr(grouped_ffn.grouped_expert_ffn)(
+            sds((rows, h), bf), sds((rows, k), jnp.int32),
+            sds((rows, k), jnp.float32), sds((held, h, i), bf),
+            sds((held, h, i), bf), sds((held, i, h), bf),
+            sds((rows,), jnp.bool_)))
+    text = re.sub(r" at [^\s\]]+:\d+", "", text)
+    assert "grouped_expert_ffn_rows" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "54e7a95df6f2f3f2"
+
+
+@pytest.mark.parametrize("rows,k,held,h,i,want", [
+    (16384, 8, 16, 2048, 768,
+     dict(fwd=16384, form="kernel", token_tile=1024, row_tile=128)),
+    (32768, 8, 16, 6144, 2048,
+     dict(fwd=5376, form="kernel", token_tile=512, row_tile=None)),
+    (512, 8, 128, 2048, 768,
+     dict(fwd=4096, form="xla", token_tile=512, row_tile=128)),
+], ids=["joyai_flash_trained", "glm5_long_prefill", "sdar_block_pass"])
+def test_the_gauges_say_what_the_shapes_chose(rows, k, held, h, i, want):
+    """Where the program is traced: the forward's and the backward's
+    window, whether the rows cross in ``grouped_expert_ffn_rows`` or in
+    XLA, and the token tile, at the three sparse cells' shapes."""
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    avals = (sds((rows, h), bf), sds((rows, k), jnp.int32),
+             sds((rows, k), jnp.float32), sds((held, h, i), bf),
+             sds((held, h, i), bf), sds((held, i, h), bf))
+
+    def loss(x, idx, w, *bank):
+        return grouped_ffn.grouped_expert_ffn(x, idx, w, *bank) \
+            .astype(jnp.float32).sum()
+
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        for name in ("fwd.window_pairs", "rows_form", "token_tile",
+                     "bwd.row_tile", "bwd.window_pairs"):
+            telemetry._gauges.pop("grouped_ffn." + name, None)
+        fn = jax.grad(loss, argnums=(0, 2)) if want["row_tile"] else loss
+        jax.make_jaxpr(fn)(*avals)
+        got = telemetry.gauges()
+    finally:
+        if not was:
+            telemetry.disable()
+    assert got["grouped_ffn.fwd.window_pairs"] == want["fwd"]
+    assert got["grouped_ffn.rows_form"] == want["form"]
+    # (a one-window forward sets none; its backward lists the held pairs)
+    assert got.get("grouped_ffn.token_tile") == want["token_tile"]
+    if want["row_tile"]:
+        assert got["grouped_ffn.bwd.row_tile"] == want["row_tile"]
+        assert got["grouped_ffn.bwd.window_pairs"] == want["fwd"]
 
 
 def test_grouped_backward_in_bfloat16_accumulates_in_float32():

@@ -6,7 +6,11 @@ sigmoid scores and a choice bias; 512 rows a call (SDAR's block pass, both
 models' longest chat prefill bucket) and 397 (its pairs fill no whole row tile);
 and one chip's 16 of GLM-5's 256 experts of 6,144 x 2,048, 8 a row, at 8,192
 rows: an expert walked in width tiles, the held pairs in windows, the padded
-end of the bucket left out; beside it the lowered text of the 32,768 bucket.
+end of the bucket left out; beside it the lowered text of the 32,768 bucket,
+and that bucket run: three windows of 5,376 pairs whose rows of 6,144 go back
+into the rows' order through ``grouped_expert_ffn_rows``.  The trained cell's
+layer (16,384 rows, 16 of 256 experts of 768): the forward and all five
+gradients of the op against XLA's of the every-expert form.
 
 Tolerance: the two forms route to the bit and differ in where they round (the
 one form rounds gate and up to bf16 and applies the weight in bf16; the kernel
@@ -83,7 +87,7 @@ def test_a_wide_bank_that_holds_a_part_of_the_router_at_a_long_prefill(
         parity_record, monkeypatch):
     """GLM-5's routed layer as ``glm5.longdoc_prefill`` runs it, 8,192 rows
     of which the last 2,192 belong to no request: the kernel (width tiles
-    of 512, 256 rows a visit, windows of 1,792 held pairs) against every
+    of 512, 256 rows a visit, windows of 5,376 held pairs) against every
     held expert on every row in chunks of 2,048, on the rows a request
     owns; the others zero; and the counts alike."""
     import jax
@@ -94,7 +98,7 @@ def test_a_wide_bank_that_holds_a_part_of_the_router_at_a_long_prefill(
 
     rows, e, held, k, h, i = 8192, 256, 16, 8, 6144, 2048
     assert grouped_ffn.tiles(h, i) == (256, 512)
-    assert grouped_ffn.window_pairs(rows, k, h, 256) == 1792
+    assert grouped_ffn.window_pairs(rows, k, h, 256) == 5376
     assert moe.expert_product(rows, k, held, h, i, jnp.bfloat16) \
         == "grouped_kernel"
     keys = jax.random.split(jax.random.PRNGKey(11), 6)
@@ -126,6 +130,47 @@ def test_a_wide_bank_that_holds_a_part_of_the_router_at_a_long_prefill(
     assert err < 4 * EPS, err
 
 
+def test_the_longest_buckets_windows_go_back_through_the_rows_kernel(
+        parity_record, monkeypatch):
+    """32,768 rows, the last quarter nobody's: some 12,288 held pairs in
+    three windows of 5,376, token tiles of 512 rows of 6,144."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import moe
+    from mxnet_tpu.ops import grouped_ffn
+
+    rows, e, held, k, h, i = 32768, 256, 16, 8, 6144, 2048
+    assert grouped_ffn.window_pairs(rows, k, h, 256) == 5376
+    assert grouped_ffn.token_rows(rows, h) == 512
+    keys = jax.random.split(jax.random.PRNGKey(13), 5)
+    bf = jnp.bfloat16
+    x = jax.random.normal(keys[0], (rows, h), bf)
+    rw = jax.random.normal(keys[1], (e, h), bf) * 0.02
+    wg = jax.random.normal(keys[2], (held, h, i), bf) * 0.02
+    wu = jax.random.normal(keys[3], (held, h, i), bf) * 0.02
+    wd = jax.random.normal(keys[4], (held, i, h), bf) * 0.02
+    live = jnp.arange(rows) < 24576
+
+    def run():
+        return jax.jit(lambda x, rw, wg, wu, wd: moe.routed_ffn(
+            x, rw, wg, wu, wd, k, score="sigmoid", scale=2.5,
+            experts_held=(0, held), live=live))(x, rw, wg, wu, wd)
+
+    y, counts = run()
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "expert_product", lambda *a: "every_expert")
+        yw, cw = run()
+    assert (np.asarray(counts) == np.asarray(cw)).all()
+    assert 2 * 5376 < int(counts[:held].sum()) <= 3 * 5376
+    y, yw = np.asarray(y, np.float32), np.asarray(yw, np.float32)
+    assert np.isfinite(y).all() and not y[24576:].any()
+    err = float(np.abs(y[:24576] - yw[:24576]).max()
+                / np.abs(yw[:24576]).max())
+    parity_record("grouped_expert_ffn", "glm5_32768_16_of_256", err)
+    assert err < 4 * EPS, err
+
+
 def test_no_array_of_every_pair_at_the_longest_bucket():
     """32,768 rows x 8 are 262,144 pairs, of which this chip holds a
     sixteenth on average: the lowered program has one kernel under a loop
@@ -145,7 +190,8 @@ def test_no_array_of_every_pair_at_the_longest_bucket():
         sds((held, h, i), bf), sds((held, i, h), bf),
         sds((rows,), jnp.bool_)).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "grouped_expert_ffn_rows" in text
     assert " while(" in text
     assert f"[{rows * k}," not in text and f"[{rows * k}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
@@ -191,3 +237,68 @@ def test_grouped_backward_matches_xla_at_the_training_cells_shapes():
         rel_rms = float(np.sqrt(np.mean((a - w) ** 2))
                         / np.sqrt(np.mean(w ** 2)))
         assert rel_rms <= 2.0 ** -5, (name, rel_rms)
+
+
+def test_the_op_and_its_five_gradients_at_the_training_cells_shapes(
+        parity_record):
+    """The op alone at ``joyai_flash.pretrain_s4k``'s layer, one window of
+    16,384 pairs and token tiles of 1,024: its result, dX, the combine
+    weights' gradient (N, k) itself, and the three banks', against XLA's of
+    every held expert on every row in chunks of 2,048 under the combine
+    matrix the same ids and weights make."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import moe
+    from mxnet_tpu.ops import grouped_ffn
+
+    rows, e, held, k, i = 16384, 256, 16, 8, 768
+    assert grouped_ffn.window_pairs(rows, k, H, 128) == 16384
+    assert grouped_ffn.token_rows(rows, H) == 1024
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    bf = jnp.bfloat16
+    x = jax.random.normal(keys[0], (rows, H), bf)
+    rw = jax.random.normal(keys[1], (e, H), bf) * 0.02
+    bank = tuple(jax.random.normal(kk, s, bf) * 0.02 for kk, s in zip(
+        keys[2:5], ((held, H, i), (held, H, i), (held, i, H))))
+    dy = jax.random.normal(keys[5], (rows, H), jnp.float32)
+    idx, w = jax.jit(lambda x, rw: moe.route(x, rw, k, "sigmoid",
+                                             scale=2.5))(x, rw)
+
+    def kernel(x, w, *bank):
+        return grouped_ffn.grouped_expert_ffn(x, idx, w, *bank)
+
+    def every(x, w, wg, wu, wd):
+        comb = jnp.where(idx[:, :, None] == jnp.arange(held),
+                         w[:, :, None], 0.0).sum(1)
+
+        def chunk(c):
+            xc, cc = c
+            g = jnp.einsum("nh,ehi->nei", xc, wg)
+            u = jnp.einsum("nh,ehi->nei", xc, wu)
+            act = g * jax.nn.sigmoid(g) * u * cc.astype(xc.dtype)[:, :, None]
+            return jnp.einsum("nei,eih->nh", act, wd)
+        return jax.lax.map(chunk, (x.reshape(-1, 2048, H),
+                                   comb.reshape(-1, 2048, held))) \
+            .reshape(rows, H)
+
+    def both(fn):
+        def loss(*a):
+            y = fn(*a)
+            return (y.astype(jnp.float32) * dy).sum(), y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=range(5), has_aux=True))(x, w, *bank)
+        return jax.block_until_ready((y,) + grads)
+
+    got, want = both(kernel), both(every)
+    for name, a, b in zip(("y", "dx", "dweights", "dgate", "dup", "ddown"),
+                          got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all(), name
+        rel_rms = float(np.sqrt(np.mean((a - b) ** 2))
+                        / np.sqrt(np.mean(b ** 2)))
+        parity_record("grouped_expert_ffn", f"joyai_16384_{name}", rel_rms)
+        assert rel_rms <= 2.0 ** -5, (name, rel_rms)
+    # a choice no held expert computes has no say in the weights' gradient
+    away = np.asarray(idx) >= held
+    assert not np.asarray(got[2])[away].any()

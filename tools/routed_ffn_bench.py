@@ -15,13 +15,24 @@ the next one's input, so nothing is hoisted), the median of 7.
         --held 16 --k 8 --hidden 6144 --width 2048 --score sigmoid \
         --rows 8192,16384,32768 --tiles 128,512x256,256@5376  # GLM-5, 1 of 16
 
+    chiprun -- python tools/routed_ffn_bench.py --train --parts \
+        --router-experts 256 --held 16 --k 8 --hidden 2048 --width 768 \
+        --score sigmoid --rows 16384          # JoyAI-LLM-Flash trained, 1 of 16
+
 Prints one JSON line a size: milliseconds a call of each form, the
 whole layer with its routing, the kernel at each ``--tiles`` entry (a row
 tile, ``rows x width tile``, or either ``@`` a window of sorted pairs), which form ``moe.expert_product`` picks there
 with the tiles and the window the shapes give, and what the one form's
 operations (every held expert on every row) and the bank's bytes come to.
+With ``--parts`` a second line a size: the milliseconds of each piece of ONE
+window of the windowed form, jitted alone at the window's shapes (the listing,
+the rows in, each kernel, the combine ``grouped_expert_ffn_rows`` beside XLA's
+scatter-add of the same rows, the combine weights' gradient), the router's
+pieces around the layer, and the whole call forward and forward + backward;
+``dispatch_floor_ms`` is what an empty program reads on this host, under which
+a piece cannot be told from nothing.
 The tables that set ``ops.grouped_ffn.GROUPED_MIN_ROWS``, ``ROW_TILE`` (PERF.md,
-PR 31), ``ROW_TILE_WALKED`` and ``WINDOW_PAIRS`` (PR 33) are this tool's kind
+PR 31), ``ROW_TILE_WALKED`` (PR 33) and ``_ONE_WINDOW_BYTES`` (PR 46) are this tool's kind
 of output.
 """
 import argparse
@@ -59,6 +70,9 @@ def main():
                     help="time forward AND backward of each form (the "
                          "gradient in the rows, the router and the bank), "
                          "one call a program, not the forward chained")
+    ap.add_argument("--parts", action="store_true",
+                    help="also time each piece of one window of the "
+                         "windowed form alone, and the router's pieces")
     args = ap.parse_args()
     e, k, h, i = args.experts, args.k, args.hidden, args.width
     held = args.held or e
@@ -155,6 +169,94 @@ def main():
         row["forms_differ_rel"] = float(jnp.abs(a - b).max()
                                         / jnp.abs(a).max())
         print(json.dumps(row), flush=True)
+        if args.parts:
+            print(json.dumps(parts(args, x, bank, held)), flush=True)
+
+
+def parts(args, x, bank, held):
+    """One window's pieces alone (see the module text)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import moe
+    from mxnet_tpu.ops import grouped_ffn as g
+
+    rw, wg, wu, wd = bank
+    n, h = x.shape
+    k = args.k
+    tm, wt = g.tiles(h, wg.shape[2])
+    win = g.window_pairs(n, k, h, tm)
+    tt, c = g.token_rows(n, h), min(g._CHUNK, tm)
+
+    def ms(fn, *a, donate=None):
+        fn = jax.jit(fn, donate_argnums=() if donate is None else donate)
+        out = jax.block_until_ready(fn(*a))
+        times = []
+        for _ in range(7):
+            t = time.perf_counter()
+            for _ in range(4):
+                if donate is not None:
+                    a = a[:donate] + (out,) + a[donate + 1:]
+                out = fn(*a)
+            jax.block_until_ready(out)
+            times.append((time.perf_counter() - t) / 4 * 1e3)
+        return round(statistics.median(times), 4), out
+
+    row = {"parts_of": "one window", "device": jax.devices()[0].device_kind,
+           "rows": n, "window_pairs": win,
+           "row_tile": tm, "token_tile": tt, "chunk": c}
+    row["dispatch_floor_ms"], _ = ms(lambda a: a + 1, jnp.zeros((8,)))
+    # the router around the layer, as ``routed_ffn`` runs it
+    row["route_ms"], (idx, w) = ms(
+        lambda x, rw: moe.route(x, rw, k, args.score), x, rw)
+    row["route_fwd_bwd_ms"], _ = ms(jax.grad(
+        lambda x, rw: moe.route(x, rw, k, args.score)[1].sum(),
+        argnums=(0, 1)), x, rw)
+    e = rw.shape[0]
+    row["expert_counts_ms"], _ = ms(
+        lambda idx: jnp.zeros((e,), jnp.int32).at[idx].add(1), idx)
+
+    def listing(idx):
+        pairs, window, runs = g._held_pairs(idx, held, None, tt)
+        return (pairs,) + window(0, win) + runs(0, win, c)
+
+    row["listing_ms"], (pairs, rows, key_w, slot, *walk) = ms(listing, idx)
+    row["pairs_held"] = int(pairs)
+    row["weights_in_ms"], ws = ms(
+        lambda w, rows, slot: jnp.where(slot, w[rows], 0.0).sum(1),
+        w.astype(jnp.float32), rows, slot)
+    row["rows_in_ms"], xw = ms(lambda x, rows: x[rows], x, rows)
+    row["fwd_kernel_with_rows_in_ms"], out = ms(
+        lambda x, rows, key, ws, *b: g._window(x, rows, key, ws, b, tm, wt,
+                                               False),
+        x, rows, key_w, ws, wg, wu, wd)
+    total = jnp.zeros((n, h), jnp.float32)
+    row["combine_kernel_ms"], total = ms(
+        lambda out, rows, total, *walk: g._add_rows(
+            total, out, rows, walk, tt, c, False),
+        out, rows, total, *walk, donate=2)
+    row["combine_xla_scatter_add_ms"], total = ms(
+        lambda out, rows, total, key: total.at[rows].add(
+            jnp.where((key < held)[:, None], out, 0.0)),
+        out, rows, total, key_w, donate=2)
+    del total
+    if wt == wg.shape[2]:
+        accs = tuple(jnp.zeros(a.shape, jnp.float32) for a in (wg, wu, wd))
+        row["bwd_kernels_ms"], _ = ms(
+            lambda xw, dyw, key, ws, accs, *b: g._window_bwd(
+                xw, dyw, key, ws, b, accs, tm, False)[2],
+            xw, xw, key_w, ws, accs, wg, wu, wd, donate=4)
+        row["weights_grad_scatter_add_ms"], _ = ms(
+            lambda rows, slot, d: jnp.zeros((n, k), jnp.float32).at[rows].add(
+                jnp.where(slot, d[:, None], 0.0)), rows, slot, ws)
+
+    whole = g.grouped_expert_ffn
+    row["whole_fwd_ms"], _ = ms(whole, x, idx, w, wg, wu, wd)
+    if wt == wg.shape[2]:
+        row["whole_fwd_bwd_ms"], _ = ms(jax.grad(
+            lambda x, w, *b: whole(x, idx, w, *b).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4)), x, w, wg, wu, wd)
+    return row
 
 
 if __name__ == "__main__":
