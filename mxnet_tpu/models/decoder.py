@@ -40,7 +40,8 @@ from .moe import expert_product
 __all__ = ["CacheSpec", "BlockDecoding", "PagedDecoder", "Causal",
            "SelectingCausal", "BehindPrefix", "DenseCache", "StepView", "rms_norm",
            "split_heads", "rope_tables", "apply_rope",
-           "headnorm_attention", "block_commit"]
+           "headnorm_attention", "block_commit", "causal_conv", "ring_conv",
+           "ring_at_length"]
 
 
 class BlockDecoding(NamedTuple):
@@ -245,6 +246,64 @@ def headnorm_attention(p, u, rope, view, num_heads, num_kv_heads, eps):
     q, k = apply_rope(q, *rope), apply_rope(k, *rope)
     ctx, kept = view.attend(q, k, v)
     return ctx.reshape(*u.shape[:-1], -1) @ p["o"].T, kept
+
+
+def causal_conv(w, mixed, bias=None):
+    """A causal depthwise convolution over whole sequences and its
+    SiLU: ``w`` (taps, C), tap ``j`` multiplying the input at ``t -
+    (taps - 1) + j``, zeros before the start; ``mixed`` (B, T, C);
+    ``bias`` (C,) or None."""
+    import jax
+    import jax.numpy as jnp
+
+    taps, t = w.shape[0], mixed.shape[1]
+    xp = jnp.pad(mixed, ((0, 0), (taps - 1, 0), (0, 0)))
+    conv = sum(w[j] * xp[:, j:j + t] for j in range(taps))
+    if bias is not None:
+        conv = conv + bias
+    return conv * jax.nn.sigmoid(conv)
+
+
+def ring_conv(w, mixed, ring, pos, live, bias=None):
+    """:func:`causal_conv`'s one token a slot against the ring of its
+    last ``taps - 1`` inputs: ``mixed`` (S, C) the input at ``pos``
+    (S,), ``ring`` (S, taps - 1, C) with row ``t % (taps - 1)`` holding
+    position ``t``'s -> (the convolution and its SiLU, the ring with
+    ``mixed`` in the oldest row's place).  The oldest row
+    makes way, which a second step at this position would miss: only a
+    slot the step owns (``live``) writes."""
+    import jax
+    import jax.numpy as jnp
+
+    taps = w.shape[0]
+    rows = jnp.arange(ring.shape[0])
+    # tap j multiplies the input at pos - (taps - 1) + j
+    conv = w[taps - 1] * mixed + sum(
+        w[j] * ring[rows, (pos - (taps - 1) + j) % (taps - 1)]
+        for j in range(taps - 1))
+    if bias is not None:
+        conv = conv + bias
+    conv = conv * jax.nn.sigmoid(conv)
+    at = pos % (taps - 1)
+    ring = ring.at[rows, at].set(
+        jnp.where(live[:, None], mixed, ring[rows, at]))
+    return conv, ring
+
+
+def ring_at_length(mixed, t0, kk):
+    """The ring :func:`ring_conv` reads next, from a whole sequence's
+    convolution input ``mixed`` (B, T, C) and the TRUE lengths ``t0``:
+    the input at ``t0 - kk .. t0 - 1`` laid out as the ring keeps it
+    (row ``t % kk``), zeros where the prompt is shorter, never the
+    padded end's."""
+    import jax.numpy as jnp
+
+    t0 = jnp.broadcast_to(t0, (mixed.shape[0],))
+    # ring row r holds the one position p in [t0-kk, t0) with p % kk == r
+    src = t0[:, None] - 1 - (t0[:, None] - 1 - jnp.arange(kk)[None]) % kk
+    take = jnp.clip(src, 0, mixed.shape[1] - 1)[:, :, None]
+    return jnp.where((src >= 0)[:, :, None],
+                     jnp.take_along_axis(mixed, take, axis=1), 0)
 
 
 def block_commit(logits, ids, masked, step, decoding):

@@ -233,13 +233,18 @@ def route(x, router_w, k, score="softmax", choice_bias=None,
 
 def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
                choice_bias=None, renormalize=True, scale=1.0,
-               experts_held=None, live=None):
-    """Dropless routed SwiGLU: every token reaches its ``k`` experts, no
-    capacity, nothing dropped.  ``x`` (N, H); ``router_w`` (E, H) over
+               experts_held=None, live=None, kind="swiglu", rows=None):
+    """Dropless routed experts: every token reaches its ``k`` experts, no
+    capacity, nothing dropped.  ``x`` (N, H); ``router_w`` (E, H') over
     ALL experts; the expert bank holds the contiguous range
     ``experts_held = (first, count)`` (default: all), stacked
     ``w_gate`` / ``w_up`` (count, H, I) and ``w_down`` (count, I, H),
-    (in, out).
+    (in, out).  ``kind`` (static) is the expert: ``"swiglu"``,
+    ``(silu(x W_g) * (x W_u)) W_d``, or ``"relu2"``, ``relu(x W_u)^2
+    W_d``: two matrices, ``w_gate`` None.  ``rows`` (N, H'): what the
+    experts compute where that is not what the router reads (a latent
+    expert layer routes on the model's width and computes in a
+    projection of it); the result then has ``rows``' width.
 
     Routes over all experts and returns the part of the result that the
     held experts give (what the absent ones would add is left out: the
@@ -277,7 +282,9 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
       ``k``, so not lower than the other form anywhere, and not equal
       to it in the last bit.
 
-    Both forms differentiate: ``every_expert`` through XLA, the kernel
+    Both forms differentiate in the ``"swiglu"`` kind (a ``"relu2"`` bank
+    has no backward yet and raises by name when differentiated, in
+    either form alike): ``every_expert`` through XLA, the kernel
     through its ``jax.custom_vjp`` (``grouped_expert_ffn_dx`` / ``_dw``,
     the pairs' dX through ``grouped_expert_ffn_rows``), so a trainer's
     step takes the form :func:`expert_product` names; the router learns
@@ -292,29 +299,36 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
     import jax
     import jax.numpy as jnp
 
-    n, h = x.shape
+    grouped_ffn.check_kind(kind, w_gate)
     e = router_w.shape[0]
     first, held = experts_held if experts_held is not None else (0, e)
-    if w_gate.shape[0] != held:
-        raise MXNetError(f"expert bank holds {w_gate.shape[0]} experts, "
+    if w_up.shape[0] != held:
+        raise MXNetError(f"expert bank holds {w_up.shape[0]} experts, "
                          f"experts_held says {held}")
     idx, w = route(x, router_w, k, score, choice_bias, renormalize, scale)
-    x = x.astype(w_gate.dtype)
+    x = (x if rows is None else rows).astype(w_up.dtype)
+    n, h = x.shape
     ones = jnp.ones((n,), jnp.int32) if live is None \
         else live.astype(jnp.int32)
     counts = jnp.zeros((e,), jnp.int32).at[idx].add(ones[:, None])
-    if expert_product(n, k, held, h, w_gate.shape[2],
-                      w_gate.dtype) == "grouped_kernel":
+    if kind == "relu2":
+        x = _no_backward(x, "routed_ffn's \"relu2\" experts")
+    if expert_product(n, k, held, h, w_up.shape[2],
+                      w_up.dtype) == "grouped_kernel":
         return grouped_ffn.grouped_expert_ffn(
-            x, idx - first, w, w_gate, w_up, w_down, live), counts
+            x, idx - first, w, w_gate, w_up, w_down, live, kind=kind), counts
     comb = jnp.zeros((n, e), jnp.float32) \
         .at[jnp.arange(n)[:, None], idx].add(w)
     comb = comb[:, first:first + held]
 
     def every_expert(x, comb):
-        g = jnp.einsum("nh,ehi->nei", x, w_gate)
-        u = jnp.einsum("nh,ehi->nei", x, w_up)
-        act = g * jax.nn.sigmoid(g) * u * comb.astype(x.dtype)[:, :, None]
+        if kind == "relu2":
+            act = jnp.square(jax.nn.relu(jnp.einsum("nh,ehi->nei", x, w_up)))
+        else:
+            g = jnp.einsum("nh,ehi->nei", x, w_gate)
+            u = jnp.einsum("nh,ehi->nei", x, w_up)
+            act = g * jax.nn.sigmoid(g) * u
+        act = act * comb.astype(x.dtype)[:, :, None]
         return jnp.einsum("nei,eih->nh", act, w_down)
 
     if n <= EVERY_EXPERT_ROWS or n % EVERY_EXPERT_ROWS:
@@ -331,6 +345,24 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
                             comb.reshape(-1, EVERY_EXPERT_ROWS, held),
                             ones.reshape(-1, EVERY_EXPERT_ROWS).any(axis=1)))
     return y.reshape(n, -1), counts
+
+
+def _no_backward(x, what):
+    """``x`` as it is; differentiating through it raises an
+    :class:`MXNetError` that names ``what`` (on every platform alike:
+    which form of a product runs is not the trainer's to know)."""
+    import jax
+
+    @jax.custom_vjp
+    def same(x):
+        return x
+
+    def bwd(_res, _dy):
+        raise MXNetError(f"{what} have no backward yet: nothing trains "
+                         "through them (ROADMAP M2)")
+
+    same.defvjp(lambda x: (x, None), bwd)
+    return same(x)
 
 
 def swiglu(u, gate, up, down):

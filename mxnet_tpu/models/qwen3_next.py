@@ -47,7 +47,8 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ops import gated_delta
 from .decoder import (CacheSpec, Causal, PagedDecoder, StepView, apply_rope,
-                      rms_norm, rope_tables, split_heads)
+                      causal_conv, ring_at_length, ring_conv, rms_norm,
+                      rope_tables, split_heads)
 from .moe import expert_product, routed_ffn
 
 __all__ = ["Qwen3NextConfig", "Qwen3NextLayer", "Qwen3NextForCausalLM",
@@ -248,33 +249,22 @@ class Qwen3NextMath:
         import jax.numpy as jnp
 
         cfg = self.cfg
-        taps, eps = cfg.linear_conv_kernel_dim, cfg.norm_eps
+        eps = cfg.norm_eps
         w = p["conv"].astype(u.dtype)                       # (taps, C)
         with jax.named_scope("delta_project"):
             qkvz = u @ p["in_qkvz"].T
             mixed, z = qkvz[..., :cfg.conv_dim], qkvz[..., cfg.conv_dim:]
             ba = u @ p["in_ba"].T
         if not isinstance(view, StepView):
-            t = mixed.shape[1]
             with jax.named_scope("delta_project"):
-                xp = jnp.pad(mixed, ((0, 0), (taps - 1, 0), (0, 0)))
-                conv = _silu(sum(w[j] * xp[:, j:j + t] for j in range(taps)))
+                conv = causal_conv(w, mixed)
             o, state = gated_delta.chunk_scan(
                 *self._rule_inputs(p, conv, ba), live=view.live)
             kept = (mixed, state)
         else:
-            (ring, state), pos = view.entry, view.pos       # (S, taps-1, C)
-            rows = jnp.arange(ring.shape[0])
+            ring, state = view.entry                        # (S, taps-1, C)
             with jax.named_scope("delta_project"):
-                # tap j multiplies the input at pos - (taps - 1) + j
-                conv = _silu(w[taps - 1] * mixed + sum(
-                    w[j] * ring[rows, (pos - (taps - 1) + j) % (taps - 1)]
-                    for j in range(taps - 1)))
-                # the oldest row makes way, which a second step at this
-                # position would miss: only a slot the step owns writes
-                at = pos % (taps - 1)
-                ring = ring.at[rows, at].set(
-                    jnp.where(view.live[:, None], mixed, ring[rows, at]))
+                conv, ring = ring_conv(w, mixed, ring, view.pos, view.live)
             o, state = gated_delta.step(
                 state, *self._rule_inputs(p, conv, ba), live=view.live,
                 kernel=gated_delta.step_form(state.shape[1:])
@@ -478,17 +468,9 @@ class Qwen3NextDecoder(PagedDecoder, Qwen3NextMath):
         where the prompt is shorter, never the padded end's; the
         recurrent state as the scan left it (padded rows do not move
         it)."""
-        import jax.numpy as jnp
-
         mixed, state = kept
-        kk = self.cfg.linear_conv_kernel_dim - 1
-        t0 = jnp.broadcast_to(t0, (mixed.shape[0],))
-        # ring row r holds the one position p in [t0-kk, t0) with p % kk == r
-        src = t0[:, None] - 1 - (t0[:, None] - 1 - jnp.arange(kk)[None]) % kk
-        take = jnp.clip(src, 0, mixed.shape[1] - 1)[:, :, None]
-        ring = jnp.where((src >= 0)[:, :, None],
-                         jnp.take_along_axis(mixed, take, axis=1), 0)
-        return ring, state
+        return ring_at_length(mixed, t0,
+                              self.cfg.linear_conv_kernel_dim - 1), state
 
 
 def qwen3_next_tiny(**overrides):

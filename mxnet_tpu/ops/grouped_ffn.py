@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import telemetry
+from ..base import MXNetError
 
 #: reviewed signature budget (mxlint T15): inlined into the step or
 #: prefill program that calls it; alone (tests_tpu/, tools/) one
@@ -224,6 +225,16 @@ def applicable(platform, mesh, rows, k, held, hidden, width, itemsize=2):
             and tiles(hidden, width, itemsize) is not None)
 
 
+def check_kind(kind, w_gate):
+    """An expert's ``kind`` beside its banks: ``"swiglu"``, ``(silu(x W_g)
+    * (x W_u)) W_d`` over three banks, or ``"relu2"``, ``relu(x W_u)^2
+    W_d`` over two (``w_gate`` None)."""
+    if kind not in ("swiglu", "relu2") or (kind == "relu2") != (w_gate is None):
+        raise MXNetError(f"expert kind {kind!r} with w_gate "
+                         f"{'absent' if w_gate is None else 'given'}: "
+                         "\"swiglu\" takes three banks, \"relu2\" two")
+
+
 def _visits(key, held, tm):
     """The kernel's walk over sorted expert ids ``key`` (W,), a window
     of the sorted list or all of it, ``held`` and more for a pair no
@@ -253,9 +264,16 @@ def _visits(key, held, tm):
 
 
 def _kernel(eid_ref, tid_ref, lo_ref, hi_ref, total_ref,
-            x_ref, w_ref, gate_ref, up_ref, down_ref, o_ref, *acc_ref):
+            x_ref, w_ref, *refs, kind="swiglu"):
+    """``refs``: the visit's blocks of the bank (``gate``, ``up``,
+    ``down``; a ``"relu2"`` expert has no gate), the output tile and,
+    where an expert is walked in width tiles, the float32 sum."""
     from jax.experimental import pallas as pl
 
+    if kind == "relu2":
+        gate_ref, (up_ref, down_ref, o_ref, *acc_ref) = None, refs
+    else:
+        gate_ref, up_ref, down_ref, o_ref, *acc_ref = refs
     v, t = pl.program_id(0), pl.program_id(1)
     tm = x_ref.shape[0]
 
@@ -274,9 +292,13 @@ def _kernel(eid_ref, tid_ref, lo_ref, hi_ref, total_ref,
     @pl.when(v < total_ref[0])
     def _visit():
         x = x_ref[...]
-        g = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
-        act = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        if gate_ref is None:
+            u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+            act = jnp.square(jnp.maximum(u, 0.0)).astype(x.dtype)
+        else:
+            g = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+            act = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
         y = jnp.dot(act, down_ref[0], preferred_element_type=jnp.float32)
         if not acc_ref:                 # the whole width in one tile
             finish(y)
@@ -302,12 +324,14 @@ def _window(x, rows, key, ws, bank, tm, wt, interpret):
     """One window of the sorted list: ``rows`` (W,) the token of each
     pair, ``key`` (W,) its expert (sorted), ``ws`` (W,) its weight ->
     the pairs' float32 products (W, H); a pair nobody computed lies in
-    a tile that may never have been written."""
+    a tile that may never have been written.  ``bank``: ``(w_gate,
+    w_up, w_down)``, ``w_gate`` None for a ``"relu2"`` expert."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     w_gate, w_up, w_down = bank
-    held, h, i = w_gate.shape
+    relu2 = w_gate is None
+    held, h, i = w_up.shape
     nw = i // wt
     eid, tid, lo, hi, total = _visits(key, held, tm)
 
@@ -324,17 +348,16 @@ def _window(x, rows, key, ws, bank, tm, wt, interpret):
     def down(v, t, eid, tid, lo, hi, total):
         return eid[v], width_tile(v, t, total), 0
 
-    need = _vmem_bytes(h, wt, np.dtype(w_gate.dtype).itemsize, tm, nw > 1)
+    need = _vmem_bytes(h, wt, np.dtype(w_up.dtype).itemsize, tm, nw > 1)
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, kind="relu2") if relu2 else _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(eid.shape[0], nw),
             in_specs=[pl.BlockSpec((tm, h), at_rows),
-                      pl.BlockSpec((tm, 1), at_rows),
-                      pl.BlockSpec((1, h, wt), gate_up),
-                      pl.BlockSpec((1, h, wt), gate_up),
-                      pl.BlockSpec((1, wt, h), down)],
+                      pl.BlockSpec((tm, 1), at_rows)]
+            + [pl.BlockSpec((1, h, wt), gate_up)] * (1 if relu2 else 2)
+            + [pl.BlockSpec((1, wt, h), down)],
             out_specs=pl.BlockSpec((tm, h), at_rows),
             # the float32 sum over an expert's width tiles
             scratch_shapes=[pltpu.VMEM((tm, h), jnp.float32)]
@@ -347,7 +370,8 @@ def _window(x, rows, key, ws, bank, tm, wt, interpret):
             vmem_limit_bytes=min(_VMEM_CAP, need + 8 * 2 ** 20)),
         name="grouped_expert_ffn",
         interpret=interpret,
-    )(eid, tid, lo, hi, total, x[rows], ws[:, None], w_gate, w_up, w_down)
+    )(eid, tid, lo, hi, total, x[rows], ws[:, None],
+      *(() if relu2 else (w_gate,)), w_up, w_down)
 
 
 def _keys(idx, held, live):
@@ -366,7 +390,7 @@ def _one_window(x, idx, weights, w_gate, w_up, w_down, live, tm, wt,
     put back in the rows' order (the program of PR 31)."""
     n, h = x.shape
     k = idx.shape[1]
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     m = n * k
     mp = -(-m // tm) * tm
     key = _keys(idx, held, live)
@@ -517,7 +541,7 @@ def _held_windows(x, idx, weights, w_gate, w_up, w_down, live, tm, wt, win,
     (:func:`_held_pairs`), ``win`` of them at a time, each window's rows
     added into a float32 sum (:func:`_add_rows`)."""
     n, h = x.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     weights = weights.astype(jnp.float32)
     pairs, window, runs = _held_pairs(idx, held, live, tt)
     c = min(_CHUNK, tm)
@@ -746,7 +770,7 @@ _grouped_bwd_jit = jax.jit(_grouped_bwd,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _grouped(x, idx, weights, w_gate, w_up, w_down, live, static):
-    tm, wt, win, tt, interpret = static
+    tm, wt, win, tt, interpret, _kind = static
     one = -(-idx.size // tm) * tm <= win
     telemetry.gauge("grouped_ffn.fwd.window_pairs", win)
     # where the rows cross between the tokens' order and the experts':
@@ -768,7 +792,11 @@ def _grouped_fwd(x, idx, weights, w_gate, w_up, w_down, live, static):
 
 def _grouped_vjp(static, res, dy):
     x, idx, weights, w_gate, w_up, w_down, live = res
-    tm, wt, win, tt, interpret = static
+    tm, wt, win, tt, interpret, kind = static
+    if kind != "swiglu":
+        raise MXNetError(
+            f"grouped_expert_ffn's backward is written for \"swiglu\" "
+            f"experts; a bank of kind {kind!r} has none yet")
     if wt != w_gate.shape[2]:
         raise NotImplementedError(
             "grouped_expert_ffn's backward takes whole experts a visit; an "
@@ -789,7 +817,7 @@ _grouped.defvjp(_grouped_fwd, _grouped_vjp)
 
 def grouped_expert_ffn(x, idx, weights, w_gate, w_up, w_down, live=None,
                        row_tile=None, width_tile=None, window=None,
-                       token_tile=None, interpret=False):
+                       token_tile=None, interpret=False, kind="swiglu"):
     """``x`` (N, H) in the bank's dtype; ``idx`` (N, k) int32 the
     experts of each row COUNTED FROM THE BANK'S FIRST (an id outside
     ``[0, held)`` is another chip's expert: left out); ``weights``
@@ -797,7 +825,9 @@ def grouped_expert_ffn(x, idx, weights, w_gate, w_up, w_down, live=None,
     ``w_down`` (held, I, H); ``live`` (N,) bool the rows a request owns
     (default: all; the others are left out like another chip's pairs
     and come back zero).  -> (N, H) in ``x``'s dtype: the sum over each
-    row's held experts of weight x SwiGLU.  ``row_tile``,
+    row's held experts of weight x SwiGLU, or with ``kind`` (static)
+    ``"relu2"`` of weight x ``relu(x w_up)^2 w_down``: two matrices an
+    expert, ``w_gate`` None.  ``row_tile``,
     ``width_tile``, ``window`` (sorted pairs, whole row tiles) and
     ``token_tile`` (rows of the float32 sum a step of
     ``grouped_expert_ffn_rows`` keeps) default to what the shapes say
@@ -807,11 +837,13 @@ def grouped_expert_ffn(x, idx, weights, w_gate, w_up, w_down, live=None,
     Differentiable (``jax.custom_vjp``) in ``x``, ``weights`` and the
     three banks: the backward runs over the same held pairs and the same
     tiles (``grouped_expert_ffn_dx``, ``grouped_expert_ffn_dw``), dropless
-    at any skew, an expert with no row getting zeros."""
-    h, i = w_gate.shape[1:]
-    tm, wt = tiles(h, i, np.dtype(w_gate.dtype).itemsize) or (ROW_TILE, i)
+    at any skew, an expert with no row getting zeros; a ``"relu2"``
+    bank has no backward yet and says so when differentiated."""
+    check_kind(kind, w_gate)
+    h, i = w_up.shape[1:]
+    tm, wt = tiles(h, i, np.dtype(w_up.dtype).itemsize) or (ROW_TILE, i)
     tm, wt = row_tile or tm, width_tile or wt
     win = window or window_pairs(*idx.shape, h, tm)
     tt = token_tile or token_rows(idx.shape[0], h)
     return _grouped(x, idx, weights, w_gate, w_up, w_down, live,
-                    (tm, wt, win, tt, bool(interpret)))
+                    (tm, wt, win, tt, bool(interpret), kind))

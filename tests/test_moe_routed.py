@@ -56,9 +56,10 @@ def _bank(seed=0, e=E):
                 wd=f(e, I, H), b=(rs.randn(e) * 0.5).astype(np.float32))
 
 
-def _oracle(t, k, score, bias, renormalize, scale, held=None):
+def _oracle(t, k, score, bias, renormalize, scale, held=None, kind="swiglu"):
     """Row by row: scores over all experts, the top k of score + bias, the
-    weights from the scores alone, the chosen experts' SwiGLUs summed."""
+    weights from the scores alone, the chosen experts' SwiGLUs (or, ``kind``
+    ``"relu2"``, their two-matrix squared ReLUs) summed."""
     logits = t["x"] @ t["rw"].T
     if score == "sigmoid":
         s = 1.0 / (1.0 + np.exp(-logits))
@@ -77,36 +78,43 @@ def _oracle(t, k, score, bias, renormalize, scale, held=None):
             counts[e] += 1
             if held is not None and not held[0] <= e < held[0] + held[1]:
                 continue
-            g = t["x"][n] @ t["wg"][e]
             u = t["x"][n] @ t["wu"][e]
-            y[n] += we * ((g / (1.0 + np.exp(-g)) * u) @ t["wd"][e])
+            if kind == "relu2":
+                act = np.square(np.maximum(u, 0.0))
+            else:
+                g = t["x"][n] @ t["wg"][e]
+                act = g / (1.0 + np.exp(-g)) * u
+            y[n] += we * (act @ t["wd"][e])
     return y, counts
 
 
 def _run(t, k, score="sigmoid", bias=True, renormalize=True, scale=1.0,
-         held=None, live=None):
+         held=None, live=None, kind="swiglu"):
     sl = slice(None) if held is None else slice(held[0], held[0] + held[1])
     y, c = moe.routed_ffn(
-        jnp.asarray(t["x"]), jnp.asarray(t["rw"]), jnp.asarray(t["wg"][sl]),
+        jnp.asarray(t["x"]), jnp.asarray(t["rw"]),
+        None if kind == "relu2" else jnp.asarray(t["wg"][sl]),
         jnp.asarray(t["wu"][sl]), jnp.asarray(t["wd"][sl]), k, score=score,
         choice_bias=jnp.asarray(t["b"]) if bias else None,
         renormalize=renormalize, scale=scale, experts_held=held,
-        live=None if live is None else jnp.asarray(live))
+        live=None if live is None else jnp.asarray(live), kind=kind)
     return np.asarray(y), np.asarray(c)
 
 
 @pytest.mark.parametrize("k", [1, 3])
-@pytest.mark.parametrize("score,bias,renormalize,scale", [
-    ("sigmoid", True, True, 1.0),      # the LFM2 router
-    ("sigmoid", False, False, 2.5),    # a scale on raw scores
-    ("softmax", False, True, 1.0),     # the Mixtral router
-    ("softmax", True, False, 1.0),
+@pytest.mark.parametrize("score,bias,renormalize,scale,kind", [
+    ("sigmoid", True, True, 1.0, "swiglu"),      # the LFM2 router
+    ("sigmoid", False, False, 2.5, "swiglu"),    # a scale on raw scores
+    ("softmax", False, True, 1.0, "swiglu"),     # the Mixtral router
+    ("softmax", True, False, 1.0, "swiglu"),
+    ("sigmoid", True, True, 5.0, "relu2"),       # Nemotron-H's experts
+    ("softmax", False, True, 1.0, "relu2"),
 ])
 def test_routed_ffn_equals_the_row_by_row_oracle(form, k, score, bias,
-                                                 renormalize, scale):
+                                                 renormalize, scale, kind):
     t = _bank(1)
-    got, counts = _run(t, k, score, bias, renormalize, scale)
-    want, wc = _oracle(t, k, score, bias, renormalize, scale)
+    got, counts = _run(t, k, score, bias, renormalize, scale, kind=kind)
+    want, wc = _oracle(t, k, score, bias, renormalize, scale, kind=kind)
     assert np.abs(got - want).max() < 1e-5
     assert (counts == wc).all() and counts.sum() == N * k
 
@@ -148,24 +156,25 @@ def test_the_choice_uses_score_plus_bias_and_the_weights_the_score():
     assert sorted(np.asarray(idx0)[0].tolist()) == [0, 1]
 
 
-@pytest.mark.parametrize("k,score,bias,experts", [
-    (2, "sigmoid", True, 8), (3, "sigmoid", True, 8),
-    (8, "softmax", False, 16),          # the Qwen3-MoE router's shape
-], ids=["2", "3", "8_of_16_softmax"])
+@pytest.mark.parametrize("k,score,bias,experts,kind", [
+    (2, "sigmoid", True, 8, "swiglu"), (3, "sigmoid", True, 8, "swiglu"),
+    (8, "softmax", False, 16, "swiglu"),    # the Qwen3-MoE router's shape
+    (6, "sigmoid", True, 16, "relu2"),      # two-matrix experts
+], ids=["2", "3", "8_of_16_softmax", "6_of_16_relu2"])
 @pytest.mark.parametrize("share", [1, 2, 4])
 def test_the_shares_of_experts_held_add_up_to_the_uncut_layer(
-        form, k, score, bias, experts, share):
+        form, k, score, bias, experts, kind, share):
     """Eight (or sixteen) experts over chips that hold 1, 2 or 4 each:
     every share routes over all experts and computes its own experts'
     part; the parts add up to the whole layer, and each agrees with the
     oracle restricted to its range."""
     t = _bank(3, experts)
-    whole, counts = _run(t, k, score, bias)
+    whole, counts = _run(t, k, score, bias, kind=kind)
     total = np.zeros_like(whole)
     for first in range(0, experts, share):
-        part, c = _run(t, k, score, bias, held=(first, share))
+        part, c = _run(t, k, score, bias, held=(first, share), kind=kind)
         want, _ = _oracle(t, k, score, bias, True, 1.0,
-                          held=(first, share))
+                          held=(first, share), kind=kind)
         assert np.abs(part - want).max() < 1e-5
         assert (c == counts).all()              # routing is over all experts
         total += part
@@ -212,10 +221,11 @@ def test_every_row_on_one_expert_and_the_forms_count_alike(monkeypatch, tile):
     assert (wc == np.where(np.arange(E) == 5, N, 0)).all()
 
 
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
 @pytest.mark.parametrize("tile", [8, 16, 128])
 @pytest.mark.parametrize("width_tiles", [2, 4])
 def test_an_expert_walked_in_width_tiles_equals_the_oracle(monkeypatch, tile,
-                                                           width_tiles):
+                                                           width_tiles, kind):
     """SwiGLU separates over the intermediate width: 12 wide in 2 tiles
     of 6 or 4 of 3, the float32 sum over them kept beside the visit and
     weight, mask and output applied once at the last; groups that
@@ -223,12 +233,13 @@ def test_an_expert_walked_in_width_tiles_equals_the_oracle(monkeypatch, tile,
     monkeypatch.setattr(moe, "expert_product", lambda *a: "grouped_kernel")
     _interpreted(monkeypatch, tile, I // width_tiles)
     t = _bank(7)
-    got, counts = _run(t, 3)
-    want, wc = _oracle(t, 3, "sigmoid", True, True, 1.0)
+    got, counts = _run(t, 3, kind=kind)
+    want, wc = _oracle(t, 3, "sigmoid", True, True, 1.0, kind=kind)
     assert np.abs(got - want).max() < 1e-5
     assert (counts == wc).all()
-    part, _ = _run(t, 3, held=(2, 4))
-    want, _ = _oracle(t, 3, "sigmoid", True, True, 1.0, held=(2, 4))
+    part, _ = _run(t, 3, held=(2, 4), kind=kind)
+    want, _ = _oracle(t, 3, "sigmoid", True, True, 1.0, held=(2, 4),
+                      kind=kind)
     assert np.abs(part - want).max() < 1e-5
 
 
@@ -238,7 +249,7 @@ PART = (6, 2)
 
 @pytest.mark.parametrize("case", ["several_windows", "no_pair_held",
                                   "every_row_on_one_held_expert",
-                                  "dead_tail"])
+                                  "dead_tail", "several_windows_relu2"])
 @pytest.mark.parametrize("width_tiles", [1, 2])
 @pytest.mark.parametrize("token_tile", [None, 8],
                          ids=["one_token_tile", "token_tiles_of_8"])
@@ -257,6 +268,8 @@ def test_the_held_pairs_in_windows_over_a_part_of_the_router(
     three of 8 (the dead tail's last tile has no pair and is never
     opened)."""
     monkeypatch.setattr(moe, "expert_product", lambda *a: "grouped_kernel")
+    kind = "relu2" if case.endswith("relu2") else "swiglu"
+    case = case.removesuffix("_relu2")
     t = _bank(8, 32)
     first, count = PART
     here = (np.arange(32) >= first) & (np.arange(32) < first + count)
@@ -273,8 +286,8 @@ def test_the_held_pairs_in_windows_over_a_part_of_the_router(
         live = np.arange(N) < 15
     window = 8 if k == 1 else 16
     _interpreted(monkeypatch, 8, I // width_tiles, window, token_tile)
-    got, counts = _run(t, k, held=PART, live=live)
-    want, wc = _oracle(t, k, "sigmoid", True, True, 1.0, held=PART)
+    got, counts = _run(t, k, held=PART, live=live, kind=kind)
+    want, wc = _oracle(t, k, "sigmoid", True, True, 1.0, held=PART, kind=kind)
     owned = np.ones(N, bool) if live is None else live
     held_pairs = int(_oracle(dict(t, x=t["x"][owned]), k, "sigmoid", True,
                              True, 1.0)[1][here].sum())
@@ -294,7 +307,7 @@ def test_the_held_pairs_in_windows_over_a_part_of_the_router(
                                   True, True, 1.0)[1]).all()
     # one window for all the pairs: the same rows to rounding
     _interpreted(monkeypatch, 8, I // width_tiles)
-    whole, _ = _run(t, k, held=PART, live=live)
+    whole, _ = _run(t, k, held=PART, live=live, kind=kind)
     assert np.abs(got - whole).max() < 1e-6
 
 
@@ -467,8 +480,97 @@ def test_expert_product_says_what_ran(case, monkeypatch):
             == [np.asarray(a).tolist() for a in plain]
 
 
+def test_the_experts_compute_other_rows_than_the_router_reads(form):
+    """A latent expert layer: the router reads the model's width, the experts
+    a projection of it (``rows``), and the result has the projection's
+    width."""
+    t = _bank(9)
+    rs = np.random.RandomState(9)
+    wide = (rs.randn(N, 3 * H) * 0.3).astype(np.float32)   # what is routed
+    rw = (rs.randn(E, 3 * H) * 0.3).astype(np.float32)
+    got, counts = moe.routed_ffn(
+        jnp.asarray(wide), jnp.asarray(rw), None, jnp.asarray(t["wu"]),
+        jnp.asarray(t["wd"]), 3, score="sigmoid",
+        choice_bias=jnp.asarray(t["b"]), scale=5.0, kind="relu2",
+        rows=jnp.asarray(t["x"]))
+    idx, w = moe.route(jnp.asarray(wide), jnp.asarray(rw), 3, "sigmoid",
+                       jnp.asarray(t["b"]), True, 5.0)
+    want = np.zeros((N, H), np.float32)
+    for n in range(N):
+        for e, we in zip(np.asarray(idx)[n], np.asarray(w)[n]):
+            u = np.maximum(t["x"][n] @ t["wu"][e], 0.0)
+            want[n] += we * ((u * u) @ t["wd"][e])
+    assert got.shape == (N, H) and np.abs(got - want).max() < 1e-5
+    assert int(counts.sum()) == 3 * N
+
+
+#: sha256 (16 hex digits) of the jaxpr (source locations taken out) of
+#: ``routed_ffn`` in its ``every_expert`` form on the commit before the
+#: expert's kind (PR 46, dbf5398), at the shapes above: the default kind's
+#: program is the parent's to the letter.  (The kernel's: ``tests/
+#: test_train_kernels.py::test_the_one_window_call_is_the_parents_program``.)
+PARENT_EVERY_EXPERT = "db18483d4ba7150a"
+
+
+def test_the_swiglu_programs_text_is_the_parents():
+    import hashlib
+    import re
+
+    import jax
+
+    sds = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+
+    def call(x, rw, wg, wu, wd, b, live):
+        return moe.routed_ffn(x, rw, wg, wu, wd, 3, score="sigmoid",
+                              choice_bias=b, scale=2.5, experts_held=(2, 4),
+                              live=live)
+
+    with jax.enable_x64(False):
+        text = str(jax.make_jaxpr(call)(
+            sds((N, H), f32), sds((E, H), f32), sds((4, H, I), f32),
+            sds((4, H, I), f32), sds((4, I, H), f32), sds((E,), f32),
+            sds((N,), jnp.bool_)))
+    text = re.sub(r" at [^\s\]]+:\d+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_EVERY_EXPERT
+
+
+def test_a_relu2_bank_has_no_backward_and_says_so(form):
+    """Differentiating ``"relu2"`` experts raises an ``MXNetError`` that names
+    them, in either form and on every platform alike; ``"swiglu"`` experts
+    differentiate as before."""
+    import jax
+
+    t = _bank(10)
+    args = [jnp.asarray(t[n]) for n in ("x", "rw", "wu", "wd")]
+
+    def loss(x, rw, wu, wd, kind):
+        wg = None if kind == "relu2" else wu
+        return moe.routed_ffn(x, rw, wg, wu, wd, 2, kind=kind)[0].sum()
+
+    with pytest.raises(mx.MXNetError, match="relu2"):
+        jax.grad(loss)(*args, "relu2")
+    assert np.isfinite(np.asarray(jax.grad(loss)(*args, "swiglu"))).all()
+    # and the kernel called alone refuses for itself
+    with pytest.raises(mx.MXNetError, match="relu2"):
+        jax.grad(lambda x: grouped_ffn.grouped_expert_ffn(
+            x, jnp.zeros((N, 2), jnp.int32), jnp.ones((N, 2), jnp.float32),
+            None, args[2], args[3], row_tile=TILE, interpret=True,
+            kind="relu2").sum())(args[0])
+
+
 def test_bad_arguments_are_refused():
     t = _bank(5)
+    with pytest.raises(mx.MXNetError, match="takes three banks"):
+        moe.routed_ffn(jnp.asarray(t["x"]), jnp.asarray(t["rw"]),
+                       jnp.asarray(t["wg"]), jnp.asarray(t["wu"]),
+                       jnp.asarray(t["wd"]), 2, kind="relu2")
+    with pytest.raises(mx.MXNetError, match="takes three banks"):
+        grouped_ffn.grouped_expert_ffn(
+            jnp.asarray(t["x"]), jnp.zeros((N, 2), jnp.int32),
+            jnp.ones((N, 2), jnp.float32), None, jnp.asarray(t["wu"]),
+            jnp.asarray(t["wd"]))
     with pytest.raises(mx.MXNetError, match="unknown router score"):
         _run(t, 2, score="tanh")
     with pytest.raises(mx.MXNetError, match="experts_held says"):

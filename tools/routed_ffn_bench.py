@@ -18,6 +18,14 @@ the next one's input, so nothing is hoisted), the median of 7.
     chiprun -- python tools/routed_ffn_bench.py --train --parts \
         --router-experts 256 --held 16 --k 8 --hidden 2048 --width 768 \
         --score sigmoid --rows 16384          # JoyAI-LLM-Flash trained, 1 of 16
+    chiprun -- python tools/routed_ffn_bench.py --kind relu2 \
+        --router-experts 512 --held 128 --k 22 --hidden 4096 --latent 1024 \
+        --width 2688 --score sigmoid --rows 128,512  # Nemotron 3 Super, 1 of 4
+
+``--kind relu2`` makes the experts two-matrix squared-ReLU products (no gate);
+``--latent`` is the width the experts multiply in where that is not the
+router's (``routed_ffn``'s ``rows``: the rows are projected down once outside
+the timed call and the result stays in the latent width).
 
 Prints one JSON line a size: milliseconds a call of each form, the
 whole layer with its routing, the kernel at each ``--tiles`` entry (a row
@@ -66,6 +74,11 @@ def main():
     ap.add_argument("--rows", default="128,256,512,2048")
     ap.add_argument("--tiles", default=str(grouped_ffn.ROW_TILE))
     ap.add_argument("--score", default="softmax")
+    ap.add_argument("--kind", default="swiglu", choices=("swiglu", "relu2"),
+                    help="the expert: three matrices, or relu(x W1)^2 W2")
+    ap.add_argument("--latent", type=int, default=None,
+                    help="the width the experts multiply in, where it is "
+                         "not the router's --hidden (a latent expert layer)")
     ap.add_argument("--train", action="store_true",
                     help="time forward AND backward of each form (the "
                          "gradient in the rows, the router and the bank), "
@@ -74,14 +87,25 @@ def main():
                     help="also time each piece of one window of the "
                          "windowed form alone, and the router's pieces")
     args = ap.parse_args()
-    e, k, h, i = args.experts, args.k, args.hidden, args.width
+    e, k, i = args.experts, args.k, args.width
+    wide, h = args.hidden, args.latent or args.hidden
+    relu2, mats = args.kind == "relu2", 2 if args.kind == "relu2" else 3
+    if (relu2 or h != wide) and (args.train or args.parts):
+        raise SystemExit("--train and --parts time the swiglu kernels at the "
+                         "router's width")
     held = args.held or e
-    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
     bf = jnp.bfloat16
-    rw = jax.random.normal(keys[0], (e, h), bf) * 0.02
-    wg = jax.random.normal(keys[1], (held, h, i), bf) * 0.02
+    rw = jax.random.normal(keys[0], (e, wide), bf) * 0.02
+    wg = None if relu2 else jax.random.normal(keys[1], (held, h, i), bf) * 0.02
     wu = jax.random.normal(keys[2], (held, h, i), bf) * 0.02
     wd = jax.random.normal(keys[3], (held, i, h), bf) * 0.02
+    # a latent layer routes on the wide rows and computes on their
+    # projection: the wide rows (a row of its own each, so that the rows
+    # spread over the experts) ride along, the latent ones chain
+    most = max(int(r) for r in args.rows.split(","))
+    routed = jax.random.normal(keys[5], (most, wide), bf) \
+        if h != wide else None
 
     # the bank rides as an argument: closed over, its 1.2 GB would be
     # constants of every program, and each compile takes minutes
@@ -92,17 +116,25 @@ def main():
             # read where routed_ffn is traced: once a program
             with mock.patch.object(moe, "expert_product",
                                    lambda *a: name):
-                return moe.routed_ffn(x, rw, wg, wu, wd, k,
+                if routed is None:
+                    return moe.routed_ffn(x, rw, wg, wu, wd, k,
+                                          score=args.score,
+                                          experts_held=(0, held),
+                                          kind=args.kind)[0]
+                wide_rows = routed[:x.shape[0]] + x[:, :1]
+                return moe.routed_ffn(wide_rows, rw, wg, wu, wd, k,
                                       score=args.score,
-                                      experts_held=(0, held))[0]
+                                      experts_held=(0, held),
+                                      kind=args.kind, rows=x)[0]
         return fn
 
     def at_tile(tm, wt, window):
         def fn(x, rw, wg, wu, wd):
-            idx, w = moe.route(x, rw, k, args.score)
+            r = x if routed is None else routed[:x.shape[0]] + x[:, :1]
+            idx, w = moe.route(r, rw, k, args.score)
             return grouped_ffn.grouped_expert_ffn(
                 x, idx, w, wg, wu, wd, row_tile=tm, width_tile=wt,
-                window=window)
+                window=window, kind=args.kind)
         return fn
 
     def chained(fn):
@@ -144,16 +176,17 @@ def main():
     dev = jax.devices()[0]
     for n in (int(r) for r in args.rows.split(",")):
         x = jax.random.normal(keys[4], (n, h), bf)
-        row = {"train": bool(args.train),
+        row = {"train": bool(args.train), "kind": args.kind,
                "rows": n, "experts": e, "held": held, "k": k, "hidden": h,
+               "router_hidden": wide,
                "width": i, "device": dev.device_kind,
                "rule_picks": moe.expert_product(n, k, held, h, i, bf),
                "row_tile": row_tile, "width_tile": width_tile,
                "window_pairs": row_tile
                and grouped_ffn.window_pairs(n, k, h, row_tile),
-               "every_expert_gflop": 2 * 3 * n * held * h * i / 1e9,
-               "routed_gflop": 2 * 3 * n * k * h * i * held / e / 1e9,
-               "bank_gb": 3 * held * h * i * 2 / 1e9}
+               "every_expert_gflop": 2 * mats * n * held * h * i / 1e9,
+               "routed_gflop": 2 * mats * n * k * h * i * held / e / 1e9,
+               "bank_gb": mats * held * h * i * 2 / 1e9}
         outs = {}
         for name, fn in columns:
             prog = chained(fn)
