@@ -268,14 +268,20 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, k, score="softmax",
       a CPU, under a mesh and below ``ops.grouped_ffn.GROUPED_MIN_ROWS``
       rows.
     * ``grouped_kernel``: ``ops.grouped_ffn.grouped_expert_ffn``, the
-      (row, expert) pairs sorted by expert, each touched expert
+      (row, expert) pairs listed expert by expert, each touched expert
       computed on its own rows alone: whole experts streamed once where
-      two fit VMEM, else walked in width tiles; where the pairs exceed
-      one window (a long prefill, a trainer's step, over a bank that
-      holds a part of the router's experts) only the pairs held here
-      are listed, gathered and computed, a window of as many as 128 MiB
-      of float32 rows hold at a time, dead rows left out, and a
-      window's rows go back into the rows' order inside
+      two fit VMEM, else walked in width tiles.  The rows cross between
+      the tokens' order and the experts' in kernels, in one of two
+      forms from static shapes (``ops.grouped_ffn.rows_form``): where
+      every pair fits one window and the call's rows fit VMEM (a served
+      step's hundreds of rows) the rows and their float32 sum stay in
+      VMEM for the whole call and a visit takes and adds its own pairs'
+      rows by their token (``grouped_expert_ffn_resident``); where the
+      pairs exceed one window (a long prefill, a trainer's step, over a
+      bank that holds a part of the router's experts) only the pairs
+      held here are listed, gathered and computed, a window of as many
+      as 128 MiB of float32 rows hold at a time, dead rows left out,
+      and a window's rows go back into the rows' order inside
       ``grouped_expert_ffn_rows`` (a tile of tokens in VMEM, its pairs
       added row by row in float32).  Same routing to the bit, dropless
       at any skew; float32 accumulation, weights and sum over a row's

@@ -6,13 +6,13 @@ Reference: NONE (the reference has no sparse experts).
 row under a combine matrix; past some 240 rows a call that product is
 bound by operations nobody asked for (PERF.md, PRs 31 and 33).  Here:
 
-- the ``N x k`` (row, expert) pairs are sorted by expert in XLA, the
-  pairs no held expert computes (another chip's expert, a row no
-  request owns) last; pair ``p`` of the sorted list is a row of ``xs``
-  (the token's hidden state, gathered) and a float32 weight.  Groups
-  are NOT padded: a row tile of sorted pairs that straddles a group
-  boundary is visited once for each expert it holds rows of, and a
-  visit keeps the rows of its own expert (``lo <= pair < hi``);
+- the (row, expert) pairs are listed expert by expert, the pairs no held
+  expert computes (another chip's expert, a row no request owns) last
+  or not at all; pair ``p`` of the list is a token's row and a float32
+  weight.  Groups are NOT padded: a row tile of listed pairs that
+  straddles a group boundary is visited once for each expert it holds
+  rows of, and a visit keeps the rows of its own expert (``lo <= pair <
+  hi``);
 - a grid step is one visit ``(expert, row tile)`` at one width tile.
   The visits are listed expert by expert (:func:`_visits`) and
   prefetched as scalars.  SwiGLU separates over the intermediate
@@ -32,17 +32,28 @@ bound by operations nobody asked for (PERF.md, PRs 31 and 33).  Here:
   bank's dtype, float32 accumulation in each product and over the
   width tiles, the pair's weight applied once in float32 to the
   float32 down product;
-- the kernel's output is a float32 row a pair.  Where every pair fits
-  one window (:func:`window_pairs`) XLA sorts the pairs, puts the
-  kernel's rows back in their rows' order and sums a row's ``k`` in
-  float32.  Where they do not (a prefill of thousands of rows, a
-  trainer's step, over a bank that holds a part of the router's
-  experts) only the HELD pairs are listed, expert by expert and row by
-  row, from a running count over ``(expert, row)`` (no sort), and
-  gathered, visited and written a window of them at a time under a
-  loop whose trip count the device computes: no array of ``N x k`` rows
-  of ``H`` exists, and nothing is dropped at any skew (every pair held
-  takes every window).  One cast at the end;
+- the rows cross between the tokens' order and the experts' order in
+  kernels, in one of two forms chosen from static shapes
+  (:func:`rows_form`), and a row's ``k`` products are summed in float32
+  in a fixed order, expert by expert.  **Resident**
+  (``grouped_expert_ffn_resident``): where every pair fits one window
+  (:func:`window_pairs`) and the call's rows, their float32 sum and the
+  kernel's blocks fit VMEM (a served step's hundreds of rows), ``x`` (N,
+  H) and the sum (N, H) stay in VMEM for the whole call; XLA sorts the
+  ``N x k`` keys (integer work), a visit takes its own pairs' rows out
+  of the resident ``x`` by their token, computes them and adds each
+  float32 result row into the resident sum at its token; one cast at
+  the last step.  No array of ``N x k`` rows of ``H`` exists, in either
+  direction.  (Before PR 48 XLA gathered ``x[rows]``, took a float32
+  row a pair from the kernel, gathered those back and summed over
+  ``k``: 0.15 us a pair, PERF.md.)  **Windowed**: where the pairs exceed
+  a window (a prefill of thousands of rows, a trainer's step, over a
+  bank that holds a part of the router's experts) only the HELD pairs
+  are listed, expert by expert and row by row, from a running count over
+  ``(expert, row)`` (no sort), and gathered, visited and written a
+  window of them at a time under a loop whose trip count the device
+  computes: nothing is dropped at any skew (every pair held takes every
+  window);
 - a window's rows go back into the tokens' order inside a kernel,
   ``grouped_expert_ffn_rows`` (:func:`_add_rows`), into ONE float32
   ``(N, H)`` sum carried in place.  Inside one expert's group the rows
@@ -73,7 +84,7 @@ from ..base import MXNetError
 __compile_signatures__ = {
     "grouped_expert_ffn":
         "0 inside a serving or training program; 1 per (operand shapes, "
-        "tiles, window) when called alone (its jits, ``_one_window_jit``, "
+        "tiles, window) when called alone (its jits, ``_resident_jit``, "
         "``_held_windows_jit`` and the backward's ``_grouped_bwd_jit``)",
 }
 
@@ -103,14 +114,23 @@ ROW_TILE_WALKED = 256
 #: expert on every row costs ``max(N x bank operations / peak, bank
 #: bytes / bandwidth)`` and turns compute-bound at 2 x 197e12 / 819e9 =
 #: 240 rows whatever the bank's shape; this form costs the touched
-#: experts' bytes and some 0.15 us a pair of sorting and gathering.
-#: On the v5e (PERF.md, PR 31: one layer with its routing, ms a call,
-#: every expert / this form; 128 experts of 768, 8 a row): 1.73 / 1.85
-#: at 128 rows, 1.86 / 1.99 at 256, 3.42 / 2.20 at 512, 6.74 / 2.75 at
-#: 1,024, 13.76 / 4.30 at 2,048; 64 experts of 1,536, 4 a row: 1.78 /
-#: 1.82 at 128, 1.87 / 1.91 at 256, 3.41 / 2.10 at 512, 13.56 / 3.07 at
-#: 2,048.  The lines cross near 290 rows; the constant lies between the
-#: measured sides, 256 and 512.
+#: experts' bytes and, since its rows cross orders in VMEM, next to
+#: nothing a pair (0.004 us; 0.15 while XLA sorted, gathered and summed
+#: them, which is what put the crossing "near 290 rows" in PR 31).
+#: Re-measured on the v5e (PERF.md, PR 48, ``tools/routed_ffn_bench.py``:
+#: one layer with its routing, ms a call, every expert / this form, at
+#: 128 / 256 / 384 / 512 rows): 128 experts of 768, 8 a row: 1.74 / 1.74,
+#: 1.90 / 1.76, 2.72 / 1.80, 3.42 / 1.82; 64 of 1,536, 4 a row: 1.75 /
+#: 1.74, 1.87 / 1.77, 2.66 / 1.77, 3.41 / 1.78; 128 of 512, 10 a row:
+#: 1.22 / 1.13, 1.38 / 1.26, 1.92 / 1.30, 2.44 / 1.35; 128 ``"relu2"``
+#: experts of 2,688 over latent rows of 1,024, 22 a row: 2.05 / 2.00,
+#: 2.26 / 2.13, 3.30 / 2.21, 4.28 / 2.27.  This form now reads lower at
+#: every size for every bank (by 0.2-7% at 128 rows, 6-9% at 256), so
+#: the evidence says 128.  The constant stays 384 in PR 48 all the
+#: same: lowering it hands the steps of ``lfm2_24b`` (128 rows),
+#: ``qwen3_next`` (256) and ``nemotron3_super`` (128) to the kernel, a
+#: pair of each on the chip has to show it first, and no chip was free
+#: for them (PERF.md section 7, ROADMAP S7(3): the next PR's first run).
 GROUPED_MIN_ROWS = 384
 
 #: VMEM a call may ask for: a v5e core has 128 MiB
@@ -118,8 +138,8 @@ _VMEM_CAP = 100 * 2 ** 20
 
 #: most bytes of float32 output rows a window holds: 16,384 pairs at a
 #: hidden size of 2,048, 5,376 (whole tiles of 256) at 6,144.  A call
-#: whose ``N x k`` pairs all fit goes in one window (sorted, and gathered
-#: back into the rows' order); past that only the HELD pairs are listed,
+#: whose ``N x k`` pairs all fit may keep its rows in VMEM
+#: (:func:`rows_form`); past that only the HELD pairs are listed,
 #: a window of this size at a time, and the last window's unused rest is
 #: neither computed nor added (the kernels stop at the listed pairs).
 #: What a window costs whatever it holds is its listing, its gathers and
@@ -205,11 +225,38 @@ def tiles(hidden, width, itemsize=2):
 
 def window_pairs(rows, k, hidden, tm):
     """Sorted pairs a window of a call of ``rows`` rows of ``k`` experts
-    holds, whole row tiles: every pair where their float32 rows take no
-    more than ``_ONE_WINDOW_BYTES`` (the two sparse chat cells' calls
-    of 512 rows), else as many as do."""
+    holds, whole row tiles: every pair where their float32 rows would
+    take no more than ``_ONE_WINDOW_BYTES`` (the served calls of 512
+    rows), else as many as do."""
     pairs = -(-rows * k // tm) * tm
     return min(pairs, max(1, _ONE_WINDOW_BYTES // (4 * hidden) // tm) * tm)
+
+
+def _resident_vmem_bytes(rows, hidden, wt, itemsize, tm, walked=False):
+    """What the resident form keeps in VMEM: beside the kernel's own
+    blocks (:func:`_vmem_bytes`, whose row and output tiles are here the
+    visit's gathered rows and weighted products), every row of the call
+    in float32 as it came (two buffers) and as it leaves (two, in the
+    bank's dtype), and the float32 sum."""
+    return _vmem_bytes(hidden, wt, itemsize, tm, walked) \
+        + rows * hidden * (2 * 4 + 2 * itemsize + 4)
+
+
+def rows_form(rows, k, hidden, width, itemsize=2, tiling=None, window=None):
+    """Where a call of ``rows`` rows of ``k`` experts each over experts
+    ``(hidden, width)`` crosses between the tokens' order and the
+    experts', from static shapes alone: ``"resident"``, in VMEM inside
+    the one kernel, where every pair fits one window and the rows, their
+    float32 sum and the kernel's blocks fit ``_VMEM_CAP`` (the served
+    calls of 512 rows: 42 of 100 MiB at 128 experts of 768 x 2,048), else
+    ``"kernel"``, a window of held pairs at a time through
+    ``grouped_expert_ffn_rows``.  ``tiling`` ``(row tile, width tile)``
+    and ``window`` default to what the shapes say."""
+    tm, wt = tiling or tiles(hidden, width, itemsize) or (ROW_TILE, width)
+    win = window or window_pairs(rows, k, hidden, tm)
+    fits = _resident_vmem_bytes(rows, hidden, wt, itemsize, tm,
+                                wt < width) <= _VMEM_CAP
+    return "resident" if -(-rows * k // tm) * tm <= win and fits else "kernel"
 
 
 def applicable(platform, mesh, rows, k, held, hidden, width, itemsize=2):
@@ -235,6 +282,17 @@ def check_kind(kind, w_gate):
                          "\"swiglu\" takes three banks, \"relu2\" two")
 
 
+def _groups(key, held):
+    """Where each held expert's group starts and ends in the sorted
+    expert ids ``key``."""
+    # counted, not searched: a binary search is a loop of gathers on
+    # the device, 1 ms a window where counting is one fused pass
+    ends = jnp.searchsorted(key, jnp.arange(held, dtype=jnp.int32),
+                            side="right", method="compare_all") \
+        .astype(jnp.int32)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]]), ends
+
+
 def _visits(key, held, tm):
     """The kernel's walk over sorted expert ids ``key`` (W,), a window
     of the sorted list or all of it, ``held`` and more for a pair no
@@ -243,12 +301,7 @@ def _visits(key, held, tm):
     visits, listed expert by expert and so tile by tile too, the static
     rest repeating the last one."""
     mp = key.shape[0]
-    # counted, not searched: a binary search is a loop of gathers on
-    # the device, 1 ms a window where counting is one fused pass
-    ends = jnp.searchsorted(key, jnp.arange(held, dtype=jnp.int32),
-                            side="right", method="compare_all") \
-        .astype(jnp.int32)
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    starts, ends = _groups(key, held)
     sizes = ends - starts
     spans = jnp.where(sizes > 0, (ends - 1) // tm - starts // tm + 1, 0)
     vend = jnp.cumsum(spans)
@@ -263,11 +316,46 @@ def _visits(key, held, tm):
     return eid, tid.astype(jnp.int32), starts[eid], ends[eid], total[None]
 
 
+def _visit_product(x, t, gate_ref, up_ref, down_ref, acc_ref, finish):
+    """A visit's rows ``x`` (tm, H) through its expert's blocks at width
+    tile ``t``: ``finish(y)`` takes the float32 (tm, H) down product, once
+    a visit, at its last width tile."""
+    from jax.experimental import pallas as pl
+
+    if gate_ref is None:
+        u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+        act = jnp.square(jnp.maximum(u, 0.0)).astype(x.dtype)
+    else:
+        g = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
+        act = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    y = jnp.dot(act, down_ref[0], preferred_element_type=jnp.float32)
+    if not acc_ref:                 # the whole width in one tile
+        finish(y)
+        return
+    # an expert walked in width tiles: the float32 sum over them
+    # stays in VMEM; weight, mask and output once, at the last
+    acc, last = acc_ref[0], pl.num_programs(1) - 1
+
+    @pl.when(t == 0)
+    def _first():
+        acc[...] = y
+
+    @pl.when(jnp.logical_and(t > 0, t < last))
+    def _middle():
+        acc[...] += y
+
+    @pl.when(t == last)
+    def _last():
+        finish(acc[...] + y)
+
+
 def _kernel(eid_ref, tid_ref, lo_ref, hi_ref, total_ref,
             x_ref, w_ref, *refs, kind="swiglu"):
-    """``refs``: the visit's blocks of the bank (``gate``, ``up``,
-    ``down``; a ``"relu2"`` expert has no gate), the output tile and,
-    where an expert is walked in width tiles, the float32 sum."""
+    """A window's visits over rows gathered for it.  ``refs``: the
+    visit's blocks of the bank (``gate``, ``up``, ``down``; a ``"relu2"``
+    expert has no gate), the output tile and, where an expert is walked
+    in width tiles, the float32 sum."""
     from jax.experimental import pallas as pl
 
     if kind == "relu2":
@@ -291,33 +379,67 @@ def _kernel(eid_ref, tid_ref, lo_ref, hi_ref, total_ref,
 
     @pl.when(v < total_ref[0])
     def _visit():
-        x = x_ref[...]
-        if gate_ref is None:
-            u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
-            act = jnp.square(jnp.maximum(u, 0.0)).astype(x.dtype)
-        else:
-            g = jnp.dot(x, gate_ref[0], preferred_element_type=jnp.float32)
-            u = jnp.dot(x, up_ref[0], preferred_element_type=jnp.float32)
-            act = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
-        y = jnp.dot(act, down_ref[0], preferred_element_type=jnp.float32)
-        if not acc_ref:                 # the whole width in one tile
-            finish(y)
-            return
-        # an expert walked in width tiles: the float32 sum over them
-        # stays in VMEM; weight, mask and output once, at the last
-        acc, last = acc_ref[0], pl.num_programs(1) - 1
+        _visit_product(x_ref[...], t, gate_ref, up_ref, down_ref, acc_ref,
+                       finish)
 
+
+def _resident_kernel(eid_ref, first_ref, hi_ref, total_ref, rows_ref, ws_ref,
+                     x_ref, *refs, kind="swiglu"):
+    """The visits of a call whose rows and float32 sum stay in VMEM:
+    ``x_ref`` (N, H) float32 every row of the call, ``rows_ref`` each
+    listed pair's token and ``ws_ref`` its weight; visit ``v``'s pairs
+    are ``first_ref[v] <= p < hi_ref[v]``, ``tm`` at most, one expert's.
+    ``refs``: the bank's blocks as :func:`_kernel`'s, the output (N, H),
+    then scratch: the visit's rows (tm, H) float32, their products, the
+    sum (N, H) and, where an expert is walked in width tiles, the sum
+    over them."""
+    from jax.experimental import pallas as pl
+
+    if kind == "relu2":
+        gate_ref, (up_ref, down_ref, o_ref, xs_ref, y_ref, sum_ref,
+                   *acc_ref) = None, refs
+    else:
+        (gate_ref, up_ref, down_ref, o_ref, xs_ref, y_ref, sum_ref,
+         *acc_ref) = refs
+    v, t = pl.program_id(0), pl.program_id(1)
+    first, hi = first_ref[v], hi_ref[v]
+
+    @pl.when(jnp.logical_and(v == 0, t == 0))
+    def _open():
+        sum_ref[...] = jnp.zeros_like(sum_ref)
+
+    def finish(y):
+        y_ref[...] = y
+
+        def add(p, carry):
+            # the pair's weight once, in float32, to the float32 product;
+            # exact float32 adds in the listing's order, expert by expert
+            sum_ref[pl.ds(rows_ref[p], 1), :] += \
+                ws_ref[p] * y_ref[pl.ds(p - first, 1), :]
+            return carry
+
+        lax.fori_loop(first, hi, add, 0)
+
+    @pl.when(v < total_ref[0])
+    def _visit():
         @pl.when(t == 0)
-        def _first():
-            acc[...] = y
+        def _rows_in():
+            def take(p, carry):
+                xs_ref[pl.ds(p - first, 1), :] = \
+                    x_ref[pl.ds(rows_ref[p], 1), :]
+                return carry
 
-        @pl.when(jnp.logical_and(t > 0, t < last))
-        def _middle():
-            acc[...] += y
+            lax.fori_loop(first, hi, take, 0)
 
-        @pl.when(t == last)
-        def _last():
-            finish(acc[...] + y)
+        # the scratch's other rows are whatever it held: a row's product
+        # is its own, and nobody reads theirs
+        _visit_product(xs_ref[...].astype(up_ref.dtype), t, gate_ref, up_ref,
+                       down_ref, acc_ref, finish)
+
+    @pl.when(jnp.logical_and(v == pl.num_programs(0) - 1,
+                             t == pl.num_programs(1) - 1))
+    def _close():
+        o_ref[...] = sum_ref[...].astype(o_ref.dtype)
 
 
 def _window(x, rows, key, ws, bank, tm, wt, interpret):
@@ -384,27 +506,114 @@ def _keys(idx, held, live):
     return jnp.where(there, idx, held).astype(jnp.int32)
 
 
-def _one_window(x, idx, weights, w_gate, w_up, w_down, live, tm, wt,
-                interpret):
-    """Every pair in one window: sorted by expert, and the kernel's rows
-    put back in the rows' order (the program of PR 31)."""
-    n, h = x.shape
-    k = idx.shape[1]
-    held = w_up.shape[0]
+def _sorted_pairs(idx, weights, held, live, tm):
+    """A resident call's listing: every pair sorted by expert, whole row
+    tiles of ``tm``, those no held expert computes last -> each pair's
+    token, its expert (``held`` for nobody's) and its float32 weight.
+    Integer work on ``N x k`` numbers; inside an expert the rows ascend."""
+    n, k = idx.shape
     m = n * k
     mp = -(-m // tm) * tm
-    key = _keys(idx, held, live)
-    there = key < held
-    key = jnp.pad(key.reshape(-1), (0, mp - m), constant_values=held)
+    key = jnp.pad(_keys(idx, held, live).reshape(-1), (0, mp - m),
+                  constant_values=held)
     key, order = lax.sort((key, jnp.arange(mp, dtype=jnp.int32)),
                           num_keys=1)
     ws = jnp.pad(weights.reshape(-1).astype(jnp.float32), (0, mp - m))[order]
-    out = _window(x, jnp.minimum(order // k, n - 1), key, ws,
-                  (w_gate, w_up, w_down), tm, wt, interpret)
-    back = jnp.zeros((mp,), jnp.int32).at[order].set(
-        jnp.arange(mp, dtype=jnp.int32), unique_indices=True)[:m]
-    y = jnp.where(there[:, :, None], out[back].reshape(n, k, h), 0.0)
-    return y.sum(axis=1).astype(x.dtype)
+    return jnp.minimum(order // k, n - 1), key, ws
+
+
+def _own_visits(key, held, tm):
+    """The resident kernel's walk over sorted expert ids ``key`` (W,):
+    a visit is ``tm`` listed pairs of ONE expert at most, from wherever
+    its group stands in the list (the rows are taken by their token, so a
+    visit owes the list's row tiles nothing, and a group of up to ``tm``
+    pairs is one visit however it lies) -> visit ``v`` is expert
+    ``eid[v]`` on the pairs ``first[v] <= p < hi[v]``; ``total`` visits,
+    expert by expert, the static rest repeating the last one."""
+    mp = key.shape[0]
+    starts, ends = _groups(key, held)
+    spans = (ends - starts + tm - 1) // tm
+    vend = jnp.cumsum(spans)
+    total = vend[-1]
+    v = jnp.arange(mp // tm + held, dtype=jnp.int32)
+    v = jnp.minimum(v, jnp.maximum(total - 1, 0))
+    eid = jnp.minimum(jnp.searchsorted(vend, v, side="right",
+                                       method="compare_all"),
+                      held - 1).astype(jnp.int32)
+    first = starts[eid] + (v - (vend - spans)[eid]) * tm
+    return eid, first, jnp.minimum(ends[eid], first + tm), total[None]
+
+
+def _resident_visits(x, rows, key, ws, bank, tm, wt, interpret):
+    """The visits over a listing (:func:`_sorted_pairs`) with ``x`` (N, H)
+    and the float32 sum in VMEM for the whole call: a visit takes its own
+    pairs' rows out of ``x`` by their token, computes them and adds each
+    float32 result into the sum at its token, row by row in the
+    listing's order; the last step casts the sum -> (N, H)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    w_gate, w_up, w_down = bank
+    n, h = x.shape
+    relu2 = w_gate is None
+    held, _, i = w_up.shape
+    nw = i // wt
+    eid, first, hi, total = _own_visits(key, held, tm)
+
+    def whole(v, t, *_):
+        return 0, 0
+
+    def width_tile(v, t, total):
+        # a step past the last visit keeps the tile that is there
+        return jnp.where(v < total[0], t, nw - 1)
+
+    def gate_up(v, t, eid, first, hi, total, *_):
+        return eid[v], 0, width_tile(v, t, total)
+
+    def down(v, t, eid, first, hi, total, *_):
+        return eid[v], width_tile(v, t, total), 0
+
+    itemsize = np.dtype(w_up.dtype).itemsize
+    need = _resident_vmem_bytes(n, h, wt, itemsize, tm, nw > 1)
+    return pl.pallas_call(
+        functools.partial(_resident_kernel, kind="relu2" if relu2
+                          else "swiglu"),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(eid.shape[0], nw),
+            in_specs=[pl.BlockSpec((n, h), whole)]
+            + [pl.BlockSpec((1, h, wt), gate_up)] * (1 if relu2 else 2)
+            + [pl.BlockSpec((1, wt, h), down)],
+            out_specs=pl.BlockSpec((n, h), whole),
+            # a visit's rows and their products, the sum in the tokens'
+            # order, the sum over an expert's width tiles
+            scratch_shapes=[pltpu.VMEM((tm, h), jnp.float32),
+                            pltpu.VMEM((tm, h), jnp.float32),
+                            pltpu.VMEM((n, h), jnp.float32)]
+            + ([pltpu.VMEM((tm, h), jnp.float32)] if nw > 1 else [])),
+        out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
+        # visits run in order: an expert's weights stay while it is the
+        # next visit's too, the sum until the last step casts it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(_VMEM_CAP, need + 8 * 2 ** 20)),
+        name="grouped_expert_ffn_resident",
+        interpret=interpret,
+    )(eid, first, hi, total, rows, ws,
+      # a one-row slice at a token's index is a float32 array's (a row of
+      # packed bf16 is half a sublane); bf16 -> float32 -> bf16 is exact
+      x.astype(jnp.float32),
+      *(() if relu2 else (w_gate,)), w_up, w_down)
+
+
+def _resident(x, idx, weights, w_gate, w_up, w_down, live, tm, wt,
+              interpret):
+    """Every pair in one window: listed by a sort of ``N x k`` keys,
+    crossed between the two orders inside the kernel.  Nothing of
+    ``N x k`` rows of ``H`` exists."""
+    return _resident_visits(
+        x, *_sorted_pairs(idx, weights, w_up.shape[0], live, tm),
+        (w_gate, w_up, w_down), tm, wt, interpret)
 
 
 def _held_pairs(idx, held, live, tt):
@@ -560,8 +769,8 @@ def _held_windows(x, idx, weights, w_gate, w_up, w_down, live, tm, wt, win,
 
 #: jitted, so that the layers of a program share one trace and one
 #: Mosaic lowering of the kernel, as ``ops.paged_attention`` does
-_one_window_jit = jax.jit(
-    _one_window, static_argnames=("tm", "wt", "interpret"))
+_resident_jit = jax.jit(
+    _resident, static_argnames=("tm", "wt", "interpret"))
 _held_windows_jit = jax.jit(
     _held_windows, static_argnames=("tm", "wt", "win", "tt", "interpret"))
 
@@ -771,14 +980,16 @@ _grouped_bwd_jit = jax.jit(_grouped_bwd,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _grouped(x, idx, weights, w_gate, w_up, w_down, live, static):
     tm, wt, win, tt, interpret, _kind = static
-    one = -(-idx.size // tm) * tm <= win
+    form = rows_form(*idx.shape, x.shape[1], w_up.shape[2],
+                     np.dtype(w_up.dtype).itemsize, (tm, wt), win)
     telemetry.gauge("grouped_ffn.fwd.window_pairs", win)
     # where the rows cross between the tokens' order and the experts':
-    # in ``grouped_expert_ffn_rows``, or in XLA's sort and gathers
-    telemetry.gauge("grouped_ffn.rows_form", "xla" if one else "kernel")
-    if one:
-        return _one_window_jit(x, idx, weights, w_gate, w_up, w_down, live,
-                               tm=tm, wt=wt, interpret=interpret)
+    # in VMEM inside the one kernel, or window by window in
+    # ``grouped_expert_ffn_rows``
+    telemetry.gauge("grouped_ffn.rows_form", form)
+    if form == "resident":
+        return _resident_jit(x, idx, weights, w_gate, w_up, w_down, live,
+                             tm=tm, wt=wt, interpret=interpret)
     telemetry.gauge("grouped_ffn.token_tile", tt)
     return _held_windows_jit(x, idx, weights, w_gate, w_up, w_down, live,
                              tm=tm, wt=wt, win=win, tt=tt,

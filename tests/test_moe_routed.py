@@ -508,7 +508,7 @@ def test_the_experts_compute_other_rows_than_the_router_reads(form):
 #: ``routed_ffn`` in its ``every_expert`` form on the commit before the
 #: expert's kind (PR 46, dbf5398), at the shapes above: the default kind's
 #: program is the parent's to the letter.  (The kernel's: ``tests/
-#: test_train_kernels.py::test_the_one_window_call_is_the_parents_program``.)
+#: test_train_kernels.py::test_the_windowed_calls_are_the_parents_programs``.)
 PARENT_EVERY_EXPERT = "db18483d4ba7150a"
 
 

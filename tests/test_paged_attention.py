@@ -949,8 +949,10 @@ def test_training_kernels_of_the_latent_expert_cell_compile_for_v5e(one_chip):
 @pytest.mark.parametrize("rows,k,held,hidden,width", [
     (512, 8, 128, 2048, 768),     # sdar_30b.chat_decode_sat: a block pass
     (512, 4, 64, 2048, 1536),     # lfm2_24b: its 512 prefill bucket
+    (512, 10, 128, 2048, 512),    # qwen3_next: its 512 prefill bucket
     (32768, 8, 16, 6144, 2048),   # glm5.longdoc_prefill: its longest bucket
-], ids=["sdar_512x8_of_128", "lfm2_512x4_of_64", "glm5_32768x8_of_16"])
+], ids=["sdar_512x8_of_128", "lfm2_512x4_of_64", "qwen3_next_512x10_of_128",
+        "glm5_32768x8_of_16"])
 def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
                                                 hidden, width):
     """Mosaic takes ``ops.grouped_ffn`` at the three sparse cells' widths
@@ -958,11 +960,13 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
     worker loads the library).  Two whole experts in VMEM, or two width
     tiles of 512 where an expert is 75.5 MB; the program holds the bank as
     it came in and nothing of ``(rows, experts, width)``, the other form's
-    intermediate, nor, where the pairs exceed a window, anything of
-    ``rows x k`` rows (262,144 x 6,144 would be 3.2 GB): the held pairs go
-    a window at a time under a loop, and ``grouped_expert_ffn_rows`` adds a
-    window's rows of 6,144 into the rows' order (row tiles of 256, token
-    tiles of 512)."""
+    intermediate, nor anything of ``rows x k`` rows: where the pairs fit
+    one window the rows and their float32 sum stay in VMEM for the call
+    (``grouped_expert_ffn_resident``: one kernel, a float32 copy of the
+    rows beside it, no gather of rows); where they exceed it (262,144 x
+    6,144 would be 3.2 GB) the held pairs go a window at a time under a
+    loop, and ``grouped_expert_ffn_rows`` adds a window's rows of 6,144
+    into the rows' order (row tiles of 256, token tiles of 512)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     from mxnet_tpu.ops import grouped_ffn
@@ -988,7 +992,7 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
         cc.reset_cache()
     text = compiled.as_text()
     tm, _ = grouped_ffn.tiles(hidden, width)
-    windows = grouped_ffn.window_pairs(rows, k, hidden, tm) < rows * k
+    windows = grouped_ffn.rows_form(rows, k, hidden, width) == "kernel"
     names = [ln.split("=")[0].strip() for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(names) == (2 if windows else 1)
@@ -1007,7 +1011,11 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, rows, k, held,
         assert f"[{rows * k}," not in text and f"[{rows * k}]" not in text
         assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
     else:
-        assert f"f32[{rows * k},{hidden}]" in text      # one window
+        assert "grouped_expert_ffn_resident" in text
+        assert f"[{rows * k},{hidden}]" not in text     # no row a pair
+        assert f"f32[{rows},{hidden}]" in text          # the rows, once
+        assert not [ln for ln in text.splitlines()
+                    if " gather(" in ln and f",{hidden}]" in ln.split("=")[1]]
 
 
 def test_latent_sparse_programs_compile_for_v5e_without_whole_arrays(one_chip):

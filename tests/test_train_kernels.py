@@ -275,24 +275,207 @@ def test_grouped_backward_matches_every_expert(n, window, token_tile, dead):
         assert n % token_tile
 
 
-def test_the_one_window_call_is_the_parents_program():
-    """The served steps' form (every pair in one window: the two sparse
-    chat cells' 512 rows a call) is not this PR's: its jaxpr, the kernel's
-    with it, is the one of the commit before (sha256, 16 hex digits, at
-    ``sdar_30b``'s shapes)."""
+#: the four served banks whose 512-row calls fit one window, cut down in
+#: rows and to widths of a lane tile or two (the published widths run on
+#: the chip, ``tests_tpu/test_grouped_ffn_tpu.py``, and compile for a v5e
+#: in ``tests/test_paged_attention.py``): the router's experts, the part
+#: held here, experts a row, the router's width, the experts' (a latent
+#: layer's differ), an expert's width, the scores, the expert's kind
+SERVED = {
+    "sdar_30b": dict(e=128, held=(0, 128), k=8, wide=128, h=128, i=128,
+                     score="softmax", kind="swiglu"),
+    "lfm2_24b": dict(e=64, held=(0, 64), k=4, wide=128, h=128, i=256,
+                     score="sigmoid", kind="swiglu"),
+    "qwen3_next": dict(e=512, held=(128, 128), k=10, wide=128, h=128, i=128,
+                       score="softmax", kind="swiglu"),
+    "nemotron3_super": dict(e=512, held=(384, 128), k=22, wide=256, h=128,
+                            i=256, score="sigmoid", kind="relu2"),
+}
+
+
+def _plain(x, idx, w, bank, live, kind):
+    """A row at a time, a pair at a time, float64."""
+    held = bank[1].shape[0]
+    wg, wu, wd = (None if a is None else np.asarray(a, np.float64)
+                  for a in bank)
+    x, out = np.asarray(x, np.float64), np.zeros(x.shape, np.float64)
+    for r in range(x.shape[0]):
+        for e, we in zip(np.asarray(idx)[r], np.asarray(w, np.float64)[r]):
+            if not live[r] or not 0 <= e < held:
+                continue
+            if kind == "relu2":
+                act = np.maximum(x[r] @ wu[e], 0.0) ** 2
+            else:
+                g = x[r] @ wg[e]
+                act = g / (1.0 + np.exp(-g)) * (x[r] @ wu[e])
+            out[r] += we * (act @ wd[e])
+    return out
+
+
+@pytest.mark.parametrize("routing", [
+    "even", "one_expert_on_every_row", "an_expert_without_a_row",
+    "dead_rows", "pairs_off_the_row_tile"])
+@pytest.mark.parametrize("bank", sorted(SERVED))
+def test_the_resident_form_matches_every_expert_and_a_plain_sum(bank,
+                                                                routing):
+    """A call whose pairs fit one window (the rows and their float32 sum
+    in VMEM, ``grouped_expert_ffn_resident``) against ``routed_ffn``'s
+    ``every_expert`` form, which a CPU takes, and against a plain float64
+    sum a pair at a time: 64 rows whose ``N x k`` pairs are whole row
+    tiles, and 45 whose are not; an expert every row holds (its group
+    runs over tiles), one nobody holds, rows no request owns."""
+    b = SERVED[bank]
+    n = 45 if routing == "pairs_off_the_row_tile" else 64
+    first, held = b["held"]
+    relu2 = b["kind"] == "relu2"
+    x = jnp.asarray(rs.randn(n, b["wide"]), jnp.float32)
+    lat = jnp.asarray(rs.randn(n, b["h"]), jnp.float32) \
+        if b["h"] != b["wide"] else None
+    rw = jnp.asarray(rs.randn(b["e"], b["wide"]) * 0.3, jnp.float32)
+    wg, wu, wd = (jnp.asarray(rs.randn(*s) * 0.1, jnp.float32) for s in (
+        (held, b["h"], b["i"]), (held, b["h"], b["i"]),
+        (held, b["i"], b["h"])))
+    wg = None if relu2 else wg
+    bias = np.zeros(b["e"], np.float32)
+    if routing == "one_expert_on_every_row":
+        bias[first + 3] = 100.0
+    if routing == "an_expert_without_a_row":
+        bias[first + held - 2] = -100.0
+    live = rs.rand(n) > 0.3 if routing == "dead_rows" else np.ones(n, bool)
+    assert grouped_ffn.rows_form(n, b["k"], b["h"], b["i"], 4) == "resident"
+    assert moe.expert_product(n, b["k"], held, b["h"], b["i"],
+                              jnp.float32) == "every_expert"
+    every, counts = moe.routed_ffn(
+        x, rw, wg, wu, wd, b["k"], score=b["score"],
+        choice_bias=jnp.asarray(bias), experts_held=b["held"],
+        live=jnp.asarray(live), kind=b["kind"], rows=lat)
+    idx, w = moe.route(x, rw, b["k"], b["score"], jnp.asarray(bias))
+    rows = x if lat is None else lat
+    got = grouped_ffn.grouped_expert_ffn(
+        rows, idx - first, w, wg, wu, wd, jnp.asarray(live), interpret=True,
+        kind=b["kind"])
+    assert got.shape == rows.shape and got.dtype == rows.dtype
+    # what the case is there to meet, said of its own routing
+    here = np.asarray(counts)[first:first + held]
+    assert (n * b["k"] % grouped_ffn.ROW_TILE != 0) \
+        == (routing == "pairs_off_the_row_tile")
+    assert (here[3] == n) == (routing == "one_expert_on_every_row")
+    assert (here[held - 2] == 0) == (routing == "an_expert_without_a_row") \
+        or bank != "sdar_30b"
+    assert 0 < here.sum() <= live.sum() * b["k"]
+    # (a row no request owns: zero here, anything there, nobody reads it)
+    _close(np.asarray(got)[live], np.asarray(every)[live], 2e-5)
+    _close(got, _plain(rows, np.asarray(idx) - first, w, (wg, wu, wd), live,
+                       b["kind"]), 2e-5)
+    assert not np.asarray(got)[~live].any()
+
+
+@pytest.mark.parametrize("sizes", [
+    [3, 0, 8, 9, 17, 0, 1], [0, 0, 40], [0, 0, 0], [8, 8, 8, 8]],
+    ids=["mixed", "one_long_group", "no_pair_held", "whole_tiles"])
+def test_a_resident_visit_is_one_experts_pairs_wherever_they_lie(sizes):
+    """``_own_visits``: every listed pair in exactly one visit, a visit
+    one expert's and ``tm`` pairs at most, a group of up to ``tm`` pairs
+    one visit however it lies across the list's row tiles (the windowed
+    form's ``_visits`` gives it one for each tile it touches)."""
+    tm, held = 8, len(sizes)
+    m = sum(sizes)
+    key = np.repeat(np.arange(held), sizes)
+    key = np.concatenate([key, np.full(-(-(m + 5) // tm) * tm - m, held)])
+    eid, first, hi, total = (np.asarray(a) for a in grouped_ffn._own_visits(
+        jnp.asarray(key, jnp.int32), held, tm))
+    total = int(total[0])
+    assert total == sum(-(-s // tm) for s in sizes)
+    assert total <= int(grouped_ffn._visits(
+        jnp.asarray(key, jnp.int32), held, tm)[4][0])
+    assert len(eid) == len(key) // tm + held
+    pairs = [np.arange(first[v], hi[v]) for v in range(total)]
+    assert all(0 < len(p) <= tm for p in pairs)
+    assert all((key[p] == eid[v]).all() for v, p in enumerate(pairs))
+    assert (np.concatenate(pairs or [np.arange(0)]) == np.arange(m)).all()
+    # the static rest repeats the last visit: no new block is fetched
+    assert (eid[total:] == eid[max(total - 1, 0)]).all()
+
+
+def test_the_resident_form_in_bfloat16_sums_in_float32():
+    """The rows wait in VMEM as float32 (a one-row slice of packed bf16
+    is half a sublane) and are products' operands as bf16 again, exactly;
+    the sum over a row's ``k`` stays float32 to the one cast."""
+    b = SERVED["sdar_30b"]
+    n, held = 64, 128
+    x = jnp.asarray(rs.randn(n, b["h"]), jnp.bfloat16)
+    bank = [jnp.asarray(rs.randn(*s) * 0.1, jnp.bfloat16) for s in (
+        (held, b["h"], b["i"]), (held, b["h"], b["i"]), (held, b["i"], b["h"]))]
+    idx = jnp.asarray(np.argsort(-rs.randn(n, held), axis=1)[:, :8]
+                      .astype(np.int32))
+    w = jnp.asarray(rs.rand(n, 8), jnp.float32)
+    got = grouped_ffn.grouped_expert_ffn(x, idx, w, *bank, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    # float64 from the same bf16 values: the kernel rounds its
+    # activations to bf16 once and its result once
+    want = _plain(x.astype(jnp.float32), idx, w,
+                  [a.astype(jnp.float32) for a in bank], np.ones(n, bool),
+                  "swiglu")
+    _close(got.astype(jnp.float32), want, 2.0 ** -6)
+
+
+@pytest.mark.parametrize("rows,k,h,i,want", [
+    (512, 8, 2048, 768, "resident"), (512, 4, 2048, 1536, "resident"),
+    (512, 10, 2048, 512, "resident"), (512, 22, 1024, 2688, "resident"),
+    (2048, 8, 2048, 768, "resident"),     # 16,384 pairs: the last window
+    (2304, 8, 2048, 768, "kernel"),       # the pairs exceed a window
+    (16384, 8, 2048, 768, "kernel"), (32768, 8, 6144, 2048, "kernel"),
+    # one window, but an expert walked in width tiles: the tiles fill the cap
+    (512, 8, 6144, 2048, "kernel"), (512, 2, 4096, 14336, "kernel"),
+], ids=["sdar_30b", "lfm2_24b", "qwen3_next", "nemotron3_super",
+        "a_full_window", "past_a_window", "joyai_flash_trained",
+        "glm5_long_prefill", "glm5_512_rows", "mixtral_512_rows"])
+def test_the_rule_of_shapes_picks_where_the_rows_cross(rows, k, h, i, want):
+    """``rows_form``: in VMEM where every pair fits one window and the
+    rows, their float32 sum and the kernel's blocks fit the cap, else a
+    window at a time through ``grouped_expert_ffn_rows``."""
+    assert grouped_ffn.rows_form(rows, k, h, i) == want
+    tm, wt = grouped_ffn.tiles(h, i)
+    one = -(-rows * k // tm) * tm <= grouped_ffn.window_pairs(rows, k, h, tm)
+    fits = grouped_ffn._resident_vmem_bytes(rows, h, wt, 2, tm, wt < i) \
+        <= grouped_ffn._VMEM_CAP
+    assert (want == "resident") == (one and fits)
+
+
+#: sha256 (16 hex digits) of the jaxprs (source locations taken out) of the
+#: windowed form on the commit before the resident form (PR 47, 8efc484):
+#: the trained layer's forward and its gradients, the long prefill's
+#: forward.  Their programs are the parent's to the letter.
+PARENT_WINDOWED = {
+    "joyai_flash_trained": ((16384, 8, 16, 2048, 768), "b6c120d36cdf064e"),
+    "joyai_flash_trained_grad": ((16384, 8, 16, 2048, 768),
+                                 "2259e0de4f2df175"),
+    "glm5_long_prefill": ((32768, 8, 16, 6144, 2048), "08a97c9d88b6cdfc"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_WINDOWED))
+def test_the_windowed_calls_are_the_parents_programs(cell):
+    """The calls whose pairs exceed a window (``joyai_flash.pretrain_s4k``,
+    ``glm5.longdoc_prefill``) are not this PR's."""
     sds = jax.ShapeDtypeStruct
     bf = jnp.bfloat16
-    rows, k, held, h, i = 512, 8, 128, 2048, 768
+    (rows, k, held, h, i), want = PARENT_WINDOWED[cell]
+    avals = (sds((rows, h), bf), sds((rows, k), jnp.int32),
+             sds((rows, k), jnp.float32), sds((held, h, i), bf),
+             sds((held, h, i), bf), sds((held, i, h), bf))
+    fn = grouped_ffn.grouped_expert_ffn
+    if cell.endswith("_grad"):
+        fn = jax.grad(lambda *a: grouped_ffn.grouped_expert_ffn(*a)
+                      .astype(jnp.float32).sum(), argnums=(0, 2, 3, 4, 5))
+    else:
+        avals += (sds((rows,), jnp.bool_),)
     with jax.enable_x64(False):
-        text = str(jax.make_jaxpr(grouped_ffn.grouped_expert_ffn)(
-            sds((rows, h), bf), sds((rows, k), jnp.int32),
-            sds((rows, k), jnp.float32), sds((held, h, i), bf),
-            sds((held, h, i), bf), sds((held, i, h), bf),
-            sds((rows,), jnp.bool_)))
+        text = str(jax.make_jaxpr(fn)(*avals))
     text = re.sub(r" at [^\s\]]+:\d+", "", text)
-    assert "grouped_expert_ffn_rows" not in text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == "54e7a95df6f2f3f2"
+    assert "grouped_expert_ffn_rows" in text
+    assert "grouped_expert_ffn_resident" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 
 @pytest.mark.parametrize("rows,k,held,h,i,want", [
@@ -301,12 +484,13 @@ def test_the_one_window_call_is_the_parents_program():
     (32768, 8, 16, 6144, 2048,
      dict(fwd=5376, form="kernel", token_tile=512, row_tile=None)),
     (512, 8, 128, 2048, 768,
-     dict(fwd=4096, form="xla", token_tile=512, row_tile=128)),
+     dict(fwd=4096, form="resident", token_tile=512, row_tile=128)),
 ], ids=["joyai_flash_trained", "glm5_long_prefill", "sdar_block_pass"])
 def test_the_gauges_say_what_the_shapes_chose(rows, k, held, h, i, want):
     """Where the program is traced: the forward's and the backward's
     window, whether the rows cross in ``grouped_expert_ffn_rows`` or in
-    XLA, and the token tile, at the three sparse cells' shapes."""
+    VMEM inside the one kernel, and the token tile, at the three sparse
+    cells' shapes."""
     sds = jax.ShapeDtypeStruct
     bf = jnp.bfloat16
     avals = (sds((rows, h), bf), sds((rows, k), jnp.int32),
@@ -331,7 +515,7 @@ def test_the_gauges_say_what_the_shapes_chose(rows, k, held, h, i, want):
             telemetry.disable()
     assert got["grouped_ffn.fwd.window_pairs"] == want["fwd"]
     assert got["grouped_ffn.rows_form"] == want["form"]
-    # (a one-window forward sets none; its backward lists the held pairs)
+    # (a resident forward sets none; its backward lists the held pairs)
     assert got.get("grouped_ffn.token_tile") == want["token_tile"]
     if want["row_tile"]:
         assert got["grouped_ffn.bwd.row_tile"] == want["row_tile"]

@@ -3,7 +3,10 @@
 layer's bank at the two sparse cells' published widths, bf16: SDAR-30B-A3B's
 128 experts of 768, 8 a row, and LFM2-24B-A2B's 64 of 1,536, 4 a row behind
 sigmoid scores and a choice bias; 512 rows a call (SDAR's block pass, both
-models' longest chat prefill bucket) and 397 (its pairs fill no whole row tile);
+models' longest chat prefill bucket) and 397 (its pairs fill no whole row tile):
+calls whose pairs fit one window, the rows and their float32 sum in VMEM
+(``grouped_expert_ffn_resident``), as is Nemotron 3 Super's 512-row bucket (128
+of 512 ``"relu2"`` experts of 2,688 over latent rows of 1,024, 22 a row);
 and one chip's 16 of GLM-5's 256 experts of 6,144 x 2,048, 8 a row, at 8,192
 rows: an expert walked in width tiles, the held pairs in windows, the padded
 end of the bucket left out; beside it the lowered text of the 32,768 bucket,
@@ -81,6 +84,80 @@ def test_grouped_kernel_matches_every_expert_on_every_row(bank, rows,
                       f"{bank}_{rows}_{'all' if held is None else 'half'}",
                       err)
         assert err < 4 * EPS, (bank, rows, held, err)
+
+
+#: the served calls whose pairs fit one window, 512 rows each: a bank's
+#: router ``e`` experts of which ``held`` lie here, ``latent`` the width the
+#: experts multiply in where it is not the router's ``h``
+SERVED = {
+    "sdar": dict(e=128, held=128, k=8, h=2048, latent=None, i=768,
+                 score="softmax", kind="swiglu"),
+    "lfm2": dict(e=64, held=64, k=4, h=2048, latent=None, i=1536,
+                 score="sigmoid", kind="swiglu"),
+    "nemotron3_super": dict(e=512, held=128, k=22, h=4096, latent=1024,
+                            i=2688, score="sigmoid", kind="relu2"),
+}
+
+
+@pytest.mark.parametrize("rows", [512, 128])
+@pytest.mark.parametrize("bank", sorted(SERVED))
+def test_the_resident_form_at_the_served_calls(bank, rows, parity_record,
+                                               monkeypatch):
+    """A call whose pairs fit one window keeps its rows and their float32
+    sum in VMEM (``grouped_expert_ffn_resident``): against every held
+    expert on every row at three served banks, 512 rows a call (a block
+    pass, the longest chat prefill bucket) and 128 (a step of 128 slots:
+    under ``GROUPED_MIN_ROWS``, so the form is asked for by name),
+    and the compiled program holds nothing of ``rows x k`` rows of the
+    hidden width: no gather of them, no float32 pairs."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import moe
+    from mxnet_tpu.ops import grouped_ffn
+
+    b = SERVED[bank]
+    e, held, k, i = b["e"], b["held"], b["k"], b["i"]
+    wide, h = b["h"], b["latent"] or b["h"]
+    relu2 = b["kind"] == "relu2"
+    assert grouped_ffn.rows_form(rows, k, h, i) == "resident"
+    assert (moe.expert_product(rows, k, held, h, i, jnp.bfloat16)
+            == "grouped_kernel") == (rows >= grouped_ffn.GROUPED_MIN_ROWS)
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    bf = jnp.bfloat16
+    x = jax.random.normal(keys[0], (rows, wide), bf)
+    lat = jax.random.normal(keys[5], (rows, h), bf) if h != wide else None
+    rw = jax.random.normal(keys[1], (e, wide), bf) * 0.02
+    wg = None if relu2 else jax.random.normal(keys[2], (held, h, i), bf) * 0.02
+    wu = jax.random.normal(keys[3], (held, h, i), bf) * 0.02
+    wd = jax.random.normal(keys[4], (held, i, h), bf) * 0.02
+    live = jnp.arange(rows) % 7 != 0
+
+    def build():
+        # traced where it is called: the form is read once a program
+        return jax.jit(lambda x, lat, rw, wg, wu, wd: moe.routed_ffn(
+            x, rw, wg, wu, wd, k, score=b["score"], experts_held=(0, held),
+            live=live, kind=b["kind"], rows=lat)[0])
+
+    with monkeypatch.context() as patch:
+        # (128 rows lie under ``GROUPED_MIN_ROWS``: asked for by name)
+        patch.setattr(moe, "expert_product", lambda *a: "grouped_kernel")
+        fn = build()
+        text = fn.lower(x, lat, rw, wg, wu, wd).compile().as_text()
+        y = fn(x, lat, rw, wg, wu, wd)
+    assert "grouped_expert_ffn_resident" in text
+    assert f"[{rows * k},{h}]" not in text, "an array of every pair's row"
+    assert f"[{rows},{held},{i}]" not in text
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "expert_product", lambda *a: "every_expert")
+        yw = build()(x, lat, rw, wg, wu, wd)
+    y, yw = np.asarray(y, np.float32), np.asarray(yw, np.float32)
+    owned = np.asarray(live)
+    assert np.isfinite(y).all() and np.abs(yw).max() > 0
+    assert not y[~owned].any()
+    err = float(np.abs(y[owned] - yw[owned]).max() / np.abs(yw[owned]).max())
+    parity_record("grouped_expert_ffn", f"{bank}_{rows}_resident", err)
+    assert err < 4 * EPS, (bank, rows, err)
 
 
 def test_a_wide_bank_that_holds_a_part_of_the_router_at_a_long_prefill(

@@ -11,6 +11,8 @@ the next one's input, so nothing is hoisted), the median of 7.
         --width 768 --rows 128,256,512,2048            # SDAR-30B-A3B
     chiprun -- python tools/routed_ffn_bench.py --experts 64 --k 4 \
         --width 1536 --score sigmoid --rows 128,256,512,2048   # LFM2-24B-A2B
+    chiprun -- python tools/routed_ffn_bench.py --router-experts 512 \
+        --held 128 --k 10 --width 512 --rows 128,256,384,512  # Qwen3-Next, 1 of 4
     chiprun -- python tools/routed_ffn_bench.py --router-experts 256 \
         --held 16 --k 8 --hidden 6144 --width 2048 --score sigmoid \
         --rows 8192,16384,32768 --tiles 128,512x256,256@5376  # GLM-5, 1 of 16
@@ -32,16 +34,22 @@ whole layer with its routing, the kernel at each ``--tiles`` entry (a row
 tile, ``rows x width tile``, or either ``@`` a window of sorted pairs), which form ``moe.expert_product`` picks there
 with the tiles and the window the shapes give, and what the one form's
 operations (every held expert on every row) and the bank's bytes come to.
-With ``--parts`` a second line a size: the milliseconds of each piece of ONE
+With ``--parts`` a second line a size.  Where the call's pairs fit one window
+(``ops.grouped_ffn.rows_form`` says ``"resident"``: a served step's rows) the
+pieces of that call jitted alone: the listing by a sort of the ``N x k`` keys
+(the program's) beside the same pairs listed by count (``_held_pairs``), the
+visits, the kernel over a finished listing, what is left in XLA (the rows'
+float32 copy), and the whole call under either listing.  Else the milliseconds
+of each piece of ONE
 window of the windowed form, jitted alone at the window's shapes (the listing,
 the rows in, each kernel, the combine ``grouped_expert_ffn_rows`` beside XLA's
 scatter-add of the same rows, the combine weights' gradient), the router's
 pieces around the layer, and the whole call forward and forward + backward;
 ``dispatch_floor_ms`` is what an empty program reads on this host, under which
 a piece cannot be told from nothing.
-The tables that set ``ops.grouped_ffn.GROUPED_MIN_ROWS``, ``ROW_TILE`` (PERF.md,
-PR 31), ``ROW_TILE_WALKED`` (PR 33) and ``_ONE_WINDOW_BYTES`` (PR 46) are this tool's kind
-of output.
+The tables that set ``ops.grouped_ffn.GROUPED_MIN_ROWS`` (PERF.md, PRs 31 and
+48), ``ROW_TILE`` (PR 31), ``ROW_TILE_WALKED`` (PR 33) and ``_ONE_WINDOW_BYTES``
+(PR 46) are this tool's kind of output.
 """
 import argparse
 import json
@@ -235,10 +243,14 @@ def parts(args, x, bank, held):
             times.append((time.perf_counter() - t) / 4 * 1e3)
         return round(statistics.median(times), 4), out
 
-    row = {"parts_of": "one window", "device": jax.devices()[0].device_kind,
+    resident = g.rows_form(n, k, h, wg.shape[2]) == "resident"
+    row = {"parts_of": "the resident call" if resident else "one window",
+           "device": jax.devices()[0].device_kind,
            "rows": n, "window_pairs": win,
            "row_tile": tm, "token_tile": tt, "chunk": c}
     row["dispatch_floor_ms"], _ = ms(lambda a: a + 1, jnp.zeros((8,)))
+    if resident:
+        return parts_resident(args, row, ms, x, bank, held, tm, wt)
     # the router around the layer, as ``routed_ffn`` runs it
     row["route_ms"], (idx, w) = ms(
         lambda x, rw: moe.route(x, rw, k, args.score), x, rw)
@@ -289,6 +301,51 @@ def parts(args, x, bank, held):
         row["whole_fwd_bwd_ms"], _ = ms(jax.grad(
             lambda x, w, *b: whole(x, idx, w, *b).astype(jnp.float32).sum(),
             argnums=(0, 1, 2, 3, 4)), x, w, wg, wu, wd)
+    return row
+
+
+def parts_resident(args, row, ms, x, bank, held, tm, wt):
+    """The pieces of a call whose pairs fit one window (see the module
+    text)."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.models import moe
+    from mxnet_tpu.ops import grouped_ffn as g
+
+    rw, wg, wu, wd = bank
+    n, k = x.shape[0], args.k
+    mp = -(-n * k // tm) * tm
+    row["route_ms"], (idx, w) = ms(
+        lambda x, rw: moe.route(x, rw, k, args.score), x, rw)
+
+    def by_sort(idx, w):
+        return g._sorted_pairs(idx, w, held, None, tm)
+
+    def by_count(idx, w):
+        _, window, _ = g._held_pairs(idx, held, None, n)
+        rows, key, slot = window(0, mp)
+        return rows, key, jnp.where(slot, w.astype(jnp.float32)[rows],
+                                    0.0).sum(axis=1)
+
+    row["listing_sort_ms"], (rows, key, ws) = ms(by_sort, idx, w)
+    row["listing_count_ms"], counted = ms(by_count, idx, w)
+    row["pairs_held"] = int((key < held).sum())
+    row["listings_agree"] = all(
+        bool((a[:row["pairs_held"]] == b[:row["pairs_held"]]).all())
+        for a, b in zip((rows, key, ws), counted))
+    row["visits_ms"], visits = ms(
+        lambda key: g._own_visits(key, held, tm), key)
+    row["visits"] = int(visits[3][0])
+    row["rows_to_float32_ms"], _ = ms(lambda x: x.astype(jnp.float32), x)
+
+    def kernel(x, rows, key, ws, *b):
+        return g._resident_visits(x, rows, key, ws, b, tm, wt, False)
+
+    row["kernel_with_visits_ms"], _ = ms(kernel, x, rows, key, ws, wg, wu, wd)
+    row["whole_fwd_ms"], _ = ms(g.grouped_expert_ffn, x, idx, w, wg, wu, wd)
+    row["whole_fwd_listed_by_count_ms"], _ = ms(
+        lambda x, idx, w, *b: kernel(x, *by_count(idx, w), *b),
+        x, idx, w, wg, wu, wd)
     return row
 
 
