@@ -78,6 +78,7 @@ from jax.profiler import TraceAnnotation
 from .. import telemetry
 from ..telemetry import numerics as _numerics
 from ..telemetry import retrace as _retrace
+from ..telemetry import tracing
 from ..base import MXNetError
 from ..ops import flash_attention
 from .scheduler import _materialize
@@ -276,12 +277,13 @@ class StepHandle:
     it reads the step's own and not the engine's newest."""
 
     __slots__ = ("seq", "active", "toks", "selected", "behind", "ahead",
-                 "t_lock", "t_disp0", "t_disp1", "t_tok", "pos",
+                 "t_lock", "t_disp0", "t_disp1", "t_tok", "c_disp1",
+                 "c_tok", "pos",
                  "kv_tokens", "selection", "experts", "writes")
 
     def __init__(self, seq, active, toks, t_lock, t_disp0, t_disp1,
                  behind=(), ahead=False, selected=None, pos=None,
-                 kv_tokens=0, selection=None, writes=None):
+                 kv_tokens=0, selection=None, writes=None, c_disp1=None):
         self.seq = seq            #: ``engine.steps`` as it was dispatched
         self.active = active      #: the slots it advances
         self.toks = toks          #: what the host fetches, on the device
@@ -297,6 +299,11 @@ class StepHandle:
         #: lock released; ``t_tok``: its tokens on the host (fetch_step)
         self.t_lock, self.t_disp0, self.t_disp1 = t_lock, t_disp0, t_disp1
         self.t_tok = None
+        #: the two ends of the wait for its tokens on the CPU clock of the
+        #: thread that passed them (``tracing.clocks``): ``c_tok`` from
+        #: fetch_step; ``c_disp1`` from the lane, whose turn goes on
+        #: after the dispatch returns (``verify`` takes its own)
+        self.c_disp1, self.c_tok = c_disp1, None
         #: (S,) the cursors after it (a block decoder's move as it is
         #: booked: None), the K/V rows it attended (``pos + 1`` over the
         #: active slots) and a selecting model's ``kv_visible`` /
@@ -1155,7 +1162,7 @@ class LlamaServingEngine:
                 out = _materialize([step.toks])[0]
         finally:
             self._step_fetched(step.seq)
-        step.t_tok = time.perf_counter()
+        step.t_tok, step.c_tok = tracing.clocks()
         out, step.experts = self.split_fetch(out, self.num_slots)
         self.booked = step
         if self.block is not None:
@@ -1229,8 +1236,9 @@ class LlamaServingEngine:
             self.steps += 1
             seq = self.steps
         ahead, behind = self._step_queued(seq)
-        step = StepHandle(seq, None, out, t_lock, t_disp0,
-                          time.perf_counter(), behind=behind, ahead=ahead)
+        t_disp1, c_disp1 = tracing.clocks()
+        step = StepHandle(seq, None, out, t_lock, t_disp0, t_disp1,
+                          behind=behind, ahead=ahead, c_disp1=c_disp1)
         try:
             if lstats is not None:
                 _numerics.record_compiled(("serving.logits",), (lstats,))
@@ -1239,7 +1247,7 @@ class LlamaServingEngine:
                 out = _materialize([out])[0]
         finally:
             self._step_fetched(seq)
-        step.t_tok = time.perf_counter()
+        step.t_tok, step.c_tok = tracing.clocks()
         self.booked = step
         return out
 

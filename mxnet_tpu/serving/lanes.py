@@ -360,7 +360,7 @@ class PrefillLane:
         looked; closed here once the group is admitted."""
         r = self.r
         mgr = r.mgr
-        t_start = time.perf_counter()
+        t_start, c_start = tracing.clocks()
         self.clock.enter("busy", t_start)
         seq = self.clock.batches
         lb = self._bucket(group[0])
@@ -437,12 +437,12 @@ class PrefillLane:
                 # the step that was queued first, which it runs behind
                 eng.prefill_in_flight = (seq,)
                 behind_tick = eng.step_in_flight
-                t_disp1 = time.perf_counter()
+                t_disp1, c_disp1 = tracing.clocks()
                 with TraceAnnotation("mxt.prefill.fetch", seq=seq,
                                      replica=r.index):
                     first = _lane_materialize([toks])[0]
                 eng.prefill_in_flight = ()
-                t_ready = time.perf_counter()
+                t_ready, c_ready = tracing.clocks()
                 # a model with routed experts sends their row counts
                 # behind the first tokens, in the same fetch
                 first, extra = eng.split_fetch(first, kb) \
@@ -486,7 +486,7 @@ class PrefillLane:
                                       "lane": "prefill",
                                       "error": repr(exc)})
             return
-        t_first = time.perf_counter()
+        t_first, c_first = tracing.clocks()
         self.clock.enter("idle", t_first)
         mates = [req.id for req in group]
         # a block decoder's prefill stores the prompt's whole blocks and
@@ -498,14 +498,16 @@ class PrefillLane:
         # it does its attention (None: a model without experts)
         product = getattr(eng, "expert_product_at", None)
         # one stamp set for every consumer: the lane log, the capacity
-        # duty cycle and (below) the request's span tree
+        # duty cycle and (below) the request's span tree; the host
+        # part's four ends on this thread's CPU clock too (c_*)
         tracing.lane_record(
             "prefill.batch", replica=r.index, seq=seq,
             request_ids=tuple(mates),
             n_tokens=int(t0s_suf[:len(group)].sum()), bucket=(kb, lb),
             radix_hit_tokens=int(sum(matched)), t_start=t_start,
             t_disp1=t_disp1, t_ready=t_ready, t_lock=t_lock,
-            t_commit1=t_commit1, t_first=t_first,
+            t_commit1=t_commit1, t_first=t_first, c_start=c_start,
+            c_disp1=c_disp1, c_ready=c_ready, c_first=c_first,
             prefill_attention=attention, behind_tick=behind_tick,
             free_slots=free_slots, queued=queued,
             passes=getattr(getattr(eng, "cache_spec", None), "passes", 1),
@@ -569,10 +571,17 @@ class DecodeLane:
         self._stop = threading.Event()
         self._thread = None
         self.error = None
-        # the turn's first stamp and the hand-offs it adopted, set by
-        # _adopt for the tick's lane-log record and its turns'
-        self._t_loop = None
+        # the turn's first stamp (on the wall's clock and this thread's)
+        # and the hand-offs it adopted, set by _adopt for the tick's
+        # lane-log record and its turns'
+        self._t_loop = self._c_loop = None
         self._adopted = ()
+        # seconds the lane has waited with nothing to step since its
+        # last record, and the CPU seconds its polling took (the next
+        # record's ``idle_s`` and ``idle_cpu_s``); when the wait under
+        # way began, on both clocks
+        self._idle_s = self._idle_cpu_s = 0.0
+        self._idle_from = None
         # the token-at-a-time tick runs a step ahead of its bookkeeping:
         # the step it has queued and not yet booked, and when the last
         # booked step's tokens came (a step has the device from then)
@@ -670,10 +679,7 @@ class DecodeLane:
             elif self._stop.is_set():
                 break
             else:
-                with TraceAnnotation("mxt.decode.wait",
-                                     replica=self.r.index):
-                    self._wake.wait(self.poll_s)
-                self._wake.clear()
+                self._rest()
 
     def _adopt(self):
         """Pull every pending handoff into this lane's slot set.  The
@@ -684,7 +690,14 @@ class DecodeLane:
         of the lane's turn (``t_loop``) and keeps the hand-offs for the
         tick's record and their ``slot.turn`` records, which the tick
         writes once its ``t_tok`` is known."""
-        self._t_loop = time.perf_counter()
+        self._t_loop, self._c_loop = tracing.clocks()
+        if self._idle_from is not None:
+            # the wait ends where a turn begins: one stretch, however
+            # many polls it took
+            t0, c0 = self._idle_from
+            self._idle_from = None
+            self._idle_s += self._t_loop - t0
+            self._idle_cpu_s += self._c_loop - c0
         with self._hand_lock:
             taken = tuple(self._handoffs)
             self._handoffs.clear()
@@ -764,9 +777,13 @@ class DecodeLane:
         return active, len(parked)
 
     def _rest(self):
-        """A turn that found every slot parked and nothing to fetch:
-        only a hand-off (or a failed prefill's release) changes that,
-        so wait for one as an empty lane does."""
+        """Wait for a hand-off: an empty lane, or a turn that found
+        every slot parked and nothing to fetch, which only a hand-off
+        (or a failed prefill's release) changes.  The wait is no part
+        of a turn's host time: the next record says how long it was,
+        from here to the top of the next turn (:meth:`_adopt`)."""
+        if self._idle_from is None:
+            self._idle_from = tracing.clocks()
         with TraceAnnotation("mxt.decode.wait", replica=self.r.index):
             self._wake.wait(self.poll_s)
         self._wake.clear()
@@ -886,7 +903,7 @@ class DecodeLane:
         adopted, self._unstepped = self._unstepped + self._adopted, ()
         # the turn's own dispatch, for its record: an instant where it
         # queues nothing
-        t_lock = t_disp0 = t_disp1 = time.perf_counter()
+        t_lock = t_disp0 = None
         try:
             if active:
                 step = eng.dispatch_step(active)
@@ -899,12 +916,14 @@ class DecodeLane:
                 self._flight = _Flight(step, self._t_loop, ids,
                                        frozenset(ending), adopted,
                                        n_parked)
-                # the count is the host's work, not a wait for tokens: the
-                # turn's ``t_disp1`` is where the lane turns to the fetch
                 t_lock, t_disp0 = step.t_lock, step.t_disp0
-                t_disp1 = time.perf_counter()
             else:
                 self._unstepped = adopted
+            # the count is the host's work, not a wait for tokens: the
+            # turn's ``t_disp1`` is where the lane turns to the fetch
+            t_disp1, c_disp1 = tracing.clocks()
+            if not active:
+                t_lock = t_disp0 = t_disp1
             if prev is None:
                 if n_parked and not active:
                     self._rest()
@@ -924,7 +943,8 @@ class DecodeLane:
         ids, n_finished, extra = book(prev, out, t_busy0)
         self._record_tick(step, ids, n_finished, prev.adopted,
                           queued_at=prev.t_loop,
-                          turn=(self._t_loop, t_lock, t_disp0, t_disp1),
+                          turn=dict(t_lock=t_lock, t_disp0=t_disp0,
+                                    t_disp1=t_disp1, c_disp1=c_disp1),
                           n_parked=prev.parked, **extra,
                           **step.experts, **step.selection)
 
@@ -1035,11 +1055,16 @@ class DecodeLane:
         summed over its slots) and ``t_tok`` are the step's.  ``t_loop,
         t_lock, t_disp0, t_disp1`` are the stamps of the turn that
         fetched and booked it, in the order the lane thread passed them:
-        a tick that runs ahead passes them as ``turn`` (its dispatch was
-        of the NEXT step) and the top of the turn that queued the step
+        a tick that runs ahead passes its dispatch's (of the NEXT step)
+        as ``turn`` and the top of the turn that queued the step
         as ``queued_at``; for a serial tick they are the step's own,
         which every record also carries as ``t_step_loop``,
-        ``t_step_lock``, ``t_step_disp0``, ``t_step_disp1``.  The lane's
+        ``t_step_lock``, ``t_step_disp0``, ``t_step_disp1``.  The
+        turn's top and the two ends of its wait for the tokens are
+        taken on the lane thread's CPU clock too (``c_loop``,
+        ``c_disp1``, ``c_tok``: ``tracing.clocks``); ``idle_s`` is how
+        long the lane waited with nothing to step since its last record
+        and ``idle_cpu_s`` the CPU seconds its polling took.  The lane's
         first record also says which attention the engine's step program
         was built with, how many KV heads a stored pool row holds
         (``kv_pack``), which product its routed experts run
@@ -1049,13 +1074,15 @@ class DecodeLane:
         every record how many bytes of it the step read and wrote
         (``state_bytes``)."""
         r = self.r
-        t_lock, t_disp0, t_disp1, t_tok = (
-            step.t_lock, step.t_disp0, step.t_disp1, step.t_tok)
-        t_loop = self._t_loop if queued_at is None else queued_at
-        extra.update(t_step_loop=t_loop, t_step_lock=t_lock,
-                     t_step_disp0=t_disp0, t_step_disp1=t_disp1)
-        if turn is not None:
-            t_loop, t_lock, t_disp0, t_disp1 = turn
+        extra.update(
+            t_step_loop=self._t_loop if queued_at is None else queued_at,
+            t_step_lock=step.t_lock, t_step_disp0=step.t_disp0,
+            t_step_disp1=step.t_disp1)
+        if turn is None:
+            turn = {k: getattr(step, k) for k in (
+                "t_lock", "t_disp0", "t_disp1", "c_disp1")}
+        idle_s, self._idle_s = self._idle_s, 0.0
+        idle_cpu_s, self._idle_cpu_s = self._idle_cpu_s, 0.0
         extra.setdefault("kv_tokens", int(step.kv_tokens))
         # the K/V rows' bytes by the engine's spec, every pass counted,
         # and how much of the pool its requests hold (blocks granted)
@@ -1094,9 +1121,11 @@ class DecodeLane:
             "decode.tick", replica=r.index, seq=step.seq,
             n_active=len(ids), n_adopted=len(adopted),
             n_finished=n_finished, request_ids=ids,
-            behind=step.behind, ahead=step.ahead,
-            t_loop=t_loop, t_lock=t_lock, t_disp0=t_disp0, t_disp1=t_disp1,
-            t_tok=t_tok, t_book=time.perf_counter(), **extra)
+            behind=step.behind, ahead=step.ahead, idle_s=idle_s,
+            idle_cpu_s=idle_cpu_s,
+            t_loop=self._t_loop, c_loop=self._c_loop, **turn,
+            t_tok=step.t_tok, c_tok=step.c_tok,
+            t_book=time.perf_counter(), **extra)
         for h in adopted:
             # every stamp is one a boundary already took: the release's
             # ``t_done``, the batch's, the hand-off's, this step's
@@ -1106,7 +1135,8 @@ class DecodeLane:
                 request_id=h.req.id, batch=h.batch, tick=step.seq,
                 freed_by=freed_by, prev_request_id=prev_id, t_free=t_free,
                 t_start=h.req.t_start, t_first=h.req.t_commit,
-                t_handoff=h.t_handoff, t_adopt=h.req.t_handoff, t_tok=t_tok)
+                t_handoff=h.t_handoff, t_adopt=h.req.t_handoff,
+                t_tok=step.t_tok)
 
     def _tick_spec(self):
         """Speculative tick: k sequential DRAFT steps propose a window,

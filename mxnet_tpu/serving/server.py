@@ -37,6 +37,10 @@ from .scheduler import BatchScheduler, RequestQueue
 
 __all__ = ["ServerConfig", "InferenceServer", "GenerativeServer"]
 
+#: the stretch of the lane log that ``GenerativeServer.stats()`` looks
+#: through for stalled turns, seconds back from now
+STALLS_VIEW_S = 60.0
+
 
 class ServerConfig:
     """Knobs shared by both servers (defaults are test-scale).
@@ -637,9 +641,15 @@ class GenerativeServer(_ServerBase):
                         sum(r.mgr.allocator.blocks_in_use for r in reps))
         if capacity.is_enabled():
             out["capacity"] = [capacity.snapshot(r.index) for r in reps]
-        # always on: where each prefill lane's wall time went, and what
-        # growth on demand granted, parked and refused behind it
-        out["lanes"] = [dict(r.prefill.clock.snapshot(), **r.mgr.growth())
+        # always on: where each prefill lane's wall time went, what
+        # growth on demand granted, parked and refused behind it, and the
+        # turns of either lane that stalled in the last minute (a bounded
+        # stretch: a scrape must not cost what it reports)
+        stalled = tracing.stalls(since=time.perf_counter() - STALLS_VIEW_S)
+        out["lanes"] = [dict(r.prefill.clock.snapshot(), **r.mgr.growth(),
+                             stalls=tracing.stall_totals(
+                                 [s for s in stalled
+                                  if s["replica"] == r.index]))
                         for r in reps]
         if self.slo is not None:
             out["slo"] = self.slo.snapshot()

@@ -41,8 +41,13 @@ nothing else.  Completed traces go three places:
 The **lane log** (further down) is the always-on half: one bounded
 ring of coarse records — a decode tick, a prefill batch, a stretch the
 prefill lane spent gated, a slot's turn from its release to its next
-tick, a train dispatch — appended by the thread that did the work, from
-``perf_counter`` stamps taken once at each boundary.
+tick, a train dispatch, a pause of the garbage collector — appended by
+the thread that did the work, from ``perf_counter`` stamps taken once
+at each boundary.  A serving lane takes the stamps that bound a turn's
+host part on two clocks (:func:`clocks`): beside the wall's ``t_*`` the
+lane thread's own CPU seconds (``c_*``), so that :func:`stalls` can say
+of a turn that took far longer than its kind whether the collector ran,
+the lane computed or the lane was kept off the CPU.
 It needs no ``enable()``; the per-request span trees above stay opt-in.
 The same boundaries open ``jax.profiler.TraceAnnotation`` spans named
 ``mxt.*`` (one atomic load while no profile runs), which land on
@@ -58,18 +63,24 @@ enables at import.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
+import statistics
 import sys
 import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["enable", "disable", "is_enabled", "start_trace", "finish",
            "recent", "clear", "dump", "incident", "Trace",
            "RECORDER_CAPACITY", "lane_record", "lane_log", "lane_state",
-           "LaneClock", "LANE_LOG_CAPACITY", "LANE_TAIL"]
+           "LaneClock", "LANE_LOG_CAPACITY", "LANE_TAIL", "clocks",
+           "gc_stats", "stalls", "stall_totals", "GC_PAUSE_MIN_S",
+           "STALL_MIN_S", "STALL_CAUSES"]
 
 # -- state -------------------------------------------------------------------
 
@@ -316,7 +327,22 @@ _LANE_SPAN = {"decode.tick": ("t_loop", "t_book"),
               "prefill.batch": ("t_start", "t_first"),
               "prefill.gated": ("t0", "t1"),
               "slot.turn": ("t_start", "t_tok"),
-              "train.dispatch": ("t0", "t_end")}
+              "train.dispatch": ("t0", "t_end"),
+              "gc.pause": ("t0", "t1")}
+
+
+def clocks():
+    """Now, on the two clocks a serving lane stamps the ends of a turn's
+    host part with -> ``(perf_counter, thread_time)``: the wall's
+    seconds (a record's ``t_*``) and the CPU seconds of the CALLING
+    thread (its ``c_*``).  Between two stamps of one thread the wall's
+    difference less the CPU's is the time that thread did not run: it
+    waited for the device, a lock or the interpreter, or was not
+    scheduled.  Only the stamps that :func:`stalls` or a reader of the
+    benchmark takes a difference of are taken on both (``thread_time``
+    is a system call, some 30 us in a serving process on a chip's host:
+    PERF.md, PR 49)."""
+    return time.perf_counter(), time.thread_time()
 
 
 def lane_record(kind, **fields):
@@ -410,6 +436,208 @@ def lane_state(replica=0):
     stretches per reason and of batches; None before any server."""
     clock = _lane_clocks.get(replica)
     return None if clock is None else clock.snapshot()
+
+
+# -- the collector's pauses ------------------------------------------------
+
+#: a collection this long or longer is written to the lane log
+GC_PAUSE_MIN_S = 1e-3
+
+_gc_totals = {"runs": 0, "seconds": 0.0}
+_gc_open = None     # (perf_counter, span) of the collection under way
+
+
+def _on_gc(phase, info):
+    """The module's one ``gc.callbacks`` entry, always on as the lane
+    log is.  The interpreter calls it in the thread that set the
+    collection off, which holds the interpreter's lock throughout: every
+    Python thread of the process stands still from ``start`` to
+    ``stop``.  Every collection counts (:func:`gc_stats`) and lies
+    under an ``mxt.gc.pause`` span; one of :data:`GC_PAUSE_MIN_S` or
+    longer is also a ``gc.pause`` lane record."""
+    global _gc_open
+    if phase == "start":
+        span = TraceAnnotation("mxt.gc.pause",
+                               generation=info["generation"])
+        span.__enter__()
+        _gc_open = (time.perf_counter(), span)
+    elif _gc_open is not None:
+        t1 = time.perf_counter()
+        (t0, span), _gc_open = _gc_open, None
+        span.__exit__(None, None, None)
+        _gc_totals["runs"] += 1
+        _gc_totals["seconds"] += t1 - t0
+        if t1 - t0 >= GC_PAUSE_MIN_S:
+            lane_record("gc.pause", t0=t0, t1=t1,
+                        generation=info["generation"],
+                        collected=info["collected"],
+                        thread=threading.current_thread().name)
+
+
+gc.callbacks.append(_on_gc)
+
+
+def gc_stats():
+    """Collections since import and the seconds they took, the short
+    ones too: ``{"runs", "seconds"}``."""
+    return dict(_gc_totals)
+
+
+# -- stalls ----------------------------------------------------------------
+
+#: a turn whose host part passes the median of its kind by more than this
+#: has stalled.  The shortest stall on record is 55 ms; the host of a chip
+#: counts CPU seconds in ticks of 10 ms, so half of a stall is more than
+#: one stray tick from here on, and a lane's own tail (a batch's commit
+#: reads up to 16 ms over its median) lies below it (PERF.md, PR 49)
+STALL_MIN_S = 0.020
+#: in the order they are tried
+STALL_CAUSES = ("gc", "own", "offcpu")
+#: turns of a kind under which their median says nothing
+_STALL_MIN_TURNS = 5
+# a kind's wall stamps in the order its thread passes them (a decode turn
+# ends at the next turn's first), the phases between them, and those of
+# them that are taken on the thread's CPU clock too: the host part's
+# ends (a turn's second is the next turn's first) and the two ends of
+# the wait for the device inside it
+_TICK_STAMPS = ("loop", "lock", "disp0", "disp1", "tok", "book")
+_TICK_PHASES = ("adopt", "lock", "dispatch", "fetch", "book", "tail")
+_TICK_CPU = ("loop", "disp1", "tok")
+_BATCH_STAMPS = ("start", "disp1", "ready", "lock", "commit1", "first")
+_BATCH_PHASES = ("dispatch", "fetch", "ready", "commit", "tail")
+_BATCH_CPU = ("start", "disp1", "ready", "first")
+
+
+def _edges(rec, clock, stamps):
+    """``rec``'s ``stamps`` on ``clock`` (``"t"`` / ``"c"``), or None
+    where one is missing (an older record, a test's stub engine)."""
+    out = [rec.get(f"{clock}_{s}") for s in stamps]
+    return None if None in out else out
+
+
+def _turns(since, until):
+    """The lane log's decode turns and prefill batches that began in
+    ``[since, until)`` and carry both clocks -> ``[(lane, phases,
+    [turn])]``, a turn its wall edges (``t``; the lane's wait with
+    nothing to step ``idle``) and the lane thread's CPU seconds over its
+    host part (``cpu``)."""
+    by_replica, turns, batches = {}, [], []
+    for rec in lane_log("decode.tick", since, until):
+        by_replica.setdefault(rec["replica"], []).append(rec)
+    for recs in by_replica.values():      # each in log order: its lane's own
+        for a, b in zip(recs, recs[1:]):
+            t = _edges(a, "t", _TICK_STAMPS)
+            c = _edges(a, "c", _TICK_CPU)
+            if b["seq"] != a["seq"] + 1 or None in (t, c, b.get("c_loop")) \
+                    or (since is not None and a["t_loop"] < since):
+                continue
+            turns.append(dict(
+                rec=a, t=t + [b["t_loop"]], idle=b.get("idle_s", 0.0),
+                cpu=(b["c_loop"] - c[0]) - (c[2] - c[1])
+                - b.get("idle_cpu_s", 0.0)))
+    for rec in lane_log("prefill.batch", since, until):
+        t = _edges(rec, "t", _BATCH_STAMPS)
+        c = _edges(rec, "c", _BATCH_CPU)
+        if None in (t, c) or (since is not None and rec["t_start"] < since):
+            continue
+        batches.append(dict(rec=rec, t=t, idle=0.0,
+                            cpu=(c[3] - c[0]) - (c[2] - c[1])))
+    return [("decode", _TICK_PHASES, turns),
+            ("prefill", _BATCH_PHASES, batches)]
+
+
+def stalls(since=None, until=None):
+    """The decode turns and prefill batches of the lane log (those that
+    began in ``[since, until)``, ``perf_counter`` seconds) that took the
+    host far longer than their kind, each with a cause -> a list of
+    dicts, oldest first: ``lane`` (``"decode"`` / ``"prefill"``),
+    ``replica``, ``seq``, ``phase``, ``t0``, ``wall_ms``, ``cpu_ms``,
+    ``cause``.  The one place the rule and its constants live:
+    ``server.stats()["lanes"][i]["stalls"]`` and the benchmark's
+    ``stall_share.*`` readers call it.
+
+    A turn's **host part** is its period less its wait for the device's
+    tokens: for a decode turn ``(next t_loop - t_loop) - (t_tok -
+    t_disp1)``, less ``idle_s`` (and ``idle_cpu_s`` of its CPU seconds)
+    where the lane waited with nothing to step; for a batch ``(t_first
+    - t_start) - (t_ready - t_disp1)``.  A turn **stalled** where its
+    host part passes the median of its kind over the asked stretch by
+    more than :data:`STALL_MIN_S`; the excess is the stall's length
+    (``wall_ms``), ``phase`` the phase with the
+    largest excess over that phase's own median (``adopt``, ``lock``,
+    ``dispatch``, ``book``, ``tail``; a batch's ``dispatch``, ``ready``,
+    ``commit``, ``tail``) and ``t0`` that phase's first stamp.  Its
+    cause is the first of these that holds:
+
+    * ``gc``: ``gc.pause`` records, of any thread, overlap the host
+      part for at least half of the stall;
+    * ``own``: the lane thread's own CPU seconds over the host part
+      (``c_*`` of the same four stamps) pass their mean over the
+      stretch's turns by at least half of the stall (``cpu_ms``): the
+      lane computed, a long booking, a manager's count, a trace;
+    * ``offcpu``: what is left.  The lane did not run and no collection
+      did: another thread of the process held the interpreter's lock or
+      a lock of the runtime (the other lane, a client thread, the
+      profiler), or the machine ran nothing of the process (the host's
+      other tenants, a throttled cgroup).  The process's own CPU clock
+      does not tell the two apart where it ticks at 10 ms (PERF.md,
+      PR 49), so neither is claimed.
+
+    The thread's CPU clock may tick coarsely (10 ms on a chip's host),
+    so what a turn is held against is the mean over the stretch, never
+    a median of single turns' CPU seconds, which reads 0 or a tick.
+
+    **What it cannot see**: a stall inside the wait for the device with
+    nothing queued ahead cannot be told from a slow step on these
+    clocks, and is not counted.  Records without the ``c_*`` fields,
+    and a kind with fewer than five turns, give nothing."""
+    pauses = [(r["t0"], r["t1"]) for r in lane_log("gc.pause", since, until)]
+    found = []
+    for lane, phases, turns in _turns(since, until):
+        if len(turns) < _STALL_MIN_TURNS:
+            continue
+        wait = phases.index("fetch")
+        for u in turns:
+            wall = [t1 - t0 for t0, t1 in zip(u["t"], u["t"][1:])]
+            wall[-1] -= u["idle"]
+            u["wall"], u["host"] = wall, sum(wall) - wall[wait]
+        med_host = statistics.median(u["host"] for u in turns)
+        mean_cpu = statistics.fmean(u["cpu"] for u in turns)
+        med_wall = [statistics.median(u["wall"][i] for u in turns)
+                    for i in range(len(phases))]
+        for u in turns:
+            excess = u["host"] - med_host
+            if excess <= STALL_MIN_S:
+                continue
+            over = [w - m for w, m in zip(u["wall"], med_wall)]
+            over[wait] = float("-inf")
+            at = over.index(max(over))
+            t = u["t"]
+            paused = sum(max(0.0, min(p1, hi) - max(p0, lo))
+                         for p0, p1 in pauses
+                         for lo, hi in ((t[0], t[wait]), (t[wait + 1], t[-1])))
+            if paused >= excess / 2:
+                cause = "gc"
+            elif u["cpu"] - mean_cpu >= excess / 2:
+                cause = "own"
+            else:
+                cause = "offcpu"
+            found.append(dict(
+                lane=lane, replica=u["rec"]["replica"], seq=u["rec"]["seq"],
+                phase=phases[at], t0=t[at], wall_ms=excess * 1e3,
+                cpu_ms=(u["cpu"] - mean_cpu) * 1e3, cause=cause))
+    return sorted(found, key=lambda s: s["t0"])
+
+
+def stall_totals(found):
+    """An operator's view of :func:`stalls`' list: how many, the longest
+    and the milliseconds by cause."""
+    by_cause = dict.fromkeys(STALL_CAUSES, 0.0)
+    for s in found:
+        by_cause[s["cause"]] += s["wall_ms"]
+    return {"count": len(found),
+            "longest_ms": max((s["wall_ms"] for s in found), default=0.0),
+            "ms_by_cause": by_cause}
 
 
 if os.environ.get("MXNET_TRACING", "0") == "1":

@@ -74,6 +74,9 @@ def test_serving_latency_bench_emits_artifact(tmp_path):
                BENCH_SERVING_CAP_BURST="12",
                BENCH_SERVING_CAP_AB_REQUESTS="4",
                BENCH_SERVING_CAP_AB_REPEATS="2",
+               # the burst lane's saturation incident dumps the flight
+               # record: here, not into the checkout the bench runs from
+               MXNET_TRACE_DUMP=str(tmp_path / "flight_record.json"),
                MXT_SERVING_LATENCY_OUT=str(out))
     env.pop("XLA_FLAGS", None)   # the bench forces its own 8-device flag
     r = subprocess.run(

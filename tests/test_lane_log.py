@@ -79,6 +79,18 @@ def one_slot():
     return _serve(n_requests=3, new_tokens=8, num_slots=1, max_batch=1)
 
 
+@pytest.fixture
+def no_collection():
+    """A collection of a millisecond writes a ``gc.pause`` record of its
+    own into the ring; a test that counts the ring's records holds the
+    collector off."""
+    import gc
+
+    gc.disable()
+    yield
+    gc.enable()
+
+
 def _kind(log, kind):
     return [r for r in log if r["kind"] == kind]
 
@@ -429,7 +441,7 @@ def test_batch_records_carry_the_counts_at_the_gate(served, one_slot):
 
 # --- the ring ----------------------------------------------------------------
 
-def test_ring_is_bounded_and_lane_log_filters():
+def test_ring_is_bounded_and_lane_log_filters(no_collection):
     base = -1e9     # before any real perf_counter: no ``since=`` sees them
     for i in range(tracing.LANE_LOG_CAPACITY + 50):
         tracing.lane_record("prefill.gated", replica=7, t0=base + i,
@@ -486,7 +498,8 @@ def test_submit_future_carries_the_request(served):
         assert q.record()["first_tick"] == q.first_tick
 
 
-def test_flight_record_carries_the_lane_tail(served, tmp_path):
+def test_flight_record_carries_the_lane_tail(served, tmp_path,
+                                             no_collection):
     path = tracing.dump(str(tmp_path / "flight.json"), reason="test")
     with open(path) as f:
         doc = json.load(f)
@@ -616,8 +629,11 @@ def _traced_serve(tmp_path, make, **cfg):
         if plane.name != "/host:CPU":
             continue
         for line in plane.lines:
+            # the lanes' own spans: the collector's (``mxt.gc.pause``) open
+            # in whichever thread set it off, between a lane's spans too
             events = [(ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
-                      for ev in line.events if ev.name.startswith("mxt.")]
+                      for ev in line.events if ev.name.startswith("mxt.")
+                      and ev.name != "mxt.gc.pause"]
             if events:
                 lines.append(events)
     return lines, tracing.lane_log(since=since)
@@ -693,12 +709,15 @@ def test_wait_and_adopt_spans_carry_their_metadata(traced):
         == gated == {"slot"}
     # the adopt span lies inside its turn's tick span
     decode = _lane_line(lines, "mxt.decode.tick")
-    tick_of = {st["seq"]: (t0, t0 + dur) for name, t0, dur, st in decode
-               if name == "mxt.decode.tick"}
+    # (a turn that queues nothing leaves its ``seq`` to the next one)
+    ticks_of = {}
+    for name, t0, dur, st in decode:
+        if name == "mxt.decode.tick":
+            ticks_of.setdefault(st["seq"], []).append((t0, t0 + dur))
     for name, t0, dur, st in decode:
         if name == "mxt.decode.adopt":
-            lo, hi = tick_of[st["seq"]]
-            assert lo <= t0 and t0 + dur <= hi
+            assert any(lo <= t0 and t0 + dur <= hi
+                       for lo, hi in ticks_of[st["seq"]])
 
 
 def test_a_lane_thread_is_always_under_a_top_level_span(traced):

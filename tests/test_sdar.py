@@ -678,7 +678,10 @@ def test_benchmark_config_keeps_every_published_width():
         # the admission, from the lane log's slot.turn records (PR 34)
         "slot_turn_ms", "slot_wait_lane_ms", "handoff_wait_ms",
         "tick_stretch_ms", "ticks_behind_prefill_share",
-        "free_slots_at_admit"}
+        "free_slots_at_admit",
+        # the host's side of a turn on two clocks (PR 49)
+        "tick_offcpu_ms", "stall_share.gc", "stall_share.own",
+        "stall_share.offcpu", "gc_pause_max_ms"}
 
 
 @pytest.fixture
