@@ -25,20 +25,34 @@ _export = make_exporter(_this)
 
 
 def sdpa_raw(q, k, v, m=None, scale=None, causal=False):
-    """Raw-array fused attention: the Pallas flash kernel when it applies
+    """Raw-array fused attention: the Pallas flash kernels when they apply
     (TPU, unmasked/causal, 128-aligned lengths), else jax.nn's
     ``dot_product_attention``.  Shared by the NDArray op below and the
     sequence-parallel bodies (parallel/ring.py).
 
-    Layout here is (B, T, N, H); the flash kernel takes (B, N, T, H)."""
+    Layout here is (B, T, N, H), a free reshape of the projections'
+    (B, T, N x H).  Where the operands' shapes allow
+    (``flash_attention.tokens_applicable``: heads that fill 128-lane
+    tiles, one tile of sequence, one chip) the token-major kernels read
+    and write that layout where it lies.  Otherwise the head-major
+    kernels take (B, N, T, H): q, k, v are transposed in and o out, and
+    the backward transposes do in and dq, dk, dv out."""
     if m is None and q.shape[1] == k.shape[1] and \
             q.shape[2] == k.shape[2] and \
             q.shape[1] % 128 == 0 and q.shape[-1] <= 256:
         # equal-head, unmasked, 128-aligned: the Pallas kernel applies
         # (GQA/MQA head broadcasting stays on the jax.nn path)
-        from .flash_attention import _on_tpu, flash_attention_raw
+        from .flash_attention import (_on_tpu, flash_attention_raw,
+                                      flash_attention_tokens,
+                                      tokens_applicable)
 
         if _on_tpu():
+            # mxlint: allow=T2 (the rule reads static shapes)
+            if tokens_applicable(q, k, v):
+                b, t, n, h = q.shape
+                return flash_attention_tokens(
+                    *(a.reshape(b, t, n * h) for a in (q, k, v)), n, causal,
+                    scale).reshape(b, t, n, h)
             qt = q.transpose(0, 2, 1, 3)
             out = flash_attention_raw(qt, k.transpose(0, 2, 1, 3),
                                       v.transpose(0, 2, 1, 3), causal,

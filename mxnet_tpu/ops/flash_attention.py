@@ -34,6 +34,23 @@ record them where the program is traced.  The backward's row statistics
 a lane tile, and so does the forward's ``lse`` where a step is several
 rows.
 
+Two entries for the trainer.  ``flash_attention_raw`` is head-major,
+``(B, H, T, D)``: the grid above, for callers that hold their heads that
+way (``models/llama.py``, ``models/joyai_flash.py``, ``parallel/ring.py``).
+``flash_attention_tokens`` is token-major, ``(B, T, N x H)`` as a
+model's projections leave q, k and v and as its output projection wants
+o: grid ``(B / bb, N x H / 128)``, a block ``(bb, T, 128)`` of ``bb``
+batch rows (``tokens_rows``) by the whole sequence by one lane tile,
+which is two heads of 64 (each reached by a lane mask: a product that
+contracts the tile's 128 lanes with the other head's zeroed) or one head
+a multiple of 128 wide.  Nothing is transposed in HBM around it, forward
+or backward; ``lse`` is ``(B, N / 2, 2, T)``, a row a (batch, head);
+``delta`` is taken inside ``dq`` and ``dkv``, which read ``o``.
+``ops.attention.sdpa_raw`` takes it where ``tokens_applicable`` reads it
+from the operands' shapes (one tile of sequence, one chip); the gauge
+``flash.token_major.*`` and ``train_form(..., layout="tokens")`` say
+which entry a program took.
+
 Backward: ``jax.custom_vjp``; on the TPU the two Pallas kernels below
 (``dq``, ``dkv``), elsewhere a K-block-chunked jnp backward
 (``lax.scan``) — recompute-based, so backward memory is O(T·block) too.
@@ -471,14 +488,17 @@ def train_blocks(kernel, tq, tk, d, dv=None, itemsize=2, causal=False):
     return default
 
 
-def _say_grid(kernel, hb, steps):
+def _say_grid(kernel, hb, steps, token_major=False):
     """The gauges of a training kernel's grid where its program is
     traced: the (batch, head) rows a step takes and the steps of a head's
     grid, all of which compute (``flash.live_steps``: under the causal
-    mask past one tile the grid is ``_live_steps``' list)."""
+    mask past one tile the grid is ``_live_steps``' list), and which of
+    the two entries it is: ``flash.token_major`` 1 for a kernel that
+    indexes ``(B, T, N x H)`` where it lies, 0 for the head-major one."""
     telemetry.gauge(f"flash.rows_per_step.{kernel}", hb)
     telemetry.gauge(f"flash.grid_steps.{kernel}", steps)
     telemetry.gauge(f"flash.live_steps.{kernel}", steps)
+    telemetry.gauge(f"flash.token_major.{kernel}", int(token_major))
 
 
 def _fa_forward_pallas(q, k, v, causal, scale, block_q=None, block_k=None,
@@ -925,6 +945,268 @@ def _fa_backward_pallas(q, k, v, o, do, lse, causal, scale, block_q=None,
             dv_out.reshape(b, h, tk, dv))
 
 
+# --- the token-major entry ----------------------------------------------------
+# The trainer's projections leave q, k and v as (B, T, N x H), and the
+# output projection wants o the same way.  The kernels above take
+# (B, N, T, H): 8 transposes a layer in HBM (q, k, v in and o out, do in
+# and dq, dk, dv out), each of which reads or writes heads of 64 padded to
+# a 128-lane tile.  The three kernels below index (B, T, N x H) where it
+# lies: a block is ``(bb, T, 128)``, ``bb`` batch rows of the whole
+# sequence by one lane tile, which holds two heads of 64 (or one head,
+# where a head is a multiple of 128 wide).
+
+def tokens_lanes(d):
+    """(lanes of a token-major block, heads it holds) at heads ``d``
+    wide, or None where heads do not fill whole lane tiles: two heads of
+    64 to a tile of 128, one head of a multiple of 128."""
+    if d == 64:
+        return 128, 2
+    return (d, 1) if d % 128 == 0 else None
+
+
+def tokens_rows(b, pair, tq, tk, d, itemsize=2):
+    """Batch rows ``bb`` of a token-major grid step: ``train_tiles``'
+    VMEM rule counted in (batch, head) rows, ``pair`` of them a batch
+    row, so that a step holds the rows the head-major one does (BERT's
+    ``(128, 128, 12 x 64)``: 8 batch rows of two heads, 16 rows).
+
+    On the v5e at that shape in bf16, ms a layer-call, forward / dq / dkv
+    (PERF.md, PR 50; ``chiprun -- python tools/prefill_flash_bench.py
+    --train 128,12,128,64 --layout tokens --rows 4,8,16,32``; the
+    head-major kernels at 16 rows in the same call read 0.520 / 0.427 /
+    0.526): 4 rows 0.421 / 0.520 / 0.408, 8 rows 0.298 / 0.332 / 0.337,
+    **16 rows 0.268 / 0.289 / 0.330**, 32 rows 0.275 / 0.282 / 0.329.
+    Half the DMA bytes a row (no head of 64 padded to 128 lanes) and no
+    accumulator in VMEM; ``dq`` and ``dkv`` take ``delta`` themselves.
+    Tried and within 2% of that, so not taken: the lane masks as one row
+    broadcast (0.271 / 0.274 / 0.339) or as a product with 0 / 1 (0.271
+    / 0.299 / 0.352), the forward's two ``p v`` as one product 2T deep
+    (0.263), the masks on ``q`` as an AND on packed words with both
+    (0.244 / 0.291 / 0.335)."""
+    cap = TRAIN_VMEM_BYTES // (pair * train_row_bytes(tq, tk, d, itemsize))
+    return max(c for c in range(1, max(1, min(cap, b)) + 1) if b % c == 0)
+
+
+def tokens_applicable(q, k, v):
+    """Whether ``ops.attention.sdpa_raw``'s unmasked call on ``q``, ``k``,
+    ``v`` (B, T, N, H) takes ``flash_attention_tokens``: read from the
+    operands' own shapes and from where the program runs, no knob.  Equal
+    shapes; heads that fill lane tiles (64 wide and an even number of
+    them, or a multiple of 128 up to 256); a sequence that is a multiple
+    of 128 and one tile (up to 512, where ``train_tiles`` takes several
+    rows a step; past one tile the rule refuses: no cell trains through
+    ``sdpa_raw`` there, and the head-major kernels keep their tiles and
+    live-step lists); the Pallas forward and backward both on
+    (``_pallas_applicable``, ``_pallas_bwd_enabled``: the switches of the
+    head-major kernels); no mesh and no ``shard_map`` around the call (a
+    Mosaic call is not partitioned, and ``_pallas_maybe_sharded``'s
+    wrapper shards heads, which here share a lane tile)."""
+    from ..parallel import current_mesh
+
+    if not (q.shape == k.shape == v.shape and q.ndim == 4):
+        return False
+    _, t, n, d = q.shape
+    lanes = tokens_lanes(d)
+    if lanes is None or d > 256 or n % lanes[1] or t % 128 or t > 512:
+        return False
+    mesh = current_mesh()
+    if (mesh is not None and mesh.size > 1) or _inside_shard_map():
+        return False
+    rows = jax.ShapeDtypeStruct((t, d), q.dtype)
+    return _pallas_applicable(rows, rows) and _pallas_bwd_enabled()
+
+
+def _head_lanes(shape, pair, d):
+    """The lane masks of a tile's ``pair`` heads, head ``j`` in lanes
+    ``[j d, (j + 1) d)`` (None for a tile that is one head)."""
+    if pair == 1:
+        return [None]
+    lane = lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return [(lane >= j * d) & (lane < (j + 1) * d) for j in range(pair)]
+
+
+def _only(x, lanes):
+    """``x`` with the lanes outside ``lanes`` zeroed: a head of a tile
+    reached by a mask, not by a slice at lane 64.  A product that
+    contracts the tile's 128 lanes then sees the one head (the v5e's MXU
+    contracts 128 deep whatever a head's width)."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _one_tile_mask(s, causal, keys_by_queries=False):
+    """Scores of a whole sequence under the causal mask."""
+    if not causal:
+        return s
+    a = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
+    b = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
+    return jnp.where((b >= a) if keys_by_queries else (a >= b), s, -jnp.inf)
+
+
+def _fa_tokens_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, causal,
+                          scale, pair, d):
+    """A step: ``bb`` batch rows of one lane tile's heads, the whole
+    sequence.  Each head's arithmetic is ``_fa_kernel``'s at one tile:
+    bf16 operands into the products, float32 scores, softmax and
+    result."""
+    q, k, v = q_ref[...], k_ref[...], v_ref[...]
+    bb, t, _ = q.shape
+    out = None
+    for j, lanes in enumerate(_head_lanes(q.shape, pair, d)):
+        s = _one_tile_mask(_dot(_only(q, lanes), k, 1, 1) * scale, causal)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=-1, keepdims=True)
+        # p_j v fills every lane; the head's own are kept
+        o = _dot(p.astype(v.dtype), v, 1, 0) / jnp.maximum(l, 1e-30)
+        out = o if out is None else jnp.where(lanes, o, out)
+        if lse_ref:
+            # along lanes, a row of T numbers a (batch, head)
+            lse_ref[0][:, 0, j:j + 1, :] = (
+                m + jnp.log(jnp.maximum(l, 1e-30))).reshape(bb, 1, t)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _head_delta(do_o, lanes):
+    """``delta`` = rowsum(dO * O) of one head of the tile, a column
+    ``(bb, T, 1)``: the head's lanes of the product ``do_o``, summed.
+    Inside the kernels, which read ``o`` for it: a sum over 64 of 768
+    minor lanes is no reduction XLA has (it wrote the float32 product to
+    HBM, changed its layout and read it again)."""
+    return _only(do_o, lanes).sum(axis=-1, keepdims=True)
+
+
+def _fa_tokens_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+                         *, causal, scale, pair, d):
+    """``_fa_bwd_dq_kernel``'s arithmetic a head (float32 operands in the
+    three products), two heads to a lane tile."""
+    q, k, v, o, do = (r[...].astype(jnp.float32)
+                      for r in (q_ref, k_ref, v_ref, o_ref, do_ref))
+    dq, do_o = None, do * o
+    for j, lanes in enumerate(_head_lanes(q.shape, pair, d)):
+        lse = lse_ref[:, 0, j:j + 1, :][..., 0, :][..., None]
+        s = _one_tile_mask(_dot(_only(q, lanes), k, 1, 1) * scale, causal)
+        p = jnp.exp(s - lse)
+        dp = _dot(_only(do, lanes), v, 1, 1)
+        ds = p * (dp - _head_delta(do_o, lanes)) * scale
+        dqj = _dot(ds, k, 1, 0)
+        dq = dqj if dq is None else jnp.where(lanes, dqj, dq)
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+
+
+def _fa_tokens_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                          dk_ref, dv_ref, *, causal, scale, pair, d):
+    """``_fa_bwd_dkv_kernel``'s arithmetic a head, scores laid keys by
+    queries; a head's ``dk`` and ``dv`` land in its own lanes because the
+    products' right operands (``q``, ``do``) are zero in the others."""
+    q, k, v, o, do = (r[...].astype(jnp.float32)
+                      for r in (q_ref, k_ref, v_ref, o_ref, do_ref))
+    bb, t, _ = q.shape
+    dk, dv, do_o = None, None, do * o
+    for j, lanes in enumerate(_head_lanes(q.shape, pair, d)):
+        lse = lse_ref[:, 0, j:j + 1, :]
+        delta = _head_delta(do_o, lanes).reshape(bb, 1, t)
+        qj, doj = _only(q, lanes), _only(do, lanes)
+        st = _one_tile_mask(_dot(k, qj, 1, 1) * scale, causal, True)
+        pt = jnp.exp(st - lse)
+        dvj = _dot(pt, doj, 1, 0)
+        dpt = _dot(v, doj, 1, 1)
+        dst = pt * (dpt - delta) * scale
+        dkj = _dot(dst, qj, 1, 0)
+        dk, dv = (dkj, dvj) if dk is None else (dk + dkj, dv + dvj)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
+def _tokens_call(name, kernel, operands, stats, like, heads, causal, scale,
+                 with_lse=False, interpret=False):
+    """One token-major kernel over its grid ``(B / bb, N x H / 128)``:
+    ``operands`` (B, T, N x H) in blocks ``(bb, T, 128)`` and ``stats``
+    (B, N / pair, pair, T) in blocks ``(bb, 1, pair, T)`` (a row of ``T``
+    numbers a (batch, head), the ``pair`` heads of a lane tile side by
+    side) in; out a result like each of ``like`` and, ``with_lse``, the
+    statistics."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, width = operands[0].shape
+    d = width // heads
+    lanes, pair = tokens_lanes(d)
+    bb = tokens_rows(b, pair, t, t, d, operands[0].dtype.itemsize)
+    _say_grid(name, bb * pair, 1, token_major=True)
+    block = pl.BlockSpec((bb, t, lanes), lambda i, j: (i, 0, j))
+    stat = pl.BlockSpec((bb, 1, pair, t), lambda i, j: (i, j, 0, 0))
+    out_specs = [block] * len(like) + [stat] * with_lse
+    out_shape = [_pallas_out_shape((b, t, width), x.dtype, *operands)
+                 for x in like] + [_pallas_out_shape(
+                     (b, heads // pair, pair, t), jnp.float32,
+                     *operands)] * with_lse
+    one = len(out_shape) == 1       # as the head-major dq: no tuple of one
+    return pl.pallas_call(
+        functools.partial(kernel, causal=causal, scale=scale, pair=pair,
+                          d=d),
+        grid=(b // bb, width // lanes),
+        in_specs=[block] * len(operands) + [stat] * len(stats),
+        out_specs=out_specs[0] if one else out_specs,
+        out_shape=out_shape[0] if one else out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret)(*operands, *stats)
+
+
+def _fa_forward_tokens(q, k, v, heads, causal, scale, with_lse=False,
+                       interpret=False):
+    """q, k, v (B, T, N x H) as the projections leave them -> o the
+    same[, lse (B, N / pair, pair, T) float32]."""
+    out = _tokens_call("fwd", _fa_tokens_fwd_kernel, (q, k, v), (), (q,),
+                       heads, causal, scale, with_lse, interpret)
+    return tuple(out) if with_lse else out
+
+
+def _fa_backward_tokens(q, k, v, o, do, lse, heads, causal, scale,
+                        interpret=False):
+    """dq, dk, dv (B, T, N x H) by two kernels, as the head-major
+    backward: ``dq`` one result, ``dkv`` two, all in the operands' dtype.
+    Both read ``o`` and take ``delta`` = rowsum(dO * O) a head
+    themselves."""
+    operands = (q, k, v, o, do)
+    dq = _tokens_call("dq", _fa_tokens_dq_kernel, operands, (lse,), (q,),
+                      heads, causal, scale, interpret=interpret)
+    dk, dv = _tokens_call("dkv", _fa_tokens_dkv_kernel, operands, (lse,),
+                          (k, v), heads, causal, scale, interpret=interpret)
+    return dq, dk, dv
+
+
+def _tokens_scale(q, heads, scale):
+    return float(scale) if scale is not None else \
+        1.0 / float(np.sqrt(q.shape[-1] // heads))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_tokens(q, k, v, heads, causal=False, scale=None):
+    """q, k, v (B, T, N x H), ``heads`` = N -> (B, T, N x H): attention a
+    head with no operand or result transposed in HBM, in the forward or
+    in the backward.  For callers that hold token-major operands and
+    have asked ``tokens_applicable``; there is no fall-back here."""
+    return _fa_forward_tokens(q, k, v, heads, causal,
+                              _tokens_scale(q, heads, scale))
+
+
+def _tokens_fwd(q, k, v, heads, causal, scale):
+    o, lse = _fa_forward_tokens(q, k, v, heads, causal,
+                                _tokens_scale(q, heads, scale),
+                                with_lse=True)
+    return o, (q, k, v, o, lse)
+
+
+def _tokens_bwd(heads, causal, scale, res, g):
+    q, k, v, o, lse = res
+    return _fa_backward_tokens(q, k, v, o, g, lse, heads, causal,
+                               _tokens_scale(q, heads, scale))
+
+
+flash_attention_tokens.defvjp(_tokens_fwd, _tokens_bwd)
+
+
 # --- chunked jnp backward ----------------------------------------------------
 
 def _causal_block_mask(tq, bk, j, offset=0):
@@ -1158,11 +1440,24 @@ def _bwd(causal, scale, res, g):
 flash_attention_raw.defvjp(_fwd, _bwd)
 
 
-def train_form(q_shape, dv=None, itemsize=2, causal=False):
+def train_form(q_shape, dv=None, itemsize=2, causal=False, layout="heads"):
     """Which form ``flash_attention_raw`` and its backward take for ``q``
     (B, H, T, D) (``k`` alike) and values ``dv`` wide, here and now, as one
     word for a log: ``pallas:fwd<block_q>x<block_k>,dq<..>,dkv<..>:d<D>/
-    <Dv>:hb<rows a step>`` or ``chunked``."""
+    <Dv>:hb<rows a step>`` or ``chunked``.  ``layout="tokens"``: the form
+    ``ops.attention.sdpa_raw`` takes for ``q`` (B, T, N, H) as it holds
+    it; where ``tokens_applicable`` gives it the token-major entry the
+    word ends ``:tokens`` (``hb`` still (batch, head) rows), else it is
+    the head-major word of the transposed shape."""
+    if layout == "tokens":
+        b, t, h, d = q_shape
+        q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+        if dv in (None, d) and tokens_applicable(q, q, q):
+            pair = tokens_lanes(d)[1]
+            hb = tokens_rows(b, pair, t, t, d, itemsize) * pair
+            return (f"pallas:fwd{t}x{t},dq{t}x{t},dkv{t}x{t}:d{d}/{d}"
+                    f":hb{hb}:tokens")
+        q_shape = (b, h, t, d)
     b, h, t, d = q_shape
     dv = d if dv is None else dv
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)

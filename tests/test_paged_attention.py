@@ -879,6 +879,42 @@ def test_training_flash_kernels_compile_for_v5e(one_chip, shape, dtype,
     assert lanes in text and (column in text) == (hb == 1)
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((128, 128, 12, 64), "bfloat16"),   # bert_base.pretrain_s128
+    ((128, 128, 12, 64), "float32"),    # the same without autocast
+    ((16, 512, 8, 128), "bfloat16"),    # the longest one-tile sequence
+    ((16, 512, 8, 64), "bfloat16"),     # and two heads to its tile
+    ((8, 384, 4, 256), "bfloat16"),     # a head two lane tiles wide
+], ids=["bert_s128_bf16", "bert_s128_f32", "t512_hd128", "t512_hd64",
+        "t384_hd256"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_token_major_flash_kernels_compile_for_v5e(one_chip, shape, dtype,
+                                                   causal):
+    """The three kernels that index ``(B, T, N x H)`` where it lies, at
+    the batch rows a step ``tokens_rows`` gives (8 at BERT-base's shape,
+    two heads of 64 each): blocks of whole lane tiles, the lane masks on
+    packed bf16 and the step's working set are what Mosaic takes for the
+    chip; three calls and nothing of XLA's between the operands and them
+    (no transpose, no copy); ``lse`` along lanes, a lane tile's heads
+    side by side."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    b, t, n, d = shape
+    pair = fa.tokens_lanes(d)[1]
+    x = jax.ShapeDtypeStruct((b, t, n * d), jnp.dtype(dtype),
+                             sharding=one_chip)
+
+    def step(q, k, v, do):
+        o, lse = fa._fa_forward_tokens(q, k, v, n, causal, 0.125,
+                                       with_lse=True)
+        return fa._fa_backward_tokens(q, k, v, o, do, lse, n, causal, 0.125)
+
+    text = _compiled_without_cache(step, x, x, x, x).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert f"f32[{b},{n // pair},{pair},{t}]" in text
+    assert " copy(" not in text and " transpose(" not in text
+
+
 def _compiled_without_cache(fn, *avals):
     from jax.experimental.compilation_cache import compilation_cache as cc
 

@@ -175,6 +175,249 @@ def test_train_form_names_the_tiles(monkeypatch):
         == "pallas:fwd128x128,dq128x128,dkv128x128:d64/64:hb16"
 
 
+# --- the token-major entry: (B, T, N x H) where it lies ---------------------------
+
+def _flat(x):           # (B, T, N, H) -> (B, T, N x H), the projections' own
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _tr(x):             # (B, T, N, H) <-> (B, N, T, H), by hand
+    return x.transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("shape,bb,dtype", [
+    ((4, 128, 12, 64), None, "float32"), ((4, 128, 12, 64), 1, "float32"),
+    ((4, 128, 12, 64), None, "bfloat16"), ((4, 256, 2, 128), None, "float32"),
+    ((4, 256, 4, 64), 2, "float32"), ((2, 512, 2, 64), None, "float32"),
+    ((2, 512, 2, 64), 2, "float32"), ((2, 128, 2, 256), None, "float32"),
+], ids=["bert_heads", "bert_heads_a_row_a_step", "bert_heads_bf16",
+        "t256_hd128", "t256_two_rows", "t512", "t512_two_rows", "hd256"])
+def test_token_major_kernels_match_the_dense_vjp_and_the_head_major_bits(
+        monkeypatch, shape, bb, dtype, causal):
+    """Forward, ``dq`` and ``dkv`` on ``(B, T, N x H)`` in interpret mode:
+    BERT's heads (12 of 64, two to a lane tile) reduced in ``B``, heads of
+    128 and 256 (one a block), sequences of 256 and 512, at the batch rows
+    a step that ``tokens_rows`` gives and at others.  Against
+    ``_sdpa_ref``'s vjp; and against the head-major kernels on the same
+    values transposed by hand: the forward and ``lse`` to the bit (a head's
+    arithmetic is the same: the other head's lanes enter its products as
+    zeros), the gradients within 2^-20 of the tensor's largest value in
+    float32 (``delta`` is summed inside the kernels, over a tile's lanes
+    under a mask, not by XLA over a head's 64) and a bf16 rounding in
+    bf16.  ``lse`` lies along lanes, ``(B, N / pair, pair, T)``."""
+    b, t, n, h = shape
+    lanes, pair = fa.tokens_lanes(h)
+    if bb is not None:
+        monkeypatch.setattr(fa, "tokens_rows", lambda *_: bb)
+    q, k, v, do = (jnp.asarray(rs.randn(*shape), jnp.float32)
+                   .astype(dtype) for _ in range(4))
+    scale = h ** -0.5
+    o, lse = fa._fa_forward_tokens(_flat(q), _flat(k), _flat(v), n, causal,
+                                   scale, with_lse=True, interpret=True)
+    assert o.shape == (b, t, n * h) and o.dtype == q.dtype
+    assert lse.shape == (b, n // pair, pair, t) and lse.dtype == jnp.float32
+    alone = fa._fa_forward_tokens(_flat(q), _flat(k), _flat(v), n, causal,
+                                  scale, interpret=True)
+    assert np.array_equal(alone, o)
+    got = fa._fa_backward_tokens(_flat(q), _flat(k), _flat(v), o, _flat(do),
+                                 lse, n, causal, scale, interpret=True)
+    assert [g.shape for g in got] == [(b, t, n * h)] * 3
+    assert [g.dtype for g in got] == [q.dtype] * 3
+
+    o2, lse2 = fa._fa_forward_pallas(_tr(q), _tr(k), _tr(v), causal, scale,
+                                     with_lse=True, interpret=True)
+    got2 = fa._fa_backward_pallas(_tr(q), _tr(k), _tr(v), o2, _tr(do), lse2,
+                                  causal, scale, interpret=True)
+    assert np.array_equal(o.reshape(shape), _tr(o2))
+    assert np.array_equal(lse.reshape(b, n, t), lse2)
+    f32 = dtype == "float32"
+    for g, g2 in zip(got, got2):
+        _close(g.reshape(shape), _tr(g2), 2.0 ** -20 if f32 else 2.0 ** -7)
+
+    want, vjp = jax.vjp(
+        lambda q, k, v: fa._sdpa_ref(q, k, v, causal, scale),
+        *(_tr(x).astype(jnp.float32) for x in (q, k, v)))
+    _close(o.reshape(shape), _tr(want), 2e-5 if f32 else 2.0 ** -7)
+    for g, w in zip(got, vjp(_tr(do).astype(jnp.float32))):
+        _close(g.reshape(shape), _tr(w), 5e-5 if f32 else 2.0 ** -5)
+
+
+def _interpreted(monkeypatch):
+    """``sdpa_raw`` as on a chip, its Pallas calls in interpret mode."""
+    import functools
+
+    monkeypatch.setenv("MXT_FORCE_PALLAS_FLASH", "1")
+    for name in ("_fa_forward_tokens", "_fa_backward_tokens"):
+        monkeypatch.setattr(fa, name, functools.partial(
+            getattr(fa, name), interpret=True))
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_sdpa_raw_differentiates_through_the_token_major_entry(monkeypatch,
+                                                               causal):
+    """The model's call, ``sdpa_raw`` on ``(B, T, N, H)``, and ``jax.vjp``
+    of it against XLA's dense attention; the gauges of the three grids say
+    whose they are."""
+    from mxnet_tpu.ops.attention import sdpa_raw
+
+    _interpreted(monkeypatch)
+    shape = (4, 128, 4, 64)
+    q, k, v, do = (jnp.asarray(rs.randn(*shape), jnp.float32)
+                   for _ in range(4))
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        out, vjp = jax.vjp(lambda q, k, v: sdpa_raw(q, k, v, causal=causal),
+                           q, k, v)
+        got = vjp(do)
+        gauges = telemetry.gauges()
+    finally:
+        if not was:
+            telemetry.disable()
+    want, vjp = jax.vjp(lambda q, k, v: jax.nn.dot_product_attention(
+        q, k, v, is_causal=causal), q, k, v)
+    _close(out, want, 2e-5)
+    for g, w in zip(got, vjp(do)):
+        _close(g, w, 5e-5)
+    for kern in ("fwd", "dq", "dkv"):
+        assert gauges[f"flash.token_major.{kern}"] == 1
+        assert gauges[f"flash.rows_per_step.{kern}"] == 8     # 4 x 2 heads
+        assert gauges[f"flash.grid_steps.{kern}"] == 1
+        assert gauges[f"flash.live_steps.{kern}"] == 1
+    # not differentiated: the forward alone, no lse
+    _close(sdpa_raw(q, k, v, causal=causal), want, 2e-5)
+
+
+#: what ``sdpa_raw`` traces for operands (B, T, N, H): ``tokens`` (the
+#: token-major kernels, no transpose), ``heads`` (transposes around
+#: ``flash_attention_raw``'s Pallas kernels: the parent's program),
+#: ``chunked`` (transposes around its ``jax.numpy`` fall-back) or ``xla``
+#: (``jax.nn.dot_product_attention``)
+RULE = {
+    "bert_base": (dict(shape=(8, 128, 12, 64)), "tokens"),
+    "heads_of_128": (dict(shape=(8, 256, 4, 128)), "tokens"),
+    "heads_of_256": (dict(shape=(2, 384, 2, 256)), "tokens"),
+    "one_tile_of_512": (dict(shape=(2, 512, 2, 64)), "tokens"),
+    "causal": (dict(shape=(8, 128, 12, 64), causal=True), "tokens"),
+    "an_odd_number_of_heads_of_64": (dict(shape=(8, 128, 3, 64)), "heads"),
+    "heads_of_192": (dict(shape=(2, 128, 4, 192)), "heads"),
+    "heads_of_32": (dict(shape=(2, 128, 4, 32)), "heads"),
+    "past_one_tile": (dict(shape=(2, 1024, 8, 64)), "heads"),
+    "a_mesh": (dict(shape=(8, 128, 12, 64), mesh=True), "heads"),
+    "a_mask": (dict(shape=(8, 128, 12, 64), mask=True), "xla"),
+    "a_length_off_the_tile": (dict(shape=(8, 120, 12, 64)), "xla"),
+    "fewer_kv_heads": (dict(shape=(8, 128, 12, 64), kv_heads=4), "xla"),
+    "MXT_PALLAS_FLASH=0": (dict(shape=(8, 128, 12, 64),
+                                env={"MXT_PALLAS_FLASH": "0"}), "chunked"),
+    "MXT_PALLAS_FLASH_BWD=0": (dict(shape=(8, 128, 12, 64),
+                                    env={"MXT_PALLAS_FLASH_BWD": "0"}),
+                               "heads"),
+    "a_cpu": (dict(shape=(8, 128, 12, 64),
+                   env={"MXT_FORCE_PALLAS_FLASH": "0"}), "xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(RULE))
+def test_the_rule_is_the_operands_own_shape(monkeypatch, case):
+    """``sdpa_raw`` takes the token-major entry where the mask is absent,
+    heads are equal and fill lane tiles (64 wide and an even number, or a
+    multiple of 128), the sequence is one tile of a multiple of 128, the
+    platform is a TPU and no mesh is set; every other call traces what it
+    traced before, under the switches it had."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops.attention import sdpa_raw
+
+    given, want = RULE[case]
+    monkeypatch.setenv("MXT_FORCE_PALLAS_FLASH", "1")
+    for name, value in given.get("env", {}).items():
+        monkeypatch.setenv(name, value)
+    b, t, n, h = given["shape"]
+    q = jax.ShapeDtypeStruct(given["shape"], jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, t, given.get("kv_heads", n), h),
+                              jnp.bfloat16)
+    mask = (jax.ShapeDtypeStruct((b, 1, t, t), jnp.bool_),) \
+        if given.get("mask") else ()
+
+    def loss(q, k, v, *m):
+        return sdpa_raw(q, k, v, *m, causal=given.get("causal", False)) \
+            .astype(jnp.float32).sum()
+
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        for kern in ("fwd", "dq", "dkv"):
+            telemetry._gauges.pop(f"flash.token_major.{kern}", None)
+        if given.get("mesh"):
+            parallel.set_mesh(parallel.make_mesh({"dp": 4, "tp": 2}))
+        with jax.enable_x64(False):
+            text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+                q, kv, kv, *mask))
+        gauges = telemetry.gauges()
+        form = fa.train_form(given["shape"], layout="tokens")
+    finally:
+        parallel.set_mesh(None)
+        if not was:
+            telemetry.disable()
+    said = [gauges.get(f"flash.token_major.{kern}")
+            for kern in ("fwd", "dq", "dkv")]
+    calls = text.count("pallas_call")
+    if want == "tokens":
+        assert said == [1, 1, 1] and calls == 3
+        assert "transpose[" not in text
+        assert f"bf16[{b},{t},{n * h}]" in text
+    elif want == "heads":
+        # MXT_PALLAS_FLASH_BWD=0: the forward's kernel, a chunked backward
+        assert calls == (1 if "env" in given else 3)
+        assert said == [0] + [0 if calls == 3 else None] * 2
+        assert "transpose[" in text
+    else:
+        assert calls == 0 and said == [None] * 3
+        assert ("transpose[" in text and "scan" in text) \
+            == (want == "chunked")
+    # the word for a log says the same of the shape (it sees no mask and
+    # no second head count)
+    assert form.endswith(":tokens") == (
+        want == "tokens" or case in ("a_mask", "fewer_kv_heads"))
+
+
+def test_train_form_names_the_layout(monkeypatch):
+    bert = (128, 128, 12, 64)               # (B, T, N, H), as sdpa_raw has it
+    assert fa.train_form(bert, layout="tokens") == "chunked"       # a CPU
+    monkeypatch.setenv("MXT_FORCE_PALLAS_FLASH", "1")
+    assert fa.train_form(bert, layout="tokens") \
+        == "pallas:fwd128x128,dq128x128,dkv128x128:d64/64:hb16:tokens"
+    assert fa.train_form((16, 512, 8, 128), layout="tokens") \
+        == "pallas:fwd512x512,dq512x512,dkv512x512:d128/128:hb2:tokens"
+    # heads of 192: the head-major word of the transposed shape
+    assert fa.train_form((4, 4096, 32, 192), 128, causal=True,
+                         layout="tokens") \
+        == fa.train_form((4, 32, 4096, 192), 128, causal=True)
+    assert fa.train_form((128, 128, 11, 64), layout="tokens") \
+        == fa.train_form((128, 11, 128, 64))
+
+
+@pytest.mark.parametrize("b,pair,t,d,itemsize,want", [
+    (128, 2, 128, 64, 2, 8), (128, 2, 128, 64, 4, 4), (4, 2, 128, 64, 2, 4),
+    (6, 2, 128, 64, 2, 6), (128, 1, 128, 128, 2, 16), (16, 1, 256, 128, 2, 4),
+    (16, 2, 512, 64, 2, 1), (16, 1, 512, 128, 2, 2), (7, 1, 384, 256, 2, 1),
+], ids=["bert_base", "bert_base_f32", "a_small_batch", "a_batch_of_6",
+        "heads_of_128", "t256", "t512_heads_of_64", "t512_heads_of_128",
+        "t384_heads_of_256"])
+def test_a_token_major_step_holds_the_rows_the_head_major_one_does(
+        b, pair, t, d, itemsize, want):
+    """``tokens_rows``: batch rows a step, a divisor of the batch, whose
+    ``pair`` heads each count a (batch, head) row of ``train_tiles``'
+    VMEM rule (16 rows at BERT's shape: 8 batch rows of a lane tile's two
+    heads)."""
+    bb = fa.tokens_rows(b, pair, t, t, d, itemsize)
+    assert bb == want and b % bb == 0
+    assert bb * pair * fa.train_row_bytes(t, t, d, itemsize) \
+        <= fa.TRAIN_VMEM_BYTES or bb == 1
+    assert bb * pair <= fa.train_tiles(b * pair * 64, t, t, d, itemsize) \
+        or bb == 1
+
+
 # --- the grouped expert feed-forward's backward -------------------------------
 
 def _case(n, h, i, e, first, held, k, skew=True, part_live=True):

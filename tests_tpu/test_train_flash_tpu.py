@@ -201,3 +201,87 @@ def test_the_cells_kernels_at_the_rules_tiles_match_the_chunked_fall_back():
         rel_max = float(np.abs(a - w).max() / np.abs(w).max())
         assert rel_rms <= 2.0 ** -6 and rel_max <= 2.0 ** -4, \
             (name, rel_rms, rel_max)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_token_major_kernels_match_the_dense_vjp(causal, parity_record):
+    """``bert_base.pretrain_s128``'s attention as the model calls it:
+    ``sdpa_raw`` on ``(128, 128, 12, 64)`` bf16, a reshape of the
+    projections' ``(B, T, N x H)``.  The rule takes the token-major entry
+    (the gauges say so, 16 (batch, head) rows a step), the program holds
+    three Mosaic calls and no transpose or copy of an operand, and the
+    output and the three gradients match ``_sdpa_ref``'s in float32; they
+    also match the head-major kernels' on the same values to the last
+    bit but ``delta``'s, which the token-major kernels sum themselves."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops.attention import sdpa_raw
+
+    b, n, t, d = SHAPE
+    scale = 1.0 / float(np.sqrt(d))
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    q, k, v, g = (jax.random.normal(kk, (b, t, n * d), jnp.float32)
+                  .astype(jnp.bfloat16) for kk in keys)
+
+    def heads(x):
+        return x.reshape(b, t, n, d)
+
+    def kern(q, k, v, g):
+        out, pull = jax.vjp(lambda a, b_, c: sdpa_raw(
+            heads(a), heads(b_), heads(c), scale=scale, causal=causal)
+            .reshape(b, t, n * d), q, k, v)
+        return (out,) + pull(g)
+
+    def head_major(q, k, v, g):
+        def tr(x):
+            return heads(x).transpose(0, 2, 1, 3)
+        out, pull = jax.vjp(lambda a, b_, c: fa.flash_attention_raw(
+            tr(a), tr(b_), tr(c), causal, scale).transpose(0, 2, 1, 3)
+            .reshape(b, t, n * d), q, k, v)
+        return (out,) + pull(g)
+
+    def ref(q, k, v, g):
+        def tr(x):
+            return heads(x.astype(jnp.float32)).transpose(0, 2, 1, 3)
+        out, pull = jax.vjp(lambda a, b_, c: fa._sdpa_ref(
+            tr(a), tr(b_), tr(c), causal, scale).transpose(0, 2, 1, 3)
+            .reshape(b, t, n * d), q, k, v)
+        return (out,) + pull(g.astype(jnp.float32))
+
+    assert fa.train_form((b, t, n, d), causal=causal, layout="tokens") \
+        == "pallas:fwd128x128,dq128x128,dkv128x128:d64/64:hb16:tokens"
+    telemetry.enable()
+    try:
+        compiled = jax.jit(kern).lower(q, k, v, g).compile()
+        gauges = telemetry.gauges()
+    finally:
+        telemetry.disable()
+    for name in ("fwd", "dq", "dkv"):
+        assert gauges[f"flash.token_major.{name}"] == 1
+        assert gauges[f"flash.rows_per_step.{name}"] == 16
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " copy(" not in text and " transpose(" not in text
+    got = jax.block_until_ready(compiled(q, k, v, g))
+    other = jax.block_until_ready(jax.jit(head_major)(q, k, v, g))
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(ref)(q, k, v, g))
+    for name, a, o, w in zip(("out", "dq", "dk", "dv"), got, other, want):
+        a, o, w = (np.asarray(x, np.float32) for x in (a, o, w))
+        assert np.isfinite(a).all(), name
+        rel_rms = float(np.sqrt(np.mean((a - w) ** 2))
+                        / np.sqrt(np.mean(w ** 2)))
+        rel_max = float(np.abs(a - w).max() / np.abs(w).max())
+        parity_record("train_flash_attention_tokens",
+                      f"{name}_{'causal' if causal else 'full'}", rel_max)
+        assert rel_rms <= 2.0 ** -6 and rel_max <= 2.0 ** -4, \
+            (name, rel_rms, rel_max)
+        # the same arithmetic a head: the forward to the bit, the
+        # gradients to a bf16 rounding of a sum taken in another order
+        if name == "out":
+            assert np.array_equal(a, o), name
+        else:
+            assert np.abs(a - o).max() <= 2.0 ** -7 * np.abs(o).max(), name

@@ -12,6 +12,15 @@ dq, dkv, and the whole custom-vjp with the XLA around it) by the rows a
 grid step takes (``ops.flash_attention.train_tiles``); with ``--tiles``
 each kernel alone at each of those tiles, the other two at the rule's
 (``ops.flash_attention.train_blocks``), and the rule's own choice.
+``--layout heads`` (the default) times the head-major kernels on
+``(B, H, T, D)`` and, beside them, the whole vjp from token-major
+operands with the eight transposes ``ops.attention.sdpa_raw`` puts around
+it (``vjp_transposed_ms``; in this chain XLA folds them into its own
+fusions, 2.056 beside ``vjp_ms`` 2.148 at BERT's shape, PERF.md, PR 50:
+what the copies cost a model is its trace's ``copy`` rows to say);
+``--layout tokens`` the token-major kernels on ``(B, T, H x D)``
+(``ops.flash_attention.flash_attention_tokens``; rows a step by
+``tokens_rows``).
 
 Each timing is one jitted program of ``CALLS`` chained calls (the output
 feeds the next call's queries, as a decoder's layers do), run ``REPS``
@@ -61,6 +70,10 @@ def main():
                          "(latent attention's expanded heads: 192,128)")
     ap.add_argument("--causal", action="store_true",
                     help="the --train kernels under the causal mask")
+    ap.add_argument("--layout", choices=("heads", "tokens"), default="heads",
+                    help="the --train kernels' entry: head-major (B, H, T, "
+                         "D), the transposes around it timed beside it, or "
+                         "token-major (B, T, H x D)")
     ap.add_argument("--rows", default="1,4,8,12,16,24",
                     help="rows a grid step of the --train sweep; the "
                          "rule's own choice is always timed")
@@ -205,22 +218,61 @@ def train(args, fa, say, timed, tiles=None):
     (the result feeds the next call's q, or k and v), by rows a grid step
     (``train_tiles`` patched, as tier 1 patches it).  A program that
     returns dq alone holds no dkv call and the other way round (XLA
-    drops a kernel whose results nothing reads); ``delta`` is computed
-    once a program, its operands being the same in every call."""
+    drops a kernel whose results nothing reads); the head-major
+    ``delta`` is computed once a program, its operands being the same in
+    every call (the token-major kernels take it themselves).  ``--layout``:
+    which entry, see the module's docstring."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     b, h, t, d, *rest = (int(n) for n in args.train.split(","))
     dv, causal = (rest[0] if rest else d), bool(args.causal)
+    tokens = args.layout == "tokens"
     scale = 1.0 / float(np.sqrt(d))
     keys = jax.random.split(jax.random.PRNGKey(t + d), 4)
     q, k, v, do = (jax.random.normal(kk, (b, h, t, w), jnp.bfloat16)
                    for kk, w in zip(keys, (d, d, dv, dv)))
-    rule = fa.train_tiles
-    chosen = rule(b * h, t, t, max(d, dv))
-    o, lse = jax.jit(lambda q, k, v: fa._fa_forward_pallas(
-        q, k, v, causal, scale, with_lse=True))(q, k, v)
+
+    def flat(x):        # (B, H, T, D) -> (B, T, H x D), as projections lie
+        return x.transpose(0, 2, 1, 3).reshape(b, t, -1)
+
+    if tokens:
+        if dv != d or tiles:
+            raise SystemExit("--layout tokens: one width, one tile")
+        pair = fa.tokens_lanes(d)[1]
+        q, k, v, do = (flat(x) for x in (q, k, v, do))
+        rule, patched = fa.tokens_rows, "tokens_rows"
+        chosen = rule(b, pair, t, t, d) * pair
+
+        def forward(q, k, v):
+            return fa._fa_forward_tokens(q, k, v, h, causal, scale,
+                                         with_lse=True)
+
+        def backward(q, k, v, o, do, lse):
+            return fa._fa_backward_tokens(q, k, v, o, do, lse, h, causal,
+                                          scale)
+
+        def attend(a, b_, c):
+            return fa.flash_attention_tokens(a, b_, c, h, causal, scale)
+    else:
+        pair = 1
+        rule, patched = fa.train_tiles, "train_tiles"
+        chosen = rule(b * h, t, t, max(d, dv))
+
+        def forward(q, k, v):
+            return fa._fa_forward_pallas(q, k, v, causal, scale,
+                                         with_lse=True)
+
+        def backward(q, k, v, o, do, lse):
+            return fa._fa_backward_pallas(q, k, v, o, do, lse, causal, scale)
+
+        def attend(a, b_, c):
+            return fa.flash_attention_raw(a, b_, c, causal, scale)
+
+    o, lse = jax.jit(forward)(q, k, v)
     if dv != d:
         # the chain feeds a result back as the next call's operand: at two
         # widths a call's results are summed into a scalar nudge instead
@@ -232,51 +284,61 @@ def train(args, fa, say, timed, tiles=None):
 
     def fwd(q, k, v, o, do, lse):
         for _ in range(CALLS):
-            out = fa._fa_forward_pallas(q, k, v, causal, scale,
-                                        with_lse=True)[0]
+            out = forward(q, k, v)[0]
             q = nudge(q, out) if nudge else out
         return q
 
     def dq(q, k, v, o, do, lse):
         for _ in range(CALLS):
-            q = fa._fa_backward_pallas(q, k, v, o, do, lse, causal,
-                                       scale)[0]
+            q = backward(q, k, v, o, do, lse)[0]
         return q
 
     def dkv(q, k, v, o, do, lse):
         for _ in range(CALLS):
-            _, k, v = fa._fa_backward_pallas(q, k, v, o, do, lse, causal,
-                                             scale)
+            _, k, v = backward(q, k, v, o, do, lse)
         return k, v
 
-    def vjp(q, k, v, o, do, lse):
+    def vjp(q, k, v, o, do, lse, attend=attend):
         for _ in range(CALLS):
-            out, pull = jax.vjp(lambda a, b_, c: fa.flash_attention_raw(
-                a, b_, c, causal, scale), q, k, v)
+            out, pull = jax.vjp(attend, q, k, v)
             q, k, v = pull(do)
             q = nudge(q, out) if nudge else q + out
         return q, k, v
 
+    def heads_from_tokens(a, b_, c):
+        """``sdpa_raw``'s head-major branch on token-major operands."""
+        def tr(x):
+            return x.reshape(b, t, h, -1).transpose(0, 2, 1, 3)
+        return flat(attend(tr(a), tr(b_), tr(c)))
+
+    operands = (q, k, v, o, do, lse)
+    programs = [("fwd", fwd, operands), ("dq", dq, operands),
+                ("dkv", dkv, operands), ("vjp", vjp, operands)]
+    if not tokens and dv == d:
+        programs.append((
+            "vjp_transposed",
+            functools.partial(vjp, attend=heads_from_tokens),
+            tuple(x if x is lse else jax.jit(flat)(x) for x in operands)))
+
     if tiles:
         train_by_tiles(fa, say, timed, tiles, args.vmem_mib,
                        (b, h, t, d, dv), causal,
-                       {"fwd": fwd, "dq": dq, "dkv": dkv},
-                       (q, k, v, o, do, lse))
+                       {"fwd": fwd, "dq": dq, "dkv": dkv}, operands)
         return
     rows = sorted({int(r) for r in args.rows.split(",") if r} | {chosen})
     try:
         for hb in rows:
-            if (b * h) % hb:
+            if (b * h) % hb or hb % pair or b % (hb // pair):
                 continue
-            fa.train_tiles = lambda *_: hb
+            setattr(fa, patched, lambda *_: hb // pair)
             rec = {"what": "train", "shape": [b, h, t, d], "dv": dv,
-                   "causal": causal, "hb": hb, "chosen": hb == chosen}
-            for name, fn in (("fwd", fwd), ("dq", dq), ("dkv", dkv),
-                             ("vjp", vjp)):
+                   "causal": causal, "layout": args.layout, "hb": hb,
+                   "chosen": hb == chosen}
+            for name, fn, given in programs:
                 try:
                     rec[name + "_ms"] = round(timed(
-                        jax.jit(lambda *a, fn=fn: fn(*a)),
-                        q, k, v, o, do, lse) * 1e3, 4)
+                        jax.jit(lambda *a, fn=fn: fn(*a)), *given)
+                        * 1e3, 4)
                 except Exception as e:      # a step Mosaic refuses
                     rec[name + "_error"] = str(e)[-300:]
             if all(n + "_ms" in rec for n in ("fwd", "dq", "dkv")):
@@ -287,7 +349,7 @@ def train(args, fa, say, timed, tiles=None):
                                   for n in ("fwd", "dq", "dkv")}
             say(**rec)
     finally:
-        fa.train_tiles = rule
+        setattr(fa, patched, rule)
 
 
 def train_by_tiles(fa, say, timed, tiles, vmem_mib, shape, causal, programs,
