@@ -242,7 +242,11 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
     keys in the same block tables (``CacheSpec.latent_layers``, with
     its ``latent_dim`` and ``index_dim``): ``latent_layers ×
     num_blocks`` × the bytes a block as ``ops.latent_cache`` stores it
-    (rows padded to whole lanes; unsharded).  This is the serving analog of the allreduce-bytes
+    (rows padded to whole lanes; unsharded).  Plus, where the K/V
+    layers select what a query reads (``CacheSpec.kv_selecting``: an
+    ``index_dim`` and no latent layer to own it): ``num_layers ×
+    num_blocks`` × the bytes a block of index keys as
+    ``ops.sparse_select`` stores them.  This is the serving analog of the allreduce-bytes
     planning the trainer gets: size the cache BEFORE building the
     engine, and feed the figure to :func:`plan_model` via
     ``kv_pool_bytes=`` to get a fit verdict that includes serving
@@ -274,6 +278,12 @@ def plan_kv_pool(num_layers, num_kv_heads, head_dim, num_blocks,
         latent = int(latent_layers) * int(num_blocks) \
             * latent_cache.bytes_per_block(block_size, latent_dim,
                                            index_dim, dtype.itemsize)
+    if index_dim and not latent_layers:
+        from ..ops import sparse_select
+
+        latent += int(num_layers) * int(num_blocks) \
+            * sparse_select.index_bytes_per_block(block_size, index_dim,
+                                                  dtype.itemsize)
     return 2 * int(num_layers) * _ceil_div(n_elem * dtype.itemsize, div) \
         + state + latent
 

@@ -13,7 +13,7 @@ from .bert import BERTModel, BERTClassifier, bert_base, bert_large, \
 
 def __getattr__(name):
     if name in ("llama", "fm", "moe", "lfm2", "sdar", "glm_moe_dsa",
-                "qwen3_next", "ouro", "nemotron_h"):
+                "qwen3_next", "ouro", "nemotron_h", "keye_vl2"):
         import importlib
 
         mod = importlib.import_module("." + name, __name__)

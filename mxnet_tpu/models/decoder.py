@@ -32,15 +32,17 @@ from typing import NamedTuple
 import numpy as np
 
 from ..base import MXNetError
-from ..ops import latent_cache, paged_attention
+from ..ops import latent_cache, paged_attention, sparse_select
 from ..ops.attention import masked_attention
 from ..ops.flash_attention import prefill_flash_attention
 from .moe import expert_product
 
 __all__ = ["CacheSpec", "BlockDecoding", "PagedDecoder", "Causal",
            "SelectingCausal", "BehindPrefix", "DenseCache", "StepView", "rms_norm",
+           "layer_norm",
            "split_heads", "rope_tables", "apply_rope",
-           "headnorm_attention", "block_commit", "causal_conv", "ring_conv",
+           "headnorm_qkv", "headnorm_attention", "block_commit",
+           "causal_conv", "ring_conv",
            "ring_at_length"]
 
 
@@ -80,8 +82,17 @@ class CacheSpec:
     owns, in the same block tables, a pool of latent rows, ``latent_dim``
     values a token, and a pool of index keys, ``index_dim`` values a
     token, stored as ``ops.latent_cache`` stores them; a query reads
-    the ``select_topk`` latent rows its indexer selects).  ``expert_layers``
-    x ``num_experts`` is the shape of the per-expert row counts that
+    the ``select_topk`` latent rows its indexer selects).  A spec of
+    ``"kv"`` layers and no latent one that states ``index_dim`` and
+    ``select_topk`` is a SELECTING K/V cache (:attr:`kv_selecting`; K/V
+    layers beside latent ones read all they see): each such layer owns
+    a third pool beside K and V, of index keys
+    (``ops.sparse_select.index_pool_shape``), its K and V pools keep
+    every KV head of a token in one stored row
+    (``ops.paged_attention.selected_rows``), and a query
+    reads the ``select_topk`` K/V rows its indexer selects.
+    ``expert_layers`` x ``num_experts`` is the shape of the per-expert
+    row counts that
     the step and prefill programs of a model with routed experts
     return beside their tokens (0: none).  ``decoding``: None for a
     decoder that yields the next token a step, left to right, or the
@@ -128,17 +139,29 @@ class CacheSpec:
                                        and self.select_topk):
             raise MXNetError("latent layers need latent_dim, index_dim "
                              "and select_topk")
+        if bool(self.index_dim) != bool(self.select_topk) or (
+                self.select_topk
+                and not (self.latent_layers or self.kv_layers)):
+            raise MXNetError(
+                "index_dim and select_topk go together and say that "
+                "layers in the block tables select what a query reads: "
+                "the latent layers where there are any, else the K/V "
+                "layers")
         self.passes = int(passes)
         if self.passes < 1:
             raise MXNetError("a stack runs at least once: passes >= 1")
         if self.passes > 1 and (self.kv_layers != len(self.layers)
-                                or self.expert_layers
+                                or self.expert_layers or self.select_topk
                                 or decoding is not None):
             raise MXNetError(
                 "a stack run several times a token (passes > 1) keeps K/V "
-                "rows a pass: a per-slot state, latent rows, routed "
-                "experts' row counts and block decoding have no per-pass "
-                "form yet")
+                "rows a pass: a per-slot state, latent rows, a selection, "
+                "routed experts' row counts and block decoding have no "
+                "per-pass form yet")
+        if self.select_topk and decoding is not None:
+            raise MXNetError(
+                "a block decoder's pass has several columns a slot; a "
+                "selecting layer's step selects for one new token a slot")
 
     @property
     def kv_layers(self):
@@ -152,13 +175,23 @@ class CacheSpec:
     def latent_layers(self):
         return self.layers.count("latent")
 
+    @property
+    def kv_selecting(self):
+        """Whether the K/V layers select what a query reads: each then
+        owns an index-key pool beside K and V."""
+        return bool(self.kv_layers and self.select_topk
+                    and not self.latent_layers)
+
     def kv_bytes_per_block(self, block_size, itemsize):
         """Bytes one block holds over every layer that keeps rows in
-        the block tables: K and V of the K/V layers, once a pass, the
-        latent rows and index keys of the latent layers as stored
-        (padding counted)."""
+        the block tables: K and V of the K/V layers, once a pass, and
+        their index keys where they select; the latent rows and index
+        keys of the latent layers; all as stored (padding counted)."""
         return 2 * self.passes * self.kv_layers * self.num_kv_heads \
             * int(block_size) * self.head_dim * int(itemsize) \
+            + self.kv_layers * self.kv_selecting \
+            * sparse_select.index_bytes_per_block(
+                block_size, self.index_dim, itemsize) \
             + self.latent_layers * latent_cache.bytes_per_block(
                 block_size, self.latent_dim, self.index_dim, itemsize)
 
@@ -201,6 +234,18 @@ def rms_norm(x, w, eps):
         .astype(x.dtype)
 
 
+def layer_norm(x, w, b, eps):
+    """LayerNorm with a weight and a bias over the last axis, in float32
+    (an indexer's key norm)."""
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    xf = xf - xf.mean(axis=-1, keepdims=True)
+    var = (xf * xf).mean(axis=-1, keepdims=True)
+    return (xf / jnp.sqrt(var + eps) * w.astype(jnp.float32)
+            + b.astype(jnp.float32)).astype(x.dtype)
+
+
 def rope_tables(t, head_dim, theta):
     """cos/sin tables (T, head_dim/2) — compile-time constants."""
     inv = 1.0 / (theta ** (np.arange(0, head_dim, 2,
@@ -233,16 +278,23 @@ def split_heads(a, n):
     return a.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
 
 
-def headnorm_attention(p, u, rope, view, num_heads, num_kv_heads, eps):
-    """GQA with an RMSNorm over each head of q and k (one learned weight
-    of ``head_dim``, shared by the heads) BEFORE RoPE, over a cache
-    view: ``u`` (B, T, H), or a step's (S, H); ``rope`` the (cos, sin)
-    rows of the call's positions over heads-major q and k; ``p`` holds
-    ``q`` / ``k`` / ``v`` / ``o`` (out, in) and ``q_norm`` / ``k_norm``
-    -> (y, what the view kept)."""
+def headnorm_qkv(p, u, num_heads, num_kv_heads, eps):
+    """The Qwen3 family's projections: ``u`` (B, T, H), or a step's (S,
+    H), through ``p["q"]`` / ``["k"]`` / ``["v"]`` (out, in), heads-major
+    (:func:`split_heads`), q and k through an RMSNorm over each head
+    (``q_norm`` / ``k_norm``: one learned weight of ``head_dim``, shared
+    by the heads), BEFORE any rotation -> (q, k, v)."""
     q = rms_norm(split_heads(u @ p["q"].T, num_heads), p["q_norm"], eps)
     k = rms_norm(split_heads(u @ p["k"].T, num_kv_heads), p["k_norm"], eps)
-    v = split_heads(u @ p["v"].T, num_kv_heads)
+    return q, k, split_heads(u @ p["v"].T, num_kv_heads)
+
+
+def headnorm_attention(p, u, rope, view, num_heads, num_kv_heads, eps):
+    """GQA over :func:`headnorm_qkv`'s heads, rotated in pairs (2i, 2i +
+    1), over a cache view: ``rope`` the (cos, sin) rows of the call's
+    positions over heads-major q and k; ``p`` also holds ``o`` -> (y,
+    what the view kept)."""
+    q, k, v = headnorm_qkv(p, u, num_heads, num_kv_heads, eps)
     q, k = apply_rope(q, *rope), apply_rope(k, *rope)
     ctx, kept = view.attend(q, k, v)
     return ctx.reshape(*u.shape[:-1], -1) @ p["o"].T, kept
@@ -400,16 +452,16 @@ class Causal:
 
 
 class SelectingCausal:
-    """Whole sequences of a latent layer (prefill): row ``t`` attends
-    the ``topk`` rows ``s <= t`` its indexer selects, all of them while
-    they are fewer.  Nothing is stored: what a cache would keep is the
-    sequence's logical ``(latent rows, index keys)``.  ``live`` (B, T)
-    the positions a request owns.  With ``lengths`` (B,) the selection
-    and the attention over the selected rows run in tiles of query rows
-    and skip those past every row's end
-    (``ops.latent_cache.causal_attention``); without, every row at once
-    in the plain expanded form
-    (``ops.latent_cache.plain_causal_attention``)."""
+    """Whole sequences of a layer that selects (prefill): row ``t``
+    attends the ``topk`` rows ``s <= t`` its indexer selects, all of
+    them while they are fewer.  Nothing is stored: what a cache would
+    keep is the sequence's logical rows, ``(latent rows, index keys)``
+    of a latent layer (:meth:`attend_latent`), ``(k, v, index keys)`` of
+    a K/V layer (:meth:`attend_selecting`).  ``live`` (B, T) the
+    positions a request owns.  With ``lengths`` (B,) the selection and
+    the attention run in tiles of query rows and skip those past every
+    row's end (``ops.sparse_select.causal_tiles``); without, every row
+    at once in the kind's plain form."""
 
     pos = None
 
@@ -429,6 +481,19 @@ class SelectingCausal:
                 make_query, latent, index_keys, per_row, self.lengths,
                 self.topk, w_uk, w_uv, scale, finish)
         return y, (latent, index_keys)
+
+    def attend_selecting(self, q, k, v, q_idx, w_idx, index_keys):
+        """A K/V layer's selection and attention: ``q`` (B, H, T, hd),
+        ``k`` / ``v`` (B, Hkv, T, hd) after their rotation, ``q_idx``
+        (B, T, J, D), ``w_idx`` (B, T, J) and ``index_keys`` (B, T, D)
+        of the indexer -> (the context (B, T, H, hd), kept)."""
+        if self.lengths is None:
+            ctx = sparse_select.kv_plain_causal_attention(
+                q, k, v, q_idx, w_idx, index_keys, self.topk)
+        else:
+            ctx = sparse_select.kv_causal_attention(
+                q, k, v, q_idx, w_idx, index_keys, self.lengths, self.topk)
+        return ctx, (k, v, index_keys)
 
 
 class BehindPrefix:
@@ -501,7 +566,9 @@ class StepView:
     """What a decode call's layer sees of the paged cache: its own
     ``entry`` (a ``(K pool, V pool)`` pair, or a state layer's
     ``CacheSpec.state_entry``: a ``(slots, L, hidden)`` state, or a
-    tuple of such), each slot's position ``pos`` and, for a K/V layer, the
+    tuple of such; a selecting layer's ``(latent pool, index-key pool)``
+    or ``(K pool, V pool, index-key pool)``), each slot's position
+    ``pos`` and, for a layer in the block tables, the
     call's ``ops.paged_attention.Window``.  The pool's layout is that
     module's; this view only says when to write and when to attend."""
 
@@ -509,7 +576,7 @@ class StepView:
 
     def __init__(self, entry, pos, win=None, topk=0):
         self.entry, self.pos, self.win, self.topk = entry, pos, win, topk
-        #: a latent layer's step leaves here what it selected: (S, k)
+        #: a selecting layer's step leaves here what it selected: (S, k)
         #: positions, -1 where a slot sees fewer than k
         self.selected = None
 
@@ -523,6 +590,19 @@ class StepView:
         return paged_attention.window_attention(q, kp, vp, self.win), \
             (kp, vp)
 
+    def _select(self, index_pool, index_keys, q_idx, w_idx):
+        """A selecting layer's step, either kind: the new token's index
+        key written in place, the slot's cached index keys scored up to
+        its position, the exact ``topk`` taken and left in
+        ``selected`` -> (the index-key pool, ``idx``, ``valid``)."""
+        import jax.numpy as jnp
+
+        ip = sparse_select.write_rows(index_pool, self.win, index_keys)
+        idx, valid = sparse_select.window_select(q_idx, w_idx, ip,
+                                                 self.win, self.topk)
+        self.selected = jnp.where(valid, idx, -1)
+        return ip, idx, valid
+
     def attend_latent(self, make_query, latent, index_keys, per_row,
                       w_uk, w_uv, scale, finish):
         """A latent layer's step: the new token's latent row and index
@@ -533,14 +613,32 @@ class StepView:
         import jax.numpy as jnp
 
         lp = latent_cache.write_rows(self.entry[0], self.win, latent)
-        ip = latent_cache.write_rows(self.entry[1], self.win, index_keys)
         q_nope, q_rope, q_idx, w_idx = make_query(*per_row)
-        idx, valid = latent_cache.window_select(q_idx, w_idx, ip, self.win,
-                                                self.topk)
-        self.selected = jnp.where(valid, idx, -1)
+        ip, idx, valid = self._select(self.entry[1], index_keys, q_idx,
+                                      w_idx)
         heads = latent_cache.window_attention(
             q_nope, q_rope, lp, self.win, idx, valid, w_uk, w_uv, scale)
         return finish(heads), (lp, ip)
+
+    def attend_selecting(self, q, k, v, q_idx, w_idx, index_keys):
+        """A selecting K/V layer's step: the new token's K, V and index
+        key (``q`` (S, H, 1, hd), ``k`` / ``v`` (S, Hkv, 1, hd),
+        ``index_keys`` (S, D)) written in place, the slot's cached index
+        keys scored up to its position with ``q_idx`` (S, J, D) /
+        ``w_idx`` (S, J), the exact ``topk`` taken and those rows of K
+        and V alone attended, through the block table -> (the context
+        (S, H, hd), the three pools)."""
+        import jax
+
+        kp = paged_attention.write_rows(self.entry[0], self.win, k)
+        vp = paged_attention.write_rows(self.entry[1], self.win, v)
+        ip, idx, valid = self._select(self.entry[2], index_keys, q_idx,
+                                      w_idx)
+        with jax.named_scope("gqa_selected_attention"):
+            ctx = sparse_select.gqa_selected_attention(
+                q[:, :, 0], paged_attention.selected_rows(kp, self.win, idx),
+                paged_attention.selected_rows(vp, self.win, idx), valid)
+        return ctx, (kp, vp, ip)
 
 
 # -- the programs -----------------------------------------------------------------

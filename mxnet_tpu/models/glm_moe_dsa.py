@@ -47,7 +47,7 @@ from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from .decoder import (CacheSpec, PagedDecoder, SelectingCausal, apply_rope,
-                      rms_norm, rope_tables)
+                      layer_norm, rms_norm, rope_tables)
 from . import mla
 from .llama import RMSNorm
 from .moe import expert_layer_ffn, expert_product
@@ -193,8 +193,8 @@ class GlmMath:
         with jax.named_scope("mla_project"):
             one = (cos[..., 0, :], sin[..., 0, :])      # no head axis
             c_q, latent = mla.latent_rows(p, u, one, kl, eps)
-            k_idx = _layer_norm(u @ p["idx_k"].T, p["idx_k_norm"],
-                                p["idx_k_bias"])
+            k_idx = layer_norm(u @ p["idx_k"].T, p["idx_k_norm"],
+                               p["idx_k_bias"], INDEX_NORM_EPS)
             k_idx = jnp.concatenate(
                 [apply_rope(k_idx[..., :dr], *one), k_idx[..., dr:]],
                 axis=-1)
@@ -244,16 +244,6 @@ class GlmMath:
         x = x + y
         y, counts = self.ffn(p, rms_norm(x, p["ffn_norm"], eps), view.live)
         return x + y, kept, counts
-
-
-def _layer_norm(x, w, b):
-    import jax.numpy as jnp
-
-    xf = x.astype(jnp.float32)
-    xf = xf - xf.mean(axis=-1, keepdims=True)
-    var = (xf * xf).mean(axis=-1, keepdims=True)
-    return (xf / jnp.sqrt(var + INDEX_NORM_EPS) * w.astype(jnp.float32)
-            + b.astype(jnp.float32)).astype(x.dtype)
 
 
 class GlmMoeDsaLayer(HybridBlock):

@@ -60,7 +60,10 @@ several times keeps its rows (:func:`pass_blocks`,
 :func:`scatter_pass_rows`), where a decode call's rows
 go and what its queries see (:func:`window`), the row write
 (:func:`write_rows`) and the decode attention, kernel or gather
-(:func:`window_attention`).  No other module indexes a pool's axes,
+(:func:`window_attention`), and the fetch of the rows a selection names
+(:func:`selected_rows`: a selecting layer's pools keep every KV head of
+a token in one stored row, ``pack`` = ``Hkv``).  No
+other module indexes a pool's axes,
 computes with ``pack`` or writes a pool with ``mode="drop"``.
 """
 from __future__ import annotations
@@ -300,6 +303,24 @@ def window_attention(q, k_pool, v_pool, win):
     kc, vc = (gathered_view(p, win.gat, pack) for p in (k_pool, v_pool))
     ctx = masked_attention(q, kc, vc, win.mask)
     return ctx if step else ctx.transpose(0, 2, 1, 3)
+
+
+def selected_rows(pool, win, idx):
+    """The rows a selection names, and no others: ``idx`` (S, k)
+    positions (``ops.sparse_select.window_select``'s), each fetched
+    through the slot's block table, whatever the context's length.  The
+    pool keeps a token in ONE stored row (a one-head-row pool; a
+    selecting K/V layer's pools at ``pack = Hkv``: ``(num_blocks, 1,
+    block_size, Hkv * hd)``), so a selected position is one contiguous
+    read and not ``Hkv`` reads a block apart -> (S, k, lanes); what the
+    rows mean, and the attention over them, is the caller's."""
+    nb, rows, bs, lanes = pool.shape
+    if rows != 1:
+        raise ValueError(
+            "a selection reads pools that keep a token in one row; this "
+            f"one has {rows} rows")
+    blk = jnp.take_along_axis(win.gat, idx // bs, axis=1)
+    return pool.reshape(nb * bs, lanes)[blk * bs + idx % bs]
 
 
 def _schedule(tables, lengths, num_blocks, block_size, chunk):

@@ -171,7 +171,9 @@ _DENSE_ONLY = ("int8=True quantizes the dense decoder's matrices only; a "
 #: nothing falls back.  The traits are :func:`refuse`'s to read off a
 #: spec: ``"loop"`` (a stack run several times a token, ``passes`` above
 #: 1), ``"latent"`` (layers that keep latent rows and select what a
-#: query reads), ``"block"`` (a block decoder, ``decoding``) and
+#: query reads), ``"kv_select"`` (K/V layers that keep an index key
+#: beside and select what a query reads: ``CacheSpec.kv_selecting``),
+#: ``"block"`` (a block decoder, ``decoding``) and
 #: ``"state"`` (per-slot state, or routed experts).  A new cache kind
 #: costs rows here and nothing in the callers.
 REFUSALS = {
@@ -213,6 +215,19 @@ REFUSALS = {
         "radix_cache=True reuses K/V blocks behind a causal suffix; the "
         "suffix prefill has no view over latent blocks and their index "
         "keys",
+    ("kv_select", "spec"):
+        "speculative decoding (draft_net / spec_k) verifies a window of "
+        "columns a slot; a selecting K/V layer's step selects and "
+        "attends for one new token a slot",
+    ("kv_select", "mesh"):
+        "a mesh-placed engine (mesh=) has no partition rule for an "
+        "index-key pool beside K and V, for pools that keep a token's "
+        "KV heads in one row, or for an expert bank",
+    ("kv_select", "int8"): _DENSE_ONLY,
+    ("kv_select", "radix"):
+        "radix_cache=True reuses K/V blocks behind a causal suffix; the "
+        "suffix prefill has no view that selects over the prefix's index "
+        "keys, and a suffix row would read every shared row",
     ("loop", "spec"):
         "speculative decoding (draft_net / spec_k) has not been carried "
         "through the loop over passes: a stack run several times a token "
@@ -237,6 +252,7 @@ def refuse(spec, *, spec_k=0, mesh=None, int8=False, radix=False):
     decoded the next token a step, is refused nothing."""
     trait = "loop" if spec.passes > 1 else \
         "latent" if spec.latent_layers else \
+        "kv_select" if spec.kv_selecting else \
         "block" if spec.decoding is not None else \
         "state" if spec.state_layers or spec.expert_layers else None
     if trait is None:
@@ -371,17 +387,22 @@ class LlamaServingEngine:
         self.max_blocks = -(-self.max_len // self.block_size)
         self.num_blocks = int(num_blocks or
                               self.num_slots * self.max_blocks)
-        from ..ops import latent_cache, paged_attention
+        from ..ops import latent_cache, paged_attention, sparse_select
 
         # decided once, before the pool is made, from where the
         # weights (and so the pool) live, the mesh and the shapes:
         # the kernel reads whole 128-lane rows, so under it heads of
         # 64 are stored ``kv_pack`` = 2 to a row, (blocks, Hkv // 2,
-        # bs, 128); the gather path keeps one head a row
-        pack = paged_attention.applicable(
-            platform, mesh, spec.head_dim, spec.num_kv_heads,
-            self.block_size, dt) if spec.kv_layers else 0
-        paged_kernel = pack > 0
+        # bs, 128); the gather path keeps one head a row.  A selecting
+        # K/V layer's step reads the rows its indexer names and never
+        # walks a slot's blocks: no paged kernel, and every KV head of
+        # a token in ONE stored row, so that a selected position is one
+        # read a pool (``paged_attention.selected_rows``)
+        pack = spec.num_kv_heads if spec.kv_selecting else \
+            paged_attention.applicable(
+                platform, mesh, spec.head_dim, spec.num_kv_heads,
+                self.block_size, dt) if spec.kv_layers else 0
+        paged_kernel = pack > 0 and not spec.kv_selecting
         self.kv_pack = pack = max(1, pack)
         # a stack run several times keeps a pass's blocks behind the
         # pass before's, in one pool a layer
@@ -391,11 +412,15 @@ class LlamaServingEngine:
         lshapes = latent_cache.pool_shapes(
             self.num_blocks, self.block_size, spec.latent_dim,
             spec.index_dim)
-        # one entry a layer, by the spec: a (K, V) pool pair, a
+        # one entry a layer, by the spec: a (K, V) pool pair (with the
+        # layer's index-key pool third where the K/V layers select), a
         # (latent rows, index keys) pool pair, or the arrays of the
         # layer's per-slot state, each of its own dtype
+        ishape = sparse_select.index_pool_shape(
+            self.num_blocks, self.block_size, spec.index_dim)
         self._pool = [
             (jnp.zeros(pshape, dt), jnp.zeros(pshape, dt))
+            + ((jnp.zeros(ishape, dt),) if spec.kv_selecting else ())
             if kind == "kv" else
             tuple(jnp.zeros(shape, dt) for shape in lshapes)
             if kind == "latent" else
@@ -481,10 +506,14 @@ class LlamaServingEngine:
         numerics_on = self._numerics
         #: which attention the step and verify programs were built
         #: with: "paged_kernel" (ops/paged_attention.py reads the pool
-        #: in place) or "gather" (a dense per-slot view through the
-        #: table); ``kv_pack`` beside it says how many KV heads a stored
-        #: row holds (above 1 only under the kernel)
+        #: in place), "gather" (a dense per-slot view through the
+        #: table), or, of a model whose layers select what a query
+        #: reads, "latent_sparse" / "kv_sparse" (the selected latent
+        #: rows, or K/V rows, alone); ``kv_pack`` beside it says how
+        #: many KV heads a stored row holds (above 1 under the kernel,
+        #: and every KV head of a token where K/V layers select)
         self.decode_attention = "latent_sparse" if spec.latent_layers \
+            else "kv_sparse" if spec.kv_selecting \
             else "paged_kernel" if paged_kernel else "gather"
         #: which form the step's linear-attention layers take
         #: (``ops.gated_delta.step_form``): "step_kernel" (the state
@@ -648,13 +677,15 @@ class LlamaServingEngine:
             # width) go block by block as its format stores them
             # (of a stack run several times: (passes, KB, Hkv, Lp,
             # hd), pass t's into that pass's blocks of the pool)
-            by_block = {"kv": paged_attention.scatter_pass_rows
-                        if spec.passes > 1
-                        else paged_attention.scatter_rows,
-                        "latent": latent_cache.scatter_rows}
+            # (a selecting K/V layer's third pool and third rows: its
+            # index keys (KB, Lp, width), by the index-key format)
+            kv = paged_attention.scatter_pass_rows if spec.passes > 1 \
+                else paged_attention.scatter_rows
+            by_block = {"kv": (kv, kv, sparse_select.scatter_rows),
+                        "latent": (latent_cache.scatter_rows,) * 2}
             return [
-                tuple(by_block[kind](p, r, flat_idx)
-                      for p, r in zip(entry, row))
+                tuple(put(p, r, flat_idx)
+                      for put, p, r in zip(by_block[kind], entry, row))
                 if kind in by_block
                 else jax.tree_util.tree_map(
                     lambda e, r: e.at[slots].set(r, mode="drop"),
@@ -794,9 +825,10 @@ class LlamaServingEngine:
     def prefill_attention_at(self, lp):
         """``"flash"`` or ``"dense"``: which attention the prefill
         program of a bucket ``lp`` positions long runs;
-        ``"latent_sparse"`` where the layers select what they read."""
-        if self.cache_spec.latent_layers:
-            return "latent_sparse"
+        ``"latent_sparse"`` / ``"kv_sparse"`` where the layers select
+        what they read."""
+        if self.cache_spec.select_topk:
+            return self.decode_attention
         return "flash" if self._prefill_flash(lp) else "dense"
 
     def selection_counts(self, seen, whole=False):
@@ -804,8 +836,9 @@ class LlamaServingEngine:
         layer: over rows that each see ``seen`` positions (a step's
         active slots) or, ``whole``, over every row of prompts ``seen``
         tokens long, the positions visible and the positions read (at
-        most ``select_topk`` a row); {} for a model that reads all it
-        sees."""
+        most ``select_topk`` a row); and ``index_key_bytes`` /
+        ``selected_kv_bytes``, the bytes those are over every selecting
+        layer; {} for a model that reads all it sees."""
         k = self.cache_spec.select_topk
         if not k:
             return {}
@@ -816,8 +849,19 @@ class LlamaServingEngine:
             read = m * (m + 1) // 2 + (n - m) * k
         else:
             visible, read = n, np.minimum(n, k)
+        # what the scoring and the attention had to read over the
+        # selecting layers: a logical index key a visible position, and
+        # what a selected position keeps (its K and V rows, or its
+        # latent row), without the stored rows' padding
+        spec, size = self.cache_spec, self.cache_itemsize
+        layers = spec.latent_layers or spec.kv_layers
+        row = spec.latent_dim if spec.latent_layers \
+            else 2 * spec.num_kv_heads * spec.head_dim
         return {"kv_visible": int(visible.sum()),
-                "kv_selected": int(read.sum())}
+                "kv_selected": int(read.sum()),
+                "index_key_bytes":
+                    int(visible.sum()) * layers * spec.index_dim * size,
+                "selected_kv_bytes": int(read.sum()) * layers * row * size}
 
     def selection_of(self, slot, step):
         """What each layer of ``step`` (its :class:`StepHandle`) read for
@@ -844,7 +888,8 @@ class LlamaServingEngine:
         ``plan_kv_pool`` predicts pre-build.  ``by_kind`` splits it:
         ``{"kv_blocks": ..., "slot_state": ...}`` and, where a layer
         keeps them, ``"latent_blocks"`` and ``"index_key_blocks"`` (as
-        stored, padding counted) and, where a state layer owns several
+        stored, padding counted; a selecting K/V layer's index keys
+        beside its ``"kv_blocks"``) and, where a state layer owns several
         arrays, ``"slot_state_arrays"``: the bytes of each over the
         layers, in the spec's order.  On a tp mesh each
         device holds one shard of the pool's head axis, so this is the
@@ -865,13 +910,17 @@ class LlamaServingEngine:
             state = sum(arrays)
             latent, keys = (sum(shard_bytes(e[i]) for k, e in zip(kinds, kv)
                                 if k == "latent") for i in (0, 1))
+            if self.cache_spec.kv_selecting:
+                keys = sum(shard_bytes(e[2]) for k, e in zip(kinds, kv)
+                           if k == "kv")
         if by_kind:
             out = {"kv_blocks": int(blocks), "slot_state": int(state)}
             if len(arrays) > 1:
                 out["slot_state_arrays"] = tuple(int(a) for a in arrays)
             if self.cache_spec.latent_layers:
-                out.update(latent_blocks=int(latent),
-                           index_key_blocks=int(keys))
+                out["latent_blocks"] = int(latent)
+            if self.cache_spec.select_topk:
+                out["index_key_blocks"] = int(keys)
             return out
         return int(blocks + state + latent + keys)
 

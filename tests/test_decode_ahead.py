@@ -15,6 +15,7 @@ it to mean; and the verify program, whose tick stays serial, lowers as before.
 import hashlib
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -28,12 +29,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "chipbench")
 
 #: a dense paged model, LFM2's ring, the gated delta rule's float32 state,
-#: a model that selects the keys a query reads (8 of them: ``index_topk``)
+#: two models that select the keys a query reads (8 of them: ``index_topk``):
+#: out of a latent cache, and out of K/V pools
 MODELS = ("llama_tiny", "lfm2_moe_tiny", "qwen3_next_tiny",
-          "glm_moe_dsa_tiny")
+          "glm_moe_dsa_tiny", "keye_vl2_tiny")
 _MODULE = {"llama_tiny": "llama", "lfm2_moe_tiny": "lfm2",
            "qwen3_next_tiny": "qwen3_next", "glm_moe_dsa_tiny": "glm_moe_dsa",
-           "sdar_moe_tiny": "sdar"}
+           "keye_vl2_tiny": "keye_vl2", "sdar_moe_tiny": "sdar"}
 SLOTS, BLOCK, MAX_LEN = 3, 4, 64
 
 
@@ -195,7 +197,8 @@ def test_pos_moves_at_dispatch_and_the_handle_keeps_its_own(model):
         picked = eng.selection_of(2, s1)
         assert picked.shape[-1] == k and (picked >= 0).sum(-1).max() == k
         assert picked.max() == 10 and eng.selection_of(2, s2).max() == 11
-        assert s1.selection == {"kv_visible": 11, "kv_selected": k}
+        assert (s1.selection["kv_visible"], s1.selection["kv_selected"]) \
+            == (11, k)
 
 
 # --- through the lanes ---------------------------------------------------------
@@ -1029,6 +1032,19 @@ def test_an_exception_in_either_half_of_a_block_pass(block, half, monkeypatch):
             return real(*args)
         return call
 
+    # the decode lane holds still, outside the device lock, until BOTH
+    # prefills are committed: neither request has a pass before the other
+    # is there to be adopted, so the first cannot run out its 40 tokens
+    # (ten blocks of passes) before the second decodes, whatever the
+    # worker's load, and the fault is armed with both in flight
+    both_committed = threading.Event()
+    real_dispatch = eng.dispatch_step
+
+    def held(active):
+        assert both_committed.wait(120)
+        return real_dispatch(active)
+
+    eng.dispatch_step = held
     with srv:
         if half == "fetch":
             monkeypatch.setattr(generative, "_materialize",
@@ -1036,8 +1052,12 @@ def test_an_exception_in_either_half_of_a_block_pass(block, half, monkeypatch):
         else:
             eng._step = planted(real_step, half)
         lost = [srv.submit(p, max_new_tokens=40) for p in prompts[:2]]
+        while any(f.request.t_commit is None for f in lost):
+            time.sleep(0.001)
+        both_committed.set()
         while any(f.request.first_tick is None for f in lost):
             time.sleep(0.001)         # both decode: two passes in flight
+        assert not any(f.done() for f in lost)
         armed["on"] = True
         for f in lost:
             with pytest.raises(RuntimeError, match="planted"):

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import nd, serving
 from mxnet_tpu.models import glm_moe_dsa as glm
-from mxnet_tpu.ops import latent_cache
+from mxnet_tpu.ops import latent_cache, sparse_select
 from mxnet_tpu.serving import ServerConfig
 from mxnet_tpu.serving.protocol import Request
 from mxnet_tpu.telemetry import tracing
@@ -219,11 +219,11 @@ def test_prefill_then_decode_through_the_latent_cache_equals_reference(
 def test_selection_is_exact_with_ties_to_the_earlier_position():
     scores = jnp.asarray([[3., 1., 3., 2., 3., 0., 3., 9.]])
     visible = jnp.asarray([[True] * 7 + [False]])
-    idx, valid = latent_cache.select(scores, visible, 3)
+    idx, valid = sparse_select.select(scores, visible, 3)
     assert idx.tolist() == [[0, 2, 4]] and valid.all()
-    idx, valid = latent_cache.select(scores[:, :2], visible[:, :2], 3)
+    idx, valid = sparse_select.select(scores[:, :2], visible[:, :2], 3)
     assert idx.shape == (1, 2) and valid.all()
-    idx, valid = latent_cache.select(scores, jnp.arange(8)[None] < 2, 3)
+    idx, valid = sparse_select.select(scores, jnp.arange(8)[None] < 2, 3)
     assert sorted(idx[0][np.asarray(valid[0])].tolist()) == [0, 1]
     assert int(valid.sum()) == 2
 
@@ -240,44 +240,60 @@ def test_the_selection_as_a_mask_is_the_sorted_selection_to_the_bit():
             sc = np.round(sc * 2) / 2
         sc[0, :50], sc[1, 10:40] = -0.0, 0.0
         vis = np.arange(t)[None, :] <= rs.randint(0, t, size=(5, 1))
-        idx, valid = latent_cache.select(jnp.asarray(sc), jnp.asarray(vis), k)
-        got = latent_cache.select_mask(jnp.asarray(sc), jnp.asarray(vis), k)
+        idx, valid = sparse_select.select(jnp.asarray(sc), jnp.asarray(vis), k)
+        got = sparse_select.select_mask(jnp.asarray(sc), jnp.asarray(vis), k)
         assert (np.asarray(got) == np.asarray(
-            latent_cache.chosen_mask(idx, valid, t))).all()
+            sparse_select.chosen_mask(idx, valid, t))).all()
         assert (np.asarray(got).sum(-1) == np.minimum(vis.sum(-1), k)).all()
         # more may be read than there are positions: all that are visible
-        assert (np.asarray(latent_cache.select_mask(
+        assert (np.asarray(sparse_select.select_mask(
             jnp.asarray(sc), jnp.asarray(vis), t + 100)) == vis).all()
 
 
 @pytest.mark.parametrize("extent", [128, 4096])
-def test_tiled_absorbed_prefill_equals_the_plain_expanded_form(monkeypatch,
-                                                               extent):
-    """``ops.latent_cache``'s two forms at sizes where the prefill runs in
-    several query tiles, in one group of keys or three (a group reads the
-    keys up to its own end), and skips the tiles past the sequences'
-    ends."""
-    monkeypatch.setattr(latent_cache, "KEY_EXTENT", extent)
+@pytest.mark.parametrize("kind", ["latent", "kv"])
+def test_tiled_prefill_equals_the_plain_form(monkeypatch, kind, extent):
+    """The prefill's tiles (``ops.sparse_select.causal_tiles``: one loop,
+    one scoring, one mask for both kinds that select) against the kind's
+    plain form, at sizes where the prefill runs in several query tiles, in
+    one group of keys or three (a group reads the keys up to its own end),
+    scores nothing where a tile sees no more than ``topk`` positions, and
+    skips the tiles past the sequences' ends: the latent kind's absorbed
+    form against the expanded one, the K/V kind's masked form against
+    ``masked_attention`` under the sorted selection."""
+    monkeypatch.setattr(sparse_select, "KEY_EXTENT", extent)
     rs = np.random.RandomState(2)
-    b, t, nh, dn, dr, dv, rank, ih, idim, topk = 2, 384, 2, 8, 4, 8, 16, 2, 8, 40
-    latent = jnp.asarray(rs.randn(b, t, rank + dr), jnp.float32)
+    b, t, nh, dn, dr, dv, rank, ih, idim = 2, 384, 2, 8, 4, 8, 16, 2, 8
+    topk = 40 if kind == "latent" else 160
     keys = jnp.asarray(rs.randn(b, t, idim), jnp.float32)
-    parts = (jnp.asarray(rs.randn(b, t, nh, dn), jnp.float32),
-             jnp.asarray(rs.randn(b, t, nh, dr), jnp.float32),
-             jnp.asarray(rs.randn(b, t, ih, idim), jnp.float32),
-             jnp.asarray(rs.randn(b, t, ih), jnp.float32))
-    w_uk = jnp.asarray(rs.randn(nh, dn, rank), jnp.float32)
-    w_uv = jnp.asarray(rs.randn(nh, dv, rank), jnp.float32)
-    args = (w_uk, w_uv, 0.3, lambda heads: heads.reshape(heads.shape[:2] + (-1,)))
+    q_idx = jnp.asarray(rs.randn(b, t, ih, idim), jnp.float32)
+    w_idx = jnp.asarray(rs.randn(b, t, ih), jnp.float32)
+    lengths = jnp.asarray([200, 130])
     with jax.default_matmul_precision("highest"):
-        plain = latent_cache.plain_causal_attention(
-            lambda *p: p, latent, keys, parts, topk, *args)
-        lengths = jnp.asarray([200, 130])
-        tiled = latent_cache.causal_attention(
-            lambda *p: p, latent, keys, parts, lengths, topk, *args)
-    plain, tiled = np.asarray(plain), np.asarray(tiled)
+        if kind == "latent":
+            latent = jnp.asarray(rs.randn(b, t, rank + dr), jnp.float32)
+            parts = (jnp.asarray(rs.randn(b, t, nh, dn), jnp.float32),
+                     jnp.asarray(rs.randn(b, t, nh, dr), jnp.float32),
+                     q_idx, w_idx)
+            args = (jnp.asarray(rs.randn(nh, dn, rank), jnp.float32),
+                    jnp.asarray(rs.randn(nh, dv, rank), jnp.float32), 0.3,
+                    lambda heads: heads.reshape(heads.shape[:2] + (-1,)))
+            plain = latent_cache.plain_causal_attention(
+                lambda *p: p, latent, keys, parts, topk, *args)
+            tiled = latent_cache.causal_attention(
+                lambda *p: p, latent, keys, parts, lengths, topk, *args)
+        else:
+            q = jnp.asarray(rs.randn(b, 2 * nh, t, dn), jnp.float32)
+            k = jnp.asarray(rs.randn(b, nh, t, dn), jnp.float32)
+            v = jnp.asarray(rs.randn(b, nh, t, dn), jnp.float32)
+            plain = sparse_select.kv_plain_causal_attention(
+                q, k, v, q_idx, w_idx, keys, topk)
+            tiled = sparse_select.kv_causal_attention(
+                q, k, v, q_idx, w_idx, keys, lengths, topk)
+    plain = np.asarray(plain).reshape(b, t, -1)
+    tiled = np.asarray(tiled).reshape(b, t, -1)
     # rows of the tiles that hold a live row of some sequence
-    live = -(-200 // latent_cache.QUERY_TILE) * latent_cache.QUERY_TILE
+    live = -(-200 // sparse_select.QUERY_TILE) * sparse_select.QUERY_TILE
     _close(tiled[:, :live], plain[:, :live], 1e-5)
     assert not tiled[:, live:].any()
 
@@ -691,20 +707,24 @@ def test_rehearsal_of_the_cell_on_the_cpu(harness, capsys, trace):
         assert set(res["metrics"]) == {"ttft_p90_ms", "tpot_p90_ms", "setup_s"}
 
 
-def _run_planted(harness, capsys):
-    res = harness.run(["--workload", "tiny_glm.open", "--seed", "11",
+def _run_planted(harness, capsys, data=DATA, cell="tiny_glm.open"):
+    res = harness.run(["--workload", cell, "--seed", "11",
                        "--seconds", "3", "--trace", "0", "--control", "0"],
-                      require_tpu=False, data_dir=DATA)
+                      require_tpu=False, data_dir=data)
     out = capsys.readouterr().out
     assert res["correct"] is False and res["failed"] == 0
     assert "FAILED" in out
     return _compared(out)
 
 
+@pytest.mark.parametrize("kind,data,cell", [
+    ("latent", "data_glm", "tiny_glm.open"),
+    ("kv", "data_keye_vl2", "tiny_keye.sat")])
 def test_most_recent_keys_instead_of_the_indexers_is_not_correct(
-        harness, capsys, monkeypatch):
-    """Planted: the selection takes the ``index_topk`` most recent visible
-    positions, whatever the indexer scored."""
+        harness, capsys, monkeypatch, kind, data, cell):
+    """Planted, in the one selection both kinds call: it takes the
+    ``index_topk`` most recent visible positions, whatever the indexer
+    scored; each kind's cell refuses it by the selection's own row."""
     def recent(scores, visible, topk):
         rank = jnp.broadcast_to(
             jnp.where(visible, jnp.arange(scores.shape[-1],
@@ -713,22 +733,19 @@ def test_most_recent_keys_instead_of_the_indexers_is_not_correct(
         vals, idx = jax.lax.top_k(rank, min(topk, scores.shape[-1]))
         return idx.astype(jnp.int32), vals > -jnp.inf
 
-    monkeypatch.setattr(latent_cache, "select", recent)
-    compared = _run_planted(harness, capsys)
-    assert compared["selection_miss_max"] > _check_limits()[
-        "selection_miss_limit"]
+    monkeypatch.setattr(sparse_select, "select", recent)
+    data = os.path.join(BENCH, "tests", data)
+    compared = _run_planted(harness, capsys, data, cell)
+    traffic = os.path.join(data, "traffic", cell.split(".")[1] + ".json")
+    assert compared["selection_miss_max"] > json.load(open(traffic))[
+        "check"]["selection_miss_limit"]
 
 
 def test_a_stale_index_key_is_not_correct(harness, capsys, monkeypatch):
     """Planted: a step writes its token's latent row and leaves the index
     key pool as it was, so later steps score what the block held before."""
-    whole, calls = latent_cache.write_rows, []
-
-    def stale(pool, win, rows):
-        calls.append(1)
-        return whole(pool, win, rows) if len(calls) % 2 else pool
-
-    monkeypatch.setattr(latent_cache, "write_rows", stale)
+    monkeypatch.setattr(sparse_select, "write_rows",
+                        lambda pool, win, rows: pool)
     compared = _run_planted(harness, capsys)
     chk = _check_limits()
     assert any(compared[row] > chk[key] for row, key in LIMIT_ROWS)

@@ -1069,7 +1069,7 @@ def test_latent_sparse_programs_compile_for_v5e_without_whole_arrays(one_chip):
 
     from mxnet_tpu.models import glm_moe_dsa as glm
     from mxnet_tpu.models import moe
-    from mxnet_tpu.ops import latent_cache
+    from mxnet_tpu.ops import latent_cache, sparse_select
 
     lp, slots, nb, bs = 16384, 16, 24576, 16
     cfg = glm.GlmMoeDsaConfig(num_layers=2, first_k_dense=1,
@@ -1110,10 +1110,74 @@ def test_latent_sparse_programs_compile_for_v5e_without_whole_arrays(one_chip):
     assert not re.findall(rf"\w+\[{lp},16,2048\]", text)
     assert re.findall(rf"\w+\[{moe.EVERY_EXPERT_ROWS},16,2048\]", text)
     # a tile's index scores before the heads are summed, at the last extent
-    assert f"f32[{latent_cache.QUERY_TILE},32,{lp}]" in text
+    assert f"f32[{sparse_select.QUERY_TILE},32,{lp}]" in text
     assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
     text = step.as_text()
-    assert re.findall(rf"bf16\[{slots},32768,128\]", text)     # index keys
+    # index keys a chunk of positions at a time, never the table's width
+    assert re.findall(rf"bf16\[{slots},{sparse_select.SCORE_CHUNK},128\]",
+                      text)
+    assert not re.findall(rf"bf16\[{slots},32768,128\]", text)
     assert re.findall(rf"bf16\[{slots},2048,640\]", text)      # selected rows
     assert not re.findall(rf"\w+\[{slots},32768,640\]", text)
+    assert step.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_kv_sparse_programs_compile_for_v5e_without_whole_arrays(one_chip):
+    """Keye-VL-2.0's widths (``keye_vl2.longctx_decode_sat``; two layers:
+    every layer's arrays have the cell's shapes, and a program's
+    temporaries are one layer's): the prefill program at the 32,768 bucket
+    holds no ``(L, L)`` array of scores or of a mask and under 2.5 GB of
+    temporaries; the step program over 16 slots with tables of 32,768
+    positions reads the index keys a chunk at a time and gathers 2,048
+    rows of 512 lanes a slot a pool, never a slot's K/V view."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.models import keye_vl2 as keye
+    from mxnet_tpu.ops import sparse_select
+
+    lp, slots, nb, bs = 32768, 16, 24576, 16
+    cfg = keye.KeyeVl2Config(num_layers=2, max_seq_len=32768)
+
+    class Net:                              # never initialized: shapes only
+        config = cfg
+
+    dec = keye.KeyeDecoder(Net(), 32768)
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = dict(layers=[{n: sds(*s) for n, s in
+                      keye._layer_param_shapes(cfg).items()}
+                     for _ in range(2)],
+             emb=sds(151936, 2048), norm=sds(2048), head=sds(151936, 2048))
+    pools = [(sds(nb, 1, bs, 512), sds(nb, 1, bs, 512), sds(nb, 1, bs, 128))
+             for _ in range(2)]
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            prefill = jax.jit(dec._prefill_rows_impl).lower(
+                w, sds(1, lp, dtype=jnp.int32),
+                sds(1, dtype=jnp.int32)).compile()
+            step = jax.jit(dec._step_blocks_impl, donate_argnums=(1,)).lower(
+                w, pools, sds(slots, 32768 // bs, dtype=jnp.int32),
+                sds(slots, dtype=jnp.int32),
+                sds(slots, dtype=jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    text = prefill.as_text()
+    assert not re.findall(rf"\w+\[(?:\d+,)*{lp},{lp}\]", text)
+    # a tile's index scores before the heads are summed, at the last extent
+    assert f"f32[{sparse_select.QUERY_TILE},16,{lp}]" in text \
+        or f"f32[1,{sparse_select.QUERY_TILE},16,{lp}]" in text
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.5e9
+    text = step.as_text()
+    assert re.findall(rf"bf16\[{slots},{sparse_select.SCORE_CHUNK},128\]",
+                      text)
+    assert not re.findall(rf"bf16\[{slots},32768,\d+\]", text)
+    assert re.findall(rf"bf16\[{slots},2048,512\]", text)      # selected rows
     assert step.memory_analysis().temp_size_in_bytes < 0.5e9

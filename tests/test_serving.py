@@ -634,6 +634,9 @@ def _refusal_specs():
         "loop": CacheSpec(("kv", "kv"), passes=2, **kw),
         "latent": CacheSpec(("latent", "kv"), latent_dim=8, index_dim=4,
                             select_topk=2, **kw),
+        # K/V layers that keep an index key beside and select
+        "kv_select": CacheSpec(("kv", "kv"), index_dim=4, select_topk=2,
+                               **kw),
         "block": CacheSpec(("kv",), decoding=blocks, **kw),
         "state": CacheSpec(("state", "kv"), state_shape=(3, 8), **kw),
         # routed experts and no state layer: the same trait

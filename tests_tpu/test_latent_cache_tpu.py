@@ -1,4 +1,4 @@
-"""``ops.latent_cache`` on the chip: the XLA selection paths the served programs
+"""``ops.latent_cache`` (and the selection it shares, ``ops.sparse_select``) on the chip: the XLA selection paths the served programs
 run (the step's: exact ``lax.top_k`` selection, gathered rows, the absorbed
 form; the prefill's: the same set as a mask by bisection, the absorbed form
 under it) against
@@ -42,10 +42,10 @@ def test_top_k_lists_equal_scores_by_position(parity_record):
     import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops import latent_cache as lc
+    from mxnet_tpu.ops import sparse_select as ss
 
     scores = jnp.zeros((8, T), jnp.float32).at[:, ::5].set(1.0)
-    idx, valid = jax.jit(lambda s: lc.select(s, jnp.ones_like(s, bool),
+    idx, valid = jax.jit(lambda s: ss.select(s, jnp.ones_like(s, bool),
                                              TOPK))(scores)
     assert bool(valid.all())
     assert (np.asarray(idx) == np.arange(TOPK) * 5).all()
@@ -59,16 +59,17 @@ def test_prefill_tiles_match_the_plain_form_at_16k(parity_record):
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import latent_cache as lc
+    from mxnet_tpu.ops import sparse_select as ss
 
-    rows = 2 * lc.QUERY_TILE
+    rows = 2 * ss.QUERY_TILE
     a = _inputs(rows)
     at = T - rows
     cols = jnp.arange(T)
     visible = cols[None, :] <= (at + jnp.arange(rows))[:, None]
 
     def fast(a):
-        scores = lc.index_scores(a["q_idx"], a["w_idx"], a["keys"])
-        idx, valid = lc.select(scores, visible, TOPK)
+        scores = ss.index_scores(a["q_idx"], a["w_idx"], a["keys"])
+        idx, valid = ss.select(scores, visible, TOPK)
         stored = jnp.pad(a["latent"], ((0, 0), (0, 0), (0, 64)))
         got = jax.vmap(lambda s, ix: s[ix])(stored, idx)
         return lc.selected_attention(a["q_nope"], a["q_rope"], got, valid,
@@ -76,13 +77,13 @@ def test_prefill_tiles_match_the_plain_form_at_16k(parity_record):
 
     def plain(a, idx, valid):
         return lc.plain_attention(a["q_nope"], a["q_rope"], a["latent"],
-                                  lc.chosen_mask(idx, valid, T), a["w_uk"],
+                                  ss.chosen_mask(idx, valid, T), a["w_uk"],
                                   a["w_uv"], 0.0625)
 
     def masked(a):
         # the prefill's own form: the set as a mask, rows in order
-        scores = lc.index_scores(a["q_idx"], a["w_idx"], a["keys"])
-        chosen = lc.select_mask(scores, visible, TOPK)
+        scores = ss.index_scores(a["q_idx"], a["w_idx"], a["keys"])
+        chosen = ss.select_mask(scores, visible, TOPK)
         stored = jnp.pad(a["latent"], ((0, 0), (0, 0), (0, 64)))
         return lc.masked_attention(a["q_nope"], a["q_rope"], stored, chosen,
                                    a["w_uk"], a["w_uv"], 0.0625), chosen
@@ -91,9 +92,9 @@ def test_prefill_tiles_match_the_plain_form_at_16k(parity_record):
     assert idx.shape == (1, rows, TOPK) and bool(valid.all())
     under_mask, chosen = jax.jit(masked)(a)
     # the bisections' set is the sort's, to the bit
-    assert bool((chosen == lc.chosen_mask(idx, valid, T)).all())
+    assert bool((chosen == ss.chosen_mask(idx, valid, T)).all())
     # exact: the 2,048 largest of each row, by an independent count
-    scores = np.asarray(lc.index_scores(a["q_idx"], a["w_idx"], a["keys"]))[0]
+    scores = np.asarray(ss.index_scores(a["q_idx"], a["w_idx"], a["keys"]))[0]
     for r in (0, rows - 1):
         row = np.where(np.asarray(visible[r]), scores[r], -np.inf)
         least = np.sort(row)[-TOPK]
@@ -119,6 +120,7 @@ def test_step_through_the_block_table_matches_the_plain_form(parity_record):
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import latent_cache as lc
+    from mxnet_tpu.ops import sparse_select as ss
     from mxnet_tpu.ops import paged_attention as pa
 
     s, bs = 16, 16
@@ -141,7 +143,7 @@ def test_step_through_the_block_table_matches_the_plain_form(parity_record):
 
     def fast(lat_pool, idx_pool, a):
         win = pa.window(lat_pool, tables, pos, T, False)
-        idx, valid = lc.window_select(a["q_idx"][0], a["w_idx"][0], idx_pool,
+        idx, valid = ss.window_select(a["q_idx"][0], a["w_idx"][0], idx_pool,
                                       win, TOPK)
         return lc.window_attention(a["q_nope"][0], a["q_rope"][0], lat_pool,
                                    win, idx, valid, a["w_uk"], a["w_uv"],
@@ -153,7 +155,7 @@ def test_step_through_the_block_table_matches_the_plain_form(parity_record):
         # a slot at a time: the expanded keys of one are 0.9 GB
         return jax.lax.map(lambda x: lc.plain_attention(
             x[0][None, None], x[1][None, None], x[2][None],
-            lc.chosen_mask(x[3][None, None], x[4][None, None], T),
+            ss.chosen_mask(x[3][None, None], x[4][None, None], T),
             a["w_uk"], a["w_uv"], 0.0625)[0, 0],
             (a["q_nope"][0], a["q_rope"][0], latent, idx, valid))
 
